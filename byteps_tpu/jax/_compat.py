@@ -1,57 +1,9 @@
-"""JAX version compatibility shims.
+"""One import site for the two jax APIs every layer of the tree uses.
 
-All in-tree code (library, tests, examples) that touches an API renamed
-or added across the supported jax range goes through this module instead
-of jax directly:
-
-- ``shard_map``: top-level on jax >= 0.8, ``jax.experimental.shard_map``
-  before; the replication-check kwarg renamed check_rep -> check_vma.
-- ``axis_size``: ``jax.lax.axis_size`` exists only on newer jax; older
-  versions spell it ``lax.psum(1, axis)`` (statically evaluated, so it
-  is a Python int inside shard_map either way, and raises NameError on
-  an unbound axis exactly like the real one).
+``shard_map`` and ``axis_size`` are plain aliases of ``jax.shard_map`` and
+``jax.lax.axis_size`` (jax 0.9): ~45 call sites in the library, tests and
+examples import them from here, so the names stay.
 """
 
-from __future__ import annotations
-
-import inspect
-
-from jax import lax as _lax
-
-try:  # jax >= 0.8 exports shard_map at top level
-    from jax import shard_map as _raw_shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
-
-# The replication-check kwarg was renamed check_rep -> check_vma across jax
-# versions; detect which one this jax accepts.
-_params = inspect.signature(_raw_shard_map).parameters
-if "check_vma" in _params:
-    _CHECK_KW = "check_vma"
-elif "check_rep" in _params:  # pragma: no cover - older jax
-    _CHECK_KW = "check_rep"
-else:  # pragma: no cover
-    _CHECK_KW = None
-
-
-def shard_map(f=None, /, *, mesh, in_specs, out_specs, check_vma=True):
-    """jax.shard_map with the replication-check kwarg name normalised."""
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if _CHECK_KW is not None:
-        kwargs[_CHECK_KW] = check_vma
-    if f is None:
-        return lambda g: _raw_shard_map(g, **kwargs)
-    return _raw_shard_map(f, **kwargs)
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` with a pre-axis_size-API fallback.
-
-    Inside ``shard_map``/``pmap`` both forms return the mapped axis size
-    as a Python int (``psum`` of a concrete constant is evaluated
-    statically); outside, both raise ``NameError`` for the unbound axis
-    name — callers that probe for "am I inside spmd?" rely on that.
-    """
-    if hasattr(_lax, "axis_size"):
-        return _lax.axis_size(axis_name)
-    return _lax.psum(1, axis_name)
+from jax import shard_map  # noqa: F401
+from jax.lax import axis_size  # noqa: F401

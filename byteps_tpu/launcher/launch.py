@@ -17,7 +17,9 @@ TPU-first differences from the reference:
   ``--workers-per-host N`` for CPU-simulation topologies.
 - ``--local N`` convenience mode brings up a full localhost fleet
   (scheduler + servers + N workers) in one command — the reference needs
-  a shell script (tests/run_byteps_test.sh) for this.
+  a shell script (tests/run_byteps_test.sh) for this. The workers get no
+  device assignment and a chip belongs to one process, so N > 1 is a CPU
+  topology (``JAX_PLATFORMS=cpu``); on a TPU host use ``--local 1``.
 - NUMA pinning: ``--numa`` prefixes workers with ``numactl --cpunodebind``
   round-robin, like the reference's numa wrapper.
 """
@@ -452,7 +454,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     "see docs/env.md)")
     p.add_argument("--local", type=int, metavar="N", default=0,
                    help="localhost fleet mode: launch scheduler + servers + "
-                        "N workers on 127.0.0.1")
+                        "N workers on 127.0.0.1 (N > 1: CPU fleets only — "
+                        "workers are assigned no devices; on a TPU host "
+                        "one worker drives all local chips)")
     p.add_argument("--num-servers", type=int, default=1,
                    help="servers for --local mode (default 1)")
     p.add_argument("--port", type=int, default=0,

@@ -174,8 +174,9 @@ def flash_attention(
     left of every query's window are skipped entirely, so compute scales
     with ``seq * window`` instead of ``seq^2 / 2``.
 
-    Exact softmax attention, O(seq) memory. ``interpret=None`` auto-selects
-    interpret mode off-TPU (tests run the same kernel on CPU). Drop-in for
+    Exact softmax attention, O(seq) memory. ``interpret=None`` compiles
+    the kernel on a TPU backend and interprets it elsewhere (tests run the
+    same kernel on CPU); pass ``False`` to refuse interpretation. Drop-in for
     ``byteps_tpu.parallel.full_attention``, including as the inner kernel
     of ``ulysses_attention(attn_fn=...)``.
     """
@@ -184,6 +185,17 @@ def flash_attention(
                          "attention is a causal scheme)")
     return _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k,
                            interpret, window=window)
+
+
+def _resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``None`` means: the compiled Mosaic kernel on a ``tpu`` backend —
+    never the interpreter there — and the Pallas interpreter elsewhere
+    (the CPU tests). A caller that must not run interpreted (a chip
+    measurement) passes ``interpret=False``, which fails off-TPU instead
+    of quietly interpreting."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
 def _to_bhsd(x):
@@ -203,8 +215,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
     s_k = k.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _resolve_interpret(interpret)
 
     bq = min(block_q, max(s_q, 8))
     bk = min(block_k, max(s_k, 8))
@@ -434,8 +445,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res,
     s_k = k.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _resolve_interpret(interpret)
 
     bq = min(_BWD_BQ, max(s_q, 8))
     bk = min(_BWD_BK, max(s_k, 8))

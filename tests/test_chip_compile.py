@@ -1,0 +1,104 @@
+"""Compile the main path's programs for the chip, without the chip.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+*described* ``v5e:2x2`` topology (on-chip-measurement guide §2.3): what it
+refuses here — an unaligned slice, too much VMEM, a program that does not
+fit HBM — would have cost chip time. Nothing runs, so these say nothing
+about results or speed; ``chip_smoke.py`` does that on the chip.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from byteps_tpu.ops.flash_attention import flash_attention  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip; the next one then warns.
+    Keep these silent and the cache clean."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+# (batch, seq, heads, head_dim), causal, window
+FLASH_CASES = {
+    "gpt2_124m_b8_s512": ((8, 512, 12, 64), True, None),
+    "long_b1_s4096_d128": ((1, 4096, 8, 128), True, None),
+    "window1024_b1_s4096": ((1, 4096, 8, 128), True, 1024),
+    "bert_large_noncausal_b32_s128": ((32, 128, 16, 64), False, None),
+}
+
+
+@pytest.mark.parametrize("with_grads", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("case", sorted(FLASH_CASES))
+def test_flash_kernel_compiles_for_v5e(topo, case, with_grads):
+    shape, causal, window = FLASH_CASES[case]
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if with_grads else fwd
+    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.slow
+def test_gpt2_124m_collective_step_compiles_for_one_v5e(topo):
+    """The whole chip_smoke phase-1 program — make_train_step, GPT-2 124M,
+    adamw, b8 x s512 — for one described chip, and it fits its 16 GB."""
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import byteps_tpu.jax as bps
+    from byteps_tpu.jax.training import make_train_step
+    from byteps_tpu.models import GPT2Small, lm_loss
+
+    model = GPT2Small()
+    tx = optax.adamw(1e-4)
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dcn", "ici"))
+    bps.init(mesh=mesh)
+    step = make_train_step(lambda p, b: lm_loss(model.apply(p, b), b), tx)
+
+    def described(tree, spec):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(mesh, spec)), tree)
+
+    tokens = jax.ShapeDtypeStruct((8, 512), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    compiled = step.lower(
+        described(params, P()), described(jax.eval_shape(tx.init, params), P()),
+        described(tokens, P(("dcn", "ici")))).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16e9

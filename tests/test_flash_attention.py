@@ -163,3 +163,15 @@ def test_flash_window_requires_causal(rng):
     q, k, v = _qkv(rng, b=1, s=32, h=1, d=16)
     with pytest.raises(ValueError, match="causal"):
         flash_attention(q, k, v, False, None, 16, 16, None, 8)
+
+
+@pytest.mark.parametrize("backend,asked,want", [
+    ("tpu", None, False),   # the chip path can never take the interpreter
+    ("cpu", None, True),    # what lets these tests run the kernel code
+    ("cpu", False, False),  # a measurement refuses interpretation outright
+    ("tpu", True, True),
+])
+def test_interpret_resolution(monkeypatch, backend, asked, want):
+    from byteps_tpu.ops.flash_attention import _resolve_interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert _resolve_interpret(asked) is want

@@ -36,8 +36,7 @@ from tools.shaped_fleet import cpu_busy_since, run_fleet  # noqa: E402
 def worker_main(args) -> None:
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=1")
-    import jax
-    jax.config.update("jax_platforms", "cpu")
+    import jax  # the fleet is pinned to the CPU by JAX_PLATFORMS alone
     import jax.numpy as jnp
     import numpy as np
     import optax
