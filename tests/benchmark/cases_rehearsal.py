@@ -1,0 +1,131 @@
+"""The command off the chip: its refusal, and whole cells at a tiny size
+through ``benchmark/run.py``'s own code path, on 1 and on 4 virtual devices.
+Each rehearsal is a process of its own: a cell requires exactly as many
+devices as it has chips, and ``bps.init()`` takes all there are."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+from bench_tiny import REPO  # noqa: E402
+
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+END_TO_END = {"tokens_per_s_per_chip", "step_ms_p50", "mfu_pct",
+              "peak_hbm_gb", "setup_s"}
+
+
+def _python(argv, devices, timeout=300):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
+               PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    return subprocess.run([sys.executable, *argv], env=env, cwd=REPO,
+                          capture_output=True, text=True, timeout=timeout)
+
+
+def _rehearse(workload, trace, devices):
+    out = _python([os.path.join(HERE, "bench_tiny.py"), workload, str(trace)],
+                  devices)
+    assert out.returncode == 0, out.stdout + out.stderr
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    diagnostics = json.loads(out.stderr.strip().splitlines()[-1])
+    return last, diagnostics
+
+
+def test_the_command_refuses_a_cpu():
+    """As the driver calls it, where JAX finds no TPU: another exit code
+    than 0 and no result, never a device metric from a CPU."""
+    out = _python([os.path.join(REPO, "benchmark", "run.py"), "--workload",
+                   "gpt2-124m.collective.1chip", "--seed", "0", "--seconds",
+                   "1", "--trace", "0"], devices=1)
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""
+    assert "needs 1 tpu device(s)" in out.stderr
+    assert "'platform': 'cpu'" in out.stderr
+
+
+def test_the_command_refuses_an_unknown_workload():
+    out = _python([os.path.join(REPO, "benchmark", "run.py"), "--workload",
+                   "nope", "--seed", "0", "--seconds", "1", "--trace", "0"],
+                  devices=1)
+    assert out.returncode != 0 and out.stdout.strip() == ""
+    assert "no workload 'nope'" in out.stderr
+
+
+def test_one_chip_cell_end_to_end_line():
+    last, diag = _rehearse("gpt2-124m.collective.1chip", 0, devices=1)
+    assert set(last) == RESULT_KEYS
+    assert last["correct"] is True and last["failed"] == 0
+    assert last["attempted"] == diag["steps"] > 0
+    assert set(last["metrics"]) == END_TO_END
+    for name, m in last["metrics"].items():
+        assert set(m) == {"value", "unit"} and isinstance(m["value"], float)
+    assert last["metrics"]["step_ms_p50"]["unit"] == "ms"
+    assert last["device"] == {"platform": "cpu", "kind": "cpu", "count": 1,
+                              "memory_peak_bytes": 0}
+    assert diag["problems"] == [] and diag["agreement"]["ok"]
+    assert diag["agreement"]["max_loss_diff"] < 2e-3
+
+
+def test_four_chip_cell_traced_line():
+    last, diag = _rehearse("bert-large.collective.4chip", 1, devices=4)
+    assert set(last) == RESULT_KEYS | {"breakdown"}
+    assert last["correct"] is True and last["failed"] == 0
+    assert set(last["metrics"]) == {
+        "step.device_ms", "step.programs_per_step", "ici.collective_ms",
+        "ici.exposed_ms", "device.idle_pct", "setup.compile_s"}
+    device = last["device"]
+    assert device["count"] == 4 and 0 < device["busy_s"] <= device["window_s"]
+    assert set(last["breakdown"]) == {"device_ops", "idle_gaps"}
+    for rows in last["breakdown"].values():
+        assert 0 < len(rows) <= 10
+        assert all(isinstance(n, str) and s >= 0 for n, s in rows)
+    assert diag["traced_steps"] == 10 and diag["problems"] == []
+
+
+def test_a_cell_needs_exactly_its_chips():
+    out = _python([os.path.join(HERE, "bench_tiny.py"),
+                   "bert-large.collective.4chip", "0"], devices=2)
+    assert out.returncode != 0 and out.stdout.strip() == ""
+    assert "needs 4 cpu device(s)" in out.stderr
+
+
+@pytest.mark.ps
+def test_ps_cell_with_a_real_loopback_fleet():
+    """The PS cell tiny: scheduler and server children come up, one float32
+    gradient tree is pushed per step, both children exit 0 (or the run
+    raises), and the C core's readers report."""
+    last, diag = _rehearse("gpt2-124m.ps.1chip", 1, devices=1)
+    assert last["correct"] is True and last["failed"] == 0
+    m = last["metrics"]
+    assert m["ccore.push_bytes_per_step"]["value"] == 4 * diag["n_params"]
+    assert m["ccore.round_wall_ms"]["value"] > 0
+    assert m["boundary.tree_roundtrip_ms"]["value"] > 0
+    assert m["fleet.start_s"]["value"] > 0
+    assert "setup.ccore_build_s" in m and diag["rounds"] == diag["steps"]
+    logs = os.listdir(os.path.join(REPO, ".benchmark_out",
+                                   "gpt2-124m.ps.1chip", "fleet"))
+    assert sorted(logs) == ["scheduler0.log", "server1.log"]
+
+
+def test_the_command_fails_where_only_the_benchmark_is(tmp_path):
+    """In a directory that holds BENCHMARK.json and the files under `paths`
+    and nothing of the program: another exit code than 0, no result."""
+    import shutil
+
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), tmp_path)
+    for path in ("benchmark", os.path.join("tests", "benchmark")):
+        shutil.copytree(os.path.join(REPO, path), tmp_path / path,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="")
+    out = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload",
+         "gpt2-124m.collective.1chip", "--seed", "0", "--seconds", "1",
+         "--trace", "0"], env=env, cwd=tmp_path, capture_output=True,
+        text=True, timeout=300)
+    assert out.returncode != 0 and out.stdout.strip() == ""
+    assert "No module named 'byteps_tpu'" in out.stderr
