@@ -5,6 +5,7 @@
 
 #include "metrics.h"
 #include "tenancy.h"
+#include "trace.h"
 
 namespace bps {
 
@@ -29,14 +30,17 @@ bool EnvOn(const char* name, bool dflt) {
 // stays bounded and the ring keeps moving.
 constexpr int kOpenRounds = 8;
 
-void AppendRec(std::string* out, const RoundRec& r) {
-  char buf[512];
-  snprintf(buf, sizeof(buf),
+// `span` (local rounds only): the elapsed-time fields follow `wall_us`.
+// Fleet records came over the heartbeat wire and have none.
+void AppendRec(std::string* out, const RoundRec& r,
+               const RoundSpan* span = nullptr) {
+  char buf[768];
+  int n = snprintf(buf, sizeof(buf),
            "{\"round\":%d,\"parts\":%d,\"queue_us\":%lld,"
            "\"comp_us\":%lld,\"push_us\":%lld,\"sum_us\":%lld,"
            "\"wire_ack_us\":%lld,\"pull_us\":%lld,\"dec_us\":%lld,"
            "\"wire_bytes\":%lld,\"wire_msgs\":%d,\"fused_frames\":%d,"
-           "\"retries\":%d,\"parked\":%d,\"wall_us\":%lld}",
+           "\"retries\":%d,\"parked\":%d,\"wall_us\":%lld",
            r.round, r.parts, static_cast<long long>(r.queue_us),
            static_cast<long long>(r.comp_us),
            static_cast<long long>(r.push_us),
@@ -48,7 +52,28 @@ void AppendRec(std::string* out, const RoundRec& r) {
            static_cast<long long>(r.wire_bytes), r.wire_msgs,
            r.fused_frames, r.retries, r.parked,
            static_cast<long long>(RoundWallUs(r)));
+  if (span) {
+    // Offsets are from start_us (the first enqueue); a stage that never
+    // ran reads 0 / 0.
+    auto rel = [&](int64_t t) {
+      return static_cast<long long>(t ? t - span->first_enq_us : 0);
+    };
+    n += snprintf(
+        buf + n, sizeof(buf) - n,
+        ",\"start_us\":%lld,\"elapsed_us\":%lld,"
+        "\"push_offset_us\":%lld,\"push_window_us\":%lld,"
+        "\"pull_offset_us\":%lld,\"pull_window_us\":%lld",
+        static_cast<long long>(span->first_enq_us),
+        static_cast<long long>(span->first_enq_us && span->last_done_us
+                                   ? span->last_done_us - span->first_enq_us
+                                   : 0),
+        rel(span->push_start_us),
+        static_cast<long long>(span->push_end_us - span->push_start_us),
+        rel(span->pull_start_us),
+        static_cast<long long>(span->pull_end_us - span->pull_start_us));
+  }
   *out += buf;
+  *out += "}";
 }
 
 }  // namespace
@@ -57,6 +82,7 @@ RoundStats::RoundStats()
     : ring_cap_(static_cast<size_t>(EnvLL("BYTEPS_ROUNDSTATS_RING", 256))) {
   if (ring_cap_ < 8) ring_cap_ = 8;
   ring_.resize(ring_cap_);
+  spans_.resize(ring_cap_);
   armed_.store(EnvOn("BYTEPS_ROUNDSTATS_ON", true),
                std::memory_order_relaxed);
   heartbeat_summary_on_ = EnvOn("BYTEPS_ROUNDSTATS_HEARTBEAT_SUMMARY", true);
@@ -83,18 +109,30 @@ void RoundStats::Track(int32_t stage, int round, int64_t us,
   std::lock_guard<std::mutex> lk(mu_);
   OpenRound& o = open_[round];
   o.rec.round = round;
+  // Four of the stages also stamp the round's elapsed time (RoundSpan).
+  // A window opens at the earliest issue (now - us) and closes at the
+  // latest completion.
+  auto widen = [us](int64_t now, int64_t* start, int64_t* end) {
+    if (*start == 0 || now - us < *start) *start = now - us;
+    if (now > *end) *end = now;
+  };
   switch (stage) {
-    case RS_ENQ:   ++o.enqueued; break;
+    case RS_ENQ:
+      ++o.enqueued;
+      if (o.span.first_enq_us == 0) o.span.first_enq_us = NowUs();
+      break;
     case RS_QUEUE: o.rec.queue_us += us; break;
     case RS_COMP:  o.rec.comp_us += us; break;
     case RS_PUSH:
       o.rec.push_us += us;
       o.rec.wire_bytes += bytes;
+      widen(NowUs(), &o.span.push_start_us, &o.span.push_end_us);
       break;
     case RS_SUM:   o.rec.sum_us += us; break;
     case RS_PULL:
       o.rec.pull_us += us;
       o.rec.wire_bytes += bytes;
+      widen(NowUs(), &o.span.pull_start_us, &o.span.pull_end_us);
       break;
     case RS_DEC:   o.rec.dec_us += us; break;
     case RS_RETRY: ++o.rec.retries; break;
@@ -106,6 +144,7 @@ void RoundStats::Track(int32_t stage, int round, int64_t us,
     case RS_DONE:
       ++o.done;
       ++o.rec.parts;
+      o.span.last_done_us = NowUs();
       break;
     default: return;
   }
@@ -147,6 +186,7 @@ void RoundStats::TryFinalizeLocked() {
 void RoundStats::FinalizeLocked(int round) {
   const RoundRec& r = open_[round].rec;
   ring_[ring_head_] = r;
+  spans_[ring_head_] = open_[round].span;
   ring_head_ = (ring_head_ + 1) % ring_cap_;
   ++ring_total_;
   PublishGaugesLocked(r);
@@ -292,7 +332,8 @@ std::string RoundStats::SnapshotJson() {
          std::to_string(forced_ + (over > 0 ? over : 0));
   out += ",\"last\":";
   if (ring_total_ > 0) {
-    AppendRec(&out, ring_[(ring_head_ + ring_cap_ - 1) % ring_cap_]);
+    size_t last = (ring_head_ + ring_cap_ - 1) % ring_cap_;
+    AppendRec(&out, ring_[last], &spans_[last]);
   } else {
     out += "null";
   }
@@ -303,7 +344,8 @@ std::string RoundStats::SnapshotJson() {
   out += ",\"rounds\":[";
   for (size_t i = 0; i < n; ++i) {
     if (i) out += ",";
-    AppendRec(&out, ring_[(start + i) % ring_cap_]);
+    size_t slot = (start + i) % ring_cap_;
+    AppendRec(&out, ring_[slot], &spans_[slot]);
   }
   out += "]";
   out += ",\"fleet\":{";

@@ -81,6 +81,21 @@ struct RoundRec {
   int32_t parked = 0;
 };
 
+// One round's elapsed-time stamps on this rank's NowUs() clock
+// (CLOCK_MONOTONIC us). RoundRec's *_us fields are sums over the round's
+// partitions (partition-time); these say when the round really ran.
+// Local only: kept BESIDE RoundRec, never inside it, so the heartbeat
+// wire element and kRoundSummaryVersion stay as they are. 0 = not seen
+// (a server's ledger-less rounds carry no ENQ/DONE).
+struct RoundSpan {
+  int64_t first_enq_us = 0;   // first RS_ENQ
+  int64_t last_done_us = 0;   // last RS_DONE
+  int64_t push_start_us = 0;  // earliest RS_PUSH issue (now - us)
+  int64_t push_end_us = 0;    // latest RS_PUSH ack
+  int64_t pull_start_us = 0;  // earliest RS_PULL issue (now - us)
+  int64_t pull_end_us = 0;    // latest RS_PULL response
+};
+
 // Heartbeat sub-payload: header + `count` RoundRecs (the rounds
 // completed since the last beat, oldest first, capped — see
 // kMaxWireRecs). Versioned so old/new nodes interop: a reader accepts
@@ -157,6 +172,7 @@ class RoundStats {
 
   struct OpenRound {
     RoundRec rec;
+    RoundSpan span;
     int32_t enqueued = 0;  // RS_ENQ count (0 on roles with no enqueue)
     int32_t done = 0;      // RS_DONE count
   };
@@ -187,6 +203,7 @@ class RoundStats {
   int64_t ring_total_ = 0;          // rounds ever finalized
   int64_t forced_ = 0;              // rounds force-finalized (table cap)
   std::vector<RoundRec> ring_;
+  std::vector<RoundSpan> spans_;    // parallel to ring_, same slots
   int64_t wire_sent_total_ = 0;     // rounds already shipped via FillWire
 
   // Fleet aggregation (scheduler; populated by Ingest).
@@ -206,10 +223,11 @@ class RoundStats {
 // documentation; see docs/monitoring.md "Round insight").
 constexpr double kRoundEwmaAlpha = 0.2;
 
-// Sum of the worker-observed stage times — the round's "wall" cost on
-// one rank (pull_us overlaps push_us across partitions, so this is an
-// attribution weight, not literal wall-clock; shares of it are what
-// insight.py classifies on).
+// Sum of the worker-observed stage times, each summed over the round's
+// partitions: an ATTRIBUTION WEIGHT (partition-time), not elapsed time —
+// with hundreds of partitions in flight it is tens of times the round's
+// duration. Shares of it are what insight.py classifies on; the round's
+// elapsed time is RoundSpan's (`elapsed_us` in the local snapshot).
 inline int64_t RoundWallUs(const RoundRec& r) {
   return r.queue_us + r.comp_us + r.push_us + r.pull_us + r.dec_us;
 }

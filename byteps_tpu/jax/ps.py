@@ -15,6 +15,7 @@ and the COPYD2H → PUSH → PULL → COPYH2D pipeline stages.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Optional
 
 import jax
@@ -22,6 +23,22 @@ import jax.numpy as jnp
 import numpy as np
 
 import byteps_tpu.jax as bps
+
+# --- host spans ---------------------------------------------------------------
+# The PS leg's spans in a ``jax.profiler`` capture (``/host:CPU``, the
+# capture's own clock; free while no capture runs). This table is the one
+# place that names them: docs/timeline.md and benchmark/layers/bridge.py
+# mirror it and tests/test_ps_spans.py compares the three.
+SPAN_STEP_GRAD = "bps.step.grad"    # training.py: dispatch of the grad program
+SPAN_STEP_PS = "bps.step.ps"        # training.py: ps_push_pull + decompress
+SPAN_STEP_APPLY = "bps.step.apply"  # training.py: dispatch of the apply program
+SPAN_PUSH_PULL = "bps.ps.push_pull"  # bridge thread: _ps_push_pull_impl, whole
+SPAN_D2H = "bps.ps.d2h"             # jax.device_get of the tree
+SPAN_STAGE = "bps.ps.stage"         # staging buffers + enqueue into the C core
+SPAN_WAIT = "bps.ps.wait"           # every handle settled (the C core round)
+SPAN_H2D = "bps.ps.h2d"             # jax.device_put + reshape/astype dispatch
+SPANS = (SPAN_STEP_GRAD, SPAN_STEP_PS, SPAN_STEP_APPLY, SPAN_PUSH_PULL,
+         SPAN_D2H, SPAN_STAGE, SPAN_WAIT, SPAN_H2D)
 
 # --- ordered bridge execution ----------------------------------------------
 # Wire keys are (declaration-order id << 16 | partition) — worker.cc's
@@ -90,10 +107,13 @@ def reset_declare_cache() -> None:
 
 
 def _writable(arr: np.ndarray) -> np.ndarray:
-    """The C core pushes FROM and pulls INTO this buffer in place. On CPU
-    backends ``device_get`` returns a read-only zero-copy view of the jax
-    buffer — writing through it would mutate the (immutable) source array,
-    so un-alias exactly when the runtime says the buffer isn't ours."""
+    """The C core pushes FROM and pulls INTO this buffer in place.
+    ``device_get`` hands back read-only arrays — on the CPU backend a
+    zero-copy view of the jax buffer, on the TPU the ``jax.Array``'s cached
+    host copy (196 of 196 leaves of a GPT-2 tree; PERF.md, PR 24) — and
+    writing through one would mutate the (immutable) source array, so
+    un-alias exactly when the runtime says the buffer isn't ours. That is a
+    copy of the whole tree every step: most of ``bps.ps.stage``."""
     arr = np.ascontiguousarray(arr)
     if not arr.flags.writeable:
         arr = np.array(arr)
@@ -225,32 +245,45 @@ def _ps_push_pull_impl(tree, average, prefix, async_mode):
     if not leaves:
         return tree
     leaves = _as_arrays(leaves)
-    plan = _wire_plan(leaves, _codec_active(st))
-    tids = _tids(client, prefix, leaves, plan)
-    # One batched D2H for the whole tree; each result is a fresh
-    # contiguous writable host buffer that serves as both push source and
-    # pull destination (no second host-side copy).
-    host = jax.device_get(leaves)
-    staged = []
-    for tid, arr, leaf, (wire_dtype, _) in zip(tids, host, leaves, plan):
-        arr = _writable(arr)
-        if arr.dtype != np.dtype(wire_dtype):
-            arr = arr.astype(wire_dtype)  # half-wire + codec: f32 DCN leg
-        h = client.push_pull(tid, arr, average=average,
-                             async_mode=async_mode)
-        staged.append((h, arr, leaf))
-    _wait_all(client, staged)
-    # ONE batched H2D for the whole tree (mirror of the batched
-    # device_get above): per-leaf jnp.asarray would pay the host-boundary
-    # dispatch latency once PER LEAF. jax.device_put on the list lets the
-    # runtime overlap them.
-    # Downcast upcast-staged leaves on host first so the upload leg pays
-    # half-precision bytes too (the device-side astype is then a no-op).
-    devs = jax.device_put(
-        [arr if arr.dtype == getattr(leaf, "dtype", arr.dtype)
-         else arr.astype(leaf.dtype) for _, arr, leaf in staged])
-    out = [d.reshape(leaf.shape).astype(leaf.dtype)
-           for d, (_, _, leaf) in zip(devs, staged)]
+    # mono_ns is CLOCK_MONOTONIC, the C core's NowUs() clock, read at the
+    # span's start: (mono_ns - the event's ts) maps the core's stamps onto
+    # the capture's clock (utils/timeline.py, docs/timeline.md).
+    with jax.profiler.TraceAnnotation(
+            SPAN_PUSH_PULL, mono_ns=time.monotonic_ns(), leaves=len(leaves),
+            bytes=sum(l.size * l.dtype.itemsize for l in leaves)):
+        plan = _wire_plan(leaves, _codec_active(st))
+        tids = _tids(client, prefix, leaves, plan)
+        # One batched D2H for the whole tree. The staged copy of each
+        # result (see _writable) serves as both push source and pull
+        # destination.
+        with jax.profiler.TraceAnnotation(SPAN_D2H):
+            host = jax.device_get(leaves)
+        staged = []
+        with jax.profiler.TraceAnnotation(SPAN_STAGE):
+            for tid, arr, leaf, (wire_dtype, _) in zip(tids, host, leaves,
+                                                       plan):
+                arr = _writable(arr)
+                if arr.dtype != np.dtype(wire_dtype):
+                    # half-wire + codec: f32 DCN leg
+                    arr = arr.astype(wire_dtype)
+                h = client.push_pull(tid, arr, average=average,
+                                     async_mode=async_mode)
+                staged.append((h, arr, leaf))
+        with jax.profiler.TraceAnnotation(SPAN_WAIT):
+            _wait_all(client, staged)
+        # ONE batched H2D for the whole tree (mirror of the batched
+        # device_get above): per-leaf jnp.asarray would pay the
+        # host-boundary dispatch latency once PER LEAF. jax.device_put on
+        # the list lets the runtime overlap them.
+        # Downcast upcast-staged leaves on host first so the upload leg
+        # pays half-precision bytes too (the device-side astype is then a
+        # no-op).
+        with jax.profiler.TraceAnnotation(SPAN_H2D):
+            devs = jax.device_put(
+                [arr if arr.dtype == getattr(leaf, "dtype", arr.dtype)
+                 else arr.astype(leaf.dtype) for _, arr, leaf in staged])
+            out = [d.reshape(leaf.shape).astype(leaf.dtype)
+                   for d, (_, _, leaf) in zip(devs, staged)]
     return jax.tree_util.tree_unflatten(treedef, out)
 
 

@@ -90,7 +90,7 @@ def _make_ps_train_step(loss_fn, optimizer, mesh, axes, average, compression,
     applied inside jit before the host transfer (XLA fuses the cast) and
     undone after the pull.
     """
-    from byteps_tpu.jax.ps import ps_push_pull
+    from byteps_tpu.jax import ps
 
     if compression.name in ("int8_quant", "int8_quant_dcn"):
         # int8_quant replaces the *collective transport* (all-to-all of
@@ -125,12 +125,17 @@ def _make_ps_train_step(loss_fn, optimizer, mesh, axes, average, compression,
                         donate_argnums=(0, 1) if donate else ())
 
     def step(params, opt_state, batch):
-        loss, grads = grad_step(params, batch)
+        # Host spans for a jax.profiler capture (names: jax/ps.py's table);
+        # each is a no-op context while no capture runs.
+        with jax.profiler.TraceAnnotation(ps.SPAN_STEP_GRAD):
+            loss, grads = grad_step(params, batch)
         dtypes = jax.tree_util.tree_map(lambda p: p.dtype, params)
-        grads = ps_push_pull(grads, average=average, prefix=prefix)
-        grads = jax.tree_util.tree_map(
-            lambda g, d: compression.decompress(g, d), grads, dtypes)
-        params, opt_state = apply_jit(params, opt_state, grads)
+        with jax.profiler.TraceAnnotation(ps.SPAN_STEP_PS):
+            grads = ps.ps_push_pull(grads, average=average, prefix=prefix)
+            grads = jax.tree_util.tree_map(
+                lambda g, d: compression.decompress(g, d), grads, dtypes)
+        with jax.profiler.TraceAnnotation(ps.SPAN_STEP_APPLY):
+            params, opt_state = apply_jit(params, opt_state, grads)
         return params, opt_state, loss
 
     return step

@@ -129,11 +129,12 @@ class Timeline:
             self._device_dir = os.path.join(
                 self._cfg.trace_dir, f"device_rank{self._rank()}")
             jax.profiler.start_trace(self._device_dir)
-            # Anchor the two clock domains: the C core stamps spans with
-            # CLOCK_MONOTONIC microseconds (std::chrono::steady_clock on
-            # Linux) == time.monotonic_ns()//1000 here. Captured at trace
-            # start so the merge can shift core spans onto the device
-            # trace's timebase.
+            # Fallback anchor of the two clock domains, for a capture
+            # without a bps.ps.push_pull span (which carries the measured
+            # relation, see merge_core_device_traces): the C core stamps
+            # spans with CLOCK_MONOTONIC microseconds ==
+            # time.monotonic_ns()//1000 here, sampled once start_trace
+            # has returned.
             self._anchor_us = time.monotonic_ns() // 1000
             self._profiling = True
         except Exception:
@@ -165,15 +166,33 @@ def find_device_chrome_trace(device_dir: str) -> Optional[str]:
     return max(paths, key=os.path.getmtime) if paths else None
 
 
+def capture_clock_offset_us(device_events) -> Optional[float]:
+    """CLOCK_MONOTONIC µs minus the capture's own ``ts``, read off the
+    capture: ``jax/ps.py`` stamps every ``bps.ps.push_pull`` span with
+    ``mono_ns`` (``time.monotonic_ns()`` at the span's start — the C core's
+    ``NowUs()`` clock), and the profiler gives the same instant as the
+    event's ``ts``. None when the capture holds no such span (collective
+    mode, or a window without a PS step)."""
+    from byteps_tpu.jax.ps import SPAN_PUSH_PULL
+
+    for e in device_events:
+        if e.get("name") == SPAN_PUSH_PULL and "mono_ns" in e.get("args", {}):
+            return int(e["args"]["mono_ns"]) / 1e3 - e["ts"]
+    return None
+
+
 def merge_core_device_traces(core_path: str, device_dir: str,
                              out_path: str, anchor_monotonic_us: int) -> int:
     """Merge the C core's DCN spans into the jax.profiler device trace —
     one Chrome JSON with device and host-comm stages on a single timeline.
 
     The core stamps spans in CLOCK_MONOTONIC µs; the device trace uses its
-    own µs timebase starting near ``start_trace``. ``anchor_monotonic_us``
-    (monotonic clock sampled at start_trace) maps one onto the other:
-    device ts 0 ≈ anchor. Returns the number of merged core events.
+    own µs timebase starting near ``start_trace``. The relation between
+    the two is measured, from a ``bps.ps.push_pull`` span of the capture
+    (``capture_clock_offset_us``). Only a capture without one falls back
+    to the guess ``anchor_monotonic_us`` — the monotonic clock sampled
+    after ``start_trace`` returned, which can lie tens of ms after the
+    capture's ts 0. Returns the number of merged core events.
     """
     import gzip
     import json
@@ -187,6 +206,9 @@ def merge_core_device_traces(core_path: str, device_dir: str,
         core = json.load(f)
 
     events = list(dev.get("traceEvents", []))
+    offset_us = capture_clock_offset_us(events)
+    if offset_us is None:
+        offset_us = anchor_monotonic_us
     events.append({"name": "process_name", "ph": "M", "pid": _DCN_PID,
                    "args": {"name": "byteps DCN (C core)"}})
     n = 0
@@ -195,7 +217,7 @@ def merge_core_device_traces(core_path: str, device_dir: str,
             continue
         shifted = dict(e)
         shifted["pid"] = _DCN_PID
-        shifted["ts"] = e["ts"] - anchor_monotonic_us
+        shifted["ts"] = e["ts"] - offset_us
         events.append(shifted)
         n += 1
     dev["traceEvents"] = events

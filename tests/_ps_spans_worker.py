@@ -1,0 +1,69 @@
+"""Worker of tests/test_ps_spans.py: two PS-mode training steps under a
+``jax.profiler`` capture; prints the capture's ``bps.*`` events as one JSON
+line for the test to judge."""
+
+import glob
+import json
+import os
+import sys
+import time
+
+import jax
+
+jax.config.update("jax_platforms", "cpu")
+
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+import optax  # noqa: E402
+
+import byteps_tpu.jax as bps  # noqa: E402
+from byteps_tpu.jax.training import make_train_step  # noqa: E402
+
+
+def main() -> int:
+    trace_dir = os.environ["BPS_SPANS_DIR"]
+    bps.init()
+    try:
+        def loss_fn(params, batch):
+            x, y = batch
+            return jnp.mean((x @ params["w"] + params["b"] - y) ** 2)
+
+        tx = optax.sgd(0.05)
+        step = make_train_step(loss_fn, tx)
+        params = {"w": jnp.zeros((64, 8), jnp.float32),
+                  "b": jnp.zeros((8,), jnp.float32)}
+        opt_state = tx.init(params)
+        prng = np.random.default_rng(3)
+
+        def batch():
+            x = jnp.asarray(prng.standard_normal((16, 64)), jnp.float32)
+            return x, x[:, :8] * 0.5
+
+        params, opt_state, _ = step(params, opt_state, batch())  # compiles
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        mono = [time.monotonic_ns()]
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        try:
+            for _ in range(2):
+                params, opt_state, loss = step(params, opt_state, batch())
+            float(loss)
+        finally:
+            jax.profiler.stop_trace()
+        mono.append(time.monotonic_ns())
+    finally:
+        bps.shutdown()
+    (xplane,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(xplane)
+    events = [{"plane": plane.name, "line": i, "name": ev.name,
+               "start_ns": int(ev.start_ns), "dur_ns": int(ev.duration_ns),
+               "stats": {k: v for k, v in ev.stats}}
+              for plane in data.planes for i, line in enumerate(plane.lines)
+              for ev in line.events if ev.name.startswith("bps.")]
+    print(json.dumps({"events": events, "mono_ns": mono}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -126,37 +126,44 @@ def test_timeline_window(tmp_path, monkeypatch):
 def test_timeline_combined_device_plus_dcn(tmp_path):
     """XPlane interop (SURVEY.md §5): the C core's DCN spans merge into
     the jax.profiler Chrome trace — device and host-comm stages on ONE
-    timeline, core monotonic clock shifted onto the device timebase."""
-    import gzip
+    timeline, core monotonic clock shifted onto the device timebase by the
+    relation the capture itself carries (a bps.ps.push_pull span's
+    mono_ns stat against its ts), not by the sampled anchor."""
     import json
     import time
 
-    from byteps_tpu.utils.timeline import (find_device_chrome_trace,
+    from byteps_tpu.jax.ps import SPAN_PUSH_PULL
+    from byteps_tpu.utils.timeline import (capture_clock_offset_us,
+                                           find_device_chrome_trace,
                                            merge_core_device_traces)
 
     dev_dir = str(tmp_path / "dev")
-    anchor = time.monotonic_ns() // 1000
     jax.profiler.start_trace(dev_dir)
     x = jax.jit(lambda a: a @ a)(jnp.ones((128, 128)))
     x.block_until_ready()
+    span_mono_ns = time.monotonic_ns()
+    with jax.profiler.TraceAnnotation(SPAN_PUSH_PULL, mono_ns=span_mono_ns):
+        time.sleep(0.002)
     jax.profiler.stop_trace()
     assert find_device_chrome_trace(dev_dir) is not None
 
     # Synthetic C-core dump, stamped in the real monotonic clock exactly
-    # as worker.cc::Record does.
+    # as worker.cc::Record does: a push 500 us into the span.
     core_path = str(tmp_path / "comm.json")
-    now = time.monotonic_ns() // 1000
+    push_ts = span_mono_ns // 1000 + 500
     core = {"traceEvents": [
         {"name": "push", "ph": "X", "pid": 0, "tid": 7,
-         "ts": now - 3000, "dur": 1000, "args": {"key": 7}},
+         "ts": push_ts, "dur": 1000, "args": {"key": 7}},
         {"name": "pull", "ph": "X", "pid": 0, "tid": 7,
-         "ts": now - 2000, "dur": 1500, "args": {"key": 7}},
+         "ts": push_ts + 1000, "dur": 1500, "args": {"key": 7}},
     ]}
     with open(core_path, "w") as f:
         json.dump(core, f)
 
     out_path = str(tmp_path / "combined.json")
-    n = merge_core_device_traces(core_path, dev_dir, out_path, anchor)
+    # an anchor 10 s off: with the span in the capture it must not be used
+    wrong_anchor = span_mono_ns // 1000 - 10_000_000
+    n = merge_core_device_traces(core_path, dev_dir, out_path, wrong_anchor)
     assert n == 2
     with open(out_path) as f:
         merged = json.load(f)
@@ -169,6 +176,13 @@ def test_timeline_combined_device_plus_dcn(tmp_path):
     # shifted onto the device timebase: within the trace's ts range,
     # not at raw monotonic magnitudes
     assert min(all_ts) - 1e6 < dcn["ts"] < max(all_ts) + 1e6
+    # and exactly where it happened: 500 us after the span's start (the
+    # stat is read a few us before the profiler stamps the span)
+    span = [e for e in merged["traceEvents"]
+            if e.get("name") == SPAN_PUSH_PULL][0]
+    assert abs(dcn["ts"] - (span["ts"] + 500)) < 200
+    # a capture without the span has no relation to offer: the anchor's turn
+    assert capture_clock_offset_us([{"name": "push", "ts": 1.0}]) is None
 
 
 def test_timeline_disabled():

@@ -166,6 +166,88 @@ def test_ingest_builds_fleet_table_and_ewma():
         assert str(node) in s["fleet_rounds"][str(100 + r)]
 
 
+# --- the round as elapsed time (RoundSpan stamps beside the sums) ----------
+
+def _finalized(ffi, round_no):
+    """Drive a later round so ``round_no`` finalizes; return its record."""
+    _drive_round(ffi, round_no + 1, parts=1)
+    (rec,) = [r for r in ffi.round_summary()["rounds"]
+              if r["round"] == round_no]
+    return rec
+
+
+def test_round_elapsed_time_and_windows():
+    """Enqueue, push, pull, done with sleeps between: ``elapsed_us`` is
+    first enqueue to last done on the core's clock, a window runs from
+    the earliest issue (completion - us) to the latest completion, and
+    ``wall_us`` is still the plain sum of the stages."""
+    import time
+
+    from byteps_tpu.core import ffi
+
+    r = 4_000_000
+    t0 = time.monotonic_ns() // 1000
+    ffi.round_track("enq", r)
+    ffi.round_track("enq", r)
+    time.sleep(0.02)
+    ffi.round_track("push", r, 15_000, 100)   # issued 15 ms ago: t0 + ~5 ms
+    time.sleep(0.03)
+    ffi.round_track("push", r, 20_000, 100)   # ends the window at ~50 ms
+    ffi.round_track("pull", r, 10_000, 100)   # issued at ~40 ms
+    time.sleep(0.02)
+    ffi.round_track("pull", r, 5_000, 100)    # ends at ~70 ms
+    ffi.round_track("done", r)
+    time.sleep(0.01)
+    ffi.round_track("done", r)                # ~80 ms
+    t1 = time.monotonic_ns() // 1000
+    rec = _finalized(ffi, r)
+    slack = 15_000   # sleeps overshoot, never undershoot
+    assert t0 <= rec["start_us"] <= t0 + slack
+    assert 80_000 <= rec["elapsed_us"] <= t1 - t0
+    assert 5_000 <= rec["push_offset_us"] <= 5_000 + slack
+    assert 45_000 <= rec["push_window_us"] <= 45_000 + slack
+    assert 40_000 <= rec["pull_offset_us"] <= 40_000 + slack
+    assert 30_000 <= rec["pull_window_us"] <= 30_000 + slack
+    # the windows overlap here: the first pull went out before the last ack
+    assert rec["pull_offset_us"] < rec["push_offset_us"] + rec[
+        "push_window_us"]
+    assert rec["push_us"] == 35_000 and rec["pull_us"] == 15_000
+    assert rec["wall_us"] == 50_000           # the sums, as before
+
+
+def test_round_elapsed_time_is_not_partition_time():
+    """200 partitions in flight at once, 50 ms each: ``wall_us`` adds them
+    up (partition-time, 10 s), ``elapsed_us`` says how long it took."""
+    from byteps_tpu.core import ffi
+
+    r, parts = 4_100_000, 200
+    for _ in range(parts):
+        ffi.round_track("enq", r)
+    for _ in range(parts):
+        ffi.round_track("push", r, 50_000, 1)
+    for _ in range(parts):
+        ffi.round_track("done", r)
+    rec = _finalized(ffi, r)
+    assert rec["wall_us"] == parts * 50_000
+    assert rec["elapsed_us"] < 50_000                  # microseconds, really
+    assert 50_000 <= rec["push_window_us"] < 100_000   # one push's length
+    assert rec["pull_window_us"] == rec["pull_offset_us"] == 0   # no pull
+
+
+def test_round_stamps_stay_off_the_heartbeat_wire():
+    """The stamps live beside RoundRec, not in it: the packed wire element
+    keeps its 80 bytes (this file's ``_REC``, ingested above), and fleet
+    records, which came over the wire, carry no elapsed-time field."""
+    from byteps_tpu.core import ffi
+
+    assert _REC.size == 80 and _HDR.size == 32
+    assert ffi.round_ingest(_pack_summary(47, [_pack_rec(11)]))
+    fleet_rec = ffi.round_summary()["fleet"]["47"]["last"]
+    assert fleet_rec["round"] == 11 and "wall_us" in fleet_rec
+    assert not {"start_us", "elapsed_us", "push_window_us",
+                "pull_window_us"} & set(fleet_rec)
+
+
 # --- classification boundaries (pure python) --------------------------------
 
 def _rec(parts=4, queue=0, comp=0, push=0, sum_us=0, pull=0, dec=0,

@@ -164,7 +164,13 @@ def test_scheduler_round_table_matches_workers_and_wire_bound(tmp_path):
         node = str(rec["node_id"])
         local_last = rec["local_last"]
         sched_rec = table[str(local_last["round"])][node]
-        assert sched_rec == local_last, (sched_rec, local_last)
+        # ... plus, locally only, the round's elapsed-time stamps: they sit
+        # beside the wire struct and never cross the heartbeat.
+        assert set(local_last) - set(sched_rec) == {
+            "start_us", "elapsed_us", "push_offset_us", "push_window_us",
+            "pull_offset_us", "pull_window_us"}
+        assert sched_rec == {k: local_last[k] for k in sched_rec}, (
+            sched_rec, local_last)
         # /metrics gauges mirror the same record (monitor.top's view).
         g = rec["gauges"]
         assert g["bps_round_last"] == local_last["round"]

@@ -1,0 +1,73 @@
+"""The PS leg's host spans (``byteps_tpu/jax/ps.py::SPANS``): one table,
+mirrored by the docs and the benchmark's reader, and a real PS step under a
+``jax.profiler`` capture writes all eight where the table says."""
+
+import json
+import os
+import re
+
+from byteps_tpu.jax import ps
+from tests.ps_utils import REPO, run_topology
+
+WORKER = os.path.join(REPO, "tests", "_ps_spans_worker.py")
+
+
+def test_span_tables_agree():
+    """The program's table, docs/timeline.md's and the benchmark reader's
+    mirror name the same eight spans."""
+    from benchmark.layers import bridge
+
+    assert len(ps.SPANS) == len(set(ps.SPANS)) == 8
+    assert bridge.SPANS == ps.SPANS
+    with open(os.path.join(REPO, "docs", "timeline.md")) as f:
+        documented = re.findall(r"^\| `(bps\.[a-z0-9_.]+)` \|", f.read(), re.M)
+    assert tuple(documented) == ps.SPANS
+
+
+def test_a_ps_step_writes_the_eight_spans(tmp_path):
+    """1 worker + 1 server on loopback, two traced steps: every span twice;
+    the three step spans on the caller's line; push_pull and its five
+    children together on another (the bridge thread), the children inside
+    it, in order, without overlap; the three stats on push_pull, with
+    ``mono_ns`` on the C core's clock."""
+    (out,) = run_topology(
+        1, 1, WORKER, extra={
+            "BYTEPS_PS_MODE": "ps", "BYTEPS_FORCE_DISTRIBUTED": "1",
+            "BPS_SPANS_DIR": str(tmp_path / "trace"),
+            "XLA_FLAGS": "--xla_force_host_platform_device_count=1"})
+    found = json.loads(out.strip().splitlines()[-1])
+    events = found["events"]
+    assert {e["plane"] for e in events} == {"/host:CPU"}
+    by_name = {name: [e for e in events if e["name"] == name]
+               for name in ps.SPANS}
+    assert {n: len(v) for n, v in by_name.items()} == dict.fromkeys(
+        ps.SPANS, 2)
+
+    caller = {e["line"] for n in ps.SPANS[:3] for e in by_name[n]}
+    bridge = {e["line"] for n in ps.SPANS[3:] for e in by_name[n]}
+    assert len(caller) == 1 and len(bridge) == 1 and caller != bridge
+
+    def end(e):
+        return e["start_ns"] + e["dur_ns"]
+
+    for k, whole in enumerate(by_name[ps.SPAN_PUSH_PULL]):
+        outer = by_name[ps.SPAN_STEP_PS][k]
+        assert outer["start_ns"] <= whole["start_ns"] <= end(whole) <= end(
+            outer)
+        children = [by_name[n][k] for n in (
+            ps.SPAN_D2H, ps.SPAN_STAGE, ps.SPAN_WAIT, ps.SPAN_H2D)]
+        edges = [whole["start_ns"]]
+        for child in children:
+            edges += [child["start_ns"], end(child)]
+        edges.append(end(whole))
+        assert edges == sorted(edges)
+        # 2 leaves: w 64x8 and b 8, float32
+        assert whole["stats"]["leaves"] == 2
+        assert whole["stats"]["bytes"] == 4 * (64 * 8 + 8)
+        lo, hi = found["mono_ns"]
+        assert lo < whole["stats"]["mono_ns"] < hi
+    first, second = by_name[ps.SPAN_PUSH_PULL]
+    # one clock relation for the whole capture: (mono_ns - ts) is a constant
+    drift = ((second["stats"]["mono_ns"] - second["start_ns"])
+             - (first["stats"]["mono_ns"] - first["start_ns"]))
+    assert abs(drift) < 1_000_000
