@@ -9,11 +9,15 @@ reduction denominator factorises as (local chips via pmean) x (worker
 hosts via PS average).
 
 Reference analogues: byteps/torch/ops.py (push_pull on framework tensors)
-and the COPYD2H → PUSH → PULL → COPYH2D pipeline stages.
+and the COPYD2H → PUSH → PULL → COPYH2D pipeline stages. As there, the
+pipeline is per tensor: a leaf is handed to the C core as soon as it is on
+the host and handed back to the device as soon as its handle has settled, so
+both host-boundary transfers run under the C core round.
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Optional
@@ -33,10 +37,10 @@ SPAN_STEP_GRAD = "bps.step.grad"    # training.py: dispatch of the grad program
 SPAN_STEP_PS = "bps.step.ps"        # training.py: ps_push_pull + decompress
 SPAN_STEP_APPLY = "bps.step.apply"  # training.py: dispatch of the apply program
 SPAN_PUSH_PULL = "bps.ps.push_pull"  # bridge thread: _ps_push_pull_impl, whole
-SPAN_D2H = "bps.ps.d2h"             # jax.device_get of the tree
-SPAN_STAGE = "bps.ps.stage"         # staging buffers + enqueue into the C core
-SPAN_WAIT = "bps.ps.wait"           # every handle settled (the C core round)
-SPAN_H2D = "bps.ps.h2d"             # jax.device_put + reshape/astype dispatch
+SPAN_D2H = "bps.ps.d2h"             # every leaf's D2H issued, the first landed
+SPAN_STAGE = "bps.ps.stage"         # per leaf: land, stage, enqueue into the C core
+SPAN_WAIT = "bps.ps.wait"           # per leaf: settle, device_put; to the last settle
+SPAN_H2D = "bps.ps.h2d"             # last device_put + reshape/astype dispatch
 SPANS = (SPAN_STEP_GRAD, SPAN_STEP_PS, SPAN_STEP_APPLY, SPAN_PUSH_PULL,
          SPAN_D2H, SPAN_STAGE, SPAN_WAIT, SPAN_H2D)
 
@@ -100,6 +104,10 @@ _tid_cache: dict = {}
 # Steps that declared at least one NEW tensor (test hook: after warm-up
 # this must stop growing — one registration per tensor lifetime).
 declare_steps: int = 0
+# The newest ps_push_pull's hand-back (test hook, and the stats of its
+# ``bps.ps.h2d`` span): ``put_early_bytes`` had their device_put issued
+# before the last handle settled — under the round — of ``bytes`` in all.
+put_stats: dict = {"put_early_bytes": 0, "bytes": 0}
 
 
 def reset_declare_cache() -> None:
@@ -108,9 +116,9 @@ def reset_declare_cache() -> None:
 
 def _writable(arr: np.ndarray) -> np.ndarray:
     """The C core pushes FROM and pulls INTO this buffer in place.
-    ``device_get`` hands back read-only arrays — on the CPU backend a
-    zero-copy view of the jax buffer, on the TPU the ``jax.Array``'s cached
-    host copy (196 of 196 leaves of a GPT-2 tree; PERF.md, PR 24) — and
+    A ``jax.Array`` hands back a read-only host array — on the CPU backend
+    a zero-copy view of the jax buffer, on the TPU the array's cached host
+    copy (196 of 196 leaves of a GPT-2 tree; PERF.md, PR 24) — and
     writing through one would mutate the (immutable) source array, so
     un-alias exactly when the runtime says the buffer isn't ours. That is a
     copy of the whole tree every step: most of ``bps.ps.stage``."""
@@ -220,13 +228,16 @@ def ps_push_pull(tree, average: bool = True, prefix: str = "grad",
     like the reference's per-partition scheduling.
 
     Host-boundary discipline (reference: shared_memory.cc + ps-lite
-    zero-copy SArray, SURVEY.md §7 hard part #2): ONE batched D2H
-    transfer for the whole tree (``jax.device_get`` — the runtime
-    overlaps per-leaf transfers), the resulting host buffers are handed
-    to the C core zero-copy (pushed from and pulled back into in place),
-    and tensor declares are cached for the tree's lifetime instead of
-    re-registering every step. Executes on the FIFO bridge thread so
-    declares keep a fleet-consistent order against async ops.
+    zero-copy SArray, SURVEY.md §7 hard part #2): a per-leaf pipeline.
+    Every leaf's D2H transfer is started up front and each leaf is enqueued
+    the moment IT has landed, so the C core round begins with the first
+    leaf and not after the last; the staged host buffers are handed to the
+    C core zero-copy (pushed from and pulled back into in place); each
+    leaf's H2D transfer is issued the moment its handle has settled, so
+    only the last leaf's upload is left after the round. Tensor declares
+    are cached for the tree's lifetime instead of re-registering every
+    step. Executes on the FIFO bridge thread so declares keep a
+    fleet-consistent order against async ops.
     """
     return _run_ordered(_ps_push_pull_impl, tree, average, prefix,
                         async_mode)
@@ -245,45 +256,80 @@ def _ps_push_pull_impl(tree, average, prefix, async_mode):
     if not leaves:
         return tree
     leaves = _as_arrays(leaves)
+    nbytes = [l.size * l.dtype.itemsize for l in leaves]
+    total = sum(nbytes)
     # mono_ns is CLOCK_MONOTONIC, the C core's NowUs() clock, read at the
     # span's start: (mono_ns - the event's ts) maps the core's stamps onto
     # the capture's clock (utils/timeline.py, docs/timeline.md).
     with jax.profiler.TraceAnnotation(
             SPAN_PUSH_PULL, mono_ns=time.monotonic_ns(), leaves=len(leaves),
-            bytes=sum(l.size * l.dtype.itemsize for l in leaves)):
+            bytes=total):
         plan = _wire_plan(leaves, _codec_active(st))
         tids = _tids(client, prefix, leaves, plan)
-        # One batched D2H for the whole tree. The staged copy of each
-        # result (see _writable) serves as both push source and pull
-        # destination.
-        with jax.profiler.TraceAnnotation(SPAN_D2H):
-            host = jax.device_get(leaves)
-        staged = []
-        with jax.profiler.TraceAnnotation(SPAN_STAGE):
-            for tid, arr, leaf, (wire_dtype, _) in zip(tids, host, leaves,
-                                                       plan):
-                arr = _writable(arr)
-                if arr.dtype != np.dtype(wire_dtype):
-                    # half-wire + codec: f32 DCN leg
-                    arr = arr.astype(wire_dtype)
-                h = client.push_pull(tid, arr, average=average,
-                                     async_mode=async_mode)
-                staged.append((h, arr, leaf))
-        with jax.profiler.TraceAnnotation(SPAN_WAIT):
-            _wait_all(client, staged)
-        # ONE batched H2D for the whole tree (mirror of the batched
-        # device_get above): per-leaf jnp.asarray would pay the
-        # host-boundary dispatch latency once PER LEAF. jax.device_put on
-        # the list lets the runtime overlap them.
-        # Downcast upcast-staged leaves on host first so the upload leg
-        # pays half-precision bytes too (the device-side astype is then a
-        # no-op).
-        with jax.profiler.TraceAnnotation(SPAN_H2D):
-            devs = jax.device_put(
-                [arr if arr.dtype == getattr(leaf, "dtype", arr.dtype)
-                 else arr.astype(leaf.dtype) for _, arr, leaf in staged])
-            out = [d.reshape(leaf.shape).astype(leaf.dtype)
-                   for d, (_, _, leaf) in zip(devs, staged)]
+        # (handle, staged buffer, leaf) per enqueued leaf, and how many of
+        # the handles have settled. The staged copy of a leaf (see
+        # _writable) is both push source and pull destination: it stays
+        # referenced here until its handle has settled and its device_put
+        # has been issued.
+        staged, settled, devs = [], 0, []
+
+        def put(arr, leaf):
+            # Downcast an upcast-staged leaf on the host first so the
+            # upload pays half-precision bytes too (the device-side astype
+            # is then a no-op).
+            devs.append(jax.device_put(
+                arr if arr.dtype == leaf.dtype else arr.astype(leaf.dtype)))
+
+        try:
+            with jax.profiler.TraceAnnotation(SPAN_D2H):
+                # Start every transfer now (what jax.device_get does before
+                # it blocks; a numpy leaf has nothing to start), so that
+                # leaf 0's runs first. A buffer that is ready is copied when
+                # asked; the copies of a program still running start at its
+                # end, the one asked for LAST first (measured on the TPU
+                # runtime: asked in declaration order, leaf 0 lands last,
+                # 51 ms after the program's end instead of 2-6; PERF.md,
+                # PR 25) — so ask for those in reverse.
+                device = [l for l in leaves
+                          if hasattr(l, "copy_to_host_async")]
+                if device and not device[0].is_ready():
+                    device.reverse()
+                for leaf in device:
+                    leaf.copy_to_host_async()
+                np.asarray(leaves[0])
+            with jax.profiler.TraceAnnotation(SPAN_STAGE):
+                for tid, leaf, (wire_dtype, _) in zip(tids, leaves, plan):
+                    # blocks only until THIS leaf has landed
+                    arr = _writable(np.asarray(leaf))
+                    if arr.dtype != np.dtype(wire_dtype):
+                        # half-wire + codec: f32 DCN leg
+                        arr = arr.astype(wire_dtype)
+                    h = client.push_pull(tid, arr, average=average,
+                                         async_mode=async_mode)
+                    staged.append((h, arr, leaf))
+            # Handles settle roughly in declaration order (that is their
+            # priority), so each leaf goes back to the device while the
+            # round still works on the ones behind it — the last leaf at
+            # least, when the rest settled while it was being staged — and
+            # only the last leaf's device_put waits for the whole round.
+            with jax.profiler.TraceAnnotation(SPAN_WAIT):
+                for i, (h, arr, leaf) in enumerate(staged):
+                    settled += 1  # wait settles h whether it returns or raises
+                    client.wait(h)
+                    if i < len(staged) - 1:  # the last put is bps.ps.h2d's
+                        put(arr, leaf)
+            put_stats.update(put_early_bytes=total - nbytes[-1], bytes=total)
+            with jax.profiler.TraceAnnotation(SPAN_H2D, **put_stats):
+                put(*staged[-1][1:])
+                out = [d.reshape(leaf.shape).astype(leaf.dtype)
+                       for d, leaf in zip(devs, leaves)]
+        except BaseException:
+            # What _wait_all guarantees, from wherever the failure came:
+            # no staging buffer is freed under the C core, and nothing more
+            # goes to the device. The first error is the one raised.
+            with contextlib.suppress(Exception):
+                _wait_all(client, staged[settled:])
+            raise
     return jax.tree_util.tree_unflatten(treedef, out)
 
 

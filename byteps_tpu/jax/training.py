@@ -207,7 +207,7 @@ def make_async_train_step(
             h = client.push_pull(tid, arr, average=False, async_mode=True)
             staged.append((h, arr, None))
         _wait_all(client, staged)  # settle every handle before surfacing
-        # ONE batched H2D for the pulled server state (mirror of ps.py).
+        # ONE batched H2D for the pulled server state.
         devs = jax.device_put([arr for _, arr, _ in staged])
         fresh = [d.reshape(leaf.shape).astype(leaf.dtype)
                  for d, leaf in zip(devs, leaves0)]

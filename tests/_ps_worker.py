@@ -28,6 +28,8 @@ def main() -> int:
         return jax_overlap_main()
     if mode == "jax_bridge":
         return jax_bridge_main()
+    if mode == "jax_stream":
+        return jax_stream_main()
     if mode == "jax_global":
         return jax_global_main()
     if mode == "jax_timeline":
@@ -1314,6 +1316,68 @@ def jax_bridge_main() -> int:
             np.testing.assert_allclose(np.asarray(leaf), expect, rtol=1e-6)
         print(f"worker {rank}: jax_bridge OK "
               f"({dt * 1e3:.2f} ms/step, 64 leaves x 257 f32)")
+        return 0
+    finally:
+        bps_jax.shutdown()
+
+
+def jax_stream_main() -> int:
+    """The streamed ``ps_push_pull`` (a leaf enqueued as it lands, put back
+    as its handle settles) returns bit for bit the sum numpy computes from
+    every worker's values: three rounds over one tree of device arrays —
+    vectors, a scalar, a matrix and a last leaf of several partitions —
+    summed and averaged. BPS_STREAM_CASE: ``f32``; ``bf16_codec`` (bfloat16
+    leaves under a configured codec — here a top-k that keeps every element,
+    so the wire is exact — staged as float32 and put back as bfloat16);
+    ``int_leaf`` (an int32 counter among the floats). Two workers, so the
+    server's sum has one order."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    import jax.numpy as jnp
+    import byteps_tpu.jax as bps_jax
+    from byteps_tpu.jax import ps as ps_mod
+
+    case = os.environ["BPS_STREAM_CASE"]
+    dtype = jnp.dtype("bfloat16" if case == "bf16_codec" else "float32")
+    shapes = [(7,), (), (33, 5), (257,), (1_500_000,)]
+
+    def values(rank, step):
+        rng = np.random.default_rng(1000 * step + rank)
+        tree = {f"l{i}": rng.standard_normal(s).astype(np.float32).astype(
+            dtype) for i, s in enumerate(shapes)}
+        if case != "f32":
+            tree["count"] = np.asarray(step * 10 + rank + 1, np.int32)
+        return tree
+
+    bps_jax.init()
+    try:
+        client = bps_jax._st().ps_client
+        nw, rank = client.num_workers(), client.worker_rank()
+        assert nw == 2
+        for step in range(3):
+            mine = values(rank, step)
+            for average in (False, True):
+                out = ps_mod.ps_push_pull(
+                    jax.tree_util.tree_map(jnp.asarray, mine),
+                    average=average, prefix=f"st{int(average)}")
+                theirs = [values(r, step) for r in range(nw)]
+                for name, got in out.items():
+                    a, b = (t[name] for t in theirs)
+                    wide = np.float32 if a.dtype == dtype else a.dtype
+                    want = a.astype(wide) + b.astype(wide)
+                    if average:
+                        want = want / nw if wide == np.float32 else want // nw
+                    want = want.astype(a.dtype)
+                    assert isinstance(got, jax.Array), type(got)
+                    assert got.dtype == want.dtype and got.shape == want.shape
+                    np.testing.assert_array_equal(
+                        np.asarray(got), want,
+                        err_msg=f"{case} step {step} avg {average} {name}")
+                nbytes = [v.nbytes for v in jax.tree_util.tree_leaves(mine)]
+                assert ps_mod.put_stats == {
+                    "put_early_bytes": sum(nbytes[:-1]),
+                    "bytes": sum(nbytes)}, ps_mod.put_stats
+        print(f"worker {rank}: jax_stream {case} OK")
         return 0
     finally:
         bps_jax.shutdown()

@@ -440,6 +440,19 @@ def test_jax_ps_bridge_declare_caching():
                  extra={"BYTEPS_PS_MODE": "ps"}, timeout=180)
 
 
+@pytest.mark.parametrize("case,env", [
+    ("f32", {}),
+    ("bf16_codec", {"BYTEPS_COMPRESSOR": "type=topk;k=4194304"}),
+    ("int_leaf", {})])
+def test_jax_streamed_push_pull_is_exact(case, env):
+    """The per-leaf pipeline of ``ps_push_pull`` over a real fleet: bit for
+    bit the numpy sum, in float32, with bfloat16 leaves under a codec, and
+    with an integer leaf in the tree."""
+    run_topology(2, 1, WORKER, mode="jax_stream",
+                 extra={"BYTEPS_PS_MODE": "ps", "BPS_STREAM_CASE": case,
+                        **env}, timeout=180)
+
+
 def test_jax_timeline_combined_capture(tmp_path):
     """One timeline from a real PS-mode training step: jax.profiler device
     events + the C core's DCN push/pull spans merged (VERDICT r1 missing
