@@ -2,8 +2,9 @@
 
     JAX_PLATFORMS=cpu python benchmark/dump_events.py <trace_dir | list.json.gz> <out.json.gz> [--steps N] [--planes N]
 
-Keeps the events of the first ``--planes`` device planes and the benchmark's
-host spans, from the start of the first step span to the end of the
+Keeps the events of the first ``--planes`` device planes, the benchmark's
+host spans and those the readers name (``SPANS`` of every file in
+``benchmark/layers/``), from the start of the first step span to the end of the
 ``--steps``-th step's last program on the first device (all traced steps and
 all planes by default), as ``[plane, line, name, start_ns, duration_ns]``
 with names cut to 160 characters and times counted from the first step
@@ -11,6 +12,7 @@ span. Prints the capture's planes and lines first: look before you reduce.
 """
 
 import argparse
+import glob
 import gzip
 import json
 import os
@@ -20,7 +22,7 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from benchmark.lib import loop, trace_reduce  # noqa: E402
+from benchmark.lib import cell, loop, trace_reduce  # noqa: E402
 
 
 def main():
@@ -47,7 +49,10 @@ def main():
         print(json.dumps({"plane": p, "line": l, **row}))
 
     layout = trace_reduce.TPU
-    spans = {loop.STEP_SPAN, *loop.SPANS}
+    readers = [cell.load_module(path, "benchmark_layer")
+               for path in sorted(glob.glob(os.path.join(
+                   REPO, "benchmark", "layers", "*.py")))]
+    spans = {loop.STEP_SPAN, *loop.SPANS, *cell.reader_spans(readers)}
     host = sorted((e for e in events if e[2] in spans
                    and re.match(layout.host_plane, e[0])),
                   key=lambda e: e[3])

@@ -19,16 +19,18 @@ Per traced step, then the median over the steps:
                          returns before the bytes have landed, the apply
                          program starts when they have.
 
-The reader reads the capture itself (README: what a reader needs beyond
-``run.trace`` it collects itself). A capture without the spans — a program
-from before they existed — reports nothing.
+``SPANS`` also goes to the reduction (``lib/cell.py``), which splits the
+device's idle gaps over these spans and the benchmark's own:
+``breakdown.idle_gaps`` then names ``bps.ps.stage``, ``bps.ps.wait`` and the
+rest where it read ``bench.step`` for the whole PS leg. A capture without
+the spans — a program from before they existed — reports nothing.
 
     python3 benchmark/layers/bridge.py <trace_dir>
 
 prints the same reduction for a capture a traced run left behind
 (``.benchmark_out/<cell>/trace``), with each step's parts, the bridge
 hand-off, how much of ``bps.ps.push_pull`` its children cover, and the
-device's idle time split over the spans (``idle_by_span``).
+device's idle time split over the spans (the reduction's ``idle_gaps``).
 """
 
 import os
@@ -109,57 +111,10 @@ def reduce_spans(events, layout) -> dict:
     return out
 
 
-def idle_by_span(events, layout) -> list:
-    """The first device's idle time inside the traced window, split at span
-    boundaries: every stretch goes to the SHORTEST span that covers it, over
-    the benchmark's spans and the program's; what no span covers is
-    ``(no span)``. ``[[name, seconds], ...]``, longest first.
-
-    ``trace_reduce.reduce_events`` gives a whole gap to the span at its
-    middle. In a PS step the device idles in ONE gap, from the end of the
-    gradient program to the start of the apply program, so that rule reads
-    ``bps.ps.wait`` for all of it, whatever ``d2h`` and ``h2d`` took."""
-    from benchmark.lib import loop, trace_reduce as tr
-
-    host_re, device_re = re.compile(layout.host_plane), re.compile(
-        layout.device_plane)
-    names = {loop.STEP_SPAN, *loop.SPANS, *SPANS}
-    host, ops = [], {}
-    for plane, line, name, start, dur in events:
-        if name in names:
-            if host_re.match(plane):
-                host.append((dur, start, start + dur, name))
-        elif device_re.match(plane) and (layout.op_lines is None
-                                         or line in layout.op_lines):
-            ops.setdefault(plane, []).append((start, start + dur))
-    steps = [h for h in host if h[3] == loop.STEP_SPAN]
-    if not ops or not steps:
-        return []
-    first = ops[min(ops)]
-    # the window reduce_events uses: first step span to the later of the
-    # last span's end and the last device operation's end
-    lo = min(s for _, s, _, _ in steps)
-    hi = max(max(e for _, e in first), max(e for _, _, e, _ in host))
-    idle = tr.subtract([[lo, hi]], tr.union(tr.clip(first, lo, hi)))
-    out = {}
-    for _, start, end, name in sorted(host):         # shortest first
-        claimed = tr.length(tr.clip(idle, start, end))
-        if claimed:
-            out[name] = out.get(name, 0) + claimed
-            idle = tr.subtract(idle, [[start, end]])
-    if idle:
-        out["(no span)"] = tr.length(idle)
-    return [[n, t * 1e-9] for n, t in sorted(out.items(),
-                                             key=lambda kv: -kv[1])]
-
-
 def read(run):
-    from benchmark.lib import trace_reduce
-
     if run.trace is None:
         return {}
-    xplane = trace_reduce.find_xplane(os.path.join(run.out_dir, "trace"))
-    return reduce_spans(trace_reduce.read_events(xplane), trace_reduce.TPU)
+    return reduce_spans(run.events, run.layout)
 
 
 def main(argv) -> int:
@@ -168,17 +123,20 @@ def main(argv) -> int:
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))))
-    from benchmark.lib import trace_reduce
+    from benchmark.lib import loop, trace_reduce
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("trace_dir")
     args = ap.parse_args(argv)
     events = trace_reduce.read_events(trace_reduce.find_xplane(args.trace_dir))
     layout = trace_reduce.TPU
+    steps = split_steps(events, layout)
+    reduced = trace_reduce.reduce_events(
+        events, steps=len(steps), spans=loop.SPANS + SPANS,
+        step_span=loop.STEP_SPAN, layout=layout, top=20)
     print(json.dumps({
-        "metrics": reduce_spans(events, layout),
-        "steps": split_steps(events, layout),
-        "idle_by_span": idle_by_span(events, layout)}))
+        "metrics": reduce_spans(events, layout), "steps": steps,
+        "idle_gaps": reduced and reduced["idle_gaps"]}))
     return 0
 
 

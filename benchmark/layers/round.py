@@ -4,12 +4,11 @@ elapsed time.
 ``ffi.round_summary()`` keeps, beside each round's per-partition sums, six
 stamps on the C core's own clock (``csrc/roundstats.h::RoundSpan``): the first
 enqueue, the last pull landed, and for pushes and pulls the earliest issue and
-the latest completion. Medians over the window's rounds, chosen as
-``ccore.py`` chooses them:
+the latest completion. Medians over the rounds completed inside the window (the
+ring keeps 256: a window with more reports nothing rather than a part):
 
-``round.elapsed_ms``      first enqueue to last pull landed. What
-                          ``ccore.round_wall_ms`` was taken for, and is not:
-                          that one is partition-time.
+``round.elapsed_ms``      first enqueue to last pull landed. Not the
+                          summary's ``wall_us``, which is partition-time.
 ``round.push_window_ms``  earliest push issued to latest push acknowledged.
 ``round.pull_window_ms``  earliest pull issued to latest response.
 

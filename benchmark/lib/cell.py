@@ -76,6 +76,12 @@ def find_cell(manifest: dict, name: str) -> dict:
     raise SystemExit(f"no workload {name!r} in BENCHMARK.json (known: {known})")
 
 
+def find_config(manifest: dict, cell: dict) -> dict:
+    """The ``configs`` entry of the configuration ``cell`` names."""
+    return next(c for c in manifest["configs"]
+                if c["name"] == cell["config"])
+
+
 def metrics_for(manifest: dict, kind: str, cell_name: str) -> dict:
     """The manifest's metrics of ``kind`` that this cell reports."""
     return {m["name"]: m for m in manifest[kind]
@@ -122,9 +128,9 @@ def count_all_reduce(hlo_text: str) -> int:
 def load_cell(repo: str, manifest: dict, cell_name: str, steer: Steer):
     """The cell's files, found by the names in its manifest entry."""
     cell = find_cell(manifest, cell_name)
-    entry = next(c for c in manifest["configs"] if c["name"] == cell["config"])
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    traffic = load_json(os.path.join(here, "traffic",
+    entry = find_config(manifest, cell)
+    bench = os.path.join(repo, "benchmark")
+    traffic = load_json(os.path.join(bench, "traffic",
                                      cell["traffic"] + ".json"))
     if traffic["chips"] != cell["chips"]:
         raise SystemExit(f"{cell_name}: BENCHMARK.json says {cell['chips']} "
@@ -148,12 +154,12 @@ def load_cell(repo: str, manifest: dict, cell_name: str, steer: Steer):
         config=load_module(
             os.path.join(repo, entry["file"][:-len(".json")] + ".py"),
             "benchmark_config"),
-        readers=[load_module(os.path.join(here, "layers", r + ".py"),
+        readers=[load_module(os.path.join(bench, "layers", r + ".py"),
                              f"benchmark_layer_{r}")
                  for r in traffic["readers"]],
         # what the readers read
-        timings={}, counters={}, probes={}, trace=None, window=None,
-        n_params=0, param_shapes=[])
+        timings={}, counters={}, probes={}, trace=None, events=None,
+        layout=steer.layout, window=None, n_params=0, param_shapes=[])
 
 
 def _start_job(run, stack: contextlib.ExitStack) -> None:
@@ -189,16 +195,18 @@ def _ps_counters(run, when: str) -> None:
         run.counters[f"round_summary_{when}"] = fleet.round_summary()
 
 
-def _round_medians(run):
+def _round_stats(run, stat):
     """PS mode, for the log: the window's rounds in the C core's own
-    counters (each a sum over the round's partitions), by their medians."""
+    counters (sums over the round's partitions, and its elapsed-time
+    stamps), each reduced by ``stat`` (the median; the maximum, which says
+    whether a stalled step stalled inside the round)."""
     if run.mode != "ps":
         return None
     after = run.counters["round_summary_after"]
     n = (after["completed_total"]
          - run.counters["round_summary_before"]["completed_total"])
     rounds = after["rounds"][-n:] if 0 < n <= len(after["rounds"]) else []
-    return {k: statistics.median(r[k] for r in rounds)
+    return {k: stat(r[k] for r in rounds)
             for k in (rounds[0] if rounds else {}) if k != "round"}
 
 
@@ -263,13 +271,21 @@ def _pushed_problem(run, steps: int) -> list:
             "within 0.1%"]
 
 
-def _per_layer(run, manifest, steer: Steer, traced: int) -> dict:
+def reader_spans(readers) -> tuple:
+    """The host spans the readers name (``SPANS``: what the program writes
+    and they read), in the readers' order."""
+    return tuple(s for r in readers for s in getattr(r, "SPANS", ()))
+
+
+def _per_layer(run, manifest, traced: int) -> dict:
     """Reduce the capture, hand it to the cell's readers. Returns the
     traced line's ``metrics``, ``breakdown`` and ``device`` additions."""
     xplane = trace_reduce.find_xplane(os.path.join(run.out_dir, "trace"))
+    run.events = trace_reduce.read_events(xplane)
     run.trace = trace_reduce.reduce_events(
-        trace_reduce.read_events(xplane), steps=traced, spans=loop.SPANS,
-        step_span=loop.STEP_SPAN, layout=steer.layout)
+        run.events, steps=traced,
+        spans=loop.SPANS + reader_spans(run.readers),
+        step_span=loop.STEP_SPAN, layout=run.layout)
     if run.trace is None:
         raise RuntimeError(f"no device operation in the capture {xplane}")
     wanted = metrics_for(manifest, "per_layer", run.cell)
@@ -400,7 +416,7 @@ def run_cell(repo: str, manifest: dict, cell_name: str, *, seed: int,
               "attempted": win.attempted, "failed": failed}
     device = {**found, "memory_peak_bytes": memory_peak}
     if trace:
-        layers = _per_layer(run, manifest, steer, traced)
+        layers = _per_layer(run, manifest, traced)
         result.update(metrics=layers["metrics"],
                       breakdown=layers["breakdown"])
         device.update(layers["device"])
@@ -428,7 +444,8 @@ def run_cell(repo: str, manifest: dict, cell_name: str, *, seed: int,
             1e3 * q for q in (min(win.step_s), *statistics.quantiles(
                 win.step_s, n=4, method="inclusive"), max(win.step_s))]
         if len(win.step_s) > 1 else None,
-        "round_medians_us": _round_medians(run),
+        "round_medians_us": _round_stats(run, statistics.median),
+        "round_max_us": _round_stats(run, max),
         "rounds": ((run.counters["round_summary_after"]["completed_total"]
                     - run.counters["round_summary_before"]["completed_total"])
                    if run.mode == "ps" else None),

@@ -137,8 +137,9 @@ def reduce_events(events, *, steps: int, spans, step_span: str,
                   layout: Layout = TPU, top: int = 10):
     """Reduce one capture of ``steps`` training steps. Returns None when
     the capture holds no device operation. All times in seconds; per-step
-    figures divide by ``steps``. ``spans`` are the benchmark's host span
-    names, ``step_span`` the name of its per-step span."""
+    figures divide by ``steps``. ``spans`` are the host span names to look
+    for (the benchmark's own and those the cell's readers name),
+    ``step_span`` the name of the benchmark's per-step span."""
     device_re = re.compile(layout.device_plane)
     host_re = re.compile(layout.host_plane)
     span_names = set(spans) | {step_span}
@@ -201,19 +202,24 @@ def reduce_events(events, *, steps: int, spans, step_span: str,
             totals[op_label(n)] += e - s
     device_ops = sorted(totals.items(), key=lambda kv: -kv[1])[:top]
 
-    # Idle gaps of the first device, by what the host was doing at the
-    # middle of each: the innermost benchmark span there.
-    inner = [h for h in host if h[2] != step_span]
+    # Idle gaps of the first device by what the host was doing in them. A
+    # gap is split at span boundaries and every stretch goes to the
+    # SHORTEST span over it, on whichever thread: a PS step idles in one
+    # gap, from the gradient program's end to the apply program's start,
+    # and the span at its middle would be given all of it. What only the
+    # step span covers lies between its calls; the rest, between steps.
+    idle = [g for g in subtract([[lo, hi]], busy[first])
+            if g[1] - g[0] >= MIN_GAP_NS]
+    covers = (sorted((e - s, s, e, n) for s, e, n in host if n != step_span)
+              + sorted((e - s, s, e, f"{n} (between calls)")
+                       for s, e, n in step_spans)
+              + [(hi - lo, lo, hi, "between steps")])
     gaps = defaultdict(int)
-    for start, end in subtract([[lo, hi]], busy[first]):
-        if end - start < MIN_GAP_NS:
-            continue
-        mid = (start + end) // 2
-        name = next((n for s, e, n in inner if s <= mid < e), None)
-        if name is None:
-            name = next((f"{n} (between calls)" for s, e, n in step_spans
-                         if s <= mid < e), "between steps")
-        gaps[name] += end - start
+    for _, start, end, name in covers:
+        claimed = length(clip(idle, start, end))
+        if claimed:
+            gaps[name] += claimed
+            idle = subtract(idle, [[start, end]])
     idle_gaps = sorted(gaps.items(), key=lambda kv: -kv[1])[:top]
 
     ns = 1e-9

@@ -3,11 +3,16 @@
     python benchmark/measure.py --cells a,b [--sets 2] [--runs 6] [--trace 1]
 
 For every cell: optionally one traced run, then ``--sets`` sets of ``--runs``
-runs of the command, each a new process with another ``--seed``, for
-``run_seconds`` of ``BENCHMARK.json``. Prints, per cell and end-to-end
-metric, each set's median and spread (distance between the quartiles over
-the median) and the bound the rule gives (five times the wider spread,
-never under 1%). Every run's result line goes to
+runs of the command, each a new process, for ``run_seconds`` of
+``BENCHMARK.json``; a set's runs have another ``--seed`` each and every set
+has the same seeds. Prints, per cell and end-to-end metric, each set's median
+and spread (distance between the quartiles, as ``statistics.quantiles(values,
+n=4)`` gives them, over the median), the bound the rule gives (five times the
+wider spread, never under 1%) and the two figures the driver's check holds a
+bound to: ``tight`` (mean over the sets of the spread less the set's run
+farthest from its median; a bound under twice that is too tight) and
+``loose`` (the wider spread; a bound over eight times that, and over 1%, is
+too loose). Every run's result line goes to
 ``chiprun_out/benchmark/runs-<first seed>.jsonl``. This process never imports JAX: a
 chip belongs to one process, and that is the run's.
 """
@@ -45,8 +50,14 @@ def run_once(manifest, cell, seed, trace, log):
 
 
 def spread(values):
-    q = statistics.quantiles(values, n=4, method="inclusive")
+    q = statistics.quantiles(values, n=4)
     return (q[2] - q[0]) / statistics.median(values)
+
+
+def spread_less_farthest(values):
+    med = statistics.median(values)
+    kept = sorted(values, key=lambda v: abs(v - med))[:-1]
+    return spread(kept) if len(kept) >= 2 else 0.0
 
 
 def main():
@@ -77,7 +88,7 @@ def main():
             for _ in range(args.sets):
                 sets.append([run_once(manifest, cell, seed + i, 0, log)
                              for i in range(args.runs)])
-                seed += args.runs
+            seed += args.runs
             for metric in manifest["end_to_end"]:
                 name, per_set = metric["name"], []
                 for runs in sets:
@@ -86,12 +97,18 @@ def main():
                     if len(values) >= 2:
                         per_set.append({"median": statistics.median(values),
                                         "spread": spread(values),
+                                        "less_farthest":
+                                        spread_less_farthest(values),
                                         "n": len(values)})
                 if per_set:
                     widest = max(s["spread"] for s in per_set)
                     print(json.dumps({
                         "cell": cell, "metric": name, "sets": per_set,
-                        "bound_by_rule": max(0.01, 5 * widest)}), flush=True)
+                        "bound_by_rule": max(0.01, 5 * widest),
+                        "tight": statistics.mean(
+                            s["less_farthest"] for s in per_set),
+                        "loose": widest,
+                        "bound": metric["bound"]}), flush=True)
 
 
 if __name__ == "__main__":

@@ -8,8 +8,6 @@ import os
 import subprocess
 import sys
 
-import pytest
-
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 from bench_tiny import REPO  # noqa: E402
@@ -27,9 +25,11 @@ def _python(argv, devices, timeout=300):
                           capture_output=True, text=True, timeout=timeout)
 
 
-def _rehearse(workload, trace, devices):
-    out = _python([os.path.join(HERE, "bench_tiny.py"), workload, str(trace)],
-                  devices)
+def _rehearse(workload, trace, devices, root=REPO):
+    """``bench_tiny.py`` of the benchmark under ``root``, the program from
+    this repo."""
+    out = _python([os.path.join(root, "tests", "benchmark", "bench_tiny.py"),
+                   workload, str(trace)], devices)
     assert out.returncode == 0, out.stdout + out.stderr
     last = json.loads(out.stdout.strip().splitlines()[-1])
     diagnostics = json.loads(out.stderr.strip().splitlines()[-1])
@@ -94,33 +94,34 @@ def test_a_cell_needs_exactly_its_chips():
     assert "needs 4 cpu device(s)" in out.stderr
 
 
-@pytest.mark.ps
-def test_ps_cell_with_a_real_loopback_fleet():
-    """The PS cell tiny: scheduler and server children come up, one float32
-    gradient tree is pushed per step, both children exit 0 (or the run
-    raises), and the C core's readers report."""
-    last, diag = _rehearse("gpt2-124m.ps.1chip", 1, devices=1)
+def test_an_added_cell_runs_from_a_copy_of_the_benchmark(tmp_path):
+    """What ``additions.py`` adds — a cut configuration with another batch,
+    a traffic file, a reader, a cell — laid over a copy of the benchmark and
+    run from there, tiny, through the copy's own ``run.py``: no file that
+    was there had to change, and the new reader's metric is on the line."""
+    import additions
+
+    manifest, files = additions.additions(
+        json.load(open(os.path.join(REPO, "BENCHMARK.json"))), REPO)
+    additions.copy_benchmark(REPO, tmp_path)
+    additions.write(tmp_path, manifest, files)
+    last, diag = _rehearse(additions.CELLS[1], 1, devices=1, root=tmp_path)
     assert last["correct"] is True and last["failed"] == 0
-    m = last["metrics"]
-    assert m["ccore.push_bytes_per_step"]["value"] == 4 * diag["n_params"]
-    assert m["ccore.round_wall_ms"]["value"] > 0
-    assert m["boundary.tree_roundtrip_ms"]["value"] > 0
-    assert m["fleet.start_s"]["value"] > 0
-    assert "setup.ccore_build_s" in m and diag["rounds"] == diag["steps"]
-    logs = os.listdir(os.path.join(REPO, ".benchmark_out",
-                                   "gpt2-124m.ps.1chip", "fleet"))
-    assert sorted(logs) == ["scheduler0.log", "server1.log"]
+    assert set(last["metrics"]) == {
+        "step.device_ms", "step.programs_per_step", "device.idle_pct",
+        "setup.compile_s", "counted.steps_per_fetch"}
+    assert 0 < last["metrics"]["counted.steps_per_fetch"]["value"] <= 5
+    # six layers cut to the rehearsal's two, by the new file's own sizing
+    assert diag["problems"] == [] and diag["n_params"] == 103_936
+    assert os.path.isdir(tmp_path / ".benchmark_out" / additions.CELLS[1])
 
 
 def test_the_command_fails_where_only_the_benchmark_is(tmp_path):
     """In a directory that holds BENCHMARK.json and the files under `paths`
     and nothing of the program: another exit code than 0, no result."""
-    import shutil
+    import additions
 
-    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), tmp_path)
-    for path in ("benchmark", os.path.join("tests", "benchmark")):
-        shutil.copytree(os.path.join(REPO, path), tmp_path / path,
-                        ignore=shutil.ignore_patterns("__pycache__"))
+    additions.copy_benchmark(REPO, tmp_path)
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="")
     out = subprocess.run(
         [sys.executable, "benchmark/run.py", "--workload",

@@ -13,17 +13,23 @@ import optax
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from bench_tiny import REPO, SIZING  # noqa: E402
+from bench_tiny import REPO  # noqa: E402
 
 from benchmark.lib import cell as cell_lib  # noqa: E402
 from benchmark.lib import loop, reference  # noqa: E402
 
-CONFIGS = ("gpt2-124m", "bert-large")
+# The generic cases run over every configuration of the manifest: a new
+# one gets them without writing any.
+CONFIGS = [c["name"] for c in cell_lib.load_json(
+    os.path.join(REPO, "BENCHMARK.json"))["configs"]]
 
 
-def _config(name, **over):
+def _config(name, tiny=False, **over):
+    """The configuration's sizes and module; ``tiny``: at the rehearsal
+    size its own file keeps."""
     path = os.path.join(REPO, "benchmark", "configs", name)
-    return ({**cell_lib.load_json(path + ".json"), **over},
+    cfg = cell_lib.load_json(path + ".json")
+    return ({**cfg, **(cfg["rehearsal_sizing"] if tiny else {}), **over},
             cell_lib.load_module(path + ".py", "cfg_" + name[:4]))
 
 
@@ -35,6 +41,7 @@ def test_gpt2_flops_per_token_by_hand():
     attention = 12 * 12 * 1024 * 768 // 2               # causal half
     assert module.flops_per_token(cfg) == 6 * matmul + attention \
         == 797_815_296                                  # ~0.80 GFLOP/token
+    assert cfg["n_params"] == 124_439_808
 
 
 def test_bert_large_flops_per_token_by_hand():
@@ -45,18 +52,19 @@ def test_bert_large_flops_per_token_by_hand():
     attention = 12 * 24 * 128 * 1024
     assert module.flops_per_token(cfg) == 6 * matmul + attention \
         == 2_043_506_688                                # ~2.04 GFLOP/token
+    assert cfg["n_params"] == 366_426_938
 
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_parameter_count_is_the_published_model_s(name):
-    """Full depth and width: the file's n_params is what the model the
-    file describes really has (shapes only, nothing is allocated)."""
+    """At the file's own depth and width: its n_params is what the model
+    the file describes really has (shapes only, nothing is allocated). The
+    published counts themselves are in the by-hand cases above."""
     cfg, module = _config(name)
     init, _ = module.build(cfg)
     shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
     n = sum(math.prod(l.shape) for l in jax.tree_util.tree_leaves(shapes))
     assert n == cfg["n_params"]
-    assert n == {"gpt2-124m": 124_439_808, "bert-large": 366_426_938}[name]
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -69,20 +77,22 @@ def test_batches_come_from_the_seed_and_cover_the_vocabulary(name):
     assert not np.array_equal(a["tokens"], c["tokens"])
     assert a["tokens"].shape == (16, cfg["seq_len"])
     assert a["tokens"].max() > 0.9 * cfg["vocab_size"]
-    if "mask" in a:   # 15% of 128 = 19 positions a row, inputs masked there
-        assert (a["mask"].sum(axis=1) == 19).all()
+    if "mask" in a:   # the file's rate of every row (BERT: 15% of 128 =
+        #               19 positions), inputs masked there
+        masked = round(cfg["mask_rate"] * cfg["seq_len"])
+        assert masked > 0 and (a["mask"].sum(axis=1) == masked).all()
         assert (a["tokens"][a["mask"] == 1] == cfg["mask_token_id"]).all()
         assert (a["tokens"] == a["labels"])[a["mask"] == 0].all()
 
 
-@pytest.mark.parametrize("name,shards", [("gpt2-124m", 1), ("gpt2-124m", 4),
-                                         ("bert-large", 1), ("bert-large", 4)])
+@pytest.mark.parametrize("shards", (1, 4))     # the chips a cell may have
+@pytest.mark.parametrize("name", CONFIGS)
 def test_plain_reference_is_the_program_s_loss(name, shards):
     """In float32 the plain jax.numpy forward pass and the program's flax
     model agree to rounding; over ``shards`` the reference's weights give
     the mean over shards of each shard's own loss — also where the shards'
     mask counts differ."""
-    cfg, module = _config(name, **SIZING[name], compute_dtype="float32")
+    cfg, module = _config(name, tiny=True, compute_dtype="float32")
     init, loss_fn = module.build(cfg)
     params = init(jax.random.PRNGKey(0))
     batch = module.make_batch(cfg, np.random.default_rng(0), 4 * shards)
@@ -99,8 +109,7 @@ def test_plain_reference_is_the_program_s_loss(name, shards):
 
 
 def test_reference_step_is_exact_under_micro_batching():
-    cfg, module = _config("bert-large", **SIZING["bert-large"],
-                          compute_dtype="float32")
+    cfg, module = _config("bert-large", tiny=True, compute_dtype="float32")
     init, _ = module.build(cfg)
     tx = optax.adamw(1e-4)
     batch = module.make_batch(cfg, np.random.default_rng(0), 8)
