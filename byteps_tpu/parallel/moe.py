@@ -10,6 +10,12 @@ full (global) token set.
 
 Per-device code under ``shard_map`` when ``ep_axis`` is set; plain dense
 computation otherwise.
+
+``dropless_moe_ffn`` is the other design, for models that route every token
+to its ``top_k`` experts whatever the load (OLMoE, Mixtral): no capacity and
+no ``[T, E, C]`` one-hot (0.67 GB each way at 4096 tokens x 64 experts x 8),
+but the assignments sorted by expert and three grouped matmuls over the
+sorted rows. One device holds all experts; it has no ``ep_axis`` yet.
 """
 
 from __future__ import annotations
@@ -180,3 +186,121 @@ def moe_ffn(
     if ep_axis is not None:
         aux = lax.pmean(aux, ep_axis)
     return y.astype(x.dtype), aux
+
+
+# --------------------------------------------------------------------------
+# Dropless top-k routing over grouped matmuls.
+
+ROUTE_SCOPE = "bps.moe.route"      # router, top-k, sort, gather, combine
+EXPERTS_SCOPE = "bps.moe.experts"  # the grouped matmuls and their casts
+
+
+@jax.custom_vjp
+def _permute(x: jax.Array, perm: jax.Array, inverse: jax.Array) -> jax.Array:
+    """``x[perm]`` for a permutation and its inverse (``inverse[perm]`` =
+    arange). Its gradient is the gather ``g[inverse]``; autodiff, which
+    cannot know that ``perm`` has no repeats, would emit a scatter-add
+    (3.7 against 1.1 ms for 32768 x 2048 bf16 on a v5e; PERF.md, PR 28)."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inverse):
+    return x[perm], (perm, inverse)
+
+
+def _permute_bwd(res, g):
+    perm, inverse = res
+    return g[inverse], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def dropless_moe_ffn(
+    x: jax.Array,
+    router_w: jax.Array,
+    w_gate: jax.Array,
+    w_up: jax.Array,
+    w_down: jax.Array,
+    *,
+    top_k: int,
+    dtype=jnp.bfloat16,
+):
+    """Dropless top-k SwiGLU expert layer: every token reaches its
+    ``top_k`` experts.
+
+    x: [T, D]; router_w: [D, E]; w_gate, w_up: [E, D, M]; w_down:
+    [E, M, D]; no biases. The router runs in float32 at the highest matmul
+    precision (which experts a token reaches must not turn on bf16
+    rounding): softmax over all E, ``lax.top_k``, and the raw probabilities
+    as combine weights — not renormalised over the chosen k. The
+    assignments are sorted by expert (stable), the tokens gathered into
+    that order, ``down(silu(gate(x)) * up(x))`` computed as three grouped
+    matmuls with ``dtype`` operands and float32 accumulation, and the rows
+    un-permuted and summed with their weights in float32.
+
+    Returns ``(y [T, D] in x's dtype, load_balance, z_loss, counts)``:
+    ``load_balance`` = E / (T k) * sum_e counts_e * mean_t p[t, e] (1 when
+    routing is uniform; gradient through p only), ``z_loss`` =
+    mean_t logsumexp(logits_t)^2, ``counts`` [E] int32 the assignments per
+    expert (they sum to T k).
+    """
+    t, d = x.shape
+    e = router_w.shape[1]
+    if not 1 <= top_k <= e:
+        raise ValueError(f"top_k must be in 1..{e}, got {top_k}")
+    with jax.named_scope(ROUTE_SCOPE):
+        logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)       # [T, E]
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        probs = jnp.exp(logits - lse[:, None])
+        top_w, top_e = lax.top_k(probs, top_k)                  # [T, k]
+        flat_e = top_e.reshape(-1)                              # [T k]
+        order = jnp.argsort(flat_e, stable=True)    # sorted row -> t*k + j
+        counts = (flat_e[:, None] == jnp.arange(e)[None, :]).sum(
+            axis=0, dtype=jnp.int32)
+        back = jnp.argsort(order)                   # t*k + j -> sorted row
+        xs = _permute(jnp.repeat(x.astype(dtype), top_k, axis=0), order,
+                      back)                                     # [T k, D]
+        load_balance = (counts.astype(jnp.float32)
+                        * probs.mean(axis=0)).sum() * (e / (t * top_k))
+        z_loss = jnp.mean(lse * lse)
+    with jax.named_scope(EXPERTS_SCOPE):
+        # lax.ragged_dot: row i of the sorted rows times the matrix of its
+        # group, float32 accumulation, result in `dtype`. The TPU compiler
+        # makes one Mosaic kernel of each call (`ragged-dot` in the device
+        # trace), forward, dgrad and the per-group wgrad alike; elsewhere
+        # it is a masked dense product. Chosen over Pallas megablox by
+        # measurement and for needing no import (PERF.md section 4).
+        gate = lax.ragged_dot(xs, w_gate.astype(dtype), counts)
+        up = lax.ragged_dot(xs, w_up.astype(dtype), counts)
+        ys = lax.ragged_dot(jax.nn.silu(gate) * up, w_down.astype(dtype),
+                            counts)                             # [T k, D]
+    with jax.named_scope(ROUTE_SCOPE):
+        y = jnp.einsum("tkd,tk->td",
+                       _permute(ys, back, order).reshape(t, top_k, d),
+                       top_w, preferred_element_type=jnp.float32)
+    return y.astype(x.dtype), load_balance, z_loss, counts
+
+
+def publish_moe_stats(moe_stats) -> dict:
+    """Per-expert assignment counts (the ``"moe_stats"`` collection of a
+    model applied with it mutable: every leaf an [E] count) to
+    ``monitor/metrics.py``: gauge ``bps_moe_max_expert_load`` (the busiest
+    expert's assignments over the mean, worst layer), counter
+    ``bps_moe_assignments_total``. Returns what it published."""
+    import numpy as np
+
+    from byteps_tpu.monitor import metrics
+
+    leaves = [np.asarray(c) for c in jax.tree_util.tree_leaves(moe_stats)]
+    if not leaves:
+        return {}
+    out = {"bps_moe_max_expert_load":
+           max(float(c.max() / c.mean()) for c in leaves),
+           "bps_moe_assignments_total": float(sum(c.sum() for c in leaves))}
+    metrics.set_gauge("bps_moe_max_expert_load",
+                      out["bps_moe_max_expert_load"])
+    metrics.inc_counter("bps_moe_assignments_total",
+                        out["bps_moe_assignments_total"])
+    return out

@@ -1,0 +1,89 @@
+"""What ``import byteps_tpu.jax, byteps_tpu.models`` loads: every cell of
+the benchmark pays for it before its first step (``setup_s``).
+
+``byteps_tpu.jax`` imports ``byteps_tpu.parallel``, which imports
+``parallel/moe.py``; ``byteps_tpu.models`` imports every model. So a kernel
+library at the top of either file is loaded by every user. The rule (PERF.md
+section 6, PR 28): a kernel library (Pallas, megablox) is imported inside
+the function that calls it, as ``models/transformer.py::_attention_fn`` does
+for the flash kernel; the expert layer's two modules import at module level
+only what was loaded before they existed.
+"""
+
+import ast
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+CHILD = r"""
+import json, sys
+import byteps_tpu.jax, byteps_tpu.models
+imported = set(sys.modules)
+
+import jax, jax.numpy as jnp, numpy as np
+from byteps_tpu.models import OlmoeTiny, olmoe_loss
+model = OlmoeTiny()
+tokens = np.zeros((1, 16), np.int32)
+params = model.init(jax.random.PRNGKey(0), tokens)
+jax.grad(lambda p: olmoe_loss(model.apply(p, tokens), tokens))(params)
+print(json.dumps({"imported": sorted(imported),
+                  "by_the_model": sorted(set(sys.modules) - imported)}))
+"""
+
+KERNEL_LIBRARIES = ("jax.experimental.pallas", "jax._src.pallas",
+                    "jax.experimental.mosaic", "byteps_tpu.ops")
+
+
+@pytest.fixture(scope="module")
+def loaded():
+    out = subprocess.run(
+        [sys.executable, "-c", CHILD], cwd=REPO, capture_output=True,
+        text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO})
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def _kernel_modules(names):
+    return [n for n in names if n.startswith(KERNEL_LIBRARIES)]
+
+
+def test_importing_the_library_loads_no_kernel_library(loaded):
+    assert "byteps_tpu.models.olmoe" in loaded["imported"]
+    assert "byteps_tpu.parallel.moe" in loaded["imported"]
+    assert _kernel_modules(loaded["imported"]) == []
+
+
+def test_the_expert_model_loads_only_what_its_grouped_matmul_needs(loaded):
+    """``lax.ragged_dot`` needs nothing beyond jax itself: building the
+    tiny OlmoeModel, applying it and taking its gradient loads no kernel
+    library and nothing of byteps_tpu that the import had not loaded."""
+    new = loaded["by_the_model"]
+    assert _kernel_modules(new) == []
+    assert [n for n in new if n.startswith("byteps_tpu")] == []
+
+
+# What the two modules may import at module level: what `import
+# byteps_tpu.jax, byteps_tpu.models` loaded before they existed.
+ALLOWED = {"__future__", "functools", "typing", "jax", "jax.numpy",
+           "flax.linen", "byteps_tpu.jax._compat", "byteps_tpu.models.llama",
+           "byteps_tpu.models.transformer", "byteps_tpu.parallel.moe"}
+
+
+@pytest.mark.parametrize("path", ("byteps_tpu/parallel/moe.py",
+                                  "byteps_tpu/models/olmoe.py"))
+def test_module_level_imports_are_the_ones_every_cell_already_paid(path):
+    with open(os.path.join(REPO, path)) as f:
+        tree = ast.parse(f.read())
+    top = []
+    for node in tree.body:
+        if isinstance(node, ast.Import):
+            top += [alias.name for alias in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            top.append(node.module)
+    assert top and set(top) <= ALLOWED, sorted(set(top) - ALLOWED)
