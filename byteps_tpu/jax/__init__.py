@@ -8,9 +8,11 @@ local_size``, ``push_pull`` (+ ``_async``/``poll``/``synchronize``),
 TPU-first semantics:
 
 - ``push_pull`` is *per-device* code when called inside ``jax.shard_map``
-  (the hot path — XLA fuses the hierarchical ICI reduce-scatter/all-gather
-  into the step program), and auto-wraps itself in a jitted shard_map when
-  called on stacked per-replica arrays outside jit.
+  (the hot path — the reduction is part of the step program: on a mesh with
+  both levels reduce-scatter over ici → dcn level → all-gather over one
+  fused buffer, on a single level one all-reduce per leaf), and auto-wraps
+  itself in a jitted shard_map when called on stacked per-replica arrays
+  outside jit.
 - Async handles map onto JAX's asynchronous dispatch: ``push_pull_async``
   returns immediately with arrays whose computation is in flight;
   ``synchronize`` blocks on them (reference: HandleManager + poll/
@@ -214,9 +216,11 @@ def push_pull(tree, average: bool = True, name: Optional[str] = None,
               compression: Compressor = Compression.none):
     """Sum (or average) a pytree of gradients across all chips.
 
-    Inside ``shard_map`` this is the hot path: hierarchical two-level
-    all-reduce (SURVEY.md §3.3's REDUCE→PUSH/PULL→BROADCAST pipeline as one
-    fused XLA program). Outside, arrays must carry a leading replica axis of
+    Inside ``shard_map`` this is the hot path, one XLA program with the
+    step: on a two-level mesh the hierarchical all-reduce (SURVEY.md §3.3's
+    REDUCE→PUSH/PULL→BROADCAST pipeline: scatter over ici, the dcn level,
+    gather), on a single level one all-reduce per leaf in the leaf's own
+    shape. Outside, arrays must carry a leading replica axis of
     length ``device_count()`` — this process's mesh size — (stacked
     per-chip values) and the same collective runs under a jitted shard_map;
     in PS mode the result then crosses the host boundary once more through
@@ -433,8 +437,9 @@ def DistributedOptimizer(
     Reference: byteps/torch DistributedOptimizer (SURVEY.md §2.5) — which
     hooks autograd to overlap communication with backward compute. In JAX
     the overlap is XLA's job: call ``update`` inside your shard_map'd jitted
-    train step and the fused reduce-scatter/all-gather is scheduled by the
-    compiler alongside remaining compute.
+    train step and the reduction (scatter / dcn level / gather on two mesh
+    levels, one all-reduce per leaf on one) is scheduled by the compiler
+    alongside remaining compute.
 
     ``backward_passes_per_step`` > 1 reproduces the reference's gradient
     accumulation contract: grads are accumulated locally that many times and

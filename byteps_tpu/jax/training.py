@@ -4,8 +4,10 @@ The reference's end-user contract (SURVEY.md §3.3): wrap your optimizer,
 call ``loss.backward()``; gradients are push_pull'd behind the scenes and
 ``step()`` applies the synchronized update. The JAX-native equivalent is a
 *jitted, shard_map'd step function*: gradients come out of ``value_and_grad``
-per-device, ``push_pull`` fuses the hierarchical reduction into the same XLA
-program, and the optimizer update runs replicated. XLA overlaps the ICI
+per-device, ``push_pull`` puts the reduction into the same XLA program
+(two mesh levels: reduce-scatter → dcn level → all-gather of one fused
+buffer; one level: one all-reduce per gradient leaf), and the optimizer
+update runs replicated. XLA overlaps the ICI
 collectives with remaining backward compute — the compiler plays the role of
 the reference's priority-scheduled background pipeline threads.
 """

@@ -13,14 +13,23 @@ TPU-native mapping:
                                      client → CPU PS (PS mode)
     BROADCAST (NCCL all-gather)   →  lax.all_gather over the ``ici`` axis
 
+That three-stage shape exists to put 1/N-sized shards on the slow fabric,
+so it runs only on a mesh that has both levels. Where one level alone has
+more than one participant (a single slice: {dcn 1, ici n}) there is no slow
+fabric to shard for, and each array is reduced by one ``lax.psum`` in its
+own shape: the TPU compiler lowers either half of a scatter / gather pair
+to a full-size all-reduce, so the pair would pay twice for one reduction.
+The choice is made at trace time from the mesh's axis sizes.
+
 Every function here is *per-device* code: call it inside ``jax.shard_map``
-over a mesh with the named axes. Shapes are static; padding is applied so
-reduce-scatter tiles evenly — both required for XLA to schedule the
-collectives on ICI without host round-trips.
+over a mesh with the named axes. Shapes are static; on the two-level path
+padding is applied so reduce-scatter tiles evenly — both required for XLA
+to schedule the collectives on ICI without host round-trips.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Callable, Optional
 
 import jax
@@ -36,6 +45,14 @@ def _axis_size(axis: Optional[str]) -> int:
     return _compat_axis_size(axis) if axis else 1
 
 
+def _levels(ici_axis: Optional[str], dcn_axis: Optional[str]):
+    """The axes that have more than one participant (else None): static at
+    trace time, so the shape of the reduction is decided per compile."""
+    ici = ici_axis if ici_axis and _axis_size(ici_axis) > 1 else None
+    dcn = dcn_axis if dcn_axis and _axis_size(dcn_axis) > 1 else None
+    return ici, dcn
+
+
 def hierarchical_all_reduce(
     x: jax.Array,
     *,
@@ -44,40 +61,46 @@ def hierarchical_all_reduce(
     average: bool = True,
     dcn_reduce_fn: Optional[ReduceFn] = None,
 ) -> jax.Array:
-    """Two-level all-reduce of one array (per-device code under shard_map).
+    """All-reduce of one array (per-device code under shard_map).
 
-    Stage 1 reduce-scatters over the fast ``ici`` axis so each chip owns
-    1/ici_size of the gradient; stage 2 reduces those shards over the slow
-    ``dcn`` axis (or hands them to ``dcn_reduce_fn`` — the PS hook); stage 3
-    all-gathers the result back over ``ici``. With 1/N-sized shards on the
-    slow fabric this is bandwidth-optimal, exactly the reference's rationale
-    (docs/rationale.md) transplanted to ICI/DCN.
+    Two levels: stage 1 reduce-scatters over the fast ``ici`` axis so each
+    chip owns 1/ici_size of the gradient; stage 2 reduces those shards over
+    the slow ``dcn`` axis (or hands them to ``dcn_reduce_fn`` — the PS
+    hook); stage 3 all-gathers the result back over ``ici``. With 1/N-sized
+    shards on the slow fabric this is bandwidth-optimal, exactly the
+    reference's rationale (docs/rationale.md) transplanted to ICI/DCN.
+
+    One level (at most one of ``ici``, ``dcn`` has more than one
+    participant): one ``lax.psum`` over that axis on ``x`` as it is — no
+    flattening, pad, scatter or gather. ``dcn_reduce_fn``, when given and
+    the level is ``dcn``, receives the flat array in its place.
     """
-    ici = ici_axis if ici_axis and _axis_size(ici_axis) > 1 else None
-    dcn = dcn_axis if dcn_axis and _axis_size(dcn_axis) > 1 else None
+    ici, dcn = _levels(ici_axis, dcn_axis)
     denom = _axis_size(ici) * _axis_size(dcn)
-
     orig_shape, orig_dtype = x.shape, x.dtype
+
+    if ici is None or dcn is None:
+        axis = ici or dcn
+        if axis is None:
+            return x
+        if dcn and dcn_reduce_fn:
+            x = dcn_reduce_fn(x.reshape(-1)).reshape(orig_shape)
+        else:
+            x = lax.psum(x, axis)
+        if average:
+            x = x / denom
+        return x.astype(orig_dtype)
+
     flat = x.reshape(-1)
     n = flat.shape[0]
-
-    if ici is None:
-        # Single-chip slice: only the slow-level reduction applies.
-        if dcn is not None:
-            flat = dcn_reduce_fn(flat) if dcn_reduce_fn else lax.psum(flat, dcn)
-        if average and denom > 1:
-            flat = flat / denom
-        return flat.reshape(orig_shape).astype(orig_dtype)
-
     ici_size = _axis_size(ici)
     pad = (-n) % ici_size
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
 
     shard = lax.psum_scatter(flat, ici, scatter_dimension=0, tiled=True)
-    if dcn is not None:
-        shard = dcn_reduce_fn(shard) if dcn_reduce_fn else lax.psum(shard, dcn)
-    if average and denom > 1:
+    shard = dcn_reduce_fn(shard) if dcn_reduce_fn else lax.psum(shard, dcn)
+    if average:
         shard = shard / denom
     out = lax.all_gather(shard, ici, axis=0, tiled=True)
     if pad:
@@ -96,12 +119,18 @@ def tree_all_reduce(
 ) -> "jax.tree_util.PyTreeDef":
     """All-reduce a pytree of arrays (per-device code under shard_map).
 
-    With ``fuse=True`` all leaves are flattened into one contiguous bf16/f32
-    buffer first (reference analogue: tensor fusion, and the reason BytePS
-    partitions at ~4 MB — big transfers saturate the fabric; SURVEY.md §6
-    "saturates 100 Gbps with ≥4 MB partitions"). One fused reduce-scatter /
-    all-gather keeps ICI busy with a single large transfer and lets XLA
-    overlap it with whatever compute remains.
+    On a two-level mesh ``fuse=True`` flattens all leaves into one
+    contiguous buffer in their widest dtype first (reference analogue:
+    tensor fusion, and the reason BytePS partitions at ~4 MB — big
+    transfers saturate the fabric; SURVEY.md §6 "saturates 100 Gbps with
+    ≥4 MB partitions"): one reduce-scatter → slow level → all-gather for
+    the whole tree, and one call of ``dcn_reduce_fn``.
+
+    On one level there is nothing to fuse: every leaf takes one all-reduce
+    in its own shape, summed in that same widest dtype and cast back, and
+    XLA's all-reduce combiner does the grouping — no tree-sized buffer is
+    built, copied or sliced. (A ``dcn_reduce_fn`` on a dcn-only mesh still
+    gets the fused buffer: one call of the hook per tree.)
     """
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if not leaves:
@@ -112,22 +141,25 @@ def tree_all_reduce(
     # (~200 MB of extra reads+writes per step on ResNet-50).
     if _axis_size(ici_axis) * _axis_size(dcn_axis) == 1:
         return tree
+    reduce = partial(
+        hierarchical_all_reduce, ici_axis=ici_axis, dcn_axis=dcn_axis,
+        average=average, dcn_reduce_fn=dcn_reduce_fn)
     if not fuse:
-        red = [
-            hierarchical_all_reduce(
-                g, ici_axis=ici_axis, dcn_axis=dcn_axis, average=average,
-                dcn_reduce_fn=dcn_reduce_fn)
-            for g in leaves
-        ]
-        return jax.tree_util.tree_unflatten(treedef, red)
+        return jax.tree_util.tree_unflatten(
+            treedef, [reduce(g) for g in leaves])
+
+    acc_dtype = jnp.result_type(*[l.dtype for l in leaves])
+    ici, dcn = _levels(ici_axis, dcn_axis)
+    if not (dcn and (ici or dcn_reduce_fn)):
+        # No slow level to shard or batch for: one psum per leaf, in the
+        # fused path's precision.
+        out = [reduce(l.astype(acc_dtype)).astype(l.dtype) for l in leaves]
+        return jax.tree_util.tree_unflatten(treedef, out)
 
     # Fused path: one flat buffer in the widest participating dtype.
-    acc_dtype = jnp.result_type(*[l.dtype for l in leaves])
     sizes = [l.size for l in leaves]
-    flat = jnp.concatenate([l.reshape(-1).astype(acc_dtype) for l in leaves])
-    flat = hierarchical_all_reduce(
-        flat, ici_axis=ici_axis, dcn_axis=dcn_axis, average=average,
-        dcn_reduce_fn=dcn_reduce_fn)
+    flat = reduce(
+        jnp.concatenate([l.reshape(-1).astype(acc_dtype) for l in leaves]))
     out, off = [], 0
     for leaf, sz in zip(leaves, sizes):
         out.append(flat[off:off + sz].reshape(leaf.shape).astype(leaf.dtype))

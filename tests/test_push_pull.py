@@ -5,12 +5,14 @@ Reference coverage model (SURVEY.md §4): push_pull over many shapes/dtypes
 root; handle poll/synchronize semantics.
 """
 
+import re
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 import byteps_tpu.jax as bps
@@ -202,3 +204,150 @@ def test_push_pull_int8_dcn_quantized_both_levels():
     expect2 = _np.sum(_np.asarray(g), axis=0)
     err2 = _np.abs(_np.asarray(out2) - expect2)
     assert err2.max() <= 0.08 * _np.abs(expect2).max() + 0.5, err2.max()
+
+
+# --- the reduction's shape follows the mesh -------------------------------
+# One level ({dcn 1, ici n} or {dcn n, ici 1}): one all-reduce per leaf, in
+# the leaf's own shape. Two levels: the fused reduce-scatter -> slow level
+# -> all-gather over one flat buffer.
+
+_MESHES = [(1, 8), (8, 1), (2, 4)]
+_MESH_IDS = ["dcn1_ici8", "dcn8_ici1", "dcn2_ici4"]
+
+
+def _odd_tree(rng, n=8):
+    """Stacked per-replica values: odd sizes (7, 5x3), a scalar-shaped
+    leaf, bf16 beside float32; 38 elements a replica."""
+    def vals(*shape):
+        return rng.standard_normal((n,) + shape).astype("float32")
+    return {
+        "a": jnp.asarray(vals(7)),
+        "b": jnp.asarray(vals(5, 3)).astype(jnp.bfloat16),
+        "s": jnp.asarray(vals()),
+        "nested": {"c": jnp.asarray(vals(5, 3))},
+    }
+
+
+def _per_device(mesh, fn):
+    """jit(shard_map(...)) of ``fn`` over stacked per-replica trees: each
+    device sees its own replica's leaves without the leading axis."""
+    spec = P(("dcn", "ici"))
+
+    @jax.jit
+    @partial(shard_map, mesh=mesh, in_specs=spec, out_specs=spec)
+    def run(tree):
+        local = jax.tree_util.tree_map(lambda x: x[0], tree)
+        return jax.tree_util.tree_map(lambda x: x[None], fn(local))
+    return run
+
+
+@pytest.mark.parametrize("average", [False, True], ids=["sum", "mean"])
+@pytest.mark.parametrize("dcn,ici", _MESHES, ids=_MESH_IDS)
+def test_tree_reduction_values_on_every_mesh_shape(dcn, ici, average):
+    mesh = _init(dcn=dcn, ici=ici)
+    tree = _odd_tree(np.random.default_rng(7))
+    out = _per_device(
+        mesh, lambda t: bps.push_pull(t, average=average))(tree)
+
+    leaves_in, treedef_in = jax.tree_util.tree_flatten(tree)
+    leaves_out, treedef_out = jax.tree_util.tree_flatten(out)
+    assert treedef_in == treedef_out
+    for i, o in zip(leaves_in, leaves_out):
+        assert o.dtype == i.dtype and o.shape == i.shape
+        # float32 accumulation of the replicas' values as they are (a bf16
+        # leaf's eight values sum exactly in float32), rounded once to the
+        # leaf's dtype: what a bf16 accumulator would not give.
+        expect = np.asarray(i, dtype="float32").sum(0)
+        if average:
+            expect = expect / 8
+        expect = np.asarray(jnp.asarray(expect).astype(i.dtype),
+                            dtype="float32")
+        got = np.asarray(o, dtype="float32")
+        for replica in got:  # every device holds the same reduced leaf
+            if i.dtype == jnp.bfloat16:
+                np.testing.assert_array_equal(replica, expect)
+            else:
+                np.testing.assert_allclose(replica, expect,
+                                           rtol=1e-6, atol=1e-6)
+
+
+def test_bf16_leaf_is_summed_in_the_widest_dtype():
+    """256 + 7 x 1 in a bf16 accumulator stays 256 in the worst order (257
+    is no bf16 number); beside a float32 leaf the tree is summed in float32
+    and the bf16 leaf comes back as bf16(263) = 264."""
+    mesh = _init(dcn=1, ici=8)
+    col = np.ones((8, 3), "float32")
+    col[0] = 256.0
+    tree = {"h": jnp.asarray(col).astype(jnp.bfloat16),
+            "w": jnp.ones((8, 2), jnp.float32)}
+    out = _per_device(mesh, lambda t: bps.push_pull(t, average=False))(tree)
+    assert out["h"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(out["h"], dtype="float32"), np.full((8, 3), 264.0))
+
+
+def _collectives(text):
+    """(op, operand type) of every collective in lowered StableHLO text,
+    in program order."""
+    found = []
+    for m in re.finditer(
+            r'"stablehlo\.(all_reduce|reduce_scatter|all_gather)"', text):
+        sig = re.search(r"\(tensor<([^>]*)>\) -> tensor<([^>]*)>",
+                        text[m.end():])
+        found.append((m.group(1), sig.group(1), sig.group(2)))
+    return found
+
+
+@pytest.mark.parametrize("dcn,ici", _MESHES, ids=_MESH_IDS)
+def test_lowered_reduction_follows_the_mesh(dcn, ici):
+    mesh = _init(dcn=dcn, ici=ici)
+    tree = _odd_tree(np.random.default_rng(0))
+    text = _per_device(
+        mesh, lambda t: bps.push_pull(t, average=True)).lower(tree).as_text()
+    ops = _collectives(text)
+    if dcn > 1 and ici > 1:
+        # 38 elements padded to 40 for four chips, float32 throughout:
+        # the triple exactly as it was before the one-level path existed.
+        assert ops == [("reduce_scatter", "40xf32", "10xf32"),
+                       ("all_reduce", "10xf32", "10xf32"),
+                       ("all_gather", "10xf32", "40xf32")]
+        assert "stablehlo.concatenate" in text
+        return
+    # One level: one all-reduce per leaf, in the leaf's shape and in the
+    # tree's widest dtype, and nothing of the tree's total size.
+    assert sorted(ops) == sorted([
+        ("all_reduce", "7xf32", "7xf32"), ("all_reduce", "5x3xf32", "5x3xf32"),
+        ("all_reduce", "f32", "f32"), ("all_reduce", "5x3xf32", "5x3xf32")])
+    for absent in ("stablehlo.concatenate", "stablehlo.pad",
+                   "stablehlo.dynamic_update_slice", "tensor<38x",
+                   "tensor<40x"):
+        assert absent not in text
+
+
+@pytest.mark.parametrize(
+    "dcn,ici,calls", [(2, 4, [(10,)]), (8, 1, [(38,)]), (1, 8, [])],
+    ids=["dcn2_ici4_gets_the_shard", "dcn8_ici1_gets_the_tree",
+         "dcn1_ici8_unused"])
+def test_dcn_reduce_fn_hook(dcn, ici, calls):
+    """The PS hook stands in for the slow level, one call a tree: on two
+    levels it receives the 1/ici shard of the fused buffer, on a dcn-only
+    mesh the fused buffer whole; with no dcn level it is not called."""
+    from byteps_tpu.parallel.hierarchical import tree_all_reduce
+
+    mesh = _init(dcn=dcn, ici=ici)
+    seen = []
+
+    def hook(shard):
+        seen.append(shard.shape)
+        return lax.psum(shard, "dcn")
+
+    tree = _odd_tree(np.random.default_rng(3))
+    out = _per_device(mesh, lambda t: tree_all_reduce(
+        t, average=False, dcn_reduce_fn=hook))(tree)
+    assert seen == calls
+    for i, o in zip(jax.tree_util.tree_leaves(tree),
+                    jax.tree_util.tree_leaves(out)):
+        np.testing.assert_allclose(
+            np.asarray(o, dtype="float32")[0],
+            np.asarray(i, dtype="float32").sum(0),
+            rtol=2e-2 if i.dtype == jnp.bfloat16 else 1e-6, atol=1e-6)
