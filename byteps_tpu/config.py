@@ -119,7 +119,8 @@ class Config:
     #   / push wire / server_sum / wire_ack / pull / decode, wire bytes,
     #   fused frames, retries, parked ops), accumulated into a bounded
     #   drop-oldest ring and classified live by monitor/insight.py.
-    #   Default ON — overhead is within noise (BENCH_insight_r07.json);
+    #   Default ON — overhead within noise on a CPU-sandbox fleet (record
+    #   in git at 72397ef), not measured on the chip;
     #   0 reduces every site to one relaxed atomic load
     roundstats_ring: int = 256            # BYTEPS_ROUNDSTATS_RING
     #   per-rank round-record ring capacity (drop-oldest; overwrites are
@@ -139,7 +140,8 @@ class Config:
     #   quarantines, ...). Non-scheduler ranks piggyback new events on
     #   CMD_HEARTBEAT; the scheduler ingests them into the clock-aligned
     #   fleet timeline served at /events and read by monitor.incident.
-    #   Default ON — overhead is within noise (BENCH_events_r20.json);
+    #   Default ON — overhead within noise on a CPU-sandbox fleet (record
+    #   in git at 72397ef), not measured on the chip;
     #   0 reduces every emit site to one relaxed atomic load
     events_ring: int = 512                # BYTEPS_EVENTS_RING
     #   per-rank journal ring capacity (drop-oldest; overwrites are

@@ -34,7 +34,8 @@ uint32_t Crc32c(const void* data, size_t len, uint32_t seed) {
   // Hardware CRC32C (the SSE4.2 crc32 instruction implements exactly
   // this reflected-Castagnoli update): ~10+ GB/s vs ~0.4 GB/s for the
   // byte-at-a-time table, which is what keeps the per-frame wire
-  // trailer inside BENCH_integrity_r19.json's <5% paced-goodput gate.
+  // trailer cheap beside the link (its cost on the chip's host: not
+  // measured).
   uint64_t c64 = c;
   while (len >= 8) {
     uint64_t w;

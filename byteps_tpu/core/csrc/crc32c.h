@@ -7,8 +7,9 @@
 // Hardware-accelerated where the build allows it (the SSE4.2 crc32
 // instruction IS reflected-Castagnoli), with a table-driven software
 // fallback — both produce identical checksums (the probe's known-vector
-// test pins them). The paced wire-overhead gate lives in
-// BENCH_integrity_r19.json.
+// test pins them). The trailer's overhead was gated on a paced
+// CPU-sandbox fleet only (record in git at 72397ef); not measured on
+// the chip.
 #pragma once
 
 #include <cstddef>

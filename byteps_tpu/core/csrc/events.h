@@ -10,8 +10,8 @@
 // bounded drop-oldest ring of TYPED, versioned FleetEvent records,
 // emitted at the exact sites where those transitions already happen,
 // cheap enough to stay on by default (BYTEPS_EVENTS_ON, armed = one
-// relaxed atomic load per site; overhead gated like BENCH_insight_r07 —
-// see BENCH_events_r20.json).
+// relaxed atomic load per site; overhead within noise on a CPU-sandbox
+// fleet, record in git at 72397ef, not measured on the chip).
 //
 // Fleet aggregation mirrors the roundstats sensor path: every
 // non-scheduler rank piggybacks its new-since-last-beat events on

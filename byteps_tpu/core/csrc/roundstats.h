@@ -7,8 +7,8 @@
 // fixed-capacity drop-oldest ring of per-round stage summaries,
 // accumulated at the SAME instrumentation sites PR 1/PR 5 already
 // touch, cheap enough to stay on by default (BYTEPS_ROUNDSTATS_ON,
-// armed = one relaxed atomic load per site; overhead gated like
-// BENCH_trace_r06 — see BENCH_insight_r07.json).
+// armed = one relaxed atomic load per site; overhead within noise on a
+// CPU-sandbox fleet, record in git at 72397ef, not measured on the chip).
 //
 // A "round" is the push_pull round number (MsgHeader.version): in the
 // synchronous step pattern every tensor advances it in lockstep, so one

@@ -35,9 +35,8 @@ inline bool QueueDebug() {
 }
 
 // BYTEPS_SCHEDULING=fifo disables the priority order (pure enqueue
-// order). Exists for A/B measurement of the scheduler's benefit
-// (tools/bench_priority.py) and as an escape hatch; "priority" (default)
-// is the reference behavior.
+// order). Exists for A/B measurement of the scheduler's benefit and as
+// an escape hatch; "priority" (default) is the reference behavior.
 inline bool FifoScheduling() {
   static const bool fifo = [] {
     const char* v = getenv("BYTEPS_SCHEDULING");

@@ -202,8 +202,9 @@ class BytePSServer {
     // would make post-recovery replies diverge from the fault-free
     // run — breaking the recovery bit-identity contract. The reply
     // rounding error is ~|aggregate|/254 per element, round-to-nearest
-    // (near-unbiased); the convergence A/B (BENCH_compression_r06)
-    // shows the worker-side push EF alone tracks dense (docs/rationale).
+    // (near-unbiased); a 29M-parameter convergence A/B (record in git
+    // at 72397ef) showed the worker-side push EF alone tracks dense
+    // (docs/rationale).
     bool quant_ok = false;
     std::vector<char> qreply[2];  // cached quantized encode per slot
     int qreply_round[2] = {-1, -1};  // round tag (see comp_reply_round)

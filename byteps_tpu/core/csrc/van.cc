@@ -3,11 +3,9 @@
 #include <arpa/inet.h>
 #include <fcntl.h>
 #include <ifaddrs.h>
-#include <linux/errqueue.h>
 #include <netdb.h>
 #include <netinet/in.h>
 #include <netinet/tcp.h>
-#include <poll.h>
 #include <sys/mman.h>
 #include <sys/socket.h>
 #include <sys/stat.h>
@@ -198,100 +196,6 @@ static void SizeSocketBuffers(int fd) {
     setsockopt(fd, SOL_SOCKET, SO_MAX_PACING_RATE, &rate, sizeof(rate));
 #endif
   }
-}
-
-// --- MSG_ZEROCOPY send path (BYTEPS_VAN_ZEROCOPY=1) -------------------------
-// The RDMA-parity experiment (SURVEY §2.4 rdma_van.h: kernel-bypass
-// zero-copy sends). Linux MSG_ZEROCOPY pins the payload pages instead of
-// copying them into kernel memory; completion arrives asynchronously on
-// the socket error queue. This implementation is SYNCHRONOUS: Send()
-// reaps the completion before returning, so caller buffer-lifetime
-// semantics are identical to the copying path (the payload may be reused
-// the moment Send returns). That costs one errqueue round trip per large
-// send — acceptable for an A/B experiment, and the per-fd send lock
-// already serialises same-connection sends. Measured verdict lives in
-// BENCH_zerocopy_r05.json / docs/best-practice.md: on loopback the
-// kernel COPIES anyway (SO_EE_CODE_ZEROCOPY_COPIED) and the notification
-// machinery is pure overhead; the path where it pays is a real NIC at
-// >=10 Gbit/s with >=1 MB partitions.
-#ifndef SO_ZEROCOPY
-#define SO_ZEROCOPY 60
-#endif
-#ifndef SO_EE_ORIGIN_ZEROCOPY
-#define SO_EE_ORIGIN_ZEROCOPY 5
-#endif
-#ifndef MSG_ZEROCOPY
-#define MSG_ZEROCOPY 0x4000000
-#endif
-
-static bool ZerocopyEnabled() {
-  static const bool on = [] {
-    const char* v = getenv("BYTEPS_VAN_ZEROCOPY");
-    return v && *v && *v != '0';
-  }();
-  return on;
-}
-
-// Minimum payload for the zerocopy path: page pinning has fixed cost, so
-// small sends always copy (the kernel's own guidance is ~10 KB; we gate
-// far above it since only partition payloads matter here).
-static constexpr int64_t kZerocopyMin = 1 << 20;
-
-// Reap errqueue notifications until the zerocopy send numbered `seq` on
-// this fd is acknowledged. Sends are serialised per fd, so completions
-// arrive in order; `reaped` tracks the highest acked sequence. TCP
-// completions arrive only once the peer ACKs the pinned pages, so on a
-// slow (e.g. paced) link a completion can legitimately take arbitrarily
-// long: there is NO fixed deadline here — the loop polls in short ticks
-// and exits on van stop or connection death (shutdown/close surfaces as
-// POLLERR/POLLHUP -> recvmsg error below).
-static bool ReapZerocopy(int fd, uint32_t seq, uint32_t* reaped,
-                         const std::atomic<bool>& stop) {
-  while (static_cast<int32_t>(*reaped - seq) < 0) {
-    pollfd pfd{fd, 0, 0};  // errqueue events surface as POLLERR
-    int pr = ::poll(&pfd, 1, 500);
-    if (pr < 0 && errno != EINTR) return false;
-    if (pr <= 0) {
-      if (stop.load()) return false;
-      continue;  // completion still in flight (slow link) — keep waiting
-    }
-    char ctrl[128];
-    msghdr mh{};
-    mh.msg_control = ctrl;
-    mh.msg_controllen = sizeof(ctrl);
-    ssize_t r = ::recvmsg(fd, &mh, MSG_ERRQUEUE);
-    if (r < 0) {
-      if (errno == EINTR) continue;
-      if (errno == EAGAIN) {
-        // POLLERR with an EMPTY errqueue: the error is on the socket
-        // itself (peer reset), not a zerocopy completion. Without these
-        // checks the loop spins at 100% CPU — poll returns instantly on
-        // the standing POLLERR, recvmsg keeps yielding EAGAIN — while
-        // holding the per-fd send lock, so even Van::Stop can't break in.
-        if (stop.load()) return false;
-        int soerr = 0;
-        socklen_t slen = sizeof(soerr);
-        if (getsockopt(fd, SOL_SOCKET, SO_ERROR, &soerr, &slen) == 0 &&
-            soerr != 0) {
-          return false;  // dead connection; completion will never come
-        }
-        continue;
-      }
-      return false;
-    }
-    for (cmsghdr* c = CMSG_FIRSTHDR(&mh); c; c = CMSG_NXTHDR(&mh, c)) {
-      // The van dials AF_INET only, so completions arrive as
-      // SOL_IP/IP_RECVERR; an IPv6 van would need SOL_IPV6/IPV6_RECVERR
-      // (25) handling here.
-      if (c->cmsg_level == SOL_IP && c->cmsg_type == IP_RECVERR) {
-        auto* ee = reinterpret_cast<sock_extended_err*>(CMSG_DATA(c));
-        if (ee->ee_origin == SO_EE_ORIGIN_ZEROCOPY) {
-          *reaped = ee->ee_data;  // range [ee_info, ee_data] completed
-        }
-      }
-    }
-  }
-  return true;
 }
 
 // --- shared-memory data path (BYTEPS_VAN_TYPE=shm) --------------------------
@@ -491,7 +395,6 @@ bool Van::SendV(int fd, const MsgHeader& head, const struct iovec* segs,
   uint64_t total = sizeof(MsgHeader) + static_cast<uint64_t>(payload_len);
   std::shared_ptr<std::mutex> smu;
   std::shared_ptr<ShmConn> shm;
-  std::shared_ptr<ZcState> zcs;
   std::shared_ptr<TxState> tx;
   {
     std::lock_guard<std::mutex> lk(mu_);
@@ -500,8 +403,6 @@ bool Van::SendV(int fd, const MsgHeader& head, const struct iovec* segs,
     smu = it->second;
     auto sit = shm_conns_.find(fd);
     if (sit != shm_conns_.end()) shm = sit->second;
-    auto zit = zc_.find(fd);
-    if (zit != zc_.end()) zcs = zit->second;
     auto tit = tx_.find(fd);
     if (tit != tx_.end()) tx = tit->second;
   }
@@ -627,18 +528,17 @@ bool Van::SendV(int fd, const MsgHeader& head, const struct iovec* segs,
   }
   bool ok = true;
   for (int send_i = 0; send_i < sends && ok; ++send_i) {
-    ok = WriteFrame(fd, h, segs, nsegs, total, payload_len, shm.get(),
-                    zcs.get());
+    ok = WriteFrame(fd, h, segs, nsegs, total, payload_len, shm.get());
   }
   return ok;
 }
 
 // One framed write on the already-locked connection: transport selection
-// (shm ring / zerocopy / gather writev) exactly as before the chaos
-// layer; factored out so a chaos-duplicated frame can be written twice.
+// (shm ring / gather writev) exactly as before the chaos layer; factored
+// out so a chaos-duplicated frame can be written twice.
 bool Van::WriteFrame(int fd, MsgHeader& h, const struct iovec* segs,
                      int nsegs, uint64_t total, int64_t payload_len,
-                     ShmConn* shm, ZcState* zcs) {
+                     ShmConn* shm) {
   // Under the per-fd send lock so the PS_VERBOSE trace order matches the
   // actual wire order (the whole point of a message trace).
   LogMsg("send", fd, h, payload_len);
@@ -660,54 +560,6 @@ bool Van::WriteFrame(int fd, MsgHeader& h, const struct iovec* segs,
         return false;
     }
     return true;
-  }
-  if (zcs && nsegs == 1 && payload_len >= kZerocopyMin) {
-    const void* payload = segs[0].iov_base;
-    // Zerocopy experiment path: copy the tiny framing, pin the payload
-    // pages. Completion is reaped before returning (synchronous — see
-    // the block comment above ZerocopyEnabled).
-    bytes_sent_.fetch_add(
-        static_cast<int64_t>(sizeof(total) + sizeof(h) + payload_len),
-        std::memory_order_relaxed);
-    if (!SendAll(fd, &total, sizeof(total)) ||
-        !SendAll(fd, &h, sizeof(h)))
-      return false;
-    const char* p = static_cast<const char*>(payload);
-    size_t left = static_cast<size_t>(payload_len);
-    while (left > 0) {
-      ssize_t n = ::send(fd, p, left, MSG_NOSIGNAL | MSG_ZEROCOPY);
-      if (n < 0) {
-        if (errno == EINTR) continue;
-        if (errno == ENOBUFS) {
-          // Usually optmem_max exhausted by unreaped notifications:
-          // drain, retry. If everything is already reaped (ENOBUFS from
-          // general memory pressure instead), the reap is a no-op — back
-          // off briefly so the retry is not a busy-spin holding the
-          // per-fd send lock.
-          bool nothing_pending =
-              zcs->next == 0 ||
-              static_cast<int32_t>(zcs->reaped - (zcs->next - 1)) >= 0;
-          if (nothing_pending) {
-            // Sustained ENOBUFS (general memory pressure) must not stall
-            // Van::Stop: bail out of the backoff loop once stop is
-            // requested instead of retrying forever under the per-fd
-            // send lock.
-            if (stop_.load()) return false;
-            usleep(1000);
-          } else if (!ReapZerocopy(fd, zcs->next - 1, &zcs->reaped,
-                                   stop_)) {
-            return false;
-          }
-          continue;
-        }
-        return false;
-      }
-      ++zcs->next;  // each MSG_ZEROCOPY send gets one completion number
-      p += n;
-      left -= static_cast<size_t>(n);
-    }
-    // left started >= kZerocopyMin, so at least one send incremented next.
-    return ReapZerocopy(fd, zcs->next - 1, &zcs->reaped, stop_);
   }
   // Gather write: framing words + every payload segment in one writev.
   // Segments beyond IOV_MAX (or past a partial write) finish through the
@@ -746,18 +598,6 @@ bool Van::WriteFrame(int fd, MsgHeader& h, const struct iovec* segs,
 
 std::shared_ptr<std::mutex> Van::StartRecvThread(int fd) {
   auto smu = std::make_shared<std::mutex>();
-  std::shared_ptr<ZcState> zcs;
-  if (ZerocopyEnabled()) {
-    int one = 1;
-    // Only arm the zerocopy path if the kernel accepts SO_ZEROCOPY —
-    // otherwise MSG_ZEROCOPY sends would fail with EINVAL.
-    if (setsockopt(fd, SOL_SOCKET, SO_ZEROCOPY, &one, sizeof(one)) == 0) {
-      zcs = std::make_shared<ZcState>();
-    } else {
-      BPS_LOG(WARNING) << "BYTEPS_VAN_ZEROCOPY=1 but SO_ZEROCOPY "
-                          "unsupported; staying on copying sends";
-    }
-  }
   auto tx = std::make_shared<TxState>();
   {
     // Seed the chaos PRNG per connection: deterministic for a fixed
@@ -769,7 +609,6 @@ std::shared_ptr<std::mutex> Van::StartRecvThread(int fd) {
   std::lock_guard<std::mutex> lk(mu_);
   send_mu_[fd] = smu;
   tx_[fd] = tx;
-  if (zcs) zc_[fd] = zcs;
   threads_.emplace_back([this, fd] { RecvLoop(fd); });
   return smu;
 }
@@ -1120,7 +959,6 @@ void Van::CloseConn(int fd) {
       shm = it->second;
       shm_conns_.erase(it);
     }
-    zc_.erase(fd);
     tx_.erase(fd);
     if (send_mu_.erase(fd) && !shm) ::close(fd);
   }

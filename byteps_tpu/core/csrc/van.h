@@ -95,12 +95,6 @@ class Van {
 
  private:
   struct ShmConn;  // mapped segment + role (van.cc)
-  // MSG_ZEROCOPY per-fd completion bookkeeping (BYTEPS_VAN_ZEROCOPY=1;
-  // van.cc zerocopy block). Touched only under the per-fd send lock.
-  struct ZcState {
-    uint32_t next = 0;              // zerocopy sends issued on this fd
-    uint32_t reaped = 0xFFFFFFFFu;  // highest completed (-1 = none yet)
-  };
   // Per-connection transmit state, mutated only under the per-fd send
   // lock: the monotone frame sequence (MsgHeader::seq) plus the chaos
   // layer's deterministic PRNG and data-frame counter
@@ -121,11 +115,11 @@ class Van {
   };
 
   // One framed write on an already-locked connection (transport
-  // selection: shm ring / zerocopy / gather writev). Factored out of
-  // SendV so the chaos layer can write a duplicated frame twice.
+  // selection: shm ring / gather writev). Factored out of SendV so the
+  // chaos layer can write a duplicated frame twice.
   bool WriteFrame(int fd, MsgHeader& h, const struct iovec* segs,
                   int nsegs, uint64_t total, int64_t payload_len,
-                  ShmConn* shm, ZcState* zcs);
+                  ShmConn* shm);
   void AcceptLoop();
   void RecvLoop(int fd);
   // Returns the per-fd send mutex it registered — an identity token for
@@ -161,8 +155,6 @@ class Van {
   // open) TCP fd. Send() consults this under the per-fd send lock, so a
   // connection's frames never interleave across transports.
   std::unordered_map<int, std::shared_ptr<ShmConn>> shm_conns_;
-  // fds armed for MSG_ZEROCOPY sends (SO_ZEROCOPY accepted at setup).
-  std::unordered_map<int, std::shared_ptr<ZcState>> zc_;
   // Per-fd transmit state (seq stamping + chaos); created with the
   // connection, looked up in SendV under the same mu_ acquisition as
   // send_mu_, mutated only under the per-fd send lock.

@@ -567,9 +567,10 @@ int64_t BytePSWorker::Declare(const std::string& name, int64_t nelem,
   // with the least bytes assigned so far (ties -> lowest index, so the
   // choice is deterministic). Every worker declares the same tensors in
   // the same order, so all workers compute the same mapping without any
-  // coordination. Round-robin by (tid + i) was measured 22% hot at 8
-  // servers on the ResNet-50 leaf distribution (tools/bench_scaling.py)
-  // — and the hottest server's links gate the whole sync round.
+  // coordination. Round-robin by (tid + i) left one of 8 servers 22%
+  // hot on the ResNet-50 leaf distribution (a byte count; record in git
+  // at 72397ef) — and the hottest server's links gate the whole sync
+  // round.
   if (server_bytes_.size() != static_cast<size_t>(ns)) {
     server_bytes_.assign(ns, 0);
   }

@@ -5,7 +5,7 @@ drives the framework through its user entry points only (``bps.init()`` →
 ``make_train_step`` / ``make_overlapped_train_step`` /
 ``make_bucketed_overlap_step`` → ``step`` → ``bps.shutdown()``, and
 ``python -m byteps_tpu.server`` for the fleet) at the full width of GPT-2
-124M as bench.py builds it (12 layers, d 768, 12 heads, vocab 50257, bf16,
+124M (12 layers, d 768, 12 heads, vocab 50257, bf16,
 seq 512, batch 8 per chip, adamw 1e-4, tokens and weights from ``--seed``).
 
 Phases of the default run, one JSON line each:
@@ -252,7 +252,7 @@ def ps_fleet(log_dir: str):
 
 def pushed_bytes() -> int:
     """Payload bytes this worker has pushed to the servers so far (the C
-    core's own counter, as bench.py's _comm_metrics reads it)."""
+    core's own counter)."""
     from byteps_tpu.core import ffi
     return int(ffi.metrics_snapshot()["counters"]["bps_push_bytes_total"])
 
@@ -288,7 +288,6 @@ def phase_device() -> dict:
 
     import jaxlib
 
-    from bench import device_peaks
     from byteps_tpu.core.build import build
     from byteps_tpu.utils.compile_cache import enable_compile_cache
 
@@ -304,7 +303,6 @@ def phase_device() -> dict:
         "jax": jax.__version__, "jaxlib": jaxlib.__version__,
         "libtpu": md.version("libtpu"),
         "memory_stats_keys": sorted(devs[0].memory_stats() or {}),
-        "peaks": device_peaks(),
         "compile_cache_dir": cache_dir,
         "compile_cache_entries_at_start": cache_entries,
         "compile_cache_max_size": jax.config.jax_compilation_cache_max_size,
@@ -363,14 +361,40 @@ def _ps_steps(name: str, make_step, prob: Problem, ref_losses, steps: int):
     }
 
 
+def host_boundary_microbench(nbytes: int):
+    """D2H / H2D GB/s for one contiguous f32 transfer of ``nbytes``
+    (callers pass the model's gradient size). Returns (d2h, h2d, bytes
+    actually moved)."""
+    import jax
+    import numpy as np
+    n = nbytes // 4
+    nbytes = n * 4  # what the probe actually moves; returned for the record
+    reps = 2
+    # One device array per repetition: a jax.Array keeps its host copy
+    # after the first device_get, so a second get of the same array
+    # would time a cache hit.
+    make = jax.jit(lambda k: jax.random.normal(k, (n,)))
+    devs = [make(jax.random.PRNGKey(i)) for i in range(reps)]
+    jax.block_until_ready(devs)
+    t0 = time.perf_counter()
+    for dev in devs:
+        host = jax.device_get(dev)
+    d2h = nbytes * reps / (time.perf_counter() - t0)
+    host = np.ascontiguousarray(host)
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        back = jax.device_put(host)
+        jax.block_until_ready(back)
+    h2d = nbytes * reps / (time.perf_counter() - t0)
+    return d2h / 1e9, h2d / 1e9, nbytes
+
+
 def phase_ps(prob: Problem, ref_losses, ref_step_s, out_dir: str,
              steps: int = 3) -> dict:
     """Phase 2: PS mode. With one worker the servers' average is the
     identity, so the losses must be phase 1's."""
     import byteps_tpu.jax as bps
     from byteps_tpu.jax.training import make_train_step
-
-    from bench_ps import host_boundary_microbench
 
     d2h, h2d, nbytes = host_boundary_microbench(4 * prob.n_params)
     rec = {"phase": "ps", "ok": True, "d2h_gbps": round(d2h, 3),

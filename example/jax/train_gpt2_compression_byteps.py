@@ -16,8 +16,7 @@ process; the env form is the reference's contract):
         --model tiny --compressor "type=onebit;ef=vanilla" --json
 
 Prints (with --json) one line with final loss, wire bytes (van
-counters: payload + framing, both legs), and steps/sec — the artifact
-the compression benchmark (BENCH_compression_r03.json) is built from.
+counters: payload + framing, both legs), and steps/sec.
 --model gpt2_medium is the reference's 345M configuration; tiny is the
 CI-sized variant the topology tests train.
 """

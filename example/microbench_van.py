@@ -475,8 +475,7 @@ def main() -> None:
     p.add_argument("--workers", type=int, default=1,
                    help="worker processes (each reports its own goodput; "
                         "per-worker goodput shrinks as workers contend "
-                        "for the servers — the scaling-model validation "
-                        "knob, docs/performance.md)")
+                        "for the servers)")
     p.add_argument("--servers", type=int, default=1)
     p.add_argument("--role", default="")
     p.add_argument("--streams-sweep", default="",

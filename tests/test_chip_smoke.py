@@ -6,6 +6,8 @@ functions take (on-chip-measurement guide §2: rehearse the control flow on
 the CPU, Pallas in interpret mode).
 """
 
+import ast
+import importlib.util
 import json
 import os
 import subprocess
@@ -40,6 +42,23 @@ def test_refuses_to_pass_without_a_tpu():
     assert "needs a TPU" in out.stderr and "'platform': 'cpu'" in out.stderr
     # it stopped at phase 0: no phase line claims success
     assert '"ok": true' not in out.stdout
+
+
+def test_every_import_resolves_off_the_chip():
+    """Phase 0 and the four-chip phase run only on a TPU, so a lazy import
+    there of a module that has left the tree shows nowhere else before the
+    chip call (PR 31: ``from bench import device_peaks``)."""
+    with open(chip_smoke.__file__) as f:
+        tree = ast.parse(f.read())
+    modules = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            modules.update(alias.name for alias in node.names)
+        elif isinstance(node, ast.ImportFrom) and node.level == 0:
+            modules.add(node.module)
+    missing = sorted(m for m in modules
+                     if importlib.util.find_spec(m) is None)
+    assert not missing, missing
 
 
 @pytest.fixture(scope="module")

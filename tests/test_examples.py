@@ -257,9 +257,8 @@ def test_half_wire_composes_with_codec_under_launcher():
 
 @pytest.mark.ps
 def test_van_microbench_multiworker_topology():
-    """The scaling-forecast validation harness: --workers/--servers spawn
-    a real w x s fleet and each worker reports goodput (docs/performance.md
-    scaling section is built from these numbers)."""
+    """--workers/--servers spawn a real w x s fleet and each worker
+    reports its goodput."""
     out = subprocess.run(
         [sys.executable, os.path.join(EX, "microbench_van.py"),
          "--mb", "1", "--tensors", "4", "--rounds", "2",

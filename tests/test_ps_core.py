@@ -40,21 +40,11 @@ def test_broadcast_from_root():
 
 
 def test_pacing_rate_path():
-    """BYTEPS_PACING_RATE (kernel TCP pacing — production NIC-fair-share
-    knob and the scaling bench's link model) must leave numerics intact;
+    """BYTEPS_PACING_RATE (kernel TCP pacing — the production
+    NIC-fair-share knob) must leave numerics intact;
     the rate is generous so the test costs no wall time."""
     run_topology(2, 1, WORKER, mode="basic",
                  extra={"BYTEPS_PACING_RATE": "1000000000"})
-
-
-def test_zerocopy_send_path():
-    """BYTEPS_VAN_ZEROCOPY=1 (MSG_ZEROCOPY experiment): the >=1 MB
-    multipart payloads take the zerocopy branch with synchronous errqueue
-    reap; sums must match exactly. Uses 1 MB partitions so at least one
-    partition clears the kZerocopyMin gate."""
-    run_topology(2, 1, WORKER, mode="multipart",
-                 extra={"BYTEPS_VAN_ZEROCOPY": "1",
-                        "BYTEPS_PARTITION_BYTES": "1048576"})
 
 
 def test_rebroadcast_delivers_fresh_values():
@@ -93,10 +83,10 @@ def test_priority_preemption(tmp_path):
 
 
 def test_fifo_mode_disables_preemption(tmp_path):
-    """BYTEPS_SCHEDULING=fifo (the A/B switch behind
-    tools/bench_priority.py): the priority signature — an
-    earlier-declared tensor popping ahead of a later-declared one that
-    entered the queue first — must NEVER appear."""
+    """BYTEPS_SCHEDULING=fifo (the A/B switch against declaration-order
+    priority): the priority signature — an earlier-declared tensor
+    popping ahead of a later-declared one that entered the queue first —
+    must NEVER appear."""
     run_topology(1, 1, WORKER, mode="priority",
                  extra={"BYTEPS_PARTITION_BYTES": "65536",
                         "BYTEPS_SCHEDULING_CREDIT": "65536",
