@@ -20,6 +20,7 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
+import weakref
 from typing import Optional
 
 import jax
@@ -108,20 +109,83 @@ declare_steps: int = 0
 # ``bps.ps.h2d`` span): ``put_early_bytes`` had their device_put issued
 # before the last handle settled — under the round — of ``bytes`` in all.
 put_stats: dict = {"put_early_bytes": 0, "bytes": 0}
+# The newest ps_push_pull's staging (test hook, and the stats of its
+# ``bps.ps.stage`` span): ``reused_bytes`` of the ``bytes`` staged went into
+# buffers an earlier call had left in the pool, the rest into new ones.
+stage_stats: dict = {"reused_bytes": 0, "bytes": 0}
+
+
+class _Slot:
+    """The staging buffer of one declared tensor: ps_push_pull copies the
+    landed leaf into it, the C core pushes from it and pulls into it in
+    place, and ``jax.device_put`` uploads from it — every step the same
+    memory, so its pages are mapped and faulted in once per tensor lifetime
+    and not once per step (a 154 MB leaf is above glibc's mmap ceiling: a
+    fresh copy of it is mmap, ≈ 37,700 page faults and munmap; PERF.md,
+    PR 25). A tid has one element count and one wire dtype for its lifetime
+    (the C core refuses a re-declare), so the buffer never changes size.
+
+    ``result`` is a WEAK reference to the array the caller got back for the
+    leaf staged here last: ``device_put`` returns before the bytes have
+    left the host, and the runtime reads ``buf`` until they have, so the
+    next write into ``buf`` first waits for that array if anyone still holds
+    it (ready implies uploaded). Weak, because a strong one would keep a
+    whole tree of device memory alive into the caller's next program; the
+    price is that a result the caller has already dropped is not waited
+    for — its bytes can then be read only by device work queued on it, and
+    a caller whose next tree depends on that work (the train step: the
+    next gradients come from the parameters these uploads updated) has
+    waited for it by having the next tree on the host at all."""
+
+    __slots__ = ("buf", "result")
+
+    def __init__(self, size: int, dtype):
+        self.buf = np.empty(size, dtype)
+        self.result = None
+
+    def fill(self, host: np.ndarray) -> np.ndarray:
+        """Copy ``host`` in (one pass; a half-precision leaf under a codec
+        is upcast to the float32 wire by the same pass) and return the
+        buffer in ``host``'s shape."""
+        prev = self.result() if self.result is not None else None
+        if prev is not None and not prev.is_deleted():
+            prev.block_until_ready()
+        arr = self.buf.reshape(host.shape)
+        np.copyto(arr, host, casting="safe")
+        return arr
+
+
+# tensor id -> _Slot: one per tensor ps_push_pull has declared and staged,
+# dropped with the tid cache (a restarted fleet never sees a stale slot).
+_slots: dict = {}
 
 
 def reset_declare_cache() -> None:
     _tid_cache.clear()
+    _slots.clear()
+
+
+def _is_host_memory_of(dev, arr: np.ndarray) -> bool:
+    """``dev`` — what ``jax.device_put`` made of ``arr`` — IS ``arr``'s
+    memory. The CPU backend does that for a buffer that happens to be
+    64-byte aligned (jax 0.9.0: about every other ``np.empty``, small or
+    4 MB) and copies otherwise; an accelerator's memory is never the
+    host's."""
+    if all(d.platform != "cpu" for d in dev.devices()):
+        return False
+    return dev.unsafe_buffer_pointer() == arr.ctypes.data
 
 
 def _writable(arr: np.ndarray) -> np.ndarray:
-    """The C core pushes FROM and pulls INTO this buffer in place.
+    """A buffer the C core may push FROM and pull INTO in place, for the
+    callers that stage a fresh one per call (``ps_broadcast``, the async and
+    the bucketed step; ``ps_push_pull`` stages into its pool, ``_Slot``).
     A ``jax.Array`` hands back a read-only host array — on the CPU backend
     a zero-copy view of the jax buffer, on the TPU the array's cached host
     copy (196 of 196 leaves of a GPT-2 tree; PERF.md, PR 24) — and
     writing through one would mutate the (immutable) source array, so
-    un-alias exactly when the runtime says the buffer isn't ours. That is a
-    copy of the whole tree every step: most of ``bps.ps.stage``."""
+    un-alias exactly when the runtime says the buffer isn't ours: a new
+    allocation and a copy of every such leaf, every call."""
     arr = np.ascontiguousarray(arr)
     if not arr.flags.writeable:
         arr = np.array(arr)
@@ -231,8 +295,12 @@ def ps_push_pull(tree, average: bool = True, prefix: str = "grad",
     zero-copy SArray, SURVEY.md §7 hard part #2): a per-leaf pipeline.
     Every leaf's D2H transfer is started up front and each leaf is enqueued
     the moment IT has landed, so the C core round begins with the first
-    leaf and not after the last; the staged host buffers are handed to the
-    C core zero-copy (pushed from and pulled back into in place); each
+    leaf and not after the last. What is copied where: the landed leaf (the
+    runtime's read-only host copy) is copied ONCE, into the staging buffer
+    its tensor id owns across calls (``_Slot``; allocated on the tensor's
+    first call, warm pages from then on); the C core pushes from that
+    buffer and pulls the sum back into it in place, and ``device_put``
+    uploads from it — no other host copy, no per-call allocation. Each
     leaf's H2D transfer is issued the moment its handle has settled, so
     only the last leaf's upload is left after the round. Tensor declares
     are cached for the tree's lifetime instead of re-registering every
@@ -267,18 +335,27 @@ def _ps_push_pull_impl(tree, average, prefix, async_mode):
         plan = _wire_plan(leaves, _codec_active(st))
         tids = _tids(client, prefix, leaves, plan)
         # (handle, staged buffer, leaf) per enqueued leaf, and how many of
-        # the handles have settled. The staged copy of a leaf (see
-        # _writable) is both push source and pull destination: it stays
-        # referenced here until its handle has settled and its device_put
-        # has been issued.
+        # the handles have settled. A leaf's staged buffer (its tid's
+        # _Slot, in the leaf's shape) is push source, pull destination and
+        # device_put source; the C core owns it until its handle settles.
         staged, settled, devs = [], 0, []
+        wire_nbytes = [l.size * np.dtype(w).itemsize
+                       for l, (w, _) in zip(leaves, plan)]
 
-        def put(arr, leaf):
-            # Downcast an upcast-staged leaf on the host first so the
-            # upload pays half-precision bytes too (the device-side astype
-            # is then a no-op).
-            devs.append(jax.device_put(
-                arr if arr.dtype == leaf.dtype else arr.astype(leaf.dtype)))
+        def put(i):
+            _, arr, leaf = staged[i]
+            if arr.dtype == leaf.dtype:
+                dev = jax.device_put(arr)
+                if _is_host_memory_of(dev, arr):
+                    # The result IS the slot's buffer: the buffer goes with
+                    # it and the tensor's next call allocates another.
+                    del _slots[tids[i]]
+            else:
+                # Downcast an upcast-staged leaf on the host first so the
+                # upload pays half-precision bytes too (the device-side
+                # astype is then a no-op).
+                dev = jax.device_put(arr.astype(leaf.dtype))
+            devs.append(dev)
 
         try:
             with jax.profiler.TraceAnnotation(SPAN_D2H):
@@ -297,13 +374,18 @@ def _ps_push_pull_impl(tree, average, prefix, async_mode):
                 for leaf in device:
                     leaf.copy_to_host_async()
                 np.asarray(leaves[0])
-            with jax.profiler.TraceAnnotation(SPAN_STAGE):
+            stage_stats.update(
+                reused_bytes=sum(n for tid, n in zip(tids, wire_nbytes)
+                                 if tid in _slots),
+                bytes=sum(wire_nbytes))
+            with jax.profiler.TraceAnnotation(SPAN_STAGE, **stage_stats):
                 for tid, leaf, (wire_dtype, _) in zip(tids, leaves, plan):
                     # blocks only until THIS leaf has landed
-                    arr = _writable(np.asarray(leaf))
-                    if arr.dtype != np.dtype(wire_dtype):
-                        # half-wire + codec: f32 DCN leg
-                        arr = arr.astype(wire_dtype)
+                    host = np.asarray(leaf)
+                    slot = _slots.get(tid)
+                    if slot is None:
+                        slot = _slots[tid] = _Slot(host.size, wire_dtype)
+                    arr = slot.fill(host)
                     h = client.push_pull(tid, arr, average=average,
                                          async_mode=async_mode)
                     staged.append((h, arr, leaf))
@@ -313,16 +395,19 @@ def _ps_push_pull_impl(tree, average, prefix, async_mode):
             # least, when the rest settled while it was being staged — and
             # only the last leaf's device_put waits for the whole round.
             with jax.profiler.TraceAnnotation(SPAN_WAIT):
-                for i, (h, arr, leaf) in enumerate(staged):
+                for i, (h, _, _) in enumerate(staged):
                     settled += 1  # wait settles h whether it returns or raises
                     client.wait(h)
                     if i < len(staged) - 1:  # the last put is bps.ps.h2d's
-                        put(arr, leaf)
+                        put(i)
             put_stats.update(put_early_bytes=total - nbytes[-1], bytes=total)
             with jax.profiler.TraceAnnotation(SPAN_H2D, **put_stats):
-                put(*staged[-1][1:])
+                put(len(staged) - 1)
                 out = [d.reshape(leaf.shape).astype(leaf.dtype)
                        for d, leaf in zip(devs, leaves)]
+            for tid, result in zip(tids, out):
+                if tid in _slots:
+                    _slots[tid].result = weakref.ref(result)
         except BaseException:
             # What _wait_all guarantees, from wherever the failure came:
             # no staging buffer is freed under the C core, and nothing more

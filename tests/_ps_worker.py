@@ -1326,7 +1326,13 @@ def jax_stream_main() -> int:
     as its handle settles) returns bit for bit the sum numpy computes from
     every worker's values: three rounds over one tree of device arrays —
     vectors, a scalar, a matrix and a last leaf of several partitions —
-    summed and averaged. BPS_STREAM_CASE: ``f32``; ``bf16_codec`` (bfloat16
+    summed and averaged, every round with values of its own, so nothing of
+    round n may survive in a staging slot into round n + 1. ``stage_stats``:
+    a prefix's first call stages into new buffers, every later one into the
+    slots of the call before — all of them, but for a slot whose upload the
+    CPU backend made of the buffer itself (an aligned one), which went with
+    that result; which those are is read here from the pointers the C core
+    was handed and each upload's own. BPS_STREAM_CASE: ``f32``; ``bf16_codec`` (bfloat16
     leaves under a configured codec — here a top-k that keeps every element,
     so the wire is exact — staged as float32 and put back as bfloat16);
     ``int_leaf`` (an int32 counter among the floats). Two workers, so the
@@ -1354,12 +1360,38 @@ def jax_stream_main() -> int:
         client = bps_jax._st().ps_client
         nw, rank = client.num_workers(), client.worker_rank()
         assert nw == 2
+        handed, aliased, real_push_pull = [], set(), client.push_pull
+        real_put = jax.device_put
+
+        def push_pull(tid, arr, **kwargs):  # what the C core is handed
+            handed.append((arr.ctypes.data, arr.nbytes))
+            return real_push_pull(tid, arr, **kwargs)
+
+        def device_put(x):  # and which uploads ARE the host buffer
+            dev = real_put(x)
+            if dev.unsafe_buffer_pointer() == x.ctypes.data:
+                aliased.add(x.ctypes.data)
+            return dev
+
+        client.push_pull, jax.device_put = push_pull, device_put
+        kept = {}  # prefix -> bytes its next call must find in the pool
         for step in range(3):
             mine = values(rank, step)
             for average in (False, True):
+                del handed[:]
+                aliased.clear()
                 out = ps_mod.ps_push_pull(
                     jax.tree_util.tree_map(jnp.asarray, mine),
                     average=average, prefix=f"st{int(average)}")
+                staged = sum(n for _, n in handed)
+                assert ps_mod.stage_stats == {
+                    "reused_bytes": kept.get(average, 0),
+                    "bytes": staged}, (step, average, ps_mod.stage_stats)
+                kept[average] = sum(n for ptr, n in handed
+                                    if ptr not in aliased)
+                assert step == 0 or case != "bf16_codec" or (
+                    ps_mod.stage_stats["reused_bytes"] >= staged - 4), (
+                        "a float32 slot of a bfloat16 leaf is never uploaded")
                 theirs = [values(r, step) for r in range(nw)]
                 for name, got in out.items():
                     a, b = (t[name] for t in theirs)

@@ -29,7 +29,7 @@ def test_a_ps_step_writes_the_eight_spans(tmp_path):
     the three step spans on the caller's line; push_pull and its five
     children together on another (the bridge thread), the children inside
     it, in order, without overlap; the three stats on push_pull, with
-    ``mono_ns`` on the C core's clock."""
+    ``mono_ns`` on the C core's clock, and ``stage_stats`` on stage."""
     (out,) = run_topology(
         1, 1, WORKER, extra={
             "BYTEPS_PS_MODE": "ps", "BYTEPS_FORCE_DISTRIBUTED": "1",
@@ -66,6 +66,12 @@ def test_a_ps_step_writes_the_eight_spans(tmp_path):
         assert whole["stats"]["bytes"] == 4 * (64 * 8 + 8)
         lo, hi = found["mono_ns"]
         assert lo < whole["stats"]["mono_ns"] < hi
+        # bps.ps.stage carries stage_stats: a step before the capture left
+        # its slots, all of which a device with memory of its own reuses
+        # (the CPU backend may have made an upload of an aligned one)
+        staged = children[1]["stats"]
+        assert staged["bytes"] == whole["stats"]["bytes"]
+        assert 0 <= staged["reused_bytes"] <= staged["bytes"]
     first, second = by_name[ps.SPAN_PUSH_PULL]
     # one clock relation for the whole capture: (mono_ns - ts) is a constant
     drift = ((second["stats"]["mono_ns"] - second["start_ns"])

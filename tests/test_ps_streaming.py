@@ -7,7 +7,9 @@ that record when their host array is taken stand in for device arrays. The
 loopback fleet checks the numbers (``tests/_ps_worker.py``, ``jax_stream``).
 """
 
+import gc
 import types
+import weakref
 
 import jax
 import numpy as np
@@ -72,6 +74,57 @@ class Client:
         self.buffers[h] *= 2
 
 
+class Aliased(np.ndarray):
+    """What ``jax.device_put`` returns where the device's memory is the
+    host's and the buffer is aligned (the CPU backend): the host buffer
+    itself, under an array's name."""
+
+    def devices(self):
+        return [types.SimpleNamespace(platform="cpu")]
+
+    def unsafe_buffer_pointer(self):
+        return self.ctypes.data
+
+
+class Uploaded:
+    """What ``jax.device_put`` returns on a device with memory of its own: a
+    copy of the host buffer as it was at the call, in the order of the puts
+    in ``uploads``. ``ready`` False stands for an upload still reading the
+    host buffer: ``block_until_ready`` is then logged."""
+
+    def __init__(self, log, uploads, host):
+        self._log, self.index, self.ready = log, len(uploads), True
+        self.value, self.source = np.array(host), host
+        log.append(("put", host.nbytes))
+        uploads.append(self)
+
+    def devices(self):
+        return [types.SimpleNamespace(platform="tpu")]
+
+    def reshape(self, shape):
+        assert shape == self.value.shape
+        return self
+
+    def astype(self, dtype):
+        assert dtype == self.value.dtype
+        return self
+
+    def is_deleted(self):
+        return False
+
+    def block_until_ready(self):
+        if not self.ready:
+            self._log.append(("block", self.index))
+            self.ready = True
+        return self
+
+
+def retake(tree, scale):
+    """The same tree signature with other values (leaf i: scale × (i + 1))."""
+    return [Leaf(l._log, l._index, np.full(l.shape, scale * (l._index + 1),
+                                           l.dtype)) for l in tree]
+
+
 @pytest.fixture
 def bridge(monkeypatch):
     """``bridge(sizes, **client)`` → (log, client, tree): the program state
@@ -83,17 +136,20 @@ def bridge(monkeypatch):
     def device_put(x):  # one array or a list of them: the order is the point
         log.extend(("put", a.nbytes) for a in (x if isinstance(x, list)
                                                else [x]))
-        return x
+        return x if isinstance(x, list) else x.view(Aliased)
 
     monkeypatch.setattr(jax, "device_put", device_put)
     ps.reset_declare_cache()
 
     def make(sizes, *, compressor="", dtype=np.float32, lost_leaf=None,
-             ready=True, **client_kwargs):
+             ready=True, uploads=None, **client_kwargs):
         client = Client(log, **client_kwargs)
         monkeypatch.setattr(ps.bps, "_st", lambda: types.SimpleNamespace(
             ps_client=client, config=types.SimpleNamespace(
                 enable_async=False, compressor=compressor)))
+        if uploads is not None:  # a device that copies, as the TPU does
+            monkeypatch.setattr(jax, "device_put", lambda x: Uploaded(
+                log, uploads, x))
         tree = [Leaf(log, i, np.full((n,), i + 1, dtype), fail=i == lost_leaf,
                      ready=ready)
                 for i, n in enumerate(sizes)]
@@ -221,3 +277,221 @@ def test_host_scalars_take_the_same_path(bridge):
     assert {k: float(v) for k, v in out.items()} == {"a": 3.0, "b": 4.0,
                                                      "c": 6.0}
     assert all(np.shape(v) == () for v in out.values())
+
+
+# --- the staging pool: one buffer per declared tensor, reused across calls ----
+
+@pytest.mark.parametrize("sizes", SIZES.values(), ids=SIZES.keys())
+def test_second_call_stages_into_the_first_calls_buffers(bridge, sizes):
+    """(pool a) Two calls on one tree signature: every buffer the client
+    sees in call 2 is call 1's memory holding call 2's values, call 1's
+    results keep theirs, and ``stage_stats`` reads 0 of all, then all."""
+    uploads = []
+    _, client, tree = bridge(sizes, uploads=uploads)
+    first = ps.ps_push_pull(tree, average=False)
+    assert ps.stage_stats == {"reused_bytes": 0, "bytes": 4 * sum(sizes)}
+    second = ps.ps_push_pull(retake(tree, 10), average=False)
+    assert ps.stage_stats == {"reused_bytes": 4 * sum(sizes),
+                              "bytes": 4 * sum(sizes)}
+    n = len(sizes)
+    assert len(client.buffers) == 2 * n and len(ps._slots) == n
+    for i in range(n):
+        a, b = client.buffers[i], client.buffers[n + i]
+        assert a.ctypes.data == b.ctypes.data and np.shares_memory(a, b)
+        np.testing.assert_array_equal(b, np.full((sizes[i],), 20 * (i + 1),
+                                                 np.float32))
+        np.testing.assert_array_equal(first[i].value, 2 * (i + 1))
+        np.testing.assert_array_equal(second[i].value, 20 * (i + 1))
+
+
+@pytest.mark.parametrize("sizes", SIZES.values(), ids=SIZES.keys())
+def test_another_tree_under_the_prefix_gets_its_own_slots(bridge, sizes):
+    """(pool b) A tree of other leaf sizes under the same prefix declares
+    its own tensors and stages into its own buffers; the first tree's are
+    not touched and are found again by its next call."""
+    uploads = []
+    _, client, tree = bridge(sizes, uploads=uploads)
+    ps.ps_push_pull(tree, average=False)
+    kept = [b.copy() for b in client.buffers]
+    other = [Leaf([], i, np.full((n + 1,), 7.0, np.float32))
+             for i, n in enumerate(sizes)]
+    ps.ps_push_pull(other, average=False)
+    assert ps.stage_stats == {"reused_bytes": 0,
+                              "bytes": 4 * (sum(sizes) + len(sizes))}
+    n = len(sizes)
+    assert len(ps._slots) == 2 * n
+    for i in range(n):
+        assert not np.shares_memory(client.buffers[i], client.buffers[n + i])
+        np.testing.assert_array_equal(client.buffers[i], kept[i])
+    ps.ps_push_pull(tree, average=False)
+    assert ps.stage_stats["reused_bytes"] == 4 * sum(sizes)
+    assert all(np.shares_memory(client.buffers[i], client.buffers[2 * n + i])
+               for i in range(n))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float16"])
+@pytest.mark.parametrize("sizes", SIZES.values(), ids=SIZES.keys())
+def test_half_wire_with_codec_fills_a_float32_slot(bridge, sizes, dtype):
+    """(pool c) Half-precision leaves under a codec: the slot is float32
+    and is filled straight from the half-precision leaf — the only arrays
+    of the leaf's size that a call allocates are the downcast copies it
+    puts, as before — the put is half precision, the slot is reused."""
+    dtype = jax.numpy.dtype(dtype)
+    uploads = []
+    _, client, tree = bridge(sizes, dtype=dtype, compressor="onebit",
+                             uploads=uploads)
+    for call, scale in enumerate((1, 3)):
+        out = ps.ps_push_pull(retake(tree, scale), average=False)
+        assert ps.stage_stats == {
+            "reused_bytes": 4 * sum(sizes) * call, "bytes": 4 * sum(sizes)}
+        for i, leaf in enumerate(out):
+            slot = client.buffers[call * len(sizes) + i]
+            assert slot.dtype == np.float32
+            assert slot.ctypes.data == client.buffers[i].ctypes.data
+            assert leaf.value.dtype == dtype and leaf.source.dtype == dtype
+            assert not np.shares_memory(leaf.source, slot)
+            np.testing.assert_array_equal(
+                leaf.value, np.full((sizes[i],), 2 * scale * (i + 1), dtype))
+
+
+@pytest.mark.parametrize("sizes", SIZES.values(), ids=SIZES.keys())
+def test_a_live_unfinished_upload_is_waited_for_a_dead_one_is_not(bridge,
+                                                                  sizes):
+    """(pool d) Before a slot is written again the previous upload from it
+    is waited for — if the caller still holds its result and it is not
+    ready. A result that is ready costs nothing, one the caller dropped
+    costs no call, and the pool keeps none alive."""
+    uploads = []
+    log, client, tree = bridge(sizes, uploads=uploads)
+    first = ps.ps_push_pull(tree, average=False)
+    last = len(sizes) - 1
+    first[0].ready = first[last].ready = False
+    del log[:]
+    ps.ps_push_pull(retake(tree, 2), average=False)
+    blocks = [e for e in log if e[0] == "block"]
+    assert blocks == [("block", 0), ("block", last)]
+    # each wait comes before that slot is handed to the client again
+    assert log.index(("block", 0)) < log.index(("enqueue", len(sizes)))
+    assert log.index(("block", last)) < log.index(
+        ("enqueue", len(sizes) + last))
+    # the caller drops call 2's results while they are unfinished: no wait
+    refs = [weakref.ref(u) for u in uploads]
+    for u in uploads:
+        u.ready = False
+    del first, u
+    uploads.clear()
+    gc.collect()
+    assert all(r() is None for r in refs), "the pool holds a put result"
+    del log[:]
+    ps.ps_push_pull(retake(tree, 3), average=False)
+    assert not [e for e in log if e[0] == "block"]
+    assert ps.stage_stats["reused_bytes"] == 4 * sum(sizes)
+
+
+@pytest.mark.parametrize("fault", ["refused-enqueue", "failed-first-wait",
+                                   "failed-last-wait", "lost-leaf"])
+@pytest.mark.parametrize("sizes", SIZES.values(), ids=SIZES.keys())
+def test_the_pool_is_usable_after_an_error(bridge, sizes, fault):
+    """(pool e) A call that fails mid-stage or mid-wait settles what it had
+    enqueued, as before, and the next call on the same tree succeeds in
+    the slots that call left."""
+    last = len(sizes) - 1
+    kwargs = {"refused-enqueue": {"refuse_enqueue": 1},
+              "failed-first-wait": {"fail_wait": [0]},
+              "failed-last-wait": {"fail_wait": [last]},
+              "lost-leaf": {"lost_leaf": 1}}[fault]
+    uploads = []
+    log, client, tree = bridge(sizes, uploads=uploads, **kwargs)
+    with pytest.raises(RuntimeError):
+        ps.ps_push_pull(tree, average=False)
+    enqueued = [h for kind, h in log if kind == "enqueue"]
+    assert [h for kind, h in log if kind == "wait"] == enqueued
+    # a refused leaf was staged before it was refused; a lost one never was
+    slots = len(ps._slots)
+    assert slots == {"refused-enqueue": 2, "lost-leaf": 1}.get(fault,
+                                                               len(sizes))
+    failed = list(client.buffers)
+    client._refuse, client._fail_wait = None, set()
+    out = ps.ps_push_pull(retake(tree, 5), average=False)
+    assert ps.stage_stats["reused_bytes"] == 4 * sum(sizes[:slots])
+    for i, leaf in enumerate(out):
+        np.testing.assert_array_equal(leaf.value, np.full(
+            (sizes[i],), 10 * (i + 1), np.float32))
+    for a, b in zip(failed, client.buffers[len(failed):]):
+        assert a.ctypes.data == b.ctypes.data
+
+
+@pytest.mark.parametrize("sizes", SIZES.values(), ids=SIZES.keys())
+def test_reset_declare_cache_empties_the_pool(bridge, sizes):
+    """(pool f) ``reset_declare_cache()`` — ``bps.init()`` and
+    ``bps.shutdown()`` — drops every slot with the tensor ids: a restarted
+    fleet's first call stages into new buffers."""
+    uploads = []
+    _, client, tree = bridge(sizes, uploads=uploads)
+    ps.ps_push_pull(tree, average=False)
+    assert len(ps._slots) == len(sizes)
+    ps.reset_declare_cache()
+    assert not ps._slots and not ps._tid_cache
+    ps.ps_push_pull(retake(tree, 2), average=False)
+    assert ps.stage_stats == {"reused_bytes": 0, "bytes": 4 * sum(sizes)}
+
+
+@pytest.mark.parametrize("sizes", SIZES.values(), ids=SIZES.keys())
+def test_a_result_that_is_the_buffer_takes_the_buffer_with_it(bridge, sizes):
+    """Hazard 2 with a ``device_put`` that hands back the host buffer itself
+    (the fixture's default; the CPU backend for an aligned buffer): the
+    slot is given away, call 2 stages into new memory, and call 1's
+    results are not rewritten."""
+    _, client, tree = bridge(sizes)
+    first = ps.ps_push_pull(tree, average=False)
+    assert not ps._slots
+    ps.ps_push_pull(retake(tree, 10), average=False)
+    assert ps.stage_stats == {"reused_bytes": 0, "bytes": 4 * sum(sizes)}
+    n = len(sizes)
+    for i in range(n):
+        assert not np.shares_memory(client.buffers[i], client.buffers[n + i])
+        np.testing.assert_array_equal(first[i], 2 * (i + 1))
+
+
+@pytest.mark.parametrize("sizes", [[3, 5], [16, 1000, 1 << 18]],
+                         ids=["two", "to-1MB"])
+def test_real_cpu_device_put_never_rewrites_an_earlier_result(monkeypatch,
+                                                              sizes):
+    """Hazard 2 through the real ``jax.device_put`` of the CPU backend,
+    device arrays in and out: the tree call 1 returned is bit for bit what
+    it was after call 2 pushed other values on the same signature, and
+    after a third. What this jax (0.9.0) showed: the CPU backend ALIASES a
+    host buffer that is 64-byte aligned (the result's
+    ``unsafe_buffer_pointer()`` is the numpy pointer and follows a later
+    write) and copies any other; ``np.empty`` is aligned so about every
+    other time, at 4 bytes as at 4 MB, and ``may_alias=False`` changes
+    nothing for a numpy source. So both branches run here, by the
+    allocator's choice: an aliased slot is given away, a copied one is
+    reused."""
+    monkeypatch.delenv("BYTEPS_COMPRESSOR", raising=False)
+    ps.reset_declare_cache()
+    client = Client([])
+    monkeypatch.setattr(ps.bps, "_st", lambda: types.SimpleNamespace(
+        ps_client=client, config=types.SimpleNamespace(
+            enable_async=False, compressor="")))
+    cpu = jax.devices("cpu")[0]
+    try:
+        results, wants, reused = [], [], 0
+        for call in range(6):
+            tree = [jax.device_put(np.full((n,), 10.0 * call + i, np.float32),
+                                   cpu) for i, n in enumerate(sizes)]
+            results.append(ps.ps_push_pull(tree, average=False))
+            wants.append([np.full((n,), 2 * (10.0 * call + i), np.float32)
+                          for i, n in enumerate(sizes)])
+            reused += ps.stage_stats["reused_bytes"]
+            for got, want in zip(results, wants):  # every call so far
+                for g, w in zip(got, want):
+                    assert isinstance(g, jax.Array)
+                    np.testing.assert_array_equal(np.asarray(g), w)
+        # a slot is in the pool exactly when its newest result is a copy
+        for tid, got in zip(range(len(sizes)), results[-1]):
+            aliased = any(got.unsafe_buffer_pointer() == b.ctypes.data
+                          for b in client.buffers)
+            assert (tid in ps._slots) != aliased
+    finally:
+        ps.reset_declare_cache()

@@ -20,7 +20,8 @@ Five parts, one JSON line each, over the 196 gradient leaf shapes of
                   by issue order and by whether the copies are issued before
                   or after that program's end.
 ``settle_trace``  ``ps_push_pull`` itself with taps: when leaves are enqueued,
-                  settled and put.
+                  settled and put, and how much of the tree was staged into
+                  buffers of an earlier call (``stage_stats``).
 ``back``          the host time of ``jax.device_put`` leaf by leaf against
                   one call on the list, and each until the bytes have landed.
 
@@ -274,7 +275,8 @@ def settle_trace(shapes, repeats=4):
                 "put_cost_ms": _ms(log["put_cost"]),
                 "return_ms": _ms(t_return - t0),
                 "landed_ms": _ms(t_landed - t0),
-                "put_stats": dict(ps.put_stats)})
+                "put_stats": dict(ps.put_stats),
+                "stage_stats": dict(ps.stage_stats)})
             del tree, out
     finally:
         st.ps_client, jax.device_put = real, real_put
