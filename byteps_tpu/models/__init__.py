@@ -16,6 +16,7 @@ from byteps_tpu.models.llama import (  # noqa: F401
     LlamaModel,
     LlamaTiny,
 )
+from byteps_tpu.models.keye import Keye30BA3B, KeyeModel, KeyeTiny, keye_loss  # noqa: F401,E501
 from byteps_tpu.models.olmoe import Olmoe1B7B, OlmoeModel, OlmoeTiny, olmoe_loss  # noqa: F401,E501
 from byteps_tpu.models.transformer import (  # noqa: F401
     BertBase,

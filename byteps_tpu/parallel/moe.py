@@ -15,7 +15,9 @@ computation otherwise.
 to its ``top_k`` experts whatever the load (OLMoE, Mixtral): no capacity and
 no ``[T, E, C]`` one-hot (0.67 GB each way at 4096 tokens x 64 experts x 8),
 but the assignments sorted by expert and three grouped matmuls over the
-sorted rows. One device holds all experts; it has no ``ep_axis`` yet.
+sorted rows. A device holds all experts or a contiguous share of them
+(``first_expert``): it routes over all, computes its own experts' part of
+the result and leaves the rest out; it has no ``ep_axis`` (no exchange) yet.
 """
 
 from __future__ import annotations
@@ -225,38 +227,62 @@ def dropless_moe_ffn(
     *,
     top_k: int,
     dtype=jnp.bfloat16,
+    first_expert: int = 0,
+    norm_topk: bool = False,
 ):
     """Dropless top-k SwiGLU expert layer: every token reaches its
     ``top_k`` experts.
 
-    x: [T, D]; router_w: [D, E]; w_gate, w_up: [E, D, M]; w_down:
-    [E, M, D]; no biases. The router runs in float32 at the highest matmul
-    precision (which experts a token reaches must not turn on bf16
-    rounding): softmax over all E, ``lax.top_k``, and the raw probabilities
-    as combine weights — not renormalised over the chosen k. The
-    assignments are sorted by expert (stable), the tokens gathered into
-    that order, ``down(silu(gate(x)) * up(x))`` computed as three grouped
-    matmuls with ``dtype`` operands and float32 accumulation, and the rows
-    un-permuted and summed with their weights in float32.
+    x: [T, D]; router_w: [D, E]; w_gate, w_up: [H, D, M]; w_down:
+    [H, M, D], the weights of the H <= E experts ``first_expert ..
+    first_expert + H - 1`` held here; no biases. The router runs in float32
+    at the highest matmul precision (which experts a token reaches must not
+    turn on bf16 rounding): softmax over all E, ``lax.top_k``, and as
+    combine weights the raw probabilities, or with ``norm_topk`` those
+    renormalised over the chosen k. The assignments are sorted by expert
+    (stable), the tokens gathered into that order,
+    ``down(silu(gate(x)) * up(x))`` computed as three grouped matmuls with
+    ``dtype`` operands and float32 accumulation, and the rows un-permuted
+    and summed with their weights in float32.
+
+    A share (H < E) routes over all E all the same and computes the
+    assignments that fall to its own experts, all of them whatever the
+    routing: the sorted rows keep their worst-case length T k, the held
+    experts' rows first, and the grouped matmuls are told the held groups
+    only. Rows beyond them (assignments to experts held elsewhere) are
+    zero on the way in and out of every grouped matmul and carry weight 0,
+    so ``y`` is this share's part of the layer's output: the parts of
+    shares that cover 0..E-1 add up to the whole layer's.
 
     Returns ``(y [T, D] in x's dtype, load_balance, z_loss, counts)``:
     ``load_balance`` = E / (T k) * sum_e counts_e * mean_t p[t, e] (1 when
     routing is uniform; gradient through p only), ``z_loss`` =
     mean_t logsumexp(logits_t)^2, ``counts`` [E] int32 the assignments per
-    expert (they sum to T k).
+    expert over all E (they sum to T k).
     """
     t, d = x.shape
-    e = router_w.shape[1]
+    e, held = router_w.shape[1], w_gate.shape[0]
     if not 1 <= top_k <= e:
         raise ValueError(f"top_k must be in 1..{e}, got {top_k}")
+    if not 0 <= first_expert <= e - held:
+        raise ValueError(f"experts {first_expert}..{first_expert + held - 1} "
+                         f"are not among the router's {e}")
     with jax.named_scope(ROUTE_SCOPE):
         logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)       # [T, E]
         lse = jax.nn.logsumexp(logits, axis=-1)
         probs = jnp.exp(logits - lse[:, None])
         top_w, top_e = lax.top_k(probs, top_k)                  # [T, k]
-        flat_e = top_e.reshape(-1)                              # [T k]
-        order = jnp.argsort(flat_e, stable=True)    # sorted row -> t*k + j
+        if norm_topk:
+            top_w = top_w / top_w.sum(axis=-1, keepdims=True)
+        flat_e = sort_key = top_e.reshape(-1)                   # [T k]
+        if held < e:
+            # held experts 0..H-1 in their order, every other one as H: last
+            local = flat_e - first_expert
+            here = (local >= 0) & (local < held)
+            top_w = jnp.where(here.reshape(t, top_k), top_w, 0.0)
+            sort_key = jnp.where(here, local, held)
+        order = jnp.argsort(sort_key, stable=True)  # sorted row -> t*k + j
         counts = (flat_e[:, None] == jnp.arange(e)[None, :]).sum(
             axis=0, dtype=jnp.int32)
         back = jnp.argsort(order)                   # t*k + j -> sorted row
@@ -265,6 +291,12 @@ def dropless_moe_ffn(
         load_balance = (counts.astype(jnp.float32)
                         * probs.mean(axis=0)).sum() * (e / (t * top_k))
         z_loss = jnp.mean(lse * lse)
+        groups, rows = counts, None
+        if held < e:
+            groups = lax.slice_in_dim(counts, first_expert,
+                                      first_expert + held)
+            rows = (jnp.arange(t * top_k) < groups.sum())[:, None]
+            xs = jnp.where(rows, xs, 0)
     with jax.named_scope(EXPERTS_SCOPE):
         # lax.ragged_dot: row i of the sorted rows times the matrix of its
         # group, float32 accumulation, result in `dtype`. The TPU compiler
@@ -272,10 +304,16 @@ def dropless_moe_ffn(
         # trace), forward, dgrad and the per-group wgrad alike; elsewhere
         # it is a masked dense product. Chosen over Pallas megablox by
         # measurement and for needing no import (PERF.md section 4).
-        gate = lax.ragged_dot(xs, w_gate.astype(dtype), counts)
-        up = lax.ragged_dot(xs, w_up.astype(dtype), counts)
-        ys = lax.ragged_dot(jax.nn.silu(gate) * up, w_down.astype(dtype),
-                            counts)                             # [T k, D]
+        def grouped(lhs, w):
+            out = lax.ragged_dot(lhs, w.astype(dtype), groups)
+            # the TPU's kernel leaves rows beyond its groups undefined (x's
+            # gradient came out 50 x too large unmasked; PERF.md, PR 33):
+            # a share takes zeros there, forward and backward
+            return out if rows is None else jnp.where(rows, out, 0)
+
+        gate = grouped(xs, w_gate)
+        up = grouped(xs, w_up)
+        ys = grouped(jax.nn.silu(gate) * up, w_down)            # [T k, D]
     with jax.named_scope(ROUTE_SCOPE):
         y = jnp.einsum("tkd,tk->td",
                        _permute(ys, back, order).reshape(t, top_k, d),
@@ -283,12 +321,15 @@ def dropless_moe_ffn(
     return y.astype(x.dtype), load_balance, z_loss, counts
 
 
-def publish_moe_stats(moe_stats) -> dict:
+def publish_moe_stats(moe_stats, held=None) -> dict:
     """Per-expert assignment counts (the ``"moe_stats"`` collection of a
     model applied with it mutable: every leaf an [E] count) to
     ``monitor/metrics.py``: gauge ``bps_moe_max_expert_load`` (the busiest
     expert's assignments over the mean, worst layer), counter
-    ``bps_moe_assignments_total``. Returns what it published."""
+    ``bps_moe_assignments_total`` and, for a share ``held`` = (first expert,
+    experts held), gauge ``bps_moe_held_load``: the assignments that reached
+    the held experts over their even part T k H / E, all layers together.
+    Returns what it published."""
     import numpy as np
 
     from byteps_tpu.monitor import metrics
@@ -299,6 +340,12 @@ def publish_moe_stats(moe_stats) -> dict:
     out = {"bps_moe_max_expert_load":
            max(float(c.max() / c.mean()) for c in leaves),
            "bps_moe_assignments_total": float(sum(c.sum() for c in leaves))}
+    if held is not None:
+        first, n = held
+        out["bps_moe_held_load"] = float(
+            sum(c[first:first + n].sum() for c in leaves)
+            / sum(c.sum() * n / c.size for c in leaves))
+        metrics.set_gauge("bps_moe_held_load", out["bps_moe_held_load"])
     metrics.set_gauge("bps_moe_max_expert_load",
                       out["bps_moe_max_expert_load"])
     metrics.inc_counter("bps_moe_assignments_total",

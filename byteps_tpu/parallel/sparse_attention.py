@@ -1,0 +1,172 @@
+"""Learned sparse attention: a lightning indexer scores every earlier key
+for every query, the ``topk`` best are selected exactly, attention runs over
+the selected keys alone, and the indexer learns from a KL term of its own
+(DeepSeek-V3.2's sparse attention; grouped-query attention underneath).
+
+``sparse_attention`` is the masked form: the selection is a boolean
+[queries, keys] mask and the softmax runs over all keys of the block with
+the unselected ones masked out — every product is computed, those of
+unselected keys included, which is ``T / mean |S_t|`` times what the
+mathematics needs (PERF.md: the first ``perf_opt`` on the cell that runs
+it). It works in query blocks of ``block`` rows, each recomputed in the
+backward pass (``jax.checkpoint``), so the [heads, block, keys] float32
+scores of one block are all that is alive: 0.54 GB at 32 heads x 512 x
+8192. The blocks tile the computation and do not change it. Chosen by
+measurement over gathering the selected keys, and the spans over one loop
+and over unrolled blocks (PERF.md section 4). No kernel library is
+imported here (``tests/test_import_footprint.py``).
+
+Gradient paths. The indexer's inputs are the caller's to detach; here the
+attention probabilities that the indexer is trained towards are detached,
+and the selection has no gradient. So the attention output carries no
+gradient to ``index_*`` and ``index_loss`` none to ``q``, ``k``, ``v``.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+INDEXER_SCOPE = "bps.dsa.indexer"  # index scores, the KL term, gradients
+SELECT_SCOPE = "bps.dsa.select"    # top-k and the mask
+ATTEND_SCOPE = "bps.dsa.attend"    # scores, softmax, values, gradients
+
+
+def _select(score, causal, topk: int):
+    """[queries, keys] bool: per query its ``min(causal keys, topk)``
+    causal keys of highest ``score``, ties to the earlier key — the set
+    ``lax.top_k`` returns, as a mask: everything above the ``topk``-th
+    value, and of the keys equal to it the earliest that still fit."""
+    if score.shape[1] <= topk:
+        return causal
+    masked = jnp.where(causal, score, -jnp.inf)
+    kth = lax.top_k(masked, topk)[0][:, -1:]
+    above, tied = masked > kth, masked == kth
+    room = topk - above.sum(axis=-1, keepdims=True)
+    return (above | (tied & (jnp.cumsum(tied, axis=-1) <= room))) & causal
+
+
+def _block(q, index_q, index_w, first, k, v, index_k, *, topk, scale):
+    """One block of queries at positions ``first..`` of one sequence over
+    the keys 0..n-1. q [B, h, dh]; k, v [n, hk, dh]; index_q [B, hi, di];
+    index_w [B, hi]; index_k [n, di]. Returns (out [B, h, dh] float32, the
+    block's summed KL, its number of selected keys)."""
+    rows, heads, head_dim = q.shape
+    n, kv_heads = k.shape[:2]
+    hi = lax.Precision.HIGHEST
+    causal = (jnp.arange(n)[None, :]
+              <= (first + jnp.arange(rows))[:, None])
+    with jax.named_scope(INDEXER_SCOPE):
+        dots = jnp.einsum("qjd,sd->qjs", index_q, index_k, precision=hi)
+        score = jnp.einsum("qjs,qj->qs", jax.nn.relu(dots), index_w,
+                           precision=hi)                        # [B, n]
+    with jax.named_scope(SELECT_SCOPE):
+        keep = _select(lax.stop_gradient(score), causal, topk)
+    with jax.named_scope(ATTEND_SCOPE):
+        grouped = q.reshape(rows, kv_heads, heads // kv_heads, head_dim)
+        logits = jnp.einsum("qcgd,scd->cgqs", grouped, k,
+                            preferred_element_type=jnp.float32) * scale
+        probs = jax.nn.softmax(
+            jnp.where(keep, logits, jnp.finfo(jnp.float32).min), axis=-1)
+        out = jnp.einsum("cgqs,scd->qcgd", probs.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
+    with jax.named_scope(INDEXER_SCOPE):
+        target = lax.stop_gradient(probs.sum(axis=(0, 1)) / heads)
+        log_index = jax.nn.log_softmax(
+            jnp.where(keep, score, jnp.finfo(jnp.float32).min), axis=-1)
+        seen = keep & (target > 0)
+        kl = jnp.where(seen, target * (jnp.log(jnp.where(seen, target, 1.0))
+                                       - log_index), 0.0).sum()
+    return (out.reshape(rows, heads, head_dim), kl,
+            keep.sum(dtype=jnp.int32))
+
+
+def _one_sequence(q, k, v, index_q, index_k, index_w, *, topk, block, scale):
+    """The blocks of one sequence, in spans of ``topk`` keys: the queries
+    of a span see the keys up to the span's end and no later one (62.5% of
+    the [s, s] pairs at s = 4 topk; the first span selects nothing), and
+    its blocks run one after the other under ``lax.map``."""
+    s = q.shape[0]
+    span = block * max(1, topk // block)
+    run = jax.checkpoint(partial(_block, topk=topk, scale=scale))
+    outs, kl, selected = [], 0.0, 0
+    for lo in range(0, s, span):
+        hi = min(lo + span, s)
+
+        def blocks(a):
+            return a[lo:hi].reshape((hi - lo) // block, block, *a.shape[1:])
+
+        out, kl_b, selected_b = lax.map(
+            lambda xs: run(*xs, k[:hi], v[:hi], index_k[:hi]),
+            (blocks(q), blocks(index_q), blocks(index_w),
+             jnp.arange(lo, hi, block)))
+        outs.append(out.reshape(hi - lo, *out.shape[2:]))
+        kl, selected = kl + kl_b.sum(), selected + selected_b.sum()
+    return jnp.concatenate(outs), kl / s, selected
+
+
+def sparse_attention(q, k, v, index_q, index_k, index_w, *, topk: int,
+                     block: int = 512, scale=None):
+    """Causal attention of every query over its ``topk`` highest-scoring
+    earlier keys (itself included; all of them while there are no more
+    than ``topk``).
+
+    q [b, s, h, dh]; k, v [b, s, hk, dh] with hk dividing h, query head
+    c * (h / hk) + i reading key-value head c; index_q [b, s, hi, di],
+    index_k [b, s, di] (one key head) and index_w [b, s, hi] in float32.
+    The index score of key s' for query t is sum_j index_w[t, j] *
+    relu(index_q[t, j] . index_k[s']), in float32 at the highest matmul
+    precision: which keys a query reaches must not turn on bf16 rounding.
+    Attention logits and the softmax are float32; the probabilities meet
+    ``v`` in ``v``'s dtype with float32 accumulation.
+
+    Returns ``(out [b, s, h, dh] in q's dtype, index_loss, selected)``:
+    ``index_loss`` = mean over queries of KL(p_t || softmax of the index
+    scores over the selected keys), p_t the attention probabilities summed
+    over heads and divided by h, detached; ``selected`` [b] int32 the
+    number of (query, key) pairs attended, sum_t min(t + 1, topk) when the
+    selection is what it says.
+    """
+    s, head_dim = q.shape[1], q.shape[-1]
+    block = min(block, s)
+    if s % block or q.shape[2] % k.shape[2]:
+        raise ValueError(f"sequence {s} must be a multiple of the block "
+                         f"{block}, heads {q.shape[2]} of the key-value "
+                         f"heads {k.shape[2]}")
+    one = partial(_one_sequence, topk=topk, block=block,
+                  scale=head_dim ** -0.5 if scale is None else scale)
+    out, index_loss, selected = jax.vmap(one)(q, k, v, index_q, index_k,
+                                              index_w)
+    return out.astype(q.dtype), index_loss.mean(), selected
+
+
+def publish_dsa_stats(dsa_stats) -> dict:
+    """The ``"dsa_stats"`` collection of a model applied with it mutable
+    (per layer ``selected`` and ``causal``, the (query, key) pairs attended
+    and those a dense causal attention would attend) to
+    ``monitor/metrics.py``: gauge ``bps_dsa_kept_keys_ratio`` (selected over
+    causal, all layers together), counter ``bps_dsa_selected_keys_total``.
+    Returns what it published."""
+    import numpy as np
+
+    from byteps_tpu.monitor import metrics
+
+    selected = causal = 0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(dsa_stats)[0]:
+        total = int(np.asarray(leaf, np.int64).sum())
+        if "selected" in jax.tree_util.keystr(path):
+            selected += total
+        else:
+            causal += total
+    if not causal:
+        return {}
+    out = {"bps_dsa_kept_keys_ratio": selected / causal,
+           "bps_dsa_selected_keys_total": float(selected)}
+    metrics.set_gauge("bps_dsa_kept_keys_ratio",
+                      out["bps_dsa_kept_keys_ratio"])
+    metrics.inc_counter("bps_dsa_selected_keys_total",
+                        out["bps_dsa_selected_keys_total"])
+    return out

@@ -6,8 +6,9 @@ the benchmark pays for it before its first step (``setup_s``).
 library at the top of either file is loaded by every user. The rule (PERF.md
 section 6, PR 28): a kernel library (Pallas, megablox) is imported inside
 the function that calls it, as ``models/transformer.py::_attention_fn`` does
-for the flash kernel; the expert layer's two modules import at module level
-only what was loaded before they existed.
+for the flash kernel; the expert layer's and the sparse attention's four
+modules import at module level only what was loaded before they existed
+(and each other).
 """
 
 import ast
@@ -26,11 +27,12 @@ import byteps_tpu.jax, byteps_tpu.models
 imported = set(sys.modules)
 
 import jax, jax.numpy as jnp, numpy as np
-from byteps_tpu.models import OlmoeTiny, olmoe_loss
-model = OlmoeTiny()
-tokens = np.zeros((1, 16), np.int32)
-params = model.init(jax.random.PRNGKey(0), tokens)
-jax.grad(lambda p: olmoe_loss(model.apply(p, tokens), tokens))(params)
+from byteps_tpu.models import KeyeTiny, OlmoeTiny, keye_loss, olmoe_loss
+for tiny, loss, seq in ((OlmoeTiny, olmoe_loss, 16), (KeyeTiny, keye_loss, 32)):
+    model = tiny()
+    tokens = np.zeros((1, seq), np.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens)
+    jax.grad(lambda p: loss(model.apply(p, tokens), tokens))(params)
 print(json.dumps({"imported": sorted(imported),
                   "by_the_model": sorted(set(sys.modules) - imported)}))
 """
@@ -54,15 +56,19 @@ def _kernel_modules(names):
 
 
 def test_importing_the_library_loads_no_kernel_library(loaded):
-    assert "byteps_tpu.models.olmoe" in loaded["imported"]
-    assert "byteps_tpu.parallel.moe" in loaded["imported"]
+    for module in ("byteps_tpu.models.olmoe", "byteps_tpu.parallel.moe",
+                   "byteps_tpu.models.keye",
+                   "byteps_tpu.parallel.sparse_attention"):
+        assert module in loaded["imported"]
     assert _kernel_modules(loaded["imported"]) == []
 
 
 def test_the_expert_model_loads_only_what_its_grouped_matmul_needs(loaded):
-    """``lax.ragged_dot`` needs nothing beyond jax itself: building the
-    tiny OlmoeModel, applying it and taking its gradient loads no kernel
-    library and nothing of byteps_tpu that the import had not loaded."""
+    """``lax.ragged_dot`` and ``lax.top_k`` need nothing beyond jax itself:
+    building the tiny OlmoeModel and the tiny KeyeModel (sparse attention,
+    a share of the experts), applying them and taking their gradients loads
+    no kernel library and nothing of byteps_tpu that the import had not
+    loaded."""
     new = loaded["by_the_model"]
     assert _kernel_modules(new) == []
     assert [n for n in new if n.startswith("byteps_tpu")] == []
@@ -72,11 +78,14 @@ def test_the_expert_model_loads_only_what_its_grouped_matmul_needs(loaded):
 # byteps_tpu.jax, byteps_tpu.models` loaded before they existed.
 ALLOWED = {"__future__", "functools", "typing", "jax", "jax.numpy",
            "flax.linen", "byteps_tpu.jax._compat", "byteps_tpu.models.llama",
-           "byteps_tpu.models.transformer", "byteps_tpu.parallel.moe"}
+           "byteps_tpu.models.transformer", "byteps_tpu.parallel.moe",
+           "byteps_tpu.parallel.sparse_attention"}
 
 
 @pytest.mark.parametrize("path", ("byteps_tpu/parallel/moe.py",
-                                  "byteps_tpu/models/olmoe.py"))
+                                  "byteps_tpu/models/olmoe.py",
+                                  "byteps_tpu/parallel/sparse_attention.py",
+                                  "byteps_tpu/models/keye.py"))
 def test_module_level_imports_are_the_ones_every_cell_already_paid(path):
     with open(os.path.join(REPO, path)) as f:
         tree = ast.parse(f.read())
