@@ -16,6 +16,16 @@ measurement over gathering the selected keys, and the spans over one loop
 and over unrolled blocks (PERF.md section 4). No kernel library is
 imported here (``tests/test_import_footprint.py``).
 
+The selection thresholds on each query's ``topk``-th highest score. That
+one number a row is found without sorting: the float32 scores are read as
+integers of the same order and the threshold's bits are fixed from the
+top, two a pass, by counting how many scores reach each candidate — 16
+compare-and-count passes over the block, exact for every input (PERF.md
+section 4: a quarter to a ninth of ``lax.top_k``'s time at these widths).
+The threshold and the room it leaves for ties, [block, 1] each, are named
+and saved across the recomputation, so the backward pass rebuilds the
+mask from them and searches nothing; the mask itself is never saved.
+
 Gradient paths. The indexer's inputs are the caller's to detach; here the
 attention probabilities that the indexer is trained towards are detached,
 and the selection has no gradient. So the attention output carries no
@@ -29,23 +39,67 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 INDEXER_SCOPE = "bps.dsa.indexer"  # index scores, the KL term, gradients
-SELECT_SCOPE = "bps.dsa.select"    # top-k and the mask
+SELECT_SCOPE = "bps.dsa.select"    # the topk-th score and the mask
 ATTEND_SCOPE = "bps.dsa.attend"    # scores, softmax, values, gradients
+
+
+_INT_MIN = -2 ** 31
+_DIGIT = 2          # bits of the threshold a pass fixes (PERF.md section 4)
+_SAVED = "bps.dsa.kth"  # what of the selection outlives the forward pass
+
+
+def _ordered(x):
+    """float32 -> int32 with the floats' own order and equality: a
+    negative float's bits count down from 0 by its magnitude, so -0.0 and
+    +0.0 are one key and -inf the smallest (no NaN among the scores)."""
+    bits = lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(bits < 0, _INT_MIN - bits, bits)
+
+
+@partial(jax.jit, static_argnums=1)
+def _kth_key(key, topk: int):
+    """[rows, 1] int32: per row of ``key`` [rows, n >= topk] its
+    ``topk``-th largest entry, exactly — the largest t that at least
+    ``topk`` entries reach, its bits fixed from the top, ``_DIGIT`` a
+    pass: a pass counts the entries that reach each candidate digit and
+    keeps the highest digit that ``topk`` still reach. No sort, and the
+    time does not turn on the data. One loop body under a jit of its own,
+    so that a model of many layers traces the passes once a width and not
+    once a pass, layer and span (unrolled in Python they added 6.6 s to
+    each trace of the four-layer model: PERF.md section 6, PR 34)."""
+    def fix(i, t):
+        # int32 wraps on purpose: t starts at the smallest int32 and the
+        # top digit carries it past zero
+        shift = 32 - _DIGIT * (i + 1)
+        reached = sum(
+            ((key >= t + lax.shift_left(jnp.int32(digit), shift)).sum(
+                axis=-1, keepdims=True, dtype=jnp.int32) >= topk
+             ).astype(jnp.int32) for digit in range(1, 1 << _DIGIT))
+        return t + lax.shift_left(reached, shift)
+
+    return lax.fori_loop(0, 32 // _DIGIT, fix,
+                         jnp.full((key.shape[0], 1), _INT_MIN, jnp.int32))
 
 
 def _select(score, causal, topk: int):
     """[queries, keys] bool: per query its ``min(causal keys, topk)``
-    causal keys of highest ``score``, ties to the earlier key — the set
-    ``lax.top_k`` returns, as a mask: everything above the ``topk``-th
-    value, and of the keys equal to it the earliest that still fit."""
+    causal keys of highest ``score``, ties to the earlier key: everything
+    above the ``topk``-th value, and of the keys equal to it the earliest
+    that still fit. The ``topk``-th value is found by ``_kth_key`` over
+    the scores' ordered bits (a query with fewer causal keys finds -inf
+    and keeps them all); it and the room left for ties are saved
+    across the block's recomputation, so the backward pass rebuilds the
+    mask from them and does not search again."""
     if score.shape[1] <= topk:
         return causal
-    masked = jnp.where(causal, score, -jnp.inf)
-    kth = lax.top_k(masked, topk)[0][:, -1:]
-    above, tied = masked > kth, masked == kth
-    room = topk - above.sum(axis=-1, keepdims=True)
+    key = _ordered(jnp.where(causal, score, -jnp.inf))
+    kth = checkpoint_name(_kth_key(key, topk), _SAVED)
+    above, tied = key > kth, key == kth
+    room = checkpoint_name(
+        topk - above.sum(axis=-1, keepdims=True, dtype=jnp.int32), _SAVED)
     return (above | (tied & (jnp.cumsum(tied, axis=-1) <= room))) & causal
 
 
@@ -91,7 +145,9 @@ def _one_sequence(q, k, v, index_q, index_k, index_w, *, topk, block, scale):
     its blocks run one after the other under ``lax.map``."""
     s = q.shape[0]
     span = block * max(1, topk // block)
-    run = jax.checkpoint(partial(_block, topk=topk, scale=scale))
+    run = jax.checkpoint(
+        partial(_block, topk=topk, scale=scale),
+        policy=jax.checkpoint_policies.save_only_these_names(_SAVED))
     outs, kl, selected = [], 0.0, 0
     for lo in range(0, s, span):
         hi = min(lo + span, s)

@@ -4,19 +4,24 @@ out here (tier-1, CPU, float32, seeded).
 The yardstick shares no code with the op: index scores for all (query, key)
 pairs at once; the selection by a stable descending argsort of each query's
 causal scores, first ``min(t + 1, topk)`` taken — "the highest, ties to the
-earlier key" said the plain way, where the op thresholds on ``lax.top_k``'s
-last value and counts ties; one masked softmax over all keys; the KL term.
+earlier key" said the plain way, where the op thresholds on the ``topk``-th
+value (an exact search over the float's bits) and counts ties; one masked
+softmax over all keys; the KL term.
 In float32 on the CPU the two differ by the order sums are taken in: a
 relative 1e-5 of the largest entry.
 """
 
+import re
+
 import jax
+import jax.ad_checkpoint
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from byteps_tpu.parallel.ring_attention import full_attention
-from byteps_tpu.parallel.sparse_attention import (_select, publish_dsa_stats,
+from byteps_tpu.parallel.sparse_attention import (_DIGIT, _kth_key, _ordered,
+                                                  _select, publish_dsa_stats,
                                                   sparse_attention)
 
 RTOL = 1e-5
@@ -128,21 +133,133 @@ def test_op_is_the_dense_masked_computation(s, topk, block, heads, kv_heads,
                       rtol=1e-5)
 
 
-@pytest.mark.parametrize("tied", (False, True))
-def test_each_query_selects_exactly_its_topk_earliest_on_ties(tied):
-    s, topk = 96, 32
-    args = _inputs(s, 4, 2, tied, seed=3)
-    score = _scores(*args[3:])[0]
+S = 96
+
+
+def _bits(rng, low, high):
+    """Small int32 to read as float32 bit patterns (denormals and +0.0)
+    or to add to a float's bits (neighbours an ulp apart)."""
+    return rng.integers(low, high, (S, S)).astype(np.int32)
+
+
+def _indexer_scores(tied):
+    score = np.asarray(_scores(*_inputs(S, 4, 2, tied, seed=3)[3:])[0])
     if tied:            # the tie is real: most causal scores are equal
         assert float((score == 0).mean()) > 0.5
-    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
-    keep = np.asarray(_select(score, causal, topk))
-    assert (keep.sum(axis=1) == np.minimum(np.arange(s) + 1, topk)).all()
+    return score
+
+
+# [S, S] float32 by kind; every query's threshold falls among them
+SCORE_KINDS = {
+    "indexer": lambda rng: _indexer_scores(False),
+    "indexer_tied": lambda rng: _indexer_scores(True),
+    "normal": lambda rng: rng.standard_normal((S, S)),
+    "negative": lambda rng: -np.abs(rng.standard_normal((S, S))) - 1e-3,
+    "quantised": lambda rng: np.round(0.7 * rng.standard_normal((S, S))),
+    "equal": lambda rng: np.full((S, S), 0.25),
+    "signed_zeros": lambda rng: rng.choice(
+        np.float32([0.0, -0.0, 0.0, -0.0, 1.0, -1.0]), (S, S)),
+    "ulp_apart": lambda rng: rng.choice(np.float32([1.0, -1.0]), (S, S)) * (
+        np.float32(1.0).view(np.int32) + _bits(rng, -2, 3)).view(np.float32),
+    "denormal": lambda rng: rng.choice(np.float32([1.0, -1.0]), (S, S))
+    * _bits(rng, 0, 6).view(np.float32),
+    "large": lambda rng: rng.choice(np.float32(
+        [3e38, -3e38, np.finfo(np.float32).max, -np.finfo(np.float32).max,
+         1e-38, -1e-38, 1.0, 0.0]), (S, S)) * rng.choice(
+             np.float32([1.0, 0.5, 0.25]), (S, S)),
+}
+SELECT_CASES = [(kind, topk) for kind in SCORE_KINDS
+                for topk in (1, S // 4, S - 1)]
+
+
+def _kind(kind):
+    score = np.asarray(SCORE_KINDS[kind](np.random.default_rng(5)),
+                       np.float32)
+    causal = np.arange(S)[None, :] <= np.arange(S)[:, None]
+    return score, causal
+
+
+@pytest.mark.parametrize("kind,topk", SELECT_CASES)
+def test_each_query_selects_exactly_its_topk_earliest_on_ties(kind, topk):
+    """``_select`` against the stable argsort, whatever the scores look
+    like; queries 0..topk-2 have fewer than ``topk`` causal keys and keep
+    them all."""
+    score, causal = _kind(kind)
+    keep = np.asarray(_select(jnp.asarray(score), jnp.asarray(causal), topk))
+    assert (keep.sum(axis=1) == np.minimum(np.arange(S) + 1, topk)).all()
     assert (keep == _mask(score[None], topk)[0]).all()
-    assert not keep[~np.asarray(causal)].any()
-    # all-equal scores: the earliest topk keys
-    keep = np.asarray(_select(jnp.zeros((s, s)), causal, topk))
-    assert keep[-1, :topk].all() and not keep[-1, topk:].any()
+    assert not keep[~causal].any()
+
+
+@pytest.mark.parametrize("kind,topk", SELECT_CASES)
+def test_the_threshold_is_the_topk_th_value_bit_for_bit(kind, topk):
+    """The search returns the number ``lax.top_k`` returns last, in every
+    bit; both are read through ``_ordered``, which changes nothing but
+    -0.0 into +0.0 (equal floats, one key)."""
+    score, causal = _kind(kind)
+    masked = jnp.where(causal, score, -jnp.inf)
+    want = _ordered(jax.lax.top_k(masked, topk)[0][:, -1:])
+    got = _kth_key(_ordered(masked), topk)
+    assert got.dtype == jnp.int32 and got.shape == (S, 1)
+    assert (np.asarray(got) == np.asarray(want)).all()
+    # fewer than topk causal keys: -inf, every causal key above it
+    short = np.asarray(got)[:topk - 1, 0]
+    assert (short == -0x7f800000).all()
+
+
+def test_ordered_bits_keep_the_floats_order_and_fold_the_zeros():
+    floats = np.float32([-np.inf, -3e38, -1.0, -1e-45, -0.0, 0.0, 1e-45, 1.0,
+                         3e38, np.inf])
+    keys = np.asarray(_ordered(jnp.asarray(floats))).tolist()
+    assert keys[0] == -0x7f800000 and keys[4] == keys[5] == 0
+    assert keys[:5] == sorted(set(keys[:5])) and keys[5:] == sorted(
+        set(keys[5:]))
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_the_backward_pass_does_not_search_again(capsys):
+    """In the gradient the search (one pass's compare-and-count per
+    candidate digit, the body of its loop) appears once a span of keys
+    that selects — the forward body of its ``lax.map`` — not again in the
+    recomputation, and what a block saves for it is the [block, 1]
+    threshold and room: no [block, keys] mask or scores."""
+    s, topk, block = 96, 32, 16
+    args = _inputs(s, 4, 2, False)
+
+    def loss(*a):
+        out, index_loss, _ = sparse_attention(*a, topk=topk, block=block)
+        return (out ** 2).sum() + index_loss
+
+    wide = [e for e in _eqns(jax.make_jaxpr(jax.grad(loss, range(6)))(
+        *args).jaxpr) if e.invars and e.invars[0].aval.shape[-2:] in (
+            (block, 64), (block, 96))]
+    counts = [e for e in wide if e.primitive.name == "ge"]
+    assert all(e.invars[0].aval.dtype == jnp.int32 for e in counts)
+    spans = 2                                   # keys 0..63 and 0..95
+    assert len(counts) == spans * (2 ** _DIGIT - 1)
+    assert not [e for e in wide if e.primitive.name in ("sort", "top_k")]
+    # the mask is still rebuilt in the backward pass: cumsum twice a span
+    assert len([e for e in wide if e.primitive.name == "cumsum"]) == 2 * spans
+
+    jax.ad_checkpoint.print_saved_residuals(loss, *args)
+    saved = re.findall(r"^(\w+)\[([\d,]*)\]", capsys.readouterr().out,
+                       re.M)
+    shapes = [(dtype, tuple(int(n) for n in dims.split(",") if n))
+              for dtype, dims in saved]
+    assert len(shapes) > 10
+    assert [x for x in shapes if x[0] == "i32" and len(x[1]) > 1] == (
+        [("i32", (B, spans, block, 1))] * 2 * spans)
+    assert not [x for x in shapes if x[0] not in ("i32", "f32")
+                or x[1][-2:] in ((block, 64), (block, 96))]
 
 
 def test_the_two_losses_do_not_reach_each_other_s_inputs():
