@@ -206,12 +206,17 @@ def masked_lm_loss(logits: jax.Array, labels: jax.Array,
     return -(ll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
-def lm_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:
-    """Next-token cross-entropy (shifted), mean over all positions."""
+def lm_log_likelihood(logits: jax.Array, tokens: jax.Array) -> jax.Array:
+    """[batch, seq - 1]: the log-probability ``logits`` at position t give
+    token t + 1 (shifted; the last position predicts nothing)."""
     logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
     tgt = tokens[:, 1:]
-    ll = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-    return -ll.mean()
+    return jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+
+
+def lm_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:
+    """Next-token cross-entropy (shifted), mean over all positions."""
+    return -lm_log_likelihood(logits, tokens).mean()
 
 
 def sp_lm_loss(logits: jax.Array, tokens: jax.Array, axis: str) -> jax.Array:

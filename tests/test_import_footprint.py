@@ -8,7 +8,7 @@ section 6, PR 28): a kernel library (Pallas, megablox) is imported inside
 the function that calls it, as ``models/transformer.py::_attention_fn`` does
 for the flash kernel; the expert layer's and the sparse attention's four
 modules import at module level only what was loaded before they existed
-(and each other).
+(and each other); so does the looped model's.
 """
 
 import ast
@@ -27,8 +27,10 @@ import byteps_tpu.jax, byteps_tpu.models
 imported = set(sys.modules)
 
 import jax, jax.numpy as jnp, numpy as np
-from byteps_tpu.models import KeyeTiny, OlmoeTiny, keye_loss, olmoe_loss
-for tiny, loss, seq in ((OlmoeTiny, olmoe_loss, 16), (KeyeTiny, keye_loss, 32)):
+from byteps_tpu.models import (KeyeTiny, OlmoeTiny, OuroTiny, keye_loss,
+                               olmoe_loss, ouro_loss)
+for tiny, loss, seq in ((OlmoeTiny, olmoe_loss, 16), (KeyeTiny, keye_loss, 32),
+                        (OuroTiny, lambda out, tokens: ouro_loss(out), 16)):
     model = tiny()
     tokens = np.zeros((1, seq), np.int32)
     params = model.init(jax.random.PRNGKey(0), tokens)
@@ -58,7 +60,8 @@ def _kernel_modules(names):
 def test_importing_the_library_loads_no_kernel_library(loaded):
     for module in ("byteps_tpu.models.olmoe", "byteps_tpu.parallel.moe",
                    "byteps_tpu.models.keye",
-                   "byteps_tpu.parallel.sparse_attention"):
+                   "byteps_tpu.parallel.sparse_attention",
+                   "byteps_tpu.models.ouro"):
         assert module in loaded["imported"]
     assert _kernel_modules(loaded["imported"]) == []
 
@@ -68,7 +71,7 @@ def test_the_expert_model_loads_only_what_its_grouped_matmul_needs(loaded):
     building the tiny OlmoeModel and the tiny KeyeModel (sparse attention,
     a share of the experts), applying them and taking their gradients loads
     no kernel library and nothing of byteps_tpu that the import had not
-    loaded."""
+    loaded; nor does the tiny OuroModel, whose loop is flax's own scan."""
     new = loaded["by_the_model"]
     assert _kernel_modules(new) == []
     assert [n for n in new if n.startswith("byteps_tpu")] == []
@@ -86,7 +89,8 @@ ALLOWED = {"__future__", "functools", "typing", "jax", "jax.numpy",
 @pytest.mark.parametrize("path", ("byteps_tpu/parallel/moe.py",
                                   "byteps_tpu/models/olmoe.py",
                                   "byteps_tpu/parallel/sparse_attention.py",
-                                  "byteps_tpu/models/keye.py"))
+                                  "byteps_tpu/models/keye.py",
+                                  "byteps_tpu/models/ouro.py"))
 def test_module_level_imports_are_the_ones_every_cell_already_paid(path):
     with open(os.path.join(REPO, path)) as f:
         tree = ast.parse(f.read())
