@@ -17,10 +17,12 @@ the passes are ONE ``lax.scan`` with the parameters broadcast into its body
 (``nn.scan``), so the program holds one copy of the stack whatever
 ``num_passes`` is (PERF.md, PR 35: against the passes unrolled, 2% less
 time, 1.4 GB less memory and a third of the compile). Every block
-application is recomputed in the backward pass (``nn.remat``): with
-``attn_impl="full"`` one application's float32 scores are 16 x s^2 x 4 B,
-and ``num_passes x num_layers`` of them cannot be kept; what is kept is each
-application's input. The exit of a pass — head, softmax, per-position
+application is recomputed in the backward pass (``nn.remat``): what is
+kept is each application's input, not its seven matmuls' operands. (Where
+``full_attention`` takes its XLA form — off the TPU, or under s 512 — one
+application's float32 scores are 16 x s^2 x 4 B as well; on the chip at
+the benchmark's s 4096 the flash kernel holds a block of them in VMEM and
+saves q, k, v, the output and a logsumexp a row.) The exit of a pass — head, softmax, per-position
 cross-entropy — is recomputed too, so that one pass's ``[s, vocab]`` float32
 logits are live at a time in both directions.
 

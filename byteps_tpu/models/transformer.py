@@ -7,9 +7,14 @@ modules so the framework's benchmarks and examples are self-contained.
 
 TPU-first choices: bfloat16 matmuls (MXU-native) with float32 layernorm /
 softmax / logits, static shapes, and a pluggable attention implementation —
-``attn_impl='full' | 'ring' | 'ulysses'`` — so the same module runs
-single-chip or sequence-parallel under ``shard_map`` (ring attention /
+``attn_impl='full' | 'flash' | 'ring' | 'ulysses'`` — so the same module
+runs single-chip or sequence-parallel under ``shard_map`` (ring attention /
 all-to-all resharding from byteps_tpu.parallel, the long-context path).
+``'full'`` means "the sequence is not sharded": exact attention by
+``byteps_tpu.parallel.full_attention``, which on a TPU runs the Pallas
+flash kernel for the shapes where that is the faster form (causal bf16
+from s 512 up) and the XLA einsums elsewhere; ``'flash'`` forces the
+kernel whatever the shape or backend (interpreted off the chip).
 """
 
 from __future__ import annotations

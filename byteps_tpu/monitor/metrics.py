@@ -38,6 +38,12 @@ def inc_counter(name: str, delta: float = 1.0) -> None:
         _py_counters[name] = _py_counters.get(name, 0.0) + delta
 
 
+def counter(name: str) -> float:
+    """A Python-side counter's value; 0 before its first increment."""
+    with _py_lock:
+        return _py_counters.get(name, 0.0)
+
+
 def set_gauge(name: str, value: float) -> None:
     with _py_lock:
         _py_gauges[name] = float(value)

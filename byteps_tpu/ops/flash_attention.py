@@ -13,36 +13,100 @@ head_dim]; any dtype (bf16 hot path), f32 accumulation.
 
 The backward pass is a pair of Pallas kernels (dQ, and dK/dV) doing the
 standard flash-attention blockwise recompute from the forward's saved
-(q, k, v, o, logsumexp) — O(seq) memory end to end, measured ~1.4x the
-XLA-recompute VJP at seq 4k on v5e and the only way 32k-token training
-fits HBM. Off-TPU the kernels run in interpret mode, so tests exercise
-the real kernel code paths on CPU.
+(q, k, v, o, logsumexp) — O(seq) memory end to end. Off-TPU the kernels
+run in interpret mode, so tests exercise the real kernel code paths on CPU.
+
+Both matmul operands of every product are in the operands' own dtype (bf16
+on the hot path), logits, running max / sum and every accumulator float32.
+Causal blocks wholly above the diagonal are neither computed nor fetched
+(their index maps repeat the last live block, so the pipeline issues no
+copy). The three ``pallas_call``s are named ``bps_flash_fwd`` /
+``bps_flash_dq`` / ``bps_flash_dkv`` and each sits under a ``jax.jit`` of
+its own, so a model with N attention layers of one shape traces and lowers
+them once; what a process pays before its first step for using them is in
+PERF.md section 6 (PR 36): the import, and 0.2-0.6 s of tracing a program.
+``byteps_tpu.parallel.full_attention`` hands this kernel the shapes where
+it beats XLA's form on the chip (PERF.md section 3, kernels).
 """
 
 from __future__ import annotations
 
 import functools
+import sys
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-try:  # pltpu is importable on CPU builds too; guard for safety
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+
+def _import_pallas():
+    """``jax.experimental.pallas`` and its ``tpu`` module, without the Mosaic
+    GPU interpreter. Every process that reaches the kernel pays this import
+    before its first step, from source (the image caches no bytecode): 1.14
+    s on the chip's host, 0.86 of them the GPU interpreter's LLVM and NVVM
+    dialects (my chip runs, PR 36), which ``jax/_src/pallas/pallas_call.py``
+    imports under ``except ImportError`` and a TPU kernel never touches. A
+    ``None`` in ``sys.modules`` is Python's own way to say a module is
+    absent; it is taken out again, so a later import of it finds it. If
+    Pallas is loaded already, or will not load this way, the plain import."""
+    gpu = "jax._src.pallas.mosaic_gpu.interpret"
+    halted = (gpu not in sys.modules
+              and "jax.experimental.pallas" not in sys.modules)
+    if halted:
+        sys.modules[gpu] = None
+    try:
+        from jax.experimental import pallas
+        from jax.experimental.pallas import tpu
+    except ImportError:
+        if not halted:
+            raise
+        del sys.modules[gpu]
+        halted = False
+        from jax.experimental import pallas
+        from jax.experimental.pallas import tpu
+    finally:
+        if halted:
+            del sys.modules[gpu]
+    return pallas, tpu
+
+
+pl, pltpu = _import_pallas()
+_VMEM = pltpu.VMEM
 
 _NEG_INF = -1e30
+
+# The kernels' names: what a device trace and the ledger's ``device_ops``
+# show for the three custom calls.
+FWD_NAME, DQ_NAME, DKV_NAME = "bps_flash_fwd", "bps_flash_dq", "bps_flash_dkv"
+
+
+def _mask(q_start, k_start, bq, bk, seq_q, seq_k, causal, window):
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    mask = k_pos < seq_k                   # key padding
+    if seq_q is not None:
+        mask = jnp.logical_and(mask, q_pos < seq_q)
+    if causal:
+        mask = jnp.logical_and(mask, q_pos >= k_pos)
+        if window is not None:
+            # sliding window: attend to the last `window` positions
+            mask = jnp.logical_and(mask, q_pos - k_pos < window)
+    return mask
+
+
+def _when_live(live, compute):
+    """``compute()`` unless the block is dead; ``live`` is ``True`` (the
+    Python value) where no mask can kill a whole block."""
+    if live is True:
+        compute()
+    else:
+        pl.when(live)(compute)
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                *, scale: float, causal: bool, block_q: int, block_k: int,
                seq_k: int, window: Optional[int] = None,
                nk_total: Optional[int] = None):
-    # lse_ref is None for inference-only calls (no residual output).
     # nk_total set => restricted-window grid: the third grid dim walks only
     # the ~window/block_k live k blocks per q block (see _window_kv_index).
     """One (bh, qi, ki) grid step of blockwise attention."""
@@ -66,24 +130,12 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         k_start = k_idx * block_k
 
     def _compute():
-        q = q_ref[0]                       # [block_q, d]
-        k = k_ref[0]                       # [block_k, d]
         v = v_ref[0]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [bq, bk]
-
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = k_pos < seq_k               # key padding
-        if causal:
-            q_pos = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, q_pos >= k_pos)
-            if window is not None:
-                # sliding window: attend to the last `window` positions
-                mask = jnp.logical_and(mask, q_pos - k_pos < window)
-        s = jnp.where(mask, s, _NEG_INF)
+        s = jnp.where(_mask(q_start, k_start, block_q, block_k, None, seq_k,
+                            causal, window), s, _NEG_INF)
 
         m_prev = m_ref[:, 0:1]             # [bq, 1]
         m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -99,6 +151,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
             preferred_element_type=jnp.float32)
         acc_ref[:] = acc_ref[:] * corr + pv
 
+    live = True
     if causal:
         # k_start/q_start are traced (program_id); predicate at runtime.
         live = k_start <= q_start + block_q - 1
@@ -108,12 +161,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                 live, k_start + block_k - 1 >= q_start - (window - 1))
         if nk_total is not None:
             live = jnp.logical_and(live, k_start < nk_total * block_k)
-
-        @pl.when(live)
-        def _():
-            _compute()
-    else:
-        _compute()
+    _when_live(live, _compute)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -123,10 +171,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
         # logsumexp per row (scaled-score space) for the backward pass;
         # +LARGE for empty rows so exp(s - lse) underflows to exactly 0.
-        if lse_ref is not None:
-            lse = jnp.where(l == 0.0, _NEG_INF * -1.0,
-                            m_ref[:, 0:1] + jnp.log(safe_l))
-            lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+        lse = jnp.where(l == 0.0, _NEG_INF * -1.0,
+                        m_ref[:, 0:1] + jnp.log(safe_l))
+        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
 def _window_start_block(q_start, window, block_k):
@@ -141,6 +188,12 @@ def _window_live_blocks(window: int, block_q: int, block_k: int,
     return min(nk, span // block_k + 2)
 
 
+def _div(a, b: int):
+    """``a // b`` for a traced ``a >= 0``: one op where ``//`` lowers to a
+    sign, a remainder and two selects in every index map."""
+    return jax.lax.div(a, jnp.int32(b))
+
+
 def _pad_to(x, multiple: int, axis: int):
     n = x.shape[axis]
     pad = (-n) % multiple
@@ -151,6 +204,29 @@ def _pad_to(x, multiple: int, axis: int):
     return jnp.pad(x, widths)
 
 
+def _block(s: int, largest: int) -> int:
+    """The largest block that pads a sequence of ``s`` by at most an
+    eighth (a block longer than ``s`` clamps to it at the call)."""
+    block = largest
+    while block > 128 and (-s) % block > s // 8:
+        block //= 2
+    return block
+
+
+def _blocks(s_q: int, s_k: int, d: int):
+    """(block_q, block_k) of the three kernels, from the shape. On a v5e,
+    causal bf16, at 16 x 128 x s4096 and at 12 x 64 x s1024 (PERF.md section
+    3, my chip runs, PR 36) — forward: 1024 x 1024 0.84 and 0.49 ms, the
+    fastest of twelve and of seven; 512 x 1024 0.94 and 0.61, 512 x 512
+    1.50 and 0.82, 256 x 256 2.85 and 1.34; 2048 x 2048 does not fit VMEM.
+    dQ + dK/dV: 1024 x 1024 2.31 and 1.60, 512 x 512 2.39 and 1.61, 256 x
+    512 3.00 and 1.90, 256 x 256 4.03 and 2.34; 1024 x 2048 does not fit
+    (the backward kernels hold p, dp and ds, three float32 [bq, bk]
+    temporaries)."""
+    largest = 1024 if d <= 128 else 512
+    return _block(s_q, largest), _block(s_k, largest)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(
     q: jax.Array,
@@ -158,16 +234,16 @@ def flash_attention(
     v: jax.Array,
     causal: bool = False,
     scale: Optional[float] = None,
-    block_q: int = 512,
-    block_k: int = 1024,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention over [batch, seq, heads, head_dim] arrays.
 
-    Default blocks (512, 1024) measured fastest on v5e at seq 2k-8k
-    (~1.6x over XLA's fused attention; 128x128 was slower than XLA).
-    Blocks clamp to the sequence length for short inputs.
+    ``block_q`` / ``block_k`` left ``None`` are derived from the shape
+    (``_blocks``; the backward kernels take theirs from it whatever is
+    passed here). Blocks clamp to the sequence length for short inputs.
 
     ``window`` (requires ``causal``) restricts each query to the last
     ``window`` positions — Mistral-style sliding-window attention; blocks
@@ -177,14 +253,12 @@ def flash_attention(
     Exact softmax attention, O(seq) memory. ``interpret=None`` compiles
     the kernel on a TPU backend and interprets it elsewhere (tests run the
     same kernel on CPU); pass ``False`` to refuse interpretation. Drop-in for
-    ``byteps_tpu.parallel.full_attention``, including as the inner kernel
-    of ``ulysses_attention(attn_fn=...)``.
+    ``byteps_tpu.parallel.full_attention`` (which calls this itself for the
+    shapes where the kernel wins on the chip), including as the inner
+    kernel of ``ulysses_attention(attn_fn=...)``.
     """
-    if window is not None and not causal:
-        raise ValueError("window requires causal=True (sliding-window "
-                         "attention is a causal scheme)")
-    return _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k,
-                           interpret, window=window)
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                      window)[0]
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -208,15 +282,20 @@ def _from_bhsd(x, b, h):
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret", "window"))
 def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
-                    return_lse: bool = False,
-                    window: Optional[int] = None):
+                    window):
+    """(out, logsumexp rows padded to [batch * heads, s_q', 8]). The one
+    forward there is: a call that needs no residual drops the logsumexp
+    (2 MB at 16 x s4096) and shares this trace with the one that does."""
+    if window is not None and not causal:
+        raise ValueError("window requires causal=True (sliding-window "
+                         "attention is a causal scheme)")
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    interpret = _resolve_interpret(interpret)
-
     bq = min(block_q, max(s_q, 8))
     bk = min(block_k, max(s_k, 8))
 
@@ -235,6 +314,13 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
             return (bh,
                     jnp.clip(_window_start_block(qi * bq, window, bk) + ki,
                              0, nk - 1), 0)
+    elif causal:
+        nkg = nk
+
+        def kv_index(bh, qi, ki):
+            # a block above the diagonal repeats the last live one's
+            # index: the kernel skips it and the pipeline copies nothing
+            return (bh, jnp.minimum(ki, _div(qi * bq + bq - 1, bk)), 0)
     else:
         nkg = nk
 
@@ -260,71 +346,41 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
     common = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
                   seq_k=s_k, window=window,
                   nk_total=nk if window is not None else None)
-    if return_lse:
-        out, lse = pl.pallas_call(
-            functools.partial(_fa_kernel, **common),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=[
-                o_spec,
-                # lane dim 8 (not 128): the smallest layout-legal tile —
-                # the kernels only read one value per row
-                vmem((1, bq, 8), lambda bh, qi, ki: (bh, qi, 0),
-                     memory_space=_VMEM),
-            ],
-            out_shape=[
-                o_shape,
-                jax.ShapeDtypeStruct((b * h, sq_p, 8), jnp.float32),
-            ],
-            scratch_shapes=scratch,
-            interpret=interpret,
-        )(qq, kk, vv)
-        return _from_bhsd(out[:, :s_q], b, h), lse  # padded [bh, sq_p, 8]
-
-    def _kernel_nolse(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
-        _fa_kernel(q_ref, k_ref, v_ref, o_ref, None, m_ref, l_ref,
-                   acc_ref, **common)
-
-    out = pl.pallas_call(
-        _kernel_nolse,
+    out, lse = pl.pallas_call(
+        functools.partial(_fa_kernel, **common),
         grid=grid,
         in_specs=in_specs,
-        out_specs=o_spec,
-        out_shape=o_shape,
+        out_specs=[
+            o_spec,
+            # lane dim 8 (not 128): the smallest layout-legal tile —
+            # the kernels only read one value per row
+            vmem((1, bq, 8), lambda bh, qi, ki: (bh, qi, 0),
+                 memory_space=_VMEM),
+        ],
+        out_shape=[
+            o_shape,
+            jax.ShapeDtypeStruct((b * h, sq_p, 8), jnp.float32),
+        ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name=FWD_NAME,
     )(qq, kk, vv)
-    return _from_bhsd(out[:, :s_q], b, h)
+    return _from_bhsd(out[:, :s_q], b, h), lse
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                window):
-    out, lse = _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k,
-                               interpret, return_lse=True, window=window)
+    derived = _blocks(q.shape[1], k.shape[1], q.shape[-1])
+    out, lse = _flash_fwd_impl(
+        q, k, v, causal, scale, block_q or derived[0], block_k or derived[1],
+        _resolve_interpret(interpret), window)
     return out, (q, k, v, out, lse)
-
-
-# Backward blocks are fixed smaller than the forward's: the bwd kernels
-# hold more live [bq, bk] f32 temporaries (p, dp, ds) in VMEM.
-_BWD_BQ = 256
-_BWD_BK = 512
-
-
-def _bwd_mask(q_start, k_start, bq, bk, seq_q, seq_k, causal, window):
-    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = jnp.logical_and(q_pos < seq_q, k_pos < seq_k)
-    if causal:
-        mask = jnp.logical_and(mask, q_pos >= k_pos)
-        if window is not None:
-            mask = jnp.logical_and(mask, q_pos - k_pos < window)
-    return mask
 
 
 def _bwd_live(q_start, k_start, bq, bk, causal, window):
     """Block-level skip predicate shared by both backward kernels."""
     if not causal:
-        return None
+        return True
     live = q_start + bq - 1 >= k_start
     if window is not None:
         live = jnp.logical_and(live,
@@ -335,26 +391,20 @@ def _bwd_live(q_start, k_start, bq, bk, causal, window):
 def _bwd_recompute(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                    q_start, k_start, *, scale, causal, block_q, block_k,
                    seq_q, seq_k, window=None):
-    """Shared dq/dkv block recompute: returns (p, ds, do_f32). The one
+    """Shared dq/dkv block recompute: returns (p, ds), float32. The one
     place the score/probability/ds math lives, so the two backward
     kernels cannot silently diverge."""
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    do = do_ref[0].astype(jnp.float32)
     lse = lse_ref[0][:, 0:1]
     dd = dd_ref[0][:, 0:1]
     sc = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
+        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    mask = _bwd_mask(q_start, k_start, block_q, block_k, seq_q, seq_k,
-                     causal, window)
-    p = jnp.where(mask, jnp.exp(sc - lse), 0.0)
+    p = jnp.where(_mask(q_start, k_start, block_q, block_k, seq_q, seq_k,
+                        causal, window), jnp.exp(sc - lse), 0.0)
     dp = jax.lax.dot_general(
-        do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
+        do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    ds = p * (dp - dd) * scale
-    return p, ds, do
+    return p, p * (dp - dd) * scale
 
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref,
@@ -372,7 +422,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref,
     k_start = ki * block_k
 
     def _compute():
-        _, ds, _ = _bwd_recompute(
+        _, ds = _bwd_recompute(
             q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_start, k_start,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k,
             seq_q=seq_q, seq_k=seq_k, window=window)
@@ -381,13 +431,8 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    live = _bwd_live(q_start, k_start, block_q, block_k, causal, window)
-    if live is None:
-        _compute()
-    else:
-        @pl.when(live)
-        def _():
-            _compute()
+    _when_live(_bwd_live(q_start, k_start, block_q, block_k, causal, window),
+               _compute)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -410,11 +455,11 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     k_start = ki * block_k
 
     def _compute():
-        p, ds, do = _bwd_recompute(
+        p, ds = _bwd_recompute(
             q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_start, k_start,
             scale=scale, causal=causal, block_q=block_q, block_k=block_k,
             seq_q=seq_q, seq_k=seq_k, window=window)
-        q = q_ref[0]
+        q, do = q_ref[0], do_ref[0]
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -422,13 +467,8 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    live = _bwd_live(q_start, k_start, block_q, block_k, causal, window)
-    if live is None:
-        _compute()
-    else:
-        @pl.when(live)
-        def _():
-            _compute()
+    _when_live(_bwd_live(q_start, k_start, block_q, block_k, causal, window),
+               _compute)
 
     @pl.when(qi == nq - 1)
     def _finish():
@@ -440,15 +480,23 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res,
                g):
     """Pallas backward: blockwise recompute from (q, k, v, o, lse) — the
     standard flash-attention backward, O(seq) memory like the forward."""
-    q, k, v, out, lse = res
+    q, k = res[0], res[1]
+    bq, bk = _blocks(q.shape[1], k.shape[1], q.shape[-1])
+    return _flash_bwd_impl(*res, g, causal, scale, bq, bk,
+                           _resolve_interpret(interpret), window)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret", "window"))
+def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale, block_q, block_k,
+                    interpret, window):
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    interpret = _resolve_interpret(interpret)
 
-    bq = min(_BWD_BQ, max(s_q, 8))
-    bk = min(_BWD_BK, max(s_k, 8))
+    bq = min(block_q, max(s_q, 8))
+    bk = min(block_k, max(s_k, 8))
 
     qq = _pad_to(_to_bhsd(q), bq, axis=1)
     kk = _pad_to(_to_bhsd(k), bk, axis=1)
@@ -466,56 +514,53 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res,
     # the forward's lse is padded with the FORWARD's bq; re-pad for bwd
     lse = _pad_to(lse[:, :s_q], bq, axis=1)
 
-    vmem = pl.BlockSpec
+    clamp = causal and window is None
+
+    def q_of_dq(bh, qi, ki):
+        return (bh, qi, 0)
+
+    def k_of_dq(bh, qi, ki):
+        # above the diagonal: repeat the last live block, copy nothing
+        if clamp:
+            ki = jnp.minimum(ki, _div(qi * bq + bq - 1, bk))
+        return (bh, ki, 0)
+
+    def q_of_dkv(bh, ki, qi):
+        # left of the first query block that sees this key block: the same
+        if clamp:
+            qi = jnp.maximum(qi, _div(ki * bk, bq))
+        return (bh, qi, 0)
+
+    def k_of_dkv(bh, ki, qi):
+        return (bh, ki, 0)
+
+    def specs(q_index, k_index):
+        rows = [(bq, d, q_index), (bk, d, k_index), (bk, d, k_index),
+                (bq, d, q_index), (bq, 8, q_index), (bq, 8, q_index)]
+        return [pl.BlockSpec((1, n, w), index, memory_space=_VMEM)
+                for n, w, index in rows]
+
     kw = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
               seq_q=s_q, seq_k=s_k, window=window)
 
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, **kw),
         grid=(b * h, sq_p // bq, sk_p // bk),
-        in_specs=[
-            vmem((1, bq, d), lambda bh, qi, ki: (bh, qi, 0),
-                 memory_space=_VMEM),
-            vmem((1, bk, d), lambda bh, qi, ki: (bh, ki, 0),
-                 memory_space=_VMEM),
-            vmem((1, bk, d), lambda bh, qi, ki: (bh, ki, 0),
-                 memory_space=_VMEM),
-            vmem((1, bq, d), lambda bh, qi, ki: (bh, qi, 0),
-                 memory_space=_VMEM),
-            vmem((1, bq, 8), lambda bh, qi, ki: (bh, qi, 0),
-                 memory_space=_VMEM),
-            vmem((1, bq, 8), lambda bh, qi, ki: (bh, qi, 0),
-                 memory_space=_VMEM),
-        ],
-        out_specs=vmem((1, bq, d), lambda bh, qi, ki: (bh, qi, 0),
-                       memory_space=_VMEM),
+        in_specs=specs(q_of_dq, k_of_dq),
+        out_specs=pl.BlockSpec((1, bq, d), q_of_dq, memory_space=_VMEM),
         out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
         scratch_shapes=[_VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name=DQ_NAME,
     )(qq, kk, vv, dd_o, lse, dd)
 
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, **kw),
         grid=(b * h, sk_p // bk, sq_p // bq),
-        in_specs=[
-            vmem((1, bq, d), lambda bh, ki, qi: (bh, qi, 0),
-                 memory_space=_VMEM),
-            vmem((1, bk, d), lambda bh, ki, qi: (bh, ki, 0),
-                 memory_space=_VMEM),
-            vmem((1, bk, d), lambda bh, ki, qi: (bh, ki, 0),
-                 memory_space=_VMEM),
-            vmem((1, bq, d), lambda bh, ki, qi: (bh, qi, 0),
-                 memory_space=_VMEM),
-            vmem((1, bq, 8), lambda bh, ki, qi: (bh, qi, 0),
-                 memory_space=_VMEM),
-            vmem((1, bq, 8), lambda bh, ki, qi: (bh, qi, 0),
-                 memory_space=_VMEM),
-        ],
+        in_specs=specs(q_of_dkv, k_of_dkv),
         out_specs=[
-            vmem((1, bk, d), lambda bh, ki, qi: (bh, ki, 0),
-                 memory_space=_VMEM),
-            vmem((1, bk, d), lambda bh, ki, qi: (bh, ki, 0),
-                 memory_space=_VMEM),
+            pl.BlockSpec((1, bk, d), k_of_dkv, memory_space=_VMEM),
+            pl.BlockSpec((1, bk, d), k_of_dkv, memory_space=_VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sk_p, d), k.dtype),
@@ -524,6 +569,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res,
         scratch_shapes=[_VMEM((bk, d), jnp.float32),
                         _VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name=DKV_NAME,
     )(qq, kk, vv, dd_o, lse, dd)
 
     dq = _from_bhsd(dq[:, :s_q], b, h)

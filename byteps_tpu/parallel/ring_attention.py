@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from byteps_tpu.jax._compat import axis_size as _axis_size
+from byteps_tpu.monitor import metrics
 
 
 def _big_neg(dtype) -> float:
@@ -133,12 +134,60 @@ def _single_device_attention(q, k, v, *, causal: bool, scale: float):
     return out.astype(q.dtype)
 
 
+# The two forms of exact attention over an unsharded sequence, as a device
+# trace names them (``jax.named_scope``) and as the metrics endpoint counts
+# their call sites at trace time.
+KERNEL_SCOPE, XLA_SCOPE = "bps.attn.kernel", "bps.attn.xla"
+KERNEL_SITES = "bps_attention_kernel_sites_total"
+XLA_SITES = "bps_attention_xla_sites_total"
+
+# The shortest sequence and the head widths at which the Pallas kernel was
+# measured against the XLA form on a TPU v5e, bf16, forward + backward, and
+# won (PERF.md section 3, kernels: my chip runs, PR 36): causal 16 x 128 at
+# s 512 / 1024 / 2048 / 4096 1.20 / 1.56 / 2.25 / 3.16 ms against 2.03 /
+# 3.85 / 7.11 / 13.77, causal 12 x 64 at s 512 / 1024 / 2048 1.73 / 2.09 /
+# 3.05 against 2.91 / 5.57 / 10.29.
+KERNEL_MIN_SEQ = 512
+KERNEL_HEAD_DIMS = (64, 128)
+
+
+def attention_form(backend: str, s_q: int, s_k: int, head_dim: int,
+                   causal: bool, dtype) -> str:
+    """``"kernel"`` or ``"xla"``: how ``full_attention`` computes these
+    operands. One algorithm, two forms of it; which is faster turns on the
+    sequence length, and the kernel exists for the TPU alone. The XLA form
+    writes float32 ``[batch, heads, s_q, s_k]`` scores to HBM and reads
+    them back; the kernel keeps a block of them in VMEM."""
+    if backend != "tpu" or jnp.dtype(dtype) != jnp.bfloat16:
+        return "xla"
+    if head_dim not in KERNEL_HEAD_DIMS or min(s_q, s_k) < KERNEL_MIN_SEQ:
+        return "xla"
+    return "kernel" if causal else "xla"
+
+
 def full_attention(q, k, v, *, causal: bool = False,
                    scale: Optional[float] = None):
-    """Unsharded reference attention (testing / single-device fallback)."""
+    """Exact softmax attention over an unsharded sequence: float32 logits
+    and softmax statistics, both products on the operands' own dtype. On a
+    TPU, for the shapes ``attention_form`` names, the Pallas kernel of
+    ``byteps_tpu.ops.flash_attention`` (blockwise, the scores never leave
+    VMEM); everywhere else the two einsums around a softmax that XLA
+    fuses as it sees fit."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    return _single_device_attention(q, k, v, causal=causal, scale=scale)
+    if attention_form(jax.default_backend(), q.shape[1], k.shape[1],
+                      q.shape[-1], causal, q.dtype) == "kernel":
+        # imported here: a process that never reaches this line (BERT's
+        # s128, any CPU run) pays for no kernel library
+        # (tests/test_import_footprint.py)
+        from byteps_tpu.ops.flash_attention import flash_attention
+
+        metrics.inc_counter(KERNEL_SITES)
+        with jax.named_scope(KERNEL_SCOPE):
+            return flash_attention(q, k, v, causal, scale)
+    metrics.inc_counter(XLA_SITES)
+    with jax.named_scope(XLA_SCOPE):
+        return _single_device_attention(q, k, v, causal=causal, scale=scale)
 
 
 @partial(jax.jit, static_argnums=(3, 4, 5, 6))

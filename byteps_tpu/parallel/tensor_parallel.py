@@ -72,8 +72,9 @@ def tp_attention(x: jax.Array, wq_shard: jax.Array, wk_shard: jax.Array,
     ``x``: [batch, seq, d_model] replicated; w*_shard: [d_model,
     local_heads*head_dim] (wo_shard transposed: [local_heads*head_dim,
     d_model]). ``attn_fn`` defaults to exact softmax attention
-    (byteps_tpu.parallel.full_attention); pass the Pallas flash kernel
-    for long sequences.
+    (byteps_tpu.parallel.full_attention, which on a TPU is the Pallas
+    flash kernel from s 512 up, causal bf16); pass ``flash_attention``
+    to force the kernel.
     """
     from byteps_tpu.parallel.ring_attention import full_attention
 

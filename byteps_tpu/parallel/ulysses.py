@@ -58,7 +58,8 @@ def ulysses_attention(
     ``q``/``k``/``v``: local blocks [batch, seq_local, heads, head_dim];
     ``heads`` must be divisible by the axis size. ``attn_fn`` replaces the
     inner full-sequence attention (signature: (q, k, v, *, causal, scale));
-    defaults to the exact softmax attention.
+    defaults to the exact softmax attention, ``full_attention``, which
+    picks its form from the per-device shapes it sees here.
     """
     n = _axis_size(axis)
     h = q.shape[2]

@@ -466,6 +466,7 @@ def _flash_kernel_check(shape, seed: int, interpret: bool) -> dict:
     import numpy as np
 
     from byteps_tpu.ops.flash_attention import flash_attention
+    from byteps_tpu.parallel.ring_attention import _single_device_attention
 
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
     q, k, v = (jax.random.normal(kk, shape, jnp.bfloat16) for kk in keys[:3])
@@ -486,22 +487,40 @@ def _flash_kernel_check(shape, seed: int, interpret: bool) -> dict:
     ref_grads = jax.jit(jax.grad(scalar(_reference_attention),
                                  argnums=(0, 1, 2)))(q, k, v)
 
+    # The XLA form by name: on the chip full_attention may be the kernel
+    # itself, and a kernel compared with itself agrees.
+    def xla_form(q, k, v):
+        return _single_device_attention(
+            q, k, v, causal=True, scale=1.0 / math.sqrt(q.shape[-1]))
+
+    xla_out = jax.jit(xla_form)(q, k, v)
+    xla_grads = jax.jit(jax.grad(scalar(xla_form),
+                                 argnums=(0, 1, 2)))(q, k, v)
+
     def err(got, want):
         got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
         if not np.isfinite(got).all():
             raise RuntimeError(f"flash {shape}: non-finite values")
         return float(np.abs(got - want).max() / max(1.0, np.abs(want).max()))
 
-    errs = {"fwd": err(out, ref_out),
-            **{f"d{n}": err(g, r)
-               for n, g, r in zip("qkv", grads, ref_grads)}}
-    worst = max(errs.values())
+    def errors(want_out, want_grads):
+        return {"fwd": err(out, want_out),
+                **{f"d{n}": err(g, r)
+                   for n, g, r in zip("qkv", grads, want_grads)}}
+
+    errs, vs_xla = errors(ref_out, ref_grads), errors(xla_out, xla_grads)
+    worst = max(*errs.values(), *vs_xla.values())
     if worst > FLASH_ERR_BOUND:
-        raise RuntimeError(
-            f"flash {shape}: error {errs} exceeds {FLASH_ERR_BOUND}")
+        raise RuntimeError(f"flash {shape}: error {errs} against float32, "
+                           f"{vs_xla} against _single_device_attention, "
+                           f"exceeds {FLASH_ERR_BOUND}")
+
+    def rounded(d):
+        return {k: round(e, 5) for k, e in d.items()}
+
     return {"shape": list(shape), "compile_and_run_s": secs,
-            "max_abs_err_over_ref_scale": {k: round(e, 5)
-                                           for k, e in errs.items()}}
+            "max_abs_err_over_ref_scale": rounded(errs),
+            "max_abs_err_over_single_device_attention": rounded(vs_xla)}
 
 
 def phase_flash(size: Size, prob: Problem, ref_losses, seed: int,

@@ -49,6 +49,11 @@ FLASH_CASES = {
     "long_b1_s4096_d128": ((1, 4096, 8, 128), True, None),
     "window1024_b1_s4096": ((1, 4096, 8, 128), True, 1024),
     "bert_large_noncausal_b32_s128": ((32, 128, 16, 64), False, None),
+    # what full_attention hands the kernel in the benchmark's cells
+    "ouro_olmoe_b1_s4096_h16_d128": ((1, 4096, 16, 128), True, None),
+    "gpt2_124m_b8_s1024": ((8, 1024, 12, 64), True, None),
+    # the narrower blocks of a head wider than 128
+    "wide_head_b1_s2048_d256": ((1, 2048, 4, 256), True, None),
 }
 
 
