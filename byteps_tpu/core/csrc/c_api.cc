@@ -943,10 +943,12 @@ long long bps_round_summary(char* buf, long long maxlen) {
 // the C core (stage = RoundStage). This IS the production path — the
 // ring/finalize unit tests drive wraparound and drop counters through
 // it without a topology, and a Python-side training loop can report
-// host-level stages into the same per-round records.
-void bps_round_track(int stage, int round, long long us,
-                     long long bytes) {
-  RoundStats::Get().Track(stage, round, us, bytes);
+// host-level stages into the same per-round records. `now_us` places the
+// event on the core's clock (0: now), so a test can hand it intervals
+// worked out by hand.
+void bps_round_track(int stage, int round, long long us, long long bytes,
+                     long long now_us) {
+  RoundStats::Get().Track(stage, round, us, bytes, now_us);
 }
 
 // Ingest a serialized heartbeat round-summary sub-payload (the exact

@@ -1,5 +1,6 @@
 #include "roundstats.h"
 
+#include <algorithm>
 #include <cstdlib>
 #include <cstring>
 
@@ -30,11 +31,39 @@ bool EnvOn(const char* name, bool dflt) {
 // stays bounded and the ring keeps moving.
 constexpr int kOpenRounds = 8;
 
+// An open round's interval list is merged in place when it gets this
+// long, so a round of any size keeps a bounded list per stage.
+constexpr size_t kCompactAt = 8192;
+
+// Sort and merge in place; returns the union's length.
+int64_t MergeIntervals(std::vector<RoundInterval>* v) {
+  std::sort(v->begin(), v->end(),
+            [](const RoundInterval& a, const RoundInterval& b) {
+              return a.start < b.start;
+            });
+  size_t n = 0;
+  int64_t total = 0;
+  for (const RoundInterval& i : *v) {
+    if (n && i.start <= (*v)[n - 1].end) {
+      if (i.end > (*v)[n - 1].end) {
+        total += i.end - (*v)[n - 1].end;
+        (*v)[n - 1].end = i.end;
+      }
+    } else {
+      total += i.end - i.start;
+      (*v)[n++] = i;
+    }
+  }
+  v->resize(n);
+  return total;
+}
+
 // `span` (local rounds only): the elapsed-time fields follow `wall_us`.
 // Fleet records came over the heartbeat wire and have none.
 void AppendRec(std::string* out, const RoundRec& r,
-               const RoundSpan* span = nullptr) {
-  char buf[768];
+               const RoundSpan* span = nullptr,
+               const RoundBusy* busy = nullptr) {
+  char buf[2048];
   int n = snprintf(buf, sizeof(buf),
            "{\"round\":%d,\"parts\":%d,\"queue_us\":%lld,"
            "\"comp_us\":%lld,\"push_us\":%lld,\"sum_us\":%lld,"
@@ -72,6 +101,29 @@ void AppendRec(std::string* out, const RoundRec& r,
         rel(span->pull_start_us),
         static_cast<long long>(span->pull_end_us - span->pull_start_us));
   }
+  if (busy) {
+    auto ll = [](int64_t v) { return static_cast<long long>(v); };
+    n += snprintf(
+        buf + n, sizeof(buf) - n,
+        ",\"queue_span_us\":%lld,\"comp_span_us\":%lld,"
+        "\"push_span_us\":%lld,\"sum_span_us\":%lld,"
+        "\"pull_span_us\":%lld,\"dec_span_us\":%lld,"
+        "\"feed_wait_us\":%lld,\"credit_blocked_us\":%lld,"
+        "\"push_thread_us\":%lld,\"push_thread_sum_us\":%lld,"
+        "\"send_blocked_us\":%lld,\"send_blocked_sum_us\":%lld,"
+        "\"server_us\":%lld,\"server_span_us\":%lld,"
+        "\"recv_thread_us\":%lld,\"recv_thread_sum_us\":%lld,"
+        "\"van_recv_us\":%lld",
+        ll(busy->span_us[RS_QUEUE]), ll(busy->span_us[RS_COMP]),
+        ll(busy->span_us[RS_PUSH]), ll(busy->span_us[RS_SUM]),
+        ll(busy->span_us[RS_PULL]), ll(busy->span_us[RS_DEC]),
+        ll(busy->feed_wait_us), ll(busy->span_us[RS_CREDIT]),
+        ll(busy->span_us[RS_PUSHTHR]), ll(busy->sum_us[RS_PUSHTHR]),
+        ll(busy->span_us[RS_SENDBLK]), ll(busy->sum_us[RS_SENDBLK]),
+        ll(busy->sum_us[RS_SERVER]), ll(busy->span_us[RS_SERVER]),
+        ll(busy->span_us[RS_RECVTHR]), ll(busy->sum_us[RS_RECVTHR]),
+        ll(busy->span_us[RS_VANRECV]));
+  }
   *out += buf;
   *out += "}";
 }
@@ -83,6 +135,7 @@ RoundStats::RoundStats()
   if (ring_cap_ < 8) ring_cap_ = 8;
   ring_.resize(ring_cap_);
   spans_.resize(ring_cap_);
+  busy_.resize(ring_cap_);
   armed_.store(EnvOn("BYTEPS_ROUNDSTATS_ON", true),
                std::memory_order_relaxed);
   heartbeat_summary_on_ = EnvOn("BYTEPS_ROUNDSTATS_HEARTBEAT_SUMMARY", true);
@@ -104,35 +157,52 @@ void RoundStats::SetNodeTenant(int node_id, int tenant) {
 }
 
 void RoundStats::Track(int32_t stage, int round, int64_t us,
-                       int64_t bytes) {
-  if (!On() || round < 0) return;
+                       int64_t bytes, int64_t now_us) {
+  if (!On() || round < 0 || stage < 0 || stage >= RS_STAGES) return;
+  const int64_t now = now_us ? now_us : NowUs();
   std::lock_guard<std::mutex> lk(mu_);
+  auto keep = [stage, us, now](OpenRound* o) {
+    std::vector<RoundInterval>& iv = o->iv[stage];
+    iv.push_back({now - us, now});
+    if (iv.size() >= kCompactAt) MergeIntervals(&iv);
+  };
+  if (stage > RS_DONE) {
+    // A resource's stamp describes a round, it never opens one: the last
+    // callback of a round ends after its RS_DONE, and by then the round
+    // may have been finalized.
+    auto it = open_.find(round);
+    if (it == open_.end() || us <= 0) return;
+    it->second.busy.sum_us[stage] += us;
+    keep(&it->second);
+    return;
+  }
   OpenRound& o = open_[round];
   o.rec.round = round;
   // Four of the stages also stamp the round's elapsed time (RoundSpan).
   // A window opens at the earliest issue (now - us) and closes at the
   // latest completion.
-  auto widen = [us](int64_t now, int64_t* start, int64_t* end) {
+  auto widen = [us, now](int64_t* start, int64_t* end) {
     if (*start == 0 || now - us < *start) *start = now - us;
     if (now > *end) *end = now;
   };
   switch (stage) {
     case RS_ENQ:
+      if (o.enqueued == o.done) o.open_since_us = now;
       ++o.enqueued;
-      if (o.span.first_enq_us == 0) o.span.first_enq_us = NowUs();
+      if (o.span.first_enq_us == 0) o.span.first_enq_us = now;
       break;
     case RS_QUEUE: o.rec.queue_us += us; break;
     case RS_COMP:  o.rec.comp_us += us; break;
     case RS_PUSH:
       o.rec.push_us += us;
       o.rec.wire_bytes += bytes;
-      widen(NowUs(), &o.span.push_start_us, &o.span.push_end_us);
+      widen(&o.span.push_start_us, &o.span.push_end_us);
       break;
     case RS_SUM:   o.rec.sum_us += us; break;
     case RS_PULL:
       o.rec.pull_us += us;
       o.rec.wire_bytes += bytes;
-      widen(NowUs(), &o.span.pull_start_us, &o.span.pull_end_us);
+      widen(&o.span.pull_start_us, &o.span.pull_end_us);
       break;
     case RS_DEC:   o.rec.dec_us += us; break;
     case RS_RETRY: ++o.rec.retries; break;
@@ -144,10 +214,11 @@ void RoundStats::Track(int32_t stage, int round, int64_t us,
     case RS_DONE:
       ++o.done;
       ++o.rec.parts;
-      o.span.last_done_us = NowUs();
+      o.span.last_done_us = now;
+      if (o.done == o.enqueued) o.open_us += now - o.open_since_us;
       break;
-    default: return;
   }
+  if (us > 0 && stage >= RS_QUEUE && stage <= RS_DEC) keep(&o);
   if (round > max_round_) max_round_ = round;
   TryFinalizeLocked();
 }
@@ -183,10 +254,35 @@ void RoundStats::TryFinalizeLocked() {
   }
 }
 
+void RoundStats::ReduceBusy(OpenRound* o) {
+  // A worker's round has both ends: nothing of it counts outside them (a
+  // callback ends a little after its RS_DONE). A server's has neither.
+  const int64_t lo = o->span.first_enq_us, hi = o->span.last_done_us;
+  const bool ends = lo && hi;
+  for (int s = 0; s < RS_STAGES; ++s) {
+    std::vector<RoundInterval>& iv = o->iv[s];
+    if (ends) {
+      for (RoundInterval& i : iv) {
+        i.start = std::min(std::max(i.start, lo), hi);
+        i.end = std::min(std::max(i.end, lo), hi);
+      }
+    }
+    o->busy.span_us[s] = MergeIntervals(&iv);
+  }
+  // Force-finalized with partitions still open: open to the end.
+  if (o->done < o->enqueued && hi > o->open_since_us) {
+    o->open_us += hi - o->open_since_us;
+  }
+  o->busy.feed_wait_us = ends ? hi - lo - o->open_us : 0;
+}
+
 void RoundStats::FinalizeLocked(int round) {
-  const RoundRec& r = open_[round].rec;
+  OpenRound& o = open_[round];
+  ReduceBusy(&o);
+  const RoundRec& r = o.rec;
   ring_[ring_head_] = r;
-  spans_[ring_head_] = open_[round].span;
+  spans_[ring_head_] = o.span;
+  busy_[ring_head_] = o.busy;
   ring_head_ = (ring_head_ + 1) % ring_cap_;
   ++ring_total_;
   PublishGaugesLocked(r);
@@ -298,24 +394,6 @@ bool RoundStats::Ingest(const void* data, size_t len) {
   return true;
 }
 
-bool RoundStats::LastCompleted(RoundRec* out) {
-  std::lock_guard<std::mutex> lk(mu_);
-  if (ring_total_ == 0) return false;
-  *out = ring_[(ring_head_ + ring_cap_ - 1) % ring_cap_];
-  return true;
-}
-
-int64_t RoundStats::completed_total() {
-  std::lock_guard<std::mutex> lk(mu_);
-  return ring_total_;
-}
-
-int64_t RoundStats::dropped() {
-  std::lock_guard<std::mutex> lk(mu_);
-  int64_t over = ring_total_ - static_cast<int64_t>(ring_cap_);
-  return forced_ + (over > 0 ? over : 0);
-}
-
 std::string RoundStats::SnapshotJson() {
   std::lock_guard<std::mutex> lk(mu_);
   std::string out = "{";
@@ -333,7 +411,7 @@ std::string RoundStats::SnapshotJson() {
   out += ",\"last\":";
   if (ring_total_ > 0) {
     size_t last = (ring_head_ + ring_cap_ - 1) % ring_cap_;
-    AppendRec(&out, ring_[last], &spans_[last]);
+    AppendRec(&out, ring_[last], &spans_[last], &busy_[last]);
   } else {
     out += "null";
   }
@@ -345,7 +423,7 @@ std::string RoundStats::SnapshotJson() {
   for (size_t i = 0; i < n; ++i) {
     if (i) out += ",";
     size_t slot = (start + i) % ring_cap_;
-    AppendRec(&out, ring_[slot], &spans_[slot]);
+    AppendRec(&out, ring_[slot], &spans_[slot], &busy_[slot]);
   }
   out += "]";
   out += ",\"fleet\":{";

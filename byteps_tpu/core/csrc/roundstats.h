@@ -42,6 +42,8 @@
 #include <string>
 #include <vector>
 
+#include "trace.h"  // NowUs
+
 namespace bps {
 
 // Accumulation sites. One entry point (Track) serves every stage so the
@@ -59,6 +61,17 @@ enum RoundStage : int32_t {
   RS_PARK = 8,   // an op parked (server slot busy / undeclared key)
   RS_FRAME = 9,  // one wire frame sent; bytes != 0 marks it fused
   RS_DONE = 10,  // a partition's pull landed (ends a round when balanced)
+  // What one resource of the pipeline was doing (RoundBusy). These never
+  // open a round: a stamp for a round that is not open is dropped.
+  RS_SERVER = 11,   // us = the push frame's residence in the server
+                    //      (received -> ack handed to SendReply), ack-reported
+  RS_CREDIT = 12,   // us = the queue's top was refused for credit
+  RS_PUSHTHR = 13,  // us = a push thread, pop -> kv Request returned
+  RS_SENDBLK = 14,  // us = inside writev / the shm ring's put, request frames
+  RS_RECVTHR = 15,  // us = an ack or pull-response callback, entry -> exit
+  RS_VANRECV = 16,  // us = the van's receive thread, a pull response's
+                    //      header read -> its payload read whole
+  RS_STAGES = 17,
 };
 
 // One round's summary. Packed: this struct IS the heartbeat wire
@@ -94,6 +107,26 @@ struct RoundSpan {
   int64_t push_end_us = 0;    // latest RS_PUSH ack
   int64_t pull_start_us = 0;  // earliest RS_PULL issue (now - us)
   int64_t pull_end_us = 0;    // latest RS_PULL response
+};
+
+// One round's stages and resources as ELAPSED time, on this rank's
+// NowUs() clock. Every Track() call that carries a duration describes the
+// interval [now - us, now]; a `*_span_us` is the length of the UNION of a
+// stage's intervals over the round — the time during which at least one
+// partition was in that stage — where RoundRec's `*_us` is their sum. So
+// span <= elapsed, sum / span is the stage's mean depth, and a window
+// less its span is the time the stage stood empty. Local only, like
+// RoundSpan: beside RoundRec, never in it.
+struct RoundBusy {
+  int64_t span_us[RS_STAGES] = {};  // union per stage (0: no duration seen)
+  int64_t sum_us[RS_STAGES] = {};   // sum, for the stages RoundRec lacks
+  // Elapsed time between the first enqueue and the last pull landed with
+  // NO partition enqueued and unfinished: the core waiting to be fed.
+  int64_t feed_wait_us = 0;
+};
+
+struct RoundInterval {
+  int64_t start, end;
 };
 
 // Heartbeat sub-payload: header + `count` RoundRecs (the rounds
@@ -134,7 +167,10 @@ class RoundStats {
 
   // The one accumulation entry point (no-op unless On()). `round` < 0
   // is ignored — broadcast traffic and pre-round ops carry no round.
-  void Track(int32_t stage, int round, int64_t us = 0, int64_t bytes = 0);
+  // `now_us` is the NowUs() the caller already read for `us` (0: read it
+  // here); a duration is kept as the interval [now_us - us, now_us].
+  void Track(int32_t stage, int round, int64_t us = 0, int64_t bytes = 0,
+             int64_t now_us = 0);
 
   // Fill the heartbeat sub-payload with rounds completed since the
   // last call (at most kMaxWireRecs). Returns false when there is
@@ -155,12 +191,6 @@ class RoundStats {
   // chunks (ISSUE 20) and the scheduler walks them with this.
   static size_t WireSize(const void* data, size_t len);
 
-  // Most recent finalized round (false when none yet).
-  bool LastCompleted(RoundRec* out);
-
-  int64_t completed_total();
-  int64_t dropped();
-
   // Whole-state JSON for bps_round_summary: {"on","role","node_id",
   // "completed_total","dropped","last","rounds":[...]} plus, on ranks
   // that ingested fleet summaries (the scheduler), "fleet" (per-rank
@@ -173,8 +203,14 @@ class RoundStats {
   struct OpenRound {
     RoundRec rec;
     RoundSpan span;
+    RoundBusy busy;        // sum_us and the open/closed ledger while open
     int32_t enqueued = 0;  // RS_ENQ count (0 on roles with no enqueue)
     int32_t done = 0;      // RS_DONE count
+    int64_t open_since_us = 0;  // enqueued > done since then
+    int64_t open_us = 0;        // elapsed time with enqueued > done
+    // Every duration of the round, per stage: ~280 partitions x a dozen
+    // stamps of 16 bytes. A list that reaches kCompactAt is merged in place.
+    std::vector<RoundInterval> iv[RS_STAGES];
   };
 
   struct RankState {
@@ -189,6 +225,7 @@ class RoundStats {
 
   void TryFinalizeLocked();
   void FinalizeLocked(int round);
+  static void ReduceBusy(OpenRound* o);
   void PublishGaugesLocked(const RoundRec& r);
 
   std::atomic<bool> armed_{false};
@@ -204,6 +241,7 @@ class RoundStats {
   int64_t forced_ = 0;              // rounds force-finalized (table cap)
   std::vector<RoundRec> ring_;
   std::vector<RoundSpan> spans_;    // parallel to ring_, same slots
+  std::vector<RoundBusy> busy_;     // likewise
   int64_t wire_sent_total_ = 0;     // rounds already shipped via FillWire
 
   // Fleet aggregation (scheduler; populated by Ingest).
@@ -214,9 +252,28 @@ class RoundStats {
   // heartbeat wire stays byte-identical — tenant identity is control-
   // plane state the scheduler already holds.
   std::map<int, int> node_tenant_;
+};
 
+// One stretch of a thread's time charged to a round as a resource's busy
+// time (RoundBusy): from construction to the end of the scope. A round
+// below 0 — a frame that carries none — costs nothing.
+class RoundBusyScope {
  public:
-  bool HeartbeatSummaryOn() const { return heartbeat_summary_on_; }
+  RoundBusyScope(int32_t stage, int round)
+      : stage_(stage), round_(round),
+        t0_(round >= 0 && RoundStats::Get().On() ? NowUs() : 0) {}
+  ~RoundBusyScope() {
+    if (!t0_) return;
+    const int64_t now = NowUs();
+    RoundStats::Get().Track(stage_, round_, now - t0_, 0, now);
+  }
+  RoundBusyScope(const RoundBusyScope&) = delete;
+  RoundBusyScope& operator=(const RoundBusyScope&) = delete;
+
+ private:
+  int32_t stage_;
+  int round_;
+  int64_t t0_;
 };
 
 // EWMA smoothing for the per-rank baselines (shared with insight.py's

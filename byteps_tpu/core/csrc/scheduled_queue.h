@@ -24,6 +24,8 @@
 #include <queue>
 #include <vector>
 
+#include "roundstats.h"
+
 namespace bps {
 
 inline bool QueueDebug() {
@@ -55,6 +57,7 @@ struct Task {
   // pops bound for the same server into one CMD_MULTI_PUSH frame.
   int server_id = -1;
   bool fusible = false;
+  int round = -1;         // push_pull round, for the round's credit stamp
   std::function<void()> run;
 };
 
@@ -93,20 +96,14 @@ class ScheduledQueue {
   bool Pop(Task* out) {
     std::unique_lock<std::mutex> lk(mu_);
     cv_.wait(lk, [this] {
-      return stopped_ ||
-             (!heap_.empty() &&
-              (inflight_bytes_ == 0 ||
-               inflight_bytes_ + heap_.top().bytes <= budget_));
+      if (stopped_) return true;
+      if (heap_.empty()) return false;
+      if (TopFitsLocked()) return true;
+      NoteRefusedLocked();
+      return false;
     });
     if (stopped_) return false;
-    *out = heap_.top();
-    heap_.pop();
-    inflight_bytes_ += out->bytes;
-    if (QueueDebug()) {
-      fprintf(stderr, "[QDEBUG] pop key=%lld bytes=%lld inflight=%lld "
-              "pending=%zu\n", (long long)out->key, (long long)out->bytes,
-              (long long)inflight_bytes_, heap_.size());
-    }
+    TakeTopLocked(out, "pop", &lk);
     return true;
   }
 
@@ -129,19 +126,12 @@ class ScheduledQueue {
     for (;;) {
       if (stopped_) return false;
       if (!heap_.empty()) {
-        const Task& top = heap_.top();
-        if (!top.fusible) return false;
-        if (inflight_bytes_ > 0 && inflight_bytes_ + top.bytes > budget_)
+        if (!heap_.top().fusible) return false;
+        if (!TopFitsLocked()) {
+          NoteRefusedLocked();
           return false;
-        *out = heap_.top();
-        heap_.pop();
-        inflight_bytes_ += out->bytes;
-        if (QueueDebug()) {
-          fprintf(stderr, "[QDEBUG] pop(fuse) key=%lld bytes=%lld "
-                  "inflight=%lld pending=%zu\n", (long long)out->key,
-                  (long long)out->bytes, (long long)inflight_bytes_,
-                  heap_.size());
         }
+        TakeTopLocked(out, "pop(fuse)", &lk);
         return true;
       }
       if (wait_us <= 0 ||
@@ -187,10 +177,45 @@ class ScheduledQueue {
   int64_t budget_bytes() const { return budget_; }
 
  private:
+  bool TopFitsLocked() const {
+    return inflight_bytes_ == 0 ||
+           inflight_bytes_ + heap_.top().bytes <= budget_;
+  }
+
+  // The top waits for credit from now until a pop admits it: elapsed
+  // time of the queue, whichever popper asked (RoundBusy, RS_CREDIT).
+  void NoteRefusedLocked() {
+    if (refused_since_us_ == 0 && RoundStats::Get().On()) {
+      refused_since_us_ = NowUs();
+    }
+  }
+
+  // Pops the top into `out` and unlocks; the stamp is written unlocked.
+  void TakeTopLocked(Task* out, const char* how,
+                     std::unique_lock<std::mutex>* lk) {
+    *out = heap_.top();
+    heap_.pop();
+    inflight_bytes_ += out->bytes;
+    if (QueueDebug()) {
+      fprintf(stderr, "[QDEBUG] %s key=%lld bytes=%lld inflight=%lld "
+              "pending=%zu\n", how, (long long)out->key,
+              (long long)out->bytes, (long long)inflight_bytes_,
+              heap_.size());
+    }
+    const int64_t since = refused_since_us_;
+    refused_since_us_ = 0;
+    lk->unlock();
+    if (since) {
+      const int64_t now = NowUs();
+      RoundStats::Get().Track(RS_CREDIT, out->round, now - since, 0, now);
+    }
+  }
+
   std::mutex mu_;
   std::condition_variable cv_;
   std::priority_queue<Task, std::vector<Task>, TaskOrder> heap_;
   int64_t budget_;
+  int64_t refused_since_us_ = 0;  // 0: the top is not waiting for credit
   int64_t inflight_bytes_ = 0;
   int64_t seq_ = 0;
   bool stopped_ = false;

@@ -4,6 +4,7 @@
 #include <unistd.h>
 
 #include <algorithm>
+#include <cstdint>
 #include <cstring>
 
 #include "cpu_reducer.h"
@@ -281,7 +282,11 @@ void BytePSServer::Handle(Message&& msg, int fd) {
   // separates "engine busy" from "summation slow" in the fleet view.
   Trace::Get().Instant("s_recv", msg.head.key, msg.head.sender,
                        msg.head.req_id, msg.head.cmd);
-  EnqueueTask(EngineTask{std::move(msg), fd, nullptr, -1});
+  EngineTask task{std::move(msg), fd, nullptr, -1};
+  if (task.msg.head.cmd == CMD_PUSH && RoundStats::Get().On()) {
+    task.recv_us = NowUs();
+  }
+  EnqueueTask(std::move(task));
 }
 
 void BytePSServer::EnqueueTask(EngineTask&& task, int lane) {
@@ -352,6 +357,8 @@ void BytePSServer::HandleMulti(Message&& msg, int fd) {
   batch->subs.resize(count);
   batch->data.resize(count);
   batch->remaining.store(count);
+  const int64_t recv_us =
+      is_push && RoundStats::Get().On() ? NowUs() : 0;
   for (int i = 0; i < count; ++i) {
     const SubHeader& s = table[i];
     BPS_CHECK(s.offset >= 0 && s.len >= 0 &&
@@ -387,6 +394,7 @@ void BytePSServer::HandleMulti(Message&& msg, int fd) {
     t.fd = fd;
     t.batch = batch;
     t.sub_idx = i;
+    t.recv_us = recv_us;
     // Same (tenant, key) hash routing as single frames: all of a key's
     // operations — fused or not — stay totally ordered on one engine
     // thread, and the KeyStore keeps its single-writer invariant.
@@ -1047,6 +1055,10 @@ void BytePSServer::Process(EngineTask&& task) {
             ack.sender = po_->my_id();
             ack.key = h.key;
             ack.req_id = h.req_id;
+            if (task.recv_us) {  // residence so far: received to parked
+              ack.version = static_cast<int32_t>(
+                  std::min<int64_t>(NowUs() - task.recv_us, INT32_MAX));
+            }
             task.replied = true;
             MarkReplied(ks, h.sender, h.req_id, ack);
             SendReply(task, ack);
@@ -1170,6 +1182,15 @@ void BytePSServer::Process(EngineTask&& task) {
       // server_sum vs wire_ack online. Old workers ignore it; old
       // servers send 0, which reads as "all wire" (degrades honestly).
       ack.arg0 = sum_us;
+      // version, as unused on a push ack as arg0 was: the frame's
+      // residence in this server, received whole to here (the engine
+      // queue's wait, a park, the sum, RoundReady and its publish). A
+      // duration, like arg0: the worker places it before the ack on its
+      // own clock. Old workers ignore it, old servers send 0.
+      if (task.recv_us) {
+        ack.version = static_cast<int32_t>(
+            std::min<int64_t>(NowUs() - task.recv_us, INT32_MAX));
+      }
       if (is_async) ack.arg1 = ks->async_pushes;
       // A replayed parked sub-push already acked at park time
       // (ack-on-park above); parking never happens in async mode, so

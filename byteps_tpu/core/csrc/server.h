@@ -123,6 +123,10 @@ class BytePSServer {
     // ORIGINAL request being completed, not a wire duplicate — it must
     // bypass the dedup window its own first arrival recorded.
     bool from_park = false;
+    // NowUs() when the frame had been received whole (a parked task keeps
+    // its first arrival): the push ack reports now - recv_us, the frame's
+    // residence in this server. 0 = RoundStats off.
+    int64_t recv_us = 0;
   };
 
   struct KeyStore {

@@ -33,6 +33,7 @@
 #include "events.h"
 #include "logging.h"
 #include "metrics.h"
+#include "roundstats.h"
 #include "shm_ring.h"
 #include "trace.h"
 
@@ -526,6 +527,13 @@ bool Van::SendV(int fd, const MsgHeader& head, const struct iovec* segs,
   if (Trace::Get().MainOn()) {
     Trace::Get().Instant("wire_send", h.key, -1, h.req_id, h.cmd);
   }
+  // A request frame that carries a round (head.version): the time inside
+  // writev, or the shm ring's put, is the round's send_blocked_us — the
+  // kernel's copy and, once the socket is full, the wait for the receiver
+  // (RoundBusy). The wait for the per-fd send lock above is not in it.
+  const bool round_frame = h.cmd == CMD_PUSH || h.cmd == CMD_PULL ||
+                           h.cmd == CMD_MULTI_PUSH || h.cmd == CMD_MULTI_PULL;
+  RoundBusyScope busy(RS_SENDBLK, round_frame ? h.version : -1);
   bool ok = true;
   for (int send_i = 0; send_i < sends && ok; ++send_i) {
     ok = WriteFrame(fd, h, segs, nsegs, total, payload_len, shm.get());
@@ -648,6 +656,13 @@ static bool ReadFrame(ReadFn&& rd, Message* msg) {
   uint64_t plen = total - sizeof(MsgHeader);
   BPS_CHECK_EQ(plen, static_cast<uint64_t>(msg->head.payload_len))
       << "frame length mismatch";
+  // A pull response carries its round (head.version): this thread from
+  // the header to the payload read whole is the round's van_recv_us — the
+  // buffer's allocation, the copy out of the kernel or the ring and,
+  // where the sender is the slower side, the wait for it (RoundBusy).
+  RoundBusyScope busy(RS_VANRECV, msg->head.cmd == CMD_PULL_RESP
+                                      ? msg->head.version
+                                      : -1);
   if (plen > 0) {
     msg->payload.resize_uninit(plen);  // reader overwrites every byte
     if (!rd(msg->payload.data(), plen)) return false;

@@ -16,6 +16,25 @@
 
 namespace bps {
 
+namespace {
+
+// The ack's report of the server's own time, placed where the worker can
+// see it: the push ran [now - push_us, now], the frame's residence in the
+// server and its decode+sum ended with the ack. A duration, so no clock
+// of the server's is compared with ours; 0 from an older server reads as
+// "all wire".
+void TrackPushAck(int round, int64_t t_push, int64_t payload_len,
+                  int64_t sum_us, int64_t server_us) {
+  const int64_t now = NowUs();
+  const int64_t push_us = now - t_push;
+  RoundStats::Get().Track(RS_PUSH, round, push_us, payload_len, now);
+  RoundStats::Get().Track(RS_SUM, round, std::min(sum_us, push_us), 0, now);
+  RoundStats::Get().Track(RS_SERVER, round, std::min(server_us, push_us), 0,
+                          now);
+}
+
+}  // namespace
+
 thread_local std::vector<BytePSWorker::PushOp>* BytePSWorker::fusion_sink_ =
     nullptr;
 
@@ -440,6 +459,9 @@ void BytePSWorker::RecoverServer(int node_id) {
 void BytePSWorker::PushLoop() {
   Task t;
   while (queue_->Pop(&t)) {
+    // This thread from the pop to the frame handed to the van (a collect
+    // session: to its last flush), charged to the popped task's round.
+    RoundBusyScope busy(RS_PUSHTHR, t.round);
     if (fusion_bytes_ <= 0 || !t.fusible) {
       t.run();
       continue;
@@ -654,6 +676,7 @@ int BytePSWorker::PushPull(int64_t tensor_id, void* ptr, int64_t nelem,
     // sub-partition-size tensors coalesce; full partitions keep their
     // own frames.
     task.fusible = fusion_bytes_ > 0 && task.bytes < fusion_bytes_;
+    task.round = version;
     const int64_t t_enq = NowUs();
     task.run = [this, ctx, p, ptr, esz, version, scale, average,
                 async_mode, handle, t_enq] {
@@ -769,6 +792,7 @@ void BytePSWorker::SendPush(PushOp op) {
       p->server_id, h, op.payload, op.payload_len,
       [this, ctx, p, base, raw_len, version, scale, average, flags,
        handle, t_push, plen](Message&& ack) {
+        RoundBusyScope busy(RS_RECVTHR, version);
         if (ack.head.cmd == CMD_ERROR) {
           // Dead server: fail the handle now with the diagnostic
           // instead of blocking Wait until the heartbeat detector.
@@ -791,13 +815,13 @@ void BytePSWorker::SendPush(PushOp op) {
         Record(p->key, "push", t_push, p->server_id, ack.head.req_id,
                version, plen, raw_len);
         BPS_METRIC_HISTO_OBSERVE("bps_push_us", NowUs() - t_push);
-        // Per-round breakdown: push wall, and the server's own
-        // decode+sum time reported back on the ack (arg0 — a field
-        // CMD_PUSH_ACK never used; old servers leave it 0, which
-        // degrades gracefully to "all wire"). wire_ack = push - sum.
-        RoundStats::Get().Track(RS_PUSH, version, NowUs() - t_push,
-                                plen);
-        RoundStats::Get().Track(RS_SUM, version, ack.head.arg0);
+        // Per-round breakdown: push wall, and the server's own times
+        // reported back on the ack — decode+sum in arg0, the frame's
+        // residence in the server in version (fields CMD_PUSH_ACK never
+        // used; old servers leave them 0, which degrades gracefully to
+        // "all wire"). wire_ack = push - sum.
+        TrackPushAck(version, t_push, plen, ack.head.arg0,
+                     ack.head.version);
         RecTrackAck(p);
         // Async: the ack carries the server's fleet-wide apply count
         // for this key as of OUR push; the pull resp carries it as
@@ -820,6 +844,7 @@ void BytePSWorker::SendPush(PushOp op) {
             p->server_id, ph, nullptr, 0,
             [this, ctx, p, base, raw_len, version, scale, average,
              handle, t_pull, flags, at_push](Message&& resp) {
+              RoundBusyScope busy(RS_RECVTHR, version);
               if (resp.head.cmd == CMD_ERROR) {
                 RecClear(p);
                 RoundStats::Get().Track(RS_DONE, version);
@@ -1010,6 +1035,7 @@ void BytePSWorker::SendFusedPush(int server_id, std::vector<PushOp> ops) {
   MsgHeader h{};
   h.cmd = CMD_MULTI_PUSH;
   h.key = table[0].key;  // stripes/routes the batch like its lead key
+  h.version = table[0].version;  // the lead round, for the van's stamp
   h.arg0 = n;
   // Parity contract unchanged under fusion: both sides count the SUB
   // payload bytes (the table is framing, like headers).
@@ -1061,6 +1087,7 @@ void BytePSWorker::SendFusedPush(int server_id, std::vector<PushOp> ops) {
 void BytePSWorker::OnFusedAck(
     int server_id, const std::shared_ptr<std::vector<PushOp>>& batch,
     int64_t t_push, Message&& ack) {
+  RoundBusyScope busy(RS_RECVTHR, (*batch)[0].version);
   if (ack.head.cmd == CMD_ERROR) {
     FailBatch(batch, std::move(ack));
     return;
@@ -1093,11 +1120,10 @@ void BytePSWorker::OnFusedAck(
            op.version, op.payload_len, op.raw_len);
     BPS_METRIC_HISTO_OBSERVE("bps_push_us", NowUs() - t_push);
     // Per-round breakdown per sub-op: the batched ack carries each
-    // sub-push's server decode+sum time in its sub-header arg0 (the
-    // same contract as the single-frame ack).
-    RoundStats::Get().Track(RS_PUSH, op.version, NowUs() - t_push,
-                            op.payload_len);
-    RoundStats::Get().Track(RS_SUM, op.version, subs[i].arg0);
+    // sub-push's server decode+sum time in its sub-header arg0 and its
+    // residence in version (the same contract as the single-frame ack).
+    TrackPushAck(op.version, t_push, op.payload_len, subs[i].arg0,
+                 subs[i].version);
     (*at_push)[i] = subs[i].arg1;  // async apply count as of our push
     SubHeader& s = table[i];
     s.key = op.p->key;
@@ -1118,6 +1144,7 @@ void BytePSWorker::OnFusedAck(
   MsgHeader h{};
   h.cmd = CMD_MULTI_PULL;
   h.key = table[0].key;
+  h.version = table[0].version;
   h.arg0 = n;
   iovec seg{table.data(), static_cast<size_t>(n) * sizeof(SubHeader)};
   int64_t t_pull = NowUs();
@@ -1138,6 +1165,7 @@ void BytePSWorker::OnFusedPullResp(
     const std::shared_ptr<std::vector<PushOp>>& batch,
     const std::shared_ptr<std::vector<int64_t>>& at_push, int64_t t_pull,
     Message&& resp) {
+  RoundBusyScope busy(RS_RECVTHR, (*batch)[0].version);
   if (resp.head.cmd == CMD_ERROR) {
     FailBatch(batch, std::move(resp));
     return;

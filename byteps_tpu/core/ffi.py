@@ -106,7 +106,8 @@ def _load() -> ctypes.CDLL:
     lib.bps_round_summary.argtypes = [ctypes.c_char_p, ctypes.c_longlong]
     lib.bps_round_summary.restype = ctypes.c_longlong
     lib.bps_round_track.argtypes = [ctypes.c_int, ctypes.c_int,
-                                    ctypes.c_longlong, ctypes.c_longlong]
+                                    ctypes.c_longlong, ctypes.c_longlong,
+                                    ctypes.c_longlong]
     lib.bps_round_track.restype = None
     lib.bps_round_ingest.argtypes = [ctypes.c_char_p, ctypes.c_longlong]
     lib.bps_round_ingest.restype = ctypes.c_int
@@ -213,15 +214,20 @@ def round_summary() -> dict:
 ROUND_STAGES = {
     "enq": 0, "queue": 1, "comp": 2, "push": 3, "sum": 4, "pull": 5,
     "dec": 6, "retry": 7, "park": 8, "frame": 9, "done": 10,
+    # one resource's busy time (RoundBusy); these never open a round
+    "server": 11, "credit": 12, "push_thread": 13, "send_blocked": 14,
+    "recv_thread": 15, "van_recv": 16,
 }
 
 
 def round_track(stage: str, round_no: int, us: int = 0,
-                nbytes: int = 0) -> None:
+                nbytes: int = 0, now_us: int = 0) -> None:
     """Feed one accumulation event into the round-summary ring (the
-    production Track path — used by tests and Python-side reporters)."""
+    production Track path — used by tests and Python-side reporters). A
+    duration is kept as the interval ``[now_us - us, now_us]`` on the
+    core's clock (CLOCK_MONOTONIC microseconds; 0: now)."""
     _load().bps_round_track(ROUND_STAGES[stage], int(round_no), int(us),
-                            int(nbytes))
+                            int(nbytes), int(now_us))
 
 
 def round_ingest(payload: bytes) -> bool:

@@ -1,6 +1,8 @@
 """Worker of tests/test_ps_spans.py: two PS-mode training steps under a
-``jax.profiler`` capture; prints the capture's ``bps.*`` events as one JSON
-line for the test to judge."""
+``jax.profiler`` capture; prints the capture's ``bps.*`` events and the C
+core's round rows as one JSON line for the test to judge.
+``BPS_SPANS_WORKER_ROUNDSTATS`` sets ``BYTEPS_ROUNDSTATS_ON`` for this process
+alone, where the fleet's other roles got another value."""
 
 import glob
 import json
@@ -22,6 +24,9 @@ from byteps_tpu.jax.training import make_train_step  # noqa: E402
 
 def main() -> int:
     trace_dir = os.environ["BPS_SPANS_DIR"]
+    if "BPS_SPANS_WORKER_ROUNDSTATS" in os.environ:
+        os.environ["BYTEPS_ROUNDSTATS_ON"] = os.environ[
+            "BPS_SPANS_WORKER_ROUNDSTATS"]
     bps.init()
     try:
         def loss_fn(params, batch):
@@ -51,6 +56,8 @@ def main() -> int:
         finally:
             jax.profiler.stop_trace()
         mono.append(time.monotonic_ns())
+        from byteps_tpu.core import ffi
+        rounds = ffi.round_summary()["rounds"]
     finally:
         bps.shutdown()
     (xplane,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
@@ -61,7 +68,7 @@ def main() -> int:
                "stats": {k: v for k, v in ev.stats}}
               for plane in data.planes for i, line in enumerate(plane.lines)
               for ev in line.events if ev.name.startswith("bps.")]
-    print(json.dumps({"events": events, "mono_ns": mono}))
+    print(json.dumps({"events": events, "mono_ns": mono, "rounds": rounds}))
     return 0
 
 

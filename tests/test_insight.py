@@ -248,6 +248,177 @@ def test_round_stamps_stay_off_the_heartbeat_wire():
                 "pull_window_us"} & set(fleet_rec)
 
 
+# --- the round's stages and resources as elapsed time (RoundBusy) -----------
+
+# an arbitrary instant on the core's clock; every event below is placed by hand
+_T = 7_000_000_000
+_SPAN_OF = {"queue": "queue_span_us", "comp": "comp_span_us",
+            "push": "push_span_us", "sum": "sum_span_us",
+            "pull": "pull_span_us", "dec": "dec_span_us"}
+_RESOURCE_OF = {"credit": ("credit_blocked_us", None),
+                "push_thread": ("push_thread_us", "push_thread_sum_us"),
+                "send_blocked": ("send_blocked_us", "send_blocked_sum_us"),
+                "server": ("server_span_us", "server_us"),
+                "recv_thread": ("recv_thread_us", "recv_thread_sum_us"),
+                "van_recv": ("van_recv_us", None)}
+_SPANS = tuple(_SPAN_OF.values()) + tuple(
+    union for union, _ in _RESOURCE_OF.values()) + ("feed_wait_us",)
+
+
+def _interval(ffi, stage, r, start, end):
+    """One duration of ``stage``: [start, end] from ``_T``."""
+    ffi.round_track(stage, r, end - start, 0, _T + end)
+
+
+@pytest.mark.parametrize("stage", sorted(_SPAN_OF))
+def test_stage_span_is_the_union_of_its_intervals(stage):
+    """Two overlapping intervals and a disjoint one: the sum adds the three
+    lengths, the span is the length of their union, worked by hand."""
+    from byteps_tpu.core import ffi
+
+    r = 5_000_000 + 10 * sorted(_SPAN_OF).index(stage)
+    ffi.round_track("enq", r, 0, 0, _T)
+    _interval(ffi, stage, r, 100, 600)
+    _interval(ffi, stage, r, 400, 900)      # overlaps the first: 100..900
+    _interval(ffi, stage, r, 2000, 2300)    # apart
+    ffi.round_track("done", r, 0, 0, _T + 3000)
+    rec = _finalized(ffi, r)
+    assert rec["start_us"] == _T and rec["elapsed_us"] == 3000
+    assert rec[stage + "_us"] == 500 + 500 + 300
+    assert rec[_SPAN_OF[stage]] == 800 + 300
+    assert rec[_SPAN_OF[stage]] <= rec[stage + "_us"]
+    for other in _SPANS:
+        assert 0 <= rec[other] <= rec["elapsed_us"]
+        if other != _SPAN_OF[stage]:
+            assert rec[other] == 0
+    if stage == "push":
+        # the window runs over the gap, the span does not: 1100 us of the
+        # 2200 had no push on the wire
+        assert rec["push_window_us"] == 2300 - 100
+        assert rec["push_window_us"] - rec["push_span_us"] == 1100
+
+
+@pytest.mark.parametrize("stage", sorted(_RESOURCE_OF))
+def test_resource_busy_time_sum_and_union(stage):
+    """A resource's stamps: sum and union as for a stage; what lies outside
+    the round's ends does not count; and a stamp opens no round."""
+    from byteps_tpu.core import ffi
+
+    r = 5_100_000 + 10 * sorted(_RESOURCE_OF).index(stage)
+    union, total = _RESOURCE_OF[stage]
+    _interval(ffi, stage, r, 0, 50)     # before any enqueue: dropped
+    ffi.round_track("enq", r, 0, 0, _T + 100)
+    _interval(ffi, stage, r, 0, 300)        # clipped to 100..300
+    _interval(ffi, stage, r, 200, 500)      # overlaps: 100..500
+    _interval(ffi, stage, r, 700, 800)
+    ffi.round_track("done", r, 0, 0, _T + 1000)
+    _interval(ffi, stage, r, 950, 1200)     # clipped to 950..1000
+    rec = _finalized(ffi, r)
+    assert rec["elapsed_us"] == 900
+    assert rec[union] == 400 + 100 + 50
+    if total:
+        assert rec[total] == 300 + 300 + 100 + 250   # as stamped, unclipped
+        assert rec[total] >= rec[union]
+    assert all(row["round"] != r - 1
+               for row in ffi.round_summary()["rounds"])
+    _interval(ffi, stage, r - 1, 0, 50)     # a round nobody enqueued
+    _drive_round(ffi, r + 2, parts=1)
+    _drive_round(ffi, r + 5, parts=1)
+    assert all(row["round"] != r - 1
+               for row in ffi.round_summary()["rounds"])
+
+
+def test_feed_wait_is_the_time_with_no_partition_open():
+    """One partition, a gap, then two at once:
+    ``feed_wait_us`` and the time with a partition open make ``elapsed_us``."""
+    from byteps_tpu.core import ffi
+
+    r = 5_200_000
+    ffi.round_track("enq", r, 0, 0, _T)
+    ffi.round_track("done", r, 0, 0, _T + 1000)    # open 0..1000
+    ffi.round_track("enq", r, 0, 0, _T + 1500)     # nothing open for 500
+    ffi.round_track("enq", r, 0, 0, _T + 1600)
+    ffi.round_track("done", r, 0, 0, _T + 2000)
+    ffi.round_track("done", r, 0, 0, _T + 2500)    # open 1500..2500
+    rec = _finalized(ffi, r)
+    any_open = 1000 + 1000
+    assert rec["elapsed_us"] == 2500 and rec["parts"] == 3
+    assert rec["feed_wait_us"] == 500
+    assert rec["feed_wait_us"] + any_open == rec["elapsed_us"]
+
+
+def test_a_round_without_durations_reports_zeros():
+    """Enqueue, frames and done only: every span and resource field is
+    there and reads 0."""
+    from byteps_tpu.core import ffi
+
+    r = 5_300_000
+    ffi.round_track("enq", r, 0, 0, _T)
+    ffi.round_track("frame", r)
+    ffi.round_track("push", r, 0, 64, _T + 10)     # a push of no length
+    ffi.round_track("done", r, 0, 0, _T + 40)
+    rec = _finalized(ffi, r)
+    assert rec["elapsed_us"] == 40 and rec["feed_wait_us"] == 0
+    for name in _SPANS + ("server_us", "push_thread_sum_us",
+                          "send_blocked_sum_us", "recv_thread_sum_us"):
+        assert rec[name] == 0, name
+
+
+def test_an_ack_from_an_old_server_reads_as_all_wire():
+    """``server_us`` rides the push ack as a duration; 0, from a server that
+    sends nothing there, leaves the whole push to wire and van."""
+    from byteps_tpu.core import ffi
+
+    r = 5_400_000
+    ffi.round_track("enq", r, 0, 0, _T)
+    ffi.round_track("push", r, 900, 64, _T + 1000)
+    ffi.round_track("sum", r, 0, 0, _T + 1000)
+    ffi.round_track("server", r, 0, 0, _T + 1000)
+    ffi.round_track("done", r, 0, 0, _T + 1200)
+    rec = _finalized(ffi, r)
+    assert rec["server_us"] == rec["server_span_us"] == 0
+    assert rec["push_us"] - rec["server_us"] == 900 == rec["wire_ack_us"]
+    # and a new one: the residence lies inside the push it came back on
+    r += 10
+    ffi.round_track("enq", r, 0, 0, _T)
+    ffi.round_track("push", r, 900, 64, _T + 1000)
+    ffi.round_track("server", r, 600, 0, _T + 1000)
+    ffi.round_track("done", r, 0, 0, _T + 1200)
+    rec = _finalized(ffi, r)
+    assert rec["server_us"] == rec["server_span_us"] == 600
+    assert rec["server_span_us"] <= rec["push_span_us"] == 900
+
+
+def test_spans_stay_off_the_heartbeat_wire():
+    """``RoundBusy`` is local, like the stamps: the wire element keeps its
+    80 bytes and version 1, and a fleet record has none of its fields."""
+    from byteps_tpu.core import ffi
+
+    assert _REC.size == 80 and _VERSION == 1
+    assert ffi.round_ingest(_pack_summary(48, [_pack_rec(12)]))
+    assert not ffi.round_ingest(_pack_summary(48, [_pack_rec(13)],
+                                              version=2))
+    fleet_rec = ffi.round_summary()["fleet"]["48"]["last"]
+    assert fleet_rec["round"] == 12
+    assert not set(_SPANS) & set(fleet_rec)
+    assert "server_us" not in fleet_rec
+
+
+def test_a_long_interval_list_is_merged_in_place():
+    """20,000 durations of one stage in one round (the list is merged at
+    8,192): sum and union as if all had been kept."""
+    from byteps_tpu.core import ffi
+
+    r, n = 5_500_000, 20_000
+    ffi.round_track("enq", r, 0, 0, _T)
+    for k in range(n):      # 30 us every 50: no two touch
+        ffi.round_track("pull", r, 30, 0, _T + 50 * k + 30)
+    ffi.round_track("done", r, 0, 0, _T + 50 * n)
+    rec = _finalized(ffi, r)
+    assert rec["pull_us"] == rec["pull_span_us"] == 30 * n
+    assert rec["elapsed_us"] == 50 * n
+
+
 # --- classification boundaries (pure python) --------------------------------
 
 def _rec(parts=4, queue=0, comp=0, push=0, sum_us=0, pull=0, dec=0,

@@ -164,11 +164,17 @@ def test_scheduler_round_table_matches_workers_and_wire_bound(tmp_path):
         node = str(rec["node_id"])
         local_last = rec["local_last"]
         sched_rec = table[str(local_last["round"])][node]
-        # ... plus, locally only, the round's elapsed-time stamps: they sit
-        # beside the wire struct and never cross the heartbeat.
+        # ... plus, locally only, the round's elapsed-time stamps and its
+        # stages and resources as elapsed time: they sit beside the wire
+        # struct and never cross the heartbeat.
         assert set(local_last) - set(sched_rec) == {
             "start_us", "elapsed_us", "push_offset_us", "push_window_us",
-            "pull_offset_us", "pull_window_us"}
+            "pull_offset_us", "pull_window_us", "queue_span_us",
+            "comp_span_us", "push_span_us", "sum_span_us", "pull_span_us",
+            "dec_span_us", "feed_wait_us", "credit_blocked_us",
+            "push_thread_us", "push_thread_sum_us", "send_blocked_us",
+            "send_blocked_sum_us", "server_us", "server_span_us",
+            "recv_thread_us", "recv_thread_sum_us", "van_recv_us"}
         assert sched_rec == {k: local_last[k] for k in sched_rec}, (
             sched_rec, local_last)
         # /metrics gauges mirror the same record (monitor.top's view).

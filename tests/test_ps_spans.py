@@ -6,6 +6,9 @@ import json
 import os
 import re
 
+import pytest
+
+from benchmark.layers import roundbusy
 from byteps_tpu.jax import ps
 from tests.ps_utils import REPO, run_topology
 
@@ -24,18 +27,24 @@ def test_span_tables_agree():
     assert tuple(documented) == ps.SPANS
 
 
+def _fleet(tmp_path, **env):
+    (out,) = run_topology(
+        1, 1, WORKER, extra={
+            "BYTEPS_PS_MODE": "ps", "BYTEPS_FORCE_DISTRIBUTED": "1",
+            "BPS_SPANS_DIR": str(tmp_path / "trace"),
+            "XLA_FLAGS": "--xla_force_host_platform_device_count=1", **env})
+    return json.loads(out.strip().splitlines()[-1])
+
+
 def test_a_ps_step_writes_the_eight_spans(tmp_path):
     """1 worker + 1 server on loopback, two traced steps: every span twice;
     the three step spans on the caller's line; push_pull and its five
     children together on another (the bridge thread), the children inside
     it, in order, without overlap; the three stats on push_pull, with
-    ``mono_ns`` on the C core's clock, and ``stage_stats`` on stage."""
-    (out,) = run_topology(
-        1, 1, WORKER, extra={
-            "BYTEPS_PS_MODE": "ps", "BYTEPS_FORCE_DISTRIBUTED": "1",
-            "BPS_SPANS_DIR": str(tmp_path / "trace"),
-            "XLA_FLAGS": "--xla_force_host_platform_device_count=1"})
-    found = json.loads(out.strip().splitlines()[-1])
+    ``mono_ns`` on the C core's clock, and ``stage_stats`` on stage. The two
+    rounds the core has closed by then carry their stages and resources as
+    elapsed time, and each lies inside its step's push_pull span."""
+    found = _fleet(tmp_path)
     events = found["events"]
     assert {e["plane"] for e in events} == {"/host:CPU"}
     by_name = {name: [e for e in events if e["name"] == name]
@@ -77,3 +86,47 @@ def test_a_ps_step_writes_the_eight_spans(tmp_path):
     drift = ((second["stats"]["mono_ns"] - second["start_ns"])
              - (first["stats"]["mono_ns"] - first["start_ns"]))
     assert abs(drift) < 1_000_000
+
+    # three steps ran, so the core has closed the first two rounds: the
+    # compiling step's and the first traced step's
+    rounds = found["rounds"]
+    assert [r["round"] for r in rounds] == [0, 1]
+    for r in rounds:
+        assert r["parts"] == 2
+        for union, total in roundbusy.STAGES.values():
+            assert 0 <= r[union] <= r["elapsed_us"], union
+            if total:
+                assert r[total] >= r[union], union
+        assert 0 <= r["feed_wait_us"] <= r["elapsed_us"]
+        assert r["server_span_us"] <= r["push_span_us"]
+        assert r["server_us"] <= r["push_us"]
+        # what two partitions over loopback TCP cannot do in no time at all
+        for name in ("push_span_us", "pull_span_us", "server_us",
+                     "push_thread_us", "send_blocked_us", "recv_thread_us"):
+            assert r[name] > 0, name
+    # round 1 is the first traced step's: through mono_ns its ends lie
+    # inside that step's bps.ps.push_pull
+    (row,) = roundbusy.align(rounds[1:], [
+        (first["start_ns"], first["dur_ns"], first["stats"]["mono_ns"])])
+    assert row["round"] == 1
+    assert row["start_margin_ms"] >= 0 and row["end_margin_ms"] >= 0
+
+
+@pytest.mark.parametrize("worker_on, server_on", [("1", "0"), ("0", "1")])
+def test_rounds_complete_against_a_peer_without_the_stamps(
+        tmp_path, worker_on, server_on):
+    """A server that sends 0 where the ack carries its times (one with the
+    stamps off does, as one from before them), and a worker that reads
+    nothing there: the steps complete either way, and the worker that looks
+    reports ``server_us`` 0 — all wire."""
+    found = _fleet(tmp_path, BYTEPS_ROUNDSTATS_ON=server_on,
+                   BPS_SPANS_WORKER_ROUNDSTATS=worker_on)
+    assert len([e for e in found["events"]
+                if e["name"] == ps.SPAN_PUSH_PULL]) == 2
+    if worker_on == "0":
+        assert found["rounds"] == []
+        return
+    assert [r["round"] for r in found["rounds"]] == [0, 1]
+    for r in found["rounds"]:
+        assert r["server_us"] == r["server_span_us"] == r["sum_us"] == 0
+        assert r["push_us"] > 0 and r["wire_ack_us"] == r["push_us"]
