@@ -77,6 +77,10 @@ enum Command : int32_t {
                          // data path; payload = shm segment name, arg0 =
                          // per-direction ring bytes. Never reaches upper
                          // layers.
+  CMD_SHM_ACK = 38,      // van-internal: the acceptor's answer to the
+                         // hello, over the socket (arg0 = 1 the ring is
+                         // mapped and carries every later frame, 0
+                         // refused: the connection stays on TCP).
   // Small-tensor fusion (BYTEPS_FUSION_BYTES): many sub-partition-size
   // operations for ONE server coalesced into a single frame. Payload =
   // arg0 x SubHeader table + gathered sub-payloads (offset/len per

@@ -173,6 +173,14 @@ static int64_t RxNowUs() {
 // loopback with ZERO userspace relay cost — the scaling/overlap benches
 // set it so fleet goodput is link-bound, not host-bound (verified: a
 // 12.5 MB/s cap measures 12.6 MB/s on this kernel's loopback).
+static uint64_t PacingRate() {
+  static const uint64_t kPace = [] {
+    const char* v = getenv("BYTEPS_PACING_RATE");
+    return v ? static_cast<uint64_t>(atoll(v)) : 0ull;
+  }();
+  return kPace;
+}
+
 static void SizeSocketBuffers(int fd) {
   static const int kBuf = [] {
     const char* v = getenv("BYTEPS_SOCKET_BUF");
@@ -182,24 +190,20 @@ static void SizeSocketBuffers(int fd) {
     setsockopt(fd, SOL_SOCKET, SO_SNDBUF, &kBuf, sizeof(kBuf));
     setsockopt(fd, SOL_SOCKET, SO_RCVBUF, &kBuf, sizeof(kBuf));
   }
-  static const uint64_t kPace = [] {
-    const char* v = getenv("BYTEPS_PACING_RATE");
-    return v ? static_cast<uint64_t>(atoll(v)) : 0ull;
-  }();
-  if (kPace > 0) {
+  if (PacingRate() > 0) {
 #ifdef SO_MAX_PACING_RATE
     // The kernel reads an unsigned 32-bit (or 64-bit on newer kernels)
     // rate; pass 32-bit for widest compatibility, saturating at 4 GB/s
     // (far above any rate worth pacing to).
-    uint32_t rate = kPace > 0xFFFFFFFFull
+    uint32_t rate = PacingRate() > 0xFFFFFFFFull
                         ? 0xFFFFFFFFu
-                        : static_cast<uint32_t>(kPace);
+                        : static_cast<uint32_t>(PacingRate());
     setsockopt(fd, SOL_SOCKET, SO_MAX_PACING_RATE, &rate, sizeof(rate));
 #endif
   }
 }
 
-// --- shared-memory data path (BYTEPS_VAN_TYPE=shm) --------------------------
+// --- shared-memory data path (a peer on this host) --------------------------
 
 struct Van::ShmConn {
   ShmHeader* hdr = nullptr;
@@ -215,15 +219,13 @@ struct Van::ShmConn {
   // — tmpfs memory leaked host-wide. A second unlink is ENOENT, so the
   // connector unlinking again at teardown is always safe.
   std::string name;
-  // The fd number has TWO standing user threads on an shm connection —
-  // the idle TCP recv thread (EOF watch) and the shm recv thread (which
-  // passes fd to handlers that may reply on it) — plus, on the connector
-  // side only, a third transient user: the OfferShm thread while its
-  // hello send is in flight (set at registration). ::close only when the
-  // LAST user is done — closing while any user still touches the fd
-  // would let the kernel reuse the number for a fresh accept and route
-  // stale writes to an unrelated peer (the fd-reuse race CloseConn's
-  // contract exists to prevent).
+  // The fd number has TWO user threads on an shm connection — the idle
+  // TCP recv thread (EOF watch) and the shm recv thread (which passes fd
+  // to handlers that may reply on it). ::close only when the LAST user
+  // is done — closing while the other still touches the fd would let the
+  // kernel reuse the number for a fresh accept and route stale writes to
+  // an unrelated peer (the fd-reuse race CloseConn's contract exists to
+  // prevent).
   std::atomic<int> fd_users{2};
 
   ~ShmConn() {
@@ -232,12 +234,27 @@ struct Van::ShmConn {
   }
 };
 
-static bool ShmEnabled() {
+// The transport is derived per connection (Connect / AttachShm); the one
+// override is BYTEPS_VAN_TYPE=tcp, which keeps every connection of this
+// process on its socket — the wire a remote peer gets, for tests and
+// benchmarks on a host where every peer is local. Any other value
+// (`shm`, unset) means derived.
+static bool ForceTcp() {
   static const bool on = [] {
     const char* v = getenv("BYTEPS_VAN_TYPE");
-    return v && strcmp(v, "shm") == 0;
+    return v && strcmp(v, "tcp") == 0;
   }();
   return on;
+}
+
+// Why this process keeps a connection on its socket whatever the peer's
+// address: the override, or kernel pacing (SO_MAX_PACING_RATE is a
+// property of the socket — a ring cannot pace, and the variable exists
+// to make loopback behave like a shaped DCN link). nullptr = no reason.
+static const char* KeepsSocket() {
+  if (ForceTcp()) return "BYTEPS_VAN_TYPE=tcp";
+  if (PacingRate() > 0) return "BYTEPS_PACING_RATE paces the socket";
+  return nullptr;
 }
 
 static uint32_t ShmRingBytes() {
@@ -347,6 +364,7 @@ int Van::Connect(const std::string& host, int port, int max_attempts) {
   hints.ai_family = AF_INET;
   hints.ai_socktype = SOCK_STREAM;
   std::string port_s = std::to_string(port);
+  bool offer = true;  // false once an offer of this call went unanswered
   // Retry: the peer may not have bound its listener yet (startup races are
   // normal — the reference's ps-lite retries its scheduler dial the same way).
   for (int attempt = 0; attempt < max_attempts; ++attempt) {
@@ -357,15 +375,47 @@ int Van::Connect(const std::string& host, int port, int max_attempts) {
     }
     int fd = ::socket(res->ai_family, res->ai_socktype, res->ai_protocol);
     if (fd >= 0 && ::connect(fd, res->ai_addr, res->ai_addrlen) == 0) {
-      bool same_host = ShmEnabled() && IsLocalAddr(res->ai_addr);
+      const bool local = IsLocalAddr(res->ai_addr);
       freeaddrinfo(res);
+      res = nullptr;
       int one = 1;
       setsockopt(fd, IPPROTO_TCP, TCP_NODELAY, &one, sizeof(one));
       SizeSocketBuffers(fd);
-      // send_mu_ entry + TCP recv thread first: the shm recv loop may
-      // dispatch a handler that replies on this fd immediately.
-      auto smu = StartRecvThread(fd);
-      if (same_host) OfferShm(fd, smu);
+      // The transport, from what this connection shows: a peer on this
+      // host is offered the ring, unless the socket was asked to pace or
+      // the override holds. Nobody else knows the fd yet, so the offer
+      // and its answer have the socket to themselves and no caller frame
+      // can race the decision.
+      const char* why_tcp = KeepsSocket();
+      if (!why_tcp && !local) why_tcp = "remote peer";
+      if (!why_tcp && !offer) why_tcp = "ring offer unanswered";
+      std::shared_ptr<ShmConn> ring;
+      if (!why_tcp) {
+        Offer ans = OfferShm(fd, &ring);
+        if (ans == Offer::kRedial) {
+          // The peer may still map the segment later and move to the
+          // ring alone: this socket cannot be trusted either way. Dial
+          // again, this time without an offer (not a new attempt).
+          ::close(fd);
+          offer = false;
+          --attempt;
+          continue;
+        }
+        if (ans == Offer::kTcp) why_tcp = "ring refused";
+      }
+      if (!StartRecvThread(fd, ring)) {  // the van is stopping
+        ::close(fd);
+        return -1;
+      }
+      if (ring) {
+        BPS_METRIC_COUNTER_ADD("bps_van_conns_shm_total", 1);
+        BPS_LOG(DEBUG) << "van fd=" << fd << " data path -> shm ring "
+                       << ring->name << " (" << ring->cap << " B/dir)";
+      } else {
+        BPS_METRIC_COUNTER_ADD("bps_van_conns_tcp_total", 1);
+        BPS_LOG(DEBUG) << "van fd=" << fd << " data path -> tcp socket ("
+                       << why_tcp << ")";
+      }
       return fd;
     }
     if (fd >= 0) ::close(fd);
@@ -604,7 +654,7 @@ bool Van::WriteFrame(int fd, MsgHeader& h, const struct iovec* segs,
   return true;
 }
 
-std::shared_ptr<std::mutex> Van::StartRecvThread(int fd) {
+bool Van::StartRecvThread(int fd, std::shared_ptr<ShmConn> ring) {
   auto smu = std::make_shared<std::mutex>();
   auto tx = std::make_shared<TxState>();
   {
@@ -615,10 +665,17 @@ std::shared_ptr<std::mutex> Van::StartRecvThread(int fd) {
               conn_idx.fetch_add(1);
   }
   std::lock_guard<std::mutex> lk(mu_);
+  if (stop_.load()) return false;
   send_mu_[fd] = smu;
   tx_[fd] = tx;
-  threads_.emplace_back([this, fd] { RecvLoop(fd); });
-  return smu;
+  threads_.emplace_back([this, fd, ring] { RecvLoop(fd, ring); });
+  if (ring) {
+    // Registered with the send mutex: the connection's first Send already
+    // finds its ring, and the socket below it stays idle (the EOF watch).
+    shm_conns_[fd] = ring;
+    threads_.emplace_back([this, fd, ring] { ShmRecvLoop(fd, ring); });
+  }
+  return true;
 }
 
 void Van::AcceptLoop() {
@@ -636,7 +693,7 @@ void Van::AcceptLoop() {
     int one = 1;
     setsockopt(fd, IPPROTO_TCP, TCP_NODELAY, &one, sizeof(one));
     SizeSocketBuffers(fd);
-    StartRecvThread(fd);
+    if (!StartRecvThread(fd, nullptr)) ::close(fd);
   }
   // The accept thread owns the listening fd's close (Stop only shuts it
   // down, so no other thread can race this close with a blocked accept).
@@ -756,16 +813,19 @@ void Van::DispatchFrame(Message&& msg, int fd, RxState* rx) {
   }
   if (msg.head.cmd == CMD_SHM_HELLO) {
     // Van-internal: the peer created a shm segment for this connection.
-    // From here on the socket carries no frames; it stays open purely
-    // as the peer-death signal (EOF in RecvLoop).
-    AttachShm(fd, msg);
+    // Once accepted the socket carries no frames; it stays open purely
+    // as the peer-death signal (EOF in RecvLoop). Refused, it goes on
+    // carrying them.
+    AttachShm(fd, msg, rx);
     return;
   }
+  if (msg.head.cmd == CMD_SHM_ACK) return;  // only Connect reads answers
   handler_(std::move(msg), fd);
 }
 
-void Van::RecvLoop(int fd) {
+void Van::RecvLoop(int fd, std::shared_ptr<ShmConn> ring) {
   RxState rx;
+  rx.ring = std::move(ring);
   while (!stop_.load()) {
     Message msg;
     if (!ReadFrame([fd](void* b, size_t n) { return RecvAll(fd, b, n); },
@@ -773,49 +833,83 @@ void Van::RecvLoop(int fd) {
       break;
     DispatchFrame(std::move(msg), fd, &rx);
   }
+  if (rx.ring) {
+    // This socket was a ring's peer-death watch, and the peer's last
+    // frames may still be in the ring: it wrote them before it closed the
+    // socket (the scheduler's SHUTDOWN broadcast ahead of its exit), but
+    // the EOF travels beside them, not behind. Closing the ring lets its
+    // consumer drain what is there; that thread then reports the loss and
+    // closes the connection — the order one byte stream would have given.
+    ShmCloseBoth(rx.ring->hdr);
+    if (rx.ring->fd_users.fetch_sub(1) == 1) ::close(fd);
+    return;
+  }
   // A live-van exit means the PEER went away (EOF / reset), not Stop():
   // let the upper layer fail that peer's outstanding requests now.
   if (!stop_.load() && disconnect_cb_) disconnect_cb_(fd);
   CloseConn(fd);
 }
 
-// Connector side: create the segment, announce it over the socket, start
-// consuming the inbound ring. Any failure leaves the connection on plain
-// TCP (no hello sent, peer never knows).
-bool Van::OfferShm(int fd, const std::shared_ptr<std::mutex>& smu) {
+// One van-internal frame written straight to the socket (never the ring,
+// never the chaos layer): the ring offer and its answer.
+static bool SendRaw(int fd, MsgHeader& h, const void* payload, size_t len) {
+  h.payload_len = static_cast<int64_t>(len);
+  uint64_t total = sizeof(MsgHeader) + len;
+  return SendAll(fd, &total, sizeof(total)) && SendAll(fd, &h, sizeof(h)) &&
+         (len == 0 || SendAll(fd, payload, len));
+}
+
+// How long the connector waits for the acceptor's answer. The acceptor's
+// receive thread for this connection is new and has nothing else to do,
+// so an answer takes microseconds; the limit is for a peer that is
+// stopped, or is not a van at all.
+constexpr int kOfferAnswerSec = 5;
+
+// Connector side, from Connect, before any other thread knows the fd:
+// create the segment, offer it over the socket, read the answer. Only
+// kRing fills `out`. Every other outcome is one WARNING and one
+// bps_van_shm_fallback_total, and leaves no segment behind (the conn's
+// destructor unmaps and unlinks).
+Van::Offer Van::OfferShm(int fd, std::shared_ptr<ShmConn>* out) {
   static std::atomic<uint32_t> seq{0};
   char name[64];
   snprintf(name, sizeof(name), "/bpsvan_%d_%d_%u", getpid(), fd,
            seq.fetch_add(1));
   uint32_t cap = ShmRingBytes();
   size_t map_len = sizeof(ShmHeader) + 2 * static_cast<size_t>(cap);
+  auto fallback = [fd](const std::string& what, Offer o) {
+    BPS_METRIC_COUNTER_ADD("bps_van_shm_fallback_total", 1);
+    BPS_LOG(WARNING) << "van fd=" << fd << ": " << what
+                     << (o == Offer::kRedial
+                             ? "; dialling again, on TCP"
+                             : "; the connection stays on TCP");
+    return o;
+  };
   int sfd = shm_open(name, O_CREAT | O_EXCL | O_RDWR, 0600);
   if (sfd < 0) {
-    BPS_LOG(WARNING) << "shm_open(" << name << ") failed: "
-                     << strerror(errno) << "; staying on TCP";
-    return false;
+    return fallback(std::string("shm_open(") + name + ") failed: " +
+                        strerror(errno), Offer::kTcp);
   }
   // posix_fallocate, not ftruncate: tmpfs enforces its size limit at
   // page-fault time, so a merely-truncated segment on a small /dev/shm
-  // (Docker default: 64 MB) would SIGBUS mid-memcpy after the hello had
-  // already committed the peer to the ring. Reserving the pages up
-  // front turns overcommit into a clean stay-on-TCP fallback here.
+  // (Docker default: 64 MB) would SIGBUS mid-memcpy after the peer had
+  // accepted the ring. Reserving the pages up front turns overcommit
+  // into a clean stay-on-TCP fallback here.
   int ferr = posix_fallocate(sfd, 0, static_cast<off_t>(map_len));
   if (ferr != 0) {
     ::close(sfd);
     shm_unlink(name);
-    BPS_LOG(WARNING) << "shm reserve (" << map_len << " B) failed: "
-                     << strerror(ferr) << "; staying on TCP";
-    return false;
+    return fallback("shm reserve (" + std::to_string(map_len) +
+                        " B) failed: " + strerror(ferr),
+                    Offer::kTcp);
   }
   void* mm = mmap(nullptr, map_len, PROT_READ | PROT_WRITE, MAP_SHARED,
                   sfd, 0);
   ::close(sfd);
   if (mm == MAP_FAILED) {
     shm_unlink(name);
-    BPS_LOG(WARNING) << "mmap shm failed: " << strerror(errno)
-                     << "; staying on TCP";
-    return false;
+    return fallback(std::string("mmap shm failed: ") + strerror(errno),
+                    Offer::kTcp);
   }
   auto conn = std::make_shared<ShmConn>();
   conn->name = name;
@@ -829,73 +923,56 @@ bool Van::OfferShm(int fd, const std::shared_ptr<std::mutex>& smu) {
   conn->out_ring = ShmRingData(conn->hdr, 0);
   conn->in_ring = ShmRingData(conn->hdr, 1);
 
-  // Register BEFORE sending the hello, under an identity check on the
-  // send mutex: if the peer died during shm setup above, the TCP recv
-  // thread's CloseConn already erased this fd and closed it — the number
-  // may already belong to a NEW connection (whose StartRecvThread
-  // re-inserted the same key with a FRESH mutex, which is why key
-  // presence alone is not enough). Writing the hello, or registering the
-  // ring, against a reused fd would corrupt an unrelated connection;
-  // bail and let the conn dtor unmap + unlink instead. Once registered,
-  // this thread holds a third fd_users reference, so the fd cannot be
-  // closed (hence not reused) while the hello send below is in flight.
-  {
-    std::lock_guard<std::mutex> lk(mu_);
-    auto it = send_mu_.find(fd);
-    if (stop_.load() || it == send_mu_.end() || it->second != smu)
-      return false;  // conn dtor unmaps + unlinks
-    conn->fd_users.store(3);  // TCP recv + shm recv + this hello send
-    shm_conns_[fd] = conn;
-    threads_.emplace_back([this, fd, conn] { ShmRecvLoop(fd, conn); });
-  }
   MsgHeader h{};
   h.cmd = CMD_SHM_HELLO;
-  int64_t plen = static_cast<int64_t>(strlen(name));
-  h.payload_len = plen;
   h.arg0 = cap;
-  uint64_t total = sizeof(MsgHeader) + static_cast<uint64_t>(plen);
-  // Raw socket send: the ONLY frame this socket will ever carry —
-  // Connect has not returned the fd to callers yet, and any concurrent
-  // internal Send already routes through the just-registered ring, so
-  // the TCP byte stream stays exclusively ours. A dead peer surfaces as
-  // a send failure; the TCP recv thread's EOF handling then tears the
-  // ring down through the normal path.
-  bool sent = SendAll(fd, &total, sizeof(total)) &&
-              SendAll(fd, &h, sizeof(h)) &&
-              SendAll(fd, name, static_cast<size_t>(plen));
-  if (conn->fd_users.fetch_sub(1) == 1) ::close(fd);
-  if (!sent) {
-    BPS_LOG(WARNING) << "shm hello send failed on fd=" << fd
-                     << "; peer-loss teardown will reap the ring";
-    return false;
+  timeval tv{kOfferAnswerSec, 0};
+  setsockopt(fd, SOL_SOCKET, SO_RCVTIMEO, &tv, sizeof(tv));
+  uint64_t total = 0;
+  MsgHeader ans{};
+  bool answered = SendRaw(fd, h, name, strlen(name)) &&
+                  RecvAll(fd, &total, sizeof(total)) &&
+                  total == sizeof(MsgHeader) &&
+                  RecvAll(fd, &ans, sizeof(ans)) &&
+                  ans.cmd == CMD_SHM_ACK;
+  tv = timeval{0, 0};
+  setsockopt(fd, SOL_SOCKET, SO_RCVTIMEO, &tv, sizeof(tv));
+  if (!answered) {
+    return fallback("the peer gave no answer to the shm ring offer",
+                    Offer::kRedial);
   }
-  BPS_LOG(DEBUG) << "van fd=" << fd << " data path -> shm ring " << name
-                 << " (" << cap << " B/dir)";
-  return true;
+  if (ans.arg0 != 1) {
+    return fallback("the peer refused the shm ring offer (its log says why)",
+                    Offer::kTcp);
+  }
+  *out = std::move(conn);
+  return Offer::kRing;
 }
 
-// Acceptor side, invoked from the connection's TCP recv thread.
-void Van::AttachShm(int fd, const Message& hello) {
+// Map the segment a hello names, acceptor's view. nullptr with `why` set
+// when this process cannot or will not use it.
+std::shared_ptr<Van::ShmConn> Van::MapOfferedRing(const Message& hello,
+                                                  std::string* why) {
+  if (const char* keeps = KeepsSocket()) {
+    *why = keeps;
+    return nullptr;
+  }
   std::string name(hello.payload.data(), hello.payload.size());
   uint32_t cap = static_cast<uint32_t>(hello.head.arg0);
   // Wrap-correctness invariant (power of two) plus the same 1<<30 upper
   // clamp the connector's ShmRingBytes enforces — a hello above it cannot
   // have come from a healthy peer.
   if (cap == 0 || (cap & (cap - 1)) != 0 || cap > (1u << 30)) {
-    BPS_LOG(WARNING) << "shm hello with invalid ring capacity " << cap
-                     << "; dropping connection";
-    ::shutdown(fd, SHUT_RDWR);
-    return;
+    *why = "invalid ring capacity " + std::to_string(cap);
+    return nullptr;
   }
   size_t map_len = sizeof(ShmHeader) + 2 * static_cast<size_t>(cap);
   int sfd = shm_open(name.c_str(), O_RDWR, 0600);
   if (sfd < 0) {
-    // Peer committed to the ring; without it this connection is dead.
-    // Close the socket — the peer's EOF handling fails it fast.
-    BPS_LOG(WARNING) << "shm_open(" << name << ") failed: "
-                   << strerror(errno) << "; dropping connection";
-    ::shutdown(fd, SHUT_RDWR);
-    return;
+    // The peers do not share /dev/shm after all (a port-forward, an IPC
+    // namespace of its own), or the name is not a segment.
+    *why = "shm_open(" + name + ") failed: " + strerror(errno);
+    return nullptr;
   }
   // The connector fallocated map_len before sending the hello, so a
   // smaller object means truncation/mismatch — mapping it would SIGBUS on
@@ -903,25 +980,21 @@ void Van::AttachShm(int fd, const Message& hello) {
   struct stat st {};
   if (fstat(sfd, &st) != 0 ||
       static_cast<size_t>(st.st_size) < map_len) {
-    BPS_LOG(WARNING) << "shm segment " << name << " size " << st.st_size
-                     << " < expected " << map_len
-                     << "; dropping connection";
+    *why = "shm segment " + name + " size " + std::to_string(st.st_size) +
+           " < expected " + std::to_string(map_len);
     ::close(sfd);
-    ::shutdown(fd, SHUT_RDWR);
-    return;
+    return nullptr;
   }
   void* mm = mmap(nullptr, map_len, PROT_READ | PROT_WRITE, MAP_SHARED,
                   sfd, 0);
   ::close(sfd);
-  shm_unlink(name.c_str());  // both sides mapped or dying; name done
+  shm_unlink(name.c_str());  // both sides mapped or refusing; name done
   if (mm == MAP_FAILED ||
       reinterpret_cast<ShmHeader*>(mm)->magic != kShmMagic ||
       reinterpret_cast<ShmHeader*>(mm)->ring_bytes != cap) {
-    BPS_LOG(WARNING) << "shm map/validate failed for " << name
-                   << "; dropping connection";
     if (mm != MAP_FAILED) munmap(mm, map_len);
-    ::shutdown(fd, SHUT_RDWR);
-    return;
+    *why = "shm map/validate failed for " + name;
+    return nullptr;
   }
   auto conn = std::make_shared<ShmConn>();
   conn->hdr = reinterpret_cast<ShmHeader*>(mm);
@@ -931,19 +1004,55 @@ void Van::AttachShm(int fd, const Message& hello) {
   conn->in = &conn->hdr->dir[0];
   conn->out_ring = ShmRingData(conn->hdr, 1);
   conn->in_ring = ShmRingData(conn->hdr, 0);
-  {
-    std::lock_guard<std::mutex> lk(mu_);
-    if (stop_.load()) return;
-    shm_conns_[fd] = conn;
-    threads_.emplace_back([this, fd, conn] { ShmRecvLoop(fd, conn); });
-  }
-  BPS_LOG(DEBUG) << "van fd=" << fd << " accepted shm ring " << name;
+  return conn;
 }
 
-// Frame consumer for one shm connection. Mirrors RecvLoop; the TCP recv
-// thread (still blocked on the idle socket) owns disconnect
-// notification, and the fd itself closes when its last user thread
-// (this loop or the TCP recv thread via CloseConn) releases it.
+// Acceptor side, invoked from the connection's TCP recv thread: map the
+// offered ring or not, and say which over the socket. The connector has
+// sent nothing else and sends nothing until it has the answer, so no
+// handler has seen this fd and nothing of ours is in flight on it.
+void Van::AttachShm(int fd, const Message& hello, RxState* rx) {
+  std::string why;
+  std::shared_ptr<ShmConn> conn = MapOfferedRing(hello, &why);
+  std::shared_ptr<std::mutex> smu;
+  {
+    std::lock_guard<std::mutex> lk(mu_);
+    auto it = send_mu_.find(fd);
+    if (stop_.load() || it == send_mu_.end()) return;
+    smu = it->second;
+    if (conn && shm_conns_.count(fd)) {
+      conn.reset();
+      why = "the connection has a ring already";
+    }
+    if (conn) {
+      shm_conns_[fd] = conn;
+      threads_.emplace_back([this, fd, conn] { ShmRecvLoop(fd, conn); });
+    }
+  }
+  rx->ring = conn;
+  if (conn) {
+    BPS_LOG(DEBUG) << "van fd=" << fd << " accepted shm ring "
+                   << std::string(hello.payload.data(), hello.payload.size());
+  } else if (KeepsSocket()) {
+    BPS_LOG(DEBUG) << "van fd=" << fd << " shm ring offer refused: " << why;
+  } else {
+    BPS_LOG(WARNING) << "van fd=" << fd << " shm ring offer refused: " << why
+                     << "; the connection stays on TCP";
+  }
+  MsgHeader ans{};
+  ans.cmd = CMD_SHM_ACK;
+  ans.arg0 = conn ? 1 : 0;
+  std::lock_guard<std::mutex> lk(*smu);
+  // A failed send is a dead peer: the recv thread's EOF tears down.
+  SendRaw(fd, ans, nullptr, 0);
+}
+
+// Frame consumer for one shm connection. Mirrors RecvLoop, disconnect
+// notification included: the TCP recv thread (blocked on the idle socket)
+// only closes the ring when the peer goes away, and this loop reports the
+// loss once it has drained what the peer left there. The fd itself closes
+// when its last user thread (this loop or the TCP recv thread) releases
+// it.
 void Van::ShmRecvLoop(int fd, std::shared_ptr<ShmConn> conn) {
   RxState rx;
   while (!stop_.load()) {
@@ -957,14 +1066,21 @@ void Van::ShmRecvLoop(int fd, std::shared_ptr<ShmConn> conn) {
       break;
     DispatchFrame(std::move(msg), fd, &rx);
   }
+  // The ring is closed and drained: the peer went away (the socket's
+  // thread saw its EOF and closed the ring), or closed the ring itself.
+  // As at RecvLoop's exit, a live van lets the upper layer fail that
+  // peer's outstanding requests now.
+  if (!stop_.load() && disconnect_cb_) disconnect_cb_(fd);
+  CloseConn(fd);
   if (conn->fd_users.fetch_sub(1) == 1) ::close(fd);
 }
 
 // Connection fds are CLOSED only by their owning recv thread (via
 // CloseConn at RecvLoop exit) — for shm connections, by whichever of
-// the TCP and shm recv threads finishes LAST. Other threads may only
-// shutdown() them. This avoids the close-vs-blocked-recv (and
-// dispatch-after-close) fd-reuse races.
+// the TCP and shm recv threads finishes LAST, each releasing its own
+// fd_users reference at its exit. Other threads may only shutdown()
+// them. This avoids the close-vs-blocked-recv (and dispatch-after-close)
+// fd-reuse races.
 void Van::CloseConn(int fd) {
   std::shared_ptr<ShmConn> shm;
   {
@@ -978,10 +1094,11 @@ void Van::CloseConn(int fd) {
     if (send_mu_.erase(fd) && !shm) ::close(fd);
   }
   // Outside mu_: wakes the shm recv thread (and any blocked producer in
-  // the peer process); the mapping lives until the last shared_ptr drops.
+  // the peer process) and the socket's EOF watch, here and in the peer;
+  // the mapping lives until the last shared_ptr drops.
   if (shm) {
     ShmCloseBoth(shm->hdr);
-    if (shm->fd_users.fetch_sub(1) == 1) ::close(fd);
+    ::shutdown(fd, SHUT_RDWR);
   }
 }
 

@@ -8,15 +8,19 @@
 // PS-scale [O(100) conns] well within epoll-free territory), writev-based
 // gather sends so payload bytes are never copied into a staging buffer.
 //
-// Second transport (BYTEPS_VAN_TYPE=shm): the role the reference's non-TCP
-// vans play (ZMQVan ipc:// and rdma_van.h — SURVEY.md §2.4) is "don't pay
-// the network stack when you don't have to". For loopback peers the
-// connector negotiates a per-connection POSIX shm segment over the freshly
-// dialled TCP socket (CMD_SHM_HELLO) and both sides move all subsequent
-// frames through lock-free SPSC byte rings (shm_ring.h). The TCP socket
-// stays open but idle: peer death still surfaces as an EOF on it, so
-// heartbeat-free fast-fail (SetDisconnectHandler) works identically on
-// both transports. Remote peers keep TCP — mixed fleets need no config.
+// Second transport, chosen per connection from what the connection shows:
+// the role the reference's non-TCP vans play (ZMQVan ipc:// and rdma_van.h
+// — SURVEY.md §2.4) is "don't pay the network stack when you don't have
+// to". Where the resolved dial address is on this host, the connector
+// offers a per-connection POSIX shm segment over the freshly dialled TCP
+// socket (CMD_SHM_HELLO), the acceptor maps it and answers (CMD_SHM_ACK),
+// and both sides move all subsequent frames through lock-free SPSC byte
+// rings (shm_ring.h). The TCP socket stays open but idle: peer death
+// still surfaces as an EOF on it, so heartbeat-free fast-fail
+// (SetDisconnectHandler) works identically on both transports. A refused
+// or failed offer leaves the connection on its socket. Remote peers, a
+// socket asked to pace (BYTEPS_PACING_RATE) and BYTEPS_VAN_TYPE=tcp keep
+// TCP — mixed fleets need no config.
 #pragma once
 
 #include <sys/uio.h>
@@ -25,6 +29,7 @@
 #include <functional>
 #include <memory>
 #include <mutex>
+#include <string>
 #include <thread>
 #include <unordered_map>
 #include <vector>
@@ -112,6 +117,9 @@ class Van {
     int64_t last_seq = 0;
     int64_t win_fails = 0;     // CRC failures inside the current window
     int64_t win_start_us = 0;  // window open time (0 = none open yet)
+    // Socket loop only: the ring this connection's frames moved to, which
+    // leaves the socket as that ring's peer-death watch (RecvLoop's exit).
+    std::shared_ptr<ShmConn> ring;
   };
 
   // One framed write on an already-locked connection (transport
@@ -121,11 +129,12 @@ class Van {
                   int nsegs, uint64_t total, int64_t payload_len,
                   ShmConn* shm);
   void AcceptLoop();
-  void RecvLoop(int fd);
-  // Returns the per-fd send mutex it registered — an identity token for
-  // THIS incarnation of the fd (a closed-and-reaccepted fd gets a fresh
-  // mutex), which OfferShm uses to detect fd reuse.
-  std::shared_ptr<std::mutex> StartRecvThread(int fd);
+  void RecvLoop(int fd, std::shared_ptr<ShmConn> ring);
+  // Registers the connection (send mutex, transmit state, `ring` if it
+  // has one) and starts its frame consumers. False, with nothing
+  // registered, once the van is stopping: Stop() has taken the thread
+  // list, and a thread added after that would never be joined.
+  bool StartRecvThread(int fd, std::shared_ptr<ShmConn> ring);
   void ShmRecvLoop(int fd, std::shared_ptr<ShmConn> conn);
   // Shared tail of both recv loops: wire accounting, PS_VERBOSE trace,
   // wire-CRC verification (BYTEPS_WIRE_CRC — a mismatching frame is
@@ -135,10 +144,17 @@ class Van {
   // the caller recv loop's per-connection state (each connection has
   // exactly one frame consumer thread per transport).
   void DispatchFrame(Message&& msg, int fd, RxState* rx);
-  // Connector side; returns false -> stay on TCP. `smu` is the send-mutex
-  // identity StartRecvThread returned for this connection.
-  bool OfferShm(int fd, const std::shared_ptr<std::mutex>& smu);
-  void AttachShm(int fd, const Message& hello);  // acceptor side
+  // How a ring offer ended: the connection's frames move to the ring; it
+  // stays on its socket (refused, or the segment could not be made); or
+  // the answer never came, so this socket is closed and the peer dialled
+  // again without an offer.
+  enum class Offer { kRing, kTcp, kRedial };
+  Offer OfferShm(int fd, std::shared_ptr<ShmConn>* out);  // connector side
+  static std::shared_ptr<ShmConn> MapOfferedRing(const Message& hello,
+                                                 std::string* why);
+  // Acceptor side, on the socket's recv thread; an accepted ring lands in
+  // `rx->ring`.
+  void AttachShm(int fd, const Message& hello, RxState* rx);
 
   Handler handler_;
   std::function<void(int fd)> disconnect_cb_;

@@ -378,7 +378,12 @@ def run_streams_sweep(args) -> None:
            "partition_mb": args.mb, "tensors": args.tensors,
            "rounds": args.rounds, "results": []}
     for streams in sweep:
-        worker_env = {"BYTEPS_VAN_STREAMS": str(streams)}
+        # The sweep is about TCP congestion windows, so it names the TCP
+        # wire: left to derive, the van gives a same-host server a shm
+        # ring — negotiated straight through the delay proxy, which the
+        # frames would then bypass.
+        worker_env = {"BYTEPS_VAN_STREAMS": str(streams),
+                      "BYTEPS_VAN_TYPE": "tcp"}
         server_env = {}
         proxy = None
         if args.delay_ms > 0:
@@ -431,8 +436,9 @@ def run_streams_sweep(args) -> None:
 
 def run_transport_sweep(args) -> None:
     """Goodput per van transport on one host: TCP loopback vs the shm
-    ring data path (BYTEPS_VAN_TYPE=shm — the second transport playing
-    the reference ZMQ-ipc///RDMA role for co-located peers). Same
+    ring data path (what the van derives for a peer on this host — the
+    second transport, playing the reference ZMQ-ipc///RDMA role;
+    BYTEPS_VAN_TYPE=tcp forces the first). Same
     workload, same fleet shape, one topology per transport."""
     out = {"what": "van goodput by transport: identical push_pull "
                    "workload over TCP loopback vs per-connection "
@@ -489,7 +495,7 @@ def main() -> None:
                         "emulator; per-stream cap = window/delay")
     p.add_argument("--transport-sweep", action="store_true",
                    help="run the workload over TCP loopback and the shm "
-                        "ring transport (BYTEPS_VAN_TYPE=shm) and report "
+                        "ring transport (BYTEPS_VAN_TYPE=tcp / shm) and report "
                         "both")
     p.add_argument("--out", default="", help="write sweep JSON here")
     args = p.parse_args()

@@ -102,8 +102,13 @@ def main() -> int:
             tid = w.declare("big", n, "float32", compression="")
             base = rng.standard_normal(n).astype(np.float32)
             arr = np.ascontiguousarray(base * (rank + 1))
+            import time as _t
+            t0 = _t.monotonic()
             h = w.push_pull(tid, arr, average=False)
             w.wait(h)
+            # For a paced fleet (test_pacing_rate_path): the pace held if
+            # this took at least the bytes over the rate.
+            print(f"push_pull_s {_t.monotonic() - t0:.3f}", flush=True)
             scale = sum(r + 1 for r in range(nw))
             np.testing.assert_allclose(arr, base * scale, rtol=1e-4,
                                        atol=1e-5)
@@ -1116,6 +1121,15 @@ def main() -> int:
         else:
             raise SystemExit(f"unknown BPS_TEST_MODE {mode!r}")
 
+        # Which transport this process's dialled connections got (the
+        # van's counters): the transport tests assert it from here and
+        # from the van's DEBUG line, so a silent fallback fails them.
+        import json as _json
+        snap = w.metrics_snapshot()["counters"]
+        print("van_conns " + _json.dumps({
+            k: snap.get(f"bps_van_{n}_total", 0) for k, n in
+            (("shm", "conns_shm"), ("tcp", "conns_tcp"),
+             ("fallback", "shm_fallback"))}), flush=True)
         print(f"worker {rank}: {mode} OK")
         return 0
     finally:

@@ -8,6 +8,7 @@ in the workers. No mock transport anywhere.
 
 from __future__ import annotations
 
+import json
 import os
 import socket
 import subprocess
@@ -98,3 +99,29 @@ def run_topology(num_workers: int, num_servers: int, worker_script: str,
             f"--- {n} exited {rc} ---\n{out}" for n, rc, out in failed)
         raise AssertionError(f"topology processes failed:\n{msgs}")
     return outputs
+
+
+# The van derives a connection's transport (ISSUE 38): on this sandbox
+# every peer is local, so a fleet that says nothing runs on shm rings. A
+# test that means the TCP wire — the one a remote peer gets — says so.
+TCP = {"BYTEPS_VAN_TYPE": "tcp"}
+
+
+def van_conns(out: str) -> Dict[str, int]:
+    """The worker's `van_conns {...}` line (tests/_ps_worker.py): its
+    dialled connections by transport and the ring offers that fell back
+    (the van's counters)."""
+    line = [ln for ln in out.splitlines() if ln.startswith("van_conns ")][-1]
+    return json.loads(line[len("van_conns "):])
+
+
+def assert_transport(outs: List[str], want: str, dialled: int) -> None:
+    """Every worker dialled `dialled` connections, all on `want`, by the
+    counters AND by the van's DEBUG line — needs BYTEPS_LOG_LEVEL=DEBUG."""
+    other = "tcp" if want == "shm" else "shm"
+    mark = {"shm": "data path -> shm ring", "tcp": "data path -> tcp socket"}
+    for o in outs:
+        assert van_conns(o) == {want: dialled, other: 0, "fallback": 0}, \
+            o[-2000:]
+        assert o.count(mark[want]) == dialled, o[-2000:]
+        assert mark[other] not in o, o[-2000:]

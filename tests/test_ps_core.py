@@ -10,12 +10,19 @@ import os
 
 import pytest
 
-from tests.ps_utils import run_topology
+from tests.ps_utils import TCP, assert_transport, run_topology
 
 WORKER = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                       "_ps_worker.py")
 
 pytestmark = pytest.mark.ps  # slow-ish multiprocess tests
+
+# The van derives a connection's transport (ISSUE 38): on this sandbox
+# every peer is local, so a fleet that says nothing runs on shm rings.
+# The tests that mean the TCP wire — the one a remote peer gets — say so.
+# What the van derives, and a refused offer, are tier-1's
+# tests/test_van_transport.py.
+TRANSPORTS = [("tcp", TCP), ("shm", {"BYTEPS_VAN_TYPE": "shm"})]
 
 
 def test_basic_sum_2workers_1server():
@@ -39,12 +46,29 @@ def test_broadcast_from_root():
     run_topology(3, 2, WORKER, mode="broadcast")
 
 
-def test_pacing_rate_path():
+@pytest.mark.parametrize("rate,mode,floor_s", [
+    (1_000_000_000, "basic", 0.0),
+    (2_000_000, "multipart", 0.5),
+], ids=["generous", "holds"])
+def test_pacing_rate_path(rate, mode, floor_s):
     """BYTEPS_PACING_RATE (kernel TCP pacing — the production
-    NIC-fair-share knob) must leave numerics intact;
-    the rate is generous so the test costs no wall time."""
-    run_topology(2, 1, WORKER, mode="basic",
-                 extra={"BYTEPS_PACING_RATE": "1000000000"})
+    NIC-fair-share knob) must leave numerics intact, and a connection
+    that asked for it keeps its socket: a ring cannot pace, so no ring is
+    offered to the local peer (transport asserted from the counters and
+    the DEBUG line). `generous` costs no wall time; `holds` moves 1.2 MB
+    each way at 2 MB/s and must take the time the pace says (0.6 s a
+    direction; over a ring it would take milliseconds)."""
+    outs = run_topology(2, 1, WORKER, mode=mode,
+                        extra={"BYTEPS_PACING_RATE": str(rate),
+                               "BYTEPS_PARTITION_BYTES": "65536",
+                               "BYTEPS_LOG_LEVEL": "DEBUG"}, timeout=120.0)
+    assert_transport(outs, "tcp", dialled=2)  # scheduler + server
+    for o in outs:
+        assert "BYTEPS_PACING_RATE paces the socket" in o, o[-2000:]
+        if floor_s:
+            took = float([l for l in o.splitlines()
+                          if l.startswith("push_pull_s ")][0].split()[1])
+            assert took >= floor_s, (took, o[-2000:])
 
 
 def test_rebroadcast_delivers_fresh_values():
@@ -111,22 +135,32 @@ def test_fleet_outlives_finalize_grace():
     run_topology(2, 1, WORKER, mode="slow_job", timeout=120.0)
 
 
-def test_no_recv_thread_send_deadlock():
+@pytest.mark.parametrize("name,transport", TRANSPORTS,
+                         ids=[t[0] for t in TRANSPORTS])
+def test_no_recv_thread_send_deadlock(name, transport):
     """Sustained multi-round MB-scale traffic over tiny (64 KiB) kernel
-    socket buffers: response callbacks must run off the van recv threads
-    (key-hashed executor), else the push->pull chain's send from the recv
-    thread wedges both directions once the buffers fill."""
+    socket buffers, or rings as small: response callbacks must run off
+    the van recv threads (key-hashed executor), else the push->pull
+    chain's send from the recv thread wedges both directions once the
+    buffers fill."""
     run_topology(2, 1, WORKER, mode="congested",
-                 extra={"BYTEPS_SOCKET_BUF": "65536"}, timeout=180.0)
+                 extra={"BYTEPS_SOCKET_BUF": "65536",
+                        "BYTEPS_SHM_RING_BYTES": "65536", **transport},
+                 timeout=180.0)
 
 
-def test_van_striped_streams():
+@pytest.mark.parametrize("name,transport", TRANSPORTS,
+                         ids=[t[0] for t in TRANSPORTS])
+def test_van_striped_streams(name, transport):
     """BYTEPS_VAN_STREAMS=4: each worker dials 4 striped connections per
-    server; keys hash onto streams (per-key ordering preserved). The
-    multi-round MB-scale workload must aggregate exactly, as with one
-    stream."""
-    run_topology(2, 1, WORKER, mode="congested",
-                 extra={"BYTEPS_VAN_STREAMS": "4"}, timeout=180.0)
+    server (each its own socket, or its own ring); keys hash onto streams
+    (per-key ordering preserved). The multi-round MB-scale workload must
+    aggregate exactly, as with one stream."""
+    outs = run_topology(2, 1, WORKER, mode="congested",
+                        extra={"BYTEPS_VAN_STREAMS": "4",
+                               "BYTEPS_LOG_LEVEL": "DEBUG", **transport},
+                        timeout=180.0)
+    assert_transport(outs, name, dialled=5)  # scheduler + 4 stripes
 
 
 def test_van_shm_transport():
@@ -402,8 +436,10 @@ def test_failure_detection_dead_server():
 def test_dead_server_fast_fail():
     """VERDICT r2 #9: a push into a dead connection must fail its handle
     in seconds with the server named — the worker-side peer-lost hook +
-    send-failure check, not the 30 s heartbeat detector."""
-    _run_dead_server_fast_fail(None)
+    send-failure check, not the 30 s heartbeat detector. Over the TCP
+    wire, said by name; test_van_shm_dead_server_fast_fail is the ring's
+    case."""
+    _run_dead_server_fast_fail(TCP)
 
 
 def test_jax_ps_single_worker_force_distributed():
@@ -634,14 +670,18 @@ def test_topology_clean_under_asan():
         "LD_PRELOAD": libasan,
         "ASAN_OPTIONS": "detect_leaks=0:abort_on_error=1",
     }
-    run_topology(2, 1, WORKER, mode="basic", extra=extra, timeout=120)
+    # These three legs pin the TCP wire (the one a remote peer gets); the
+    # ring's leg follows, and the no-shutdown worker runs on the derived
+    # default, so both transports stay under the sanitizer.
+    run_topology(2, 1, WORKER, mode="basic", extra={**extra, **TCP},
+                 timeout=120)
     # Round-2 concurrency paths: parked pushes + replay (deep
     # pipelining), the cached compressed reply + both-ways codec path,
     # and the byte-credit admission window.
-    run_topology(2, 1, WORKER, mode="deep_pipeline", extra=extra,
-                 timeout=120)
-    run_topology(2, 1, WORKER, mode="pull_compress", extra=extra,
-                 timeout=180)
+    run_topology(2, 1, WORKER, mode="deep_pipeline",
+                 extra={**extra, **TCP}, timeout=120)
+    run_topology(2, 1, WORKER, mode="pull_compress",
+                 extra={**extra, **TCP}, timeout=180)
     # shm ring transport: MB-scale sustained traffic checks every ring
     # offset/wrap memcpy under ASan redzones.
     run_topology(2, 1, WORKER, mode="congested",
@@ -672,12 +712,15 @@ def test_topology_clean_under_tsan():
         "LD_PRELOAD": libtsan,
         "TSAN_OPTIONS": "halt_on_error=1:report_bugs=1",
     }
-    run_topology(2, 1, WORKER, mode="basic", extra=extra, timeout=240)
-    run_topology(2, 1, WORKER, mode="deep_pipeline", extra=extra,
+    # The TCP wire by name; the ring's leg follows.
+    run_topology(2, 1, WORKER, mode="basic", extra={**extra, **TCP},
                  timeout=240)
+    run_topology(2, 1, WORKER, mode="deep_pipeline",
+                 extra={**extra, **TCP}, timeout=240)
     # shm transport: the in-process interplay (send threads vs the shm
-    # recv thread vs CloseConn/Stop teardown, fd_users refcount) is
-    # TSan-visible; the cross-process ring words themselves are not —
-    # their protocol is the seq_cst Dekker pairing in shm_ring.h.
+    # recv thread vs CloseConn/Stop teardown, fd_users refcount, the
+    # offer and its answer inside Connect) is TSan-visible; the
+    # cross-process ring words themselves are not — their protocol is
+    # the seq_cst Dekker pairing in shm_ring.h.
     run_topology(2, 1, WORKER, mode="congested",
                  extra={**extra, "BYTEPS_VAN_TYPE": "shm"}, timeout=240)
