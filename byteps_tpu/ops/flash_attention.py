@@ -8,8 +8,8 @@ broadcasted iota. Per /opt/skills/guides/pallas_guide.md patterns: grid
 iterates (batch*heads, q_block, k_block) with the k_block dimension
 innermost so VMEM scratch carries the running (m, l, acc) across K steps.
 
-Layout contract matches byteps_tpu.parallel attention: [batch, seq, heads,
-head_dim]; any dtype (bf16 hot path), f32 accumulation.
+Layout as byteps_tpu.parallel attention: [batch, seq, heads, head_dim], v's
+head_dim (and the output's) free to differ from q's and k's; f32 accumulation.
 
 The backward pass is a pair of Pallas kernels (dQ, and dK/dV) doing the
 standard flash-attention blockwise recompute from the forward's saved
@@ -293,7 +293,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
         raise ValueError("window requires causal=True (sliding-window "
                          "attention is a causal scheme)")
     b, s_q, h, d = q.shape
-    s_k = k.shape[1]
+    s_k, d_v = k.shape[1], v.shape[-1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     bq = min(block_q, max(s_q, 8))
@@ -331,18 +331,18 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
     scratch = [
         _VMEM((bq, 128), jnp.float32),  # m (value in lane 0)
         _VMEM((bq, 128), jnp.float32),  # l (value in lane 0)
-        _VMEM((bq, d), jnp.float32),    # acc
+        _VMEM((bq, d_v), jnp.float32),  # acc
     ]
     vmem = pl.BlockSpec
     in_specs = [
         vmem((1, bq, d), lambda bh, qi, ki: (bh, qi, 0),
              memory_space=_VMEM),
         vmem((1, bk, d), kv_index, memory_space=_VMEM),
-        vmem((1, bk, d), kv_index, memory_space=_VMEM),
+        vmem((1, bk, d_v), kv_index, memory_space=_VMEM),
     ]
-    o_spec = vmem((1, bq, d), lambda bh, qi, ki: (bh, qi, 0),
+    o_spec = vmem((1, bq, d_v), lambda bh, qi, ki: (bh, qi, 0),
                   memory_space=_VMEM)
-    o_shape = jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype)
+    o_shape = jax.ShapeDtypeStruct((b * h, sq_p, d_v), q.dtype)
     common = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
                   seq_k=s_k, window=window,
                   nk_total=nk if window is not None else None)
@@ -491,7 +491,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res,
 def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                     interpret, window):
     b, s_q, h, d = q.shape
-    s_k = k.shape[1]
+    s_k, d_v = k.shape[1], v.shape[-1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
 
@@ -535,8 +535,8 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         return (bh, ki, 0)
 
     def specs(q_index, k_index):
-        rows = [(bq, d, q_index), (bk, d, k_index), (bk, d, k_index),
-                (bq, d, q_index), (bq, 8, q_index), (bq, 8, q_index)]
+        rows = [(bq, d, q_index), (bk, d, k_index), (bk, d_v, k_index),
+                (bq, d_v, q_index), (bq, 8, q_index), (bq, 8, q_index)]
         return [pl.BlockSpec((1, n, w), index, memory_space=_VMEM)
                 for n, w, index in rows]
 
@@ -560,14 +560,14 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         in_specs=specs(q_of_dkv, k_of_dkv),
         out_specs=[
             pl.BlockSpec((1, bk, d), k_of_dkv, memory_space=_VMEM),
-            pl.BlockSpec((1, bk, d), k_of_dkv, memory_space=_VMEM),
+            pl.BlockSpec((1, bk, d_v), k_of_dkv, memory_space=_VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sk_p, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk_p, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h, sk_p, d_v), v.dtype),
         ],
         scratch_shapes=[_VMEM((bk, d), jnp.float32),
-                        _VMEM((bk, d), jnp.float32)],
+                        _VMEM((bk, d_v), jnp.float32)],
         interpret=interpret,
         name=DKV_NAME,
     )(qq, kk, vv, dd_o, lse, dd)

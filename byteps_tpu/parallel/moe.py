@@ -229,6 +229,10 @@ def dropless_moe_ffn(
     dtype=jnp.bfloat16,
     first_expert: int = 0,
     norm_topk: bool = False,
+    scoring: str = "softmax",
+    select_bias: Optional[jax.Array] = None,
+    norm_eps: float = 0.0,
+    routed_scale: float = 1.0,
 ):
     """Dropless top-k SwiGLU expert layer: every token reaches its
     ``top_k`` experts.
@@ -239,7 +243,13 @@ def dropless_moe_ffn(
     at the highest matmul precision (which experts a token reaches must not
     turn on bf16 rounding): softmax over all E, ``lax.top_k``, and as
     combine weights the raw probabilities, or with ``norm_topk`` those
-    renormalised over the chosen k. The assignments are sorted by expert
+    renormalised over the chosen k. ``scoring="sigmoid"`` is DeepSeek-V3's
+    gate: the scores are ``sigmoid(logits)``, one expert's independent of
+    the others'; ``select_bias`` [E] is added to the scores for the choice
+    of the k and never to a weight (it balances the load without a loss);
+    ``norm_eps`` joins the renormalisation's denominator (the source's
+    1e-20) and ``routed_scale`` multiplies the weights. At their defaults
+    the four change nothing. The assignments are sorted by expert
     (stable), the tokens gathered into that order,
     ``down(silu(gate(x)) * up(x))`` computed as three grouped matmuls with
     ``dtype`` operands and float32 accumulation, and the rows un-permuted
@@ -256,7 +266,8 @@ def dropless_moe_ffn(
 
     Returns ``(y [T, D] in x's dtype, load_balance, z_loss, counts)``:
     ``load_balance`` = E / (T k) * sum_e counts_e * mean_t p[t, e] (1 when
-    routing is uniform; gradient through p only), ``z_loss`` =
+    routing is uniform; gradient through p only; sigmoid scores are divided
+    by their sum over E for it), ``z_loss`` =
     mean_t logsumexp(logits_t)^2, ``counts`` [E] int32 the assignments per
     expert over all E (they sum to T k).
     """
@@ -267,14 +278,27 @@ def dropless_moe_ffn(
     if not 0 <= first_expert <= e - held:
         raise ValueError(f"experts {first_expert}..{first_expert + held - 1} "
                          f"are not among the router's {e}")
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"scoring must be softmax|sigmoid, got {scoring!r}")
     with jax.named_scope(ROUTE_SCOPE):
         logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)       # [T, E]
         lse = jax.nn.logsumexp(logits, axis=-1)
-        probs = jnp.exp(logits - lse[:, None])
-        top_w, top_e = lax.top_k(probs, top_k)                  # [T, k]
+        if scoring == "softmax":
+            probs = scores = jnp.exp(logits - lse[:, None])
+        else:
+            scores = jax.nn.sigmoid(logits)
+            probs = scores / scores.sum(axis=-1, keepdims=True)
+        if select_bias is None:
+            top_w, top_e = lax.top_k(scores, top_k)             # [T, k]
+        else:
+            _, top_e = lax.top_k(scores + select_bias, top_k)
+            top_w = jnp.take_along_axis(scores, top_e, axis=-1)
         if norm_topk:
-            top_w = top_w / top_w.sum(axis=-1, keepdims=True)
+            total = top_w.sum(axis=-1, keepdims=True)
+            top_w = top_w / (total + norm_eps if norm_eps else total)
+        if routed_scale != 1.0:
+            top_w = top_w * routed_scale
         flat_e = sort_key = top_e.reshape(-1)                   # [T k]
         if held < e:
             # held experts 0..H-1 in their order, every other one as H: last

@@ -141,26 +141,26 @@ KERNEL_SCOPE, XLA_SCOPE = "bps.attn.kernel", "bps.attn.xla"
 KERNEL_SITES = "bps_attention_kernel_sites_total"
 XLA_SITES = "bps_attention_xla_sites_total"
 
-# The shortest sequence and the head widths at which the Pallas kernel was
-# measured against the XLA form on a TPU v5e, bf16, forward + backward, and
-# won (PERF.md section 3, kernels: my chip runs, PR 36): causal 16 x 128 at
-# s 512 / 1024 / 2048 / 4096 1.20 / 1.56 / 2.25 / 3.16 ms against 2.03 /
-# 3.85 / 7.11 / 13.77, causal 12 x 64 at s 512 / 1024 / 2048 1.73 / 2.09 /
-# 3.05 against 2.91 / 5.57 / 10.29.
+# The shortest sequence and the head widths (queries and keys, values) at
+# which the Pallas kernel was measured against the XLA form on a TPU v5e,
+# bf16, forward + backward, and won (PERF.md section 3, kernels; PR 36):
+# causal 16 x 128 at s 512 / 1024 / 2048 / 4096 1.20 / 1.56 / 2.25 / 3.16 ms
+# against 2.03 / 3.85 / 7.11 / 13.77, causal 12 x 64 at s 512 / 1024 / 2048
+# 1.73 / 2.09 / 3.05 against 2.91 / 5.57 / 10.29; 192 / 128 in PR 39.
 KERNEL_MIN_SEQ = 512
-KERNEL_HEAD_DIMS = (64, 128)
+KERNEL_HEAD_DIMS = ((64, 64), (128, 128), (192, 128))
 
 
 def attention_form(backend: str, s_q: int, s_k: int, head_dim: int,
-                   causal: bool, dtype) -> str:
-    """``"kernel"`` or ``"xla"``: how ``full_attention`` computes these
-    operands. One algorithm, two forms of it; which is faster turns on the
-    sequence length, and the kernel exists for the TPU alone. The XLA form
-    writes float32 ``[batch, heads, s_q, s_k]`` scores to HBM and reads
-    them back; the kernel keeps a block of them in VMEM."""
+                   causal: bool, dtype, value_dim: int = 0) -> str:
+    """``"kernel"`` or ``"xla"``: how ``full_attention`` computes operands of
+    these shapes (``value_dim``: v's width where it is not q's and k's). One
+    algorithm, two forms: the XLA form writes float32 ``[batch, heads, s_q,
+    s_k]`` scores to HBM, the kernel (TPU only) keeps a block in VMEM."""
     if backend != "tpu" or jnp.dtype(dtype) != jnp.bfloat16:
         return "xla"
-    if head_dim not in KERNEL_HEAD_DIMS or min(s_q, s_k) < KERNEL_MIN_SEQ:
+    widths = (head_dim, value_dim or head_dim)
+    if widths not in KERNEL_HEAD_DIMS or min(s_q, s_k) < KERNEL_MIN_SEQ:
         return "xla"
     return "kernel" if causal else "xla"
 
@@ -176,7 +176,7 @@ def full_attention(q, k, v, *, causal: bool = False,
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if attention_form(jax.default_backend(), q.shape[1], k.shape[1],
-                      q.shape[-1], causal, q.dtype) == "kernel":
+                      q.shape[-1], causal, q.dtype, v.shape[-1]) == "kernel":
         # imported here: a process that never reaches this line (BERT's
         # s128, any CPU run) pays for no kernel library
         # (tests/test_import_footprint.py)

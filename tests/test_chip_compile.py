@@ -76,6 +76,26 @@ def test_flash_kernel_compiles_for_v5e(topo, case, with_grads):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("with_grads", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_kernel_at_two_widths_compiles_for_v5e(topo, with_grads):
+    """The latent layer of the Kimi-Linear cell: 32 heads, keys 192 wide
+    (128 + 64), values 128, s 16384 — Mosaic takes the 192 lanes as they
+    are."""
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16, sharding=one)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, True, 192 ** -0.5, interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if with_grads else fwd
+    text = jax.jit(fn).lower(q, q, v).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.slow
 def test_gpt2_124m_collective_step_compiles_for_one_v5e(topo):
     """The whole chip_smoke phase-1 program — make_train_step, GPT-2 124M,

@@ -175,3 +175,18 @@ def test_interpret_resolution(monkeypatch, backend, asked, want):
     from byteps_tpu.ops.flash_attention import _resolve_interpret
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     assert _resolve_interpret(asked) is want
+
+
+def test_equal_widths_lower_as_before_the_second_width():
+    """PR 39 gave the kernels a value width of their own. With v as wide
+    as q and k the three kernels lower to the text they lowered to at
+    ``3f4a582`` (interpret mode: plain HLO, no source position in it):
+    forward, dQ and dK/dV at 1 x 256 x 2 x 64, causal, blocks of 128."""
+    import hashlib
+
+    x = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.float32)
+    text = jax.jit(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, True, None, 128, 128).sum(),
+        argnums=(0, 1, 2))).lower(x, x, x).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "87ce996fa8e50d2dd795149e030aa8b9f4c1dd8b238dae133638605f1ee2b108")

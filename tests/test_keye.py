@@ -273,3 +273,18 @@ def test_counts_are_sown_only_when_asked_for_and_published():
     assert float(a[0]) == float(b[0])
     for x, y in zip(*(jax.tree_util.tree_leaves(g[1]) for g in (a, b))):
         assert _close(x, y, rtol=1e-6)
+
+
+def test_the_gate_s_new_arguments_leave_the_lowered_step_as_it_was():
+    """PR 39 gave ``dropless_moe_ffn`` a scoring rule, a selection bias, an
+    epsilon and a routed scale. At their defaults the gradient of
+    KeyeTiny's loss (a share: experts 0..1 of 8) lowers to the text it
+    lowered to at ``3f4a582``."""
+    import hashlib
+
+    model, tokens = KeyeTiny(), np.zeros((2, 32), np.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    text = jax.jit(jax.grad(lambda p: keye_loss(
+        model.apply(p, tokens), tokens))).lower(params).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "454cbe32b2173f0eee3d720395bab9d48259ae3313ff10b06aa2fe36adde89e6")

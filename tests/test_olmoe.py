@@ -237,3 +237,17 @@ def test_counts_are_sown_only_when_asked_for_and_published():
         worst)
     assert metrics._py_counters["bps_moe_assignments_total"] == before + 256
     assert publish_moe_stats({}) == {}
+
+
+def test_the_gate_s_new_arguments_leave_the_lowered_step_as_it_was():
+    """PR 39 gave ``dropless_moe_ffn`` a scoring rule, a selection bias, an
+    epsilon and a routed scale. At their defaults the gradient of
+    OlmoeTiny's loss lowers to the text it lowered to at ``3f4a582``."""
+    import hashlib
+
+    model, tokens = OlmoeTiny(), np.zeros((2, 32), np.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    text = jax.jit(jax.grad(lambda p: olmoe_loss(
+        model.apply(p, tokens), tokens))).lower(params).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "29589b7f081a55779b06f19f6d437629ccd485aeae93f00e1a98fdab1f98fb6d")
