@@ -42,10 +42,35 @@ two [C, C] products of float32 operands in three bf16 passes.
 
 Decay, cumulated decay, ``A``, ``T`` and the state are float32; the
 operands of the other products are cast to ``dtype`` (bf16 on the chip)
-and accumulate in float32. The backward pass is ``jax.grad`` through all of
-it, a group of chunks recomputed at a time (``jax.checkpoint``): the scan
-keeps one state a group. A hand-written backward of the recurrence was
-measured against it and lost (PERF.md section 6, PR 39).
+and accumulate in float32.
+
+**One algorithm, two forms of a chunk's operands** (``kda_form``, a pure
+function of the backend and the shapes, as ``ring_attention.py::
+attention_form`` is for exact attention; each counted at trace time). The
+XLA form above, ``_chunk_operands``, is what every CPU run, float32
+``dtype`` and any width but 128 x 128 gets: its pairs of a sub-chunk are a
+float32 ``[.., sub, sub, d_k]`` tensor and its decayed keys ``[.., n, C,
+d_k]``, written to HBM and read back, forward, recomputed and backward. On
+a ``tpu`` backend, bf16 ``dtype``, keys and values 128 wide, heads in
+eights and a chunk of a multiple of 8 up to 128 tokens, the kernel pair of
+``byteps_tpu.ops.kda_chunk`` holds a chunk of all heads in VMEM: every
+pair ``j <= i`` of the chunk one by one in float32 (no product between
+sub-chunks is left), the triangular system by forward substitution in the
+same walk (``T`` itself is never formed: ``W`` and ``U_v`` are), and a
+hand-written backward kernel that solves the transposed system (``dA =
+-T^T dT T^T`` below the diagonal, by back substitution) instead of
+differentiating an inverse's steps. Same guarantees: no positive number
+exponentiated, no clamped decay, float32 wherever the XLA form has it.
+
+The scan has two levels, groups of chunks and the chunks of a group, and
+its backward pass is ``jax.grad`` through both, a group recomputed at a
+time (``jax.checkpoint``): the scan keeps one state a group. The XLA form
+computes a group's operands inside the group (its pair tensor bounds the
+group); the kernel form computes every chunk's operands in one call
+before the scan, so its groups hold the recurrence alone and a group is
+about the square root of the chunks. A hand-written backward of the
+recurrence was measured against ``jax.grad`` and lost (PERF.md section 6,
+PR 39); of the operands, the kernel's is the hand-written one (PR 40).
 """
 
 from __future__ import annotations
@@ -62,6 +87,15 @@ from byteps_tpu.monitor import metrics
 # counter of its call sites at trace time.
 PREP_SCOPE, SCAN_SCOPE = "bps.kda.prep", "bps.kda.scan"
 SCAN_SITES = "bps_kda_scan_sites_total"
+# ... and of those that took the kernel form of a chunk's operands
+KERNEL_SITES = "bps_kda_kernel_sites_total"
+
+# What the kernel of ``byteps_tpu.ops.kda_chunk`` was measured at against
+# the XLA form on a TPU v5e and won (PERF.md section 6, PR 40): keys and
+# values 128 wide (a token of a head is one row of lanes), bf16 operands.
+# Its tiling admits any chunk of whole sublane groups (a multiple of 8) up
+# to a row of lanes (128), and heads in whole sublane groups.
+KERNEL_WIDTH = 128
 
 # float32 operands as three bf16 passes: the triangular system's inverse and
 # the products between sub-chunks need more than the one pass a TPU gives a
@@ -158,6 +192,28 @@ def _chunk_operands(q, k, v, beta, G, sub, dtype):
             jnp.exp(total[..., 0, :]), a_q.astype(dtype))
 
 
+def kda_form(backend: str, heads: int, d_k: int, d_v: int, dtype,
+             chunk: int) -> str:
+    """``"kernel"`` or ``"xla"``: how ``kda_attention`` computes a chunk's
+    operands at these shapes. One algorithm, two forms: the XLA form writes
+    float32 ``[.., sub, sub, d_k]`` pairs and ``[.., n, C, d_k]`` decayed
+    keys to HBM and reads them back, the kernel (TPU only) holds a chunk in
+    VMEM."""
+    if backend != "tpu" or jnp.dtype(dtype) != jnp.bfloat16:
+        return "xla"
+    if (d_k, d_v) != (KERNEL_WIDTH, KERNEL_WIDTH) or heads % 8:
+        return "xla"
+    return "kernel" if chunk % 8 == 0 and chunk <= 128 else "xla"
+
+
+def _divisor(n: int, most: int) -> int:
+    """The largest divisor of ``n`` that is at most ``most`` (at least 1)."""
+    d = max(1, min(n, most))
+    while n % d:
+        d -= 1
+    return d
+
+
 def _recurrence(state, w, u_v, q_g, k_d, gamma, a_q, dtype):
     """The scan over the chunks of a group from ``state`` [b, h, d_k, d_v].
     Leading axis: the chunk; ``w``, ``q_g``, ``k_d`` [g, b, h, C, d_k] and
@@ -196,34 +252,64 @@ def kda_attention(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
                          f"{g.shape}, {v.shape}, {beta.shape}")
     s = q.shape[1]
     metrics.inc_counter(SCAN_SITES)
-    f32 = jnp.float32
-    with jax.named_scope(PREP_SCOPE):
-        G = chunk_log_decay(g, chunk)                   # [b, n, C, h, d_k]
-    with jax.named_scope(SCAN_SCOPE):
-        b, n, _, h, d_k = G.shape
-        # Two levels: groups of chunks, one at a time and recomputed in the
-        # backward pass, and the chunks of a group. A group's operands (the
-        # pairs of a sub-chunk are a [.., sub, sub, d_k] tensor, which the
-        # backward pass writes out) are alive for that group alone; a group
-        # is as many chunks as keep that tensor under 2^26 entries (256 MB).
-        group = max(1, min(n, 2 ** 26 // (b * h * chunk * sub * d_k)))
-        while n % group:
-            group -= 1
+    kernel = kda_form(jax.default_backend(), q.shape[2], q.shape[3],
+                      v.shape[3], dtype, chunk) == "kernel"
+    if kernel:
+        # imported here: a process that never reaches this line (every
+        # other model, any CPU run) pays for no kernel library
+        # (tests/test_import_footprint.py)
+        from byteps_tpu.ops.kda_chunk import chunk_operands
 
-        def grouped(x):              # [b, n, C, h, ...] -> [n / group, b,
-            x = x.reshape(b, n // group, group, *x.shape[2:])    # group, h,
-            return jnp.moveaxis(x, 1, 0).swapaxes(3, 4)          # C, ...]
+        metrics.inc_counter(KERNEL_SITES)
+    f32 = jnp.float32
+    tokens = tuple(chunked(x.astype(f32), chunk) for x in (q, k, v, beta))
+    if not kernel:
+        with jax.named_scope(PREP_SCOPE):
+            G = chunk_log_decay(g, chunk)               # [b, n, C, h, d_k]
+    with jax.named_scope(SCAN_SCOPE):
+        b, n, _, h, d_k = tokens[0].shape
+        # Two levels: groups of chunks, one at a time and recomputed in the
+        # backward pass, and the chunks of a group: the scan keeps one state
+        # a group and a group's states while it is differentiated.
+        if kernel:
+            # Every chunk's operands in one call (the kernel cumulates a
+            # chunk's decay itself), then the chunk leading and heads before
+            # tokens for the scan. The groups hold the recurrence alone: a
+            # group is about the square root of the chunks.
+            group = _divisor(n, math.isqrt(n))
+            w, u_v, q_g, k_d, gamma, a_q = chunk_operands(
+                *tokens, chunked(g.astype(f32), chunk), sub, dtype)
+            xs = tuple(
+                x.reshape(n // group, group, *x.shape[1:]) for x in (
+                    *(x.transpose(1, 0, 3, 2, 4) for x in (
+                        w, u_v, q_g, k_d)), jnp.moveaxis(gamma, 1, 0),
+                    a_q.astype(dtype).transpose(1, 0, 3, 2, 4)))
+
+            def operands_of(xs):
+                return xs
+        else:
+            # A group's operands are alive for that group alone. The pairs
+            # of a sub-chunk are a [.., sub, sub, d_k] tensor, which the
+            # backward pass writes out: a group is as many chunks as keep
+            # that tensor under 2^26 entries (256 MB).
+            group = _divisor(n, 2 ** 26 // (b * h * chunk * sub * d_k))
+
+            def grouped(x):          # [b, n, C, h, ...] -> [n / group, b,
+                x = x.reshape(b, n // group, group, *x.shape[2:])  # group,
+                return jnp.moveaxis(x, 1, 0).swapaxes(3, 4)   # h, C, ...]
+
+            xs = tuple(grouped(x) for x in (*tokens, G))
+
+            def operands_of(xs):
+                return (jnp.moveaxis(x, 1, 0)
+                        for x in _chunk_operands(*xs, sub, dtype))
 
         @jax.checkpoint
         def one_group(state, xs):
-            operands = _chunk_operands(*xs, sub, dtype)  # [b, group, h, ...]
-            return _recurrence(
-                state, *(jnp.moveaxis(x, 1, 0) for x in operands), dtype)
+            return _recurrence(state, *operands_of(xs), dtype)
 
         state = jnp.zeros((b, h, d_k, v.shape[-1]), f32)
-        o = lax.scan(one_group, state, tuple(grouped(x) for x in (
-            *(chunked(x.astype(f32), chunk) for x in (q, k, v, beta)),
-            G)))[1]
+        o = lax.scan(one_group, state, xs)[1]
         # [n / group, group, b, h, C, d_v] -> [b, s, h, d_v]
         return o.transpose(2, 0, 1, 4, 3, 5).reshape(
             b, n * chunk, h, -1)[:, :s]

@@ -96,6 +96,29 @@ def test_flash_kernel_at_two_widths_compiles_for_v5e(topo, with_grads):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("with_grads", [False, True], ids=["fwd", "fwd_bwd"])
+def test_kda_operand_kernels_compile_for_v5e(topo, with_grads):
+    """The linear-attention layers of the Kimi-Linear cell: b 1 x s 8192 as
+    256 chunks of 32 tokens (sub-chunks of 8), 32 heads of 128 x 128, bf16
+    operands out — the forward kernel, and the forward that saves with the
+    hand-written backward kernel through ``jax.grad``."""
+    from byteps_tpu.ops.kda_chunk import chunk_operands
+
+    one = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((1, 256, 32, 32, 128), jnp.float32, sharding=one)
+    beta = jax.ShapeDtypeStruct((1, 256, 32, 32), jnp.float32, sharding=one)
+
+    def fwd(q, k, v, beta, G):
+        return chunk_operands(q, k, v, beta, G, 8, jnp.bfloat16, False)
+
+    def loss(*args):
+        return sum(o.astype(jnp.float32).sum() for o in fwd(*args))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if with_grads else fwd
+    text = jax.jit(fn).lower(x, x, x, beta, x).compile().as_text()
+    assert text.count("tpu_custom_call") == (2 if with_grads else 1)
+
+
 @pytest.mark.slow
 def test_gpt2_124m_collective_step_compiles_for_one_v5e(topo):
     """The whole chip_smoke phase-1 program — make_train_step, GPT-2 124M,

@@ -28,6 +28,7 @@ from byteps_tpu.models.kimi_linear import (KimiSparseMoe, causal_conv,
                                            layer_kinds)
 from byteps_tpu.monitor import metrics
 from byteps_tpu.ops.flash_attention import flash_attention
+import byteps_tpu.parallel.linear_attention as la
 from byteps_tpu.parallel.linear_attention import (SCAN_SITES, kda_attention,
                                                   publish_kda_stats)
 from byteps_tpu.parallel.moe import dropless_moe_ffn, publish_moe_stats
@@ -85,6 +86,16 @@ def _kda_inputs(s, strength, b=2, h=3, d_k=8, d_v=6, seed=0):
             jax.random.normal(ks[5], (b, s, h, d_v)))
 
 
+@pytest.fixture(params=("xla", "kernel"))
+def form(request, monkeypatch):
+    """Both forms of a chunk's operands: the XLA form every CPU run takes,
+    and the kernel of ``ops/kda_chunk.py`` in interpret mode (the rule is
+    told it may: the kernel still sees the CPU and interprets)."""
+    if request.param == "kernel":
+        monkeypatch.setattr(la, "kda_form", lambda *shapes: "kernel")
+    return request.param
+
+
 @pytest.mark.parametrize("s,chunk,sub,strength", [
     (64, 16, 4, 0.1),      # the chunk divides s; a mild decay
     (50, 16, 4, 1.0),      # it does not: 14 zero tokens close the last chunk
@@ -92,10 +103,11 @@ def _kda_inputs(s, strength, b=2, h=3, d_k=8, d_v=6, seed=0):
     (64, 32, 8, 8.0),      # e^-G overflows float32
     (33, 8, 2, 8.0),
 ])
-def test_chunked_scan_is_the_token_recurrence(s, chunk, sub, strength):
+def test_chunked_scan_is_the_token_recurrence(form, s, chunk, sub, strength):
     """Values and all five gradients. 1e-5: nothing discrete; the chunked
     form sums a chunk's pairs in another order than 64 rank-one updates."""
     *args, weight = _kda_inputs(s, strength)
+    before = metrics.counter(la.KERNEL_SITES)
 
     def chunked(*a):
         return kda_attention(*a, chunk=chunk, sub=sub, dtype=jnp.float32)
@@ -107,9 +119,10 @@ def test_chunked_scan_is_the_token_recurrence(s, chunk, sub, strength):
     for g, w in zip(got, want):
         assert bool(jnp.isfinite(g).all())
         assert _rel(g, w) <= 1e-5
+    assert (metrics.counter(la.KERNEL_SITES) > before) == (form == "kernel")
 
 
-def test_a_decay_that_overflows_the_naive_form_is_exact_here():
+def test_a_decay_that_overflows_the_naive_form_is_exact_here(form):
     """The strong cases above are past float32: a chunk's cumulated
     log-decay goes under -88.7, so e^-G, which the product form ``(k e^G)(k
     e^-G)^T`` needs, is inf and that form NaN — the chunked scan above
