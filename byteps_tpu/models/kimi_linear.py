@@ -23,6 +23,10 @@ reaches the model through the decay and the convolutions alone.
 rotary embedding on them (``mla_use_nope``). Causal softmax over keys as
 wide as ``nope + rope`` and values as wide as ``v_dim``
 (``parallel.full_attention``: on the chip the flash kernel at two widths).
+``KimiLatentAttention`` is DeepSeek-V3's layer whole: with ``q_rank`` the
+queries go through a normalised latent of their own, and with
+``rope_theta`` the ``rope`` parts are rotated (``models/joyai.py`` sets
+both; here both are off).
 
 **Expert layer**. ``dropless_moe_ffn`` with sigmoid scores, a selection
 bias that chooses and never weighs, the chosen weights renormalised (+
@@ -39,7 +43,8 @@ decay's two projections and the router in float32 at the highest matmul
 precision (a decay is cumulated over thousands of tokens, a router's
 rounding changes which experts a token reaches). Each half of a block is
 recomputed in the backward pass (``nn.remat``), and so is every block of
-``loss_rows`` rows of the head and the cross-entropy: one block's [rows,
+``loss_rows`` rows of the head and the cross-entropy (``next_token_nll``,
+which ``models/joyai.py`` calls for two streams): one block's [rows,
 vocab] float32 logits are alive at a time. The model returns the per-position
 cross-entropy [batch, seq - 1]; ``kimi_linear_loss`` is its mean. Apply
 with ``mutable=["moe_stats", "kda_stats"]`` for the per-expert counts and
@@ -50,7 +55,7 @@ each KDA layer's most negative cumulated log-decay of a chunk
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable, Sequence
+from typing import Callable, Optional, Sequence
 
 import flax.linen as nn
 import jax
@@ -58,7 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from byteps_tpu.models.llama import LlamaMLP, RMSNorm
+from byteps_tpu.models.llama import LlamaMLP, RMSNorm, _rope
 from byteps_tpu.parallel.linear_attention import (PREP_SCOPE, chunk_log_decay,
                                                   kda_attention)
 from byteps_tpu.parallel.moe import dropless_moe_ffn
@@ -66,6 +71,7 @@ from byteps_tpu.parallel.ring_attention import full_attention
 
 KDA_OUT_SCOPE = "bps.kda.out"          # head norm and output gate
 MLA_ATTEND_SCOPE = "bps.mla.attend"    # around full_attention's own scope
+MLA_PROJ_SCOPE = "bps.mla.proj"        # projections, latent norms, rotation
 SHARED_SCOPE = "bps.moe.shared"        # the shared expert
 HEAD_SCOPE = "bps.lm.head"             # head and cross-entropy, row blocks
 
@@ -167,6 +173,13 @@ class KimiDeltaAttention(nn.Module):
 
 
 class KimiLatentAttention(nn.Module):
+    """DeepSeek-V3's latent attention. ``q_rank``: the queries go through a
+    ``q_rank``-wide RMS-normalised latent (``q_a``, ``q_norm``, ``q_b``)
+    and not one projection ``q``. ``rope_theta``: the last ``rope_dim`` of
+    every query and the shared key part carry a rotary embedding of the
+    row's own position, in interleaved pairs (``models/llama.py::_rope``,
+    float32). Both None is Kimi-Linear's layer."""
+
     heads: int
     nope_dim: int
     rope_dim: int
@@ -174,26 +187,44 @@ class KimiLatentAttention(nn.Module):
     kv_rank: int
     dtype: jnp.dtype = jnp.bfloat16
     eps: float = 1e-5
+    q_rank: Optional[int] = None
+    rope_theta: Optional[float] = None
 
     @nn.compact
     def __call__(self, x):
         b, s, d_model = x.shape
         dense = partial(nn.Dense, use_bias=False, dtype=self.dtype)
         qk_dim = self.nope_dim + self.rope_dim
-        q = dense(self.heads * qk_dim, name="q")(x).reshape(
-            b, s, self.heads, qk_dim)
-        c = dense(self.kv_rank + self.rope_dim, name="kv_a")(x)
-        shared = jnp.broadcast_to(c[:, :, None, self.kv_rank:],
-                                  (b, s, self.heads, self.rope_dim))
-        kv = dense(self.heads * (self.nope_dim + self.v_dim), name="kv_b")(
-            RMSNorm(self.eps, name="kv_norm")(c[..., :self.kv_rank])
-        ).reshape(b, s, self.heads, self.nope_dim + self.v_dim)
-        k = jnp.concatenate([kv[..., :self.nope_dim], shared], axis=-1)
+        with jax.named_scope(MLA_PROJ_SCOPE):
+            if self.q_rank is None:
+                q = dense(self.heads * qk_dim, name="q")(x)
+            else:
+                q = dense(self.heads * qk_dim, name="q_b")(
+                    RMSNorm(self.eps, name="q_norm")(
+                        dense(self.q_rank, name="q_a")(x)))
+            q = q.reshape(b, s, self.heads, qk_dim)
+            c = dense(self.kv_rank + self.rope_dim, name="kv_a")(x)
+            shared = c[:, :, None, self.kv_rank:]
+            if self.rope_theta is not None:
+                positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+                rotate = partial(_rope, positions=positions,
+                                 theta=self.rope_theta, interleaved=True)
+                q = jnp.concatenate([q[..., :self.nope_dim],
+                                     rotate(q[..., self.nope_dim:])], axis=-1)
+                shared = rotate(shared)
+            shared = jnp.broadcast_to(shared,
+                                      (b, s, self.heads, self.rope_dim))
+            kv = dense(self.heads * (self.nope_dim + self.v_dim),
+                       name="kv_b")(
+                RMSNorm(self.eps, name="kv_norm")(c[..., :self.kv_rank])
+            ).reshape(b, s, self.heads, self.nope_dim + self.v_dim)
+            k = jnp.concatenate([kv[..., :self.nope_dim], shared], axis=-1)
         with jax.named_scope(MLA_ATTEND_SCOPE):
             out = full_attention(q, k, kv[..., self.nope_dim:], causal=True,
                                  scale=qk_dim ** -0.5)
-        return dense(d_model, name="o")(
-            out.reshape(b, s, self.heads * self.v_dim))
+        with jax.named_scope(MLA_PROJ_SCOPE):
+            return dense(d_model, name="o")(
+                out.reshape(b, s, self.heads * self.v_dim))
 
 
 class KimiSparseMoe(nn.Module):
@@ -274,6 +305,38 @@ class KimiBlock(nn.Module):
             half(self.mixer, self.eps, name="mixer")(x))
 
 
+def _block_nll(model, h, targets):
+    """[rows, d] and the rows' targets -> their cross-entropy through
+    ``model.lm_head``."""
+    with jax.named_scope(HEAD_SCOPE):
+        logp = jax.nn.log_softmax(model.lm_head(h).astype(jnp.float32))
+        return -jnp.take_along_axis(logp, targets[:, None], axis=-1)[:, 0]
+
+
+def next_token_nll(model, h, tokens, ahead):
+    """The cross-entropy [b, s - ahead] of row i's prediction of token ``i +
+    ahead`` from the normalised hidden rows ``h`` [b, s, d], through
+    ``model.lm_head`` in blocks of ``model.loss_rows`` rows, each recomputed
+    in the backward pass: one block's [rows, vocab] float32 logits are alive
+    at a time. A sequence's last ``ahead`` rows have no target: they get
+    token 0 and are dropped, so that b s rows divide evenly. For any setup()
+    model with those two attributes, and for each of its streams."""
+    b, s, d = h.shape
+    h = h.reshape(b * s, d)
+    targets = jnp.pad(tokens[:, ahead:], ((0, 0), (0, ahead))).reshape(b * s)
+    rows = model.loss_rows if (b * s) % model.loss_rows == 0 else b * s
+    nll = nn.remat(_block_nll, prevent_cse=False)
+    if model.is_initializing() or rows == b * s:
+        out = nll(model, h, targets)
+    else:
+        out = nn.scan(
+            lambda model, _, block: (None, nll(model, *block)),
+            variable_broadcast="params", split_rngs={"params": False})(
+                model, None, (h.reshape(-1, rows, d),
+                              targets.reshape(-1, rows)))[1]
+    return out.reshape(b, s)[:, :s - ahead]
+
+
 class KimiLinearModel(nn.Module):
     """Causal LM. ``tokens`` [batch, seq] -> the next-token cross-entropy
     [batch, seq - 1], float32. ``layer_kinds``: one of ``"kda"`` / ``"mla"``
@@ -332,32 +395,11 @@ class KimiLinearModel(nn.Module):
         self.lm_head = nn.Dense(self.vocab_size, use_bias=False,
                                 dtype=self.dtype)
 
-    def _nll(self, h, targets):
-        """[rows, d] and the rows' next tokens -> their cross-entropy."""
-        with jax.named_scope(HEAD_SCOPE):
-            logp = jax.nn.log_softmax(self.lm_head(h).astype(jnp.float32))
-            return -jnp.take_along_axis(logp, targets[:, None], axis=-1)[:, 0]
-
     def __call__(self, tokens):
-        b, s = tokens.shape
         x = self.embed(tokens)       # float32 from here on (module docstring)
         for i in range(len(self.layer_kinds)):
             x = getattr(self, f"layer_{i}")(x)
-        h = self.final_norm(x).reshape(b * s, self.d_model)
-        # a row's target is the next token; a sequence's last row has none
-        # (it gets token 0 and is dropped), so that b s rows divide evenly
-        targets = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1))).reshape(b * s)
-        rows = self.loss_rows if (b * s) % self.loss_rows == 0 else b * s
-        nll = nn.remat(KimiLinearModel._nll, prevent_cse=False)
-        if self.is_initializing() or rows == b * s:
-            out = nll(self, h, targets)
-        else:
-            out = nn.scan(
-                lambda model, _, block: (None, nll(model, *block)),
-                variable_broadcast="params", split_rngs={"params": False})(
-                    self, None, (h.reshape(-1, rows, self.d_model),
-                                 targets.reshape(-1, rows)))[1]
-        return out.reshape(b, s)[:, :-1]
+        return next_token_nll(self, self.final_norm(x), tokens, 1)
 
 
 def kimi_linear_loss(nll: jax.Array) -> jax.Array:

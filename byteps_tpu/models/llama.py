@@ -42,15 +42,23 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(orig_dtype)
 
 
-def _rope(x: jax.Array, positions: jax.Array,
-          theta: float = 10000.0) -> jax.Array:
-    """Rotary position embedding over [batch, seq, heads, head_dim]."""
+def _rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+          interleaved: bool = False) -> jax.Array:
+    """Rotary position embedding over [batch, seq, heads, head_dim], in
+    float32. Pair j of a head is rotated by ``position theta^(-2j / d)``:
+    entries ``(j, j + d/2)`` (half against half), or with ``interleaved``
+    entries ``(2j, 2j + 1)``, each staying where it was."""
     d = x.shape[-1]
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [b, s, half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if interleaved:
+        pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
     x1, x2 = x[..., :half].astype(jnp.float32), \
         x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],

@@ -119,13 +119,102 @@ def test_kda_operand_kernels_compile_for_v5e(topo, with_grads):
     assert text.count("tpu_custom_call") == (2 if with_grads else 1)
 
 
+def _described(mesh, tree, spec):
+    """``tree``'s shapes as arrays laid out by ``spec`` on ``mesh``."""
+    from jax.sharding import NamedSharding
+
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, spec)), tree)
+
+
+@pytest.fixture
+def as_on_a_tpu(monkeypatch):
+    """``full_attention`` picks its form by the backend it runs on, and the
+    kernel interprets itself off a TPU: here both are told the described
+    chip's answer."""
+    import importlib
+
+    # the package exports functions under both modules' names
+    fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+    ra = importlib.import_module("byteps_tpu.parallel.ring_attention")
+    rule = ra.attention_form
+    monkeypatch.setattr(ra, "attention_form",
+                        lambda backend, *rest: rule("tpu", *rest))
+    monkeypatch.setattr(fa, "_resolve_interpret", lambda interpret: False)
+
+
+def test_rotary_latent_attention_compiles_for_v5e(topo, as_on_a_tpu):
+    """A mixer of the JoyAI cell, forward and backward: b 1 x s 8192, 32
+    heads behind latents of 1536 and 512, the interleaved rotation in
+    float32 feeding the flash kernels at keys 192 / values 128."""
+    from byteps_tpu.models.kimi_linear import KimiLatentAttention
+
+    one = SingleDeviceSharding(topo.devices[0])
+    layer = KimiLatentAttention(32, 128, 64, 128, 512, jnp.bfloat16, 1e-6,
+                                1536, 32e6)
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.float32, sharding=one)
+    params = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8, 2048))))
+    text = jax.jit(jax.grad(
+        lambda p, x: layer.apply(p, x).astype(jnp.float32).sum(),
+        argnums=(0, 1))).lower(params, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3      # forward, dq, dkv
+    assert "bps.mla.proj" in text and "bps.mla.attend" in text
+
+
+def test_joyai_collective_step_compiles_for_one_v5e(topo, as_on_a_tpu):
+    """make_train_step over JoyAIFlashModel at the cell's widths, b 1 x s
+    8192, adamw, for one described chip — cut to the dense layer and the
+    MTP module (an expert layer, both passes through the head), which is
+    every kind of program the cell's step holds, so that the case stays in
+    tier-1's time (the whole cut compiles in 53 s: ``compiled_bytes`` in
+    the configuration's file)."""
+    import sys
+
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    import byteps_tpu.jax as bps
+    from byteps_tpu.jax.training import make_train_step
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmark.lib import cell as cell_lib
+
+    path = os.path.join(repo, "benchmark", "configs", "joyai-llm-flash")
+    cfg = {**cell_lib.load_json(path + ".json"), "num_hidden_layers": 1}
+    init, loss_fn = cell_lib.load_module(path + ".py", "joyai_config").build(
+        cfg)
+    tx = optax.adamw(1e-4)
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dcn", "ici"))
+    bps.init(mesh=mesh)
+    step = make_train_step(loss_fn, tx)
+    params = jax.eval_shape(init, jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((1, cfg["seq_len"]), jnp.int32)}
+    compiled = step.lower(
+        _described(mesh, params, P()),
+        _described(mesh, jax.eval_shape(tx.init, params), P()),
+        _described(mesh, batch, P(("dcn", "ici")))).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16e9
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 6       # two mixers' kernels
+    assert "bps.mtp" in text and "ragged-dot" in text
+
+
 @pytest.mark.slow
 def test_gpt2_124m_collective_step_compiles_for_one_v5e(topo):
     """The whole chip_smoke phase-1 program — make_train_step, GPT-2 124M,
     adamw, b8 x s512 — for one described chip, and it fits its 16 GB."""
     import numpy as np
     import optax
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import Mesh, PartitionSpec as P
 
     import byteps_tpu.jax as bps
     from byteps_tpu.jax.training import make_train_step
@@ -137,16 +226,12 @@ def test_gpt2_124m_collective_step_compiles_for_one_v5e(topo):
     bps.init(mesh=mesh)
     step = make_train_step(lambda p, b: lm_loss(model.apply(p, b), b), tx)
 
-    def described(tree, spec):
-        return jax.tree_util.tree_map(
-            lambda s: jax.ShapeDtypeStruct(
-                s.shape, s.dtype, sharding=NamedSharding(mesh, spec)), tree)
-
     tokens = jax.ShapeDtypeStruct((8, 512), jnp.int32)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
     compiled = step.lower(
-        described(params, P()), described(jax.eval_shape(tx.init, params), P()),
-        described(tokens, P(("dcn", "ici")))).compile()
+        _described(mesh, params, P()),
+        _described(mesh, jax.eval_shape(tx.init, params), P()),
+        _described(mesh, tokens, P(("dcn", "ici")))).compile()
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16e9
