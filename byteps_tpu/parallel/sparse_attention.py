@@ -3,18 +3,32 @@ for every query, the ``topk`` best are selected exactly, attention runs over
 the selected keys alone, and the indexer learns from a KL term of its own
 (DeepSeek-V3.2's sparse attention; grouped-query attention underneath).
 
-``sparse_attention`` is the masked form: the selection is a boolean
-[queries, keys] mask and the softmax runs over all keys of the block with
-the unselected ones masked out — every product is computed, those of
-unselected keys included, which is ``T / mean |S_t|`` times what the
-mathematics needs (PERF.md: the first ``perf_opt`` on the cell that runs
-it). It works in query blocks of ``block`` rows, each recomputed in the
-backward pass (``jax.checkpoint``), so the [heads, block, keys] float32
-scores of one block are all that is alive: 0.54 GB at 32 heads x 512 x
-8192. The blocks tile the computation and do not change it. Chosen by
-measurement over gathering the selected keys, and the spans over one loop
-and over unrolled blocks (PERF.md section 4). No kernel library is
-imported here (``tests/test_import_footprint.py``).
+``sparse_attention`` works in query blocks of ``block`` rows, each
+recomputed in the backward pass (``jax.checkpoint``); the blocks tile the
+computation and do not change it. The selection is a boolean [queries,
+keys] mask and the softmax runs over all keys of the block's span with the
+unselected ones masked out: every product of the span is computed, those
+of unselected keys included (``T / mean |S_t|`` times what the mathematics
+needs). Chosen by measurement over gathering the selected keys, and the
+spans over one loop and over unrolled blocks (PERF.md section 4).
+
+**One computation, two forms of a block's attention** (``attend_form``, a
+pure function of the backend, the operands' dtype and the shapes, as
+``linear_attention.py::kda_form`` is for a KDA chunk; each counted at
+trace time). The masked form, in XLA, is what every CPU run, float32
+operands and any head width but 128 get: its [heads, block, keys] float32
+scores — 0.54 GB at 32 heads x 512 x 8192 — go to HBM and come back a
+dozen times a block, forward, recomputed and backward. On a ``tpu``
+backend with bf16 operands, heads 128 wide and a block of a multiple of 32
+queries, the kernels of ``byteps_tpu.ops.sparse_flash`` take the mask as
+an int8 operand and hold a [block, tile] score in VMEM: forward, the
+head-mean probabilities the indexer learns from, and one backward kernel.
+Same arithmetic: bf16 operands, float32 accumulation, logits and sums. A
+block keeps its [block, heads] logsumexp across the recomputation, so the
+forward kernel runs once a step; the output is kept once, by whoever
+reads it next (``sparse_flash.masked_attention``: how it is
+differentiated). The kernel library is imported inside the branch that
+takes the kernel form and nowhere else (``tests/test_import_footprint.py``).
 
 The selection thresholds on each query's ``topk``-th highest score. That
 one number a row is found without sorting: the float32 scores are read as
@@ -44,6 +58,18 @@ from jax.ad_checkpoint import checkpoint_name
 INDEXER_SCOPE = "bps.dsa.indexer"  # index scores, the KL term, gradients
 SELECT_SCOPE = "bps.dsa.select"    # the topk-th score and the mask
 ATTEND_SCOPE = "bps.dsa.attend"    # scores, softmax, values, gradients
+# counted at trace time: calls of ``sparse_attention``, and those of them
+# whose blocks attend through the kernels
+ATTEND_SITES = "bps_dsa_attend_sites_total"
+KERNEL_SITES = "bps_dsa_kernel_sites_total"
+
+# What the kernels of ``byteps_tpu.ops.sparse_flash`` were measured at on a
+# TPU v5e and won (PERF.md section 6, PR 42): heads one row of lanes wide,
+# bf16 operands. Their tiling admits a block of whole int8 sublane groups
+# (a multiple of 32 queries) whose query heads of one key-value head fit
+# the accumulators the kernels hold in VMEM (block x group up to 4096).
+KERNEL_WIDTH = 128
+KERNEL_ROWS = 4096
 
 
 _INT_MIN = -2 ** 31
@@ -103,13 +129,33 @@ def _select(score, causal, topk: int):
     return (above | (tied & (jnp.cumsum(tied, axis=-1) <= room))) & causal
 
 
-def _block(q, index_q, index_w, first, k, v, index_k, *, topk, scale):
+def attend_form(backend: str, dtype, head_dim: int, group: int,
+                block: int) -> str:
+    """``"kernel"`` or ``"masked"``: how a block of ``block`` queries, each
+    key-value head under ``group`` query heads ``head_dim`` wide, attends
+    over its selection. One computation, two forms: the masked form writes
+    its [heads, block, keys] float32 scores to HBM and reads them back, the
+    kernels (TPU only) hold a [block, tile] score in VMEM."""
+    if backend != "tpu" or jnp.dtype(dtype) != jnp.bfloat16:
+        return "masked"
+    if head_dim != KERNEL_WIDTH or block % 32 or block * group > KERNEL_ROWS:
+        return "masked"
+    return "kernel"
+
+
+def _block(q, index_q, index_w, first, k, v, index_k, *, topk, scale,
+           kernel):
     """One block of queries at positions ``first..`` of one sequence over
     the keys 0..n-1. q [B, h, dh]; k, v [n, hk, dh]; index_q [B, hi, di];
-    index_w [B, hi]; index_k [n, di]. Returns (out [B, h, dh] float32, the
-    block's summed KL, its number of selected keys)."""
-    rows, heads, head_dim = q.shape
-    n, kv_heads = k.shape[:2]
+    index_w [B, hi]; index_k [n, di]. ``kernel``: q [B, h * dh], the heads
+    side by side, and k, v all keys of the sequence, of which the block
+    reads those up to its last query. Returns (out [B, h, dh] float32 — the
+    kernel form [B, h * dh] in q's dtype, yet to be ``renormalised``, and
+    the logits' logsumexp [B, h], else ``None`` — the block's summed KL,
+    its number of selected keys)."""
+    rows = q.shape[0]
+    n, (kv_heads, head_dim) = index_k.shape[0], k.shape[1:]
+    heads = q.size // (rows * head_dim)
     hi = lax.Precision.HIGHEST
     causal = (jnp.arange(n)[None, :]
               <= (first + jnp.arange(rows))[:, None])
@@ -119,49 +165,79 @@ def _block(q, index_q, index_w, first, k, v, index_k, *, topk, scale):
                            precision=hi)                        # [B, n]
     with jax.named_scope(SELECT_SCOPE):
         keep = _select(lax.stop_gradient(score), causal, topk)
-    with jax.named_scope(ATTEND_SCOPE):
-        grouped = q.reshape(rows, kv_heads, heads // kv_heads, head_dim)
-        logits = jnp.einsum("qcgd,scd->cgqs", grouped, k,
-                            preferred_element_type=jnp.float32) * scale
-        probs = jax.nn.softmax(
-            jnp.where(keep, logits, jnp.finfo(jnp.float32).min), axis=-1)
-        out = jnp.einsum("cgqs,scd->qcgd", probs.astype(v.dtype), v,
-                         preferred_element_type=jnp.float32)
+    if kernel:
+        from byteps_tpu.ops.sparse_flash import masked_attention
+
+        with jax.named_scope(ATTEND_SCOPE):
+            out, lse, target = masked_attention(q, k, v, keep, first, scale)
+    else:
+        lse = None
+        with jax.named_scope(ATTEND_SCOPE):
+            grouped = q.reshape(rows, kv_heads, heads // kv_heads, head_dim)
+            logits = jnp.einsum("qcgd,scd->cgqs", grouped, k,
+                                preferred_element_type=jnp.float32) * scale
+            probs = jax.nn.softmax(
+                jnp.where(keep, logits, jnp.finfo(jnp.float32).min), axis=-1)
+            out = jnp.einsum("cgqs,scd->qcgd", probs.astype(v.dtype), v,
+                             preferred_element_type=jnp.float32)
+        with jax.named_scope(INDEXER_SCOPE):
+            target = probs.sum(axis=(0, 1)) / heads
     with jax.named_scope(INDEXER_SCOPE):
-        target = lax.stop_gradient(probs.sum(axis=(0, 1)) / heads)
+        target = lax.stop_gradient(target)
         log_index = jax.nn.log_softmax(
             jnp.where(keep, score, jnp.finfo(jnp.float32).min), axis=-1)
         seen = keep & (target > 0)
         kl = jnp.where(seen, target * (jnp.log(jnp.where(seen, target, 1.0))
                                        - log_index), 0.0).sum()
-    return (out.reshape(rows, heads, head_dim), kl,
-            keep.sum(dtype=jnp.int32))
+    if not kernel:
+        out = out.reshape(rows, heads, head_dim)
+    return out, lse, kl, keep.sum(dtype=jnp.int32)
 
 
-def _one_sequence(q, k, v, index_q, index_k, index_w, *, topk, block, scale):
+def _one_sequence(q, k, v, index_q, index_k, index_w, *, topk, block, scale,
+                  kernel):
     """The blocks of one sequence, in spans of ``topk`` keys: the queries
     of a span see the keys up to the span's end and no later one (62.5% of
     the [s, s] pairs at s = 4 topk; the first span selects nothing), and
-    its blocks run one after the other under ``lax.map``."""
-    s = q.shape[0]
+    its blocks run one after the other under ``lax.map``. In the kernel
+    form a block is handed all keys, one shape for every span, and the
+    kernels stop at the tile of the block's last query."""
+    s, heads = q.shape[:2]
     span = block * max(1, topk // block)
+    saved = [_SAVED]
+    if kernel:
+        # imported here: a process that never reaches this line (any CPU
+        # run, float32 operands) pays for no kernel library
+        # (tests/test_import_footprint.py)
+        from byteps_tpu.ops.sparse_flash import SAVED, renormalised
+
+        saved.append(SAVED)
+        q = q.reshape(s, -1)     # as the kernels read it and write ``out``
     run = jax.checkpoint(
-        partial(_block, topk=topk, scale=scale),
-        policy=jax.checkpoint_policies.save_only_these_names(_SAVED))
-    outs, kl, selected = [], 0.0, 0
+        partial(_block, topk=topk, scale=scale, kernel=kernel),
+        policy=jax.checkpoint_policies.save_only_these_names(*saved))
+    outs, lses, kl, selected = [], [], 0.0, 0
     for lo in range(0, s, span):
         hi = min(lo + span, s)
 
         def blocks(a):
             return a[lo:hi].reshape((hi - lo) // block, block, *a.shape[1:])
 
-        out, kl_b, selected_b = lax.map(
-            lambda xs: run(*xs, k[:hi], v[:hi], index_k[:hi]),
+        out, lse, kl_b, selected_b = lax.map(
+            lambda xs: run(*xs, *((k, v) if kernel else (k[:hi], v[:hi])),
+                           index_k[:hi]),
             (blocks(q), blocks(index_q), blocks(index_w),
              jnp.arange(lo, hi, block)))
         outs.append(out.reshape(hi - lo, *out.shape[2:]))
+        if kernel:
+            lses.append(lse.reshape(hi - lo, -1))
         kl, selected = kl + kl_b.sum(), selected + selected_b.sum()
-    return jnp.concatenate(outs), kl / s, selected
+    out = jnp.concatenate(outs)
+    if kernel:
+        # the normaliser's gradient, for all blocks at once: it reads the
+        # output the caller's next layer keeps, so no block keeps its own
+        out = renormalised(out, jnp.concatenate(lses)).reshape(s, heads, -1)
+    return out, kl / s, selected
 
 
 def sparse_attention(q, k, v, index_q, index_k, index_w, *, topk: int,
@@ -192,7 +268,15 @@ def sparse_attention(q, k, v, index_q, index_k, index_w, *, topk: int,
         raise ValueError(f"sequence {s} must be a multiple of the block "
                          f"{block}, heads {q.shape[2]} of the key-value "
                          f"heads {k.shape[2]}")
-    one = partial(_one_sequence, topk=topk, block=block,
+    from byteps_tpu.monitor import metrics
+
+    metrics.inc_counter(ATTEND_SITES)
+    kernel = attend_form(
+        jax.default_backend(), jnp.result_type(q, k, v), head_dim,
+        q.shape[2] // k.shape[2], block) == "kernel"
+    if kernel:
+        metrics.inc_counter(KERNEL_SITES)
+    one = partial(_one_sequence, topk=topk, block=block, kernel=kernel,
                   scale=head_dim ** -0.5 if scale is None else scale)
     out, index_loss, selected = jax.vmap(one)(q, k, v, index_q, index_k,
                                               index_w)

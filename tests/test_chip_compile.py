@@ -119,6 +119,34 @@ def test_kda_operand_kernels_compile_for_v5e(topo, with_grads):
     assert text.count("tpu_custom_call") == (2 if with_grads else 1)
 
 
+@pytest.mark.parametrize("with_grads", [False, True], ids=["fwd", "fwd_bwd"])
+def test_sparse_flash_kernels_compile_for_v5e(topo, with_grads):
+    """A block of the Keye cell's sparse attention: 512 queries, 32 heads
+    over 4 key-value heads of 128, all 8192 keys handed in and the mask of
+    a span of 4096 — the forward and probabilities kernels, and through
+    ``jax.grad`` the forward (for its logsumexp) and the backward kernel."""
+    from byteps_tpu.ops.sparse_flash import masked_attention, renormalised
+
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((512, 32 * 128), jnp.bfloat16, sharding=one)
+    k = jax.ShapeDtypeStruct((8192, 4, 128), jnp.bfloat16, sharding=one)
+    keep = jax.ShapeDtypeStruct((512, 4096), jnp.bool_, sharding=one)
+    first = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+
+    def fwd(q, k, v, keep, first):
+        out, lse, target = masked_attention(q, k, v, keep, first, 128 ** -0.5,
+                                            False)
+        return renormalised(out, lse), target
+
+    def loss(*args):
+        return fwd(*args)[0].astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if with_grads else fwd
+    text = jax.jit(fn).lower(q, k, k, keep, first).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert ("bps_dsa_bwd" if with_grads else "bps_dsa_probs") in text
+
+
 def _described(mesh, tree, spec):
     """``tree``'s shapes as arrays laid out by ``spec`` on ``mesh``."""
     from jax.sharding import NamedSharding
