@@ -129,8 +129,8 @@ class KeyeSparseMoe(nn.Module):
                                             batch_axis=0)
         ffn = partial(dropless_moe_ffn, top_k=self.top_k, dtype=self.dtype,
                       first_expert=self.first_expert, norm_topk=True)
-        # the sorted rows keep their worst-case length b s k whatever share
-        # is held: 0.8 GB a layer at 8192 x 8 rows, unless recomputed
+        # the routed part keeps its arguments alone either way; recomputed,
+        # the router's half keeps neither its [T, E] scores nor the sort
         y, load_balance, _, counts = (jax.checkpoint(ffn) if self.remat
                                       else ffn)(
             x.reshape(b * s, d),

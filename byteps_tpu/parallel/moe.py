@@ -22,6 +22,7 @@ the result and leaves the rest out; it has no ``ep_axis`` (no exchange) yet.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -217,6 +218,151 @@ def _permute_bwd(res, g):
 
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
+# A share's rows a pass over its even part T k H / E. The three cells that
+# hold a share start at held loads of 0.87-1.02 of the even part (PERF.md
+# section 5): 1 x would send JoyAI's 1.024 through two passes every step,
+# 2 x leaves a balanced router (auxiliary loss or selection bias) room.
+HELD_ROWS_OVER_EVEN = 2
+HELD_ROWS_MULTIPLE = 512
+
+
+def held_row_bound(t: int, top_k: int, held: int, e: int) -> int:
+    """The rows a share of ``held`` of ``e`` experts works on in one pass:
+    twice the even part of the assignments that reach it, in 512s, and at
+    most all T k. 4,096 of 65,536 for 8 of 256 experts, 16,384 for 16 of
+    128; every row where half the experts or more are held."""
+    rows = t * top_k
+    room = -(-HELD_ROWS_OVER_EVEN * rows * held // e)
+    return min(rows, -(-room // HELD_ROWS_MULTIPLE) * HELD_ROWS_MULTIPLE)
+
+
+def _grouped_ffn(xs, w_gate, w_up, w_down, groups, rows, dtype):
+    """``down(silu(gate(xs)) * up(xs))`` over rows sorted by expert; ``rows``
+    marks those inside ``groups`` where the groups do not cover them all."""
+    with jax.named_scope(EXPERTS_SCOPE):
+        # lax.ragged_dot: row i of the sorted rows times the matrix of its
+        # group, float32 accumulation, result in `dtype`. The TPU compiler
+        # makes one Mosaic kernel of each call (`ragged-dot` in the device
+        # trace), forward, dgrad and the per-group wgrad alike; elsewhere
+        # it is a masked dense product. Chosen over Pallas megablox by
+        # measurement and for needing no import (PERF.md section 4).
+        def grouped(lhs, w):
+            out = lax.ragged_dot(lhs, w.astype(dtype), groups)
+            # the TPU's kernel leaves rows beyond its groups undefined (x's
+            # gradient came out 50 x too large unmasked; PERF.md, PR 33):
+            # a share takes zeros there, forward and backward
+            return out if rows is None else jnp.where(rows, out, 0)
+
+        gate = grouped(xs, w_gate)
+        up = grouped(xs, w_up)
+        return grouped(jax.nn.silu(gate) * up, w_down)
+
+
+# jitted: the written-out pass and the loop's, forward and backward, in every
+# expert layer of a model are this function at the same shapes, so one trace
+# and one lowered function serve them all (≈ 1 s less set-up on the chip's
+# host; the compiled step is the same to the byte of its memory)
+@partial(jax.jit, static_argnums=(0, 1))
+def _held_pass(dtype, bound, start, y, x, top_w, w_gate, w_up, w_down, order,
+               groups):
+    """``y`` [T, D] float32 plus the held experts' part of the layer for
+    the sorted rows ``start .. start + bound - 1``: those rows gathered
+    from ``x`` (float32, so that its gradient adds in float32) by token,
+    multiplied in the groups that fall inside the pass, and added to their
+    tokens. Rows past the held experts' last are zero in and out."""
+    top_k = top_w.shape[1]
+    with jax.named_scope(ROUTE_SCOPE):
+        # sorted row -> t*k + j
+        first = lax.dynamic_slice_in_dim(order, start, bound)
+        token = first // top_k
+        ends = jnp.clip(jnp.cumsum(groups) - start, 0, bound)
+        rows = (jnp.arange(bound) < ends[-1])[:, None]
+        xs = jnp.where(rows, x[token].astype(dtype), 0)
+        weight = top_w.reshape(-1)[first]
+    ys = _grouped_ffn(xs, w_gate, w_up, w_down, jnp.diff(ends, prepend=0),
+                      rows, dtype)
+    with jax.named_scope(ROUTE_SCOPE):
+        return y.at[token].add(ys * weight[:, None])
+
+
+def _pass_operands(bound, dtype, x, top_w, w_gate, w_up, w_down, order,
+                   groups):
+    """What every pass reads, made once and under the scope that reads it:
+    x in float32, the weights in ``dtype``, the order padded to whole
+    passes."""
+    with jax.named_scope(ROUTE_SCOPE):
+        x = x.astype(jnp.float32)
+    with jax.named_scope(EXPERTS_SCOPE):
+        w_gate, w_up, w_down = (w.astype(dtype)
+                                for w in (w_gate, w_up, w_down))
+    with jax.named_scope(ROUTE_SCOPE):
+        order = jnp.pad(order, (0, -order.size % bound))
+    return x, top_w, w_gate, w_up, w_down, order, groups
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_rows(bound, dtype, *args):
+    """A share's part of the layer, [T, D] float32: the sorted rows of the
+    held experts, which come first, in passes of ``bound`` rows — one where
+    the assignments that reach them fit ``bound``, as many as it takes
+    where they do not, so whatever the routing no assignment is dropped and
+    none beyond the held experts' is gathered or multiplied. One pass is
+    written out beside the loop, which would run it as well: around a
+    ``while`` at the top level of a step the TPU compiler kept several
+    blocks' recomputed residuals alive (15.2 against 12.0 GB compiled for
+    the Kimi-Linear cell; PERF.md, PR 43), around a ``conditional`` it does
+    not. Both sit outside the two scopes, which a pass opens itself: on a
+    device trace neither carries one. Nothing but the arguments is kept for
+    the backward pass, which runs the same passes over ``jax.vjp`` of each
+    and adds up their gradients in float32."""
+    operands = _pass_operands(bound, dtype, *args)
+    held_rows, y = args[-1].sum(), jnp.zeros(args[0].shape, jnp.float32)
+    return lax.cond(
+        held_rows <= bound,
+        lambda: _held_pass(dtype, bound, jnp.int32(0), y, *operands),
+        lambda: lax.while_loop(
+            lambda at: at[0] < held_rows,
+            lambda at: (at[0] + bound,
+                        _held_pass(dtype, bound, at[0], at[1], *operands)),
+            (jnp.int32(0), y))[1])
+
+
+def _held_rows_fwd(bound, dtype, *args):
+    return _held_rows(bound, dtype, *args), args
+
+
+def _held_rows_bwd(bound, dtype, args, g):
+    *floats, order, groups = _pass_operands(bound, dtype, *args)
+    held_rows = groups.sum()
+
+    def pulled(start):
+        """The float operands' gradients through the pass at ``start``."""
+        return jax.vjp(lambda *f: _held_pass(
+            dtype, bound, start, jnp.zeros_like(g), *f, order, groups),
+            *floats)[1](g)
+
+    def summed():
+        """Every pass's, added up in float32 and handed on as one pass's
+        are: a weight's in ``dtype``, which is what a grouped matmul gives
+        (float32 out of the choice would keep three float32 weights more a
+        layer alive to the optimizer: + 0.9% of the Keye cell's memory)."""
+        totals = lax.while_loop(
+            lambda at: at[0] < held_rows,
+            lambda at: (at[0] + bound, tuple(
+                total + part.astype(jnp.float32)
+                for total, part in zip(at[1], pulled(at[0])))),
+            (jnp.int32(0), tuple(
+                jnp.zeros(f.shape, jnp.float32) for f in floats)))[1]
+        return tuple(total.astype(f.dtype)
+                     for total, f in zip(totals, floats))
+
+    grads = lax.cond(held_rows <= bound, lambda: pulled(jnp.int32(0)), summed)
+    return (*(grad.astype(arg.dtype) for grad, arg in zip(grads, args)),
+            None, None)
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
 
 def dropless_moe_ffn(
     x: jax.Array,
@@ -257,10 +403,13 @@ def dropless_moe_ffn(
 
     A share (H < E) routes over all E all the same and computes the
     assignments that fall to its own experts, all of them whatever the
-    routing: the sorted rows keep their worst-case length T k, the held
-    experts' rows first, and the grouped matmuls are told the held groups
-    only. Rows beyond them (assignments to experts held elsewhere) are
-    zero on the way in and out of every grouped matmul and carry weight 0,
+    routing. The sort puts the held experts' rows first, and the share
+    gathers, multiplies and adds to their tokens (float32) those rows
+    alone, ``held_row_bound`` of the shapes at a pass: one pass where the
+    routing sends the held experts up to twice their even part, as many as
+    the held rows take where it sends more (a loop on their count), never a
+    row of an expert held elsewhere. Rows of a pass beyond the held
+    experts' last are zero on the way in and out of every grouped matmul,
     so ``y`` is this share's part of the layer's output: the parts of
     shares that cover 0..E-1 add up to the whole layer's.
 
@@ -309,39 +458,24 @@ def dropless_moe_ffn(
         order = jnp.argsort(sort_key, stable=True)  # sorted row -> t*k + j
         counts = (flat_e[:, None] == jnp.arange(e)[None, :]).sum(
             axis=0, dtype=jnp.int32)
-        back = jnp.argsort(order)                   # t*k + j -> sorted row
-        xs = _permute(jnp.repeat(x.astype(dtype), top_k, axis=0), order,
-                      back)                                     # [T k, D]
+        if held == e:
+            back = jnp.argsort(order)               # t*k + j -> sorted row
+            xs = _permute(jnp.repeat(x.astype(dtype), top_k, axis=0), order,
+                          back)                                 # [T k, D]
         load_balance = (counts.astype(jnp.float32)
                         * probs.mean(axis=0)).sum() * (e / (t * top_k))
         z_loss = jnp.mean(lse * lse)
-        groups, rows = counts, None
-        if held < e:
-            groups = lax.slice_in_dim(counts, first_expert,
-                                      first_expert + held)
-            rows = (jnp.arange(t * top_k) < groups.sum())[:, None]
-            xs = jnp.where(rows, xs, 0)
-    with jax.named_scope(EXPERTS_SCOPE):
-        # lax.ragged_dot: row i of the sorted rows times the matrix of its
-        # group, float32 accumulation, result in `dtype`. The TPU compiler
-        # makes one Mosaic kernel of each call (`ragged-dot` in the device
-        # trace), forward, dgrad and the per-group wgrad alike; elsewhere
-        # it is a masked dense product. Chosen over Pallas megablox by
-        # measurement and for needing no import (PERF.md section 4).
-        def grouped(lhs, w):
-            out = lax.ragged_dot(lhs, w.astype(dtype), groups)
-            # the TPU's kernel leaves rows beyond its groups undefined (x's
-            # gradient came out 50 x too large unmasked; PERF.md, PR 33):
-            # a share takes zeros there, forward and backward
-            return out if rows is None else jnp.where(rows, out, 0)
-
-        gate = grouped(xs, w_gate)
-        up = grouped(xs, w_up)
-        ys = grouped(jax.nn.silu(gate) * up, w_down)            # [T k, D]
-    with jax.named_scope(ROUTE_SCOPE):
-        y = jnp.einsum("tkd,tk->td",
-                       _permute(ys, back, order).reshape(t, top_k, d),
-                       top_w, preferred_element_type=jnp.float32)
+    if held == e:
+        ys = _grouped_ffn(xs, w_gate, w_up, w_down, counts, None, dtype)
+        with jax.named_scope(ROUTE_SCOPE):
+            y = jnp.einsum("tkd,tk->td",
+                           _permute(ys, back, order).reshape(t, top_k, d),
+                           top_w, preferred_element_type=jnp.float32)
+    else:
+        y = _held_rows(
+            held_row_bound(t, top_k, held, e), dtype, x, top_w, w_gate, w_up,
+            w_down, order,
+            lax.slice_in_dim(counts, first_expert, first_expert + held))
     return y.astype(x.dtype), load_balance, z_loss, counts
 
 
@@ -351,9 +485,11 @@ def publish_moe_stats(moe_stats, held=None) -> dict:
     ``monitor/metrics.py``: gauge ``bps_moe_max_expert_load`` (the busiest
     expert's assignments over the mean, worst layer), counter
     ``bps_moe_assignments_total`` and, for a share ``held`` = (first expert,
-    experts held), gauge ``bps_moe_held_load``: the assignments that reached
-    the held experts over their even part T k H / E, all layers together.
-    Returns what it published."""
+    experts held), gauges ``bps_moe_held_load``: the assignments that reached
+    the held experts over their even part T k H / E, all layers together,
+    and ``bps_moe_compact_share``: the share of layers whose held
+    assignments fit ``held_row_bound``, the layers that take one pass over
+    their rows and not several. Returns what it published."""
     import numpy as np
 
     from byteps_tpu.monitor import metrics
@@ -369,7 +505,12 @@ def publish_moe_stats(moe_stats, held=None) -> dict:
         out["bps_moe_held_load"] = float(
             sum(c[first:first + n].sum() for c in leaves)
             / sum(c.sum() * n / c.size for c in leaves))
-        metrics.set_gauge("bps_moe_held_load", out["bps_moe_held_load"])
+        # the bound reads t and top_k as their product, the counts' sum
+        out["bps_moe_compact_share"] = float(np.mean([
+            c[first:first + n].sum()
+            <= held_row_bound(int(c.sum()), 1, n, c.size) for c in leaves]))
+        for name in ("bps_moe_held_load", "bps_moe_compact_share"):
+            metrics.set_gauge(name, out[name])
     metrics.set_gauge("bps_moe_max_expert_load",
                       out["bps_moe_max_expert_load"])
     metrics.inc_counter("bps_moe_assignments_total",

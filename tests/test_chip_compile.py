@@ -147,6 +147,40 @@ def test_sparse_flash_kernels_compile_for_v5e(topo, with_grads):
     assert ("bps_dsa_bwd" if with_grads else "bps_dsa_probs") in text
 
 
+def test_expert_share_compiles_its_passes_for_v5e(topo):
+    """The routed experts of a Kimi-Linear layer, forward and backward as
+    the block recomputes them: T 8192, k 8, experts 16..23 of 256, D 2304,
+    width 1024, sigmoid gate. Passes of 4,096 of the 65,536 sorted rows:
+    one choice between a pass and a loop of passes forward (the recomputed
+    one is dead code: nothing of it is kept) and one backward, each side of
+    it with its gather, its grouped matmuls and its scatter-add lowered for
+    the chip, and no array of 65,536 rows by the model's or the experts'
+    width anywhere."""
+    from byteps_tpu.parallel.moe import dropless_moe_ffn, held_row_bound
+
+    one = SingleDeviceSharding(topo.devices[0])
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+              for shape, dtype in (((8192, 2304), jnp.bfloat16),
+                                   ((2304, 256), jnp.float32),
+                                   ((8, 2304, 1024), jnp.float32),
+                                   ((8, 2304, 1024), jnp.float32),
+                                   ((8, 1024, 2304), jnp.float32))]
+    assert held_row_bound(8192, 8, 8, 256) == 4096
+
+    @jax.checkpoint
+    def layer(*args):
+        return dropless_moe_ffn(
+            *args, top_k=8, first_expert=16, norm_topk=True,
+            scoring="sigmoid", norm_eps=1e-20, routed_scale=2.446)[0]
+
+    text = jax.jit(jax.value_and_grad(
+        lambda *args: layer(*args).astype(jnp.float32).sum(),
+        argnums=range(5))).lower(*shapes).compile().as_text()
+    assert text.count(" conditional(") == text.count(" while(") == 2
+    assert "bf16[4096,2304]" in text and "bf16[4096,1024]" in text
+    assert "[65536,2304]" not in text and "[65536,1024]" not in text
+
+
 def _described(mesh, tree, spec):
     """``tree``'s shapes as arrays laid out by ``spec`` on ``mesh``."""
     from jax.sharding import NamedSharding

@@ -17,7 +17,9 @@ import numpy as np
 import pytest
 
 from byteps_tpu.models import Keye30BA3B, KeyeTiny, keye_loss
-from byteps_tpu.parallel.moe import dropless_moe_ffn, publish_moe_stats
+import byteps_tpu.parallel.moe as moe
+from byteps_tpu.parallel.moe import (dropless_moe_ffn, held_row_bound,
+                                     publish_moe_stats)
 from byteps_tpu.parallel.sparse_attention import publish_dsa_stats
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -35,15 +37,16 @@ def _close(got, want, rtol=1e-5):
     return float(jnp.abs(got - want).max()) <= rtol * max(scale, 1e-30)
 
 
-def _layer_inputs(skew, seed=0):
-    """Seeded tokens and all E experts' weights. ``skew``: every token's
-    two best experts are 0 and 1 (a feature column only their router columns
-    read), so one share of two receives every assignment."""
+def _layer_inputs(skew, seed=0, t=T):
+    """Seeded tokens and all E experts' weights. ``skew``: that share of the
+    tokens has 0 and 1 as its two best experts (a feature column only their
+    router columns read) — at 1 one share of two receives every assignment
+    — and the other tokens have them as their two worst."""
     rng = np.random.default_rng(seed)
-    x = rng.standard_normal((T, D)).astype(np.float32)
+    x = rng.standard_normal((t, D)).astype(np.float32)
     wr = rng.standard_normal((D, E)).astype(np.float32) * 0.5
     if skew:
-        x[:, 0] = 8.0
+        x[:, 0] = np.where(np.arange(t) < skew * t, 8.0, -8.0)
         wr[0, :] = 0.0
         wr[0, :2] = 4.0
     scale = 1.0 / math.sqrt(D)
@@ -54,20 +57,24 @@ def _layer_inputs(skew, seed=0):
         rng.standard_normal((E, M, D)).astype(np.float32) * scale))
 
 
-def _share(x, wr, wg, wu, wd, first, held, top_k=2):
+def _share(x, wr, wg, wu, wd, first, held, top_k=2, **gate):
     return dropless_moe_ffn(
         x, wr, *(w[first:first + held] for w in (wg, wu, wd)), top_k=top_k,
-        dtype=jnp.float32, first_expert=first, norm_topk=True)
+        dtype=jnp.float32, first_expert=first, norm_topk=True, **gate)
 
 
-@pytest.mark.parametrize("skew", (False, True))
-def test_the_shares_parts_add_up_to_the_uncut_layer(skew):
+# 48 tokens: 96 rows, under one multiple of the bound, so a pass is every
+# row. 512 tokens: 1,024 rows in passes of 512 — even routing fits one pass
+# in every share; with three quarters of the tokens on experts 0 and 1 the
+# first share (768 rows) takes two and the others one.
+@pytest.mark.parametrize("skew,t", ((0, T), (1, T), (0, 512), (0.75, 512)))
+def test_the_shares_parts_add_up_to_the_uncut_layer(skew, t):
     """E = 8 in 4 shares of 2: what the shares compute, each for its own
     experts, sums to the plain reference's layer with all experts held —
     values and gradients — and each share alone is the reference given the
-    same share. Dropless under any routing: with ``skew`` one share gets
+    same share. Dropless under any routing: with ``skew`` 1 one share gets
     all T k assignments and the others none."""
-    args = _layer_inputs(skew)
+    args = _layer_inputs(skew, t=t)
     uncut = {"router": args[1], "gate": args[2], "up": args[3],
              "down": args[4]}
     with jax.default_matmul_precision("highest"):
@@ -76,10 +83,15 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(skew):
     assert _close(sum(p[0] for p in parts), want)
     for y, load_balance, _, counts in parts:
         assert abs(float(load_balance) - float(want_lb)) <= 1e-5
-        assert int(counts.sum()) == T * 2       # counted over all experts
-    if skew:
-        assert list(np.asarray(parts[0][3])) == [T, T, 0, 0, 0, 0, 0, 0]
+        assert int(counts.sum()) == t * 2       # counted over all experts
+    if skew == 1:
+        assert list(np.asarray(parts[0][3])) == [t, t, 0, 0, 0, 0, 0, 0]
         assert not np.asarray(parts[1][0]).any()
+    if t == 512:
+        fits = [int(parts[0][3][first:first + 2].sum())
+                <= held_row_bound(t, 2, 2, E) == 512
+                for first in range(0, E, 2)]
+        assert fits == [not skew, True, True, True]
     for first in range(0, E, 2):
         held = {k: v if k == "router" else v[first:first + 2]
                 for k, v in uncut.items()}
@@ -89,7 +101,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(skew):
         assert _close(parts[first // 2][0], alone)
 
     cot = jnp.asarray(np.random.default_rng(1).standard_normal(
-        (T, D)).astype(np.float32))
+        (t, D)).astype(np.float32))
 
     def summed(*a):
         return sum((_share(*a, first, 2)[0] * cot).sum()
@@ -107,6 +119,128 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(skew):
     for name, got, want_leaf in zip(("x", "router", "gate", "up", "down"),
                                     got_g, want_g):
         assert _close(got, want_leaf, rtol=3e-5), name
+
+
+@pytest.mark.parametrize("first", (0, 4))
+@pytest.mark.parametrize("scoring", ("softmax", "sigmoid"))
+def test_one_pass_is_the_layer_in_several_passes(monkeypatch, scoring, first):
+    """The same 512 tokens through a share of 2 of 8 experts in one pass of
+    512 of the 1,024 rows, then with a pass held to 128 rows, under what
+    the share receives: two to four passes. ``y``, the counts, both losses
+    and the gradients of x, the router and the three weights agree to 1e-6
+    of a leaf's largest entry: the same float32 products, a token's sum and
+    a weight's gradient taken in another order."""
+    args = _layer_inputs(0, t=512)
+    gate = dict(scoring=scoring)
+    if scoring == "sigmoid":
+        gate.update(norm_eps=1e-20, routed_scale=2.446, select_bias=jnp.where(
+            jnp.arange(E) == 5, 0.3, 0.0))
+    cot = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (512, D)).astype(np.float32))
+
+    def both(*a):
+        y, load_balance, z_loss, counts = _share(*a, first, 2, **gate)
+        return (y * cot).sum() + load_balance + z_loss, (y, load_balance,
+                                                         z_loss, counts)
+
+    run = jax.jit(jax.value_and_grad(both, argnums=range(5), has_aux=True))
+    (_, one_pass), one_pass_g = run(*args)
+    received = int(one_pass[3][first:first + 2].sum())
+    assert 128 < received <= held_row_bound(512, 2, 2, E) == 512
+    text = run.lower(*args).as_text()
+    # forward and backward: one pass, or a loop of them
+    assert text.count("stablehlo.case") == text.count("stablehlo.while") == 2
+    monkeypatch.setattr(moe, "held_row_bound", lambda *shape: 128)
+    (_, passes), passes_g = jax.jit(jax.value_and_grad(
+        both, argnums=range(5), has_aux=True))(*args)
+    assert np.array_equal(np.asarray(one_pass[3]), np.asarray(passes[3]))
+    for got, want in zip(one_pass[:3] + one_pass_g, passes[:3] + passes_g):
+        assert _close(got, want, rtol=1e-6)
+
+
+def _routed_to_held(held_rows, t=512):
+    """Tokens and a router that send exactly ``held_rows`` of the t * 2
+    assignments to experts 0 and 1: both choices of the first
+    ``held_rows // 2`` tokens, one of the next token's if the number is odd,
+    none of the others'."""
+    args = list(_layer_inputs(0, t=t))
+    x, wr = np.array(args[0]), np.array(args[1])
+    both, one = held_rows // 2, held_rows % 2
+    x[:, 0] = np.where(np.arange(t) < both, 8.0, -8.0)
+    x[:, 1] = 0.0
+    wr[:2, :] = 0.0
+    wr[0, :2] = 4.0                         # column 0: experts 0 and 1, or not
+    if one:
+        x[both, :2] = (0.0, 8.0)
+        wr[1, :2] = (4.0, -4.0)             # column 1: expert 0 and not 1
+    return [jnp.asarray(x), jnp.asarray(wr)] + args[2:]
+
+
+@pytest.mark.parametrize("held_rows", (512, 513, 1024))
+def test_nothing_is_dropped_at_the_bound_and_beyond_it(held_rows):
+    """A routing built to put exactly the bound (512 of 1,024 rows: one
+    pass), one assignment more (a second pass for it), and every assignment
+    (all T k: each token's two best experts are the held two) on the held
+    experts: the share's output and gradients are the plain reference's, so
+    no row was left out at the edge of a pass or beyond the first."""
+    args = _routed_to_held(held_rows)
+    assert held_row_bound(512, 2, 2, E) == 512
+    y, _, _, counts = jax.jit(lambda *a: _share(*a, 0, 2))(*args)
+    assert int(counts[:2].sum()) == held_rows
+    held = [args[1]] + [w[:2] for w in args[2:]]
+    cot = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (512, D)).astype(np.float32))
+
+    def reference(x, wr, wg, wu, wd):
+        with jax.default_matmul_precision("highest"):
+            return plain_keye._experts(x, {"router": wr, "gate": wg, "up": wu,
+                                           "down": wd}, 2, 0, jnp.float32)[0]
+
+    assert _close(y, reference(args[0], *held))
+    got_g = jax.jit(jax.grad(lambda x, wr, wg, wu, wd: (dropless_moe_ffn(
+        x, wr, wg, wu, wd, top_k=2, dtype=jnp.float32, first_expert=0,
+        norm_topk=True)[0] * cot).sum(), argnums=range(5)))(args[0], *held)
+    want_g = jax.grad(lambda *a: (reference(*a) * cot).sum(),
+                      argnums=range(5))(args[0], *held)
+    for name, got, want in zip(("x", "router", "gate", "up", "down"),
+                               got_g, want_g):
+        assert _close(got, want, rtol=3e-5), name
+
+
+@pytest.mark.parametrize("shape,bound", (
+    ((8192, 8, 8, 256), 4096),        # Kimi-Linear's and JoyAI's cells
+    ((8192, 8, 16, 128), 16384),      # Keye's
+    ((8192, 8, 64, 64), 65536),       # OLMoE's: every expert, every row
+    ((8192, 8, 64, 128), 65536),      # half the experts: twice the even part
+    ((512, 2, 2, 8), 512), ((48, 2, 2, 8), 96),     # these tests'
+    ((1000, 3, 5, 64), 512), ((1000, 3, 7, 64), 1024),      # in 512s, up
+))
+def test_the_row_bound_by_hand(shape, bound):
+    assert held_row_bound(*shape) == bound
+
+
+def test_the_compact_share_counts_the_layers_that_fit_the_bound():
+    """Made-up counts of three layers over 8 experts, 1,024 assignments
+    each, experts 2 and 3 held (bound 512): 512 and 100 fit, 513 does
+    not."""
+    from byteps_tpu.monitor import metrics
+
+    def layer(held_rows):
+        counts = np.zeros(8, np.int64)
+        counts[2], counts[3] = held_rows - 40, 40
+        counts[7] = 1024 - held_rows
+        return counts
+
+    out = publish_moe_stats([layer(512), layer(100), layer(513)], held=(2, 2))
+    assert out["bps_moe_compact_share"] == pytest.approx(2 / 3)
+    assert metrics._py_gauges["bps_moe_compact_share"] == pytest.approx(2 / 3)
+    assert out["bps_moe_held_load"] == pytest.approx(1125 / 768)
+    assert publish_moe_stats([layer(100)], held=(2, 2))[
+        "bps_moe_compact_share"] == 1.0
+    # half the experts held: a pass is every row, and always enough
+    assert publish_moe_stats([layer(1024)], held=(0, 4))[
+        "bps_moe_compact_share"] == 1.0
+    assert "bps_moe_compact_share" not in publish_moe_stats([layer(100)])
 
 
 def test_holding_every_expert_is_the_layer_olmoe_calls_bit_for_bit():
@@ -275,16 +409,33 @@ def test_counts_are_sown_only_when_asked_for_and_published():
         assert _close(x, y, rtol=1e-6)
 
 
-def test_the_gate_s_new_arguments_leave_the_lowered_step_as_it_was():
+@pytest.mark.parametrize("shape,loops,digest", (
+    ((2, 32), 16,
+     "30123efc7a3f9036f9dd190365acdec3cbf897ea1f8bf2fedc58b16eb4e12471"),
+    ((2, 256), 128,
+     "2f5fe18e5b810f3122ada42a7c8c089bb7dff8ce8fc2174501cbbcd9928ac0dc"),
+))
+def test_the_gate_s_new_arguments_leave_the_lowered_step_as_it_was(
+        shape, loops, digest):
     """PR 39 gave ``dropless_moe_ffn`` a scoring rule, a selection bias, an
     epsilon and a routed scale. At their defaults the gradient of
-    KeyeTiny's loss (a share: experts 0..1 of 8) lowers to the text it
-    lowered to at ``3f4a582``."""
+    KeyeTiny's loss (a share: experts 0..1 of 8) lowers to a pinned text.
+
+    Re-pinned in PR 43 (the commit after ``e700ff8``), which changes a
+    share's text by design: until then the digest was ``454cbe32…`` of the
+    text at ``3f4a582`` (12 loops at 64 tokens, all sparse attention's;
+    124 at 512; no ``case``). Each of the two expert layers now walks the
+    held experts' sorted rows in passes, forward and backward: a choice
+    between one pass and a loop of them each way, so four choices and four
+    loops more at either size. At 64 tokens a pass is all 128 rows, at 512
+    it is 512 of the 1,024. OLMoE's text (``tests/test_olmoe.py``) did not
+    move."""
     import hashlib
 
-    model, tokens = KeyeTiny(), np.zeros((2, 32), np.int32)
+    model, tokens = KeyeTiny(), np.zeros(shape, np.int32)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
     text = jax.jit(jax.grad(lambda p: keye_loss(
         model.apply(p, tokens), tokens))).lower(params).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == (
-        "454cbe32b2173f0eee3d720395bab9d48259ae3313ff10b06aa2fe36adde89e6")
+    assert text.count("stablehlo.while") == loops
+    assert text.count("stablehlo.case") == 4
+    assert hashlib.sha256(text.encode()).hexdigest() == digest

@@ -218,10 +218,10 @@ def test_the_rule_admits_the_latent_layer_s_two_widths(args, form):
 T, D, M, E, K = 48, 32, 24, 8, 2
 
 
-def _layer_inputs(seed=0):
+def _layer_inputs(seed=0, t=T):
     rng = np.random.default_rng(seed)
     scale = 1.0 / math.sqrt(D)
-    arrays = (rng.standard_normal((T, D)), rng.standard_normal((D, E)) * 0.5,
+    arrays = (rng.standard_normal((t, D)), rng.standard_normal((D, E)) * 0.5,
               rng.standard_normal((E, D, M)) * scale,
               rng.standard_normal((E, D, M)) * scale,
               rng.standard_normal((E, M, D)) * scale)
@@ -280,18 +280,24 @@ def test_gate_defaults_are_the_softmax_gate_and_bad_scoring_is_refused():
         dropless_moe_ffn(x, wr, wg, wu, wd, top_k=K, scoring="tanh")
 
 
-def test_the_shares_parts_add_up_with_the_shared_expert_counted_once():
+@pytest.mark.parametrize("t,tilt", ((T, 0.0), (512, 0.0), (512, 10.0)))
+def test_the_shares_parts_add_up_with_the_shared_expert_counted_once(t, tilt):
     """The model-configs guide's test: four chips hold two of eight experts
     each; each computes its experts' part and the shared expert whole. The
     four outputs less three copies of the shared expert's are the uncut
-    layer's (``plain.experts`` holding all eight)."""
-    x, wr, wg, wu, wd = _layer_inputs()
+    layer's (``plain.experts`` holding all eight). At 48 tokens a share's
+    pass is all 96 rows; at 512 it is 512 of the 1,024 rows, and with the
+    selection bias tilted to experts 0 and 1 the first share receives all
+    1,024 and takes two passes while the three others, which receive none,
+    take none."""
+    x, wr, wg, wu, wd = _layer_inputs(t=t)
     rng = np.random.default_rng(1)
     shared = {name: {"kernel": jnp.asarray(
         rng.standard_normal(shape).astype(np.float32) / math.sqrt(shape[0]))}
         for name, shape in (("gate", (D, M)), ("up", (D, M)),
                             ("down", (M, D)))}
-    bias = jnp.asarray(rng.standard_normal(E).astype(np.float32) * 0.1)
+    bias = jnp.asarray(rng.standard_normal(E).astype(np.float32) * 0.1
+                       + tilt * (np.arange(E) < 2))
     total = 0.0
     for first in range(0, E, 2):
         layer = KimiSparseMoe(E, 2, first, K, M, 2.446, dtype=jnp.float32)
@@ -317,12 +323,21 @@ def _model_and_params(rows=2, s=64):
     return model, model.init(jax.random.PRNGKey(0), tokens), tokens
 
 
-@pytest.mark.parametrize("rows", (1, 2))
-def test_model_loss_and_gradients_are_the_plain_reference_s(rows):
+@pytest.mark.parametrize("rows,pass_rows", ((1, None), (2, None), (2, 32)))
+def test_model_loss_and_gradients_are_the_plain_reference_s(
+        monkeypatch, rows, pass_rows):
     """Through a dense KDA layer, a KDA expert layer, an MLA expert layer
     (keys 24 wide, values 16) and another KDA expert layer. Loss 1e-6;
     gradients 5e-5 of a leaf's largest entry: four layers' sums in another
-    order, and the chunked scan against the token recurrence."""
+    order, and the chunked scan against the token recurrence. With
+    ``pass_rows`` a share's pass is held to 32 rows, so that each of the
+    three expert layers (2 of 8 experts, 256 rows, 64 of them a share's
+    even part) walks its rows in several passes inside ``KimiBlock``'s
+    ``nn.remat``, forward and backward."""
+    import byteps_tpu.parallel.moe as moe
+
+    if pass_rows:
+        monkeypatch.setattr(moe, "held_row_bound", lambda *shape: pass_rows)
     model, params, tokens = _model_and_params(rows)
     got, want = (jax.jit(jax.value_and_grad(f))(params) for f in (
         lambda p: kimi_linear_loss(model.apply(p, tokens)),
@@ -338,6 +353,10 @@ def test_model_loss_and_gradients_are_the_plain_reference_s(rows):
         assert _rel(g, w) <= 5e-5, name
         reached += bool(w.any())
     assert reached == len(flat) - 3     # every other leaf has a gradient
+    _, stats = model.apply(params, tokens, mutable=["moe_stats"])
+    # one pass everywhere, or no layer's held rows fit one
+    assert publish_moe_stats(stats["moe_stats"], held=(0, 2))[
+        "bps_moe_compact_share"] == (0 if pass_rows else 1)
 
 
 def test_the_comparison_fails_a_bf16_state():
@@ -431,18 +450,29 @@ def test_stats_are_sown_only_when_asked_for_and_published():
 
 def test_scopes_and_the_site_counter():
     """Each span of the tracing is in the lowered program, forward and
-    backward, and a trace of the model counts its three KDA sites."""
+    backward, and a trace of the model counts its three KDA sites. A share's
+    pass is one jitted function for every layer and both directions (PR 43):
+    the lowered text names the experts' scope from that function's top, and
+    only the compiler joins a callee's names to its callers', so that scope
+    is looked for in the compiled program's ``op_name``s, which is what a
+    device trace's ``tf_op`` holds."""
+    import re
+
     model, params, tokens = _model_and_params(1)
     before = metrics.counter(SCAN_SITES)
-    text = jax.jit(jax.grad(lambda p: kimi_linear_loss(
-        model.apply(p, tokens)))).lower(params).as_text(debug_info=True)
+    lowered = jax.jit(jax.grad(lambda p: kimi_linear_loss(
+        model.apply(p, tokens)))).lower(params)
+    text = lowered.as_text(debug_info=True)
     assert metrics.counter(SCAN_SITES) - before >= 3
     for scope in ("bps.kda.prep", "bps.kda.scan", "bps.kda.out",
-                  "bps.mla.attend", "bps.moe.shared", "bps.moe.route",
-                  "bps.moe.experts"):
+                  "bps.mla.attend", "bps.moe.shared", "bps.moe.route"):
         assert f"/{scope}/" in text, scope
         assert any(scope in line and "transpose(" in line
                    for line in text.splitlines()), scope
+    names = set(re.findall(r'op_name="([^"]*bps\.moe\.experts[^"]*)"',
+                           lowered.compile().as_text()))
+    assert any("/jvp(" in n and "transpose(" not in n for n in names)
+    assert any("/transpose(jvp(" in n for n in names)
 
 
 def test_the_model_trains_through_make_train_step_on_the_mesh():
