@@ -25,8 +25,9 @@ hands the host only its 1/k shard of the locally-summed gradient, so the
 host↔DCN leg carries exactly one gradient's worth of bytes per step
 regardless of local chip count — the reference's two-level pipeline
 (SURVEY.md §3.3) with XLA playing NCCL. Shards are declared as separate
-PS keys (``{name}.{j}``), preserving declaration-order priority
-(front-of-model first) at shard granularity.
+PS tensors (one ``ps.bind`` of every leaf's shards, leaf by leaf),
+preserving declaration-order priority (front-of-model first) at shard
+granularity.
 
 Priorities follow parameter declaration order (flattened tree order =
 front-of-model first for standard model pytrees), so early layers' pulls
@@ -53,6 +54,7 @@ from jax.experimental import io_callback
 from jax.sharding import PartitionSpec as P
 
 import byteps_tpu.jax as bps
+from byteps_tpu.jax import ps
 from byteps_tpu.jax._compat import shard_map as _shard_map
 
 
@@ -76,6 +78,7 @@ class _TapState:
         # (leaf_idx, shard_idx) -> declared tensor id / in-flight handle
         self.tids: Dict[Tuple[int, int], int] = {}
         self.shard_elems: Dict[int, int] = {}
+        self.dtypes: list = []  # leaf idx -> its shards' wire dtype
         self.blocks: Dict[int, int] = {}
         self.cv = threading.Condition()
         self.inflight: Dict[Tuple[int, int], Tuple[int, np.ndarray]] = {}
@@ -89,6 +92,7 @@ class _TapState:
 
     def declare_all(self, leaves) -> None:
         k = self.n_shards
+        shards = []
         for i, leaf in enumerate(leaves):
             n = int(np.size(leaf))
             if self.wire_dtype == "int8":
@@ -100,12 +104,17 @@ class _TapState:
             self.shard_elems[i] = padded // k
             # Quantized/cast wires always land as f32 on the host (the C
             # codecs and summation operate on f32).
-            dt = (np.dtype(leaf.dtype).name
-                  if self.wire_dtype == "float32" else "float32")
-            for j in range(k):
-                self.tids[(i, j)] = self.client.declare(
-                    f"{self.prefix}_{i}.{j}", self.shard_elems[i], dt,
-                    compression=self.compression_config)
+            dt = leaf.dtype if self.wire_dtype == "float32" else np.float32
+            shards += [jax.ShapeDtypeStruct((self.shard_elems[i],), dt)] * k
+        # The binding declares (shape-signed names, on the bridge thread)
+        # and is asked for nothing else: the taps' callbacks hand their
+        # shards to the client themselves — they run on the runtime's
+        # threads, mid-program, and must not wait on the bridge.
+        bound = ps.bind(self.prefix, shards,
+                        compression=self.compression_config)
+        for n, tid in enumerate(bound.tids):
+            self.tids[divmod(n, k)] = tid
+        self.dtypes = bound.wire_dtypes[::k]
 
     def push_shard(self, idx: int, j, g: np.ndarray,
                    scales: Optional[np.ndarray] = None) -> None:
@@ -120,8 +129,7 @@ class _TapState:
                    * np.asarray(scales, np.float32).reshape(-1, 1)
                    ).reshape(-1)
         else:
-            arr = np.array(g, dtype=np.float32 if self.wire_dtype != "float32"
-                           else None, copy=True).reshape(-1)
+            arr = np.array(g, dtype=self.dtypes[idx], copy=True).reshape(-1)
         if self.bpps > 1:
             # Gradient accumulation (reference: DistributedOptimizer
             # backward_passes_per_step): sum K backward passes host-side,

@@ -24,7 +24,6 @@ import weakref
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 import byteps_tpu.jax as bps
@@ -96,11 +95,12 @@ def drain_bridge() -> None:
     if p is not None:
         p.shutdown(wait=True)
 
-# (prefix, n_leaves) -> list of tensor ids. Declares are per-tensor-
-# lifetime, not per-step: each declare is a ctypes call into the C core's
-# locked registry (and, on first sight, a blocking INIT_KEY round trip to
-# every owning server) — pure per-step overhead once the tree shape is
-# fixed. Cleared by bps.init()/shutdown() via reset_declare_cache().
+# (prefix, shape signature, wire dtypes) -> the tree's WireTree. Declares
+# are per-tensor-lifetime, not per-step: each declare is a ctypes call
+# into the C core's locked registry (and, on first sight, a blocking
+# INIT_KEY round trip to every owning server) — pure per-step overhead once
+# the tree shape is fixed. Cleared by bps.init()/shutdown() via
+# reset_declare_cache().
 _tid_cache: dict = {}
 # Steps that declared at least one NEW tensor (test hook: after warm-up
 # this must stop growing — one registration per tensor lifetime).
@@ -177,9 +177,9 @@ def _is_host_memory_of(dev, arr: np.ndarray) -> bool:
 
 
 def _writable(arr: np.ndarray) -> np.ndarray:
-    """A buffer the C core may push FROM and pull INTO in place, for the
-    callers that stage a fresh one per call (``ps_broadcast``, the async and
-    the bucketed step; ``ps_push_pull`` stages into its pool, ``_Slot``).
+    """A buffer the C core may push FROM and pull INTO in place, for
+    ``ps_broadcast``, which stages a fresh one per call (a ``WireTree``
+    stages into the pool, ``_Slot``).
     A ``jax.Array`` hands back a read-only host array — on the CPU backend
     a zero-copy view of the jax buffer, on the TPU the array's cached host
     copy (196 of 196 leaves of a GPT-2 tree; PERF.md, PR 24) — and
@@ -227,9 +227,11 @@ def _codec_active(st) -> bool:
                 or os.environ.get("BYTEPS_COMPRESSOR", ""))
 
 
-def _wire_plan(leaves, codec: bool):
+def _wire_plan(leaves, codec: bool, config: Optional[str] = None):
     """Per-leaf (declare dtype, compression override) so half-precision
-    wire and lossy codecs compose instead of fail-stopping:
+    wire and lossy codecs compose instead of fail-stopping. ``config`` is
+    a caller's own codec string for this tree (``bind``'s ``compression``)
+    and stands where the fleet default would be inherited (None):
 
     - float32 + codec: inherit the default codec (None).
     - bfloat16/float16 + codec: declare FLOAT32 and upcast the staged
@@ -243,25 +245,188 @@ def _wire_plan(leaves, codec: bool):
     plan = []
     for leaf in leaves:
         name = np.dtype(leaf.dtype).name
-        if not codec:
-            plan.append((name, None))
-        elif name == "float32":
-            plan.append((name, None))
+        if not codec or name == "float32":
+            plan.append((name, config))
         elif name in ("bfloat16", "float16"):
-            plan.append(("float32", None))
+            plan.append(("float32", config))
         else:
             plan.append((name, ""))
     return plan
 
 
-def _tids(client, prefix: str, leaves, plan):
+class WireTree:
+    """A tree bound to the wire (``bind``): the tensor ids its leaves were
+    declared under (``tids``, tree order — declaration order is the PS
+    priority) and the dtype each crosses in (``wire_dtypes``).
+
+    Leaves move through it a whole tree at once (``push_pull``) or in
+    pieces — ``push`` some leaves now, others later, then one ``finish`` —
+    for a caller whose leaves become ready program by program. Either way,
+    on the bridge thread, a leaf is staged into the buffer its tensor id
+    owns (``_Slot``) as it lands and goes back to the device as its handle
+    settles, and an error leaves only after every handle in flight has
+    settled. One round at a time: ``finish`` or an error ends it."""
+
+    def __init__(self, tids, plan):
+        self.tids = tids
+        self.wire_dtypes = [wire_dtype for wire_dtype, _ in plan]
+        # leaf index -> (handle, staged buffer, leaf) of what is in flight.
+        # A leaf's staged buffer (its tid's _Slot, in the leaf's shape) is
+        # push source, pull destination and device_put source; the C core
+        # owns it until its handle settles.
+        self._staged: dict = {}
+
+    def push_pull(self, leaves, average: bool = True,
+                  async_mode: Optional[bool] = None):
+        """``ps_push_pull`` of the tree's leaves to THESE tensors."""
+        return _run_ordered(_ps_push_pull_impl, list(leaves), average, self,
+                            async_mode)
+
+    def push(self, indices, leaves, average: bool = True,
+             async_mode: Optional[bool] = None) -> None:
+        """Hand over ``leaves``, the tree's leaves ``indices``: start their
+        D2H, stage and enqueue each as it lands."""
+        _run_ordered(self._push, indices, _as_arrays(leaves), average,
+                     async_mode)
+
+    def finish(self):
+        """Wait for every leaf pushed since the last round, in tree order,
+        put each back as it settles; the summed leaves in tree order."""
+        return _run_ordered(self._finish)
+
+    def _settle(self):
+        # What _wait_all guarantees, from wherever the failure came; the
+        # caller raises its own, the first, error.
+        with contextlib.suppress(Exception):
+            _wait_all(bps._st().ps_client, self._staged.values())
+        self._staged.clear()
+
+    def _push(self, indices, leaves, average, async_mode):
+        st = bps._st()
+        client = st.ps_client
+        if async_mode is None:
+            async_mode = st.config.enable_async
+        try:
+            with jax.profiler.TraceAnnotation(SPAN_D2H):
+                # Start every transfer now (what jax.device_get does before
+                # it blocks; a numpy leaf has nothing to start), so that
+                # leaf 0's runs first. A buffer that is ready is copied when
+                # asked; the copies of a program still running start at its
+                # end, the one asked for LAST first (measured on the TPU
+                # runtime: asked in declaration order, leaf 0 lands last,
+                # 51 ms after the program's end instead of 2-6; PERF.md,
+                # PR 25) — so ask for those in reverse.
+                device = [l for l in leaves
+                          if hasattr(l, "copy_to_host_async")]
+                if device and not device[0].is_ready():
+                    device.reverse()
+                for leaf in device:
+                    leaf.copy_to_host_async()
+                np.asarray(leaves[0])
+            wire_nbytes = [l.size * np.dtype(self.wire_dtypes[i]).itemsize
+                           for i, l in zip(indices, leaves)]
+            if not self._staged:  # a round's later pieces add to its first
+                stage_stats.update(reused_bytes=0, bytes=0)
+            stage_stats["reused_bytes"] += sum(
+                n for i, n in zip(indices, wire_nbytes)
+                if self.tids[i] in _slots)
+            stage_stats["bytes"] += sum(wire_nbytes)
+            with jax.profiler.TraceAnnotation(SPAN_STAGE, **stage_stats):
+                for i, leaf in zip(indices, leaves):
+                    tid = self.tids[i]
+                    # blocks only until THIS leaf has landed
+                    host = np.asarray(leaf)
+                    slot = _slots.get(tid)
+                    if slot is None:
+                        slot = _slots[tid] = _Slot(host.size,
+                                                   self.wire_dtypes[i])
+                    arr = slot.fill(host)
+                    h = client.push_pull(tid, arr, average=average,
+                                         async_mode=async_mode)
+                    self._staged[i] = (h, arr, leaf)
+        except BaseException:
+            self._settle()
+            raise
+
+    def _put(self, i, arr, leaf):
+        if arr.dtype == leaf.dtype:
+            dev = jax.device_put(arr)
+            if _is_host_memory_of(dev, arr):
+                # The result IS the slot's buffer: the buffer goes with
+                # it and the tensor's next call allocates another.
+                del _slots[self.tids[i]]
+            return dev
+        # Downcast an upcast-staged leaf on the host first so the upload
+        # pays half-precision bytes too (the device's astype is a no-op).
+        return jax.device_put(arr.astype(leaf.dtype))
+
+    def _finish(self):
+        client = bps._st().ps_client
+        last = len(self.tids) - 1
+        try:
+            # KeyError: finish() before that leaf was pushed
+            leaves = [self._staged[i][2] for i in range(last + 1)]
+            nbytes = [l.size * l.dtype.itemsize for l in leaves]
+            devs = []
+            # Handles settle roughly in declaration order (that is their
+            # priority), so each leaf goes back to the device while the
+            # round still works on the ones behind it — the last leaf at
+            # least, when the rest settled while it was being staged — and
+            # only the last leaf's device_put waits for the whole round.
+            with jax.profiler.TraceAnnotation(SPAN_WAIT):
+                for i in range(last + 1):
+                    # wait settles h whether it returns or raises
+                    h, arr, leaf = self._staged.pop(i)
+                    client.wait(h)
+                    if i < last:  # the last put is bps.ps.h2d's
+                        devs.append(self._put(i, arr, leaf))
+            put_stats.update(put_early_bytes=sum(nbytes[:-1]),
+                             bytes=sum(nbytes))
+            with jax.profiler.TraceAnnotation(SPAN_H2D, **put_stats):
+                devs.append(self._put(last, arr, leaf))
+                out = [d.reshape(leaf.shape).astype(leaf.dtype)
+                       for d, leaf in zip(devs, leaves)]
+            for tid, result in zip(self.tids, out):
+                if tid in _slots:
+                    _slots[tid].result = weakref.ref(result)
+        except BaseException:
+            self._settle()
+            raise
+        return out
+
+
+def bind(prefix: str, leaves, compression: Optional[str] = None) -> WireTree:
+    """Bind a tree to the wire: declare ``leaves`` (arrays, or anything
+    with their ``size`` and ``dtype``, in tree order) under ``prefix`` on
+    the bridge thread, once — a tree of the same prefix, leaf sizes and
+    dtypes gets the binding an earlier call made. ``compression`` is a
+    C-core codec string for this tree's float leaves in place of the fleet
+    default (``BYTEPS_COMPRESSOR``)."""
+    return _run_ordered(_bind, prefix, _as_arrays(leaves), compression)
+
+
+def _client():
+    client = bps._st().ps_client
+    if client is None:
+        raise RuntimeError(
+            "PS mode is not active (init with BYTEPS_PS_MODE=ps / "
+            "DMLC_NUM_SERVER>0)")
+    return client
+
+
+def _bind(prefix, leaves, compression=None):
     global declare_steps
+    client = _client()
+    plan = _wire_plan(leaves, _codec_active(bps._st()) if compression is None
+                      else bool(compression), compression)
     # Shape/dtype signature in the key: a same-named tree with different
     # leaf sizes must re-declare (the C core rejects size changes).
     sig = tuple((int(l.size), str(l.dtype)) for l in leaves)
     key = (prefix, sig, tuple(p[0] for p in plan))
-    tids = _tid_cache.get(key)
-    if tids is None:
+    if compression is not None:
+        key += (compression,)
+    bound = _tid_cache.get(key)
+    if bound is None:
         declare_steps += 1
         # The shape signature goes INTO the wire name: two different-shaped
         # trees under the same prefix (e.g. two unnamed push_pull call
@@ -271,14 +436,13 @@ def _tids(client, prefix: str, leaves, plan):
         # (python's hash() is salted per process and would NOT be).
         import zlib
         shape_key = zlib.crc32(repr(key).encode())
-        tids = [
+        bound = _tid_cache[key] = WireTree([
             client.declare(f"{prefix}_{shape_key:08x}_{i}", int(leaf.size),
                            wire_dtype, compression=comp)
             for i, (leaf, (wire_dtype, comp)) in enumerate(zip(leaves,
                                                                plan))
-        ]
-        _tid_cache[key] = tids
-    return tids
+        ], plan)
+    return bound
 
 
 def ps_push_pull(tree, average: bool = True, prefix: str = "grad",
@@ -304,117 +468,31 @@ def ps_push_pull(tree, average: bool = True, prefix: str = "grad",
     leaf's H2D transfer is issued the moment its handle has settled, so
     only the last leaf's upload is left after the round. Tensor declares
     are cached for the tree's lifetime instead of re-registering every
-    step. Executes on the FIFO bridge thread so declares keep a
+    step (the tree's ``WireTree``, looked up by prefix and shape
+    signature). Executes on the FIFO bridge thread so declares keep a
     fleet-consistent order against async ops.
     """
     return _run_ordered(_ps_push_pull_impl, tree, average, prefix,
                         async_mode)
 
 
-def _ps_push_pull_impl(tree, average, prefix, async_mode):
-    st = bps._st()
-    client = st.ps_client
-    if client is None:
-        raise RuntimeError(
-            "PS mode is not active (init with BYTEPS_PS_MODE=ps / "
-            "DMLC_NUM_SERVER>0)")
-    if async_mode is None:
-        async_mode = st.config.enable_async
+def _ps_push_pull_impl(tree, average, where, async_mode):
+    """``where``: the prefix to look the binding up by, or the binding."""
+    _client()
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if not leaves:
         return tree
     leaves = _as_arrays(leaves)
-    nbytes = [l.size * l.dtype.itemsize for l in leaves]
-    total = sum(nbytes)
     # mono_ns is CLOCK_MONOTONIC, the C core's NowUs() clock, read at the
     # span's start: (mono_ns - the event's ts) maps the core's stamps onto
     # the capture's clock (utils/timeline.py, docs/timeline.md).
     with jax.profiler.TraceAnnotation(
             SPAN_PUSH_PULL, mono_ns=time.monotonic_ns(), leaves=len(leaves),
-            bytes=total):
-        plan = _wire_plan(leaves, _codec_active(st))
-        tids = _tids(client, prefix, leaves, plan)
-        # (handle, staged buffer, leaf) per enqueued leaf, and how many of
-        # the handles have settled. A leaf's staged buffer (its tid's
-        # _Slot, in the leaf's shape) is push source, pull destination and
-        # device_put source; the C core owns it until its handle settles.
-        staged, settled, devs = [], 0, []
-        wire_nbytes = [l.size * np.dtype(w).itemsize
-                       for l, (w, _) in zip(leaves, plan)]
-
-        def put(i):
-            _, arr, leaf = staged[i]
-            if arr.dtype == leaf.dtype:
-                dev = jax.device_put(arr)
-                if _is_host_memory_of(dev, arr):
-                    # The result IS the slot's buffer: the buffer goes with
-                    # it and the tensor's next call allocates another.
-                    del _slots[tids[i]]
-            else:
-                # Downcast an upcast-staged leaf on the host first so the
-                # upload pays half-precision bytes too (the device-side
-                # astype is then a no-op).
-                dev = jax.device_put(arr.astype(leaf.dtype))
-            devs.append(dev)
-
-        try:
-            with jax.profiler.TraceAnnotation(SPAN_D2H):
-                # Start every transfer now (what jax.device_get does before
-                # it blocks; a numpy leaf has nothing to start), so that
-                # leaf 0's runs first. A buffer that is ready is copied when
-                # asked; the copies of a program still running start at its
-                # end, the one asked for LAST first (measured on the TPU
-                # runtime: asked in declaration order, leaf 0 lands last,
-                # 51 ms after the program's end instead of 2-6; PERF.md,
-                # PR 25) — so ask for those in reverse.
-                device = [l for l in leaves
-                          if hasattr(l, "copy_to_host_async")]
-                if device and not device[0].is_ready():
-                    device.reverse()
-                for leaf in device:
-                    leaf.copy_to_host_async()
-                np.asarray(leaves[0])
-            stage_stats.update(
-                reused_bytes=sum(n for tid, n in zip(tids, wire_nbytes)
-                                 if tid in _slots),
-                bytes=sum(wire_nbytes))
-            with jax.profiler.TraceAnnotation(SPAN_STAGE, **stage_stats):
-                for tid, leaf, (wire_dtype, _) in zip(tids, leaves, plan):
-                    # blocks only until THIS leaf has landed
-                    host = np.asarray(leaf)
-                    slot = _slots.get(tid)
-                    if slot is None:
-                        slot = _slots[tid] = _Slot(host.size, wire_dtype)
-                    arr = slot.fill(host)
-                    h = client.push_pull(tid, arr, average=average,
-                                         async_mode=async_mode)
-                    staged.append((h, arr, leaf))
-            # Handles settle roughly in declaration order (that is their
-            # priority), so each leaf goes back to the device while the
-            # round still works on the ones behind it — the last leaf at
-            # least, when the rest settled while it was being staged — and
-            # only the last leaf's device_put waits for the whole round.
-            with jax.profiler.TraceAnnotation(SPAN_WAIT):
-                for i, (h, _, _) in enumerate(staged):
-                    settled += 1  # wait settles h whether it returns or raises
-                    client.wait(h)
-                    if i < len(staged) - 1:  # the last put is bps.ps.h2d's
-                        put(i)
-            put_stats.update(put_early_bytes=total - nbytes[-1], bytes=total)
-            with jax.profiler.TraceAnnotation(SPAN_H2D, **put_stats):
-                put(len(staged) - 1)
-                out = [d.reshape(leaf.shape).astype(leaf.dtype)
-                       for d, leaf in zip(devs, leaves)]
-            for tid, result in zip(tids, out):
-                if tid in _slots:
-                    _slots[tid].result = weakref.ref(result)
-        except BaseException:
-            # What _wait_all guarantees, from wherever the failure came:
-            # no staging buffer is freed under the C core, and nothing more
-            # goes to the device. The first error is the one raised.
-            with contextlib.suppress(Exception):
-                _wait_all(client, staged[settled:])
-            raise
+            bytes=sum(l.size * l.dtype.itemsize for l in leaves)):
+        bound = (where if isinstance(where, WireTree)
+                 else _bind(where, leaves))
+        bound._push(range(len(leaves)), leaves, average, async_mode)
+        out = bound._finish()
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -426,19 +504,16 @@ def ps_broadcast(tree, root_rank: int = 0, prefix: str = "param"):
 
 
 def _ps_broadcast_impl(tree, root_rank, prefix):
-    st = bps._st()
-    client = st.ps_client
-    if client is None:
-        raise RuntimeError("PS mode is not active")
+    client = _client()
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if not leaves:
         return tree
     leaves = _as_arrays(leaves)
-    plan = _wire_plan(leaves, _codec_active(st))
-    tids = _tids(client, prefix, leaves, plan)
+    bound = _bind(prefix, leaves)
     host = jax.device_get(leaves)
     staged = []
-    for tid, arr, leaf, (wire_dtype, _) in zip(tids, host, leaves, plan):
+    for tid, arr, leaf, wire_dtype in zip(bound.tids, host, leaves,
+                                          bound.wire_dtypes):
         arr = _writable(arr)
         if arr.dtype != np.dtype(wire_dtype):
             arr = arr.astype(wire_dtype)
@@ -456,7 +531,4 @@ def _ps_broadcast_impl(tree, root_rank, prefix):
 
 def ps_barrier() -> None:
     """Fleet-wide worker barrier through the scheduler."""
-    st = bps._st()
-    if st.ps_client is None:
-        raise RuntimeError("PS mode is not active")
-    st.ps_client.barrier()
+    _client().barrier()

@@ -18,7 +18,6 @@ from functools import partial
 from typing import Callable, Optional
 
 import jax
-import jax.numpy as jnp
 import optax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -77,6 +76,39 @@ def make_train_step(
     return jax.jit(_step, **jit_kwargs)
 
 
+def ps_grad_step(value_and_grad, mesh, axes, average, compress):
+    """The device half of a PS step before the host boundary, jitted:
+    ``value_and_grad(params, batch) -> (loss, grads)`` per chip, the local
+    chips reduced inside the program, ``compress`` applied to what leaves
+    it. The serial step differentiates the whole tree; a bucket program
+    (``bucketed.py``) the leaves of its bucket."""
+
+    @jax.jit
+    @partial(_shard_map, mesh=mesh, in_specs=(P(), P(axes)),
+             out_specs=(P(), P()), check_vma=False)
+    def grad_step(params, batch):
+        loss, grads = value_and_grad(params, batch)
+        reduce = lax.pmean if average else lax.psum
+        for ax in axes:
+            grads = jax.tree_util.tree_map(
+                lambda g, a=ax: reduce(g, a), grads)
+            loss = lax.pmean(loss, ax)
+        grads = jax.tree_util.tree_map(compress, grads)
+        return loss, grads
+
+    return grad_step
+
+
+def ps_apply_step(optimizer, donate):
+    """The device half after it: the optimizer on the summed gradients."""
+
+    def apply_step(params, opt_state, grads):
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
+
+    return jax.jit(apply_step, donate_argnums=(0, 1) if donate else ())
+
+
 def _make_ps_train_step(loss_fn, optimizer, mesh, axes, average, compression,
                         donate, prefix="grad"):
     """PS-mode step: local-chip level inside jit, cross-host DCN level
@@ -106,25 +138,9 @@ def _make_ps_train_step(loss_fn, optimizer, mesh, axes, average, compression,
             "string (e.g. BYTEPS_COMPRESSOR=onebit or type=dithering;k=4), "
             "or use Compression.bf16/fp16 for an in-jit wire cast.")
 
-    @jax.jit
-    @partial(_shard_map, mesh=mesh, in_specs=(P(), P(axes)),
-             out_specs=(P(), P()), check_vma=False)
-    def grad_step(params, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        reduce = lax.pmean if average else lax.psum
-        for ax in axes:
-            grads = jax.tree_util.tree_map(
-                lambda g, a=ax: reduce(g, a), grads)
-            loss = lax.pmean(loss, ax)
-        grads = jax.tree_util.tree_map(compression.compress, grads)
-        return loss, grads
-
-    def apply_step(params, opt_state, grads):
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state
-
-    apply_jit = jax.jit(apply_step,
-                        donate_argnums=(0, 1) if donate else ())
+    grad_step = ps_grad_step(jax.value_and_grad(loss_fn), mesh, axes, average,
+                             compression.compress)
+    apply_jit = ps_apply_step(optimizer, donate)
 
     def step(params, opt_state, batch):
         # Host spans for a jax.profiler capture (names: jax/ps.py's table);
@@ -163,19 +179,21 @@ def make_async_train_step(
     (params, opt_state, loss)`` where the returned params are the freshly
     pulled server state.
     """
-    from byteps_tpu.jax.ps import ps_broadcast
+    from byteps_tpu.jax import ps
 
-    st = bps._st()
-    client = st.ps_client
-    if client is None:
-        raise RuntimeError(
-            "make_async_train_step needs PS mode (DMLC_NUM_SERVER>0)")
-
-    # Seed: rank 0's initial params become the server-resident copy —
+    # Seed (raises unless PS mode is active, DMLC_NUM_SERVER>0): rank 0's
+    # initial params become the server-resident copy —
     # CMD_BCAST_PUSH initialises the async accumulator for THE SAME wire
     # keys the step pushes deltas to, and everyone starts from the same
     # values.
-    params = ps_broadcast(params, root_rank=0, prefix=prefix)
+    params = ps.ps_broadcast(params, root_rank=0, prefix=prefix)
+    leaves0, treedef = jax.tree_util.tree_flatten(params)
+    # Bound from the PARAMETERS' leaves: the binding the broadcast above
+    # made (same prefix and shape signature, so nothing is declared here).
+    # Keys of the updates' own would be fresh, never-initialised server
+    # tensors — the first delta would silently BECOME the parameters
+    # instead of updating them.
+    wire = ps.bind(prefix, leaves0)
 
     @jax.jit
     def local_update(p, opt_state, batch):
@@ -183,36 +201,10 @@ def make_async_train_step(
         updates, opt_state = optimizer.update(grads, opt_state, p)
         return updates, opt_state, loss
 
-    leaves0, treedef = jax.tree_util.tree_flatten(params)
-    # Wire keys MUST be the ones ps_broadcast seeded: _tids derives the
-    # same `{prefix}_{crc32:08x}_{i}` names (and hits its cache, since
-    # the broadcast above registered this exact tree). Declaring bare
-    # `{prefix}_{i}` here instead would push the deltas to fresh,
-    # never-initialised server keys — the first delta would silently
-    # BECOME the parameters instead of updating them.
-    from byteps_tpu.jax.ps import (_as_arrays, _codec_active, _tids,
-                                   _wait_all, _wire_plan, _writable)
-
-    plan_leaves = _as_arrays(leaves0)
-    tids = _tids(client, prefix, plan_leaves,
-                 _wire_plan(plan_leaves, _codec_active(st)))
-
     def step(params, opt_state, batch):
         updates, opt_state, loss = local_update(params, opt_state, batch)
-        up_leaves = jax.tree_util.tree_flatten(updates)[0]
-        # ONE batched D2H for the whole delta tree (per-leaf np.asarray
-        # pays the host-boundary dispatch latency once per leaf).
-        host = jax.device_get(up_leaves)
-        staged = []
-        for tid, arr in zip(tids, host):
-            arr = _writable(arr)
-            h = client.push_pull(tid, arr, average=False, async_mode=True)
-            staged.append((h, arr, None))
-        _wait_all(client, staged)  # settle every handle before surfacing
-        # ONE batched H2D for the pulled server state.
-        devs = jax.device_put([arr for _, arr, _ in staged])
-        fresh = [d.reshape(leaf.shape).astype(leaf.dtype)
-                 for d, leaf in zip(devs, leaves0)]
+        fresh = wire.push_pull(jax.tree_util.tree_leaves(updates),
+                               average=False, async_mode=True)
         return (jax.tree_util.tree_unflatten(treedef, fresh), opt_state,
                 loss)
 

@@ -434,11 +434,10 @@ def phase_overlap(prob: Problem, ref_losses, out_dir: str,
                     lambda: make_overlapped_train_step(
                         prob.loss_fn, prob.tx, prefix="taps"),
                     prob, ref_losses, steps)
-                rec["bucketed_multi_program"] = _ps_steps(
+                rec["bucketed"] = _ps_steps(
                     "overlap/bucketed",
                     lambda: make_bucketed_overlap_step(
-                        prob.loss_fn, prob.tx, multi_program=True,
-                        prefix="bkt"),
+                        prob.loss_fn, prob.tx, prefix="bkt"),
                     prob, ref_losses, steps)
         finally:
             bps.shutdown()

@@ -1726,12 +1726,11 @@ def jax_bucketed_main() -> int:
                               jnp.float32) * 0.4,
         }
         tx = optax.sgd(0.1)
-        multi = os.environ.get("BPS_BUCKET_MODE", "multi") != "single"
         wire = os.environ.get("BPS_OVERLAP_WIRE") or "float32"
         comp = os.environ.get("BPS_OVERLAP_COMPRESSION") or None
         step = make_bucketed_overlap_step(
             loss_fn, tx, n_buckets=int(os.environ.get("BPS_BUCKET_N", "2")),
-            multi_program=multi, wire_dtype=wire, compression_config=comp)
+            wire_dtype=wire, compression_config=comp)
         params = jax.tree_util.tree_map(jnp.array, params0)
         opt_state = tx.init(params)
         per = 8
@@ -1769,8 +1768,7 @@ def jax_bucketed_main() -> int:
             np.testing.assert_allclose(
                 np.asarray(params[k]), np.asarray(ref_params[k]),
                 rtol=rtol, atol=atol)
-        print(f"worker {rank}: jax_bucketed OK "
-              f"({'multi' if multi else 'single'}, wire={wire})")
+        print(f"worker {rank}: jax_bucketed OK (wire={wire})")
         return 0
     finally:
         bps_jax.shutdown()

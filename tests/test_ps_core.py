@@ -560,17 +560,7 @@ def test_jax_bucketed_overlap_matches_single_process():
     io_callback-free design): per-bucket gradient programs + the
     D2H/DCN/H2D bucket pipeline reproduce single-process numerics."""
     run_topology(2, 1, WORKER, mode="jax_bucketed",
-                 extra={"BYTEPS_PS_MODE": "ps", "XLA_FLAGS": "",
-                        "BPS_BUCKET_MODE": "multi"},
-                 timeout=240)
-
-
-def test_jax_bucketed_single_program_pipeline():
-    """Bucketed overlap, single-program variant (boundary-leg pipelining
-    only — no recompute) matches single-process numerics too."""
-    run_topology(2, 1, WORKER, mode="jax_bucketed",
-                 extra={"BYTEPS_PS_MODE": "ps", "XLA_FLAGS": "",
-                        "BPS_BUCKET_MODE": "single"},
+                 extra={"BYTEPS_PS_MODE": "ps", "XLA_FLAGS": ""},
                  timeout=240)
 
 
@@ -582,7 +572,6 @@ def test_jax_bucketed_multichip_bf16_wire():
                  extra={"BYTEPS_PS_MODE": "ps",
                         "XLA_FLAGS":
                             "--xla_force_host_platform_device_count=4",
-                        "BPS_BUCKET_MODE": "multi",
                         "BPS_OVERLAP_WIRE": "bfloat16",
                         "BPS_BUCKET_N": "3"},
                  timeout=240)
@@ -594,7 +583,6 @@ def test_jax_bucketed_with_compression():
     in the tap path."""
     run_topology(2, 1, WORKER, mode="jax_bucketed",
                  extra={"BYTEPS_PS_MODE": "ps", "XLA_FLAGS": "",
-                        "BPS_BUCKET_MODE": "single",
                         "BPS_OVERLAP_COMPRESSION":
                             "type=topk;k=24;ef=vanilla"},
                  timeout=240)

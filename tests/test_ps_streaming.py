@@ -16,148 +16,7 @@ import numpy as np
 import pytest
 
 from byteps_tpu.jax import ps
-
-
-class Leaf:
-    """What ``ps.py`` sees of a device array: ``dtype`` / ``size`` / ``shape``,
-    ``is_ready``, ``copy_to_host_async`` and ``__array__``, which hands back
-    a read-only host copy as ``jax.Array`` does."""
-
-    def __init__(self, log, index, value, fail=False, ready=True):
-        self._log, self._index, self._fail = log, index, fail
-        self._ready = ready
-        self._value = np.asarray(value)
-        self._value.flags.writeable = False
-        self.dtype, self.size = self._value.dtype, self._value.size
-        self.shape = self._value.shape
-
-    def is_ready(self):
-        return self._ready
-
-    def copy_to_host_async(self):
-        self._log.append(("d2h", self._index))
-
-    def __array__(self, dtype=None, copy=None):
-        self._log.append(("take", self._index))
-        if self._fail:
-            raise RuntimeError(f"leaf {self._index} lost")
-        return self._value
-
-
-class Client:
-    """Handles are 0, 1, 2, ... in enqueue order. The "sum" of two equal
-    workers lands in the staged buffer when its handle is waited — a buffer
-    put to the device before that would carry the unsummed values."""
-
-    def __init__(self, log, fail_wait=(), refuse_enqueue=None):
-        self._log, self._fail_wait = log, set(fail_wait)
-        self._refuse, self.buffers, self.wire_dtypes = refuse_enqueue, [], []
-
-    def declare(self, name, nelem, dtype, compression=None):
-        self.wire_dtypes.append(np.dtype(dtype).name)
-        return len(self.wire_dtypes) - 1
-
-    def push_pull(self, tid, arr, average=True, async_mode=False):
-        h = len(self.buffers)
-        if h == self._refuse:
-            raise RuntimeError(f"enqueue {h} refused")
-        assert arr.flags.writeable and arr.flags.c_contiguous
-        assert arr.dtype.name == self.wire_dtypes[tid]
-        self._log.append(("enqueue", h))
-        self.buffers.append(arr)
-        return h
-
-    def wait(self, h):
-        self._log.append(("wait", h))
-        if h in self._fail_wait:
-            raise RuntimeError(f"handle {h} failed")
-        self.buffers[h] *= 2
-
-
-class Aliased(np.ndarray):
-    """What ``jax.device_put`` returns where the device's memory is the
-    host's and the buffer is aligned (the CPU backend): the host buffer
-    itself, under an array's name."""
-
-    def devices(self):
-        return [types.SimpleNamespace(platform="cpu")]
-
-    def unsafe_buffer_pointer(self):
-        return self.ctypes.data
-
-
-class Uploaded:
-    """What ``jax.device_put`` returns on a device with memory of its own: a
-    copy of the host buffer as it was at the call, in the order of the puts
-    in ``uploads``. ``ready`` False stands for an upload still reading the
-    host buffer: ``block_until_ready`` is then logged."""
-
-    def __init__(self, log, uploads, host):
-        self._log, self.index, self.ready = log, len(uploads), True
-        self.value, self.source = np.array(host), host
-        log.append(("put", host.nbytes))
-        uploads.append(self)
-
-    def devices(self):
-        return [types.SimpleNamespace(platform="tpu")]
-
-    def reshape(self, shape):
-        assert shape == self.value.shape
-        return self
-
-    def astype(self, dtype):
-        assert dtype == self.value.dtype
-        return self
-
-    def is_deleted(self):
-        return False
-
-    def block_until_ready(self):
-        if not self.ready:
-            self._log.append(("block", self.index))
-            self.ready = True
-        return self
-
-
-def retake(tree, scale):
-    """The same tree signature with other values (leaf i: scale × (i + 1))."""
-    return [Leaf(l._log, l._index, np.full(l.shape, scale * (l._index + 1),
-                                           l.dtype)) for l in tree]
-
-
-@pytest.fixture
-def bridge(monkeypatch):
-    """``bridge(sizes, **client)`` → (log, client, tree): the program state
-    of a worker in PS mode whose client and ``device_put`` record into
-    ``log``; leaf ``i`` holds ``sizes[i]`` float32 of value ``i + 1``."""
-    log = []
-    monkeypatch.delenv("BYTEPS_COMPRESSOR", raising=False)
-
-    def device_put(x):  # one array or a list of them: the order is the point
-        log.extend(("put", a.nbytes) for a in (x if isinstance(x, list)
-                                               else [x]))
-        return x if isinstance(x, list) else x.view(Aliased)
-
-    monkeypatch.setattr(jax, "device_put", device_put)
-    ps.reset_declare_cache()
-
-    def make(sizes, *, compressor="", dtype=np.float32, lost_leaf=None,
-             ready=True, uploads=None, **client_kwargs):
-        client = Client(log, **client_kwargs)
-        monkeypatch.setattr(ps.bps, "_st", lambda: types.SimpleNamespace(
-            ps_client=client, config=types.SimpleNamespace(
-                enable_async=False, compressor=compressor)))
-        if uploads is not None:  # a device that copies, as the TPU does
-            monkeypatch.setattr(jax, "device_put", lambda x: Uploaded(
-                log, uploads, x))
-        tree = [Leaf(log, i, np.full((n,), i + 1, dtype), fail=i == lost_leaf,
-                     ready=ready)
-                for i, n in enumerate(sizes)]
-        return log, client, tree
-
-    yield make
-    ps.reset_declare_cache()
-
+from tests.ps_recording import Client, Leaf, bridge, retake  # noqa: F401
 
 SIZES = {"two": [3, 5], "five": [1, 2, 3, 4, 50], "gpt2-like": [768] * 195
          + [50257]}

@@ -84,16 +84,17 @@ def instant_feed(shapes):
     client = bps._st().ps_client
     host = [np.full(s, 1.0, np.float32) for s in shapes]
     assert all(a.flags.writeable and a.flags.c_contiguous for a in host)
-    tids = ps._tids(client, "probe", host, ps._wire_plan(host, False))
+    tids = ps.bind("probe", host).tids
     feed, whole = [], []
     before = ffi.round_summary()["completed_total"]
     # one more than is read: a round is closed by the next one's start
     for _ in range(WARMUP + ROUNDS + 1):
         t0 = time.perf_counter()
-        staged = [(client.push_pull(tid, arr, average=True), arr, None)
-                  for tid, arr in zip(tids, host)]
+        handles = [client.push_pull(tid, arr, average=True)
+                   for tid, arr in zip(tids, host)]
         t1 = time.perf_counter()
-        ps._wait_all(client, staged)
+        for h in handles:
+            client.wait(h)
         feed.append(t1 - t0)
         whole.append(time.perf_counter() - t0)
     summary = ffi.round_summary()
