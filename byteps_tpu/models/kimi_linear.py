@@ -241,6 +241,7 @@ class KimiSparseMoe(nn.Module):
     routed_scale: float
     shared: int = 1
     dtype: jnp.dtype = jnp.bfloat16
+    select_bias: bool = True      # False: the scores alone choose
 
     @nn.compact
     def __call__(self, x):
@@ -262,7 +263,7 @@ class KimiSparseMoe(nn.Module):
             routed_scale=self.routed_scale,
             select_bias=jax.lax.stop_gradient(self.param(
                 "select_bias", nn.initializers.zeros, (self.num_experts,),
-                jnp.float32)))
+                jnp.float32)) if self.select_bias else None)
         if not self.is_initializing():
             self.sow("moe_stats", "counts", counts)
         with jax.named_scope(SHARED_SCOPE):

@@ -16,6 +16,7 @@ Design notes for TPU:
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Optional
 
@@ -42,18 +43,65 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(orig_dtype)
 
 
+def yarn_inv_freq(rotary_dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float,
+                  beta_slow: float) -> jax.Array:
+    """YaRN's blended rotary frequencies (arXiv 2309.00071, "NTK-by-parts"),
+    float32 [rotary_dim / 2]. Pair j's own frequency ``f_j = theta^(-2j /
+    rotary_dim)`` where it turns more than ``beta_fast`` times over
+    ``original_max`` positions, ``f_j / factor`` where fewer than
+    ``beta_slow``, a linear ramp between the two pairs where that happens:
+    ``r(beta) = rotary_dim ln(original_max / (2 pi beta)) / (2 ln theta)``,
+    ``lo = floor(r(beta_fast))``, ``hi = ceil(r(beta_slow))``, both clamped
+    to ``0 .. rotary_dim - 1``, ``gamma_j = clip((j - lo) / (hi - lo), 0,
+    1)``, ``omega_j = (1 - gamma_j) f_j + gamma_j f_j / factor``."""
+    lo, hi = yarn_ramp(rotary_dim, theta, original_max, beta_fast, beta_slow)
+    j = jnp.arange(rotary_dim // 2, dtype=jnp.float32)
+    freqs = theta ** (-j / (rotary_dim // 2))
+    gamma = jnp.clip((j - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return (1.0 - gamma) * freqs + gamma * freqs / factor
+
+
+def yarn_ramp(rotary_dim: int, theta: float, original_max: int,
+              beta_fast: float, beta_slow: float) -> tuple:
+    """``(lo, hi)`` of ``yarn_inv_freq``: the pairs the ramp runs between."""
+    def pair(beta):
+        return (rotary_dim * math.log(original_max / (2 * math.pi * beta))
+                / (2 * math.log(theta)))
+
+    return (max(math.floor(pair(beta_fast)), 0),
+            min(math.ceil(pair(beta_slow)), rotary_dim - 1))
+
+
 def _rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
-          interleaved: bool = False) -> jax.Array:
+          interleaved: bool = False, *, rotary_dim: Optional[int] = None,
+          inv_freq: Optional[jax.Array] = None,
+          factor: float = 1.0) -> jax.Array:
     """Rotary position embedding over [batch, seq, heads, head_dim], in
     float32. Pair j of a head is rotated by ``position theta^(-2j / d)``:
     entries ``(j, j + d/2)`` (half against half), or with ``interleaved``
-    entries ``(2j, 2j + 1)``, each staying where it was."""
+    entries ``(2j, 2j + 1)``, each staying where it was. ``rotary_dim``:
+    the first ``rotary_dim`` entries are the ``d`` that is rotated and the
+    rest pass as they are (a partial rotary factor). ``inv_freq`` [d / 2]:
+    the pairs' frequencies where they are not ``theta``'s
+    (``yarn_inv_freq``). ``factor``: cos and sin are multiplied by it (YaRN's
+    attention factor: the rotated part of a logit carries its square)."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        return jnp.concatenate([
+            _rope(x[..., :rotary_dim], positions, theta, interleaved,
+                  inv_freq=inv_freq, factor=factor),
+            x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = inv_freq
     angles = positions[..., None].astype(jnp.float32) * freqs  # [b, s, half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     if interleaved:
         pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
         x1, x2 = pairs[..., 0], pairs[..., 1]

@@ -27,6 +27,13 @@ them once; what a process pays before its first step for using them is in
 PERF.md section 6 (PR 36): the import, and 0.2-0.6 s of tracing a program.
 ``byteps_tpu.parallel.full_attention`` hands this kernel the shapes where
 it beats XLA's form on the chip (PERF.md section 3, kernels).
+
+``k`` and ``v`` may have fewer heads than ``q`` (a divisor): the K/V index
+maps read head ``i // groups`` and the dK/dV kernel walks the group's heads
+one after another over one key block, so no repeated K/V and no [heads]
+dK/dV are written (at 64 / 8 x 128 x s8192 3-9% faster than repeating
+ahead of the call, PERF.md section 3). Under a ``window`` all three grids
+walk only the blocks the band touches.
 """
 
 from __future__ import annotations
@@ -74,6 +81,10 @@ pl, pltpu = _import_pallas()
 _VMEM = pltpu.VMEM
 
 _NEG_INF = -1e30
+
+# The largest block of a windowed call (``_blocks``): a band of a few
+# hundred keys crosses few blocks, and a block computes all its pairs.
+WINDOW_BLOCK = 512
 
 # The kernels' names: what a device trace and the ledger's ``device_ops``
 # show for the three custom calls.
@@ -125,9 +136,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         k_start = ki * block_k
     else:
         # real (unclamped) k block this step serves; duplicates from the
-        # index-map clamp are skipped via the k_idx bound below
-        k_idx = _window_start_block(q_start, window, block_k) + ki
-        k_start = k_idx * block_k
+        # index-map clamp are skipped via the bounds on k_start below
+        k_start = (_window_start_block(q_start, window, block_k) + ki) \
+            * block_k
 
     def _compute():
         v = v_ref[0]
@@ -181,11 +192,34 @@ def _window_start_block(q_start, window, block_k):
     return jnp.maximum((q_start - (window - 1)) // block_k, 0)
 
 
+def _window_k_blocks(window: int, block_q: int, block_k: int,
+                     nq: int, nk: int) -> list:
+    """For each q block, the k blocks its band touches: keys ``q_start -
+    window + 1 .. q_start + block_q - 1``, counted block by block (a
+    static loop over at most a few dozen q blocks) and not bounded by a
+    formula, which at 256 x 512 would walk a third block no query needs.
+    The one model of the band's blocks: the grids are sized by its largest
+    entry, ``window_walked_pairs`` sums it."""
+    return [min((qi * block_q + block_q - 1) // block_k, nk - 1)
+            - max(qi * block_q - window + 1, 0) // block_k + 1
+            for qi in range(nq)]
+
+
 def _window_live_blocks(window: int, block_q: int, block_k: int,
-                        nk: int) -> int:
-    """Static count of k blocks a q block can touch under the window."""
-    span = window + block_q - 1
-    return min(nk, span // block_k + 2)
+                        nq: int, nk: int) -> int:
+    """The most k blocks the band of one q block touches: the third
+    dimension of the forward's and dQ's grids under a window."""
+    return max(_window_k_blocks(window, block_q, block_k, nq, nk))
+
+
+def _window_live_q_blocks(window: int, block_q: int, block_k: int,
+                          nq: int, nk: int) -> int:
+    """The most q blocks that see one k block: queries ``k_start ..
+    k_start + block_k + window - 2``."""
+    return max(
+        min((ki * block_k + block_k + window - 2) // block_q, nq - 1)
+        - (ki * block_k) // block_q + 1
+        for ki in range(nk))
 
 
 def _div(a, b: int):
@@ -213,7 +247,7 @@ def _block(s: int, largest: int) -> int:
     return block
 
 
-def _blocks(s_q: int, s_k: int, d: int):
+def _blocks(s_q: int, s_k: int, d: int, window: Optional[int] = None):
     """(block_q, block_k) of the three kernels, from the shape. On a v5e,
     causal bf16, at 16 x 128 x s4096 and at 12 x 64 x s1024 (PERF.md section
     3, my chip runs, PR 36) — forward: 1024 x 1024 0.84 and 0.49 ms, the
@@ -222,9 +256,30 @@ def _blocks(s_q: int, s_k: int, d: int):
     dQ + dK/dV: 1024 x 1024 2.31 and 1.60, 512 x 512 2.39 and 1.61, 256 x
     512 3.00 and 1.90, 256 x 256 4.03 and 2.34; 1024 x 2048 does not fit
     (the backward kernels hold p, dp and ds, three float32 [bq, bk]
-    temporaries)."""
+    temporaries). Under a ``window`` the blocks are capped at
+    ``WINDOW_BLOCK``: at 64 heads over 8 key heads x 128 x s8192, window 512
+    (my chip runs, PR 47), forward + backward 512 x 512 13.87 ms, 256 x 512
+    15.90, 512 x 1024 16.60, 1024 x 1024 18.64, 256 x 256 19.05, 1024 x 512
+    19.66, 512 x 256 21.02, 128 x 256 25.35, 128 x 128 35.66; the forward
+    alone is fastest at 512 x 1024 (4.93 against 5.80)."""
     largest = 1024 if d <= 128 else 512
+    if window is not None:
+        largest = min(largest, WINDOW_BLOCK)
     return _block(s_q, largest), _block(s_k, largest)
+
+
+def _clamped(s_q: int, s_k: int, block_q: int, block_k: int) -> tuple:
+    """Blocks no longer than their sequence (8 rows at the least)."""
+    return min(block_q, max(s_q, 8)), min(block_k, max(s_k, 8))
+
+
+def window_walked_pairs(s_q: int, s_k: int, d: int, window: int) -> int:
+    """The (query, key) pairs one head of one sequence costs a windowed
+    call: every block its grids compute (``_window_k_blocks``, the rest of
+    a grid is skipped), whole."""
+    bq, bk = _clamped(s_q, s_k, *_blocks(s_q, s_k, d, window))
+    nq, nk = -(-s_q // bq), -(-s_k // bk)
+    return sum(_window_k_blocks(window, bq, bk, nq, nk)) * bq * bk
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -272,6 +327,19 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
     return interpret
 
 
+def _kv_head(heads: int, kv_heads: int):
+    """Index map's part for grouped keys: row ``bh`` of [batch * heads] ->
+    its row of [batch * kv_heads], query head i reading key head ``i //
+    groups``. Equal counts: the row itself, and the text they lowered to."""
+    if heads % kv_heads:
+        raise ValueError(f"{heads} query heads do not divide over "
+                         f"{kv_heads} key heads")
+    groups = heads // kv_heads
+    if groups == 1:
+        return lambda bh: bh
+    return lambda bh: _div(bh, groups)
+
+
 def _to_bhsd(x):
     b, s, h, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -294,10 +362,10 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
                          "attention is a causal scheme)")
     b, s_q, h, d = q.shape
     s_k, d_v = k.shape[1], v.shape[-1]
+    kv_head = _kv_head(h, k.shape[2])
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    bq = min(block_q, max(s_q, 8))
-    bk = min(block_k, max(s_k, 8))
+    bq, bk = _clamped(s_q, s_k, block_q, block_k)
 
     qq = _pad_to(_to_bhsd(q), bq, axis=1)
     kk = _pad_to(_to_bhsd(k), bk, axis=1)
@@ -308,24 +376,26 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
     if window is not None:
         # visit only the live k blocks per q block: grid work (and the
         # BlockSpec K/V prefetches) scale with seq*window, not seq^2
-        nkg = _window_live_blocks(window, bq, bk, nk)
+        nkg = _window_live_blocks(window, bq, bk, sq_p // bq, nk)
 
         def kv_index(bh, qi, ki):
-            return (bh,
-                    jnp.clip(_window_start_block(qi * bq, window, bk) + ki,
-                             0, nk - 1), 0)
+            # past the diagonal: the last live block again, no copy
+            return (kv_head(bh), jnp.minimum(
+                _window_start_block(qi * bq, window, bk) + ki,
+                jnp.minimum(_div(qi * bq + bq - 1, bk), nk - 1)), 0)
     elif causal:
         nkg = nk
 
         def kv_index(bh, qi, ki):
             # a block above the diagonal repeats the last live one's
             # index: the kernel skips it and the pipeline copies nothing
-            return (bh, jnp.minimum(ki, _div(qi * bq + bq - 1, bk)), 0)
+            return (kv_head(bh), jnp.minimum(ki, _div(qi * bq + bq - 1, bk)),
+                    0)
     else:
         nkg = nk
 
         def kv_index(bh, qi, ki):
-            return (bh, ki, 0)
+            return (kv_head(bh), ki, 0)
 
     grid = (b * h, sq_p // bq, nkg)
     scratch = [
@@ -370,7 +440,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                window):
-    derived = _blocks(q.shape[1], k.shape[1], q.shape[-1])
+    derived = _blocks(q.shape[1], k.shape[1], q.shape[-1], window)
     out, lse = _flash_fwd_impl(
         q, k, v, causal, scale, block_q or derived[0], block_k or derived[1],
         _resolve_interpret(interpret), window)
@@ -409,8 +479,10 @@ def _bwd_recompute(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref,
                       dq_acc, *, scale, causal, block_q, block_k,
-                      seq_q, seq_k, window=None):
-    """dQ = scale * sum_k [p * (dO V^T - D)] K; grid (bh, qi, ki)."""
+                      seq_q, seq_k, window=None, nk_total=None):
+    """dQ = scale * sum_k [p * (dO V^T - D)] K; grid (bh, qi, ki).
+    ``nk_total`` set: ki counts from the first k block of the q block's
+    window (the forward's restricted grid), of ``nk_total`` in all."""
     qi, ki = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -418,7 +490,9 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    q_start = qi * block_q
+    step, q_start = ki, qi * block_q
+    if nk_total is not None:
+        ki = _window_start_block(q_start, window, block_k) + ki
     k_start = ki * block_k
 
     def _compute():
@@ -431,18 +505,24 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_live(_bwd_live(q_start, k_start, block_q, block_k, causal, window),
-               _compute)
+    live = _bwd_live(q_start, k_start, block_q, block_k, causal, window)
+    if nk_total is not None:
+        live = jnp.logical_and(live, k_start < nk_total * block_k)
+    _when_live(live, _compute)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finish():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                        dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                       block_q, block_k, seq_q, seq_k, window=None):
-    """dK = scale * sum_q ds^T Q;  dV = sum_q p^T dO; grid (bh, ki, qi)."""
+                       block_q, block_k, seq_q, seq_k, window=None,
+                       q_of_step=None, nq_total=None):
+    """dK = scale * sum_q ds^T Q;  dV = sum_q p^T dO; grid (bh, ki, qi).
+    ``q_of_step(ki, step)``: the q block of the third grid position, where
+    it is not the position itself (a group's heads one after another over
+    one key head; a window's q blocks alone, of ``nq_total`` in all)."""
     ki, qi = pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
 
@@ -451,6 +531,9 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
+    step = qi
+    if q_of_step is not None:
+        qi = q_of_step(ki, qi)
     q_start = qi * block_q
     k_start = ki * block_k
 
@@ -467,10 +550,12 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_live(_bwd_live(q_start, k_start, block_q, block_k, causal, window),
-               _compute)
+    live = _bwd_live(q_start, k_start, block_q, block_k, causal, window)
+    if nq_total is not None:
+        live = jnp.logical_and(live, q_start < nq_total * block_q)
+    _when_live(live, _compute)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == nq - 1)
     def _finish():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -481,7 +566,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res,
     """Pallas backward: blockwise recompute from (q, k, v, o, lse) — the
     standard flash-attention backward, O(seq) memory like the forward."""
     q, k = res[0], res[1]
-    bq, bk = _blocks(q.shape[1], k.shape[1], q.shape[-1])
+    bq, bk = _blocks(q.shape[1], k.shape[1], q.shape[-1], window)
     return _flash_bwd_impl(*res, g, causal, scale, bq, bk,
                            _resolve_interpret(interpret), window)
 
@@ -491,12 +576,12 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res,
 def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                     interpret, window):
     b, s_q, h, d = q.shape
-    s_k, d_v = k.shape[1], v.shape[-1]
+    s_k, d_v, h_kv = k.shape[1], v.shape[-1], k.shape[2]
+    kv_head, groups = _kv_head(h, h_kv), h // h_kv
     if scale is None:
         scale = 1.0 / (d ** 0.5)
 
-    bq = min(block_q, max(s_q, 8))
-    bk = min(block_k, max(s_k, 8))
+    bq, bk = _clamped(s_q, s_k, block_q, block_k)
 
     qq = _pad_to(_to_bhsd(q), bq, axis=1)
     kk = _pad_to(_to_bhsd(k), bk, axis=1)
@@ -514,21 +599,43 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     # the forward's lse is padded with the FORWARD's bq; re-pad for bwd
     lse = _pad_to(lse[:, :s_q], bq, axis=1)
 
-    clamp = causal and window is None
+    nq, nk = sq_p // bq, sk_p // bk
+    banded = window is not None
+    # a window's grids walk the band alone, as the forward's does: the
+    # most k blocks a q block sees, the most q blocks that see a k block
+    nk_dq = _window_live_blocks(window, bq, bk, nq, nk) if banded else nk
+    nq_dkv = _window_live_q_blocks(window, bq, bk, nq, nk) if banded else nq
 
     def q_of_dq(bh, qi, ki):
         return (bh, qi, 0)
 
     def k_of_dq(bh, qi, ki):
+        if banded:
+            ki = _window_start_block(qi * bq, window, bk) + ki
         # above the diagonal: repeat the last live block, copy nothing
-        if clamp:
+        if causal:
             ki = jnp.minimum(ki, _div(qi * bq + bq - 1, bk))
-        return (bh, ki, 0)
+        if banded:
+            ki = jnp.minimum(ki, nk - 1)
+        return (kv_head(bh), ki, 0)
 
-    def q_of_dkv(bh, ki, qi):
+    # the third grid position of dK/dV -> (head of the group, q block)
+    q_of_step = None
+    if banded or groups > 1:
+        def q_of_step(ki, step):
+            if groups > 1:
+                step = jax.lax.rem(step, jnp.int32(nq_dkv))
+            return _div(ki * bk, bq) + step if banded else step
+
+    def q_of_dkv(bh, ki, step):
+        qi = step if q_of_step is None else q_of_step(ki, step)
         # left of the first query block that sees this key block: the same
-        if clamp:
+        if causal and not banded:
             qi = jnp.maximum(qi, _div(ki * bk, bq))
+        if banded:
+            qi = jnp.minimum(qi, nq - 1)
+        if groups > 1:
+            bh = bh * groups + _div(step, nq_dkv)
         return (bh, qi, 0)
 
     def k_of_dkv(bh, ki, qi):
@@ -544,8 +651,9 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale, block_q, block_k,
               seq_q=s_q, seq_k=s_k, window=window)
 
     dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, **kw),
-        grid=(b * h, sq_p // bq, sk_p // bk),
+        functools.partial(_fa_bwd_dq_kernel, **kw,
+                          nk_total=nk if banded else None),
+        grid=(b * h, nq, nk_dq),
         in_specs=specs(q_of_dq, k_of_dq),
         out_specs=pl.BlockSpec((1, bq, d), q_of_dq, memory_space=_VMEM),
         out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
@@ -555,16 +663,17 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     )(qq, kk, vv, dd_o, lse, dd)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, **kw),
-        grid=(b * h, sk_p // bk, sq_p // bq),
+        functools.partial(_fa_bwd_dkv_kernel, **kw, q_of_step=q_of_step,
+                          nq_total=nq if banded else None),
+        grid=(b * h_kv, nk, groups * nq_dkv),
         in_specs=specs(q_of_dkv, k_of_dkv),
         out_specs=[
             pl.BlockSpec((1, bk, d), k_of_dkv, memory_space=_VMEM),
             pl.BlockSpec((1, bk, d_v), k_of_dkv, memory_space=_VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sk_p, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk_p, d_v), v.dtype),
+            jax.ShapeDtypeStruct((b * h_kv, sk_p, d), k.dtype),
+            jax.ShapeDtypeStruct((b * h_kv, sk_p, d_v), v.dtype),
         ],
         scratch_shapes=[_VMEM((bk, d), jnp.float32),
                         _VMEM((bk, d_v), jnp.float32)],
@@ -573,8 +682,8 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     )(qq, kk, vv, dd_o, lse, dd)
 
     dq = _from_bhsd(dq[:, :s_q], b, h)
-    dk = _from_bhsd(dk[:, :s_k], b, h)
-    dv = _from_bhsd(dv[:, :s_k], b, h)
+    dk = _from_bhsd(dk[:, :s_k], b, h_kv)
+    dv = _from_bhsd(dv[:, :s_k], b, h_kv)
     return dq, dk, dv
 
 

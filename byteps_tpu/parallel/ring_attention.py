@@ -121,12 +121,16 @@ def ring_attention(
     return out.astype(q.dtype)
 
 
-def _single_device_attention(q, k, v, *, causal: bool, scale: float):
+def _single_device_attention(q, k, v, *, causal: bool, scale: float,
+                             window: Optional[int] = None):
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         s_q, s_k = q.shape[1], k.shape[1]
         mask = jnp.arange(s_q)[:, None] >= jnp.arange(s_k)[None, :]
+        if window is not None:
+            mask &= (jnp.arange(s_q)[:, None] - jnp.arange(s_k)[None, :]
+                     < window)
         s = jnp.where(mask[None, None], s, _big_neg(jnp.float32))
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(p.dtype),
@@ -151,12 +155,25 @@ KERNEL_MIN_SEQ = 512
 KERNEL_HEAD_DIMS = ((64, 64), (128, 128), (192, 128))
 
 
+# A windowed call site, and the (query, key) pairs its form computes and
+# the band needs, summed over batch and heads at trace time: the kernel
+# form computes every block the band touches, the XLA form the square.
+WINDOW_SITES = "bps_attention_window_sites_total"
+WINDOW_WALKED = "bps_attention_window_walked_pairs"
+WINDOW_NEEDED = "bps_attention_window_needed_pairs"
+
+
 def attention_form(backend: str, s_q: int, s_k: int, head_dim: int,
-                   causal: bool, dtype, value_dim: int = 0) -> str:
+                   causal: bool, dtype, value_dim: int = 0,
+                   window: Optional[int] = None) -> str:
     """``"kernel"`` or ``"xla"``: how ``full_attention`` computes operands of
     these shapes (``value_dim``: v's width where it is not q's and k's). One
     algorithm, two forms: the XLA form writes float32 ``[batch, heads, s_q,
-    s_k]`` scores to HBM, the kernel (TPU only) keeps a block in VMEM."""
+    s_k]`` scores to HBM, the kernel (TPU only) keeps a block in VMEM. A
+    ``window`` does not move the rule: the kernel's grid then walks the
+    band alone, the XLA form masks the square."""
+    if window is not None and not causal:
+        raise ValueError("window requires causal=True")
     if backend != "tpu" or jnp.dtype(dtype) != jnp.bfloat16:
         return "xla"
     widths = (head_dim, value_dim or head_dim)
@@ -165,29 +182,58 @@ def attention_form(backend: str, s_q: int, s_k: int, head_dim: int,
     return "kernel" if causal else "xla"
 
 
+def _count_window(q, window: int, walked: int):
+    """``walked``: the pairs the form computes for one head of one
+    sequence; needed are the band's own, ``sum_q min(q + 1, window)``."""
+    short = min(window, q.shape[1])
+    needed = short * (short + 1) // 2 + (q.shape[1] - short) * window
+    metrics.inc_counter(WINDOW_SITES)
+    metrics.inc_counter(WINDOW_WALKED, q.shape[0] * q.shape[2] * walked)
+    metrics.inc_counter(WINDOW_NEEDED, q.shape[0] * q.shape[2] * needed)
+
+
 def full_attention(q, k, v, *, causal: bool = False,
-                   scale: Optional[float] = None):
+                   scale: Optional[float] = None,
+                   window: Optional[int] = None):
     """Exact softmax attention over an unsharded sequence: float32 logits
     and softmax statistics, both products on the operands' own dtype. On a
     TPU, for the shapes ``attention_form`` names, the Pallas kernel of
     ``byteps_tpu.ops.flash_attention`` (blockwise, the scores never leave
     VMEM); everywhere else the two einsums around a softmax that XLA
-    fuses as it sees fit."""
+    fuses as it sees fit. ``window`` (causal only): a query sees the last
+    ``window`` keys, itself among them. ``k`` and ``v`` may have fewer heads
+    than ``q``, a divisor: query head i reads key head ``i // groups``."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    if attention_form(jax.default_backend(), q.shape[1], k.shape[1],
-                      q.shape[-1], causal, q.dtype, v.shape[-1]) == "kernel":
+    groups = q.shape[2] // k.shape[2]
+    if groups * k.shape[2] != q.shape[2] or v.shape[2] != k.shape[2]:
+        raise ValueError(f"{q.shape[2]} query heads over {k.shape[2]} key "
+                         f"and {v.shape[2]} value heads")
+    form = attention_form(jax.default_backend(), q.shape[1], k.shape[1],
+                          q.shape[-1], causal, q.dtype, v.shape[-1], window)
+    if form == "kernel":
         # imported here: a process that never reaches this line (BERT's
         # s128, any CPU run) pays for no kernel library
         # (tests/test_import_footprint.py)
-        from byteps_tpu.ops.flash_attention import flash_attention
+        from byteps_tpu.ops.flash_attention import (
+            flash_attention, window_walked_pairs)
 
         metrics.inc_counter(KERNEL_SITES)
+        if window is not None:
+            # the kernel's own count of the blocks its grids compute
+            _count_window(q, window, window_walked_pairs(
+                q.shape[1], k.shape[1], q.shape[-1], window))
         with jax.named_scope(KERNEL_SCOPE):
-            return flash_attention(q, k, v, causal, scale)
+            return flash_attention(q, k, v, causal, scale, window=window)
     metrics.inc_counter(XLA_SITES)
+    if window is not None:
+        _count_window(q, window, q.shape[1] * k.shape[1])   # the square
     with jax.named_scope(XLA_SCOPE):
-        return _single_device_attention(q, k, v, causal=causal, scale=scale)
+        if groups > 1:
+            # the XLA form knows no groups: a gather it fuses
+            k, v = (jnp.repeat(x, groups, axis=2) for x in (k, v))
+        return _single_device_attention(q, k, v, causal=causal, scale=scale,
+                                        window=window)
 
 
 @partial(jax.jit, static_argnums=(3, 4, 5, 6))

@@ -60,7 +60,7 @@ def _kernel_form(monkeypatch):
         m.setattr(ra, "attention_form",
                   lambda backend, *rest: rule("tpu", *rest))
         m.setattr(ra, "KERNEL_MIN_SEQ", 128)
-        m.setattr(fa, "_blocks", lambda s_q, s_k, d: (64, 128))
+        m.setattr(fa, "_blocks", lambda s_q, s_k, d, window=None: (64, 128))
         yield
 
 

@@ -76,6 +76,42 @@ def test_flash_kernel_compiles_for_v5e(topo, case, with_grads):
     assert "tpu_custom_call" in text
 
 
+# (query heads, window): the two kinds of layer of the Laguna cell, b 1 x s
+# 8192 over 8 key heads of 128
+GROUPED_CASES = {"laguna_window512_h64": (64, 512),
+                 "laguna_global_h48": (48, None)}
+
+
+@pytest.mark.parametrize("with_grads", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("case", sorted(GROUPED_CASES))
+def test_grouped_flash_kernel_compiles_for_v5e(topo, case, with_grads):
+    """Grouped keys (the K/V index maps read head i // groups, dK/dV sum
+    their group inside the kernel) and, under the window, all three grids
+    walking the band alone, at the shape the cell runs and the blocks
+    ``_blocks`` derives for it."""
+    heads, window = GROUPED_CASES[case]
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16,
+                             sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16, sharding=one)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if with_grads else fwd
+    compiled = jax.jit(fn).lower(q, kv, kv).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= (
+        3 if with_grads else 1)
+    if with_grads:
+        dq, dk, dv = jax.eval_shape(fn, q, kv, kv)
+        assert (dq.shape, dk.shape, dv.shape) == (q.shape, kv.shape,
+                                                  kv.shape)
+
+
 @pytest.mark.parametrize("with_grads", [False, True], ids=["fwd", "fwd_bwd"])
 def test_flash_kernel_at_two_widths_compiles_for_v5e(topo, with_grads):
     """The latent layer of the Kimi-Linear cell: 32 heads, keys 192 wide

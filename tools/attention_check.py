@@ -1,0 +1,180 @@
+"""On the chip: ``full_attention`` at a cell's shapes against exact float32
+attention — values and the three gradients, tensor by tensor — and the
+wrong computations the tolerance must fail.
+
+    chiprun --chips 1 -- python3 tools/attention_check.py [--seed N]
+
+What a benchmark run cannot see (``benchmark/lib/reference.py`` compares
+three losses to 2e-3, and at random weights a band one key off moves them by
+3e-4: PERF.md, PR 47) is held here, on the device, for the compiled Mosaic
+kernels the cell runs: the Laguna cell's two calls, b 1 x s 8,192 x 128 in
+bf16 over 8 key heads — 64 query heads under a window of 512, 48 under the
+causal triangle alone. The reference is the benchmark's own plain one
+(``benchmark/lib/plain_laguna.py::banded_attention``: the band as a mask
+over all keys, the group as an axis, blocks of 256 queries) on the same
+bf16 operands in float32 at the highest matmul precision, nothing of
+``byteps_tpu`` in it.
+
+The measure, for out, dQ, dK and dV each: ``|got - want|_2 / |want|_2``.
+Operands are standard normal, so a query's logits spread over about a unit
+and one key at the band's edge weighs 1/850 on average and a sixth now and
+then: a key more or less moves every tensor by 3.8-4.0%, where the kernels
+read 0.21-0.34% (bf16 outputs, bf16 probabilities) and the other layout of a
+group 130%. ``TOLERANCE`` lies between (my chip run, PR 47: PERF.md section
+6 has every reading). The precision shows in ``out`` alone, which carries
+one rounding and not three: the kernels read 0.207-0.216%, the reference
+with bf16 probabilities (the cell's own precision) 0.226-0.231%, with bf16
+logits and statistics 0.40-0.44%; ``OUT_TOLERANCE`` lies between. The run
+fails — exit 1, ``"ok": false`` — if a kernel's tensor reads above its
+tolerance or a control below it. The controls are the reference computed
+wrongly, not the kernel: a window one key short and one key long (the
+windowed call), query head i reading key head ``i % key heads``, and bf16
+logits and softmax statistics (on ``out``).
+
+One JSON line a case, then ``{"ok": ..., "device": ...}``; off the chip the
+kernels are interpreted at a small size (``tests/test_attention_check.py``).
+"""
+
+from __future__ import annotations
+
+import argparse
+import collections
+import json
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+TOLERANCE = 1.2e-2          # every tensor: the band, the grouping
+OUT_TOLERANCE = 3.0e-3      # out alone: float32 logits and statistics
+TENSORS = ("out", "dq", "dk", "dv")
+
+
+# window None: the causal triangle
+Case = collections.namedtuple("Case",
+                              "name seq heads kv_heads head_dim window")
+
+
+# the Laguna cell's two calls (benchmark/configs/laguna-xs.2.json)
+CELL_CASES = (Case("windowed", 8192, 64, 8, 128, 512),
+              Case("global", 8192, 48, 8, 128, None))
+
+
+def _relative(got, want) -> float:
+    import numpy as np
+
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    if not np.isfinite(got).all():
+        return float("inf")
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def check(case: Case, seed: int, attend=None) -> dict:
+    """One case: the program's attention (``attend(q, k, v, window)``, by
+    default ``full_attention``) against the float32 reference, and the
+    controls. ``attend`` is a test's handle on the kernel form off the chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.lib.plain_laguna import banded_attention
+
+    s, h, kv, d = case.seq, case.heads, case.kv_heads, case.head_dim
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(keys[0], (1, s, h, d), jnp.bfloat16)
+    k, v = (jax.random.normal(key, (1, s, kv, d), jnp.bfloat16)
+            for key in keys[1:3])
+    w = jax.random.normal(keys[3], (1, s, h, d), jnp.float32)   # cotangent
+
+    if attend is None:
+        from byteps_tpu.parallel import full_attention
+
+        def attend(q, k, v, window):
+            return full_attention(q, k, v, causal=True, window=window)
+
+    def run(fn):
+        """(out, dq, dk, dv) of ``fn(q, k, v) -> [1, s, h, d]``."""
+        def scalar(q, k, v, w):
+            out = fn(q, k, v)
+            return (out.astype(jnp.float32) * w).sum(), out
+
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            scalar, argnums=(0, 1, 2), has_aux=True))(q, k, v, w)
+        return jax.device_get((out, *grads))
+
+    groups = h // kv
+
+    def plain(window=case.window, dtype=jnp.float32,
+              logits_dtype=jnp.float32, interleaved=False):
+        """The plain reference as a function of the same operands; the
+        keyword arguments are the ways to compute it wrongly. Its layout is
+        [s, key heads, group, d]: query head i is member ``i % group`` of
+        key head ``i // group``, or, ``interleaved``, head i reads key head
+        ``i % key heads`` — the other way to lay a group out."""
+        def fn(q, k, v):
+            grouped = (jnp.swapaxes(q[0].reshape(s, groups, kv, d), 1, 2)
+                       if interleaved else q[0].reshape(s, kv, groups, d))
+            with jax.default_matmul_precision("highest"):
+                out = banded_attention(
+                    grouped.astype(dtype), k[0].astype(dtype),
+                    v[0].astype(dtype), window=window, dtype=dtype,
+                    query_block=min(256, s), logits_dtype=logits_dtype)
+            if interleaved:
+                out = jnp.swapaxes(out, 1, 2)
+            return out.reshape(1, s, h, d)
+
+        return fn
+
+    lowered = jax.jit(lambda q, k, v: attend(q, k, v, case.window)).lower(
+        q, k, v).as_text()
+    got = run(lambda q, k, v: attend(q, k, v, case.window))
+    want = run(plain())
+    record = {
+        "case": case._asdict(), "seed": seed,
+        "kernel_in_program": "tpu_custom_call" in lowered,
+        "kernel": dict(zip(TENSORS, map(_relative, got, want)))}
+
+    controls = {}
+    if case.window is not None:
+        controls["window_minus_1"] = plain(window=case.window - 1)
+        controls["window_plus_1"] = plain(window=case.window + 1)
+    if kv > 1 and groups > 1:
+        controls["heads_interleaved"] = plain(interleaved=True)
+    def readings(fn):
+        return dict(zip(TENSORS, map(_relative, run(fn), want)))
+
+    record["controls"] = {name: readings(fn)
+                          for name, fn in controls.items()}
+    # the cell's own precision, reported; one precision below, a control
+    record["bf16_probabilities"] = readings(plain(dtype=jnp.bfloat16))
+    record["bf16_logits_and_statistics"] = readings(plain(
+        dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16))
+    record["tolerance"], record["out_tolerance"] = TOLERANCE, OUT_TOLERANCE
+    record["ok"] = bool(
+        max(record["kernel"].values()) <= TOLERANCE
+        and record["kernel"]["out"] <= OUT_TOLERANCE
+        and all(max(c.values()) > TOLERANCE
+                for c in record["controls"].values())
+        and record["bf16_logits_and_statistics"]["out"] > OUT_TOLERANCE)
+    return record
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args()
+
+    import jax
+
+    device = jax.devices()[0]
+    ok = device.platform == "tpu"        # never a CPU's figures by mistake
+    for case in CELL_CASES if ok else ():
+        record = check(case, args.seed)
+        ok = ok and record["ok"] and record["kernel_in_program"]
+        print(json.dumps(record), flush=True)
+    print(json.dumps({"ok": ok, "device": {
+        "platform": device.platform, "kind": device.device_kind}}))
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
