@@ -57,7 +57,8 @@ class Config:
     partition_bytes: int = 4096000        # BYTEPS_PARTITION_BYTES (~4 MB)
     scheduling_credit: int = 0            # BYTEPS_SCHEDULING_CREDIT
     #   in-flight BYTE budget for the DCN push stage (reference semantics);
-    #   0 = auto: 4 x partition_bytes
+    #   0 = auto: 10 x partition_bytes (sized on the chip's PS cell,
+    #   PERF.md section 6, PR 48)
     fusion_bytes: int = 65536             # BYTEPS_FUSION_BYTES
     #   small-tensor fusion: partitions under this many raw bytes are
     #   coalesced into one multi-key wire frame per (server, flush);
@@ -404,7 +405,7 @@ class Config:
         if self.scheduling_credit < 0:
             raise ValueError(
                 "BYTEPS_SCHEDULING_CREDIT is a byte budget; must be >= 0 "
-                "(0 = auto: 4 x BYTEPS_PARTITION_BYTES)")
+                "(0 = auto: 10 x BYTEPS_PARTITION_BYTES)")
         if 0 < self.scheduling_credit < 1024:
             # A handful of BYTES can only be a legacy partition-count
             # value; honouring it as bytes would serialise every push.
@@ -420,7 +421,7 @@ class Config:
                 "like a legacy in-flight partition count; the core will "
                 f"interpret it as {self.scheduling_credit} x "
                 f"{self.partition_bytes} bytes (it is now a BYTE budget; "
-                "set 0 for auto = 4 x BYTEPS_PARTITION_BYTES)",
+                "set 0 for auto = 10 x BYTEPS_PARTITION_BYTES)",
                 stacklevel=2)
         if self.fusion_bytes < 0:
             raise ValueError(

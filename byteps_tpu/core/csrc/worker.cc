@@ -133,7 +133,16 @@ void BytePSWorker::Start(Postoffice* po, KVWorker* kv, int64_t partition_bytes,
   Metrics::Get().Histogram("bps_round_wall_us");
   recovery_on_ = RecoveryEnabled();
   // Reference semantics: BYTEPS_SCHEDULING_CREDIT is an in-flight BYTE
-  // budget. 0 = auto: four full partitions' worth. A value under 1024
+  // budget. 0 = auto: ten full partitions' worth, sized by measurement
+  // against the pipeline it spans (push leg, server, pull leg: a
+  // partition holds its credit until its pulled bytes have landed). At
+  // four the push thread stood refused for 37% of a round with the
+  // server and the receive side half idle; from ten on nothing is gained
+  // and the queue only moves from this heap, which is ordered by
+  // priority, into the server's, which is not (PERF.md §6, PR 48). It
+  // also bounds what a late high-priority partition can wait behind:
+  // budget / rate.
+  // A value under 1024
   // can only be a legacy partition count (the reference default was 4;
   // no real byte budget is smaller than 1 KiB, and no in-flight count
   // reaches 1024) — honouring it as bytes would serialise every push,
@@ -149,7 +158,7 @@ void BytePSWorker::Start(Postoffice* po, KVWorker* kv, int64_t partition_bytes,
                      << partition_bytes << " bytes";
     credit_bytes = credit_bytes * partition_bytes;
   }
-  if (credit_bytes <= 0) credit_bytes = 4 * partition_bytes;
+  if (credit_bytes <= 0) credit_bytes = 10 * partition_bytes;
   queue_ = std::make_unique<ScheduledQueue>(credit_bytes);
   // Sender parallelism: the van's writev blocks once a connection's
   // SNDBUF fills, and with ONE push thread a full stripe head-of-line

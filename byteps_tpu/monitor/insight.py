@@ -282,10 +282,13 @@ def hints(state: str, fleet_rec: dict) -> List[str]:
             "(stuck past BYTEPS_ELASTIC_TIMEOUT_MS would fail-stop)")
     if bd["queue"] / wall >= DOMINANCE_SHARE:
         out.append(
-            "scheduled-queue wait dominates the wall -> raise "
-            "BYTEPS_SCHEDULING_CREDIT if credit-limited; otherwise the "
-            "queue is draining at the bound stage's rate (fix that "
-            "first)")
+            "scheduled-queue wait dominates the wall -> the queue is "
+            "draining at the bound stage's rate (fix that first); only "
+            "if credit_blocked_us is a large share of the round is it "
+            "credit-limited: then unset a forced "
+            "BYTEPS_SCHEDULING_CREDIT (the default, ten partitions, "
+            "is sized to keep the push thread busy) or raise it for a "
+            "high bandwidth-delay link")
     if bd["compress"] / wall >= DOMINANCE_SHARE:
         out.append(
             "encode cost dominates -> larger BYTEPS_WIRE_QUANT_BLOCK "

@@ -180,6 +180,42 @@ def main() -> int:
                 peak = max(peak, cur)
             assert peak <= 2, f"byte credit violated: {peak} in flight"
 
+        elif mode == "credit_budget":
+            # ISSUE 48: the byte budget in force — ten partitions' worth
+            # by default, a forced BYTEPS_SCHEDULING_CREDIT to the byte (a
+            # legacy count times the partition size) — is what stands in
+            # flight when nothing can land: rank 0 pushes late, so the
+            # server can answer no pull and every other worker's queue
+            # holds exactly what its budget admits, no byte more.
+            import time
+            part = int(os.environ["BYTEPS_PARTITION_BYTES"])
+            forced = int(os.environ.get("BYTEPS_SCHEDULING_CREDIT", "0"))
+            budget = (forced * part if 0 < forced < 1024
+                      else forced or 10 * part)
+            n_parts = 32
+            tid = w.declare("wide", n_parts * (part // 4), "float32",
+                            compression="")
+            wide = np.ones(n_parts * (part // 4), dtype=np.float32)
+            w.wait(w.push_pull(tid, wide, average=False))  # ranks in step
+            np.testing.assert_allclose(wide, float(nw))
+            wide[:] = 2.0
+            if rank == 0:
+                time.sleep(1.5)
+            h = w.push_pull(tid, wide, average=False)
+            if rank != 0:
+                time.sleep(0.5)
+                q = w.metrics_snapshot()["queue"]
+                # whole partitions, at least one (always-admit-one)
+                admitted = min(n_parts, max(1, budget // part))
+                assert q["credit_budget_bytes"] == budget, q
+                assert q["inflight_bytes"] == admitted * part, q
+                assert q["pending"] == n_parts - admitted, q
+            w.wait(h)
+            np.testing.assert_allclose(wide, float(2 * nw))
+            q = w.metrics_snapshot()["queue"]
+            assert (q["inflight_bytes"], q["pending"]) == (0, 0), q
+            print(f"credit_budget {q['credit_budget_bytes']}")
+
         elif mode == "priority":
             # The reference's scheduling rationale: an EARLIER-declared
             # (front-of-model) tensor preempts a later-declared one at

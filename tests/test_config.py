@@ -11,7 +11,7 @@ def test_defaults(monkeypatch):
     cfg = load_config()
     assert cfg.role == "worker"
     assert cfg.partition_bytes == 4096000
-    # byte budget; 0 = auto (4 x partition_bytes, resolved in the C core)
+    # byte budget; 0 = auto (10 x partition_bytes, resolved in the C core)
     assert cfg.scheduling_credit == 0
     assert not cfg.distributed
     assert not cfg.use_ps
@@ -31,6 +31,51 @@ def test_legacy_partition_count_credit_warns_passthrough(monkeypatch):
     with pytest.warns(UserWarning):
         cfg.validate()  # idempotent: same warning, value still unchanged
     assert cfg.scheduling_credit == 4
+
+
+@pytest.mark.parametrize("credit,says", [
+    (-1, "0 = auto: 10 x BYTEPS_PARTITION_BYTES"),
+    (4, "set 0 for auto = 10 x BYTEPS_PARTITION_BYTES"),
+], ids=["negative", "legacy-count"])
+def test_credit_messages_name_the_default_in_force(credit, says):
+    """ISSUE 48: what `0` stands for is ten partitions (resolved in
+    `worker.cc`); the error and the warning that send a user there say
+    so."""
+    import warnings
+    cfg = Config(scheduling_credit=credit)
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        if credit < 0:
+            with pytest.raises(ValueError) as err:
+                cfg.validate()
+            text = str(err.value)
+        else:
+            cfg.validate()
+            text = " ".join(str(w.message) for w in caught)
+    assert says in text, text
+
+
+@pytest.mark.parametrize("path,phrase", [
+    ("byteps_tpu/config.py", "0 = auto: {n} x BYTEPS_PARTITION_BYTES"),
+    ("docs/env.md", "`0` = auto: {n} × `BYTEPS_PARTITION_BYTES`"),
+    ("docs/best-practice.md", "default {n} × partition)"),
+    ("docs/troubleshooting.md", "({n} x partition bytes)"),
+    ("docs/monitoring.md", "to hold against the credit's {n})"),
+    ("PARITY.md", "(0 = {n} × partition"),
+], ids=["config", "env", "best-practice", "troubleshooting", "monitoring",
+        "parity"])
+def test_default_credit_reads_the_same_wherever_it_is_named(path, phrase):
+    """The default is resolved in one place, `worker.cc::Start`; every file
+    that tells a user what `0` stands for names the number that is there."""
+    import os
+    import re
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "byteps_tpu/core/csrc/worker.cc")) as f:
+        found = re.findall(r"credit_bytes = (\d+) \* partition_bytes;",
+                           f.read())
+    assert len(found) == 1, found
+    with open(os.path.join(root, path)) as f:
+        assert phrase.format(n=found[0]) in f.read(), (path, found[0])
 
 
 def test_env_parity_names(monkeypatch):

@@ -508,6 +508,19 @@ def test_hints_name_the_knob():
     assert any("BYTEPS_SCHEDULING_CREDIT" in h for h in hs)
 
 
+def test_queue_hint_sends_the_reader_to_the_bound_stage_first():
+    """ISSUE 48: the default credit is sized to keep the push thread busy,
+    so a dominant queue wait is the bound stage's doing unless
+    ``credit_blocked_us`` says the credit refused; the hint no longer
+    opens with "raise the credit"."""
+    fleet_q = insight.merge_recs([_rec(queue=500_000, push=100_000)])
+    hint = [h for h in insight.hints("healthy", fleet_q)
+            if "scheduled-queue" in h][0]
+    assert hint.index("bound stage") < hint.index("credit_blocked_us") \
+        < hint.index("BYTEPS_SCHEDULING_CREDIT")
+    assert "ten partitions" in hint
+
+
 def test_regressions_need_baseline_and_blowout():
     fleet = {
         "3": {"role": 2, "updates": 10, "ewma_wall_us": 10_000.0,

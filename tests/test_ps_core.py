@@ -93,6 +93,32 @@ def test_byte_credit_bounds_inflight(tmp_path):
                         "BYTEPS_TRACE_DIR": str(tmp_path)})
 
 
+_PART = 65536
+
+
+@pytest.mark.parametrize("extra,budget", [
+    ({}, 10 * _PART),
+    (TCP, 10 * _PART),
+    ({"BYTEPS_SCHEDULING_CREDIT": "196608"}, 196608),
+    ({"BYTEPS_SCHEDULING_CREDIT": "200000"}, 200000),
+    ({"BYTEPS_SCHEDULING_CREDIT": "3"}, 3 * _PART),
+    ({"BYTEPS_SCHEDULING_CREDIT": "32768"}, 32768),
+    ({"BYTEPS_SCHEDULING_CREDIT": str(64 * _PART)}, 64 * _PART),
+], ids=["default", "default-tcp", "forced", "forced-odd", "legacy-count",
+        "under-a-partition", "over-the-round"])
+def test_credit_budget_on_a_fleet(extra, budget):
+    """ISSUE 48: unset, the budget is ten partitions' worth; a forced
+    BYTEPS_SCHEDULING_CREDIT is honoured to the byte. With the server
+    unable to answer (one worker pushes late) exactly the whole partitions
+    the budget admits stand in flight — one when the budget is under a
+    partition, the whole round when it is over it — and the sums stay
+    exact."""
+    outs = run_topology(2, 1, WORKER, mode="credit_budget",
+                        extra={"BYTEPS_PARTITION_BYTES": str(_PART), **extra})
+    for o in outs:
+        assert f"credit_budget {budget}" in o, o[-2000:]
+
+
 def test_priority_preemption(tmp_path):
     """Declaration-order priority (the reference's front-of-model-first
     scheduling): across repeated rounds under a 1-partition byte budget,
