@@ -374,10 +374,12 @@ long long bps_declare(const char* name, long long nelem, int dtype,
                               comp_config ? comp_config : "__default__");
 }
 
-int bps_push_pull(long long tensor_id, void* ptr, long long nelem, int dtype,
-                  int average, int async_mode) {
-  return g()->worker->PushPull(tensor_id, ptr, nelem, dtype, average != 0,
-                               async_mode != 0);
+// Pushes `src`, pulls the aggregate into `dst`; src == dst is the in-place
+// call. Both must stay alive, and src unmodified, until the handle settles.
+int bps_push_pull(long long tensor_id, const void* src, void* dst,
+                  long long nelem, int dtype, int average, int async_mode) {
+  return g()->worker->PushPull(tensor_id, src, dst, nelem, dtype,
+                               average != 0, async_mode != 0);
 }
 
 int bps_broadcast(long long tensor_id, void* ptr, long long nelem, int dtype,

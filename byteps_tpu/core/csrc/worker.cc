@@ -646,8 +646,9 @@ int64_t BytePSWorker::Declare(const std::string& name, int64_t nelem,
   return id;
 }
 
-int BytePSWorker::PushPull(int64_t tensor_id, void* ptr, int64_t nelem,
-                           int dtype, bool average, bool async_mode) {
+int BytePSWorker::PushPull(int64_t tensor_id, const void* src, void* dst,
+                           int64_t nelem, int dtype, bool average,
+                           bool async_mode) {
   std::unique_lock<std::mutex> lk(mu_);
   // Elastic membership gate (ISSUE 8): while a JOIN commits, new
   // rounds wait here so the acked counters stay final. Rounds already
@@ -687,19 +688,22 @@ int BytePSWorker::PushPull(int64_t tensor_id, void* ptr, int64_t nelem,
     task.fusible = fusion_bytes_ > 0 && task.bytes < fusion_bytes_;
     task.round = version;
     const int64_t t_enq = NowUs();
-    task.run = [this, ctx, p, ptr, esz, version, scale, average,
+    task.run = [this, ctx, p, src, dst, esz, version, scale, average,
                 async_mode, handle, t_enq] {
       // Scheduled-queue wait (credit admission + priority) — the first
       // stage of the per-round breakdown (ISSUE 7).
       RoundStats::Get().Track(RS_QUEUE, version, NowUs() - t_enq);
-      char* base = static_cast<char*>(ptr) + p->offset * esz;
+      // The partition's slice of the source (read: the raw payload, the
+      // codec's and the quantiser's input) and of the destination (the
+      // pull's target). An in-place caller passes one pointer as both.
+      const char* from = static_cast<const char*>(src) + p->offset * esz;
       int64_t raw_len = p->len * esz;
       PushOp op;
       op.p = p;
       op.ctx = ctx;
-      op.base = base;
+      op.base = static_cast<char*>(dst) + p->offset * esz;
       op.raw_len = raw_len;
-      op.payload = base;
+      op.payload = from;
       op.payload_len = raw_len;
       op.flags = async_mode ? FLAG_ASYNC : 0;
       op.version = version;
@@ -708,7 +712,7 @@ int BytePSWorker::PushPull(int64_t tensor_id, void* ptr, int64_t nelem,
       op.handle = handle;
       int64_t t0 = NowUs();
       if (p->comp) {
-        p->comp->Compress(reinterpret_cast<const float*>(base), p->len,
+        p->comp->Compress(reinterpret_cast<const float*>(from), p->len,
                           &p->comp_buf);
         op.payload = p->comp_buf.data();
         op.payload_len = static_cast<int64_t>(p->comp_buf.size());
@@ -729,7 +733,7 @@ int BytePSWorker::PushPull(int64_t tensor_id, void* ptr, int64_t nelem,
         // every later round) bit-identical across fault and fault-free
         // runs.
         if (p->qresidual.empty()) p->qresidual.assign(p->len, 0.0f);
-        const float* g = reinterpret_cast<const float*>(base);
+        const float* g = reinterpret_cast<const float*>(from);
         for (int64_t i = 0; i < p->len; ++i) p->qresidual[i] += g[i];
         BPS_CHECK(BlockQuant::EncodeEF(p->qresidual.data(), p->len,
                                        quant_block_, &p->qbuf))

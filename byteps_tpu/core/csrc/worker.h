@@ -60,10 +60,18 @@ class BytePSWorker {
                   const std::string& comp_config);
 
   // Enqueue all partitions; returns a completion handle immediately.
-  // The aggregate (sum over workers; divided by num_workers when `average`)
-  // is written back into `ptr` in place.
-  int PushPull(int64_t tensor_id, void* ptr, int64_t nelem, int dtype,
-               bool average, bool async_mode);
+  // The contribution is read from `src`; the aggregate (sum over workers;
+  // divided by num_workers when `average`) is written into `dst`. The two
+  // may be one buffer (the in-place call) or must not overlap. Contract:
+  // both stay alive, and `src` unmodified, until the handle settles — the
+  // raw payload is sent from `src` without a copy, so a fused frame's
+  // gather, the retry layer's resend and recovery's re-push all read it
+  // again, at any time before the settle. With a separate `src` they read
+  // the unsummed contribution whatever the pulls have written by then; in
+  // place, a partition is overwritten only by its own completed pull,
+  // after which nothing re-sends it.
+  int PushPull(int64_t tensor_id, const void* src, void* dst, int64_t nelem,
+               int dtype, bool average, bool async_mode);
 
   // Init-time weight sync: root's buffer becomes everyone's (in place).
   int Broadcast(int64_t tensor_id, void* ptr, int64_t nelem, int dtype,
@@ -105,12 +113,13 @@ class BytePSWorker {
 
   // One wire-ready push staged by a scheduled-queue task: everything the
   // send path needs after compression ran. `payload` points into the
-  // caller's buffer or the partition's comp_buf — both stay alive until
-  // the handle settles, so fused sends may gather them without copies.
+  // caller's source or the partition's comp_buf / qbuf — all stay alive
+  // until the handle settles, so fused sends may gather them without
+  // copies. `base` is the caller's destination, which the source may be.
   struct PushOp {
     Part* p = nullptr;
     TensorCtx* ctx = nullptr;
-    char* base = nullptr;  // caller buffer slice (pull destination)
+    char* base = nullptr;  // destination slice (pull target, scaled there)
     int64_t raw_len = 0;
     const void* payload = nullptr;
     int64_t payload_len = 0;
@@ -142,8 +151,8 @@ class BytePSWorker {
     //   rec_stage 2: push ACKED, pull in flight — the dead server's
     //     partial sum held our contribution, so recovery must RE-PUSH
     //     it (rec_op's payload pointers stay valid: the handle has not
-    //     settled, so the caller buffer / comp_buf are alive and the
-    //     pull has not overwritten them).
+    //     settled, so the caller's source / comp_buf are alive, and the
+    //     pull has not overwritten them — it writes the destination).
     int rec_stage = 0;
     int rec_push_rid = -1;
     PushOp rec_op;

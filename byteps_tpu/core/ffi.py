@@ -61,8 +61,8 @@ def _load() -> ctypes.CDLL:
                                 ctypes.c_int, ctypes.c_char_p]
     lib.bps_declare.restype = ctypes.c_longlong
     lib.bps_push_pull.argtypes = [ctypes.c_longlong, ctypes.c_void_p,
-                                  ctypes.c_longlong, ctypes.c_int,
-                                  ctypes.c_int, ctypes.c_int]
+                                  ctypes.c_void_p, ctypes.c_longlong,
+                                  ctypes.c_int, ctypes.c_int, ctypes.c_int]
     lib.bps_push_pull.restype = ctypes.c_int
     lib.bps_broadcast.argtypes = [ctypes.c_longlong, ctypes.c_void_p,
                                   ctypes.c_longlong, ctypes.c_int,
@@ -789,13 +789,29 @@ class Worker(_Node):
         return int(self._lib.bps_declare(name.encode(), nelem, dt, comp))
 
     def push_pull(self, tensor_id: int, arr: np.ndarray,
-                  average: bool = True, async_mode: bool = False) -> int:
-        """Enqueue all partitions of `arr`; sums across workers IN PLACE.
-        Returns a handle for wait/poll. The array must stay alive and
-        unmodified until the handle completes."""
+                  average: bool = True, async_mode: bool = False,
+                  out: Optional[np.ndarray] = None) -> int:
+        """Enqueue all partitions of `arr`, the source; the sum across
+        workers (the mean under `average`) lands in `out`, the destination
+        — `arr` itself when none is given, the in-place call. Returns a
+        handle for wait/poll. `out` has `arr`'s size and dtype and, unless
+        it is `arr`, no byte in common with it. The core sends from `arr`
+        without a copy (first send, a retry's resend, a recovery's re-push)
+        and writes `out` as partitions come back, so until the handle
+        completes both must stay alive, `arr` unmodified and `out` unread;
+        a separate `arr` is only ever read and may be read-only memory."""
         assert arr.flags["C_CONTIGUOUS"], "push_pull needs a contiguous array"
+        if out is None:
+            out = arr
+        elif not (out.flags["C_CONTIGUOUS"] and out.flags["WRITEABLE"]
+                  and out.size == arr.size and out.dtype == arr.dtype):
+            # the core writes arr.nbytes through this pointer
+            raise ValueError(
+                f"push_pull destination must be a writable contiguous array "
+                f"of {arr.size} {arr.dtype}, got {out.size} {out.dtype}")
         return int(self._lib.bps_push_pull(
-            tensor_id, arr.ctypes.data_as(ctypes.c_void_p), arr.size,
+            tensor_id, arr.ctypes.data_as(ctypes.c_void_p),
+            out.ctypes.data_as(ctypes.c_void_p), arr.size,
             _DTYPE_MAP[arr.dtype.name], int(average), int(async_mode)))
 
     def broadcast(self, tensor_id: int, arr: np.ndarray,

@@ -12,7 +12,10 @@ Reference analogues: byteps/torch/ops.py (push_pull on framework tensors)
 and the COPYD2H → PUSH → PULL → COPYH2D pipeline stages. As there, the
 pipeline is per tensor: a leaf is handed to the C core as soon as it is on
 the host and handed back to the device as soon as its handle has settled, so
-both host-boundary transfers run under the C core round.
+both host-boundary transfers run under the C core round. A push has a source
+and a destination: the core sends a landed leaf from where the runtime put it
+and pulls the sum into the buffer the tensor owns (``_Slot``), so a leaf that
+is already in its wire form is never copied on the host.
 """
 
 from __future__ import annotations
@@ -38,7 +41,7 @@ SPAN_STEP_PS = "bps.step.ps"        # training.py: ps_push_pull + decompress
 SPAN_STEP_APPLY = "bps.step.apply"  # training.py: dispatch of the apply program
 SPAN_PUSH_PULL = "bps.ps.push_pull"  # bridge thread: _ps_push_pull_impl, whole
 SPAN_D2H = "bps.ps.d2h"             # every leaf's D2H issued, the first landed
-SPAN_STAGE = "bps.ps.stage"         # per leaf: land, stage, enqueue into the C core
+SPAN_STAGE = "bps.ps.stage"         # per leaf: land, enqueue into the C core
 SPAN_WAIT = "bps.ps.wait"           # per leaf: settle, device_put; to the last settle
 SPAN_H2D = "bps.ps.h2d"             # last device_put + reshape/astype dispatch
 SPANS = (SPAN_STEP_GRAD, SPAN_STEP_PS, SPAN_STEP_APPLY, SPAN_PUSH_PULL,
@@ -110,32 +113,37 @@ declare_steps: int = 0
 # before the last handle settled — under the round — of ``bytes`` in all.
 put_stats: dict = {"put_early_bytes": 0, "bytes": 0}
 # The newest ps_push_pull's staging (test hook, and the stats of its
-# ``bps.ps.stage`` span): ``reused_bytes`` of the ``bytes`` staged went into
-# buffers an earlier call had left in the pool, the rest into new ones.
-stage_stats: dict = {"reused_bytes": 0, "bytes": 0}
+# ``bps.ps.stage`` span): of the ``bytes`` enqueued, ``direct_bytes`` are
+# pushed from the landed array itself, with no copy on the host (the rest
+# were copied into their slot first), and ``reused_bytes`` have a slot an
+# earlier call had left in the pool, the rest a new one.
+stage_stats: dict = {"direct_bytes": 0, "reused_bytes": 0, "bytes": 0}
 
 
 class _Slot:
-    """The staging buffer of one declared tensor: ps_push_pull copies the
-    landed leaf into it, the C core pushes from it and pulls into it in
-    place, and ``jax.device_put`` uploads from it — every step the same
-    memory, so its pages are mapped and faulted in once per tensor lifetime
-    and not once per step (a 154 MB leaf is above glibc's mmap ceiling: a
-    fresh copy of it is mmap, ≈ 37,700 page faults and munmap; PERF.md,
+    """The host buffer of one declared tensor: the C core pulls the sum
+    into it and ``jax.device_put`` uploads from it — and a leaf that cannot
+    be the wire's source as it landed (``_is_wire_source``) is copied into
+    it first and pushed from it in place. Every step the same memory, so
+    its pages are mapped and faulted in once per tensor lifetime and not
+    once per step (a 154 MB leaf is above glibc's mmap ceiling: a fresh
+    buffer of it is mmap, ≈ 37,700 page faults and munmap; PERF.md,
     PR 25). A tid has one element count and one wire dtype for its lifetime
     (the C core refuses a re-declare), so the buffer never changes size.
 
     ``result`` is a WEAK reference to the array the caller got back for the
-    leaf staged here last: ``device_put`` returns before the bytes have
-    left the host, and the runtime reads ``buf`` until they have, so the
-    next write into ``buf`` first waits for that array if anyone still holds
-    it (ready implies uploaded). Weak, because a strong one would keep a
-    whole tree of device memory alive into the caller's next program; the
-    price is that a result the caller has already dropped is not waited
-    for — its bytes can then be read only by device work queued on it, and
-    a caller whose next tree depends on that work (the train step: the
-    next gradients come from the parameters these uploads updated) has
-    waited for it by having the next tree on the host at all."""
+    leaf that came back through here last: ``device_put`` returns before
+    the bytes have left the host, and the runtime reads ``buf`` until they
+    have, so before ``buf`` is written again — by a copy, or by the core,
+    which writes it from the first pull on — ``claim`` waits for that array
+    if anyone still holds it (ready implies uploaded). Weak, because a
+    strong one would keep a whole tree of device memory alive into the
+    caller's next program; the price is that a result the caller has
+    already dropped is not waited for — its bytes can then be read only by
+    device work queued on it, and a caller whose next tree depends on that
+    work (the train step: the next gradients come from the parameters these
+    uploads updated) has waited for it by having the next tree on the host
+    at all."""
 
     __slots__ = ("buf", "result")
 
@@ -143,19 +151,16 @@ class _Slot:
         self.buf = np.empty(size, dtype)
         self.result = None
 
-    def fill(self, host: np.ndarray) -> np.ndarray:
-        """Copy ``host`` in (one pass; a half-precision leaf under a codec
-        is upcast to the float32 wire by the same pass) and return the
-        buffer in ``host``'s shape."""
+    def claim(self, shape) -> np.ndarray:
+        """The buffer in ``shape``, free to be written: the last upload
+        from it has left the host."""
         prev = self.result() if self.result is not None else None
         if prev is not None and not prev.is_deleted():
             prev.block_until_ready()
-        arr = self.buf.reshape(host.shape)
-        np.copyto(arr, host, casting="safe")
-        return arr
+        return self.buf.reshape(shape)
 
 
-# tensor id -> _Slot: one per tensor ps_push_pull has declared and staged,
+# tensor id -> _Slot: one per tensor ps_push_pull has declared and pushed,
 # dropped with the tid cache (a restarted fleet never sees a stale slot).
 _slots: dict = {}
 
@@ -176,10 +181,23 @@ def _is_host_memory_of(dev, arr: np.ndarray) -> bool:
     return dev.unsafe_buffer_pointer() == arr.ctypes.data
 
 
+def _is_wire_source(host: np.ndarray, wire_dtype: str) -> bool:
+    """The C core can send the landed array ``host`` as it stands: it is in
+    the tensor's wire dtype (not a half-precision leaf that a codec's
+    float32 wire upcasts) and C-contiguous with at least one axis. Asked of
+    the array that landed, not of the leaf: the TPU runtime lands a device
+    array in the layout the compiler gave it on the device, which for a
+    matrix may be column-major (PERF.md, PR 49). Such an array is only
+    ever read — it may be the runtime's read-only host copy, or on the
+    CPU backend the device buffer itself."""
+    return (host.ndim > 0 and host.dtype == np.dtype(wire_dtype)
+            and host.flags.c_contiguous)
+
+
 def _writable(arr: np.ndarray) -> np.ndarray:
     """A buffer the C core may push FROM and pull INTO in place, for
     ``ps_broadcast``, which stages a fresh one per call (a ``WireTree``
-    stages into the pool, ``_Slot``).
+    pulls into the pool, ``_Slot``, and copies only what it must).
     A ``jax.Array`` hands back a read-only host array — on the CPU backend
     a zero-copy view of the jax buffer, on the TPU the array's cached host
     copy (196 of 196 leaves of a GPT-2 tree; PERF.md, PR 24) — and
@@ -206,9 +224,10 @@ def _wait_all(client, staged):
     live-server partitions are still in flight — the C core's pull
     callbacks would then memcpy into freed memory (the same use-after-free
     the Wait/Poll settle semantics in worker.cc prevent one layer down).
-    Collect errors, wait everything, then re-raise the first."""
+    Collect errors, wait everything, then re-raise the first. The same
+    holds for a push's source, which the core reads again on a resend."""
     first_err = None
-    for h, _, _ in staged:
+    for h, *_ in staged:
         try:
             client.wait(h)
         except Exception as e:  # noqa: BLE001 — must settle all handles
@@ -234,8 +253,8 @@ def _wire_plan(leaves, codec: bool, config: Optional[str] = None):
     and stands where the fleet default would be inherited (None):
 
     - float32 + codec: inherit the default codec (None).
-    - bfloat16/float16 + codec: declare FLOAT32 and upcast the staged
-      host buffer — the in-jit half cast still halves the dominant
+    - bfloat16/float16 + codec: declare FLOAT32 and upcast into the
+      tensor's host buffer — the in-jit half cast still halves the dominant
       device<->host boundary both ways; the C codec (e.g. onebit, 32x)
       takes the DCN leg from there.
     - non-float leaves (int step counters in optimizer trees): declare
@@ -262,18 +281,23 @@ class WireTree:
     Leaves move through it a whole tree at once (``push_pull``) or in
     pieces — ``push`` some leaves now, others later, then one ``finish`` —
     for a caller whose leaves become ready program by program. Either way,
-    on the bridge thread, a leaf is staged into the buffer its tensor id
-    owns (``_Slot``) as it lands and goes back to the device as its handle
-    settles, and an error leaves only after every handle in flight has
-    settled. One round at a time: ``finish`` or an error ends it."""
+    on the bridge thread, a leaf is enqueued as it lands — pushed from the
+    landed array itself where that can be the wire's source
+    (``_is_wire_source``), else copied into the buffer its tensor id owns
+    (``_Slot``) and pushed from there; pulled into that buffer always — and
+    goes back to the device from the buffer as its handle settles. An error
+    leaves only after every handle in flight has settled. One round at a
+    time: ``finish`` or an error ends it."""
 
     def __init__(self, tids, plan):
         self.tids = tids
         self.wire_dtypes = [wire_dtype for wire_dtype, _ in plan]
-        # leaf index -> (handle, staged buffer, leaf) of what is in flight.
-        # A leaf's staged buffer (its tid's _Slot, in the leaf's shape) is
-        # push source, pull destination and device_put source; the C core
-        # owns it until its handle settles.
+        # leaf index -> (handle, buffer, leaf, source) of what is in flight.
+        # The buffer (the tid's _Slot, in the leaf's shape) is pull
+        # destination and device_put source; the source is what the core
+        # sends, and resends: the leaf's landed array, or the buffer. The C
+        # core owns both until the handle settles, so neither is let go
+        # before.
         self._staged: dict = {}
 
     def push_pull(self, leaves, average: bool = True,
@@ -285,7 +309,7 @@ class WireTree:
     def push(self, indices, leaves, average: bool = True,
              async_mode: Optional[bool] = None) -> None:
         """Hand over ``leaves``, the tree's leaves ``indices``: start their
-        D2H, stage and enqueue each as it lands."""
+        D2H, enqueue each as it lands."""
         _run_ordered(self._push, indices, _as_arrays(leaves), average,
                      async_mode)
 
@@ -326,13 +350,17 @@ class WireTree:
             wire_nbytes = [l.size * np.dtype(self.wire_dtypes[i]).itemsize
                            for i, l in zip(indices, leaves)]
             if not self._staged:  # a round's later pieces add to its first
-                stage_stats.update(reused_bytes=0, bytes=0)
+                stage_stats.update(direct_bytes=0, reused_bytes=0, bytes=0)
             stage_stats["reused_bytes"] += sum(
                 n for i, n in zip(indices, wire_nbytes)
                 if self.tids[i] in _slots)
             stage_stats["bytes"] += sum(wire_nbytes)
-            with jax.profiler.TraceAnnotation(SPAN_STAGE, **stage_stats):
-                for i, leaf in zip(indices, leaves):
+            # direct_bytes is counted as the leaves land and joins the
+            # span's stats at its end
+            with jax.profiler.TraceAnnotation(
+                    SPAN_STAGE, reused_bytes=stage_stats["reused_bytes"],
+                    bytes=stage_stats["bytes"]) as span:
+                for i, leaf, nbytes in zip(indices, leaves, wire_nbytes):
                     tid = self.tids[i]
                     # blocks only until THIS leaf has landed
                     host = np.asarray(leaf)
@@ -340,10 +368,19 @@ class WireTree:
                     if slot is None:
                         slot = _slots[tid] = _Slot(host.size,
                                                    self.wire_dtypes[i])
-                    arr = slot.fill(host)
-                    h = client.push_pull(tid, arr, average=average,
-                                         async_mode=async_mode)
-                    self._staged[i] = (h, arr, leaf)
+                    # from here the slot is the core's to write
+                    arr = slot.claim(host.shape)
+                    if _is_wire_source(host, self.wire_dtypes[i]):
+                        stage_stats["direct_bytes"] += nbytes
+                    else:
+                        # one pass; a half-precision leaf under a codec is
+                        # upcast to the float32 wire by the same pass
+                        np.copyto(arr, host, casting="safe")
+                        host = arr
+                    h = client.push_pull(tid, host, average=average,
+                                         async_mode=async_mode, out=arr)
+                    self._staged[i] = (h, arr, leaf, host)
+                span.set_metadata(direct_bytes=stage_stats["direct_bytes"])
         except BaseException:
             self._settle()
             raise
@@ -375,8 +412,9 @@ class WireTree:
             # only the last leaf's device_put waits for the whole round.
             with jax.profiler.TraceAnnotation(SPAN_WAIT):
                 for i in range(last + 1):
-                    # wait settles h whether it returns or raises
-                    h, arr, leaf = self._staged.pop(i)
+                    # wait settles h whether it returns or raises; this
+                    # frame holds the popped source until it has
+                    h, arr, leaf, _source = self._staged.pop(i)
                     client.wait(h)
                     if i < last:  # the last put is bps.ps.h2d's
                         devs.append(self._put(i, arr, leaf))
@@ -459,13 +497,16 @@ def ps_push_pull(tree, average: bool = True, prefix: str = "grad",
     zero-copy SArray, SURVEY.md §7 hard part #2): a per-leaf pipeline.
     Every leaf's D2H transfer is started up front and each leaf is enqueued
     the moment IT has landed, so the C core round begins with the first
-    leaf and not after the last. What is copied where: the landed leaf (the
-    runtime's read-only host copy) is copied ONCE, into the staging buffer
-    its tensor id owns across calls (``_Slot``; allocated on the tensor's
-    first call, warm pages from then on); the C core pushes from that
-    buffer and pulls the sum back into it in place, and ``device_put``
-    uploads from it — no other host copy, no per-call allocation. Each
-    leaf's H2D transfer is issued the moment its handle has settled, so
+    leaf and not after the last. What is copied where: nothing, for a leaf
+    that lands in its wire form — the C core sends the landed leaf (the
+    runtime's read-only host copy) from where it is, pulls the sum into the
+    buffer its tensor id owns across calls (``_Slot``; allocated on the
+    tensor's first call, warm pages from then on), and ``device_put``
+    uploads from that buffer. A leaf that has to change on the way (a
+    half-precision leaf under a codec, a host scalar, a strided view) is
+    copied ONCE, into that buffer, and pushed from it in place — no other
+    host copy, no per-call allocation. Each leaf's H2D transfer is issued
+    the moment its handle has settled, so
     only the last leaf's upload is left after the round. Tensor declares
     are cached for the tree's lifetime instead of re-registering every
     step (the tree's ``WireTree``, looked up by prefix and shape

@@ -81,7 +81,19 @@ def ps_grad_step(value_and_grad, mesh, axes, average, compress):
     ``value_and_grad(params, batch) -> (loss, grads)`` per chip, the local
     chips reduced inside the program, ``compress`` applied to what leaves
     it. The serial step differentiates the whole tree; a bucket program
-    (``bucketed.py``) the leaves of its bucket."""
+    (``bucketed.py``) the leaves of its bucket.
+
+    A gradient of three or more axes leaves the program flat, in row-major
+    order (``ps_apply_step`` gives it its shape back). Left in its shape,
+    the TPU compiler gives such a result the layout its producer likes (a
+    ``[768, 12, 64]`` q/k/v kernel's gradient comes out with axis 0
+    minor-most), the runtime lands it on the host in that layout, and the
+    wire is row-major: the host would pay a transposing copy of every such
+    leaf every step (85 of the 498 MB of a GPT-2 tree) where the device
+    pays a relayout at HBM speed. Flat and not pinned to a row-major
+    layout in its own shape, because that one is tiled over the last two
+    axes: 12 x 64 pads to 16 x 128, 142 MB of HBM for the same 36 leaves
+    (PERF.md, PR 49). Vectors and matrices land row-major as they are."""
 
     @jax.jit
     @partial(_shard_map, mesh=mesh, in_specs=(P(), P(axes)),
@@ -94,15 +106,19 @@ def ps_grad_step(value_and_grad, mesh, axes, average, compress):
                 lambda g, a=ax: reduce(g, a), grads)
             loss = lax.pmean(loss, ax)
         grads = jax.tree_util.tree_map(compress, grads)
-        return loss, grads
+        return loss, jax.tree_util.tree_map(
+            lambda g: g.reshape(-1) if g.ndim > 2 else g, grads)
 
     return grad_step
 
 
 def ps_apply_step(optimizer, donate):
-    """The device half after it: the optimizer on the summed gradients."""
+    """The device half after it: the optimizer on the summed gradients,
+    each in its parameter's shape again."""
 
     def apply_step(params, opt_state, grads):
+        grads = jax.tree_util.tree_map(lambda g, p: g.reshape(p.shape),
+                                       grads, params)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         return optax.apply_updates(params, updates), opt_state
 

@@ -88,6 +88,70 @@ def main() -> int:
                         arr.astype("float64"), expect.reshape(shape),
                         rtol=rtol, atol=1e-8)
 
+        elif mode == "src_dst":
+            # A push has a source and a destination: the core reads the
+            # contribution from one array — first send, a fused frame's
+            # gather, a retry's resend — and writes the aggregate into
+            # another. Every tensor goes round twice a round, from a
+            # read-only source into a destination that holds a sentinel (a
+            # read of the destination in the source's place would push
+            # it), and in place under a name of its own, which is the call
+            # as it always was: the two results must be equal to the bit,
+            # the numpy sum where the wire is exact, and the source what
+            # it was. Sizes: one fused with its neighbours, one a frame of
+            # its own, one of 19 partitions (BYTEPS_PARTITION_BYTES 65536).
+            import json
+
+            quantised = os.environ.get("BYTEPS_WIRE_QUANT", "") not in (
+                "", "0")  # the float32 wire is then block-quantised
+            sizes = (48, 20_000, 300_000)
+            wires = [("f32", "float32", ""), ("int", "int32", ""),
+                     ("f16", "float16", ""),
+                     ("topk", "float32", f"type=topk;k={sizes[-1]}"),
+                     ("onebit", "float32", "type=onebit")]
+            tids = {(name, n, twin): w.declare(
+                        f"sd_{name}_{n}_{twin}", n, dtype, compression=comp)
+                    for name, dtype, comp in wires for n in sizes
+                    for twin in "ab"}
+            for rnd in range(3):
+                average = rnd == 1
+                flight = []
+                for name, dtype, _ in wires:
+                    for n in sizes:
+                        base = (np.arange(n) % 61 + rnd
+                                - (0 if name == "int" else 30)).astype(dtype)
+                        src = np.ascontiguousarray(base * (rank + 1))
+                        kept, same = src.copy(), src.copy()
+                        src.flags.writeable = False
+                        dst = np.full_like(src, 7777)
+                        flight.append((
+                            w.push_pull(tids[name, n, "a"], src,
+                                        average=average, out=dst),
+                            w.push_pull(tids[name, n, "b"], same,
+                                        average=average),
+                            name, base, src, kept, dst, same))
+                for h, h_same, name, base, src, kept, dst, same in flight:
+                    w.wait(h)
+                    w.wait(h_same)
+                    assert src.tobytes() == kept.tobytes(), (rnd, name)
+                    assert dst.tobytes() == same.tobytes(), (rnd, name)
+                    if name == "onebit" or (quantised and name == "f32"):
+                        continue  # a lossy wire: the twin is the witness
+                    total = base.astype(np.float64) * sum(
+                        r + 1 for r in range(nw))
+                    if average:
+                        total = total // nw if name == "int" else total / nw
+                    np.testing.assert_array_equal(
+                        dst, total.astype(dst.dtype), err_msg=f"{rnd} {name}")
+            w.barrier(GROUP_WORKERS)  # all counters final
+            snap = w.metrics_snapshot()["counters"]
+            print(json.dumps({
+                "retries": snap.get("bps_retries_total", 0),
+                "chaos_drop": snap.get("bps_chaos_drop_total", 0),
+                "fused_frames": snap.get("bps_fused_msgs_total", 0)}),
+                flush=True)
+            w.barrier(GROUP_WORKERS)
+
         elif mode == "average":
             tid = w.declare("avg", 50, "float32", compression="")
             arr = np.full(50, float(rank + 1), dtype=np.float32)
@@ -1377,16 +1441,21 @@ def jax_stream_main() -> int:
     every worker's values: three rounds over one tree of device arrays —
     vectors, a scalar, a matrix and a last leaf of several partitions —
     summed and averaged, every round with values of its own, so nothing of
-    round n may survive in a staging slot into round n + 1. ``stage_stats``:
-    a prefix's first call stages into new buffers, every later one into the
-    slots of the call before — all of them, but for a slot whose upload the
-    CPU backend made of the buffer itself (an aligned one), which went with
-    that result; which those are is read here from the pointers the C core
-    was handed and each upload's own. BPS_STREAM_CASE: ``f32``; ``bf16_codec`` (bfloat16
-    leaves under a configured codec — here a top-k that keeps every element,
-    so the wire is exact — staged as float32 and put back as bfloat16);
-    ``int_leaf`` (an int32 counter among the floats). Two workers, so the
-    server's sum has one order."""
+    round n may survive in a slot into round n + 1. A leaf in its wire form
+    goes to the C core as source = the device array's own read-only host
+    array, destination = the slot (on this backend the source is the device
+    buffer itself: bit for bit what it was after the round, or jax's
+    immutable array was written); any other as one buffer, in place.
+    ``stage_stats``: a prefix's first call pulls into new buffers, every
+    later one into the slots of the call before — all of them, but for a
+    slot whose upload the CPU backend made of the buffer itself (an aligned
+    one), which went with that result; which those are is read here from
+    the pointers the C core was handed and each upload's own.
+    BPS_STREAM_CASE: ``f32``; ``bf16_codec`` (bfloat16 leaves under a
+    configured codec — here a top-k that keeps every element, so the wire
+    is exact — upcast into float32 slots, pushed from there and put back as
+    bfloat16); ``int_leaf`` (an int32 counter among the floats). Two
+    workers, so the server's sum has one order."""
     import jax
     jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
@@ -1413,9 +1482,13 @@ def jax_stream_main() -> int:
         handed, aliased, real_push_pull = [], set(), client.push_pull
         real_put = jax.device_put
 
-        def push_pull(tid, arr, **kwargs):  # what the C core is handed
-            handed.append((arr.ctypes.data, arr.nbytes))
-            return real_push_pull(tid, arr, **kwargs)
+        def push_pull(tid, arr, out=None, **kwargs):  # what the core gets
+            assert out.flags.writeable and out.flags.c_contiguous
+            if out is not arr:  # pushed from where it landed, never written
+                assert not arr.flags.writeable
+                assert not np.shares_memory(arr, out)
+            handed.append((out.ctypes.data, out.nbytes, arr))
+            return real_push_pull(tid, arr, out=out, **kwargs)
 
         def device_put(x):  # and which uploads ARE the host buffer
             dev = real_put(x)
@@ -1433,11 +1506,20 @@ def jax_stream_main() -> int:
                 out = ps_mod.ps_push_pull(
                     jax.tree_util.tree_map(jnp.asarray, mine),
                     average=average, prefix=f"st{int(average)}")
-                staged = sum(n for _, n in handed)
+                staged = sum(n for _, n, _ in handed)
+                # every leaf with an axis that is in its wire dtype
+                direct = [np.asarray(v) for v in mine.values()
+                          if v.shape and (case != "bf16_codec"
+                                          or v.dtype != dtype)]
+                sources = [a for p, _, a in handed if a.ctypes.data != p]
+                assert len(sources) == len(direct)
+                for got, want in zip(sources, direct):  # as they went in
+                    np.testing.assert_array_equal(got, want)
                 assert ps_mod.stage_stats == {
+                    "direct_bytes": sum(a.nbytes for a in direct),
                     "reused_bytes": kept.get(average, 0),
                     "bytes": staged}, (step, average, ps_mod.stage_stats)
-                kept[average] = sum(n for ptr, n in handed
+                kept[average] = sum(n for ptr, n, _ in handed
                                     if ptr not in aliased)
                 assert step == 0 or case != "bf16_codec" or (
                     ps_mod.stage_stats["reused_bytes"] >= staged - 4), (

@@ -6,6 +6,7 @@ stand in for device arrays."""
 
 import threading
 import types
+import weakref
 
 import jax
 import numpy as np
@@ -17,11 +18,14 @@ from byteps_tpu.jax import ps
 class Leaf:
     """What ``ps.py`` sees of a device array: ``dtype`` / ``size`` / ``shape``,
     ``is_ready``, ``copy_to_host_async`` and ``__array__``, which hands back
-    a read-only host copy as ``jax.Array`` does."""
+    a read-only host copy as ``jax.Array`` does — the one it keeps, or with
+    ``fresh`` a new one every time, which lives only as long as its taker
+    holds it."""
 
-    def __init__(self, log, index, value, fail=False, ready=True):
+    def __init__(self, log, index, value, fail=False, ready=True,
+                 fresh=False):
         self._log, self._index, self._fail = log, index, fail
-        self._ready = ready
+        self._ready, self._fresh = ready, fresh
         self._value = np.asarray(value)
         self._value.flags.writeable = False
         self.dtype, self.size = self._value.dtype, self._value.size
@@ -37,23 +41,38 @@ class Leaf:
         self._log.append(("take", self._index))
         if self._fail:
             raise RuntimeError(f"leaf {self._index} lost")
-        return self._value
+        if not self._fresh:
+            return self._value
+        taken = self._value.copy()
+        taken.flags.writeable = False
+        return taken
 
 
 class Client:
     """Handles are 0, 1, 2, ... in enqueue order. The "sum" of two equal
-    workers lands in the staged buffer when its handle is waited — a buffer
-    put to the device before that would carry the unsummed values (a
-    broadcast leaves the root's, this worker's, as they are). ``declared``
-    are the wire names in declaration order, ``pushed`` the tensor id and
-    the options of every enqueue, ``threads`` the names of the threads that
-    have called the client."""
+    workers — twice the source as it is THEN — lands in the destination
+    when its handle is waited: a buffer put to the device before that
+    would carry what it held, a source let go or rewritten before that
+    would be missed (a broadcast leaves the root's, this worker's, as they
+    are). ``sources`` are the arrays pushed from and ``buffers`` the
+    destinations, one of each a handle, the same array for an in-place
+    call; with ``weak_sources`` the client keeps no source alive, as the C
+    core keeps none, and a wait that finds its source gone says so in
+    ``lost``. ``declared`` are the wire names in declaration order,
+    ``pushed`` the tensor id and the options of every enqueue, ``threads``
+    the names of the threads that have called the client."""
 
-    def __init__(self, log, fail_wait=(), refuse_enqueue=None):
+    def __init__(self, log, fail_wait=(), refuse_enqueue=None,
+                 weak_sources=False):
         self._log, self._fail_wait = log, set(fail_wait)
         self._refuse, self.buffers, self.wire_dtypes = refuse_enqueue, [], []
+        self._weak, self.sources, self.lost = weak_sources, [], []
         self.declared, self.pushed, self.threads = [], [], set()
         self._broadcasts = set()
+
+    def _record(self, source, dest):
+        self.sources.append(weakref.ref(source) if self._weak else source)
+        self.buffers.append(dest)
 
     def declare(self, name, nelem, dtype, compression=None):
         self.threads.add(threading.current_thread().name)
@@ -61,32 +80,39 @@ class Client:
         self.wire_dtypes.append(np.dtype(dtype).name)
         return len(self.wire_dtypes) - 1
 
-    def push_pull(self, tid, arr, average=True, async_mode=False):
+    def push_pull(self, tid, arr, average=True, async_mode=False, out=None):
         self.threads.add(threading.current_thread().name)
         h = len(self.buffers)
         if h == self._refuse:
             raise RuntimeError(f"enqueue {h} refused")
-        assert arr.flags.writeable and arr.flags.c_contiguous
-        assert arr.dtype.name == self.wire_dtypes[tid]
+        out = arr if out is None else out
+        assert arr.flags.c_contiguous and out.flags.c_contiguous
+        assert out.flags.writeable and out.shape == arr.shape
+        assert arr.dtype.name == out.dtype.name == self.wire_dtypes[tid]
+        assert out is arr or not np.shares_memory(out, arr)
         self._log.append(("enqueue", h))
-        self.buffers.append(arr)
+        self._record(arr, out)
         self.pushed.append((tid, average, async_mode))
         return h
 
     def broadcast(self, tid, arr, root_rank=0):
         self.threads.add(threading.current_thread().name)
         assert arr.flags.writeable and arr.dtype.name == self.wire_dtypes[tid]
-        self.buffers.append(arr)
+        self._record(arr, arr)
         self._broadcasts.add(len(self.buffers) - 1)
         return len(self.buffers) - 1
 
     def wait(self, h):
         self.threads.add(threading.current_thread().name)
         self._log.append(("wait", h))
+        source = self.sources[h]() if self._weak else self.sources[h]
+        if source is None:  # failed or not, the core may read it till now
+            self.lost.append(h)
+            raise RuntimeError(f"the source of handle {h} was let go")
         if h in self._fail_wait:
             raise RuntimeError(f"handle {h} failed")
         if h not in self._broadcasts:
-            self.buffers[h] *= 2
+            np.multiply(source, 2, out=self.buffers[h])
 
 
 class Aliased(np.ndarray):
@@ -137,14 +163,16 @@ class Uploaded:
 def retake(tree, scale):
     """The same tree signature with other values (leaf i: scale × (i + 1))."""
     return [Leaf(l._log, l._index, np.full(l.shape, scale * (l._index + 1),
-                                           l.dtype)) for l in tree]
+                                           l.dtype), fresh=l._fresh)
+            for l in tree]
 
 
 @pytest.fixture
 def bridge(monkeypatch):
     """``bridge(sizes, **client)`` → (log, client, tree): the program state
     of a worker in PS mode whose client and ``device_put`` record into
-    ``log``; leaf ``i`` holds ``sizes[i]`` float32 of value ``i + 1``.
+    ``log``; leaf ``i`` holds ``sizes[i]`` float32 of value ``i + 1``
+    (``fresh``: a new host copy at every take, see ``Leaf``).
     ``real_uploads`` puts a copy of the host buffer on the CPU device, a
     ``jax.Array`` a program can take; ``mesh`` is the state's, for a step
     builder."""
@@ -166,8 +194,8 @@ def bridge(monkeypatch):
         return real_put(jax.tree_util.tree_map(np.array, x))
 
     def make(sizes, *, compressor="", dtype=np.float32, lost_leaf=None,
-             ready=True, uploads=None, real_uploads=False, mesh=None,
-             **client_kwargs):
+             ready=True, fresh=False, uploads=None, real_uploads=False,
+             mesh=None, **client_kwargs):
         client = Client(log, **client_kwargs)
         monkeypatch.setattr(ps.bps, "_st", lambda: types.SimpleNamespace(
             ps_client=client, mesh=mesh, config=types.SimpleNamespace(
@@ -179,7 +207,7 @@ def bridge(monkeypatch):
         if real_uploads:
             monkeypatch.setattr(jax, "device_put", put_copy)
         tree = [Leaf(log, i, np.full((n,), i + 1, dtype), fail=i == lost_leaf,
-                     ready=ready)
+                     ready=ready, fresh=fresh)
                 for i, n in enumerate(sizes)]
         return log, client, tree
 

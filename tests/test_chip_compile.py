@@ -333,3 +333,40 @@ def test_gpt2_124m_collective_step_compiles_for_one_v5e(topo):
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16e9
+
+
+@pytest.mark.slow
+def test_gpt2_124m_ps_gradients_leave_the_chip_row_major(topo, as_on_a_tpu):
+    """The PS step's gradient program — ``ps_grad_step``, GPT-2 124M at the
+    benchmark's b8 x s1024 — for one described chip: every gradient leaf
+    comes out in a row-major layout, so it lands on the host C-contiguous
+    and the PS leg pushes it from where it landed. In its own shape a
+    ``[768, 12, 64]`` q/k/v kernel's gradient gets ``major_to_minor``
+    (1, 2, 0) from the compiler — 36 leaves, 85 MB a step of transposing
+    copy on the host (PERF.md, PR 49) — so those leave flat."""
+    import numpy as np
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from byteps_tpu.jax.training import ps_grad_step
+    from byteps_tpu.models import GPT2Small, lm_loss
+
+    model = GPT2Small()
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dcn", "ici"))
+    grad = ps_grad_step(
+        jax.value_and_grad(lambda p, b: lm_loss(model.apply(p, b), b)),
+        mesh, ("dcn", "ici"), True, lambda g: g)
+    tokens = jax.ShapeDtypeStruct((8, 1024), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    lowered = grad.lower(_described(mesh, params, P()),
+                         _described(mesh, tokens, P(("dcn", "ici"))))
+    _, shapes = lowered.out_info
+    _, formats = lowered.compile().output_formats
+    leaves = jax.tree_util.tree_leaves(params)
+    assert len(leaves) == 196
+    assert sum(l.shape == (768, 12, 64) for l in leaves) == 36
+    for leaf, out, fmt in zip(leaves, jax.tree_util.tree_leaves(shapes),
+                              jax.tree_util.tree_leaves(formats)):
+        assert out.size == leaf.size
+        assert out.ndim == (leaf.ndim if leaf.ndim <= 2 else 1)
+        assert fmt.layout.major_to_minor == tuple(range(out.ndim)), (
+            leaf.shape, fmt)

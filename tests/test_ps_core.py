@@ -6,6 +6,7 @@ broadcast from root, handle semantics, compression codecs + error
 feedback, async mode, trace timeline, barriers.
 """
 
+import json
 import os
 
 import pytest
@@ -503,6 +504,43 @@ def test_jax_streamed_push_pull_is_exact(case, env):
     run_topology(2, 1, WORKER, mode="jax_stream",
                  extra={"BYTEPS_PS_MODE": "ps", "BPS_STREAM_CASE": case,
                         **env}, timeout=180)
+
+
+def _src_dst_rows(extra):
+    outs = run_topology(2, 1, WORKER, mode="src_dst", extra=extra,
+                        timeout=240)
+    rows = [json.loads(ln) for o in outs for ln in o.splitlines()
+            if ln.startswith("{")]
+    assert len(rows) == 2, outs
+    return rows
+
+
+@pytest.mark.parametrize("extra", [
+    {}, TCP, {"BYTEPS_FUSION_BYTES": "0"},
+    {"BYTEPS_WIRE_QUANT": "1", "BYTEPS_WIRE_QUANT_MIN_BYTES": "1024"}],
+    ids=["rings", "tcp", "unfused", "quantised"])
+def test_push_pull_from_a_source_into_a_destination(extra):
+    """``Worker.push_pull(tid, src, out=dst)`` over 2 workers: bit for bit
+    the in-place call's result (and the numpy sum wherever the wire is
+    exact) for float32, int32, float16, an exact and a lossy codec wire and
+    the block-quantised one, fused, alone and in 19 partitions; the
+    read-only source is what it was."""
+    rows = _src_dst_rows({"BYTEPS_PARTITION_BYTES": "65536", **extra})
+    assert (rows[0]["fused_frames"] > 0) == (
+        "BYTEPS_FUSION_BYTES" not in extra)
+
+
+def test_a_resent_push_reads_the_source():
+    """The same under a van that drops frames (``BYTEPS_CHAOS_DROP``, as
+    ``tests/test_chaos.py`` sets it): the retry layer's resend of a dropped
+    push reads the source again — not the destination, which holds a
+    sentinel — so every sum is still exact, and the counters show that
+    frames were dropped and resent."""
+    rows = _src_dst_rows({
+        "BYTEPS_PARTITION_BYTES": "65536", "BYTEPS_RETRY_TIMEOUT_MS": "200",
+        "BYTEPS_CHAOS_SEED": "42", "BYTEPS_CHAOS_DROP": "0.05"})
+    assert sum(r["chaos_drop"] for r in rows) > 0, rows
+    assert sum(r["retries"] for r in rows) > 0, rows
 
 
 def test_jax_timeline_combined_capture(tmp_path):

@@ -81,6 +81,8 @@ def test_a_ps_step_writes_the_eight_spans(tmp_path):
         staged = children[1]["stats"]
         assert staged["bytes"] == whole["stats"]["bytes"]
         assert 0 <= staged["reused_bytes"] <= staged["bytes"]
+        # float32 leaves, no codec: pushed from where they landed, all
+        assert staged["direct_bytes"] == staged["bytes"]
     first, second = by_name[ps.SPAN_PUSH_PULL]
     # one clock relation for the whole capture: (mono_ns - ts) is a constant
     drift = ((second["stats"]["mono_ns"] - second["start_ns"])

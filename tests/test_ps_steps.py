@@ -22,7 +22,7 @@ from tests.ps_recording import bridge  # noqa: F401
 from tests.ps_utils import REPO
 
 PARAMS = {"a": np.full((6,), 1.0, np.float32),
-          "b": np.full((2, 3), 2.0, np.float32),
+          "b": np.full((1, 2, 3), 2.0, np.float32),  # crosses flat: ps_grad_step
           "c": np.full((5,), 3.0, np.float32)}
 NBYTES = 4 * (6 + 6 + 5)
 LR = 0.5
@@ -66,19 +66,25 @@ def test_async_step_pushes_to_what_the_broadcast_declared(bridge):
 
 
 def test_async_steps_second_round_reuses_every_slot(bridge):
-    """(i) The async step stages into the pool: nothing reused in step 1,
-    every byte in step 2, into the same memory."""
+    """(i) The async step pulls into the pool: nothing reused in step 1,
+    every byte in step 2, into the same memory — and pushes every byte
+    from where it landed."""
     _, client, _ = bridge([], real_uploads=True)
     tx = optax.sgd(LR)
     params, step = make_async_train_step(loss_fn, tx, dict(PARAMS))
     opt_state = tx.init(params)
     params, opt_state, _ = step(params, opt_state, np.ones((4,)))
-    assert ps.stage_stats == {"reused_bytes": 0, "bytes": NBYTES}
+    assert ps.stage_stats == {"direct_bytes": NBYTES, "reused_bytes": 0,
+                              "bytes": NBYTES}
     params, opt_state, _ = step(params, opt_state, np.ones((4,)))
-    assert ps.stage_stats == {"reused_bytes": NBYTES, "bytes": NBYTES}
+    assert ps.stage_stats == {"direct_bytes": NBYTES,
+                              "reused_bytes": NBYTES, "bytes": NBYTES}
     pushes = client.buffers[3:]  # after the broadcast's three
     for first, second in zip(pushes[:3], pushes[3:]):
         assert first.ctypes.data == second.ctypes.data
+    # pushed from the deltas' landed arrays, pulled into the slots
+    for source, dest in zip(client.sources[3:], pushes):
+        assert not source.flags.writeable and source is not dest
 
 
 def bucketed(bridge, **client_kwargs):
@@ -110,15 +116,23 @@ def test_bucketed_step_declares_each_leaf_once_shape_signed(bridge):
 
 
 def test_bucketed_steps_second_round_reuses_every_slot(bridge):
-    """(ii) The pieces of a round add up in ``stage_stats``; step 2 stages
-    every leaf into the buffer step 1 left for it."""
+    """(ii) The pieces of a round add up in ``stage_stats``; step 2 pulls
+    every leaf into the buffer step 1 left for it, and both push every
+    leaf from where it landed."""
     _, client, step, params, opt_state = bucketed(bridge)
     params, opt_state, _ = step(params, opt_state, np.ones((4,)))
-    assert ps.stage_stats == {"reused_bytes": 0, "bytes": NBYTES}
+    assert ps.stage_stats == {"direct_bytes": NBYTES, "reused_bytes": 0,
+                              "bytes": NBYTES}
     params, opt_state, _ = step(params, opt_state, np.ones((4,)))
-    assert ps.stage_stats == {"reused_bytes": NBYTES, "bytes": NBYTES}
+    assert ps.stage_stats == {"direct_bytes": NBYTES,
+                              "reused_bytes": NBYTES, "bytes": NBYTES}
     for first, second in zip(client.buffers[:3], client.buffers[3:]):
         assert first.ctypes.data == second.ctypes.data
+    for source, dest in zip(client.sources, client.buffers):
+        assert not source.flags.writeable and source is not dest
+    # b, of three axes, left its program flat and has its shape again
+    assert [s.shape for s in client.sources[:3]] == [(6,), (5,), (6,)]
+    assert params["b"].shape == (1, 2, 3)
 
 
 @pytest.mark.parametrize("refused", [1, 2])

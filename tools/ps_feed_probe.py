@@ -20,8 +20,9 @@ Five parts, one JSON line each, over the 196 gradient leaf shapes of
                   by issue order and by whether the copies are issued before
                   or after that program's end.
 ``settle_trace``  ``ps_push_pull`` itself with taps: when leaves are enqueued,
-                  settled and put, and how much of the tree was staged into
-                  buffers of an earlier call (``stage_stats``).
+                  settled and put, and how much of the tree was pushed from
+                  where it landed (``direct_bytes``) and pulled into buffers
+                  of an earlier call (``reused_bytes``): ``stage_stats``.
 ``back``          the host time of ``jax.device_put`` leaf by leaf against
                   one call on the list, and each until the bytes have landed.
 
