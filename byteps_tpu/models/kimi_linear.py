@@ -242,6 +242,10 @@ class KimiSparseMoe(nn.Module):
     shared: int = 1
     dtype: jnp.dtype = jnp.bfloat16
     select_bias: bool = True      # False: the scores alone choose
+    scoring: str = "sigmoid"      # or "softmax" over all num_experts
+    # the shared expert's output times sigmoid(h w_sg), w_sg [d, 1]
+    shared_gate: bool = False
+    aux: bool = False             # True: returns (y, load-balance loss)
 
     @nn.compact
     def __call__(self, x):
@@ -250,7 +254,7 @@ class KimiSparseMoe(nn.Module):
         # fan-in scaling per expert: axis 0 counts experts, not inputs
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                             batch_axis=0)
-        y, _, _, counts = dropless_moe_ffn(
+        y, load_balance, _, counts = dropless_moe_ffn(
             x.reshape(b * s, d),
             self.param("router", nn.initializers.lecun_normal(),
                        (d, self.num_experts), jnp.float32),
@@ -259,7 +263,7 @@ class KimiSparseMoe(nn.Module):
             self.param("down", init, (held, m, d), jnp.float32),
             top_k=self.top_k, dtype=self.dtype,
             first_expert=self.first_expert, norm_topk=True,
-            scoring="sigmoid", norm_eps=1e-20,
+            scoring=self.scoring, norm_eps=1e-20,
             routed_scale=self.routed_scale,
             select_bias=jax.lax.stop_gradient(self.param(
                 "select_bias", nn.initializers.zeros, (self.num_experts,),
@@ -267,9 +271,14 @@ class KimiSparseMoe(nn.Module):
         if not self.is_initializing():
             self.sow("moe_stats", "counts", counts)
         with jax.named_scope(SHARED_SCOPE):
-            y = y.reshape(b, s, d) + LlamaMLP(
-                self.shared * m, self.dtype, name="shared")(x)
-        return y
+            y = y.reshape(b, s, d)
+            shared = LlamaMLP(self.shared * m, self.dtype, name="shared")(x)
+            if self.shared_gate:
+                shared = shared * jax.nn.sigmoid(nn.Dense(
+                    1, use_bias=False, dtype=self.dtype,
+                    name="shared_gate")(x).astype(jnp.float32))
+            y = y + shared
+        return (y, load_balance) if self.aux else y
 
 
 class KimiSublayer(nn.Module):
@@ -278,10 +287,11 @@ class KimiSublayer(nn.Module):
 
     make: Callable[[], nn.Module]
     eps: float = 1e-5
+    norm: Callable[..., nn.Module] = RMSNorm
 
     @nn.compact
     def __call__(self, x):
-        return x + self.make()(RMSNorm(self.eps, name="norm")(x))
+        return x + self.make()(self.norm(self.eps, name="norm")(x))
 
 
 class KimiBlock(nn.Module):

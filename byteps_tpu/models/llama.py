@@ -30,17 +30,24 @@ from byteps_tpu.models.transformer import _attention_fn, _default_positions
 
 
 class RMSNorm(nn.Module):
+    """``x rsqrt(mean x^2 + eps) scale``, float32. ``zero_centred``: the
+    learned vector starts at 0 and the row is multiplied by ``1 + scale``
+    (Qwen3-Next's; weight decay then pulls the scale to 1, not 0)."""
+
     eps: float = 1e-6
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x):
         orig_dtype = x.dtype
         xf = x.astype(jnp.float32)
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
+        scale = self.param(
+            "scale", nn.initializers.zeros if self.zero_centred
+            else nn.initializers.ones, (x.shape[-1],), jnp.float32)
         y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1,
                                         keepdims=True) + self.eps)
-        return (y * scale).astype(orig_dtype)
+        return (y * (1.0 + scale if self.zero_centred else scale)).astype(
+            orig_dtype)
 
 
 def yarn_inv_freq(rotary_dim: int, theta: float, factor: float,

@@ -261,8 +261,16 @@ def _blocks(s_q: int, s_k: int, d: int, window: Optional[int] = None):
     (my chip runs, PR 47), forward + backward 512 x 512 13.87 ms, 256 x 512
     15.90, 512 x 1024 16.60, 1024 x 1024 18.64, 256 x 256 19.05, 1024 x 512
     19.66, 512 x 256 21.02, 128 x 256 25.35, 128 x 128 35.66; the forward
-    alone is fastest at 512 x 1024 (4.93 against 5.80)."""
-    largest = 1024 if d <= 128 else 512
+    alone is fastest at 512 x 1024 (4.93 against 5.80). Heads 256 wide, 16
+    over 2 key heads x s8192 | s16384 (my chip runs, PR 50), forward / dQ +
+    dK/dV in ms: 1024 x 1024 5.56 / 14.59 | 16.84 / 48.50, the fastest of
+    ten both ways and inside VMEM (the operands are twice as wide as at
+    128, the float32 temporaries the same); 512 x 1024 5.91 / 14.81 | 17.84
+    / 49.51, 1024 x 512 6.17 / 14.66 | 19.12 / 50.01, 512 x 512 6.91 / 14.70
+    | 22.16 / 50.92, 256 x 1024 6.39 / 15.32, 256 x 512 8.24 / 16.12, 1024 x
+    256 9.33 / 15.29, 512 x 256 11.76 / 15.93, 128 x 512 10.59 / 19.37, 256
+    x 256 12.70 / 18.53. 192 keeps the 512 it was measured at in PR 39."""
+    largest = 512 if d == 192 else 1024
     if window is not None:
         largest = min(largest, WINDOW_BLOCK)
     return _block(s_q, largest), _block(s_k, largest)

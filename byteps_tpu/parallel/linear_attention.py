@@ -62,6 +62,26 @@ hand-written backward kernel that solves the transposed system (``dA =
 differentiating an inverse's steps. Same guarantees: no positive number
 exponentiated, no clamped decay, float32 wherever the XLA form has it.
 
+**One decay a head** (Gated DeltaNet, arXiv 2412.06464; ``g`` [b, s, h]
+and not [b, s, h, d_k]): ``alpha_t`` is one number, the exponent of a pair
+no longer turns on the channel, and
+
+    P(a, b)[i, j] = (a_i . b_j) e^{G_i - G_j}                 (j <= i)
+
+is one ``[C, d_k] x [d_k, C]`` product under a ``[C, C]`` mask of
+exponentials, ``K e^G`` a scaling of rows, ``e^{G_C}`` one float a head
+(``_head_operands``; form ``"head"``, every backend and dtype). No
+sub-chunk is needed for the rule above: ``G_i - G_j`` is formed for the
+pair itself, masked to ``-inf`` above the diagonal before the ``exp``, and
+never clamped. It is XLA's to schedule; the decay broadcast over the channels
+into the kernel pair instead (16 MB of float32 decay a layer and 1000 tokens)
+moved no step at s 8,192 and has none at 16,384, where the step does not
+compile with it (PERF.md section 6, PR 50). Chunking, the triangular solve, the groups and
+``_recurrence`` are the per-channel form's own. ``q`` and ``k`` may have
+fewer heads than ``v``, a divisor: key head j serves value heads ``j
+groups .. (j + 1) groups - 1``, and the per-head form multiplies each key
+head's pairs once.
+
 The scan has two levels, groups of chunks and the chunks of a group, and
 its backward pass is ``jax.grad`` through both, a group recomputed at a
 time (``jax.checkpoint``): the scan keeps one state a group. The XLA form
@@ -76,6 +96,7 @@ PR 39); of the operands, the kernel's is the hand-written one (PR 40).
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -86,9 +107,13 @@ from byteps_tpu.monitor import metrics
 # As a device trace names the op's two parts (``jax.named_scope``), and the
 # counter of its call sites at trace time.
 PREP_SCOPE, SCAN_SCOPE = "bps.kda.prep", "bps.kda.scan"
+# ... where the decay is one number a head (Gated DeltaNet)
+GDN_PREP_SCOPE, GDN_SCAN_SCOPE = "bps.gdn.prep", "bps.gdn.scan"
 SCAN_SITES = "bps_kda_scan_sites_total"
 # ... and of those that took the kernel form of a chunk's operands
 KERNEL_SITES = "bps_kda_kernel_sites_total"
+# ... or the per-head form
+HEAD_SITES = "bps_kda_head_sites_total"
 
 # What the kernel of ``byteps_tpu.ops.kda_chunk`` was measured at against
 # the XLA form on a TPU v5e and won (PERF.md section 6, PR 40): keys and
@@ -96,6 +121,13 @@ KERNEL_SITES = "bps_kda_kernel_sites_total"
 # Its tiling admits any chunk of whole sublane groups (a multiple of 8) up
 # to a row of lanes (128), and heads in whole sublane groups.
 KERNEL_WIDTH = 128
+
+# The chunks of a group in the per-head form, whose operands are computed
+# (and, in the backward pass, recomputed and differentiated) a group at a
+# time. On a TPU v5e at [1, 8192, 32, 128] over 16 key heads, bf16, chunks of
+# 32, forward + backward (PERF.md section 6, my chip runs, PR 50): 4 chunks
+# 22.8 ms, 16 chunks 32.0, 32 chunks 31.9; at s 16384 4 chunks 44.6, 8 45.7.
+HEAD_GROUP = 4
 
 # float32 operands as three bf16 passes: the triangular system's inverse and
 # the products between sub-chunks need more than the one pass a TPU gives a
@@ -115,8 +147,8 @@ def chunked(x: jax.Array, chunk: int) -> jax.Array:
 
 
 def chunk_log_decay(g: jax.Array, chunk: int) -> jax.Array:
-    """[b, n, chunk, h, d_k] float32: ``g`` [b, s, h, d_k] cumulated inside
-    each chunk of ``chunk`` tokens."""
+    """[b, n, chunk, h, ...] float32: ``g`` [b, s, h, d_k] (or [b, s, h],
+    one decay a head) cumulated inside each chunk of ``chunk`` tokens."""
     return jnp.cumsum(chunked(g.astype(jnp.float32), chunk), axis=2)
 
 
@@ -169,6 +201,14 @@ def _unit_lower_inverse(a):
     return t
 
 
+def _solved(t, beta, x, dtype):
+    """``T (beta x)``: the triangular system's solution for the right-hand
+    sides ``x`` [..., C, d], both operands in ``dtype``, float32 out."""
+    return jnp.einsum("...ij,...jd->...id", t.astype(dtype),
+                      (beta[..., None] * x).astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
 def _chunk_operands(q, k, v, beta, G, sub, dtype):
     """What the scan needs of every chunk and can have before it: ``W``,
     ``U_v``, ``Q e^G``, ``K e^{G_C - G}``, ``e^{G_C}`` and ``tril(P(q,
@@ -179,26 +219,51 @@ def _chunk_operands(q, k, v, beta, G, sub, dtype):
     i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
     t = _unit_lower_inverse(jnp.where(j < i, beta[..., None] * p_k, 0.0))
     total = G[..., -1:, :]                              # a chunk's whole decay
-
-    def solved(x):
-        return jnp.einsum("...ij,...jd->...id", t.astype(dtype),
-                          (beta[..., None] * x).astype(dtype),
-                          preferred_element_type=jnp.float32)
-
     # what only ever is a matmul operand is kept in ``dtype``
-    return (solved(k * jnp.exp(G)).astype(dtype), solved(v),
+    return (_solved(t, beta, k * jnp.exp(G), dtype).astype(dtype),
+            _solved(t, beta, v, dtype),
             (q * jnp.exp(G)).astype(dtype),
             (k * jnp.exp(total - G)).astype(dtype),
             jnp.exp(total[..., 0, :]), a_q.astype(dtype))
 
 
+def _head_operands(q, k, v, beta, G, dtype):
+    """``_chunk_operands`` for one decay a head (module docstring): G [...,
+    h, C]; q, k [..., h_k, C, d_k] with ``h_k`` a divisor of h; v [..., h,
+    C, d_v], beta [..., h, C], float32. ``e^{G_C}`` comes back [..., h, 1]:
+    it scales every row of the state alike."""
+    groups, c = G.shape[-2] // q.shape[-3], G.shape[-1]
+    i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    decay = jnp.exp(jnp.where(j <= i, G[..., :, None] - G[..., None, :],
+                              -jnp.inf))                # [..., h, C, C]
+
+    def pairs(a):       # a key head's products, under each of its decays
+        return jnp.repeat(jnp.einsum("...id,...jd->...ij", a, k,
+                                     precision=EXACT), groups, -3) * decay
+
+    a_q = pairs(q)
+    t = _unit_lower_inverse(jnp.where(j < i, beta[..., None] * pairs(k),
+                                      0.0))
+    q, k = (jnp.repeat(x, groups, -3) for x in (q, k))
+    total = G[..., -1:]                                 # a chunk's whole decay
+    e = jnp.exp(G)[..., None]
+    return (_solved(t, beta, k * e, dtype).astype(dtype),
+            _solved(t, beta, v, dtype), (q * e).astype(dtype),
+            (k * jnp.exp(total - G)[..., None]).astype(dtype),
+            jnp.exp(total), a_q.astype(dtype))
+
+
 def kda_form(backend: str, heads: int, d_k: int, d_v: int, dtype,
-             chunk: int) -> str:
-    """``"kernel"`` or ``"xla"``: how ``kda_attention`` computes a chunk's
-    operands at these shapes. One algorithm, two forms: the XLA form writes
+             chunk: int, per_head: bool = False) -> str:
+    """``"head"``, ``"kernel"`` or ``"xla"``: how ``kda_attention`` computes
+    a chunk's operands at these shapes. ``per_head`` (the decay is one
+    number a head): the per-head form, wherever it runs. One decay a
+    channel: one algorithm, two forms: the XLA form writes
     float32 ``[.., sub, sub, d_k]`` pairs and ``[.., n, C, d_k]`` decayed
     keys to HBM and reads them back, the kernel (TPU only) holds a chunk in
     VMEM."""
+    if per_head:
+        return "head"
     if backend != "tpu" or jnp.dtype(dtype) != jnp.bfloat16:
         return "xla"
     if (d_k, d_v) != (KERNEL_WIDTH, KERNEL_WIDTH) or heads % 8:
@@ -239,21 +304,28 @@ def _recurrence(state, w, u_v, q_g, k_d, gamma, a_q, dtype):
 def kda_attention(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
                   dtype=jnp.bfloat16):
     """``o`` [b, s, h, d_v] float32 of the recurrence in the module
-    docstring. q, k [b, s, h, d_k] (the caller normalises and scales them),
-    v [b, s, h, d_v], g [b, s, h, d_k] the log-decay (<= 0), beta [b, s, h].
-    ``chunk`` need not divide s (zero tokens are appended and dropped);
-    ``sub`` divides ``chunk`` (``sub = chunk``: all pairs one by one)."""
+    docstring. q, k [b, s, h_k, d_k] (the caller normalises and scales them;
+    ``h_k`` divides h), v [b, s, h, d_v], g the log-decay (<= 0), [b, s, h,
+    d_k] a channel or [b, s, h] a head, beta [b, s, h]. ``chunk`` need not
+    divide s (zero tokens are appended and dropped); ``sub`` divides
+    ``chunk`` (``sub = chunk``: all pairs one by one; the per-head form has
+    no use for it)."""
     if chunk % sub:
         raise ValueError(f"sub ({sub}) must divide chunk ({chunk})")
-    if not (q.shape == k.shape == g.shape and beta.shape == q.shape[:3]
-            and v.shape[:3] == q.shape[:3]):
-        raise ValueError("kda_attention: q, k, g [b, s, h, d_k], v [b, s, h, "
-                         f"d_v], beta [b, s, h]; got {q.shape}, {k.shape}, "
-                         f"{g.shape}, {v.shape}, {beta.shape}")
+    h, per_head = v.shape[2], g.ndim == 3
+    if not (q.shape == k.shape and q.shape[:2] == v.shape[:2]
+            and h % q.shape[2] == 0 and beta.shape == v.shape[:3]
+            and g.shape == v.shape[:3] + (() if per_head else q.shape[3:])):
+        raise ValueError("kda_attention: q, k [b, s, h_k, d_k] (h_k a "
+                         "divisor of h), v [b, s, h, d_v], g [b, s, h, d_k] "
+                         "or [b, s, h], beta [b, s, h]; got "
+                         f"{q.shape}, {k.shape}, {v.shape}, {g.shape}, "
+                         f"{beta.shape}")
     s = q.shape[1]
     metrics.inc_counter(SCAN_SITES)
-    kernel = kda_form(jax.default_backend(), q.shape[2], q.shape[3],
-                      v.shape[3], dtype, chunk) == "kernel"
+    form = kda_form(jax.default_backend(), h, q.shape[3], v.shape[3], dtype,
+                    chunk, per_head)
+    kernel = form == "kernel"
     if kernel:
         # imported here: a process that never reaches this line (every
         # other model, any CPU run) pays for no kernel library
@@ -261,13 +333,20 @@ def kda_attention(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
         from byteps_tpu.ops.kda_chunk import chunk_operands
 
         metrics.inc_counter(KERNEL_SITES)
+    if form == "head":
+        metrics.inc_counter(HEAD_SITES)
+    elif q.shape[2] != h:
+        # one decay a channel knows no groups: a gather XLA fuses
+        q, k = (jnp.repeat(x, h // q.shape[2], axis=2) for x in (q, k))
+    prep_scope, scan_scope = ((GDN_PREP_SCOPE, GDN_SCAN_SCOPE) if per_head
+                              else (PREP_SCOPE, SCAN_SCOPE))
     f32 = jnp.float32
     tokens = tuple(chunked(x.astype(f32), chunk) for x in (q, k, v, beta))
     if not kernel:
-        with jax.named_scope(PREP_SCOPE):
+        with jax.named_scope(prep_scope):
             G = chunk_log_decay(g, chunk)               # [b, n, C, h, d_k]
-    with jax.named_scope(SCAN_SCOPE):
-        b, n, _, h, d_k = tokens[0].shape
+    with jax.named_scope(scan_scope):
+        b, n, _, _, d_k = tokens[0].shape
         # Two levels: groups of chunks, one at a time and recomputed in the
         # backward pass, and the chunks of a group: the scan keeps one state
         # a group and a group's states while it is differentiated.
@@ -291,8 +370,13 @@ def kda_attention(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
             # A group's operands are alive for that group alone. The pairs
             # of a sub-chunk are a [.., sub, sub, d_k] tensor, which the
             # backward pass writes out: a group is as many chunks as keep
-            # that tensor under 2^26 entries (256 MB).
-            group = _divisor(n, 2 ** 26 // (b * h * chunk * sub * d_k))
+            # that tensor under 2^26 entries (256 MB). One decay a head has
+            # no such tensor (a chunk's largest are [C, C] a head): its
+            # group is ``HEAD_GROUP`` chunks.
+            group = _divisor(n, HEAD_GROUP if per_head else
+                             2 ** 26 // (b * h * chunk * sub * d_k))
+            operands = _head_operands if per_head else partial(
+                _chunk_operands, sub=sub)
 
             def grouped(x):          # [b, n, C, h, ...] -> [n / group, b,
                 x = x.reshape(b, n // group, group, *x.shape[2:])  # group,
@@ -302,7 +386,7 @@ def kda_attention(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
 
             def operands_of(xs):
                 return (jnp.moveaxis(x, 1, 0)
-                        for x in _chunk_operands(*xs, sub, dtype))
+                        for x in operands(*xs, dtype=dtype))
 
         @jax.checkpoint
         def one_group(state, xs):

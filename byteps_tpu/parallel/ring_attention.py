@@ -150,9 +150,11 @@ XLA_SITES = "bps_attention_xla_sites_total"
 # bf16, forward + backward, and won (PERF.md section 3, kernels; PR 36):
 # causal 16 x 128 at s 512 / 1024 / 2048 / 4096 1.20 / 1.56 / 2.25 / 3.16 ms
 # against 2.03 / 3.85 / 7.11 / 13.77, causal 12 x 64 at s 512 / 1024 / 2048
-# 1.73 / 2.09 / 3.05 against 2.91 / 5.57 / 10.29; 192 / 128 in PR 39.
+# 1.73 / 2.09 / 3.05 against 2.91 / 5.57 / 10.29; 192 / 128 in PR 39; 256 /
+# 256 in PR 50, where the XLA form's float32 scores of 16 heads at s 8192
+# are 4.3 GB and no step.
 KERNEL_MIN_SEQ = 512
-KERNEL_HEAD_DIMS = ((64, 64), (128, 128), (192, 128))
+KERNEL_HEAD_DIMS = ((64, 64), (128, 128), (192, 128), (256, 256))
 
 
 # A windowed call site, and the (query, key) pairs its form computes and

@@ -39,7 +39,8 @@ BF16, F32 = jnp.bfloat16, jnp.float32
     (("tpu", 4096, 256, 128, True, BF16), "xla"),
     (("tpu", 4096, 4096, 128, True, F32), "xla"),
     (("tpu", 4096, 4096, 80, True, BF16), "xla"),
-    (("tpu", 4096, 4096, 256, True, BF16), "xla"),
+    (("tpu", 4096, 4096, 256, True, BF16), "kernel"),     # PR 50
+    (("tpu", 4096, 4096, 512, True, BF16), "xla"),
     (("cpu", 4096, 4096, 128, True, BF16), "xla"),
     (("gpu", 4096, 4096, 128, True, BF16), "xla"),
     (("cpu", 128, 128, 64, False, BF16), "xla"),

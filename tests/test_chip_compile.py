@@ -52,7 +52,7 @@ FLASH_CASES = {
     # what full_attention hands the kernel in the benchmark's cells
     "ouro_olmoe_b1_s4096_h16_d128": ((1, 4096, 16, 128), True, None),
     "gpt2_124m_b8_s1024": ((8, 1024, 12, 64), True, None),
-    # the narrower blocks of a head wider than 128
+    # a head 256 wide: blocks of 1024 again (PR 50), twice the operand bytes
     "wide_head_b1_s2048_d256": ((1, 2048, 4, 256), True, None),
 }
 
@@ -261,6 +261,36 @@ def test_rotary_latent_attention_compiles_for_v5e(topo, as_on_a_tpu):
         argnums=(0, 1))).lower(params, x).compile().as_text()
     assert text.count("tpu_custom_call") >= 3      # forward, dq, dkv
     assert "bps.mla.proj" in text and "bps.mla.attend" in text
+
+
+@pytest.mark.parametrize("kind", ["gdn", "attn"])
+def test_qwen3_next_mixers_compile_for_v5e(topo, as_on_a_tpu, kind):
+    """The two mixers of the Qwen3-Next cell, forward and backward, b 1 x s
+    16384 at the published widths: Gated DeltaNet (16 key heads under 32
+    value heads of 128; the per-head form is XLA's to compile, no kernel)
+    and gated attention (16 query heads over 2 key heads of 256 feeding the
+    flash kernels at their fourth width, with the blocks ``_blocks`` derives
+    for it)."""
+    from byteps_tpu.models.qwen3_next import GatedAttention, GatedDeltaNet
+
+    one = SingleDeviceSharding(topo.devices[0])
+    layer = (GatedDeltaNet(16, 32, 128, 128) if kind == "gdn"
+             else GatedAttention(16, 2, 256, 1e7, 0.25))
+    x = jax.ShapeDtypeStruct((1, 16384, 2048), jnp.float32, sharding=one)
+    params = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8, 2048))))
+    text = jax.jit(jax.grad(
+        lambda p, x: layer.apply(p, x).astype(jnp.float32).sum(),
+        argnums=(0, 1))).lower(params, x).compile().as_text()
+    if kind == "gdn":
+        assert "tpu_custom_call" not in text
+        for scope in ("bps.gdn.prep", "bps.gdn.scan", "bps.gdn.out"):
+            assert scope in text
+    else:
+        assert text.count("tpu_custom_call") >= 3      # forward, dq, dkv
+        assert "bps.gattn.proj" in text and "bps.gattn.attend" in text
 
 
 def test_joyai_collective_step_compiles_for_one_v5e(topo, as_on_a_tpu):

@@ -1,0 +1,63 @@
+"""``tools/scan_check.py`` at a small size on the CPU: the check the chip
+runs at the Qwen3-Next cell's shapes passes for the sound scan and fails for
+a bf16 state, a clamped decay and the other head grouping; the 256-wide
+attention case is ``tools/attention_check.py``'s own check."""
+
+import importlib
+import json
+import os
+
+import jax.numpy as jnp
+import pytest
+
+from tools import scan_check as tool
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMALL = tool.ScanCase(1024, 2, 4, 64, 64, 64)
+
+
+@pytest.mark.parametrize("seed", (0, 2147483907))
+def test_the_check_passes_the_scan_and_fails_its_controls(seed):
+    record = tool.check_scan(SMALL, seed)
+    assert record["ok"], record
+    assert set(record["scan"]) == set(tool.TENSORS)
+    assert max(record["scan"].values()) <= tool.SCAN_TOLERANCE
+    assert set(record["controls"]) == {"bf16_state", "heads_interleaved",
+                                       "clamped_at_-20"}
+    for control in record["controls"].values():
+        assert max(control.values()) > tool.SCAN_TOLERANCE
+    assert record["min_chunk_log_decay"] < tool.CLAMP
+
+
+def test_a_wrong_scan_fails_the_check():
+    """The program computed wrongly (its state's decay rounded to bf16)
+    reads above the tolerance, so ``ok`` is false."""
+    from byteps_tpu.parallel.linear_attention import kda_attention
+
+    def rounded(q, k, v, g, beta):
+        return kda_attention(q, k, v, g.astype(jnp.bfloat16).astype(
+            jnp.float32) * 1.1, beta, chunk=SMALL.chunk, sub=SMALL.chunk)
+
+    record = tool.check_scan(SMALL, 1, scan=rounded)
+    assert not record["ok"]
+    assert max(record["scan"].values()) > tool.SCAN_TOLERANCE
+
+
+def test_the_cell_cases_are_the_configuration_s():
+    cfg = json.load(open(os.path.join(
+        REPO, "benchmark", "configs", "qwen3-next-80b-a3b.json")))
+    scan, attention = tool.cell_cases()
+    assert scan == (cfg["seq_len"], 16, 32, 128, 128, cfg["gdn_chunk"])
+    assert attention[1:] == (cfg["seq_len"], 16, 2, 256, None)
+
+
+def test_the_attention_case_at_a_small_size():
+    """8 query heads a key head, as the cell's: the interpreted kernels pass
+    ``attention_check``'s check and its two controls fail it."""
+    fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+    record = tool.attention_check.check(
+        tool.attention_check.Case("gated", 96, 16, 2, 32, None), seed=0,
+        attend=lambda q, k, v, window: fa.flash_attention(
+            q, k, v, True, None, 32, 64, True, window))
+    assert record["ok"], record
+    assert set(record["controls"]) == {"heads_interleaved"}

@@ -1,0 +1,213 @@
+"""On the chip: the per-head delta-rule scan and the 256-wide attention
+kernels at the Qwen3-Next cell's shapes against the plain reference —
+values and every gradient, tensor by tensor — and the wrong computations the
+tolerance must fail.
+
+    chiprun --chips 1 -- python3 tools/scan_check.py [--seed N]
+
+What a benchmark run cannot see (``benchmark/lib/reference.py`` compares
+three losses to 2e-3: PERF.md section 7, "``correct`` by cell") is held
+here, on the device, for the compiled programs the cell runs.
+
+**The scan**: ``kda_attention`` with one decay a head, b 1 x s of the cell,
+16 key heads of 128 under 32 value heads of 128, bf16 operands, the file's
+chunk — ``o`` and the gradients of q, k, v, g and beta against
+``benchmark/lib/plain_qwen3_next.py::gated_delta_rule``, the recurrence
+token by token in float32 with nothing of ``byteps_tpu`` in it. q and k are
+unit vectors (q times 128^-1/2), v and the cotangent standard normal, beta a
+sigmoid of one, and the log-decay Mamba's rule as the configuration
+initialises it: ``-A softplus(n + dt_bias)``, A over (1, 16) and dt over
+(1e-3, 1e-1), both spread evenly in the logarithm over the heads, n standard
+normal — heads that forget in a token beside heads that remember
+thousands. The measure, for each tensor: ``|got - want|_2 / |want|_2``. The controls are the reference
+computed wrongly, not the program: a state rounded to bf16 after every
+token, a chunk's cumulated log-decay clamped at -20 (what a form that
+exponentiates its negation does to stay finite; as per-token decays, so
+that the recurrence computes exactly what such a chunked form would), and
+value head i reading key head ``i % 16`` and not ``i // 2``. The run fails
+— exit 1, ``"ok": false`` — if a tensor of the program reads above
+``SCAN_TOLERANCE`` or a control's worst tensor below it.
+
+**The kernels**: ``tools/attention_check.py``'s check, called, at 16 query
+heads over 2 key heads of 256 under the causal triangle: out, dQ, dK, dV
+within its ``TOLERANCE``, out alone within its ``OUT_TOLERANCE``, the other
+head grouping and bf16 logits and statistics above them.
+
+My chip run's readings (PR 50) are in PERF.md section 6. One JSON line a
+case, then ``{"ok": ..., "device": ...}``; off the chip both run at a small
+size (``tests/test_scan_check.py``).
+"""
+
+from __future__ import annotations
+
+import argparse
+import collections
+import json
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from tools import attention_check  # noqa: E402
+
+SCAN_TOLERANCE = 1.0e-2     # every tensor of the scan (PERF.md, PR 50)
+CLAMP = -20.0
+TENSORS = ("o", "dq", "dk", "dv", "dg", "dbeta")
+
+ScanCase = collections.namedtuple(
+    "ScanCase", "seq key_heads heads key_dim value_dim chunk")
+
+
+def cell_cases():
+    """``(the scan's case, the attention's)`` from the configuration's
+    file."""
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "qwen3-next-80b-a3b.json")) as f:
+        cfg = json.load(f)
+    return (ScanCase(cfg["seq_len"], cfg["linear_num_key_heads"],
+                     cfg["linear_num_value_heads"],
+                     cfg["linear_key_head_dim"],
+                     cfg["linear_value_head_dim"], cfg["gdn_chunk"]),
+            attention_check.Case("gated", cfg["seq_len"],
+                                 cfg["num_attention_heads"],
+                                 cfg["num_key_value_heads"],
+                                 cfg["head_dim"], None))
+
+
+def clamped_in_chunks(g, chunk: int, floor: float):
+    """The per-token log-decays [b, s, h] whose cumulated sum inside every
+    chunk of ``chunk`` tokens is ``max(G, floor)``: what a chunked form that
+    clamps its cumulated log-decay at ``floor`` really computes."""
+    import jax.numpy as jnp
+
+    G = jnp.maximum(cumulated_in_chunks(g, chunk), floor)
+    steps = jnp.diff(G, axis=2, prepend=jnp.zeros_like(G[:, :, :1]))
+    return steps.reshape(g.shape[0], -1, g.shape[2])[:, :g.shape[1]]
+
+
+def cumulated_in_chunks(g, chunk: int):
+    """[b, n, chunk, h]: ``g`` [b, s, h] cumulated inside each chunk of
+    ``chunk`` tokens, zeros after s."""
+    import jax.numpy as jnp
+
+    b, s, h = g.shape
+    return jnp.cumsum(jnp.pad(g, ((0, 0), (0, (-s) % chunk), (0, 0))).reshape(
+        b, -1, chunk, h), axis=2)
+
+
+def scan_inputs(case: ScanCase, seed: int):
+    """(q, k, v, g, beta, cotangent), float32, b 1."""
+    import jax
+    import jax.numpy as jnp
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    s, h = case.seq, case.heads
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    # both ranges spread evenly over the heads, so that every seed holds a
+    # head that forgets in a token and one that remembers thousands
+    spread = jnp.arange(h, dtype=jnp.float32) / max(h - 1, 1)
+    rate, dt = 16.0 ** spread, 1e-3 * 100.0 ** spread
+    dt_bias = dt + jnp.log(-jnp.expm1(-dt))
+    return (unit(jax.random.normal(keys[0], (1, s, case.key_heads,
+                                             case.key_dim)))
+            * case.key_dim ** -0.5,
+            unit(jax.random.normal(keys[1], (1, s, case.key_heads,
+                                             case.key_dim))),
+            jax.random.normal(keys[2], (1, s, h, case.value_dim)),
+            -rate * jax.nn.softplus(jax.random.normal(keys[3], (1, s, h))
+                                    + dt_bias),
+            jax.nn.sigmoid(jax.random.normal(keys[4], (1, s, h))),
+            jax.random.normal(keys[5], (1, s, h, case.value_dim)))
+
+
+def check_scan(case: ScanCase, seed: int, scan=None) -> dict:
+    """The program's scan (``scan(q, k, v, g, beta)``, by default
+    ``kda_attention`` in bf16 at the case's chunk) against the recurrence,
+    and the controls. ``scan`` is a test's handle on a wrong program."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.lib.plain_qwen3_next import gated_delta_rule
+
+    *operands, w = scan_inputs(case, seed)
+    if scan is None:
+        from byteps_tpu.parallel.linear_attention import kda_attention
+
+        def scan(q, k, v, g, beta):
+            return kda_attention(q, k, v, g, beta, chunk=case.chunk,
+                                 sub=case.chunk, dtype=jnp.bfloat16)
+
+    def run(fn):
+        """(o, dq, dk, dv, dg, dbeta) of ``fn(q, k, v, g, beta)``."""
+        def scalar(*a):
+            out = fn(*a)
+            return (out * w).sum(), out
+
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            scalar, argnums=(0, 1, 2, 3, 4), has_aux=True))(*operands)
+        return jax.device_get((out, *grads))
+
+    block = min(128, case.seq)
+
+    def plain(**wrong):
+        def fn(q, k, v, g, beta):
+            with jax.default_matmul_precision("highest"):
+                return gated_delta_rule(q[0], k[0], v[0], g[0], beta[0],
+                                        scan_block=block, **wrong)[None]
+
+        return fn
+
+    def readings(got):
+        return dict(zip(TENSORS, map(attention_check._relative, got, want)))
+
+    want = run(plain())
+    controls = {
+        "bf16_state": plain(state_dtype=jnp.bfloat16),
+        "heads_interleaved": plain(key_head_of=[
+            i % case.key_heads for i in range(case.heads)]),
+    }
+    sound = plain()
+    controls["clamped_at_-20"] = lambda q, k, v, g, beta: sound(
+        q, k, v, clamped_in_chunks(g, case.chunk, CLAMP), beta)
+    record = {
+        "case": case._asdict(), "seed": seed,
+        "min_chunk_log_decay": float(
+            cumulated_in_chunks(operands[3], case.chunk).min()),
+        "scan": readings(run(scan)),
+        "controls": {name: readings(run(fn))
+                     for name, fn in controls.items()},
+        "tolerance": SCAN_TOLERANCE}
+    record["ok"] = bool(
+        max(record["scan"].values()) <= SCAN_TOLERANCE
+        and all(max(c.values()) > SCAN_TOLERANCE
+                for c in record["controls"].values()))
+    return record
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args()
+
+    import jax
+
+    device = jax.devices()[0]
+    ok = device.platform == "tpu"        # never a CPU's figures by mistake
+    if ok:
+        scan_case, attention_case = cell_cases()
+        record = check_scan(scan_case, args.seed)
+        ok = ok and record["ok"]
+        print(json.dumps(record), flush=True)
+        record = attention_check.check(attention_case, args.seed)
+        ok = ok and record["ok"] and record["kernel_in_program"]
+        print(json.dumps(record), flush=True)
+    print(json.dumps({"ok": ok, "device": {
+        "platform": device.platform, "kind": device.device_kind}}))
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
