@@ -249,28 +249,28 @@ def _block(s: int, largest: int) -> int:
 
 def _blocks(s_q: int, s_k: int, d: int, window: Optional[int] = None):
     """(block_q, block_k) of the three kernels, from the shape. On a v5e,
-    causal bf16, at 16 x 128 x s4096 and at 12 x 64 x s1024 (PERF.md section
-    3, my chip runs, PR 36) — forward: 1024 x 1024 0.84 and 0.49 ms, the
-    fastest of twelve and of seven; 512 x 1024 0.94 and 0.61, 512 x 512
+    causal bf16, at 16 x 128 x s4096 and at 12 x 64 x s1024 (my chip runs,
+    PR 36) — forward: 1024 x 1024 0.84 and 0.49 ms, the fastest; 512 x 512
     1.50 and 0.82, 256 x 256 2.85 and 1.34; 2048 x 2048 does not fit VMEM.
     dQ + dK/dV: 1024 x 1024 2.31 and 1.60, 512 x 512 2.39 and 1.61, 256 x
-    512 3.00 and 1.90, 256 x 256 4.03 and 2.34; 1024 x 2048 does not fit
-    (the backward kernels hold p, dp and ds, three float32 [bq, bk]
-    temporaries). Under a ``window`` the blocks are capped at
-    ``WINDOW_BLOCK``: at 64 heads over 8 key heads x 128 x s8192, window 512
-    (my chip runs, PR 47), forward + backward 512 x 512 13.87 ms, 256 x 512
-    15.90, 512 x 1024 16.60, 1024 x 1024 18.64, 256 x 256 19.05, 1024 x 512
-    19.66, 512 x 256 21.02, 128 x 256 25.35, 128 x 128 35.66; the forward
-    alone is fastest at 512 x 1024 (4.93 against 5.80). Heads 256 wide, 16
-    over 2 key heads x s8192 | s16384 (my chip runs, PR 50), forward / dQ +
-    dK/dV in ms: 1024 x 1024 5.56 / 14.59 | 16.84 / 48.50, the fastest of
-    ten both ways and inside VMEM (the operands are twice as wide as at
-    128, the float32 temporaries the same); 512 x 1024 5.91 / 14.81 | 17.84
-    / 49.51, 1024 x 512 6.17 / 14.66 | 19.12 / 50.01, 512 x 512 6.91 / 14.70
-    | 22.16 / 50.92, 256 x 1024 6.39 / 15.32, 256 x 512 8.24 / 16.12, 1024 x
-    256 9.33 / 15.29, 512 x 256 11.76 / 15.93, 128 x 512 10.59 / 19.37, 256
-    x 256 12.70 / 18.53. 192 keeps the 512 it was measured at in PR 39."""
-    largest = 512 if d == 192 else 1024
+    256 4.03 and 2.34; 1024 x 2048 does not fit (the backward kernels hold
+    p, dp and ds, three float32 [bq, bk] temporaries). Under a ``window``
+    the blocks are capped at ``WINDOW_BLOCK``: at 64 heads over 8 key heads
+    x 128 x s8192, window 512 (my chip runs, PR 47), forward + backward 512
+    x 512 13.87 ms, 256 x 512 15.90, 512 x 1024 16.60, 1024 x 1024 18.64,
+    five more 19.05-35.66; the forward alone is fastest at 512 x 1024 (4.93
+    against 5.80). Heads 256 wide, 16 over 2 key heads x s8192 | s16384 (my
+    chip runs, PR 50), forward / dQ + dK/dV in ms: 1024 x 1024 5.56 / 14.59
+    | 16.84 / 48.50, the fastest of ten both ways; 512 x 1024 5.91 / 14.81 |
+    17.84 / 49.51, 1024 x 512 6.17 / 14.66 | 19.12 / 50.01, 512 x 512 6.91 /
+    14.70 | 22.16 / 50.92, the six smaller pairs 6.39-12.70 / 15.29-19.37.
+    Keys 192 / values 128, 32 heads (my chip runs, PR 51; the same ten, the
+    call's layout copies in both figures): 1024 x 1024 9.26 / 24.62 | 28.64
+    / 83.06, the fastest again; 512 x 1024 10.43 / 25.26 | 32.89 / 85.77,
+    1024 x 512 14.44 / 25.08 | 49.14 / 85.51, 512 x 512 (this width's until
+    then) 15.04 / 25.83 | 51.76 / 90.51, the six smaller pairs 12.41-27.56 /
+    26.40-43.89. One rule for four widths."""
+    largest = 1024
     if window is not None:
         largest = min(largest, WINDOW_BLOCK)
     return _block(s_q, largest), _block(s_k, largest)

@@ -1,10 +1,12 @@
 """``tools/attention_check.py`` at a small size, the kernels interpreted:
-the check the chip runs at the Laguna cell's shapes passes for the sound
-kernel and fails for a band one key off, for the wrong head grouping and
-for bf16 logits."""
+the check the chip runs at the Laguna cell's shapes and at the latent call
+of the JoyAI and Kimi-Linear cells passes for the sound kernel and fails for
+a band one key off, for the wrong head grouping, for the other width's
+softmax scale and for bf16 logits."""
 
 import importlib
 import importlib.util
+import json
 import os
 
 import pytest
@@ -21,21 +23,22 @@ def tool():
     return module
 
 
-def _interpreted(q, k, v, window):
+def _interpreted(q, k, v, window, scale=None):
     fa = importlib.import_module("byteps_tpu.ops.flash_attention")
     # blocks of 32 x 64: the band crosses blocks
-    return fa.flash_attention(q, k, v, True, None, 32, 64, True, window)
+    return fa.flash_attention(q, k, v, True, scale, 32, 64, True, window)
 
 
-@pytest.mark.parametrize("name, window, controls", [
-    ("windowed", 24, {"window_minus_1", "window_plus_1",
-                      "heads_interleaved"}),
-    ("global", None, {"heads_interleaved"}),
-])
+@pytest.mark.parametrize("case, controls", [
+    (("windowed", 96, 6, 2, 16, 24), {"window_minus_1", "window_plus_1",
+                                      "heads_interleaved"}),
+    (("global", 96, 6, 2, 16, None), {"heads_interleaved"}),
+    # keys 24 wide, values 16: out and dV take the value width
+    (("latent", 96, 4, 4, 24, None, 16), {"scale_of_value_width"}),
+], ids=lambda value: value[0] if isinstance(value, tuple) else "")
 def test_the_check_passes_the_kernel_and_fails_its_controls(
-        tool, name, window, controls):
-    record = tool.check(tool.Case(name, 96, 6, 2, 16, window), seed=0,
-                        attend=_interpreted)
+        tool, case, controls):
+    record = tool.check(tool.Case(*case), seed=0, attend=_interpreted)
     assert record["ok"], record
     assert set(record["kernel"]) == set(tool.TENSORS)
     assert max(record["kernel"].values()) <= tool.TOLERANCE
@@ -45,6 +48,17 @@ def test_the_check_passes_the_kernel_and_fails_its_controls(
     assert record["kernel"]["out"] <= tool.OUT_TOLERANCE
     assert record["bf16_probabilities"]["out"] <= tool.OUT_TOLERANCE
     assert record["bf16_logits_and_statistics"]["out"] > tool.OUT_TOLERANCE
+
+
+def test_a_kernel_with_the_value_width_in_its_scale_fails_the_check(tool):
+    """The program computed wrongly at two widths (16^-1/2 where the keys
+    are 24 wide) reads above the tolerance."""
+    record = tool.check(
+        tool.Case("latent", 96, 4, 4, 24, None, 16), seed=1,
+        attend=lambda q, k, v, window: _interpreted(q, k, v, window,
+                                                    scale=16 ** -0.5))
+    assert not record["ok"]
+    assert max(record["kernel"].values()) > tool.TOLERANCE
 
 
 def test_a_wrong_kernel_fails_the_check(tool):
@@ -57,15 +71,30 @@ def test_a_wrong_kernel_fails_the_check(tool):
     assert max(record["kernel"].values()) > tool.TOLERANCE
 
 
-def test_the_cell_cases_are_the_configurations(tool):
-    import json
+def _config(name):
+    with open(os.path.join(REPO, "benchmark", "configs", name + ".json")) as f:
+        return json.load(f)
 
-    cfg = json.load(open(os.path.join(
-        REPO, "benchmark", "configs", "laguna-xs.2.json")))
+
+@pytest.mark.parametrize("name", ["joyai-llm-flash", "kimi-linear-48b-a3b"])
+def test_the_latent_case_is_the_configurations(tool, name):
+    cfg = _config(name)
+    case, = (c for c in tool.CELL_CASES if c.name == "latent")
+    assert case.seq == cfg["seq_len"]
+    assert case.heads == cfg["num_attention_heads"]
+    assert case.kv_heads == cfg["num_key_value_heads"]
+    assert case.head_dim == (cfg["qk_nope_head_dim"]
+                             + cfg["qk_rope_head_dim"])
+    assert case.value_dim == cfg["v_head_dim"]
+    assert case.window is None
+
+
+def test_the_cell_cases_are_the_configurations(tool):
+    cfg = _config("laguna-xs.2")
     n = cfg["num_hidden_layers"]
     kinds = dict(zip(cfg["layer_types"][:n],
                      cfg["num_attention_heads_per_layer"][:n]))
-    for case in tool.CELL_CASES:
+    for case in (c for c in tool.CELL_CASES if c.name != "latent"):
         windowed = case.window is not None
         assert case.seq == cfg["seq_len"]
         assert case.head_dim == cfg["head_dim"]

@@ -219,21 +219,31 @@ def test_the_kimi_linear_step_lowers_to_what_it_did():
 # --------------------------------------------------------------------------
 # the flash kernels 256 wide
 
-# (s_q, s_k, d, window) of every call the ten cells before this PR make, and
-# what ``_blocks`` returned for it at ``42d0a9d``
-BLOCKS_BEFORE = {
-    (1024, 1024, 64, None): (1024, 1024),     # GPT-2, both cells
-    (4096, 4096, 128, None): (1024, 1024),    # OLMoE, Ouro
-    (8192, 8192, 128, None): (1024, 1024),    # Laguna's global layers
-    (8192, 8192, 128, 512): (512, 512),       # Laguna's windowed layers
-    (8192, 8192, 192, None): (512, 512),      # Kimi-Linear's latent layer
-    (4096, 4096, 192, None): (512, 512),      # JoyAI's, twice a step
-}
+# (s_q, s_k, d, window) of every call the ten cells before PR 50 make and
+# what ``_blocks`` returns for it: the four rows at widths 64 and 128 pinned
+# at ``42d0a9d`` (the parent of PR 50, which added 256), the two at 192 at
+# PR 51's sweep (512 x 512 until then; ``_blocks``'s docstring)
+BLOCKS_BEFORE = (
+    ((1024, 1024, 64, None), (1024, 1024)),     # GPT-2, both cells
+    ((4096, 4096, 128, None), (1024, 1024)),    # OLMoE, Ouro
+    ((8192, 8192, 128, None), (1024, 1024)),    # Laguna's global layers
+    ((8192, 8192, 128, 512), (512, 512)),       # Laguna's windowed layers
+    ((8192, 8192, 192, None), (1024, 1024)),    # Kimi-Linear's latent layer
+    ((8192, 8192, 192, None), (1024, 1024)),    # JoyAI's six, 12 forwards
+)
 
 
-@pytest.mark.parametrize("call", sorted(BLOCKS_BEFORE, key=str))
-def test_blocks_at_the_three_old_widths_are_what_they_were(call):
-    assert _blocks(*call) == BLOCKS_BEFORE[call]
+@pytest.mark.parametrize("call, blocks", BLOCKS_BEFORE)
+def test_blocks_at_the_three_old_widths_are_what_they_were(call, blocks):
+    assert _blocks(*call) == blocks
+
+
+@pytest.mark.parametrize("s", (4096, 8192, 16384))
+def test_blocks_at_192(s):
+    """The sweep's choice at keys 192 / values 128 (``_blocks``'s docstring,
+    PERF.md section 6, PR 51): the cells' sequence and its two neighbours,
+    the lengths PR 39 and PR 51 measured."""
+    assert _blocks(s, s, 192) == (1024, 1024)
 
 
 @pytest.mark.parametrize("s", (8192, 16384))

@@ -48,7 +48,7 @@ def test_the_cell_cases_are_the_configuration_s():
         REPO, "benchmark", "configs", "qwen3-next-80b-a3b.json")))
     scan, attention = tool.cell_cases()
     assert scan == (cfg["seq_len"], 16, 32, 128, 128, cfg["gdn_chunk"])
-    assert attention[1:] == (cfg["seq_len"], 16, 2, 256, None)
+    assert attention[1:] == (cfg["seq_len"], 16, 2, 256, None, None)
 
 
 def test_the_attention_case_at_a_small_size():

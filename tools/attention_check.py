@@ -9,7 +9,9 @@ three losses to 2e-3, and at random weights a band one key off moves them by
 3e-4: PERF.md, PR 47) is held here, on the device, for the compiled Mosaic
 kernels the cell runs: the Laguna cell's two calls, b 1 x s 8,192 x 128 in
 bf16 over 8 key heads — 64 query heads under a window of 512, 48 under the
-causal triangle alone. The reference is the benchmark's own plain one
+causal triangle alone — and the latent call of the JoyAI and Kimi-Linear
+cells, 32 heads over 32 key heads at s 8,192 with keys 192 wide and values
+128 (PR 51). The reference is the benchmark's own plain one
 (``benchmark/lib/plain_laguna.py::banded_attention``: the band as a mask
 over all keys, the group as an axis, blocks of 256 queries) on the same
 bf16 operands in float32 at the highest matmul precision, nothing of
@@ -28,8 +30,9 @@ logits and statistics 0.40-0.44%; ``OUT_TOLERANCE`` lies between. The run
 fails — exit 1, ``"ok": false`` — if a kernel's tensor reads above its
 tolerance or a control below it. The controls are the reference computed
 wrongly, not the kernel: a window one key short and one key long (the
-windowed call), query head i reading key head ``i % key heads``, and bf16
-logits and softmax statistics (on ``out``).
+windowed call), query head i reading key head ``i % key heads``, logits
+scaled by the value width where it is not the key width (the latent call:
+128^-1/2 for 192^-1/2), and bf16 logits and softmax statistics (on ``out``).
 
 One JSON line a case, then ``{"ok": ..., "device": ...}``; off the chip the
 kernels are interpreted at a small size (``tests/test_attention_check.py``).
@@ -50,14 +53,17 @@ OUT_TOLERANCE = 3.0e-3      # out alone: float32 logits and statistics
 TENSORS = ("out", "dq", "dk", "dv")
 
 
-# window None: the causal triangle
-Case = collections.namedtuple("Case",
-                              "name seq heads kv_heads head_dim window")
+# window None: the causal triangle; value_dim None: values as wide as keys
+Case = collections.namedtuple(
+    "Case", "name seq heads kv_heads head_dim window value_dim",
+    defaults=(None,))
 
 
-# the Laguna cell's two calls (benchmark/configs/laguna-xs.2.json)
+# the Laguna cell's two calls (benchmark/configs/laguna-xs.2.json) and the
+# latent one of joyai-llm-flash.json and kimi-linear-48b-a3b.json
 CELL_CASES = (Case("windowed", 8192, 64, 8, 128, 512),
-              Case("global", 8192, 48, 8, 128, None))
+              Case("global", 8192, 48, 8, 128, None),
+              Case("latent", 8192, 32, 32, 192, None, 128))
 
 
 def _relative(got, want) -> float:
@@ -79,11 +85,12 @@ def check(case: Case, seed: int, attend=None) -> dict:
     from benchmark.lib.plain_laguna import banded_attention
 
     s, h, kv, d = case.seq, case.heads, case.kv_heads, case.head_dim
+    d_v = case.value_dim or d
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(keys[0], (1, s, h, d), jnp.bfloat16)
-    k, v = (jax.random.normal(key, (1, s, kv, d), jnp.bfloat16)
-            for key in keys[1:3])
-    w = jax.random.normal(keys[3], (1, s, h, d), jnp.float32)   # cotangent
+    k = jax.random.normal(keys[1], (1, s, kv, d), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (1, s, kv, d_v), jnp.bfloat16)
+    w = jax.random.normal(keys[3], (1, s, h, d_v), jnp.float32)  # cotangent
 
     if attend is None:
         from byteps_tpu.parallel import full_attention
@@ -92,7 +99,7 @@ def check(case: Case, seed: int, attend=None) -> dict:
             return full_attention(q, k, v, causal=True, window=window)
 
     def run(fn):
-        """(out, dq, dk, dv) of ``fn(q, k, v) -> [1, s, h, d]``."""
+        """(out, dq, dk, dv) of ``fn(q, k, v) -> [1, s, h, d_v]``."""
         def scalar(q, k, v, w):
             out = fn(q, k, v)
             return (out.astype(jnp.float32) * w).sum(), out
@@ -104,23 +111,28 @@ def check(case: Case, seed: int, attend=None) -> dict:
     groups = h // kv
 
     def plain(window=case.window, dtype=jnp.float32,
-              logits_dtype=jnp.float32, interleaved=False):
+              logits_dtype=jnp.float32, interleaved=False, scale_dim=d):
         """The plain reference as a function of the same operands; the
         keyword arguments are the ways to compute it wrongly. Its layout is
         [s, key heads, group, d]: query head i is member ``i % group`` of
         key head ``i // group``, or, ``interleaved``, head i reads key head
-        ``i % key heads`` — the other way to lay a group out."""
+        ``i % key heads`` — the other way to lay a group out. The
+        reference scales by the key width, ``scale_dim`` another width's;
+        its values are as wide as its keys, so narrower ones go in under
+        zeros and the output's first ``d_v`` columns come out."""
         def fn(q, k, v):
             grouped = (jnp.swapaxes(q[0].reshape(s, groups, kv, d), 1, 2)
                        if interleaved else q[0].reshape(s, kv, groups, d))
+            padded_v = jnp.pad(v[0], ((0, 0), (0, 0), (0, d - d_v)))
             with jax.default_matmul_precision("highest"):
                 out = banded_attention(
-                    grouped.astype(dtype), k[0].astype(dtype),
-                    v[0].astype(dtype), window=window, dtype=dtype,
+                    grouped.astype(dtype) * (d / scale_dim) ** 0.5,
+                    k[0].astype(dtype), padded_v.astype(dtype),
+                    window=window, dtype=dtype,
                     query_block=min(256, s), logits_dtype=logits_dtype)
             if interleaved:
                 out = jnp.swapaxes(out, 1, 2)
-            return out.reshape(1, s, h, d)
+            return out.reshape(1, s, h, d)[..., :d_v]
 
         return fn
 
@@ -139,6 +151,8 @@ def check(case: Case, seed: int, attend=None) -> dict:
         controls["window_plus_1"] = plain(window=case.window + 1)
     if kv > 1 and groups > 1:
         controls["heads_interleaved"] = plain(interleaved=True)
+    if d_v != d:
+        controls["scale_of_value_width"] = plain(scale_dim=d_v)
     def readings(fn):
         return dict(zip(TENSORS, map(_relative, run(fn), want)))
 
