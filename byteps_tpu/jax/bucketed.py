@@ -148,19 +148,26 @@ def make_bucketed_overlap_step(
         if not built:
             built.extend(build(params))
         bound, treedef, dtypes, programs = built
-        # Dispatch EVERY program now (async): the device pipelines them
-        # back-to-back while the host walks completed buckets.
-        outs = [program(params, batch) for _, program in programs]
-        for (idx, _), (_, grads_b) in zip(programs, outs):
-            # Blocks only until this program's outputs have landed — later
-            # programs keep computing while this bucket crosses D2H and
-            # the wire. An error here or in finish() has settled every
-            # handle in flight before it leaves the binding.
-            bound.push(idx, grads_b, average=average)
-        grads = [wire.decompress(g, d)
-                 for g, d in zip(bound.finish(), dtypes)]
-        params, opt_state = apply_jit(
-            params, opt_state, jax.tree_util.tree_unflatten(treedef, grads))
+        # Host spans for a jax.profiler capture (names and meaning: jax/ps.py's
+        # table; the binding writes bps.ps.d2h / .stage a bucket and
+        # bps.ps.wait / .h2d once, on the bridge thread, inside bps.step.ps).
+        with jax.profiler.TraceAnnotation(ps.SPAN_STEP_GRAD):
+            # Dispatch EVERY program now (async): the device pipelines them
+            # back-to-back while the host walks completed buckets.
+            outs = [program(params, batch) for _, program in programs]
+        with ps.step_ps_span():
+            for (idx, _), (_, grads_b) in zip(programs, outs):
+                # Blocks only until this program's outputs have landed —
+                # later programs keep computing while this bucket crosses
+                # D2H and the wire. An error here or in finish() has settled
+                # every handle in flight before it leaves the binding.
+                bound.push(idx, grads_b, average=average)
+            grads = [wire.decompress(g, d)
+                     for g, d in zip(bound.finish(), dtypes)]
+        with jax.profiler.TraceAnnotation(ps.SPAN_STEP_APPLY):
+            params, opt_state = apply_jit(
+                params, opt_state,
+                jax.tree_util.tree_unflatten(treedef, grads))
         return params, opt_state, outs[0][0]
 
     return step

@@ -120,39 +120,47 @@ class _TapState:
                    scales: Optional[np.ndarray] = None) -> None:
         # io_callback may hand a read-only view; the C core sums in place,
         # so stage through a writable copy that also serves as the pull
-        # destination.
+        # destination. The span is this design's bps.ps.stage (jax/ps.py's
+        # table): on a runtime callback thread, once a shard — mid-program
+        # by design; on the TPU runtime of PERF.md's PR 52 entry only after
+        # the program has ended. direct_bytes (of bytes, pushed from where
+        # they landed) is 0 while every shard is copied.
         j = int(j)
-        if scales is not None:
-            # int8 wire: dequantize blockwise on the host (cheap
-            # vectorised numpy), push f32.
-            arr = (np.asarray(g, np.float32).reshape(-1, self.blocks[idx])
-                   * np.asarray(scales, np.float32).reshape(-1, 1)
-                   ).reshape(-1)
-        else:
-            arr = np.array(g, dtype=self.dtypes[idx], copy=True).reshape(-1)
-        if self.bpps > 1:
-            # Gradient accumulation (reference: DistributedOptimizer
-            # backward_passes_per_step): sum K backward passes host-side,
-            # communicate once on the K-th. Division by K is the
-            # caller's, exactly as in the reference. Under the lock:
-            # unordered io_callbacks for the same key can run on
-            # different host threads (a straggler from microbatch m
-            # racing m+1), and an unguarded read-modify-write here would
-            # lose a gradient or an acc_count increment.
-            key = (idx, j)
+        with jax.profiler.TraceAnnotation(ps.SPAN_TAP_PUSH, leaf=idx,
+                                          shard=j) as span:
+            if scales is not None:
+                # int8 wire: dequantize blockwise on the host (cheap
+                # vectorised numpy), push f32.
+                arr = (np.asarray(g, np.float32).reshape(-1, self.blocks[idx])
+                       * np.asarray(scales, np.float32).reshape(-1, 1)
+                       ).reshape(-1)
+            else:
+                arr = np.array(g, dtype=self.dtypes[idx],
+                               copy=True).reshape(-1)
+            span.set_metadata(bytes=arr.nbytes, direct_bytes=0)
+            if self.bpps > 1:
+                # Gradient accumulation (reference: DistributedOptimizer
+                # backward_passes_per_step): sum K backward passes
+                # host-side, communicate once on the K-th. Division by K is
+                # the caller's, exactly as in the reference. Under the lock:
+                # unordered io_callbacks for the same key can run on
+                # different host threads (a straggler from microbatch m
+                # racing m+1), and an unguarded read-modify-write here would
+                # lose a gradient or an acc_count increment.
+                key = (idx, j)
+                with self.cv:
+                    acc = self.acc.get(key)
+                    self.acc[key] = arr if acc is None else acc + arr
+                    self.acc_count[key] = self.acc_count.get(key, 0) + 1
+                    if self.acc_count[key] < self.bpps:
+                        return
+                    arr = self.acc.pop(key)
+                    self.acc_count[key] = 0
+            h = self.client.push_pull(self.tids[(idx, j)], arr,
+                                      average=self.average)
             with self.cv:
-                acc = self.acc.get(key)
-                self.acc[key] = arr if acc is None else acc + arr
-                self.acc_count[key] = self.acc_count.get(key, 0) + 1
-                if self.acc_count[key] < self.bpps:
-                    return
-                arr = self.acc.pop(key)
-                self.acc_count[key] = 0
-        h = self.client.push_pull(self.tids[(idx, j)], arr,
-                                  average=self.average)
-        with self.cv:
-            self.inflight[(idx, j)] = (h, arr)
-            self.cv.notify_all()
+                self.inflight[(idx, j)] = (h, arr)
+                self.cv.notify_all()
 
     def reset_window(self) -> None:
         """Drop any partial accumulation/in-flight state. Called at the
@@ -191,15 +199,18 @@ class _TapState:
             import os
             timeout = float(os.environ.get("BYTEPS_TAP_TIMEOUT_S", "600"))
         out = []
-        for i, leaf in enumerate(leaves):
-            shards = []
-            for j in range(self.n_shards):
-                h, arr = self._pop((i, j), timeout)
-                self.client.wait(h)
-                shards.append(arr)
-            flat = shards[0] if self.n_shards == 1 else np.concatenate(shards)
-            out.append(flat[:int(np.size(leaf))].reshape(np.shape(leaf))
-                       .astype(leaf.dtype))
+        # bps.ps.wait, as the binding's: the settle loop up to the last settle
+        with jax.profiler.TraceAnnotation(ps.SPAN_WAIT):
+            for i, leaf in enumerate(leaves):
+                shards = []
+                for j in range(self.n_shards):
+                    h, arr = self._pop((i, j), timeout)
+                    self.client.wait(h)
+                    shards.append(arr)
+                flat = (shards[0] if self.n_shards == 1
+                        else np.concatenate(shards))
+                out.append(flat[:int(np.size(leaf))].reshape(np.shape(leaf))
+                           .astype(leaf.dtype))
         return out
 
 
@@ -353,28 +364,38 @@ def make_overlapped_train_step(
             state.declare_all(leaves)
             for i in range(len(leaves)):
                 taps[i] = _make_tap(state, i, axes, k)
-        if micro[0] % backward_passes_per_step == 0:
-            # window start: discard any state a crashed step left behind
-            state.reset_window()
+        # Host spans for a jax.profiler capture (names and meaning: jax/ps.py's
+        # table). The caller stays in bps.step.grad for the whole program and
+        # every callback — the pushes run under it, on the runtime's threads
+        # (bps.tap.push).
         try:
-            loss = grad_device(params, batch)
-            # Pushes already overlapped the backward pass; the effects
-            # barrier flushes any unordered callbacks the runtime hasn't
-            # yet run.
-            loss.block_until_ready()
-            jax.effects_barrier()
+            with jax.profiler.TraceAnnotation(ps.SPAN_STEP_GRAD):
+                if micro[0] % backward_passes_per_step == 0:
+                    # window start: discard any state a crashed step left
+                    state.reset_window()
+                loss = grad_device(params, batch)
+                # Pushes already overlapped the backward pass; the effects
+                # barrier flushes any unordered callbacks the runtime hasn't
+                # yet run.
+                loss.block_until_ready()
+                jax.effects_barrier()
             micro[0] += 1
             if micro[0] % backward_passes_per_step:
                 # accumulation pass: gradients summed host-side, nothing
                 # on the wire yet, parameters unchanged
                 return params, opt_state, loss
-            # ONE batched H2D for the whole collected tree: passing the
-            # numpy leaves straight to apply_jit would transfer each
-            # leaf individually at dispatch — the same per-leaf pattern
-            # the ps.py bridge batches away.
-            grads = jax.tree_util.tree_unflatten(
-                treedef, jax.device_put(state.collect(leaves)))
-            params, opt_state = apply_jit(params, opt_state, grads)
+            with ps.step_ps_span():
+                host = state.collect(leaves)
+                # ONE batched H2D for the whole collected tree: passing the
+                # numpy leaves straight to apply_jit would transfer each
+                # leaf individually at dispatch — the same per-leaf pattern
+                # the ps.py bridge batches away.
+                with jax.profiler.TraceAnnotation(
+                        ps.SPAN_H2D, bytes=sum(a.nbytes for a in host)):
+                    grads = jax.tree_util.tree_unflatten(
+                        treedef, jax.device_put(host))
+            with jax.profiler.TraceAnnotation(ps.SPAN_STEP_APPLY):
+                params, opt_state = apply_jit(params, opt_state, grads)
             return params, opt_state, loss
         except Exception:
             # A crash mid-window (some taps fired, counter not advanced)

@@ -164,7 +164,7 @@ def _make_ps_train_step(loss_fn, optimizer, mesh, axes, average, compression,
         with jax.profiler.TraceAnnotation(ps.SPAN_STEP_GRAD):
             loss, grads = grad_step(params, batch)
         dtypes = jax.tree_util.tree_map(lambda p: p.dtype, params)
-        with jax.profiler.TraceAnnotation(ps.SPAN_STEP_PS):
+        with ps.step_ps_span():
             grads = ps.ps_push_pull(grads, average=average, prefix=prefix)
             grads = jax.tree_util.tree_map(
                 lambda g, d: compression.decompress(g, d), grads, dtypes)

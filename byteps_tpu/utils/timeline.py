@@ -130,8 +130,8 @@ class Timeline:
                 self._cfg.trace_dir, f"device_rank{self._rank()}")
             jax.profiler.start_trace(self._device_dir)
             # Fallback anchor of the two clock domains, for a capture
-            # without a bps.ps.push_pull span (which carries the measured
-            # relation, see merge_core_device_traces): the C core stamps
+            # without a bps.step.ps or bps.ps.push_pull span (which carry the
+            # measured relation, see merge_core_device_traces): the C core stamps
             # spans with CLOCK_MONOTONIC microseconds ==
             # time.monotonic_ns()//1000 here, sampled once start_trace
             # has returned.
@@ -168,15 +168,17 @@ def find_device_chrome_trace(device_dir: str) -> Optional[str]:
 
 def capture_clock_offset_us(device_events) -> Optional[float]:
     """CLOCK_MONOTONIC µs minus the capture's own ``ts``, read off the
-    capture: ``jax/ps.py`` stamps every ``bps.ps.push_pull`` span with
-    ``mono_ns`` (``time.monotonic_ns()`` at the span's start — the C core's
-    ``NowUs()`` clock), and the profiler gives the same instant as the
-    event's ``ts``. None when the capture holds no such span (collective
-    mode, or a window without a PS step)."""
-    from byteps_tpu.jax.ps import SPAN_PUSH_PULL
+    capture: every PS step builder stamps its ``bps.step.ps`` span, and
+    ``jax/ps.py`` every ``bps.ps.push_pull``, with ``mono_ns``
+    (``time.monotonic_ns()`` at the span's start — the C core's ``NowUs()``
+    clock), and the profiler gives the same instant as the event's ``ts``.
+    The first of either is taken. None when the capture holds neither
+    (collective mode, or a window without a PS step)."""
+    from byteps_tpu.jax.ps import SPAN_PUSH_PULL, SPAN_STEP_PS
 
     for e in device_events:
-        if e.get("name") == SPAN_PUSH_PULL and "mono_ns" in e.get("args", {}):
+        if (e.get("name") in (SPAN_STEP_PS, SPAN_PUSH_PULL)
+                and "mono_ns" in e.get("args", {})):
             return int(e["args"]["mono_ns"]) / 1e3 - e["ts"]
     return None
 
@@ -188,8 +190,9 @@ def merge_core_device_traces(core_path: str, device_dir: str,
 
     The core stamps spans in CLOCK_MONOTONIC µs; the device trace uses its
     own µs timebase starting near ``start_trace``. The relation between
-    the two is measured, from a ``bps.ps.push_pull`` span of the capture
-    (``capture_clock_offset_us``). Only a capture without one falls back
+    the two is measured, from a ``bps.step.ps`` or ``bps.ps.push_pull`` span
+    of the capture (``capture_clock_offset_us``), so from any of the three
+    PS step designs. Only a capture without one falls back
     to the guess ``anchor_monotonic_us`` — the monotonic clock sampled
     after ``start_trace`` returned, which can lie tens of ms after the
     capture's ts 0. Returns the number of merged core events.

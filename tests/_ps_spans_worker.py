@@ -1,9 +1,12 @@
 """Worker of tests/test_ps_spans.py: two PS-mode training steps under a
 ``jax.profiler`` capture; prints the capture's ``bps.*`` events and the C
 core's round rows as one JSON line for the test to judge.
+``BPS_SPANS_BUILDER`` picks the step design (``serial``, the default:
+``make_train_step``; ``bucketed``; ``taps``).
 ``BPS_SPANS_WORKER_ROUNDSTATS`` sets ``BYTEPS_ROUNDSTATS_ON`` for this process
 alone, where the fleet's other roles got another value."""
 
+import functools
 import glob
 import json
 import os
@@ -19,7 +22,14 @@ import numpy as np  # noqa: E402
 import optax  # noqa: E402
 
 import byteps_tpu.jax as bps  # noqa: E402
+from byteps_tpu.jax.bucketed import make_bucketed_overlap_step  # noqa: E402
+from byteps_tpu.jax.overlap import make_overlapped_train_step  # noqa: E402
 from byteps_tpu.jax.training import make_train_step  # noqa: E402
+
+BUILDERS = {"serial": make_train_step,
+            "bucketed": functools.partial(make_bucketed_overlap_step,
+                                          n_buckets=2),
+            "taps": make_overlapped_train_step}
 
 
 def main() -> int:
@@ -31,12 +41,16 @@ def main() -> int:
     try:
         def loss_fn(params, batch):
             x, y = batch
-            return jnp.mean((x @ params["w"] + params["b"] - y) ** 2)
+            return jnp.mean(
+                ((x @ params["a"]) @ params["w"] + params["b"] - y) ** 2)
 
         tx = optax.sgd(0.05)
-        step = make_train_step(loss_fn, tx)
-        params = {"w": jnp.zeros((64, 8), jnp.float32),
-                  "b": jnp.zeros((8,), jnp.float32)}
+        step = BUILDERS[os.environ.get("BPS_SPANS_BUILDER", "serial")](
+            loss_fn, tx)
+        # three leaves, 4096 + 32 + 512 bytes: of two buckets [a] and [b, w]
+        params = {"a": jnp.full((64, 16), 0.01, jnp.float32),
+                  "b": jnp.zeros((8,), jnp.float32),
+                  "w": jnp.full((16, 8), 0.01, jnp.float32)}
         opt_state = tx.init(params)
         prng = np.random.default_rng(3)
 

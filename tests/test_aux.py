@@ -123,16 +123,19 @@ def test_timeline_window(tmp_path, monkeypatch):
     tl.close()           # idempotent
 
 
-def test_timeline_combined_device_plus_dcn(tmp_path):
+@pytest.mark.parametrize("span_name", ["bps.ps.push_pull", "bps.step.ps"])
+def test_timeline_combined_device_plus_dcn(tmp_path, span_name):
     """XPlane interop (SURVEY.md §5): the C core's DCN spans merge into
     the jax.profiler Chrome trace — device and host-comm stages on ONE
     timeline, core monotonic clock shifted onto the device timebase by the
-    relation the capture itself carries (a bps.ps.push_pull span's
-    mono_ns stat against its ts), not by the sampled anchor."""
+    relation the capture itself carries (the mono_ns stat of a
+    bps.ps.push_pull span, or of the bps.step.ps every PS step design
+    writes, against its ts), not by the sampled anchor."""
     import json
     import time
 
-    from byteps_tpu.jax.ps import SPAN_PUSH_PULL
+    from byteps_tpu.jax import ps
+    assert span_name in (ps.SPAN_PUSH_PULL, ps.SPAN_STEP_PS)
     from byteps_tpu.utils.timeline import (capture_clock_offset_us,
                                            find_device_chrome_trace,
                                            merge_core_device_traces)
@@ -142,7 +145,7 @@ def test_timeline_combined_device_plus_dcn(tmp_path):
     x = jax.jit(lambda a: a @ a)(jnp.ones((128, 128)))
     x.block_until_ready()
     span_mono_ns = time.monotonic_ns()
-    with jax.profiler.TraceAnnotation(SPAN_PUSH_PULL, mono_ns=span_mono_ns):
+    with jax.profiler.TraceAnnotation(span_name, mono_ns=span_mono_ns):
         time.sleep(0.002)
     jax.profiler.stop_trace()
     assert find_device_chrome_trace(dev_dir) is not None
@@ -179,7 +182,7 @@ def test_timeline_combined_device_plus_dcn(tmp_path):
     # and exactly where it happened: 500 us after the span's start (the
     # stat is read a few us before the profiler stamps the span)
     span = [e for e in merged["traceEvents"]
-            if e.get("name") == SPAN_PUSH_PULL][0]
+            if e.get("name") == span_name][0]
     assert abs(dcn["ts"] - (span["ts"] + 500)) < 200
     # a capture without the span has no relation to offer: the anchor's turn
     assert capture_clock_offset_us([{"name": "push", "ts": 1.0}]) is None

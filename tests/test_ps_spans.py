@@ -1,6 +1,7 @@
-"""The PS leg's host spans (``byteps_tpu/jax/ps.py::SPANS``): one table,
-mirrored by the docs and the benchmark's reader, and a real PS step under a
-``jax.profiler`` capture writes all eight where the table says."""
+"""The PS leg's host spans (``byteps_tpu/jax/ps.py``): one table, mirrored by
+the docs and the benchmark's readers, and a real PS step of each of the three
+designs under a ``jax.profiler`` capture writes its part of it where the table
+says."""
 
 import json
 import os
@@ -8,72 +9,82 @@ import re
 
 import pytest
 
-from benchmark.layers import roundbusy
+from benchmark.layers import psleg, roundbusy
+from benchmark.lib import trace_reduce
 from byteps_tpu.jax import ps
 from tests.ps_utils import REPO, run_topology
 
 WORKER = os.path.join(REPO, "tests", "_ps_spans_worker.py")
+# the worker's tree: a 64x16, b 8, w 16x8, float32
+LEAVES, BYTES = 3, 4 * (64 * 16 + 8 + 16 * 8)
+TRACED = 2
+# A CPU capture has no device plane and no line of programs: the reader's
+# intervals come from the host spans alone, which is all that is asked here.
+CPU = trace_reduce.Layout(device_plane=r"^/device:none$", op_lines=None,
+                          sync_line=None, module_line="none")
+# per design: virtual devices (overlap.py's own warning asks the taps for
+# two), spans a traced step, partitions a round
+DESIGNS = {
+    "serial": (1, dict.fromkeys(ps.SPANS, 1), LEAVES),
+    "bucketed": (1, {**dict.fromkeys(ps.SPANS[:3], 1), ps.SPAN_D2H: 2,
+                     ps.SPAN_STAGE: 2, ps.SPAN_WAIT: 1, ps.SPAN_H2D: 1},
+                 LEAVES),
+    "taps": (2, {**dict.fromkeys(ps.SPANS[:3], 1), ps.SPAN_WAIT: 1,
+                 ps.SPAN_H2D: 1, ps.SPAN_TAP_PUSH: 2 * LEAVES}, 2 * LEAVES),
+}
 
 
 def test_span_tables_agree():
-    """The program's table, docs/timeline.md's and the benchmark reader's
-    mirror name the same eight spans."""
+    """The program's table of eight and the benchmark's mirror of it name
+    the same spans; docs/timeline.md's table documents those and the taps'
+    one, in the program's order; the reader of all three designs names no
+    span the program does not."""
     from benchmark.layers import bridge
 
     assert len(ps.SPANS) == len(set(ps.SPANS)) == 8
     assert bridge.SPANS == ps.SPANS
+    assert ps.ALL_SPANS == ps.SPANS + (ps.SPAN_TAP_PUSH,)
+    assert set(psleg.SPANS) <= set(ps.ALL_SPANS)
     with open(os.path.join(REPO, "docs", "timeline.md")) as f:
         documented = re.findall(r"^\| `(bps\.[a-z0-9_.]+)` \|", f.read(), re.M)
-    assert tuple(documented) == ps.SPANS
+    assert tuple(documented) == ps.ALL_SPANS
 
 
-def _fleet(tmp_path, **env):
+def _fleet(tmp_path, builder="serial", **env):
+    devices = DESIGNS[builder][0]
     (out,) = run_topology(
         1, 1, WORKER, extra={
             "BYTEPS_PS_MODE": "ps", "BYTEPS_FORCE_DISTRIBUTED": "1",
             "BPS_SPANS_DIR": str(tmp_path / "trace"),
-            "XLA_FLAGS": "--xla_force_host_platform_device_count=1", **env})
+            "BPS_SPANS_BUILDER": builder,
+            "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
+            **env})
     return json.loads(out.strip().splitlines()[-1])
 
 
-def test_a_ps_step_writes_the_eight_spans(tmp_path):
-    """1 worker + 1 server on loopback, two traced steps: every span twice;
-    the three step spans on the caller's line; push_pull and its five
-    children together on another (the bridge thread), the children inside
-    it, in order, without overlap; the three stats on push_pull, with
-    ``mono_ns`` on the C core's clock, and ``stage_stats`` on stage. The two
-    rounds the core has closed by then carry their stages and resources as
-    elapsed time, and each lies inside its step's push_pull span."""
-    found = _fleet(tmp_path)
-    events = found["events"]
-    assert {e["plane"] for e in events} == {"/host:CPU"}
-    by_name = {name: [e for e in events if e["name"] == name]
-               for name in ps.SPANS}
-    assert {n: len(v) for n, v in by_name.items()} == dict.fromkeys(
-        ps.SPANS, 2)
+def _end(e):
+    return e["start_ns"] + e["dur_ns"]
 
-    caller = {e["line"] for n in ps.SPANS[:3] for e in by_name[n]}
-    bridge = {e["line"] for n in ps.SPANS[3:] for e in by_name[n]}
-    assert len(caller) == 1 and len(bridge) == 1 and caller != bridge
 
-    def end(e):
-        return e["start_ns"] + e["dur_ns"]
-
+def _serial_bridge_spans(by_name, mono):
+    """What only the serial step writes: push_pull and its four children
+    together on one line (the bridge thread), the children inside it, in
+    order, without overlap; the three stats on push_pull, with ``mono_ns``
+    on the C core's clock, and ``stage_stats`` on stage."""
     for k, whole in enumerate(by_name[ps.SPAN_PUSH_PULL]):
         outer = by_name[ps.SPAN_STEP_PS][k]
-        assert outer["start_ns"] <= whole["start_ns"] <= end(whole) <= end(
+        assert outer["start_ns"] <= whole["start_ns"] <= _end(whole) <= _end(
             outer)
         children = [by_name[n][k] for n in (
             ps.SPAN_D2H, ps.SPAN_STAGE, ps.SPAN_WAIT, ps.SPAN_H2D)]
         edges = [whole["start_ns"]]
         for child in children:
-            edges += [child["start_ns"], end(child)]
-        edges.append(end(whole))
+            edges += [child["start_ns"], _end(child)]
+        edges.append(_end(whole))
         assert edges == sorted(edges)
-        # 2 leaves: w 64x8 and b 8, float32
-        assert whole["stats"]["leaves"] == 2
-        assert whole["stats"]["bytes"] == 4 * (64 * 8 + 8)
-        lo, hi = found["mono_ns"]
+        assert whole["stats"]["leaves"] == LEAVES
+        assert whole["stats"]["bytes"] == BYTES
+        lo, hi = mono
         assert lo < whole["stats"]["mono_ns"] < hi
         # bps.ps.stage carries stage_stats: a step before the capture left
         # its slots, all of which a device with memory of its own reuses
@@ -88,13 +99,61 @@ def test_a_ps_step_writes_the_eight_spans(tmp_path):
     drift = ((second["stats"]["mono_ns"] - second["start_ns"])
              - (first["stats"]["mono_ns"] - first["start_ns"]))
     assert abs(drift) < 1_000_000
+    return first
+
+
+@pytest.mark.parametrize("builder", list(DESIGNS))
+def test_a_ps_step_writes_its_spans(tmp_path, builder):
+    """1 worker + 1 server on loopback, two traced steps of one design:
+    every span of the design as many times a step as the table says and no
+    other; the three step spans on the caller's line, the binding's on
+    another (the bridge thread), the taps' pushes on lines that are neither
+    (the runtime's threads); ``bps.ps.wait`` inside ``bps.step.ps``;
+    ``mono_ns`` on ``bps.step.ps``, on the C core's clock. The rounds the
+    core has closed by then carry their stages and resources as elapsed
+    time, and the first traced step's, put on the capture's clock through
+    that ``mono_ns`` alone, lies inside the step's leg — first enqueue to
+    the end of ``bps.ps.wait`` — to 200 us."""
+    _, per_step, parts = DESIGNS[builder]
+    found = _fleet(tmp_path, builder)
+    events = found["events"]
+    assert {e["plane"] for e in events} == {"/host:CPU"}
+    by_name = {name: [e for e in events if e["name"] == name]
+               for name in ps.ALL_SPANS}
+    assert {n: len(v) for n, v in by_name.items()} == {
+        n: TRACED * per_step.get(n, 0) for n in ps.ALL_SPANS}
+
+    caller = {e["line"] for n in ps.SPANS[:3] for e in by_name[n]}
+    assert len(caller) == 1
+    binding = {e["line"] for n in ps.SPANS[3:] for e in by_name[n]}
+    runtime = {e["line"] for e in by_name[ps.SPAN_TAP_PUSH]}
+    if builder == "taps":
+        # collect runs on the caller's thread, the pushes never do
+        assert binding == caller and runtime and not runtime & caller
+    else:
+        assert len(binding) == 1 and binding != caller and not runtime
+
+    lo, hi = found["mono_ns"]
+    for outer, wait in zip(by_name[ps.SPAN_STEP_PS], by_name[ps.SPAN_WAIT]):
+        assert outer["start_ns"] <= wait["start_ns"] <= _end(wait) <= _end(
+            outer)
+        assert lo < outer["stats"]["mono_ns"] < hi
+    for push in by_name[ps.SPAN_TAP_PUSH]:
+        assert push["stats"]["direct_bytes"] == 0 < push["stats"]["bytes"]
+        assert 0 <= push["stats"]["leaf"] < LEAVES
+        assert push["stats"]["shard"] in (0, 1)
+    if builder == "taps":
+        assert sum(e["stats"]["bytes"] for e in by_name[ps.SPAN_TAP_PUSH]) \
+            == TRACED * BYTES
+    first_push_pull = (_serial_bridge_spans(by_name, found["mono_ns"])
+                       if builder == "serial" else None)
 
     # three steps ran, so the core has closed the first two rounds: the
     # compiling step's and the first traced step's
     rounds = found["rounds"]
     assert [r["round"] for r in rounds] == [0, 1]
     for r in rounds:
-        assert r["parts"] == 2
+        assert r["parts"] == parts
         for union, total in roundbusy.STAGES.values():
             assert 0 <= r[union] <= r["elapsed_us"], union
             if total:
@@ -102,16 +161,39 @@ def test_a_ps_step_writes_the_eight_spans(tmp_path):
         assert 0 <= r["feed_wait_us"] <= r["elapsed_us"]
         assert r["server_span_us"] <= r["push_span_us"]
         assert r["server_us"] <= r["push_us"]
-        # what two partitions over loopback TCP cannot do in no time at all
+        # what a few partitions over loopback cannot do in no time at all
         for name in ("push_span_us", "pull_span_us", "server_us",
                      "push_thread_us", "send_blocked_us", "recv_thread_us"):
             assert r[name] > 0, name
-    # round 1 is the first traced step's: through mono_ns its ends lie
-    # inside that step's bps.ps.push_pull
-    (row,) = roundbusy.align(rounds[1:], [
-        (first["start_ns"], first["dur_ns"], first["stats"]["mono_ns"])])
+
+    # round 1 is the first traced step's. The benchmark's reader cuts the
+    # capture into steps and legs; through bps.step.ps's mono_ns the round
+    # lies inside its step's leg.
+    steps = psleg.split_steps(
+        [(e["plane"], str(e["line"]), e["name"], e["start_ns"], e["dur_ns"])
+         for e in events], CPU)
+    assert len(steps) == TRACED
+    for step in steps:
+        assert step["enqueues"] == per_step.get(
+            ps.SPAN_STAGE, 0) + per_step.get(ps.SPAN_TAP_PUSH, 0)
+        assert step["hidden"] == 0 and step["exposed"] == step["leg"] > 0
+    (row,) = psleg.align(rounds[1:], steps, [
+        (e["start_ns"], e["stats"]["mono_ns"])
+        for e in by_name[ps.SPAN_STEP_PS]])
     assert row["round"] == 1
-    assert row["start_margin_ms"] >= 0 and row["end_margin_ms"] >= 0
+    assert row["start_margin_ms"] >= -0.2 and row["end_margin_ms"] >= -0.2
+    if builder != "taps":
+        # the binding's steps enqueue inside bps.step.ps; the taps' rounds
+        # begin under bps.step.grad, by design
+        assert row["step_ps_start_margin_ms"] >= -0.2
+    assert row["step_ps_end_margin_ms"] >= -0.2
+    if first_push_pull:
+        # and, as before bps.step.ps carried the anchor, inside push_pull
+        (row,) = roundbusy.align(rounds[1:], [
+            (first_push_pull["start_ns"], first_push_pull["dur_ns"],
+             first_push_pull["stats"]["mono_ns"])])
+        assert row["round"] == 1
+        assert row["start_margin_ms"] >= 0 and row["end_margin_ms"] >= 0
 
 
 @pytest.mark.parametrize("worker_on, server_on", [("1", "0"), ("0", "1")])
