@@ -82,15 +82,37 @@ fewer heads than ``v``, a divisor: key head j serves value heads ``j
 groups .. (j + 1) groups - 1``, and the per-head form multiplies each key
 head's pairs once.
 
-The scan has two levels, groups of chunks and the chunks of a group, and
-its backward pass is ``jax.grad`` through both, a group recomputed at a
-time (``jax.checkpoint``): the scan keeps one state a group. The XLA form
-computes a group's operands inside the group (its pair tensor bounds the
-group); the kernel form computes every chunk's operands in one call
-before the scan, so its groups hold the recurrence alone and a group is
-about the square root of the chunks. A hand-written backward of the
-recurrence was measured against ``jax.grad`` and lost (PERF.md section 6,
-PR 39); of the operands, the kernel's is the hand-written one (PR 40).
+**The scan over chunks has two forms too, and the operands' form picks
+it.** Where XLA builds the operands (the XLA form and the per-head form)
+the scan has two levels, groups of chunks and the chunks of a group, and
+its backward pass is ``jax.grad`` through both, a group recomputed at a time
+(``jax.checkpoint``): the scan keeps one state a group, and a group's
+operands are computed inside the group (the XLA form's pair tensor bounds
+the group, the per-head form's is ``HEAD_GROUP`` chunks). ``_recurrence``
+there is an XLA ``while`` iteration of four small products a chunk, the
+float32 state (2 MB at 32 heads) through HBM around each. A hand-written
+backward of it *in XLA* was measured against ``jax.grad`` and lost (PERF.md
+section 6, PR 39) while the operands were most of the scan; since their
+kernel (PR 40) the recurrence, the slices and copies of the ``while`` over
+groups and the copy that laid the operands out chunk-major for it were the
+larger half of the kernel form's scan, bound by nothing the chip has.
+
+The kernel form computes every chunk's operands in one call before the
+scan and leaves them ``[b, n, C, h, d]``, and since PR 54 the kernel pair of
+``byteps_tpu.ops.kda_recurrence`` runs all chunks as one call over them as
+they lie, the state in VMEM from the first chunk to the last: no scan over
+groups, no layout copy (PERF.md section 6, PR 54). Its backward is
+hand-written (``custom_vjp``): the forward kernel leaves the state every 16
+chunks (what the scan over groups kept), and one backward kernel walks each
+such group forward again, leaving its states in VMEM, and back, carrying
+``dS`` from group to group. Same guarantees: the carried state float32 and
+never rounded, the state and ``U`` rounded to ``dtype`` as matmul operands
+only, float32 accumulation. It is the layout that decides: the per-head
+form's operands come out of XLA a few chunks at a time with heads before
+tokens, fused into ``_recurrence``'s products; laid out for a call of the
+pair they were measured at 4 to 32 chunks a call and did not beat it (the
+pair ahead for the recurrence alone, behind in the whole op by the layout
+copies), so that form keeps ``_recurrence``.
 """
 
 from __future__ import annotations
@@ -114,6 +136,9 @@ SCAN_SITES = "bps_kda_scan_sites_total"
 KERNEL_SITES = "bps_kda_kernel_sites_total"
 # ... or the per-head form
 HEAD_SITES = "bps_kda_head_sites_total"
+# ... and of all scan sites, those whose scan over chunks is the kernel pair
+# of ``byteps_tpu.ops.kda_recurrence`` (today the kernel form's)
+RECURRENCE_KERNEL_SITES = "bps_kda_recurrence_kernel_sites_total"
 
 # What the kernel of ``byteps_tpu.ops.kda_chunk`` was measured at against
 # the XLA form on a TPU v5e and won (PERF.md section 6, PR 40): keys and
@@ -127,6 +152,9 @@ KERNEL_WIDTH = 128
 # time. On a TPU v5e at [1, 8192, 32, 128] over 16 key heads, bf16, chunks of
 # 32, forward + backward (PERF.md section 6, my chip runs, PR 50): 4 chunks
 # 22.8 ms, 16 chunks 32.0, 32 chunks 31.9; at s 16384 4 chunks 44.6, 8 45.7.
+# A group's scan as a call of the recurrence kernels, its operands laid out
+# for it, at s 16384 (my chip runs, PR 54): 4 chunks 52.5 ms, 8 51.1, 16
+# 53.3, 32 59.2 against ``_recurrence`` at 4 chunks 50.9, which stays.
 HEAD_GROUP = 4
 
 # float32 operands as three bf16 passes: the triangular system's inverse and
@@ -256,12 +284,15 @@ def _head_operands(q, k, v, beta, G, dtype):
 def kda_form(backend: str, heads: int, d_k: int, d_v: int, dtype,
              chunk: int, per_head: bool = False) -> str:
     """``"head"``, ``"kernel"`` or ``"xla"``: how ``kda_attention`` computes
-    a chunk's operands at these shapes. ``per_head`` (the decay is one
-    number a head): the per-head form, wherever it runs. One decay a
-    channel: one algorithm, two forms: the XLA form writes
-    float32 ``[.., sub, sub, d_k]`` pairs and ``[.., n, C, d_k]`` decayed
-    keys to HBM and reads them back, the kernel (TPU only) holds a chunk in
-    VMEM."""
+    a chunk's operands at these shapes, and with them how it scans the
+    chunks. ``per_head`` (the decay is one number a head): the per-head
+    form, wherever it runs. One decay a channel: one algorithm, two forms:
+    the XLA form writes float32 ``[.., sub, sub, d_k]`` pairs and ``[.., n,
+    C, d_k]`` decayed keys to HBM and reads them back, the kernel (TPU only)
+    holds a chunk in VMEM and leaves every chunk's operands ``[b, n, C, h,
+    d]``, where the recurrence kernels scan them as they lie. ``"head"``
+    and ``"xla"`` scan with ``_recurrence`` under a scan over groups
+    (module docstring)."""
     if per_head:
         return "head"
     if backend != "tpu" or jnp.dtype(dtype) != jnp.bfloat16:
@@ -331,8 +362,10 @@ def kda_attention(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
         # other model, any CPU run) pays for no kernel library
         # (tests/test_import_footprint.py)
         from byteps_tpu.ops.kda_chunk import chunk_operands
+        from byteps_tpu.ops.kda_recurrence import recurrence
 
         metrics.inc_counter(KERNEL_SITES)
+        metrics.inc_counter(RECURRENCE_KERNEL_SITES)
     if form == "head":
         metrics.inc_counter(HEAD_SITES)
     elif q.shape[2] != h:
@@ -347,53 +380,45 @@ def kda_attention(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
             G = chunk_log_decay(g, chunk)               # [b, n, C, h, d_k]
     with jax.named_scope(scan_scope):
         b, n, _, _, d_k = tokens[0].shape
-        # Two levels: groups of chunks, one at a time and recomputed in the
-        # backward pass, and the chunks of a group: the scan keeps one state
-        # a group and a group's states while it is differentiated.
+        state = (b, h, d_k, v.shape[-1])            # float32, S_0 = 0
         if kernel:
             # Every chunk's operands in one call (the kernel cumulates a
-            # chunk's decay itself), then the chunk leading and heads before
-            # tokens for the scan. The groups hold the recurrence alone: a
-            # group is about the square root of the chunks.
-            group = _divisor(n, math.isqrt(n))
-            w, u_v, q_g, k_d, gamma, a_q = chunk_operands(
-                *tokens, chunked(g.astype(f32), chunk), sub, dtype)
-            xs = tuple(
-                x.reshape(n // group, group, *x.shape[1:]) for x in (
-                    *(x.transpose(1, 0, 3, 2, 4) for x in (
-                        w, u_v, q_g, k_d)), jnp.moveaxis(gamma, 1, 0),
-                    a_q.astype(dtype).transpose(1, 0, 3, 2, 4)))
+            # chunk's decay itself): [b, n, C, h, d], a token a [h, d] tile.
+            # The recurrence kernels read them where they lie, all chunks in
+            # one call, and keep a state every 16 chunks for their backward
+            # pass themselves: nothing is sliced, laid out anew or copied on
+            # its way to a group (PERF.md section 6, PR 54). They round the
+            # pairs [.., C, h, C] to ``dtype`` ahead of the call: a row of
+            # 32 fills a quarter of its lanes, so in float32 they are 134 MB
+            # a layer at 256 chunks, and their gradient as much.
+            o = recurrence(jnp.zeros(state, f32), *chunk_operands(
+                *tokens, chunked(g.astype(f32), chunk), sub, dtype), dtype)[1]
+            return o.reshape(b, n * chunk, h, -1)[:, :s]
+        # Two levels: groups of chunks, one at a time and recomputed in the
+        # backward pass, and the chunks of a group: the scan keeps one state
+        # a group and a group's states while it is differentiated. A group's
+        # operands are alive for that group alone. The pairs of a sub-chunk
+        # are a [.., sub, sub, d_k] tensor, which the backward pass writes
+        # out: a group is as many chunks as keep that tensor under 2^26
+        # entries (256 MB). One decay a head has no such tensor (a chunk's
+        # largest are [C, C] a head): its group is ``HEAD_GROUP`` chunks.
+        group = _divisor(n, HEAD_GROUP if per_head else
+                         2 ** 26 // (b * h * chunk * sub * d_k))
+        operands = _head_operands if per_head else partial(
+            _chunk_operands, sub=sub)
 
-            def operands_of(xs):
-                return xs
-        else:
-            # A group's operands are alive for that group alone. The pairs
-            # of a sub-chunk are a [.., sub, sub, d_k] tensor, which the
-            # backward pass writes out: a group is as many chunks as keep
-            # that tensor under 2^26 entries (256 MB). One decay a head has
-            # no such tensor (a chunk's largest are [C, C] a head): its
-            # group is ``HEAD_GROUP`` chunks.
-            group = _divisor(n, HEAD_GROUP if per_head else
-                             2 ** 26 // (b * h * chunk * sub * d_k))
-            operands = _head_operands if per_head else partial(
-                _chunk_operands, sub=sub)
-
-            def grouped(x):          # [b, n, C, h, ...] -> [n / group, b,
-                x = x.reshape(b, n // group, group, *x.shape[2:])  # group,
-                return jnp.moveaxis(x, 1, 0).swapaxes(3, 4)   # h, C, ...]
-
-            xs = tuple(grouped(x) for x in (*tokens, G))
-
-            def operands_of(xs):
-                return (jnp.moveaxis(x, 1, 0)
-                        for x in operands(*xs, dtype=dtype))
+        def grouped(x):              # [b, n, C, h, ...] -> [n / group, b,
+            x = x.reshape(b, n // group, group, *x.shape[2:])      # group,
+            return jnp.moveaxis(x, 1, 0).swapaxes(3, 4)       # h, C, ...]
 
         @jax.checkpoint
         def one_group(state, xs):
-            return _recurrence(state, *operands_of(xs), dtype)
+            return _recurrence(state, *(
+                jnp.moveaxis(x, 1, 0) for x in operands(*xs, dtype=dtype)),
+                dtype)
 
-        state = jnp.zeros((b, h, d_k, v.shape[-1]), f32)
-        o = lax.scan(one_group, state, xs)[1]
+        xs = tuple(grouped(x) for x in (*tokens, G))
+        o = lax.scan(one_group, jnp.zeros(state, f32), xs)[1]
         # [n / group, group, b, h, C, d_v] -> [b, s, h, d_v]
         return o.transpose(2, 0, 1, 4, 3, 5).reshape(
             b, n * chunk, h, -1)[:, :s]

@@ -156,6 +156,40 @@ def test_kda_operand_kernels_compile_for_v5e(topo, with_grads):
 
 
 @pytest.mark.parametrize("with_grads", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("chunks", [256, 512])
+def test_kda_recurrence_kernels_compile_for_v5e(topo, chunks, with_grads):
+    """The scan over every chunk of a Kimi-Linear layer in one call: 32
+    heads of 128 x 128, chunks of 32, bf16 operands as the operand kernels
+    leave them, a token a [heads, d] tile, at the cell's s 8,192 (256
+    chunks) and at the s 16,384 its configuration was first measured at —
+    the forward kernel, and through ``jax.grad`` the forward kernel that
+    saves a state every 16 chunks and the backward kernel."""
+    from byteps_tpu.ops.kda_recurrence import BWD_NAME, FWD_NAME, recurrence
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    keys = described(1, chunks, 32, 32, 128)
+    values = described(1, chunks, 32, 32, 128, dtype=jnp.float32)
+    shapes = (described(1, 32, 128, 128, dtype=jnp.float32), keys, values,
+              keys, keys, described(1, chunks, 32, 128, dtype=jnp.float32),
+              described(1, chunks, 32, 32, 32))
+
+    def fwd(*args):
+        return recurrence(*args, jnp.bfloat16, False)
+
+    def loss(*args):
+        return sum(o.sum() for o in fwd(*args))
+
+    fn = jax.grad(loss, argnums=tuple(range(7))) if with_grads else fwd
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert text.count("tpu_custom_call") == (2 if with_grads else 1)
+    assert FWD_NAME in text and (BWD_NAME in text) == with_grads
+
+
+@pytest.mark.parametrize("with_grads", [False, True], ids=["fwd", "fwd_bwd"])
 def test_sparse_flash_kernels_compile_for_v5e(topo, with_grads):
     """A block of the Keye cell's sparse attention: 512 queries, 32 heads
     over 4 key-value heads of 128, all 8192 keys handed in and the mask of

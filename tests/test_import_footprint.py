@@ -101,3 +101,23 @@ def test_module_level_imports_are_the_ones_every_cell_already_paid(path):
         elif isinstance(node, ast.ImportFrom):
             top.append(node.module)
     assert top and set(top) <= ALLOWED, sorted(set(top) - ALLOWED)
+
+
+@pytest.mark.parametrize("name", sorted(
+    n for n in os.listdir(os.path.join(REPO, "byteps_tpu", "ops"))
+    if n.endswith(".py")))
+def test_a_kernel_module_imports_nothing_of_the_layer_that_calls_it(name):
+    """``byteps_tpu/ops`` is the bottom layer: ``parallel/`` and ``models/``
+    import a kernel inside the function that calls it, and no kernel file
+    imports them back, at module level or inside a function (a cycle, and
+    the whole ``parallel`` package for whoever imports the kernel alone)."""
+    with open(os.path.join(REPO, "byteps_tpu", "ops", name)) as f:
+        tree = ast.parse(f.read())
+    imported = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported += [alias.name for alias in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            imported.append(node.module or "")
+    above = ("byteps_tpu.parallel", "byteps_tpu.models", "byteps_tpu.jax")
+    assert not [m for m in imported if m.startswith(above)]

@@ -196,9 +196,11 @@ def test_the_form_is_a_pure_function_of_backend_and_shapes(args, form):
 def test_the_kimi_linear_step_lowers_to_what_it_did():
     """PR 50 gave the scan a second rank of decay, key heads under value
     heads and a third form. With one decay a channel, the gradient of
-    KimiLinearTiny's loss lowers to the text it lowered to at ``42d0a9d``,
-    in the XLA form and in the kernel form (interpreted here: the same
-    trace the chip's compile starts from)."""
+    KimiLinearTiny's loss lowers to the text it lowered to at ``42d0a9d``
+    in the XLA form; the kernel form (interpreted here: the same trace the
+    chip's compile starts from) lowers to PR 54's text, which gave its scan
+    over chunks to the recurrence kernels and changed nothing ahead of
+    them."""
     model, tokens = KimiLinearTiny(), np.zeros((2, 32), np.int32)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
 
@@ -213,7 +215,7 @@ def test_the_kimi_linear_step_lowers_to_what_it_did():
     with pytest.MonkeyPatch.context() as m:
         m.setattr(la, "kda_form", lambda *shapes: "kernel")
         assert digest() == (
-            "c5b734563f51a88c48e768303542c03e77a19968c54e4a951dc6216b13132bf9")
+            "52e66df7ef2c64873f0e3d0e974da1e31d00209a34286ad03a65398dfe2822f4")
 
 
 # --------------------------------------------------------------------------

@@ -1,7 +1,9 @@
 """``tools/scan_check.py`` at a small size on the CPU: the check the chip
 runs at the Qwen3-Next cell's shapes passes for the sound scan and fails for
-a bf16 state, a clamped decay and the other head grouping; the 256-wide
-attention case is ``tools/attention_check.py``'s own check."""
+a bf16 state, a clamped decay and the other head grouping, and so does its
+per-channel twin at the Kimi-Linear cell's shapes (a bf16 state, a clamped
+decay); the 256-wide attention case is ``tools/attention_check.py``'s own
+check."""
 
 import importlib
 import json
@@ -14,6 +16,7 @@ from tools import scan_check as tool
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMALL = tool.ScanCase(1024, 2, 4, 64, 64, 64)
+SMALL_CHANNEL = tool.ChannelCase(512, 4, 32, 32, 32, 8)
 
 
 @pytest.mark.parametrize("seed", (0, 2147483907))
@@ -24,6 +27,18 @@ def test_the_check_passes_the_scan_and_fails_its_controls(seed):
     assert max(record["scan"].values()) <= tool.SCAN_TOLERANCE
     assert set(record["controls"]) == {"bf16_state", "heads_interleaved",
                                        "clamped_at_-20"}
+    for control in record["controls"].values():
+        assert max(control.values()) > tool.SCAN_TOLERANCE
+    assert record["min_chunk_log_decay"] < tool.CLAMP
+
+
+@pytest.mark.parametrize("seed", (0, 2147483907))
+def test_the_per_channel_check_passes_the_scan_and_fails_its_controls(seed):
+    record = tool.check_scan(SMALL_CHANNEL, seed)
+    assert record["ok"], record
+    assert set(record["scan"]) == set(tool.TENSORS)
+    assert max(record["scan"].values()) <= tool.SCAN_TOLERANCE
+    assert set(record["controls"]) == {"bf16_state", "clamped_at_-20"}
     for control in record["controls"].values():
         assert max(control.values()) > tool.SCAN_TOLERANCE
     assert record["min_chunk_log_decay"] < tool.CLAMP
@@ -49,6 +64,16 @@ def test_the_cell_cases_are_the_configuration_s():
     scan, attention = tool.cell_cases()
     assert scan == (cfg["seq_len"], 16, 32, 128, 128, cfg["gdn_chunk"])
     assert attention[1:] == (cfg["seq_len"], 16, 2, 256, None, None)
+
+
+def test_the_channel_case_is_the_configuration_s():
+    cfg = json.load(open(os.path.join(
+        REPO, "benchmark", "configs", "kimi-linear-48b-a3b.json")))
+    assert tool.channel_case() == (cfg["seq_len"], 32, 128, 128, 32, 8)
+    q, k, v, g, beta, w = tool.scan_inputs(SMALL_CHANNEL, 0)
+    assert q.shape == k.shape == g.shape == (1, 512, 4, 32)
+    assert v.shape == w.shape == (1, 512, 4, 32) and beta.shape == (1, 512, 4)
+    assert float(g.max()) < 0.0
 
 
 def test_the_attention_case_at_a_small_size():

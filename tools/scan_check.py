@@ -1,6 +1,7 @@
-"""On the chip: the per-head delta-rule scan and the 256-wide attention
-kernels at the Qwen3-Next cell's shapes against the plain reference —
-values and every gradient, tensor by tensor — and the wrong computations the
+"""On the chip: the delta-rule scan of both hybrid cells (one decay a head
+at the Qwen3-Next cell's shapes, one a channel at the Kimi-Linear cell's)
+and the 256-wide attention kernels against the plain references — values
+and every gradient, tensor by tensor — and the wrong computations the
 tolerance must fail.
 
     chiprun --chips 1 -- python3 tools/scan_check.py [--seed N]
@@ -27,6 +28,16 @@ that the recurrence computes exactly what such a chunked form would), and
 value head i reading key head ``i % 16`` and not ``i // 2``. The run fails
 — exit 1, ``"ok": false`` — if a tensor of the program reads above
 ``SCAN_TOLERANCE`` or a control's worst tensor below it.
+
+**The scan, one decay a channel** (since PR 54, whose recurrence kernels
+this form's scan runs on the chip): ``kda_attention`` at b 1 x s of the Kimi-Linear
+cell, 32 heads of 128 x 128, the file's chunks of 32 in sub-chunks of 8,
+against ``benchmark/lib/plain_kimi_linear.py::delta_rule``; the same inputs
+with a standard normal a channel under the decay's softplus, the same
+measure and tolerance, and the controls that exist there: the bf16 state
+(that reference rounds by a cast there and back, which the TPU compiler
+may drop as excess precision: the control is compiled with
+``xla_allow_excess_precision`` off, it alone) and the clamped decay.
 
 **The kernels**: ``tools/attention_check.py``'s check, called, at 16 query
 heads over 2 key heads of 256 under the causal triangle: out, dQ, dK, dV
@@ -57,6 +68,9 @@ TENSORS = ("o", "dq", "dk", "dv", "dg", "dbeta")
 
 ScanCase = collections.namedtuple(
     "ScanCase", "seq key_heads heads key_dim value_dim chunk")
+# one decay a channel: as many key heads as value heads, and sub-chunks
+ChannelCase = collections.namedtuple(
+    "ChannelCase", "seq heads key_dim value_dim chunk sub")
 
 
 def cell_cases():
@@ -75,33 +89,50 @@ def cell_cases():
                                  cfg["head_dim"], None))
 
 
+def channel_case() -> ChannelCase:
+    """The Kimi-Linear cell's scan from its configuration's file."""
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        cfg = json.load(f)
+    linear = cfg["linear_attn_config"]
+    return ChannelCase(cfg["seq_len"], linear["num_heads"],
+                       linear["head_dim"], linear["head_dim"],
+                       cfg["kda_chunk"], cfg["kda_sub_chunk"])
+
+
 def clamped_in_chunks(g, chunk: int, floor: float):
-    """The per-token log-decays [b, s, h] whose cumulated sum inside every
-    chunk of ``chunk`` tokens is ``max(G, floor)``: what a chunked form that
-    clamps its cumulated log-decay at ``floor`` really computes."""
+    """The per-token log-decays [b, s, h] (or [b, s, h, d_k]) whose
+    cumulated sum inside every chunk of ``chunk`` tokens is ``max(G,
+    floor)``: what a chunked form that clamps its cumulated log-decay at
+    ``floor`` really computes."""
     import jax.numpy as jnp
 
     G = jnp.maximum(cumulated_in_chunks(g, chunk), floor)
     steps = jnp.diff(G, axis=2, prepend=jnp.zeros_like(G[:, :, :1]))
-    return steps.reshape(g.shape[0], -1, g.shape[2])[:, :g.shape[1]]
+    return steps.reshape(g.shape[0], -1, *g.shape[2:])[:, :g.shape[1]]
 
 
 def cumulated_in_chunks(g, chunk: int):
-    """[b, n, chunk, h]: ``g`` [b, s, h] cumulated inside each chunk of
-    ``chunk`` tokens, zeros after s."""
+    """[b, n, chunk, h, ...]: ``g`` [b, s, h, ...] cumulated inside each
+    chunk of ``chunk`` tokens, zeros after s."""
     import jax.numpy as jnp
 
-    b, s, h = g.shape
-    return jnp.cumsum(jnp.pad(g, ((0, 0), (0, (-s) % chunk), (0, 0))).reshape(
-        b, -1, chunk, h), axis=2)
+    b, s = g.shape[:2]
+    pad = ((0, 0), (0, (-s) % chunk)) + ((0, 0),) * (g.ndim - 2)
+    return jnp.cumsum(jnp.pad(g, pad).reshape(b, -1, chunk, *g.shape[2:]),
+                      axis=2)
 
 
-def scan_inputs(case: ScanCase, seed: int):
-    """(q, k, v, g, beta, cotangent), float32, b 1."""
+def scan_inputs(case, seed: int):
+    """(q, k, v, g, beta, cotangent), float32, b 1; g [1, s, h] for a
+    ``ScanCase``, [1, s, h, d_k] for a ``ChannelCase``."""
     import jax
     import jax.numpy as jnp
     keys = jax.random.split(jax.random.PRNGKey(seed), 6)
     s, h = case.seq, case.heads
+    per_head = isinstance(case, ScanCase)
+    key_heads = case.key_heads if per_head else h
+    decays = (1, s, h) if per_head else (1, s, h, case.key_dim)
 
     def unit(x):
         return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
@@ -111,43 +142,50 @@ def scan_inputs(case: ScanCase, seed: int):
     spread = jnp.arange(h, dtype=jnp.float32) / max(h - 1, 1)
     rate, dt = 16.0 ** spread, 1e-3 * 100.0 ** spread
     dt_bias = dt + jnp.log(-jnp.expm1(-dt))
-    return (unit(jax.random.normal(keys[0], (1, s, case.key_heads,
-                                             case.key_dim)))
+    if not per_head:            # a head's rate and bias for all its channels
+        rate, dt_bias = rate[:, None], dt_bias[:, None]
+    return (unit(jax.random.normal(keys[0], (1, s, key_heads, case.key_dim)))
             * case.key_dim ** -0.5,
-            unit(jax.random.normal(keys[1], (1, s, case.key_heads,
-                                             case.key_dim))),
+            unit(jax.random.normal(keys[1], (1, s, key_heads, case.key_dim))),
             jax.random.normal(keys[2], (1, s, h, case.value_dim)),
-            -rate * jax.nn.softplus(jax.random.normal(keys[3], (1, s, h))
+            -rate * jax.nn.softplus(jax.random.normal(keys[3], decays)
                                     + dt_bias),
             jax.nn.sigmoid(jax.random.normal(keys[4], (1, s, h))),
             jax.random.normal(keys[5], (1, s, h, case.value_dim)))
 
 
-def check_scan(case: ScanCase, seed: int, scan=None) -> dict:
+def check_scan(case, seed: int, scan=None) -> dict:
     """The program's scan (``scan(q, k, v, g, beta)``, by default
-    ``kda_attention`` in bf16 at the case's chunk) against the recurrence,
-    and the controls. ``scan`` is a test's handle on a wrong program."""
+    ``kda_attention`` in bf16 at the case's chunk) against the recurrence
+    of the case's kind, and the controls. ``scan`` is a test's handle on a
+    wrong program."""
     import jax
     import jax.numpy as jnp
 
-    from benchmark.lib.plain_qwen3_next import gated_delta_rule
+    per_head = isinstance(case, ScanCase)
+    if per_head:
+        from benchmark.lib.plain_qwen3_next import gated_delta_rule as rule
+    else:
+        from benchmark.lib.plain_kimi_linear import delta_rule as rule
 
     *operands, w = scan_inputs(case, seed)
     if scan is None:
         from byteps_tpu.parallel.linear_attention import kda_attention
 
         def scan(q, k, v, g, beta):
-            return kda_attention(q, k, v, g, beta, chunk=case.chunk,
-                                 sub=case.chunk, dtype=jnp.bfloat16)
+            return kda_attention(
+                q, k, v, g, beta, chunk=case.chunk,
+                sub=case.chunk if per_head else case.sub, dtype=jnp.bfloat16)
 
-    def run(fn):
+    def run(fn, **compiler_options):
         """(o, dq, dk, dv, dg, dbeta) of ``fn(q, k, v, g, beta)``."""
         def scalar(*a):
             out = fn(*a)
             return (out * w).sum(), out
 
         (_, out), grads = jax.jit(jax.value_and_grad(
-            scalar, argnums=(0, 1, 2, 3, 4), has_aux=True))(*operands)
+            scalar, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(
+                *operands).compile(compiler_options or None)(*operands)
         return jax.device_get((out, *grads))
 
     block = min(128, case.seq)
@@ -155,8 +193,8 @@ def check_scan(case: ScanCase, seed: int, scan=None) -> dict:
     def plain(**wrong):
         def fn(q, k, v, g, beta):
             with jax.default_matmul_precision("highest"):
-                return gated_delta_rule(q[0], k[0], v[0], g[0], beta[0],
-                                        scan_block=block, **wrong)[None]
+                return rule(q[0], k[0], v[0], g[0], beta[0],
+                            scan_block=block, **wrong)[None]
 
         return fn
 
@@ -164,21 +202,22 @@ def check_scan(case: ScanCase, seed: int, scan=None) -> dict:
         return dict(zip(TENSORS, map(attention_check._relative, got, want)))
 
     want = run(plain())
-    controls = {
-        "bf16_state": plain(state_dtype=jnp.bfloat16),
-        "heads_interleaved": plain(key_head_of=[
-            i % case.key_heads for i in range(case.heads)]),
-    }
     sound = plain()
-    controls["clamped_at_-20"] = lambda q, k, v, g, beta: sound(
-        q, k, v, clamped_in_chunks(g, case.chunk, CLAMP), beta)
+    controls = {
+        # the per-channel reference rounds by a cast there and back
+        "bf16_state": readings(run(
+            plain(state_dtype=jnp.bfloat16),
+            **({} if per_head else {"xla_allow_excess_precision": False}))),
+        "clamped_at_-20": readings(run(lambda q, k, v, g, beta: sound(
+            q, k, v, clamped_in_chunks(g, case.chunk, CLAMP), beta)))}
+    if per_head:
+        controls["heads_interleaved"] = readings(run(plain(key_head_of=[
+            i % case.key_heads for i in range(case.heads)])))
     record = {
         "case": case._asdict(), "seed": seed,
         "min_chunk_log_decay": float(
             cumulated_in_chunks(operands[3], case.chunk).min()),
-        "scan": readings(run(scan)),
-        "controls": {name: readings(run(fn))
-                     for name, fn in controls.items()},
+        "scan": readings(run(scan)), "controls": controls,
         "tolerance": SCAN_TOLERANCE}
     record["ok"] = bool(
         max(record["scan"].values()) <= SCAN_TOLERANCE
@@ -198,9 +237,10 @@ def main() -> int:
     ok = device.platform == "tpu"        # never a CPU's figures by mistake
     if ok:
         scan_case, attention_case = cell_cases()
-        record = check_scan(scan_case, args.seed)
-        ok = ok and record["ok"]
-        print(json.dumps(record), flush=True)
+        for case in (scan_case, channel_case()):
+            record = check_scan(case, args.seed)
+            ok = ok and record["ok"]
+            print(json.dumps(record), flush=True)
         record = attention_check.check(attention_case, args.seed)
         ok = ok and record["ok"] and record["kernel_in_program"]
         print(json.dumps(record), flush=True)
