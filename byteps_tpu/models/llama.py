@@ -32,18 +32,24 @@ from byteps_tpu.models.transformer import _attention_fn, _default_positions
 class RMSNorm(nn.Module):
     """``x rsqrt(mean x^2 + eps) scale``, float32. ``zero_centred``: the
     learned vector starts at 0 and the row is multiplied by ``1 + scale``
-    (Qwen3-Next's; weight decay then pulls the scale to 1, not 0)."""
+    (Qwen3-Next's; weight decay then pulls the scale to 1, not 0).
+    ``initial``: another starting value (``models/zaya.py``'s final norm,
+    whose rows meet a tied unit-variance embedding and start at ln(vocab) / d)."""
 
     eps: float = 1e-6
     zero_centred: bool = False
+    # where the learned vector starts, if not at 0 (zero_centred) or 1
+    initial: Optional[float] = None
 
     @nn.compact
     def __call__(self, x):
         orig_dtype = x.dtype
         xf = x.astype(jnp.float32)
         scale = self.param(
-            "scale", nn.initializers.zeros if self.zero_centred
-            else nn.initializers.ones, (x.shape[-1],), jnp.float32)
+            "scale", nn.initializers.constant(self.initial)
+            if self.initial is not None else nn.initializers.zeros
+            if self.zero_centred else nn.initializers.ones,
+            (x.shape[-1],), jnp.float32)
         y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1,
                                         keepdims=True) + self.eps)
         return (y * (1.0 + scale if self.zero_centred else scale)).astype(

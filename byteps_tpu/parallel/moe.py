@@ -366,7 +366,7 @@ _held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
 
 def dropless_moe_ffn(
     x: jax.Array,
-    router_w: jax.Array,
+    router_w: Optional[jax.Array],
     w_gate: jax.Array,
     w_up: jax.Array,
     w_down: jax.Array,
@@ -379,6 +379,7 @@ def dropless_moe_ffn(
     select_bias: Optional[jax.Array] = None,
     norm_eps: float = 0.0,
     routed_scale: float = 1.0,
+    logits: Optional[jax.Array] = None,
 ):
     """Dropless top-k SwiGLU expert layer: every token reaches its
     ``top_k`` experts.
@@ -389,7 +390,10 @@ def dropless_moe_ffn(
     at the highest matmul precision (which experts a token reaches must not
     turn on bf16 rounding): softmax over all E, ``lax.top_k``, and as
     combine weights the raw probabilities, or with ``norm_topk`` those
-    renormalised over the chosen k. ``scoring="sigmoid"`` is DeepSeek-V3's
+    renormalised over the chosen k. A caller whose router is more than one
+    matrix (``models/zaya.py``'s reads a state handed from layer to layer)
+    passes its own float32 ``logits`` [T, E] and None for ``router_w``:
+    everything from the scores on is the same. ``scoring="sigmoid"`` is DeepSeek-V3's
     gate: the scores are ``sigmoid(logits)``, one expert's independent of
     the others'; ``select_bias`` [E] is added to the scores for the choice
     of the k and never to a weight (it balances the load without a loss);
@@ -421,7 +425,11 @@ def dropless_moe_ffn(
     expert over all E (they sum to T k).
     """
     t, d = x.shape
-    e, held = router_w.shape[1], w_gate.shape[0]
+    if (logits is None) == (router_w is None):
+        raise ValueError("give router_w or the caller's own logits, one of "
+                         "the two")
+    e = (router_w if logits is None else logits).shape[1]
+    held = w_gate.shape[0]
     if not 1 <= top_k <= e:
         raise ValueError(f"top_k must be in 1..{e}, got {top_k}")
     if not 0 <= first_expert <= e - held:
@@ -430,8 +438,10 @@ def dropless_moe_ffn(
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"scoring must be softmax|sigmoid, got {scoring!r}")
     with jax.named_scope(ROUTE_SCOPE):
-        logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                         precision=lax.Precision.HIGHEST)       # [T, E]
+        if logits is None:
+            logits = jnp.dot(x.astype(jnp.float32),
+                             router_w.astype(jnp.float32),
+                             precision=lax.Precision.HIGHEST)   # [T, E]
         lse = jax.nn.logsumexp(logits, axis=-1)
         if scoring == "softmax":
             probs = scores = jnp.exp(logits - lse[:, None])

@@ -102,3 +102,52 @@ def test_the_cell_cases_are_the_configurations(tool):
         assert case.heads == kinds[
             "sliding_attention" if windowed else "full_attention"]
         assert case.window == (cfg["sliding_window"] if windowed else None)
+
+
+# --------------------------------------------------------------------------
+# the ZAYA1 cell's mixer (PR 55)
+
+def test_the_mixer_check_passes_the_mixer_and_fails_its_five_controls(tool):
+    """A mixer of 4 query heads over 2 key heads of 128 (the published
+    width: a running sum of 128 squares is what a bf16 normalisation loses
+    most of), 64 tokens, float32: the program reads at float32's rounding
+    and every control — the value shift dropped, conv1's taps swapped, the
+    q-k mean left out, the temperature ignored, a bf16 normalisation — over
+    the tolerance."""
+    import jax.numpy as jnp
+
+    record = tool.check_cca(0, tool.Mixer(64, 128, 4, 2, 128, 5e6, 0.5),
+                            dtype=jnp.float32)
+    assert record["ok"], record
+    assert set(record["mixer"]) == set(tool.CCA_TENSORS)
+    assert max(record["mixer"].values()) <= 1e-4
+    assert set(record["controls"]) == set(tool.CCA_CONTROLS) and len(
+        record["controls"]) == 5
+    for name, control in record["controls"].items():
+        assert max(control.values()) > tool.CCA_TOLERANCE, name
+
+
+def test_a_mixer_that_turns_the_whole_head_fails_the_check(tool, monkeypatch):
+    """The program computed wrongly (all 128 entries of a head rotated where
+    the reference rotates 64) reads above the tolerance."""
+    import jax.numpy as jnp
+
+    zaya = importlib.import_module("byteps_tpu.models.zaya")
+    layer = zaya.CompressedConvAttention
+    monkeypatch.setattr(
+        zaya, "CompressedConvAttention",
+        lambda heads, kv, d, theta, factor, **kw: layer(
+            heads, kv, d, theta, 1.0, **kw))
+    record = tool.check_cca(1, tool.Mixer(64, 128, 4, 2, 128, 5e6, 0.5),
+                            dtype=jnp.float32)
+    assert not record["ok"]
+    assert max(record["mixer"].values()) > tool.CCA_TOLERANCE
+
+
+def test_the_mixer_case_is_the_configuration_s(tool):
+    cfg = _config("zaya1-8b")
+    rope = cfg["rope_parameters"]["hybrid"]
+    assert tool.CCA_CELL == tool.Mixer(
+        cfg["seq_len"], cfg["hidden_size"], cfg["num_attention_heads"],
+        cfg["num_key_value_heads"], cfg["head_dim"], rope["rope_theta"],
+        rope["partial_rotary_factor"])

@@ -327,6 +327,60 @@ def test_qwen3_next_mixers_compile_for_v5e(topo, as_on_a_tpu, kind):
         assert "bps.gattn.proj" in text and "bps.gattn.attend" in text
 
 
+def test_zaya_mixer_compiles_for_v5e(topo, as_on_a_tpu):
+    """The mixer of the ZAYA1 cell, forward and backward, b 1 x s 16384 at
+    the published widths: 8 query heads over 2 key heads of 128 computed in
+    the latent (the grouped flash kernels at 4 query heads a key head),
+    behind the two convolutions, the mean, the value shift, the
+    normalisation and the rotation, all XLA's to compile."""
+    from byteps_tpu.models.zaya import CompressedConvAttention
+
+    one = SingleDeviceSharding(topo.devices[0])
+    layer = CompressedConvAttention(8, 2, 128, 5e6, 0.5)
+    x = jax.ShapeDtypeStruct((1, 16384, 2048), jnp.float32, sharding=one)
+    params = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8, 2048))))
+    text = jax.jit(jax.grad(
+        lambda p, x: layer.apply(p, x).astype(jnp.float32).sum(),
+        argnums=(0, 1))).lower(params, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 3          # forward, dq, dkv
+    for kernel in ("bps_flash_fwd", "bps_flash_dq", "bps_flash_dkv"):
+        assert kernel in text
+    for scope in ("bps.cca.proj", "bps.cca.mix", "bps.cca.attend"):
+        assert scope in text
+
+
+def test_expert_share_given_logits_compiles_one_pass_for_v5e(topo):
+    """The experts of a ZAYA1 layer, forward and backward as the block
+    recomputes them: T 16384, top-1 by the caller's logits, experts 0..7 of
+    16, D 2048, width 2048. Half the experts held: the bound is every
+    assignment, 16,384 rows, one pass whatever the routing, still behind
+    the choice between a pass and a loop."""
+    from byteps_tpu.parallel.moe import dropless_moe_ffn, held_row_bound
+
+    one = SingleDeviceSharding(topo.devices[0])
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+              for shape, dtype in (((16384, 2048), jnp.float32),
+                                   ((16384, 16), jnp.float32),
+                                   ((8, 2048, 2048), jnp.float32),
+                                   ((8, 2048, 2048), jnp.float32),
+                                   ((8, 2048, 2048), jnp.float32))]
+    assert held_row_bound(16384, 1, 8, 16) == 16384
+
+    @jax.checkpoint
+    def layer(x, logits, *weights):
+        return dropless_moe_ffn(x, None, *weights, top_k=1,
+                                logits=logits)[0]
+
+    text = jax.jit(jax.value_and_grad(
+        lambda *args: layer(*args).astype(jnp.float32).sum(),
+        argnums=range(5))).lower(*shapes).compile().as_text()
+    assert text.count(" conditional(") == text.count(" while(") == 2
+    assert "bf16[16384,2048]" in text
+
+
 def test_joyai_collective_step_compiles_for_one_v5e(topo, as_on_a_tpu):
     """make_train_step over JoyAIFlashModel at the cell's widths, b 1 x s
     8192, adamw, for one described chip — cut to the dense layer and the

@@ -34,6 +34,23 @@ windowed call), query head i reading key head ``i % key heads``, logits
 scaled by the value width where it is not the key width (the latent call:
 128^-1/2 for 192^-1/2), and bf16 logits and softmax statistics (on ``out``).
 
+Since PR 55 one case more holds a whole mixer, not the kernels alone:
+``check_cca`` compiles one layer of ``models/zaya.py::
+CompressedConvAttention`` at the ZAYA1 cell's shapes — [1, 16384, 2048] in,
+8 query heads over 2 key heads of 128 in the latent, bf16 operands — and
+compares ``o W_o`` and the gradients of ``h``, ``W_q``, ``W_k``, ``W_v2``,
+conv1's taps and the temperature with ``benchmark/lib/plain_zaya.py::cca``
+in float32 at the highest matmul precision, on weights moved off their
+initial zeros (temperature -0.3 | 0.3, biases 0.1 sigma). Its controls are
+that reference computed wrongly: the value shift dropped, conv1's two taps
+swapped (a convolution that reads token t + 1's tap at t), the q-k mean
+left out, the temperature ignored, and the normalisation carried in bf16
+(row, squares, running sum, root and quotient each rounded). Each must read
+above ``CCA_TOLERANCE`` in some tensor and the mixer below it in all: the
+mixer reads 0.66-0.96% (bf16 projections, bf16 q^ and k^ into the kernels),
+the bf16 normalisation 2.6-4.0% in every tensor and the four others 31-298%
+(my chip runs, PR 55: PERF.md section 6 has every reading).
+
 One JSON line a case, then ``{"ok": ..., "device": ...}``; off the chip the
 kernels are interpreted at a small size (``tests/test_attention_check.py``).
 """
@@ -51,6 +68,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 TOLERANCE = 1.2e-2          # every tensor: the band, the grouping
 OUT_TOLERANCE = 3.0e-3      # out alone: float32 logits and statistics
 TENSORS = ("out", "dq", "dk", "dv")
+CCA_TOLERANCE = 1.6e-2      # the mixer: every tensor under, every control over
+CCA_TENSORS = ("out", "dh", "dW_q", "dW_k", "dW_v2", "dconv1", "dtheta")
+CCA_CONTROLS = {"value_shift_dropped": {"value_shift": False},
+                "conv1_taps_swapped": {"swap_taps": True},
+                "qk_mean_left_out": {"qk_mean": False},
+                "temperature_ignored": {"temperature": False},
+                "bf16_normalisation": {"norm_dtype": "bfloat16"}}
 
 
 # window None: the causal triangle; value_dim None: values as wide as keys
@@ -61,6 +85,11 @@ Case = collections.namedtuple(
 
 # the Laguna cell's two calls (benchmark/configs/laguna-xs.2.json) and the
 # latent one of joyai-llm-flash.json and kimi-linear-48b-a3b.json
+# the ZAYA1 cell's mixer (benchmark/configs/zaya1-8b.json)
+Mixer = collections.namedtuple(
+    "Mixer", "seq d_model heads kv_heads head_dim rope_theta rotary_factor")
+CCA_CELL = Mixer(16384, 2048, 8, 2, 128, 5e6, 0.5)
+
 CELL_CASES = (Case("windowed", 8192, 64, 8, 128, 512),
               Case("global", 8192, 48, 8, 128, None),
               Case("latent", 8192, 32, 32, 192, None, 128))
@@ -172,6 +201,77 @@ def check(case: Case, seed: int, attend=None) -> dict:
     return record
 
 
+def check_cca(seed: int, size: Mixer = CCA_CELL, dtype=None) -> dict:
+    """One layer's compiled mixer against the plain one, and the controls.
+    ``size`` and ``dtype`` (bf16 unless given) are a test's handle off the
+    chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.lib import plain_zaya
+    from benchmark.lib.plain_qwen3_next import rotary_of
+    from byteps_tpu.models.zaya import CompressedConvAttention
+
+    layer = CompressedConvAttention(
+        size.heads, size.kv_heads, size.head_dim, size.rope_theta,
+        size.rotary_factor, dtype=dtype or jnp.bfloat16)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    h = jax.random.normal(keys[0], (1, size.seq, size.d_model), jnp.float32)
+    w = jax.random.normal(keys[1], h.shape, jnp.float32)      # cotangent
+    p = dict(jax.jit(layer.init)(keys[2], h[:, :8])["params"])
+    p["temperature"] = jnp.where(
+        jnp.arange(size.kv_heads) % 2 == 0, -0.3, 0.3).astype(jnp.float32)
+    for name, key in zip(("conv0_bias", "conv1_bias"), keys[3:]):
+        p[name] = 0.1 * jax.random.normal(key, p[name].shape, jnp.float32)
+    rotary = rotary_of(size.head_dim, size.rope_theta, size.rotary_factor)
+
+    def plain(**control):
+        control = {key: jnp.dtype(value) if key == "norm_dtype" else value
+                   for key, value in control.items()}
+
+        def fn(p, h):
+            with jax.default_matmul_precision("highest"):
+                return plain_zaya.cca(
+                    h[0], p, head_dim=size.head_dim, rotary=rotary,
+                    dtype=jnp.float32, query_block=min(128, size.seq),
+                    **control)[None]
+
+        return fn
+
+    def run(fn):
+        def scalar(p, h):
+            out = fn(p, h)
+            return (out.astype(jnp.float32) * w).sum(), out
+
+        (_, out), (dp, dh) = jax.jit(jax.value_and_grad(
+            scalar, argnums=(0, 1), has_aux=True))(p, h)
+        return jax.device_get((
+            out, dh, dp["q"]["kernel"], dp["k"]["kernel"],
+            dp["v2"]["kernel"], dp["conv1"], dp["temperature"]))
+
+    def program(p, h):
+        return layer.apply({"params": p}, h)
+
+    want = run(plain())
+
+    def readings(fn):
+        return dict(zip(CCA_TENSORS, map(_relative, run(fn), want)))
+
+    record = {
+        "case": {"name": "cca", **size._asdict()}, "seed": seed,
+        "kernel_in_program": "tpu_custom_call" in jax.jit(program).lower(
+            p, h).as_text(),
+        "mixer": readings(program),
+        "controls": {name: readings(plain(**control))
+                     for name, control in CCA_CONTROLS.items()},
+        "tolerance": CCA_TOLERANCE}
+    record["ok"] = bool(
+        max(record["mixer"].values()) <= CCA_TOLERANCE
+        and all(max(c.values()) > CCA_TOLERANCE
+                for c in record["controls"].values()))
+    return record
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, default=0)
@@ -184,6 +284,10 @@ def main() -> int:
     for case in CELL_CASES if ok else ():
         record = check(case, args.seed)
         ok = ok and record["ok"] and record["kernel_in_program"]
+        print(json.dumps(record), flush=True)
+    if ok:
+        record = check_cca(args.seed)
+        ok = record["ok"] and record["kernel_in_program"]
         print(json.dumps(record), flush=True)
     print(json.dumps({"ok": ok, "device": {
         "platform": device.platform, "kind": device.device_kind}}))
