@@ -11,29 +11,35 @@ innermost so VMEM scratch carries the running (m, l, acc) across K steps.
 Layout as byteps_tpu.parallel attention: [batch, seq, heads, head_dim], v's
 head_dim (and the output's) free to differ from q's and k's; f32 accumulation.
 
-The backward pass is a pair of Pallas kernels (dQ, and dK/dV) doing the
-standard flash-attention blockwise recompute from the forward's saved
-(q, k, v, o, logsumexp) — O(seq) memory end to end. Off-TPU the kernels
+The backward pass is the standard flash-attention blockwise recompute from
+the forward's saved (q, k, v, o, logsumexp) — O(seq) memory end to end — in
+one of two forms that ``backward_form`` picks from the shapes: one kernel
+that computes a block's p and dS once and adds dV, dK and dQ from them
+(five products a block; dK and dV summed in float32 in VMEM over the whole
+sequence), or, where those sums do not fit, the pair it replaced (dQ, and
+dK/dV: seven products, the block recomputed in each). Off-TPU the kernels
 run in interpret mode, so tests exercise the real kernel code paths on CPU.
 
 Both matmul operands of every product are in the operands' own dtype (bf16
 on the hot path), logits, running max / sum and every accumulator float32.
 Causal blocks wholly above the diagonal are neither computed nor fetched
 (their index maps repeat the last live block, so the pipeline issues no
-copy). The three ``pallas_call``s are named ``bps_flash_fwd`` /
-``bps_flash_dq`` / ``bps_flash_dkv`` and each sits under a ``jax.jit`` of
-its own, so a model with N attention layers of one shape traces and lowers
-them once; what a process pays before its first step for using them is in
+copy). The ``pallas_call``s are named ``bps_flash_fwd`` and
+``bps_flash_bwd`` (the pair: ``bps_flash_dq`` / ``bps_flash_dkv``), forward
+and backward each under a ``jax.jit`` of its own, so a model with N
+attention layers of one shape traces and lowers them once; what a process
+pays before its first step for using them is in
 PERF.md section 6 (PR 36): the import, and 0.2-0.6 s of tracing a program.
 ``byteps_tpu.parallel.full_attention`` hands this kernel the shapes where
 it beats XLA's form on the chip (PERF.md section 3, kernels).
 
 ``k`` and ``v`` may have fewer heads than ``q`` (a divisor): the K/V index
-maps read head ``i // groups`` and the dK/dV kernel walks the group's heads
-one after another over one key block, so no repeated K/V and no [heads]
-dK/dV are written (at 64 / 8 x 128 x s8192 3-9% faster than repeating
-ahead of the call, PERF.md section 3). Under a ``window`` all three grids
-walk only the blocks the band touches.
+maps read head ``i // groups`` and dK/dV sum their group in the kernel (the
+fused kernel over the group's consecutive rows of its grid, the pair's
+dK/dV kernel walking the group's heads one after another over one key
+block), so no repeated K/V and no [heads] dK/dV are written (at 64 / 8 x
+128 x s8192 3-9% faster than repeating ahead of the call, PERF.md section
+3). Under a ``window`` every grid walks only the blocks the band touches.
 """
 
 from __future__ import annotations
@@ -87,8 +93,21 @@ _NEG_INF = -1e30
 WINDOW_BLOCK = 512
 
 # The kernels' names: what a device trace and the ledger's ``device_ops``
-# show for the three custom calls.
-FWD_NAME, DQ_NAME, DKV_NAME = "bps_flash_fwd", "bps_flash_dq", "bps_flash_dkv"
+# show for the custom calls (forward and fused backward, or the pair).
+FWD_NAME, BWD_NAME = "bps_flash_fwd", "bps_flash_bwd"
+DQ_NAME, DKV_NAME = "bps_flash_dq", "bps_flash_dkv"
+
+# The most the fused backward's call may ask of the chip's 128 MiB of VMEM
+# (the ``_VMEM_LIMIT`` of ``ops/kda_chunk.py`` and ``ops/kda_recurrence.py``),
+# and what a kernel gets unasked. ``backward_form`` counts what a call of the
+# shapes holds there (``_fused_vmem_bytes``) and gives it the fused kernel
+# where that is within the first; the call asks for what was counted and no
+# more. What a call asks for is felt outside it: the JoyAI cell's
+# ``peak_hbm_gb`` read 8.5969 with every call asking 64 MiB, 8.6642 asking 96
+# (the parent's 8.5992 + 0.76%, against a bound of 1%) and 8.5965 asking the
+# 47 counted for its shape (my chip runs, PR 57).
+_FUSED_VMEM_LIMIT = 96 * 1024 * 1024
+_VMEM_UNASKED = 16 * 1024 * 1024
 
 
 def _mask(q_start, k_start, bq, bk, seq_q, seq_k, causal, window):
@@ -248,7 +267,7 @@ def _block(s: int, largest: int) -> int:
 
 
 def _blocks(s_q: int, s_k: int, d: int, window: Optional[int] = None):
-    """(block_q, block_k) of the three kernels, from the shape. On a v5e,
+    """(block_q, block_k) of every kernel, from the shape. On a v5e,
     causal bf16, at 16 x 128 x s4096 and at 12 x 64 x s1024 (my chip runs,
     PR 36) — forward: 1024 x 1024 0.84 and 0.49 ms, the fastest; 512 x 512
     1.50 and 0.82, 256 x 256 2.85 and 1.34; 2048 x 2048 does not fit VMEM.
@@ -269,7 +288,10 @@ def _blocks(s_q: int, s_k: int, d: int, window: Optional[int] = None):
     / 83.06, the fastest again; 512 x 1024 10.43 / 25.26 | 32.89 / 85.77,
     1024 x 512 14.44 / 25.08 | 49.14 / 85.51, 512 x 512 (this width's until
     then) 15.04 / 25.83 | 51.76 / 90.51, the six smaller pairs 12.41-27.56 /
-    26.40-43.89. One rule for four widths."""
+    26.40-43.89. One rule for four widths. The fused backward
+    (``backward_form``) was swept at three shapes over four pairs (PR 57,
+    its docstring): 1024 x 1024 again, so forward, fused backward and pair
+    share one rule."""
     largest = 1024
     if window is not None:
         largest = min(largest, WINDOW_BLOCK)
@@ -279,6 +301,81 @@ def _blocks(s_q: int, s_k: int, d: int, window: Optional[int] = None):
 def _clamped(s_q: int, s_k: int, block_q: int, block_k: int) -> tuple:
     """Blocks no longer than their sequence (8 rows at the least)."""
     return min(block_q, max(s_q, 8)), min(block_k, max(s_k, 8))
+
+
+def _fused_vmem_bytes(bq: int, bk: int, sk_p: int, d: int, d_v: int,
+                      itemsize: int) -> int:
+    """What one call of the fused backward holds in VMEM, counted as the
+    chip lays it out: a row of any width takes whole 128-lane tiles (64
+    wide takes 128, 192 takes 256), and the pipeline keeps two buffers of
+    every block it copies. Float32 dK and dV over the padded keys; their
+    output blocks, as long, twice; a block's p, dP and dS in float32 and
+    p and dS again in the operands' dtype; q, dO, k and v blocks, twice;
+    the logsumexp and row-sum columns (8 lanes asked, 128 held), twice;
+    dQ's float32 sum and its output block, twice. The whole count and not
+    the sums alone: at width 64 and 32,768 keys over two heads the
+    compiler takes 76 MiB for the call, 32 of them the sums (PR 57)."""
+    lanes_k, lanes_v = -(-d // 128) * 128, -(-d_v // 128) * 128
+    lanes = lanes_k + lanes_v
+    sums = sk_p * lanes * 4
+    outputs = 2 * sk_p * lanes * itemsize
+    temporaries = bq * bk * (3 * 4 + 2 * itemsize)
+    operands = 2 * (bq + bk) * lanes * itemsize
+    columns = 2 * 2 * bq * 128 * 4
+    dq = bq * lanes_k * (4 + 2 * itemsize)
+    return sums + outputs + temporaries + operands + columns + dq
+
+
+def backward_form(s_q: int, s_k: int, d: int, d_v: int, groups: int,
+                  window: Optional[int] = None, itemsize: int = 2) -> str:
+    """``"fused"`` or ``"pair"``: the backward pass of a call of these
+    shapes. One algorithm, two schedules: ``bps_flash_bwd`` walks dQ's grid
+    (query blocks outside, key blocks inside), computes a block once and
+    keeps float32 dK and dV of one key head over all its keys in VMEM, so
+    it runs where what its call holds there (``_fused_vmem_bytes``, of the
+    blocks ``_blocks`` gives, the keys padded to them and operands of
+    ``itemsize`` bytes, bf16 unless said) is within ``_FUSED_VMEM_LIMIT``;
+    a longer sequence keeps the pair, which recomputes the block in each
+    kernel and holds a block's sums alone.
+    Neither the group nor the window moves the count of the sums (the
+    group's heads add into the same sums, a window's grid still spans the
+    sequence); a window's smaller blocks shrink the temporaries. The last
+    lengths that read "fused": 37,888 keys at widths 64 and 128, 24,576 at
+    192 / 128, 18,432 at 256, 45,568 under a window of 512. Each compiles
+    for a v5e (``tests/test_chip_compile.py``); the compiler's own count
+    there is 86-87 MiB of the 95-96 counted here (93 of 95.5 under the
+    window) and never more over 40 shapes compiled (PR 57): it keeps one
+    buffer of an output block where there is one key head in all. Past
+    those lengths the pair is held to compile and to the same gradients,
+    not to a speed: no benchmark cell sends such a call, and at every last
+    length the fused kernel was still the faster, so the limit is VMEM's.
+
+    dQ, dK and dV are the pair's to the last bit on the chip too, at the
+    twelve shapes below. On a v5e, causal bf16, the backward alone with its
+    layout copies and row sums, pair | fused, ms (my chip runs, PR 57,
+    every call asking 96 MiB): 32 x 192 / 128 x s8192 23.70 | 17.80, b8 x
+    12 x 64 x s1024 1.607 | 1.388, 16 x 128 x s4096 2.600 | 2.178, 48 over
+    8 x 128 x s8192 23.27 | 17.04, 64 over 8 under a window of 512 9.58 |
+    7.50, 8 over 2 x 128 x s16384 13.70 | 10.20, 16 over 2 x 256 x s16384
+    47.57 | 35.52; at the last lengths, 4 heads over 2 key heads (4 over 4
+    at 192 / 128, 8 over 2 under the window): 34.13 | 23.90 at 64, 34.00 |
+    24.18 at 128, 22.14 | 16.13 at 192 / 128, 15.32 | 11.64 at 256, 7.55 |
+    6.03 under the window. Under a call limit of 64 MiB (an earlier call of
+    PR 57, which the rest of this paragraph is from) the seven fused
+    figures read 17.70, 1.393, 2.125, 16.76, 7.43, 10.07, 34.92, and
+    JoyAI's the same 17.70 at 100. The other schedule (key blocks outside
+    as dK/dV's grid, float32 dQ of a group's heads full length: ``groups x
+    s_q x d x 4`` bytes, 134 MB at the seventh shape, which does not fit)
+    read 16.78, 1.384, 2.017, 16.18 and 9.78 at the five shapes it fits:
+    1-5% ahead, and not kept -- one rule that covers every shape the
+    benchmark sends against two. The fused kernel at other blocks than
+    ``_blocks``' (whose sums would change order): 1024 x 1024 17.70 | 10.07
+    | 1.393 at the first, sixth and second shape, 1024 x 512 17.96 | 10.29
+    | 1.385, 512 x 1024 18.11 | 10.56 | 1.392, 512 x 512 18.44 | 11.22 |
+    1.390 (the pair at 512 x 512 25.01 at the first)."""
+    bq, bk = _clamped(s_q, s_k, *_blocks(s_q, s_k, d, window))
+    held = _fused_vmem_bytes(bq, bk, -(-s_k // bk) * bk, d, d_v, itemsize)
+    return "fused" if held <= _FUSED_VMEM_LIMIT else "pair"
 
 
 def window_walked_pairs(s_q: int, s_k: int, d: int, window: int) -> int:
@@ -456,7 +553,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
 
 
 def _bwd_live(q_start, k_start, bq, bk, causal, window):
-    """Block-level skip predicate shared by both backward kernels."""
+    """Block-level skip predicate shared by the backward kernels."""
     if not causal:
         return True
     live = q_start + bq - 1 >= k_start
@@ -469,9 +566,9 @@ def _bwd_live(q_start, k_start, bq, bk, causal, window):
 def _bwd_recompute(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                    q_start, k_start, *, scale, causal, block_q, block_k,
                    seq_q, seq_k, window=None):
-    """Shared dq/dkv block recompute: returns (p, ds), float32. The one
-    place the score/probability/ds math lives, so the two backward
-    kernels cannot silently diverge."""
+    """A block's recompute, shared by the fused kernel and the pair:
+    returns (p, ds), float32. The one place the score/probability/ds math
+    lives, so the backward kernels cannot silently diverge."""
     lse = lse_ref[0][:, 0:1]
     dd = dd_ref[0][:, 0:1]
     sc = jax.lax.dot_general(
@@ -569,20 +666,96 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
+                         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                         scale, causal, block_q, block_k, seq_q, seq_k,
+                         groups, window=None, nk_total=None):
+    """All three gradients from one recompute of a block; grid (bh, qi, ki)
+    as dQ's, ``nk_total`` as there. dQ's sum over the inner axis sits in
+    ``dq_acc`` [block_q, d]; dK's and dV's, over the outer axis and a
+    group's heads, in ``dk_acc`` / ``dv_acc`` as long as the padded keys,
+    float32, from a key head's first step to its last, when they leave in
+    the operands' dtype as one block. A key block's contributions arrive
+    in the order the dK/dV kernel adds them (head of the group, then q
+    block), a query block's in dQ's: the sums are the pair's bit for bit."""
+    bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nq, nk = pl.num_programs(1), pl.num_programs(2)
+    head = jax.lax.rem(bh, jnp.int32(groups))     # of its key head's group
+    blocks = dk_acc.shape[0] // block_k
+
+    def rows(i):
+        return pl.ds(pl.multiple_of(i * block_k, block_k), block_k)
+
+    @pl.when(jnp.logical_and(jnp.logical_and(qi == 0, ki == 0), head == 0))
+    def _init_keys():
+        def zero(i, _):
+            dk_acc[rows(i), :] = jnp.zeros((block_k, dk_acc.shape[1]),
+                                           jnp.float32)
+            dv_acc[rows(i), :] = jnp.zeros((block_k, dv_acc.shape[1]),
+                                           jnp.float32)
+        jax.lax.fori_loop(0, blocks, zero, None)
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    step, q_start = ki, qi * block_q
+    if nk_total is not None:
+        ki = _window_start_block(q_start, window, block_k) + ki
+    k_start = ki * block_k
+
+    def _compute():
+        p, ds = _bwd_recompute(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_start, k_start,
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            seq_q=seq_q, seq_k=seq_k, window=window)
+        q, k, do = q_ref[0], k_ref[0], do_ref[0]
+        dv_acc[rows(ki), :] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = ds.astype(q.dtype)
+        dk_acc[rows(ki), :] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dq_acc[:] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    live = _bwd_live(q_start, k_start, block_q, block_k, causal, window)
+    if nk_total is not None:
+        live = jnp.logical_and(live, k_start < nk_total * block_k)
+    _when_live(live, _compute)
+
+    @pl.when(step == nk - 1)
+    def _finish():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(jnp.logical_and(qi == nq - 1, step == nk - 1),
+                             head == groups - 1))
+    def _finish_keys():
+        def cast(i, _):
+            dk_ref[0, rows(i), :] = dk_acc[rows(i), :].astype(dk_ref.dtype)
+            dv_ref[0, rows(i), :] = dv_acc[rows(i), :].astype(dv_ref.dtype)
+        jax.lax.fori_loop(0, blocks, cast, None)
+
+
 def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res,
                g):
     """Pallas backward: blockwise recompute from (q, k, v, o, lse) — the
-    standard flash-attention backward, O(seq) memory like the forward."""
-    q, k = res[0], res[1]
+    standard flash-attention backward, O(seq) memory like the forward, in
+    the form ``backward_form`` names for the shapes."""
+    q, k, v = res[:3]
     bq, bk = _blocks(q.shape[1], k.shape[1], q.shape[-1], window)
+    form = backward_form(q.shape[1], k.shape[1], q.shape[-1], v.shape[-1],
+                         q.shape[2] // k.shape[2], window, q.dtype.itemsize)
     return _flash_bwd_impl(*res, g, causal, scale, bq, bk,
-                           _resolve_interpret(interpret), window)
+                           _resolve_interpret(interpret), window, form)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "block_q", "block_k", "interpret", "window"))
+    "causal", "scale", "block_q", "block_k", "interpret", "window", "form"))
 def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale, block_q, block_k,
-                    interpret, window):
+                    interpret, window, form):
     b, s_q, h, d = q.shape
     s_k, d_v, h_kv = k.shape[1], v.shape[-1], k.shape[2]
     kv_head, groups = _kv_head(h, h_kv), h // h_kv
@@ -658,36 +831,65 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     kw = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
               seq_q=s_q, seq_k=s_k, window=window)
 
-    dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, **kw,
-                          nk_total=nk if banded else None),
-        grid=(b * h, nq, nk_dq),
-        in_specs=specs(q_of_dq, k_of_dq),
-        out_specs=pl.BlockSpec((1, bq, d), q_of_dq, memory_space=_VMEM),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
-        scratch_shapes=[_VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
-        name=DQ_NAME,
-    )(qq, kk, vv, dd_o, lse, dd)
+    if form == "fused":
+        def keys_of(bh, qi, ki):
+            return (kv_head(bh), 0, 0)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, **kw, q_of_step=q_of_step,
-                          nq_total=nq if banded else None),
-        grid=(b * h_kv, nk, groups * nq_dkv),
-        in_specs=specs(q_of_dkv, k_of_dkv),
-        out_specs=[
-            pl.BlockSpec((1, bk, d), k_of_dkv, memory_space=_VMEM),
-            pl.BlockSpec((1, bk, d_v), k_of_dkv, memory_space=_VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h_kv, sk_p, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h_kv, sk_p, d_v), v.dtype),
-        ],
-        scratch_shapes=[_VMEM((bk, d), jnp.float32),
-                        _VMEM((bk, d_v), jnp.float32)],
-        interpret=interpret,
-        name=DKV_NAME,
-    )(qq, kk, vv, dd_o, lse, dd)
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_fa_bwd_fused_kernel, **kw, groups=groups,
+                              nk_total=nk if banded else None),
+            grid=(b * h, nq, nk_dq),
+            in_specs=specs(q_of_dq, k_of_dq),
+            out_specs=[
+                pl.BlockSpec((1, bq, d), q_of_dq, memory_space=_VMEM),
+                pl.BlockSpec((1, sk_p, d), keys_of, memory_space=_VMEM),
+                pl.BlockSpec((1, sk_p, d_v), keys_of, memory_space=_VMEM),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
+                jax.ShapeDtypeStruct((b * h_kv, sk_p, d), k.dtype),
+                jax.ShapeDtypeStruct((b * h_kv, sk_p, d_v), v.dtype),
+            ],
+            scratch_shapes=[_VMEM((bq, d), jnp.float32),
+                            _VMEM((sk_p, d), jnp.float32),
+                            _VMEM((sk_p, d_v), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=max(
+                _fused_vmem_bytes(bq, bk, sk_p, d, d_v, q.dtype.itemsize),
+                _VMEM_UNASKED)),
+            interpret=interpret,
+            name=BWD_NAME,
+        )(qq, kk, vv, dd_o, lse, dd)
+    else:
+        dq = pl.pallas_call(
+            functools.partial(_fa_bwd_dq_kernel, **kw,
+                              nk_total=nk if banded else None),
+            grid=(b * h, nq, nk_dq),
+            in_specs=specs(q_of_dq, k_of_dq),
+            out_specs=pl.BlockSpec((1, bq, d), q_of_dq, memory_space=_VMEM),
+            out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
+            scratch_shapes=[_VMEM((bq, d), jnp.float32)],
+            interpret=interpret,
+            name=DQ_NAME,
+        )(qq, kk, vv, dd_o, lse, dd)
+
+        dk, dv = pl.pallas_call(
+            functools.partial(_fa_bwd_dkv_kernel, **kw, q_of_step=q_of_step,
+                              nq_total=nq if banded else None),
+            grid=(b * h_kv, nk, groups * nq_dkv),
+            in_specs=specs(q_of_dkv, k_of_dkv),
+            out_specs=[
+                pl.BlockSpec((1, bk, d), k_of_dkv, memory_space=_VMEM),
+                pl.BlockSpec((1, bk, d_v), k_of_dkv, memory_space=_VMEM),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b * h_kv, sk_p, d), k.dtype),
+                jax.ShapeDtypeStruct((b * h_kv, sk_p, d_v), v.dtype),
+            ],
+            scratch_shapes=[_VMEM((bk, d), jnp.float32),
+                            _VMEM((bk, d_v), jnp.float32)],
+            interpret=interpret,
+            name=DKV_NAME,
+        )(qq, kk, vv, dd_o, lse, dd)
 
     dq = _from_bhsd(dq[:, :s_q], b, h)
     dk = _from_bhsd(dk[:, :s_k], b, h_kv)

@@ -144,6 +144,9 @@ def _single_device_attention(q, k, v, *, causal: bool, scale: float,
 KERNEL_SCOPE, XLA_SCOPE = "bps.attn.kernel", "bps.attn.xla"
 KERNEL_SITES = "bps_attention_kernel_sites_total"
 XLA_SITES = "bps_attention_xla_sites_total"
+# ... and the kernel sites whose backward pass is the one fused kernel
+# (``ops/flash_attention.py::backward_form`` of the site's shapes)
+FUSED_BACKWARD_SITES = "bps_attention_fused_backward_sites_total"
 
 # The shortest sequence and the head widths (queries and keys, values) at
 # which the Pallas kernel was measured against the XLA form on a TPU v5e,
@@ -218,9 +221,12 @@ def full_attention(q, k, v, *, causal: bool = False,
         # s128, any CPU run) pays for no kernel library
         # (tests/test_import_footprint.py)
         from byteps_tpu.ops.flash_attention import (
-            flash_attention, window_walked_pairs)
+            backward_form, flash_attention, window_walked_pairs)
 
         metrics.inc_counter(KERNEL_SITES)
+        if backward_form(q.shape[1], k.shape[1], q.shape[-1], v.shape[-1],
+                         groups, window, q.dtype.itemsize) == "fused":
+            metrics.inc_counter(FUSED_BACKWARD_SITES)
         if window is not None:
             # the kernel's own count of the blocks its grids compute
             _count_window(q, window, window_walked_pairs(

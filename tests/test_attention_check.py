@@ -35,6 +35,8 @@ def _interpreted(q, k, v, window, scale=None):
     (("global", 96, 6, 2, 16, None), {"heads_interleaved"}),
     # keys 24 wide, values 16: out and dV take the value width
     (("latent", 96, 4, 4, 24, None, 16), {"scale_of_value_width"}),
+    # three sequences in the call, as many key heads as query heads
+    (("dense", 96, 3, 3, 16, None, None, 3), {"next_key_head"}),
 ], ids=lambda value: value[0] if isinstance(value, tuple) else "")
 def test_the_check_passes_the_kernel_and_fails_its_controls(
         tool, case, controls):
@@ -94,7 +96,8 @@ def test_the_cell_cases_are_the_configurations(tool):
     n = cfg["num_hidden_layers"]
     kinds = dict(zip(cfg["layer_types"][:n],
                      cfg["num_attention_heads_per_layer"][:n]))
-    for case in (c for c in tool.CELL_CASES if c.name != "latent"):
+    for case in (c for c in tool.CELL_CASES
+                 if c.name in ("windowed", "global")):
         windowed = case.window is not None
         assert case.seq == cfg["seq_len"]
         assert case.head_dim == cfg["head_dim"]
@@ -102,6 +105,25 @@ def test_the_cell_cases_are_the_configurations(tool):
         assert case.heads == kinds[
             "sliding_attention" if windowed else "full_attention"]
         assert case.window == (cfg["sliding_window"] if windowed else None)
+
+
+def test_the_dense_case_is_the_gpt2_configuration(tool):
+    cfg = _config("gpt2-124m")
+    case, = (c for c in tool.CELL_CASES if c.name == "dense")
+    assert (case.batch, case.seq) == (cfg["batch_per_chip"], cfg["seq_len"])
+    assert case.heads == case.kv_heads == cfg["n_head"]
+    assert case.head_dim == cfg["n_embd"] // cfg["n_head"]
+    assert case.window is None and case.value_dim is None
+
+
+def test_a_kernel_that_mixes_a_batch_s_sequences_fails_the_check(tool):
+    """The program computed wrongly (the sequences of the call answered in
+    another order) reads above the tolerance."""
+    record = tool.check(
+        tool.Case("dense", 96, 3, 3, 16, None, None, 3), seed=1,
+        attend=lambda q, k, v, window: _interpreted(q, k, v, window)[::-1])
+    assert not record["ok"]
+    assert max(record["kernel"].values()) > tool.TOLERANCE
 
 
 # --------------------------------------------------------------------------

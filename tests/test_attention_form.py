@@ -18,7 +18,7 @@ import pytest
 
 from byteps_tpu.monitor import metrics
 from byteps_tpu.parallel.ring_attention import (
-    KERNEL_SCOPE, KERNEL_SITES, XLA_SCOPE, XLA_SITES,
+    FUSED_BACKWARD_SITES, KERNEL_SCOPE, KERNEL_SITES, XLA_SCOPE, XLA_SITES,
     _single_device_attention, attention_form, full_attention)
 
 # the module: the package re-exports a function of the same name
@@ -211,6 +211,42 @@ def test_the_counters_count_one_site_per_attention_call(monkeypatch):
         text = step().lower(params).as_text(debug_info=True)
     assert sites() == (k0 + 3, x0 + 3)
     assert KERNEL_SCOPE in text and XLA_SCOPE not in text
+
+
+@pytest.mark.parametrize("limit, fused, kernels", [
+    (None, 1, ("bps_flash_fwd", "bps_flash_bwd")),
+    (0, 0, ("bps_flash_fwd", "bps_flash_dq", "bps_flash_dkv")),
+], ids=["fits", "over_the_limit"])
+def test_the_fused_backward_counter_counts_the_sites_that_take_it(
+        rng, monkeypatch, limit, fused, kernels):
+    """``bps_attention_fused_backward_sites_total`` beside the kernel
+    sites' counter, at trace time: a site asks ``backward_form`` of its
+    shapes, and what it lowers to is that form's kernels by name. On the
+    CPU backend no site takes the kernel, and the counter stands."""
+    fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+    q, k, v = _operands(rng, 4, 64)
+    k, v = k[:, :, :2], v[:, :, :2]
+
+    def text():
+        return _lowered(jax.grad(
+            lambda q, k, v: full_attention(q, k, v, causal=True)
+            .astype(F32).sum(), argnums=(0, 1, 2)), q, k, v, debug_info=True)
+
+    def sites():
+        return (metrics.counter(KERNEL_SITES),
+                metrics.counter(FUSED_BACKWARD_SITES))
+
+    if limit is not None:
+        monkeypatch.setattr(fa, "_FUSED_VMEM_LIMIT", limit)
+    k0, f0 = sites()
+    text()
+    assert sites() == (k0, f0)
+    with _kernel_form(monkeypatch):
+        lowered = text()
+    assert sites() == (k0 + 1, f0 + fused)
+    for name in ("bps_flash_fwd", "bps_flash_dq", "bps_flash_dkv",
+                 "bps_flash_bwd"):
+        assert (name in lowered) == (name in kernels), name
 
 
 def test_each_form_is_named_in_the_lowered_program(rng, monkeypatch):

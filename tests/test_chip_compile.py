@@ -86,7 +86,7 @@ GROUPED_CASES = {"laguna_window512_h64": (64, 512),
 @pytest.mark.parametrize("case", sorted(GROUPED_CASES))
 def test_grouped_flash_kernel_compiles_for_v5e(topo, case, with_grads):
     """Grouped keys (the K/V index maps read head i // groups, dK/dV sum
-    their group inside the kernel) and, under the window, all three grids
+    their group inside the kernel) and, under the window, every grid
     walking the band alone, at the shape the cell runs and the blocks
     ``_blocks`` derives for it."""
     heads, window = GROUPED_CASES[case]
@@ -105,11 +105,54 @@ def test_grouped_flash_kernel_compiles_for_v5e(topo, case, with_grads):
     fn = jax.grad(loss, argnums=(0, 1, 2)) if with_grads else fwd
     compiled = jax.jit(fn).lower(q, kv, kv).compile()
     assert compiled.as_text().count("tpu_custom_call") >= (
-        3 if with_grads else 1)
+        2 if with_grads else 1)                        # forward, backward
     if with_grads:
         dq, dk, dv = jax.eval_shape(fn, q, kv, kv)
         assert (dq.shape, dk.shape, dv.shape) == (q.shape, kv.shape,
                                                   kv.shape)
+
+
+FUSED = ("bps_flash_fwd", "bps_flash_bwd")
+PAIR = ("bps_flash_fwd", "bps_flash_dq", "bps_flash_dkv")
+# s, (query heads, key heads), (key width, value width), window, dtype
+BACKWARD_FORM_CASES = {
+    "qwen3_next_cell": (16384, (16, 2), (256, 256), None, jnp.bfloat16, FUSED),
+    # the last length ``backward_form`` gives the fused kernel, by width
+    "last_fused_64": (37888, (4, 2), (64, 64), None, jnp.bfloat16, FUSED),
+    "last_fused_128": (37888, (4, 2), (128, 128), None, jnp.bfloat16, FUSED),
+    "last_fused_192_128": (24576, (2, 2), (192, 128), None, jnp.bfloat16,
+                           FUSED),
+    "last_fused_256": (18432, (4, 2), (256, 256), None, jnp.bfloat16, FUSED),
+    "last_fused_window512": (45568, (4, 2), (128, 128), 512, jnp.bfloat16,
+                             FUSED),
+    "last_fused_float32": (22528, (4, 2), (128, 128), None, jnp.float32,
+                           FUSED),
+    "pair_over_the_limit": (32768, (16, 2), (256, 256), None, jnp.bfloat16,
+                            PAIR),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BACKWARD_FORM_CASES))
+def test_each_backward_form_compiles_for_v5e(topo, case):
+    """What ``backward_form`` calls "fused" has to fit the VMEM its call
+    asks for: at the Qwen3-Next cell's shape (32 MiB of float32 dK and dV),
+    at the rule's last length of every width (more than one key head, so
+    the long output blocks keep both their buffers), and beyond it the
+    pair."""
+    s, (heads, kv_heads), (d, d_v), window, dtype, kernels = (
+        BACKWARD_FORM_CASES[case])
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, s, heads, d), dtype, sharding=one)
+    k = jax.ShapeDtypeStruct((1, s, kv_heads, d), dtype, sharding=one)
+    v = jax.ShapeDtypeStruct((1, s, kv_heads, d_v), dtype, sharding=one)
+    text = jax.jit(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, window=window,
+                                        interpret=False)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    ).lower(q, k, v).compile().as_text()
+    assert text.count("tpu_custom_call") == len(kernels)
+    for name in set(FUSED + PAIR):
+        assert (name in text) == (name in kernels), name
 
 
 @pytest.mark.parametrize("with_grads", [False, True], ids=["fwd", "fwd_bwd"])
@@ -293,7 +336,8 @@ def test_rotary_latent_attention_compiles_for_v5e(topo, as_on_a_tpu):
     text = jax.jit(jax.grad(
         lambda p, x: layer.apply(p, x).astype(jnp.float32).sum(),
         argnums=(0, 1))).lower(params, x).compile().as_text()
-    assert text.count("tpu_custom_call") >= 3      # forward, dq, dkv
+    assert text.count("tpu_custom_call") >= 2      # forward, backward
+    assert "bps_flash_bwd" in text
     assert "bps.mla.proj" in text and "bps.mla.attend" in text
 
 
@@ -323,7 +367,8 @@ def test_qwen3_next_mixers_compile_for_v5e(topo, as_on_a_tpu, kind):
         for scope in ("bps.gdn.prep", "bps.gdn.scan", "bps.gdn.out"):
             assert scope in text
     else:
-        assert text.count("tpu_custom_call") >= 3      # forward, dq, dkv
+        assert text.count("tpu_custom_call") >= 2      # forward, backward
+        assert "bps_flash_bwd" in text
         assert "bps.gattn.proj" in text and "bps.gattn.attend" in text
 
 
@@ -345,9 +390,11 @@ def test_zaya_mixer_compiles_for_v5e(topo, as_on_a_tpu):
     text = jax.jit(jax.grad(
         lambda p, x: layer.apply(p, x).astype(jnp.float32).sum(),
         argnums=(0, 1))).lower(params, x).compile().as_text()
-    assert text.count("tpu_custom_call") == 3          # forward, dq, dkv
-    for kernel in ("bps_flash_fwd", "bps_flash_dq", "bps_flash_dkv"):
+    assert text.count("tpu_custom_call") == 2          # forward, backward
+    for kernel in ("bps_flash_fwd", "bps_flash_bwd"):
         assert kernel in text
+    for kernel in ("bps_flash_dq", "bps_flash_dkv"):
+        assert kernel not in text
     for scope in ("bps.cca.proj", "bps.cca.mix", "bps.cca.attend"):
         assert scope in text
 

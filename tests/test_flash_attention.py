@@ -6,8 +6,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import importlib
+
 from byteps_tpu.ops import flash_attention
 from byteps_tpu.parallel.ring_attention import full_attention
+
+# the module: the package re-exports a function of the same name
+fa = importlib.import_module("byteps_tpu.ops.flash_attention")
 
 
 def _qkv(rng, b=2, s=64, h=3, d=32, dtype=jnp.float32):
@@ -177,16 +182,126 @@ def test_interpret_resolution(monkeypatch, backend, asked, want):
     assert _resolve_interpret(asked) is want
 
 
-def test_equal_widths_lower_as_before_the_second_width():
+def test_equal_widths_lower_as_before_the_second_width(monkeypatch):
     """PR 39 gave the kernels a value width of their own. With v as wide
     as q and k the three kernels lower to the text they lowered to at
     ``3f4a582`` (interpret mode: plain HLO, no source position in it):
-    forward, dQ and dK/dV at 1 x 256 x 2 x 64, causal, blocks of 128."""
+    forward, dQ and dK/dV at 1 x 256 x 2 x 64, causal, blocks of 128. The
+    pair is what a shape over the fused backward's budget still runs."""
     import hashlib
 
+    monkeypatch.setattr(fa, "backward_form", lambda *shape: "pair")
     x = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.float32)
     text = jax.jit(jax.grad(
         lambda q, k, v: flash_attention(q, k, v, True, None, 128, 128).sum(),
         argnums=(0, 1, 2))).lower(x, x, x).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == (
         "87ce996fa8e50d2dd795149e030aa8b9f4c1dd8b238dae133638605f1ee2b108")
+
+
+# batch, s_q, s_k, query heads, key heads, key width, value width, causal,
+# window, dtype; blocks of 64 queries x 128 keys throughout
+FUSED_CASES = {
+    "width_64": (2, 256, 256, 2, 2, 64, 64, True, None, jnp.bfloat16),
+    "width_128": (1, 256, 256, 2, 2, 128, 128, True, None, jnp.bfloat16),
+    "keys_192_values_128": (1, 256, 256, 2, 2, 192, 128, True, None,
+                            jnp.bfloat16),
+    "8_heads_over_2": (1, 256, 256, 8, 2, 64, 64, True, None, jnp.bfloat16),
+    "window_shorter_than_a_block": (1, 512, 512, 4, 2, 64, 64, True, 40,
+                                    jnp.bfloat16),
+    "window_longer_than_a_block": (1, 512, 512, 4, 1, 64, 64, True, 200,
+                                   jnp.bfloat16),
+    "unaligned": (1, 300, 300, 4, 2, 64, 64, True, None, jnp.bfloat16),
+    "rectangular_causal": (2, 256, 384, 2, 2, 64, 64, True, None,
+                           jnp.bfloat16),
+    "rectangular_not_causal": (1, 256, 384, 4, 2, 64, 64, False, None,
+                               jnp.bfloat16),
+    "eight_by_four_blocks": (1, 512, 512, 2, 1, 64, 64, True, None,
+                             jnp.bfloat16),
+    "float32": (1, 300, 300, 2, 2, 32, 32, True, None, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+def test_fused_backward_is_the_pair_to_the_last_bit(rng, monkeypatch, case):
+    """``bps_flash_bwd`` against ``bps_flash_dq`` + ``bps_flash_dkv``: the
+    same ``p`` and ``dS`` of a block, the same products, and a block's
+    contributions added in the order the pair adds them, so dQ, dK and dV
+    are equal, not close. No sum's order had to change."""
+    b, s_q, s_k, h, h_kv, d, d_v, causal, window, dtype = FUSED_CASES[case]
+    q = jnp.asarray(rng.standard_normal((b, s_q, h, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((b, s_k, h_kv, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((b, s_k, h_kv, d_v)), dtype)
+    w = jnp.asarray(rng.standard_normal((b, s_q, h, d_v)), jnp.float32)
+    monkeypatch.setattr(fa, "_blocks", lambda *shape: (64, 128))
+    assert fa.backward_form(s_q, s_k, d, d_v, h // h_kv, window) == "fused"
+
+    def grads(form):
+        monkeypatch.setattr(fa, "backward_form", lambda *shape: form)
+        return jax.grad(
+            lambda q, k, v: (flash_attention(q, k, v, causal, window=window)
+                             .astype(jnp.float32) * w).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    pair, fused = grads("pair"), grads("fused")
+    for name, want, got in zip(("dq", "dk", "dv"), pair, fused):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.abs(np.asarray(want, np.float32)).max() > 0.1, name
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32), name)
+
+
+# s_q, s_k, key width, value width, query heads a key head, window: what
+# each cell's attention would ask (BERT's and Keye's never do: the XLA
+# form at s 128, ``ops/sparse_flash.py``)
+BACKWARD_FORMS = {
+    "gpt2-124m.collective.1chip": ((1024, 1024, 64, 64, 1, None), "fused"),
+    "gpt2-124m.ps.1chip": ((1024, 1024, 64, 64, 1, None), "fused"),
+    "gpt2-124m.ps-bucketed.1chip": ((1024, 1024, 64, 64, 1, None), "fused"),
+    "bert-large.collective.4chip": ((128, 128, 64, 64, 1, None), "fused"),
+    "bert-large.collective.1chip": ((128, 128, 64, 64, 1, None), "fused"),
+    "olmoe-1b-7b.collective-moe.1chip": (
+        (4096, 4096, 128, 128, 1, None), "fused"),
+    "keye-vl-2.0-30b-a3b.collective-dsa.1chip": (
+        (8192, 8192, 128, 128, 8, None), "fused"),
+    "ouro-2.6b.collective-loop.1chip": (
+        (4096, 4096, 128, 128, 1, None), "fused"),
+    "kimi-linear-48b-a3b.collective-kda.1chip": (
+        (8192, 8192, 192, 128, 1, None), "fused"),
+    "joyai-llm-flash.collective-mtp.1chip": (
+        (8192, 8192, 192, 128, 1, None), "fused"),
+    "laguna-xs.2.collective-swa.1chip, global": (
+        (8192, 8192, 128, 128, 6, None), "fused"),
+    "laguna-xs.2.collective-swa.1chip, windowed": (
+        (8192, 8192, 128, 128, 8, 512), "fused"),
+    "qwen3-next-80b-a3b.collective-gdn.1chip": (
+        (16384, 16384, 256, 256, 8, None), "fused"),
+    "zaya1-8b.collective-cca.1chip": (
+        (16384, 16384, 128, 128, 4, None), "fused"),
+    # 32,768 keys 256 wide: 64 MiB of float32 dK and dV, 64 more of their
+    # output blocks' two buffers, against a limit of 96
+    "too long for the limit": ((32768, 32768, 256, 256, 8, None), "pair"),
+    "the keys alone decide": ((512, 32768, 256, 256, 8, None), "pair"),
+    # the rule's edge at each width: the last length that reads "fused"
+    # (tests/test_chip_compile.py compiles each) and the next block's
+    "64, the last": ((37888, 37888, 64, 64, 1, None), "fused"),
+    "64, one block on": ((38912, 38912, 64, 64, 1, None), "pair"),
+    "64 holds the lanes of 128": ((37888, 37888, 128, 128, 2, None), "fused"),
+    "128, one block on": ((38912, 38912, 128, 128, 2, None), "pair"),
+    "192 / 128, the last": ((24576, 24576, 192, 128, 1, None), "fused"),
+    "192 / 128, one block on": ((25600, 25600, 192, 128, 1, None), "pair"),
+    "256, the last": ((18432, 18432, 256, 256, 8, None), "fused"),
+    "256, one block on": ((19456, 19456, 256, 256, 8, None), "pair"),
+    # a window's 512 x 512 blocks leave the sums more room
+    "windowed, the last": ((45568, 45568, 128, 128, 8, 512), "fused"),
+    "windowed, one block on": ((46080, 46080, 128, 128, 8, 512), "pair"),
+    # float32 operands: output blocks of twice the bytes
+    "float32 at 256": ((16384, 16384, 256, 256, 8, None, 4), "pair"),
+    "float32 at 128": ((16384, 16384, 128, 128, 4, None, 4), "fused"),
+}
+
+
+@pytest.mark.parametrize("site", sorted(BACKWARD_FORMS))
+def test_backward_form_is_a_pure_function_of_the_shapes(site):
+    shape, form = BACKWARD_FORMS[site]
+    assert fa.backward_form(*shape) == form

@@ -63,7 +63,7 @@ def test_the_cell_cases_are_the_configuration_s():
         REPO, "benchmark", "configs", "qwen3-next-80b-a3b.json")))
     scan, attention = tool.cell_cases()
     assert scan == (cfg["seq_len"], 16, 32, 128, 128, cfg["gdn_chunk"])
-    assert attention[1:] == (cfg["seq_len"], 16, 2, 256, None, None)
+    assert attention[1:] == (cfg["seq_len"], 16, 2, 256, None, None, 1)
 
 
 def test_the_channel_case_is_the_configuration_s():

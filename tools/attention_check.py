@@ -11,7 +11,9 @@ kernels the cell runs: the Laguna cell's two calls, b 1 x s 8,192 x 128 in
 bf16 over 8 key heads — 64 query heads under a window of 512, 48 under the
 causal triangle alone — and the latent call of the JoyAI and Kimi-Linear
 cells, 32 heads over 32 key heads at s 8,192 with keys 192 wide and values
-128 (PR 51). The reference is the benchmark's own plain one
+128 (PR 51), and, since PR 57, the GPT-2 cells' call, eight sequences of
+1,024 x 12 heads of 64 (control: every head reading the key head after its
+own). The reference is the benchmark's own plain one
 (``benchmark/lib/plain_laguna.py::banded_attention``: the band as a mask
 over all keys, the group as an axis, blocks of 256 queries) on the same
 bf16 operands in float32 at the highest matmul precision, nothing of
@@ -77,10 +79,11 @@ CCA_CONTROLS = {"value_shift_dropped": {"value_shift": False},
                 "bf16_normalisation": {"norm_dtype": "bfloat16"}}
 
 
-# window None: the causal triangle; value_dim None: values as wide as keys
+# window None: the causal triangle; value_dim None: values as wide as keys;
+# batch: sequences in the call, each checked against the reference
 Case = collections.namedtuple(
-    "Case", "name seq heads kv_heads head_dim window value_dim",
-    defaults=(None,))
+    "Case", "name seq heads kv_heads head_dim window value_dim batch",
+    defaults=(None, 1))
 
 
 # the Laguna cell's two calls (benchmark/configs/laguna-xs.2.json) and the
@@ -92,7 +95,10 @@ CCA_CELL = Mixer(16384, 2048, 8, 2, 128, 5e6, 0.5)
 
 CELL_CASES = (Case("windowed", 8192, 64, 8, 128, 512),
               Case("global", 8192, 48, 8, 128, None),
-              Case("latent", 8192, 32, 32, 192, None, 128))
+              Case("latent", 8192, 32, 32, 192, None, 128),
+              # the GPT-2 cells' call (gpt2-124m.json): the width the most
+              # cells share, eight sequences in one call
+              Case("dense", 1024, 12, 12, 64, None, batch=8))
 
 
 def _relative(got, want) -> float:
@@ -114,12 +120,12 @@ def check(case: Case, seed: int, attend=None) -> dict:
     from benchmark.lib.plain_laguna import banded_attention
 
     s, h, kv, d = case.seq, case.heads, case.kv_heads, case.head_dim
-    d_v = case.value_dim or d
+    d_v, b = case.value_dim or d, case.batch
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
-    q = jax.random.normal(keys[0], (1, s, h, d), jnp.bfloat16)
-    k = jax.random.normal(keys[1], (1, s, kv, d), jnp.bfloat16)
-    v = jax.random.normal(keys[2], (1, s, kv, d_v), jnp.bfloat16)
-    w = jax.random.normal(keys[3], (1, s, h, d_v), jnp.float32)  # cotangent
+    q = jax.random.normal(keys[0], (b, s, h, d), jnp.bfloat16)
+    k = jax.random.normal(keys[1], (b, s, kv, d), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (b, s, kv, d_v), jnp.bfloat16)
+    w = jax.random.normal(keys[3], (b, s, h, d_v), jnp.float32)  # cotangent
 
     if attend is None:
         from byteps_tpu.parallel import full_attention
@@ -128,7 +134,7 @@ def check(case: Case, seed: int, attend=None) -> dict:
             return full_attention(q, k, v, causal=True, window=window)
 
     def run(fn):
-        """(out, dq, dk, dv) of ``fn(q, k, v) -> [1, s, h, d_v]``."""
+        """(out, dq, dk, dv) of ``fn(q, k, v) -> [b, s, h, d_v]``."""
         def scalar(q, k, v, w):
             out = fn(q, k, v)
             return (out.astype(jnp.float32) * w).sum(), out
@@ -140,30 +146,36 @@ def check(case: Case, seed: int, attend=None) -> dict:
     groups = h // kv
 
     def plain(window=case.window, dtype=jnp.float32,
-              logits_dtype=jnp.float32, interleaved=False, scale_dim=d):
-        """The plain reference as a function of the same operands; the
-        keyword arguments are the ways to compute it wrongly. Its layout is
-        [s, key heads, group, d]: query head i is member ``i % group`` of
-        key head ``i // group``, or, ``interleaved``, head i reads key head
-        ``i % key heads`` — the other way to lay a group out. The
-        reference scales by the key width, ``scale_dim`` another width's;
-        its values are as wide as its keys, so narrower ones go in under
-        zeros and the output's first ``d_v`` columns come out."""
-        def fn(q, k, v):
-            grouped = (jnp.swapaxes(q[0].reshape(s, groups, kv, d), 1, 2)
-                       if interleaved else q[0].reshape(s, kv, groups, d))
-            padded_v = jnp.pad(v[0], ((0, 0), (0, 0), (0, d - d_v)))
+              logits_dtype=jnp.float32, interleaved=False, scale_dim=d,
+              next_key_head=False):
+        """The plain reference as a function of the same operands, a
+        sequence at a time; the keyword arguments are the ways to compute
+        it wrongly. Its layout is [s, key heads, group, d]: query head i is
+        member ``i % group`` of key head ``i // group``, or,
+        ``interleaved``, head i reads key head ``i % key heads`` — the
+        other way to lay a group out — or, ``next_key_head``, the key head
+        after its own. The reference scales by the key width,
+        ``scale_dim`` another width's; its values are as wide as its keys,
+        so narrower ones go in under zeros and the output's first ``d_v``
+        columns come out."""
+        def one(q, k, v):
+            grouped = (jnp.swapaxes(q.reshape(s, groups, kv, d), 1, 2)
+                       if interleaved else q.reshape(s, kv, groups, d))
+            padded_v = jnp.pad(v, ((0, 0), (0, 0), (0, d - d_v)))
+            if next_key_head:
+                k, padded_v = (jnp.roll(x, -1, axis=1)
+                               for x in (k, padded_v))
             with jax.default_matmul_precision("highest"):
                 out = banded_attention(
                     grouped.astype(dtype) * (d / scale_dim) ** 0.5,
-                    k[0].astype(dtype), padded_v.astype(dtype),
+                    k.astype(dtype), padded_v.astype(dtype),
                     window=window, dtype=dtype,
                     query_block=min(256, s), logits_dtype=logits_dtype)
             if interleaved:
                 out = jnp.swapaxes(out, 1, 2)
-            return out.reshape(1, s, h, d)[..., :d_v]
+            return out.reshape(s, h, d)[..., :d_v]
 
-        return fn
+        return jax.vmap(one)
 
     lowered = jax.jit(lambda q, k, v: attend(q, k, v, case.window)).lower(
         q, k, v).as_text()
@@ -182,6 +194,9 @@ def check(case: Case, seed: int, attend=None) -> dict:
         controls["heads_interleaved"] = plain(interleaved=True)
     if d_v != d:
         controls["scale_of_value_width"] = plain(scale_dim=d_v)
+    if not controls:
+        # neither a band, nor a group, nor a second width to get wrong
+        controls["next_key_head"] = plain(next_key_head=True)
     def readings(fn):
         return dict(zip(TENSORS, map(_relative, run(fn), want)))
 
