@@ -231,7 +231,7 @@ class KimiSparseMoe(nn.Module):
     """Router over ``num_experts`` and its selection bias; the SwiGLU
     experts ``first_expert .. first_expert + num_local_experts - 1`` of
     width ``mlp_dim`` held here; ``shared`` experts' worth of one SwiGLU
-    that every token passes."""
+    that every token passes (0: no such module is built, nothing added)."""
 
     num_experts: int
     num_local_experts: int
@@ -272,6 +272,8 @@ class KimiSparseMoe(nn.Module):
             self.sow("moe_stats", "counts", counts)
         with jax.named_scope(SHARED_SCOPE):
             y = y.reshape(b, s, d)
+            if not self.shared:
+                return (y, load_balance) if self.aux else y
             shared = LlamaMLP(self.shared * m, self.dtype, name="shared")(x)
             if self.shared_gate:
                 shared = shared * jax.nn.sigmoid(nn.Dense(

@@ -88,8 +88,8 @@ _VMEM = pltpu.VMEM
 
 _NEG_INF = -1e30
 
-# The largest block of a windowed call (``_blocks``): a band of a few
-# hundred keys crosses few blocks, and a block computes all its pairs.
+# The largest block under a window shorter than 1024 keys (``_blocks``): such
+# a band crosses few blocks, and a block computes all its pairs.
 WINDOW_BLOCK = 512
 
 # The kernels' names: what a device trace and the ledger's ``device_ops``
@@ -273,28 +273,34 @@ def _blocks(s_q: int, s_k: int, d: int, window: Optional[int] = None):
     1.50 and 0.82, 256 x 256 2.85 and 1.34; 2048 x 2048 does not fit VMEM.
     dQ + dK/dV: 1024 x 1024 2.31 and 1.60, 512 x 512 2.39 and 1.61, 256 x
     256 4.03 and 2.34; 1024 x 2048 does not fit (the backward kernels hold
-    p, dp and ds, three float32 [bq, bk] temporaries). Under a ``window``
-    the blocks are capped at ``WINDOW_BLOCK``: at 64 heads over 8 key heads
-    x 128 x s8192, window 512 (my chip runs, PR 47), forward + backward 512
-    x 512 13.87 ms, 256 x 512 15.90, 512 x 1024 16.60, 1024 x 1024 18.64,
-    five more 19.05-35.66; the forward alone is fastest at 512 x 1024 (4.93
-    against 5.80). Heads 256 wide, 16 over 2 key heads x s8192 | s16384 (my
-    chip runs, PR 50), forward / dQ + dK/dV in ms: 1024 x 1024 5.56 / 14.59
-    | 16.84 / 48.50, the fastest of ten both ways; 512 x 1024 5.91 / 14.81 |
-    17.84 / 49.51, 1024 x 512 6.17 / 14.66 | 19.12 / 50.01, 512 x 512 6.91 /
-    14.70 | 22.16 / 50.92, the six smaller pairs 6.39-12.70 / 15.29-19.37.
-    Keys 192 / values 128, 32 heads (my chip runs, PR 51; the same ten, the
-    call's layout copies in both figures): 1024 x 1024 9.26 / 24.62 | 28.64
-    / 83.06, the fastest again; 512 x 1024 10.43 / 25.26 | 32.89 / 85.77,
-    1024 x 512 14.44 / 25.08 | 49.14 / 85.51, 512 x 512 (this width's until
-    then) 15.04 / 25.83 | 51.76 / 90.51, the six smaller pairs 12.41-27.56 /
+    p, dp and ds, three float32 [bq, bk] temporaries). A ``window`` shorter
+    than that block caps both at ``WINDOW_BLOCK``: at 64 heads over 8 key
+    heads x 128 x s8192, window 512 (my chip runs, PR 47), forward +
+    backward 512 x 512 13.87 ms, 256 x 512 15.90, 512 x 1024 16.60, 1024 x
+    1024 18.64, five more 19.05-35.66; the forward alone is fastest at 512 x
+    1024 (4.93 against 5.80). A window of a whole block or more keeps 1024 x
+    1024: at 2 x 32 heads over 4 key heads x 128 x s8192, window 1024 (my
+    chip runs, PR 58), forward / fused backward in ms: 1024 x 1024 5.90 /
+    11.08, 512 x 1024 6.56 / 11.38, 512 x 512 8.41 / 9.55, 256 x 1024 7.73 /
+    11.11, 256 x 512 9.04 / 11.13, 1024 x 512 9.82 / 11.24 (a step under
+    remat runs the forward twice: 22.89 against 512 x 512's 26.37). Heads
+    256 wide, 16 over 2 key heads x s8192 | s16384 (my chip runs, PR 50),
+    forward / dQ + dK/dV in ms: 1024 x 1024 5.56 / 14.59 | 16.84 / 48.50,
+    the fastest of ten both ways; 512 x 1024 5.91 / 14.81 | 17.84 / 49.51,
+    1024 x 512 6.17 / 14.66 | 19.12 / 50.01, 512 x 512 6.91 / 14.70 | 22.16
+    / 50.92, the six smaller pairs 6.39-12.70 / 15.29-19.37. Keys 192 /
+    values 128, 32 heads (my chip runs, PR 51; the same ten, the call's
+    layout copies in both figures): 1024 x 1024 9.26 / 24.62 | 28.64 /
+    83.06, the fastest again; 512 x 1024 10.43 / 25.26 | 32.89 / 85.77, 1024
+    x 512 14.44 / 25.08 | 49.14 / 85.51, 512 x 512 (this width's until then)
+    15.04 / 25.83 | 51.76 / 90.51, the six smaller pairs 12.41-27.56 /
     26.40-43.89. One rule for four widths. The fused backward
     (``backward_form``) was swept at three shapes over four pairs (PR 57,
     its docstring): 1024 x 1024 again, so forward, fused backward and pair
     share one rule."""
     largest = 1024
-    if window is not None:
-        largest = min(largest, WINDOW_BLOCK)
+    if window is not None and window < largest:
+        largest = WINDOW_BLOCK
     return _block(s_q, largest), _block(s_k, largest)
 
 

@@ -18,6 +18,11 @@ but the assignments sorted by expert and three grouped matmuls over the
 sorted rows. A device holds all experts or a contiguous share of them
 (``first_expert``): it routes over all, computes its own experts' part of
 the result and leaves the rest out; it has no ``ep_axis`` (no exchange) yet.
+The shares that run today (``benchmark/configs``): 8 of 256 at top-8
+(Kimi-Linear, JoyAI), 16 of 128 (Keye) and 16 of 256 (Laguna) at top-8, 32
+of 512 at top-10 (Qwen3-Next), 8 of 16 at top-1 (ZAYA1) and 16 of 64 at
+top-8 (Mellum2: a quarter of the experts, two held assignments a token, a
+pass of half of all T k rows).
 """
 
 from __future__ import annotations
@@ -230,7 +235,9 @@ def held_row_bound(t: int, top_k: int, held: int, e: int) -> int:
     """The rows a share of ``held`` of ``e`` experts works on in one pass:
     twice the even part of the assignments that reach it, in 512s, and at
     most all T k. 4,096 of 65,536 for 8 of 256 experts, 16,384 for 16 of
-    128; every row where half the experts or more are held."""
+    128; half of all rows for 16 of 64 at top-8, two held assignments a
+    token (65,536 of 131,072 at 16,384 tokens, 131,072 of 262,144 at
+    32,768); every row where half the experts or more are held."""
     rows = t * top_k
     room = -(-HELD_ROWS_OVER_EVEN * rows * held // e)
     return min(rows, -(-room // HELD_ROWS_MULTIPLE) * HELD_ROWS_MULTIPLE)

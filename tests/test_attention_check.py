@@ -37,6 +37,12 @@ def _interpreted(q, k, v, window, scale=None):
     (("latent", 96, 4, 4, 24, None, 16), {"scale_of_value_width"}),
     # three sequences in the call, as many key heads as query heads
     (("dense", 96, 3, 3, 16, None, None, 3), {"next_key_head"}),
+    # the Mellum2 cell's: 4 query heads a key head, two sequences, a band
+    # of two of the kernel's key blocks
+    (("windowed_1024", 160, 8, 2, 16, 128, None, 2), {
+        "window_minus_1", "window_plus_1", "heads_interleaved"}),
+    (("global_32_over_4", 96, 8, 2, 16, None, None, 2),
+     {"heads_interleaved"}),
 ], ids=lambda value: value[0] if isinstance(value, tuple) else "")
 def test_the_check_passes_the_kernel_and_fails_its_controls(
         tool, case, controls):
@@ -105,6 +111,21 @@ def test_the_cell_cases_are_the_configurations(tool):
         assert case.heads == kinds[
             "sliding_attention" if windowed else "full_attention"]
         assert case.window == (cfg["sliding_window"] if windowed else None)
+
+
+def test_the_mellum_cases_are_the_configuration_s(tool):
+    cfg = _config("mellum2-12b-a2.5b")
+    windowed, full = (c for c in tool.CELL_CASES
+                      if c.name in ("windowed_1024", "global_32_over_4"))
+    for case in (windowed, full):
+        assert (case.batch, case.seq) == (cfg["batch_per_chip"],
+                                          cfg["seq_len"])
+        assert (case.heads, case.kv_heads, case.head_dim) == (
+            cfg["num_attention_heads"], cfg["num_key_value_heads"],
+            cfg["head_dim"])
+        assert case.value_dim is None
+    assert (windowed.window, full.window) == (cfg["sliding_window"], None)
+    assert set(cfg["layer_types"]) == {"sliding_attention", "full_attention"}
 
 
 def test_the_dense_case_is_the_gpt2_configuration(tool):

@@ -53,6 +53,11 @@ mixer reads 0.66-0.96% (bf16 projections, bf16 q^ and k^ into the kernels),
 the bf16 normalisation 2.6-4.0% in every tensor and the four others 31-298%
 (my chip runs, PR 55: PERF.md section 6 has every reading).
 
+Since PR 58 two cases more are the Mellum2 cell's calls (``windowed_1024``,
+``global_32_over_4``): 32 query heads over 4 key heads of 128, the cell's
+two sequences of 8,192 in one call, under a window of 1024 and under the
+causal triangle; ``--cases a,b`` runs the named cases alone.
+
 One JSON line a case, then ``{"ok": ..., "device": ...}``; off the chip the
 kernels are interpreted at a small size (``tests/test_attention_check.py``).
 """
@@ -98,7 +103,11 @@ CELL_CASES = (Case("windowed", 8192, 64, 8, 128, 512),
               Case("latent", 8192, 32, 32, 192, None, 128),
               # the GPT-2 cells' call (gpt2-124m.json): the width the most
               # cells share, eight sequences in one call
-              Case("dense", 1024, 12, 12, 64, None, batch=8))
+              Case("dense", 1024, 12, 12, 64, None, batch=8),
+              # the Mellum2 cell's two calls (mellum2-12b-a2.5b.json): 32
+              # query heads over 4 key heads, two sequences in one call
+              Case("windowed_1024", 8192, 32, 4, 128, 1024, batch=2),
+              Case("global_32_over_4", 8192, 32, 4, 128, None, batch=2))
 
 
 def _relative(got, want) -> float:
@@ -290,17 +299,22 @@ def check_cca(seed: int, size: Mixer = CCA_CELL, dtype=None) -> dict:
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--cases", default="", help="comma-separated names of "
+                    "CELL_CASES (and 'cca'); empty: all of them")
     args = ap.parse_args()
+    wanted = set(filter(None, args.cases.split(",")))
 
     import jax
 
     device = jax.devices()[0]
     ok = device.platform == "tpu"        # never a CPU's figures by mistake
     for case in CELL_CASES if ok else ():
+        if wanted and case.name not in wanted:
+            continue
         record = check(case, args.seed)
         ok = ok and record["ok"] and record["kernel_in_program"]
         print(json.dumps(record), flush=True)
-    if ok:
+    if ok and (not wanted or "cca" in wanted):
         record = check_cca(args.seed)
         ok = record["ok"] and record["kernel_in_program"]
         print(json.dumps(record), flush=True)
