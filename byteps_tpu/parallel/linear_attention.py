@@ -69,36 +69,47 @@ no longer turns on the channel, and
     P(a, b)[i, j] = (a_i . b_j) e^{G_i - G_j}                 (j <= i)
 
 is one ``[C, d_k] x [d_k, C]`` product under a ``[C, C]`` mask of
-exponentials, ``K e^G`` a scaling of rows, ``e^{G_C}`` one float a head
-(``_head_operands``; form ``"head"``, every backend and dtype). No
+exponentials, ``K e^G`` a scaling of rows, ``e^{G_C}`` one float a head. No
 sub-chunk is needed for the rule above: ``G_i - G_j`` is formed for the
 pair itself, masked to ``-inf`` above the diagonal before the ``exp``, and
-never clamped. It is XLA's to schedule; the decay broadcast over the channels
-into the kernel pair instead (16 MB of float32 decay a layer and 1000 tokens)
-moved no step at s 8,192 and has none at 16,384, where the step does not
-compile with it (PERF.md section 6, PR 50). Chunking, the triangular solve, the groups and
-``_recurrence`` are the per-channel form's own. ``q`` and ``k`` may have
-fewer heads than ``v``, a divisor: key head j serves value heads ``j
-groups .. (j + 1) groups - 1``, and the per-head form multiplies each key
-head's pairs once.
+never clamped. ``q`` and ``k`` may have fewer heads than ``v``, a divisor:
+key head j serves value heads ``j groups .. (j + 1) groups - 1``, and a key
+head's pairs are multiplied once. This rank has two forms too, under the
+per-channel forms' rule of the shapes (``kda_form``). ``_head_operands``
+(form ``"head"``: every backend, dtype and shape) builds a group's operands
+in XLA, ``HEAD_GROUP`` chunks at a time, with chunking, the triangular
+solve by doubling, the groups and ``_recurrence`` the per-channel XLA
+form's own. On a ``tpu`` backend at the kernel shapes (form
+``"head_kernel"``, since PR 59) the kernel pair of
+``byteps_tpu.ops.gdn_chunk`` holds a chunk of all heads in VMEM: the pair
+products once a key head on the MXU in three bf16 passes (the chip's
+``EXACT``), the chunk's decay cumulated there, the triangular system by
+forward substitution in float32 and, in a hand-written backward kernel that
+keeps nothing of the forward, the transposed system by back substitution —
+and leaves ``W``, ``U_v``, ``Q e^G``, ``K e^{G_C - G}``, ``e^{G_C}`` and
+the pairs ``[b, n, C, h, d]`` in the dtypes the recurrence kernels read.
+The decay broadcast over the channels into the per-channel kernels instead
+(16 MB of float32 decay a layer and 1000 tokens) had moved no step at s
+8,192 and did not compile in the step at 16,384 (PERF.md section 6, PR 50).
 
 **The scan over chunks has two forms too, and the operands' form picks
-it.** Where XLA builds the operands (the XLA form and the per-head form)
-the scan has two levels, groups of chunks and the chunks of a group, and
-its backward pass is ``jax.grad`` through both, a group recomputed at a time
+it.** Where XLA builds the operands (``"xla"`` and ``"head"``) the scan has
+two levels, groups of chunks and the chunks of a group, and its backward
+pass is ``jax.grad`` through both, a group recomputed at a time
 (``jax.checkpoint``): the scan keeps one state a group, and a group's
-operands are computed inside the group (the XLA form's pair tensor bounds
-the group, the per-head form's is ``HEAD_GROUP`` chunks). ``_recurrence``
-there is an XLA ``while`` iteration of four small products a chunk, the
-float32 state (2 MB at 32 heads) through HBM around each. A hand-written
-backward of it *in XLA* was measured against ``jax.grad`` and lost (PERF.md
-section 6, PR 39) while the operands were most of the scan; since their
-kernel (PR 40) the recurrence, the slices and copies of the ``while`` over
-groups and the copy that laid the operands out chunk-major for it were the
-larger half of the kernel form's scan, bound by nothing the chip has.
+operands are computed inside the group (the per-channel form's pair tensor
+bounds the group, the per-head form's is ``HEAD_GROUP`` chunks).
+``_recurrence`` there is an XLA ``while`` iteration of four small products a
+chunk, the float32 state (2 MB at 32 heads) through HBM around each. A
+hand-written backward of it *in XLA* was measured against ``jax.grad`` and
+lost (PERF.md section 6, PR 39) while the operands were most of the scan;
+since their kernel (PR 40) the recurrence, the slices and copies of the
+``while`` over groups and the copy that laid the operands out chunk-major
+for it were the larger half of the kernel form's scan, bound by nothing the
+chip has.
 
-The kernel form computes every chunk's operands in one call before the
-scan and leaves them ``[b, n, C, h, d]``, and since PR 54 the kernel pair of
+The kernel forms compute every chunk's operands in one call before the
+scan and leave them ``[b, n, C, h, d]``, and since PR 54 the kernel pair of
 ``byteps_tpu.ops.kda_recurrence`` runs all chunks as one call over them as
 they lie, the state in VMEM from the first chunk to the last: no scan over
 groups, no layout copy (PERF.md section 6, PR 54). Its backward is
@@ -108,11 +119,16 @@ such group forward again, leaving its states in VMEM, and back, carrying
 ``dS`` from group to group. Same guarantees: the carried state float32 and
 never rounded, the state and ``U`` rounded to ``dtype`` as matmul operands
 only, float32 accumulation. It is the layout that decides: the per-head
-form's operands come out of XLA a few chunks at a time with heads before
-tokens, fused into ``_recurrence``'s products; laid out for a call of the
-pair they were measured at 4 to 32 chunks a call and did not beat it (the
-pair ahead for the recurrence alone, behind in the whole op by the layout
-copies), so that form keeps ``_recurrence``.
+operands as XLA builds them come a few chunks at a time with heads before
+tokens, and laid out anew for a call of the pair at 4 to 32 chunks they did
+not beat ``_recurrence`` (the pair ahead for the recurrence alone, behind in
+the whole op by the layout copies: PR 54); the per-head kernels write the
+pair's own layout. One call's operands and, in the backward pass, their
+cotangents are alive at once, where the XLA forms hold a few chunks': 0.7
+GB and as much again a layer at the Qwen3-Next cell's 512 chunks, which the
+compiled step holds within the XLA form's peak. Cutting the sequence into
+calls with the state handed on was measured and made both worse (PERF.md
+section 6, PR 59): there is one call.
 """
 
 from __future__ import annotations
@@ -136,8 +152,12 @@ SCAN_SITES = "bps_kda_scan_sites_total"
 KERNEL_SITES = "bps_kda_kernel_sites_total"
 # ... or the per-head form
 HEAD_SITES = "bps_kda_head_sites_total"
+# ... and of those, the ones whose operands are the kernel pair of
+# ``byteps_tpu.ops.gdn_chunk``
+HEAD_KERNEL_SITES = "bps_kda_head_kernel_sites_total"
 # ... and of all scan sites, those whose scan over chunks is the kernel pair
-# of ``byteps_tpu.ops.kda_recurrence`` (today the kernel form's)
+# of ``byteps_tpu.ops.kda_recurrence`` (both kernel forms': it is their
+# layout it reads)
 RECURRENCE_KERNEL_SITES = "bps_kda_recurrence_kernel_sites_total"
 
 # What the kernel of ``byteps_tpu.ops.kda_chunk`` was measured at against
@@ -146,6 +166,17 @@ RECURRENCE_KERNEL_SITES = "bps_kda_recurrence_kernel_sites_total"
 # Its tiling admits any chunk of whole sublane groups (a multiple of 8) up
 # to a row of lanes (128), and heads in whole sublane groups.
 KERNEL_WIDTH = 128
+
+# The per-head kernels of ``byteps_tpu.ops.gdn_chunk`` hand the compiler
+# every row and pair of a chunk as straight-line code, about C^2 / 2 pairs a
+# walk, and ask of VMEM by the rows (tokens x value heads) a chunk holds.
+# Chunks of 32 are what the benchmark runs and what was measured; at 64 the
+# backward kernel took 98 s to compile for a v5e (21 s at 32) and minutes
+# interpreted, for a chunk no configuration uses: the XLA form keeps it.
+# 1024 rows a chunk are the cell's 32 x 32, which ask 32 MiB of VMEM: the
+# most that ran on the chip, and with 64 heads x 16 and 128 x 8 the edges
+# that tests/test_chip_compile.py compiles for a v5e.
+HEAD_KERNEL_CHUNK, HEAD_KERNEL_ROWS = 32, 1024
 
 # The chunks of a group in the per-head form, whose operands are computed
 # (and, in the backward pass, recomputed and differentiated) a group at a
@@ -282,24 +313,36 @@ def _head_operands(q, k, v, beta, G, dtype):
 
 
 def kda_form(backend: str, heads: int, d_k: int, d_v: int, dtype,
-             chunk: int, per_head: bool = False) -> str:
-    """``"head"``, ``"kernel"`` or ``"xla"``: how ``kda_attention`` computes
-    a chunk's operands at these shapes, and with them how it scans the
-    chunks. ``per_head`` (the decay is one number a head): the per-head
-    form, wherever it runs. One decay a channel: one algorithm, two forms:
-    the XLA form writes float32 ``[.., sub, sub, d_k]`` pairs and ``[.., n,
-    C, d_k]`` decayed keys to HBM and reads them back, the kernel (TPU only)
-    holds a chunk in VMEM and leaves every chunk's operands ``[b, n, C, h,
-    d]``, where the recurrence kernels scan them as they lie. ``"head"``
-    and ``"xla"`` scan with ``_recurrence`` under a scan over groups
-    (module docstring)."""
-    if per_head:
-        return "head"
+             chunk: int, per_head: bool, key_heads: int) -> str:
+    """``"head"``, ``"head_kernel"``, ``"kernel"`` or ``"xla"``: how
+    ``kda_attention`` computes a chunk's operands at these shapes, and with
+    them how it scans the chunks. One algorithm; a decay of either rank has
+    an XLA form for every backend, dtype and shape (``"head"`` where
+    ``per_head``, the decay one number a head, else ``"xla"``) and a kernel
+    form (``"head_kernel"``, ``"kernel"``) under one rule of the shapes: a
+    ``tpu`` backend, bf16 operands, keys and values ``KERNEL_WIDTH`` wide,
+    the heads a token's tile puts on sublanes in whole sublane groups (the
+    value heads; the per-head kernel tiles by the ``key_heads`` under them
+    too) and a chunk the tiling admits (a multiple of
+    8 up to 128; the per-head kernels up to ``HEAD_KERNEL_CHUNK``, at most
+    ``HEAD_KERNEL_ROWS`` tokens x heads a chunk). The XLA forms
+    write their pairs and decayed keys to HBM, a few chunks at a time, and
+    scan with ``_recurrence`` under a scan over groups; the kernel forms
+    hold a chunk in VMEM and leave every chunk's operands ``[b, n, C, h,
+    d]``, where the recurrence kernels scan them as they lie (module
+    docstring)."""
+    xla = "head" if per_head else "xla"
     if backend != "tpu" or jnp.dtype(dtype) != jnp.bfloat16:
-        return "xla"
+        return xla
     if (d_k, d_v) != (KERNEL_WIDTH, KERNEL_WIDTH) or heads % 8:
-        return "xla"
-    return "kernel" if chunk % 8 == 0 and chunk <= 128 else "xla"
+        return xla
+    if chunk % 8 or chunk > 128:
+        return xla
+    if not per_head:
+        return "kernel"
+    if key_heads % 8 or chunk > HEAD_KERNEL_CHUNK:
+        return xla
+    return "head_kernel" if chunk * heads <= HEAD_KERNEL_ROWS else xla
 
 
 def _divisor(n: int, most: int) -> int:
@@ -355,18 +398,24 @@ def kda_attention(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
     s = q.shape[1]
     metrics.inc_counter(SCAN_SITES)
     form = kda_form(jax.default_backend(), h, q.shape[3], v.shape[3], dtype,
-                    chunk, per_head)
-    kernel = form == "kernel"
+                    chunk, per_head, q.shape[2])
+    kernel = form in ("kernel", "head_kernel")
     if kernel:
         # imported here: a process that never reaches this line (every
         # other model, any CPU run) pays for no kernel library
         # (tests/test_import_footprint.py)
-        from byteps_tpu.ops.kda_chunk import chunk_operands
         from byteps_tpu.ops.kda_recurrence import recurrence
 
-        metrics.inc_counter(KERNEL_SITES)
+        if per_head:
+            from byteps_tpu.ops.gdn_chunk import head_operands
+
+            metrics.inc_counter(HEAD_KERNEL_SITES)
+        else:
+            from byteps_tpu.ops.kda_chunk import chunk_operands
+
+            metrics.inc_counter(KERNEL_SITES)
         metrics.inc_counter(RECURRENCE_KERNEL_SITES)
-    if form == "head":
+    if per_head:
         metrics.inc_counter(HEAD_SITES)
     elif q.shape[2] != h:
         # one decay a channel knows no groups: a gather XLA fuses
@@ -387,12 +436,16 @@ def kda_attention(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
             # The recurrence kernels read them where they lie, all chunks in
             # one call, and keep a state every 16 chunks for their backward
             # pass themselves: nothing is sliced, laid out anew or copied on
-            # its way to a group (PERF.md section 6, PR 54). They round the
-            # pairs [.., C, h, C] to ``dtype`` ahead of the call: a row of
-            # 32 fills a quarter of its lanes, so in float32 they are 134 MB
-            # a layer at 256 chunks, and their gradient as much.
-            o = recurrence(jnp.zeros(state, f32), *chunk_operands(
-                *tokens, chunked(g.astype(f32), chunk), sub, dtype), dtype)[1]
+            # its way to a group (PERF.md section 6, PR 54 and PR 59). They
+            # round the pairs [.., C, h, C] to ``dtype`` ahead of the call
+            # (the per-head kernels write them so): a row of 32 fills a
+            # quarter of its lanes, so in float32 they are 134 MB a layer at
+            # 256 chunks, and their gradient as much.
+            zero = jnp.zeros(state, f32)
+            raw = chunked(g.astype(f32), chunk)
+            operands = (head_operands(*tokens, raw, dtype) if per_head
+                        else chunk_operands(*tokens, raw, sub, dtype))
+            o = recurrence(zero, *operands, dtype)[1]
             return o.reshape(b, n * chunk, h, -1)[:, :s]
         # Two levels: groups of chunks, one at a time and recomputed in the
         # backward pass, and the chunks of a group: the scan keeps one state

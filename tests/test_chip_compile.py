@@ -198,6 +198,54 @@ def test_kda_operand_kernels_compile_for_v5e(topo, with_grads):
     assert text.count("tpu_custom_call") == (2 if with_grads else 1)
 
 
+# chunks, chunk, key heads, value heads
+GDN_CASES = {
+    # the Gated DeltaNet layers of the Qwen3-Next cell: b 1 x s 16384
+    "qwen3_next_512x32_h16_32": (512, 32, 16, 32),
+    # the edges of ``kda_form``'s rule for the per-head kernels, whose ask
+    # of VMEM goes by the rows (tokens x value heads) a chunk holds:
+    # ``HEAD_KERNEL_ROWS`` of them in shorter chunks of more heads, and the
+    # least the tiling admits
+    "rows_1024_as_16_h32_64": (4, 16, 32, 64),
+    "rows_1024_as_8_h64_128": (4, 8, 64, 128),
+    "least_8_h8_8": (4, 8, 8, 8),
+}
+
+
+@pytest.mark.parametrize("with_grads", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("case", sorted(GDN_CASES))
+def test_gdn_operand_kernels_compile_for_v5e(topo, case, with_grads):
+    """One decay a head, keys and values 128 wide, bf16 operands out as the
+    recurrence kernels read them, one call over all chunks — the forward
+    kernel, and through ``jax.grad`` the hand-written backward kernel, which
+    keeps nothing of the forward. Every case is one the rule admits."""
+    from byteps_tpu.ops.gdn_chunk import BWD_NAME, FWD_NAME, head_operands
+    from byteps_tpu.parallel.linear_attention import kda_form
+
+    chunks, chunk, key_heads, heads = GDN_CASES[case]
+    assert kda_form("tpu", heads, 128, 128, jnp.bfloat16, chunk, True,
+                    key_heads) == "head_kernel"
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(*shape):
+        return jax.ShapeDtypeStruct((1, chunks, chunk) + shape, jnp.float32,
+                                    sharding=one)
+
+    shapes = (described(key_heads, 128), described(key_heads, 128),
+              described(heads, 128), described(heads), described(heads))
+
+    def fwd(*args):
+        return head_operands(*args, jnp.bfloat16, False)
+
+    def loss(*args):
+        return sum((o.astype(jnp.float32) ** 2).sum() for o in fwd(*args))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if with_grads else fwd
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert text.count("tpu_custom_call") == (2 if with_grads else 1)
+    assert FWD_NAME in text and (BWD_NAME in text) == with_grads
+
+
 @pytest.mark.parametrize("with_grads", [False, True], ids=["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("chunks", [256, 512])
 def test_kda_recurrence_kernels_compile_for_v5e(topo, chunks, with_grads):
@@ -305,18 +353,26 @@ def _described(mesh, tree, spec):
 
 @pytest.fixture
 def as_on_a_tpu(monkeypatch):
-    """``full_attention`` picks its form by the backend it runs on, and the
-    kernel interprets itself off a TPU: here both are told the described
-    chip's answer."""
+    """``full_attention`` and ``kda_attention`` pick their forms by the
+    backend they run on, and a kernel interprets itself off a TPU: here all
+    three are told the described chip's answer."""
     import importlib
 
     # the package exports functions under both modules' names
     fa = importlib.import_module("byteps_tpu.ops.flash_attention")
     ra = importlib.import_module("byteps_tpu.parallel.ring_attention")
-    rule = ra.attention_form
+    la = importlib.import_module("byteps_tpu.parallel.linear_attention")
+    rule, scan_rule = ra.attention_form, la.kda_form
     monkeypatch.setattr(ra, "attention_form",
                         lambda backend, *rest: rule("tpu", *rest))
-    monkeypatch.setattr(fa, "_resolve_interpret", lambda interpret: False)
+    monkeypatch.setattr(la, "kda_form",
+                        lambda backend, *rest: scan_rule("tpu", *rest))
+    # ... in every module that bound the name when it was imported
+    for ops in (fa, *(importlib.import_module(f"byteps_tpu.ops.{name}")
+                      for name in ("kda_chunk", "kda_recurrence",
+                                   "gdn_chunk"))):
+        monkeypatch.setattr(ops, "_resolve_interpret",
+                            lambda interpret: False)
 
 
 def test_rotary_latent_attention_compiles_for_v5e(topo, as_on_a_tpu):
@@ -345,14 +401,15 @@ def test_rotary_latent_attention_compiles_for_v5e(topo, as_on_a_tpu):
 def test_qwen3_next_mixers_compile_for_v5e(topo, as_on_a_tpu, kind):
     """The two mixers of the Qwen3-Next cell, forward and backward, b 1 x s
     16384 at the published widths: Gated DeltaNet (16 key heads under 32
-    value heads of 128; the per-head form is XLA's to compile, no kernel)
+    value heads of 128, chunks of 32: since PR 59 the per-head operand
+    kernels and the recurrence kernels, one call over the 512 chunks each)
     and gated attention (16 query heads over 2 key heads of 256 feeding the
     flash kernels at their fourth width, with the blocks ``_blocks`` derives
     for it)."""
     from byteps_tpu.models.qwen3_next import GatedAttention, GatedDeltaNet
 
     one = SingleDeviceSharding(topo.devices[0])
-    layer = (GatedDeltaNet(16, 32, 128, 128) if kind == "gdn"
+    layer = (GatedDeltaNet(16, 32, 128, 128, chunk=32) if kind == "gdn"
              else GatedAttention(16, 2, 256, 1e7, 0.25))
     x = jax.ShapeDtypeStruct((1, 16384, 2048), jnp.float32, sharding=one)
     params = jax.tree_util.tree_map(
@@ -363,13 +420,62 @@ def test_qwen3_next_mixers_compile_for_v5e(topo, as_on_a_tpu, kind):
         lambda p, x: layer.apply(p, x).astype(jnp.float32).sum(),
         argnums=(0, 1))).lower(params, x).compile().as_text()
     if kind == "gdn":
-        assert "tpu_custom_call" not in text
+        # operands and recurrence, forward (saving) and backward
+        assert text.count("tpu_custom_call") == 4
+        for name in ("bps_gdn_operands_fwd", "bps_gdn_operands_bwd",
+                     "bps_kda_recurrence_fwd", "bps_kda_recurrence_bwd"):
+            assert name in text
+        assert "while(" not in text         # no scan over groups is left
         for scope in ("bps.gdn.prep", "bps.gdn.scan", "bps.gdn.out"):
             assert scope in text
     else:
         assert text.count("tpu_custom_call") >= 2      # forward, backward
         assert "bps_flash_bwd" in text
         assert "bps.gattn.proj" in text and "bps.gattn.attend" in text
+
+
+def test_kimi_linear_mixer_lowers_for_v5e_to_what_it_did(topo, as_on_a_tpu,
+                                                         monkeypatch):
+    """PR 59 gave ``kda_form`` a fourth answer and ``kda_attention`` a
+    second kernel branch. A KDA mixer of the Kimi-Linear cell (one decay a
+    channel: 32 heads of 128, chunks of 32 in sub-chunks of 8, b 1 x s
+    8192), forward and backward, lowers for the described chip to the text
+    it lowered to at ``b4fce99``, the parent of PR 59 — with the source
+    positions stripped from the kernels' serialized bodies, which carry the
+    file and line of every frame under a ``pallas_call`` and change with
+    any line added above one (``.claude/skills/verify/SKILL.md``)."""
+    import hashlib
+    import types
+
+    from jax._src import tpu_custom_call
+    from jaxlib.mlir.passmanager import PassManager
+
+    from byteps_tpu.models.kimi_linear import KimiDeltaAttention
+
+    lower = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def stripped(module, **kwargs):
+        with module.context:
+            op = module.operation.clone()
+            PassManager.parse("builtin.module(strip-debuginfo)").run(op)
+        return lower(types.SimpleNamespace(context=module.context,
+                                           operation=op), **kwargs)
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm",
+                        stripped)
+    one = SingleDeviceSharding(topo.devices[0])
+    layer = KimiDeltaAttention(32, 128, 128, 4, 32, 8)
+    x = jax.ShapeDtypeStruct((1, 8192, 2304), jnp.float32, sharding=one)
+    params = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8, 2304))))
+    text = jax.jit(jax.grad(
+        lambda p, x: layer.apply(p, x).astype(jnp.float32).sum(),
+        argnums=(0, 1))).lower(params, x).as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "b577b795545004973efe5fbf632858acd30ca19bb923290636d5f2ee6356b1e3")
 
 
 def test_zaya_mixer_compiles_for_v5e(topo, as_on_a_tpu):

@@ -44,8 +44,10 @@ def _highest():
     (("cpu", 3, 8, 6, F32, 16), "xla"),            # the CPU tests' shapes
 ])
 def test_the_rule_is_a_pure_function_of_backend_and_shapes(args, form):
-    assert kda_form(*args) == form
-    assert kda_form(*args[:4], np.dtype(args[4]), args[5]) == form
+    # one decay a channel, as many key heads as value heads
+    assert kda_form(*args, False, args[1]) == form
+    assert kda_form(*args[:4], np.dtype(args[4]), args[5], False,
+                    args[1]) == form
 
 
 def _inputs(s, b=1, h=8, d_k=8, d_v=6, strength=1.0, seed=0):

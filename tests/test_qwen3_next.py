@@ -178,16 +178,20 @@ def test_shapes_are_checked():
 
 
 @pytest.mark.parametrize("args,form", [
-    # one decay a head: the per-head form wherever it runs
-    (("tpu", 32, 128, 128, jnp.bfloat16, 64, True), "head"),
-    (("cpu", 32, 128, 128, jnp.float32, 64, True), "head"),
-    (("tpu", 4, 16, 16, jnp.bfloat16, 8, True), "head"),
+    # one decay a head: the per-head form wherever it runs, since PR 59 as
+    # kernels under the per-channel kernels' rule of the shapes (the table
+    # of its refusals is tests/test_gdn_kernel.py's)
+    (("tpu", 32, 128, 128, jnp.bfloat16, 64, True, 32), "head"),
+    (("tpu", 32, 128, 128, jnp.bfloat16, 32, True, 16), "head_kernel"),
+    (("tpu", 32, 128, 128, jnp.float32, 32, True, 16), "head"),
+    (("cpu", 32, 128, 128, jnp.float32, 64, True, 32), "head"),
+    (("tpu", 4, 16, 16, jnp.bfloat16, 8, True, 4), "head"),
     # one a channel: what it was (the Kimi-Linear cell's shapes first)
-    (("tpu", 32, 128, 128, jnp.bfloat16, 32, False), "kernel"),
-    (("tpu", 32, 128, 128, jnp.bfloat16, 32), "kernel"),
-    (("cpu", 32, 128, 128, jnp.bfloat16, 32), "xla"),
-    (("tpu", 32, 128, 128, jnp.float32, 32), "xla"),
-    (("tpu", 32, 64, 64, jnp.bfloat16, 32), "xla"),
+    (("tpu", 32, 128, 128, jnp.bfloat16, 32, False, 32), "kernel"),
+    (("tpu", 32, 128, 128, jnp.bfloat16, 32, False, 8), "kernel"),
+    (("cpu", 32, 128, 128, jnp.bfloat16, 32, False, 32), "xla"),
+    (("tpu", 32, 128, 128, jnp.float32, 32, False, 32), "xla"),
+    (("tpu", 32, 64, 64, jnp.bfloat16, 32, False, 32), "xla"),
 ])
 def test_the_form_is_a_pure_function_of_backend_and_shapes(args, form):
     assert kda_form(*args) == form

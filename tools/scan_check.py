@@ -12,7 +12,9 @@ here, on the device, for the compiled programs the cell runs.
 
 **The scan**: ``kda_attention`` with one decay a head, b 1 x s of the cell,
 16 key heads of 128 under 32 value heads of 128, bf16 operands, the file's
-chunk — ``o`` and the gradients of q, k, v, g and beta against
+chunk (on the chip, since PR 59, the operand kernels of ``ops/gdn_chunk.py``
+under the recurrence kernels: whatever ``kda_form`` picks is what is held)
+— ``o`` and the gradients of q, k, v, g and beta against
 ``benchmark/lib/plain_qwen3_next.py::gated_delta_rule``, the recurrence
 token by token in float32 with nothing of ``byteps_tpu`` in it. q and k are
 unit vectors (q times 128^-1/2), v and the cotangent standard normal, beta a
