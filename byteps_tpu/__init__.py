@@ -17,8 +17,9 @@ Layout (capability parity with the reference's layer map, SURVEY.md §1):
                               codecs) + ctypes bindings (core/ffi.py).
 - ``byteps_tpu.jax``        — the flagship JAX plugin (init/push_pull/
                               DistributedOptimizer/broadcast_parameters,
-                              collective + PS modes, per-layer overlap,
-                              sync/async/flax/haiku step builders).
+                              collective + PS modes, the serial and the
+                              bucketed PS step, sync/async/flax/haiku
+                              step builders).
 - ``byteps_tpu.torch`` / ``.tensorflow`` / ``.keras`` / ``.mxnet`` —
                               Horovod-compatible framework plugins.
 - ``byteps_tpu.parallel``   — mesh construction, hierarchical DP (+ int8

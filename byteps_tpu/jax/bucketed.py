@@ -1,11 +1,11 @@
-"""Bucketed multi-program overlap for PS-mode training — no host callbacks.
+"""Bucketed multi-program overlap for PS-mode training.
 
-SURVEY.md §7 hard part #1 names three designs for recovering the
-reference's hook-style push streaming (byteps/torch/__init__.py
-_make_hook) in JAX: custom_vjp taps (``overlap.py``, host callbacks
-inside the jitted backward), donated double-buffers, or **multi-program
-stepping**. This module is the third, and holds what is its own: the
-buckets and one gradient program per bucket.
+The reference's torch plugin starts a gradient's push the moment backward
+has produced it (byteps/torch/__init__.py _make_hook; SURVEY.md §7 hard
+part #1). JAX has no hooks: a jitted ``value_and_grad`` hands over all
+gradients at its end. This module recovers the overlap by **multi-program
+stepping**, and holds what is its own: the buckets and one gradient program
+per bucket.
 
 * The parameter tree is split into K contiguous, byte-balanced
   **buckets** (model order; processed in reverse = backward order, the
@@ -18,7 +18,7 @@ buckets and one gradient program per bucket.
   the verbatim overlap contract of the reference's per-parameter hooks,
   with programs playing hooks. The price is recomputation (K forwards +
   progressively deeper partial backwards), which pays off only where the
-  device↔host boundary dominates the step; it needs no host callbacks.
+  device↔host boundary dominates the step.
 
 How a leaf crosses the host boundary is ``ps.py``'s: the tree is bound to
 the wire once (``ps.bind``), each finished program's leaves are handed to

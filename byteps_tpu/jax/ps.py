@@ -36,10 +36,10 @@ import byteps_tpu.jax as bps
 # capture's own clock; free while no capture runs). This table is the one
 # place that names them: docs/timeline.md and benchmark/layers/bridge.py
 # mirror it and tests/test_ps_spans.py compares the three. The three
-# ``bps.step.*`` are written on the caller's thread by every PS step builder
-# (training.py's serial step, bucketed.py, overlap.py), with one meaning:
-SPAN_STEP_GRAD = "bps.step.grad"    # the gradient program(s): dispatch (the
-#                                     taps step stays for the whole program)
+# ``bps.step.*`` are written on the caller's thread by both PS step builders
+# (training.py's serial step, bucketed.py), with one meaning; the async step
+# and ``ps_push_pull`` called alone write only the binding's ``bps.ps.*``:
+SPAN_STEP_GRAD = "bps.step.grad"    # the gradient program(s): dispatch
 SPAN_STEP_PS = "bps.step.ps"        # the leg as the caller sees it, to the
 #                                     summed tree on its way back; stat mono_ns
 SPAN_STEP_APPLY = "bps.step.apply"  # dispatch of the apply program
@@ -50,18 +50,15 @@ SPAN_WAIT = "bps.ps.wait"           # per leaf: settle, device_put; to the last 
 SPAN_H2D = "bps.ps.h2d"             # last device_put + reshape/astype dispatch
 SPANS = (SPAN_STEP_GRAD, SPAN_STEP_PS, SPAN_STEP_APPLY, SPAN_PUSH_PULL,
          SPAN_D2H, SPAN_STAGE, SPAN_WAIT, SPAN_H2D)
-# The taps' bps.ps.stage, under a name of its own: it runs on the runtime's
-# callback threads, once a shard and not once a tree.
-SPAN_TAP_PUSH = "bps.tap.push"      # overlap.py: copy a shard, enqueue it
-ALL_SPANS = SPANS + (SPAN_TAP_PUSH,)  # every name docs/timeline.md documents
 
 
 def step_ps_span():
-    """``bps.step.ps`` as every PS step builder opens it, on the caller's
+    """``bps.step.ps`` as both PS step builders open it, on the caller's
     thread: stat ``mono_ns`` is CLOCK_MONOTONIC, the C core's ``NowUs()``
     clock, read at the span's start, so (``mono_ns`` - the event's ts) maps
     the core's stamps onto the capture's clock (utils/timeline.py,
-    docs/timeline.md) in the two designs that write no ``bps.ps.push_pull``."""
+    docs/timeline.md) in the bucketed step too, which writes no
+    ``bps.ps.push_pull``."""
     return jax.profiler.TraceAnnotation(SPAN_STEP_PS,
                                         mono_ns=time.monotonic_ns())
 

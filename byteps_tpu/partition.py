@@ -8,8 +8,10 @@ compression, push, summation, and pull, and its partitions spread across all
 parameter servers (ps-lite ``Postoffice::GetServerKeyRanges`` equivalent).
 
 TPU-first notes: partition sizes are computed on *flattened, padded* arrays
-so shapes stay static under jit; the same partition table drives both the
-host-side C++ PS path and the in-jit bucketing used for overlap.
+so shapes stay static under jit. This table mirrors the host-side C++ PS
+path's; the bucketed PS step (``jax/bucketed.py``) groups whole leaves into
+byte-balanced buckets of its own (``partition_buckets``) and reads nothing
+here.
 """
 
 from __future__ import annotations

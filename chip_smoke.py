@@ -2,10 +2,9 @@
 
 One command, ``python chip_smoke.py``, on a machine with one TPU chip. It
 drives the framework through its user entry points only (``bps.init()`` →
-``make_train_step`` / ``make_overlapped_train_step`` /
-``make_bucketed_overlap_step`` → ``step`` → ``bps.shutdown()``, and
-``python -m byteps_tpu.server`` for the fleet) at the full width of GPT-2
-124M (12 layers, d 768, 12 heads, vocab 50257, bf16,
+``make_train_step`` / ``make_bucketed_overlap_step`` → ``step`` →
+``bps.shutdown()``, and ``python -m byteps_tpu.server`` for the fleet) at the
+full width of GPT-2 124M (12 layers, d 768, 12 heads, vocab 50257, bf16,
 seq 512, batch 8 per chip, adamw 1e-4, tokens and weights from ``--seed``).
 
 Phases of the default run, one JSON line each:
@@ -13,7 +12,7 @@ Phases of the default run, one JSON line each:
   0 device     JAX must have found a TPU; versions; C core rebuilt from csrc/
   1 collective make_train_step vs a plain jax.jit step, 5 steps
   2 ps         scheduler + server children, this process the worker, 3 steps
-  3 overlap    io_callback taps, then bucketed multi-program, 3 steps each
+  3 overlap    the bucketed multi-program PS step, 3 steps
   4 flash      Pallas kernel vs float32 attention; 2 steps with attn "flash"
   5 profile    jax.profiler capture of 2 steps, read back, TPU plane required
 
@@ -44,7 +43,6 @@ import socket
 import subprocess
 import sys
 import time
-import warnings
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 
@@ -416,29 +414,20 @@ def phase_ps(prob: Problem, ref_losses, ref_step_s, out_dir: str,
 
 def phase_overlap(prob: Problem, ref_losses, out_dir: str,
                   steps: int = 3) -> dict:
-    """Phase 3: both overlap designs on a fresh fleet, with every warning
-    an error — a builder that quietly handed back another step design
-    used to say so only in a warning."""
+    """Phase 3: the bucketed PS step on a fresh fleet; the losses must be
+    phase 1's."""
     import byteps_tpu.jax as bps
     from byteps_tpu.jax.bucketed import make_bucketed_overlap_step
-    from byteps_tpu.jax.overlap import make_overlapped_train_step
 
     rec = {"phase": "overlap", "ok": True}
     with ps_fleet(os.path.join(out_dir, "overlap")):
         bps.init()
         try:
-            with warnings.catch_warnings():
-                warnings.simplefilter("error")
-                rec["io_callback_taps"] = _ps_steps(
-                    "overlap/taps",
-                    lambda: make_overlapped_train_step(
-                        prob.loss_fn, prob.tx, prefix="taps"),
-                    prob, ref_losses, steps)
-                rec["bucketed"] = _ps_steps(
-                    "overlap/bucketed",
-                    lambda: make_bucketed_overlap_step(
-                        prob.loss_fn, prob.tx, prefix="bkt"),
-                    prob, ref_losses, steps)
+            rec["bucketed"] = _ps_steps(
+                "overlap/bucketed",
+                lambda: make_bucketed_overlap_step(
+                    prob.loss_fn, prob.tx, prefix="bkt"),
+                prob, ref_losses, steps)
         finally:
             bps.shutdown()
     return emit(rec)

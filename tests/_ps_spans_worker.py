@@ -2,7 +2,7 @@
 ``jax.profiler`` capture; prints the capture's ``bps.*`` events and the C
 core's round rows as one JSON line for the test to judge.
 ``BPS_SPANS_BUILDER`` picks the step design (``serial``, the default:
-``make_train_step``; ``bucketed``; ``taps``).
+``make_train_step``; ``bucketed``).
 ``BPS_SPANS_WORKER_ROUNDSTATS`` sets ``BYTEPS_ROUNDSTATS_ON`` for this process
 alone, where the fleet's other roles got another value."""
 
@@ -23,13 +23,11 @@ import optax  # noqa: E402
 
 import byteps_tpu.jax as bps  # noqa: E402
 from byteps_tpu.jax.bucketed import make_bucketed_overlap_step  # noqa: E402
-from byteps_tpu.jax.overlap import make_overlapped_train_step  # noqa: E402
 from byteps_tpu.jax.training import make_train_step  # noqa: E402
 
 BUILDERS = {"serial": make_train_step,
             "bucketed": functools.partial(make_bucketed_overlap_step,
-                                          n_buckets=2),
-            "taps": make_overlapped_train_step}
+                                          n_buckets=2)}
 
 
 def main() -> int:

@@ -24,8 +24,6 @@ def main() -> int:
     mode = os.environ.get("BPS_TEST_MODE", "basic")
     if mode == "jax_train":
         return jax_train_main()
-    if mode == "jax_overlap":
-        return jax_overlap_main()
     if mode == "jax_bridge":
         return jax_bridge_main()
     if mode == "jax_stream":
@@ -36,8 +34,6 @@ def main() -> int:
         return jax_timeline_main()
     if mode == "mxnet_stub":
         return mxnet_stub_main()
-    if mode == "jax_overlap_accum":
-        return jax_overlap_accum_main()
     if mode == "jax_async":
         return jax_async_main()
     if mode == "jax_async_seed":
@@ -1727,95 +1723,11 @@ def jax_timeline_main() -> int:
         bps_jax.shutdown()
 
 
-def jax_overlap_main() -> int:
-    """Per-layer overlapped PS training (custom_vjp taps + io_callback)
-    must reproduce single-process numerics exactly — the hook-streaming
-    analogue of jax_train_main."""
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-    import jax.numpy as jnp
-    import optax
-    import byteps_tpu.jax as bps_jax
-    from byteps_tpu.config import get_config
-    from byteps_tpu.jax.overlap import make_overlapped_train_step
-
-    cfg = get_config(reload=True)
-    assert cfg.use_ps, "expected PS mode in jax_overlap"
-    bps_jax.init()
-    try:
-        return _jax_overlap_body()
-    finally:
-        # always tear down the C++ worker threads, or a failing assert
-        # leaves this process (and the whole fleet) hanging
-        bps_jax.shutdown()
-
-
-def jax_overlap_accum_main() -> int:
-    """backward_passes_per_step in the overlap path: K accumulation
-    passes push once and must equal one big-batch step exactly (lr
-    scaled by 1/K — the caller-divides contract)."""
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-    import jax.numpy as jnp
-    import optax
-    import byteps_tpu.jax as bps_jax
-    from byteps_tpu.jax.overlap import make_overlapped_train_step
-
-    bps_jax.init()
-    try:
-        st = bps_jax._st()
-        rank = st.ps_client.worker_rank()
-        nw = st.ps_client.num_workers()
-        K = 3
-
-        def loss_fn(params, batch):
-            x, y = batch
-            return jnp.mean((jnp.tanh(x @ params["w"]) - y) ** 2)
-
-        prng = np.random.default_rng(8)
-        params0 = {"w": jnp.asarray(prng.standard_normal((5, 4)),
-                                    jnp.float32) * 0.4}
-        lr = 0.3
-        tx = optax.sgd(lr / K)  # caller divides by K
-        step = make_overlapped_train_step(loss_fn, tx,
-                                          backward_passes_per_step=K)
-        params = jax.tree_util.tree_map(jnp.array, params0)
-        opt_state = tx.init(params)
-        per = 6
-        micro = []
-        for _ in range(K):
-            gx = prng.standard_normal((nw * per, 5)).astype(np.float32)
-            gy = np.tanh(gx[:, :4] * 0.7).astype(np.float32)
-            micro.append((gx, gy))
-        for m_i, (gx, gy) in enumerate(micro):
-            lo, hi = rank * per, (rank + 1) * per
-            p_before = np.asarray(params["w"])
-            params, opt_state, _ = step(params, opt_state,
-                                        (gx[lo:hi], gy[lo:hi]))
-            if m_i < K - 1:  # accumulation passes leave params untouched
-                np.testing.assert_array_equal(np.asarray(params["w"]),
-                                              p_before)
-        # reference: mean of the K microbatch grads on the FULL batch,
-        # one plain SGD step at lr/K on the summed (=K*mean) grads.
-        def full_loss(p):
-            return sum(loss_fn(p, m) for m in micro) / K
-
-        g = jax.grad(full_loss)(params0)
-        expect = {"w": params0["w"] - lr * g["w"]}
-        np.testing.assert_allclose(np.asarray(params["w"]),
-                                   np.asarray(expect["w"]),
-                                   rtol=2e-4, atol=2e-5)
-        print(f"worker {rank}: jax_overlap_accum OK")
-        return 0
-    finally:
-        bps_jax.shutdown()
-
-
 def jax_bucketed_main() -> int:
-    """Bucketed multi-program overlap (io_callback-free fallback,
-    SURVEY.md §7 hard part #1 option 2) must reproduce single-process
-    numerics: per-bucket gradient programs + the D2H/DCN/H2D bucket
-    pipeline change WHEN communication happens, never WHAT is summed."""
+    """The overlap step — bucketed multi-program stepping (SURVEY.md §7
+    hard part #1) — must reproduce single-process numerics: per-bucket
+    gradient programs + the D2H/DCN/H2D bucket pipeline change WHEN
+    communication happens, never WHAT is summed."""
     import jax
     jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
@@ -1890,81 +1802,6 @@ def jax_bucketed_main() -> int:
         return 0
     finally:
         bps_jax.shutdown()
-
-
-def _jax_overlap_body() -> int:
-    import jax
-    import jax.numpy as jnp
-    import optax
-    import byteps_tpu.jax as bps_jax
-    from byteps_tpu.jax.overlap import make_overlapped_train_step
-
-    st = bps_jax._st()
-    rank = st.ps_client.worker_rank()
-    nw = st.ps_client.num_workers()
-
-    def loss_fn(params, batch):
-        x, y = batch
-        h = jnp.tanh(x @ params["w1"] + params["b1"])
-        pred = h @ params["w2"]
-        return jnp.mean((pred - y) ** 2)
-
-    prng = np.random.default_rng(5)
-    params0 = {
-        "w1": jnp.asarray(prng.standard_normal((6, 8)), jnp.float32) * 0.4,
-        "b1": jnp.zeros((8,), jnp.float32),
-        "w2": jnp.asarray(prng.standard_normal((8, 3)), jnp.float32) * 0.4,
-    }
-    tx = optax.sgd(0.1)
-    comp = os.environ.get("BPS_OVERLAP_COMPRESSION") or None
-    wire = os.environ.get("BPS_OVERLAP_WIRE") or "float32"
-    step = make_overlapped_train_step(loss_fn, tx,
-                                      compression_config=comp,
-                                      wire_dtype=wire)
-    params = jax.tree_util.tree_map(jnp.array, params0)
-    opt_state = tx.init(params)
-    per = 8
-    for _ in range(6):
-        gx = prng.standard_normal((nw * per, 6)).astype(np.float32)
-        gy = gx[:, :3] * 2.0
-        lo, hi = rank * per, (rank + 1) * per
-        params, opt_state, loss = step(params, opt_state,
-                                       (gx[lo:hi], gy[lo:hi]))
-
-    ref_prng = np.random.default_rng(5)
-    ref_prng.standard_normal((6, 8))
-    ref_prng.standard_normal((8, 3))
-
-    @jax.jit
-    def ref_step(p, s, batch):
-        _, g = jax.value_and_grad(loss_fn)(p, batch)
-        u, s = tx.update(g, s, p)
-        return optax.apply_updates(p, u), s
-
-    ref_params = jax.tree_util.tree_map(jnp.array, params0)
-    ref_state = tx.init(ref_params)
-    for _ in range(6):
-        gx = ref_prng.standard_normal((nw * per, 6)).astype(np.float32)
-        gy = gx[:, :3] * 2.0
-        ref_params, ref_state = ref_step(ref_params, ref_state, (gx, gy))
-    if comp or wire == "int8":
-        # lossy codec / quantized wire: same trajectory, looser bound
-        for k in params:
-            np.testing.assert_allclose(
-                np.asarray(params[k]), np.asarray(ref_params[k]),
-                rtol=0.5, atol=0.2)
-    elif wire == "bfloat16":
-        for k in params:
-            np.testing.assert_allclose(
-                np.asarray(params[k]), np.asarray(ref_params[k]),
-                rtol=0.05, atol=0.02)
-    else:
-        for k in params:
-            np.testing.assert_allclose(
-                np.asarray(params[k]), np.asarray(ref_params[k]),
-                rtol=2e-4, atol=2e-5)
-    print(f"worker {rank}: jax_overlap OK")
-    return 0
 
 
 if __name__ == "__main__":

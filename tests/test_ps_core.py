@@ -577,52 +577,10 @@ def test_jax_async_training_converges():
                  timeout=180)
 
 
-def test_jax_overlapped_training_matches_single_process():
-    """Hook-style per-layer push streaming (custom_vjp taps + io_callback,
-    SURVEY.md §7 hard part #1) reproduces single-process numerics."""
-    # Workers are one-accelerator processes (the reference's layout):
-    # drop the pytest env's 8-device XLA flag for the children.
-    run_topology(2, 1, WORKER, mode="jax_overlap",
-                 extra={"BYTEPS_PS_MODE": "ps", "XLA_FLAGS": ""},
-                 timeout=180)
-
-
-def test_jax_overlapped_training_multichip_controller():
-    """Per-layer overlap under a MULTI-chip controller (SURVEY.md §7 hard
-    part #1, the open half): each worker process drives 4 virtual chips;
-    every tap reduce-scatters its gradient over the local mesh inside jit
-    and streams only host-level 1/4 shards to the PS. Numerics must still
-    match single-process training on the combined batch."""
-    run_topology(2, 1, WORKER, mode="jax_overlap",
-                 extra={"BYTEPS_PS_MODE": "ps",
-                        "XLA_FLAGS":
-                            "--xla_force_host_platform_device_count=4"},
-                 timeout=240)
-
-
-def test_jax_overlap_device_wire_compression():
-    """On-device wire compression for the host boundary (SURVEY.md §7
-    step 5): taps cast/quantize the reduce-scattered shard INSIDE jit —
-    bf16 (2x) stays near-exact; int8 (4x) converges within quantization
-    tolerance — on multi-chip controllers."""
-    run_topology(2, 1, WORKER, mode="jax_overlap",
-                 extra={"BYTEPS_PS_MODE": "ps",
-                        "XLA_FLAGS":
-                            "--xla_force_host_platform_device_count=4",
-                        "BPS_OVERLAP_WIRE": "bfloat16"},
-                 timeout=240)
-    run_topology(2, 1, WORKER, mode="jax_overlap",
-                 extra={"BYTEPS_PS_MODE": "ps",
-                        "XLA_FLAGS":
-                            "--xla_force_host_platform_device_count=4",
-                        "BPS_OVERLAP_WIRE": "int8"},
-                 timeout=240)
-
-
 def test_jax_bucketed_overlap_matches_single_process():
-    """Bucketed MULTI-PROGRAM overlap (SURVEY.md §7 hard part #1, the
-    io_callback-free design): per-bucket gradient programs + the
-    D2H/DCN/H2D bucket pipeline reproduce single-process numerics."""
+    """The overlap step, bucketed MULTI-PROGRAM stepping (SURVEY.md §7 hard
+    part #1): per-bucket gradient programs + the D2H/DCN/H2D bucket
+    pipeline reproduce single-process numerics."""
     run_topology(2, 1, WORKER, mode="jax_bucketed",
                  extra={"BYTEPS_PS_MODE": "ps", "XLA_FLAGS": ""},
                  timeout=240)
@@ -643,8 +601,8 @@ def test_jax_bucketed_multichip_bf16_wire():
 
 def test_jax_bucketed_with_compression():
     """Bucketed overlap composed with the C-core codec layer (topk+EF on
-    the bucketed pushes) — the codec rides per-leaf declares exactly as
-    in the tap path."""
+    the bucketed pushes) — the codec rides the per-leaf declares of the
+    tree's binding."""
     run_topology(2, 1, WORKER, mode="jax_bucketed",
                  extra={"BYTEPS_PS_MODE": "ps", "XLA_FLAGS": "",
                         "BPS_OVERLAP_COMPRESSION":
@@ -652,36 +610,18 @@ def test_jax_bucketed_with_compression():
                  timeout=240)
 
 
-def test_jax_overlap_gradient_accumulation():
-    """backward_passes_per_step in the overlap path (reference hook
-    contract): K accumulation passes communicate once and equal one
-    big-batch step exactly; non-final passes leave params untouched."""
-    run_topology(2, 1, WORKER, mode="jax_overlap_accum",
-                 extra={"BYTEPS_PS_MODE": "ps", "XLA_FLAGS": ""},
-                 timeout=180)
-
-
-def test_jax_overlap_stress_4workers_2servers_compressed_multichip():
+def test_jax_bucketed_stress_4workers_2servers_compressed_multichip():
     """Composition stress: 4 worker processes x 2 virtual chips each,
-    2 servers, per-layer overlap (reduce-scattered taps), C-core codec
-    with error feedback, and the pull-leg re-encode — all at once."""
-    run_topology(4, 2, WORKER, mode="jax_overlap",
+    2 servers, the bucketed overlap step (every bucket's program reduces
+    over the local mesh), C-core codec with error feedback, and the
+    pull-leg re-encode — all at once."""
+    run_topology(4, 2, WORKER, mode="jax_bucketed",
                  extra={"BYTEPS_PS_MODE": "ps",
                         "XLA_FLAGS":
                             "--xla_force_host_platform_device_count=2",
                         "BPS_OVERLAP_COMPRESSION":
-                            "type=topk;k=48;ef=vanilla"},
+                            "type=topk;k=24;ef=vanilla"},
                  timeout=300)
-
-
-def test_jax_overlapped_training_with_compression():
-    """Per-layer overlap composed with the C-core codec layer (topk + error
-    feedback on the streamed pushes)."""
-    run_topology(2, 1, WORKER, mode="jax_overlap",
-                 extra={"BYTEPS_PS_MODE": "ps", "XLA_FLAGS": "",
-                        "BPS_OVERLAP_COMPRESSION":
-                            "type=topk;k=64;ef=vanilla"},
-                 timeout=180)
 
 
 def test_mxnet_plugin_over_real_topology():

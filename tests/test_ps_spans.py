@@ -1,5 +1,5 @@
 """The PS leg's host spans (``byteps_tpu/jax/ps.py``): one table, mirrored by
-the docs and the benchmark's readers, and a real PS step of each of the three
+the docs and the benchmark's readers, and a real PS step of each of the two
 designs under a ``jax.profiler`` capture writes its part of it where the table
 says."""
 
@@ -22,36 +22,42 @@ TRACED = 2
 # intervals come from the host spans alone, which is all that is asked here.
 CPU = trace_reduce.Layout(device_plane=r"^/device:none$", op_lines=None,
                           sync_line=None, module_line="none")
-# per design: virtual devices (overlap.py's own warning asks the taps for
-# two), spans a traced step, partitions a round
+# The bucketed step's spans of a traced step: one bps.ps.d2h / .stage a bucket
+# (the worker asks for two), the rest once.
+BUCKETED = {**dict.fromkeys(ps.SPANS[:3], 1), ps.SPAN_D2H: 2,
+            ps.SPAN_STAGE: 2, ps.SPAN_WAIT: 1, ps.SPAN_H2D: 1}
+# per case: the worker's builder, virtual devices, spans a traced step,
+# partitions a round. Two devices are one controller's two chips: the step
+# reduces in the program, so the host sees one tree a step and the counts are
+# the one-device case's — once a step, not once a device.
 DESIGNS = {
-    "serial": (1, dict.fromkeys(ps.SPANS, 1), LEAVES),
-    "bucketed": (1, {**dict.fromkeys(ps.SPANS[:3], 1), ps.SPAN_D2H: 2,
-                     ps.SPAN_STAGE: 2, ps.SPAN_WAIT: 1, ps.SPAN_H2D: 1},
-                 LEAVES),
-    "taps": (2, {**dict.fromkeys(ps.SPANS[:3], 1), ps.SPAN_WAIT: 1,
-                 ps.SPAN_H2D: 1, ps.SPAN_TAP_PUSH: 2 * LEAVES}, 2 * LEAVES),
+    "serial": ("serial", 1, dict.fromkeys(ps.SPANS, 1), LEAVES),
+    "bucketed": ("bucketed", 1, BUCKETED, LEAVES),
+    "bucketed-2dev": ("bucketed", 2, BUCKETED, LEAVES),
 }
+# benchmark/layers/psleg.py still reads this name as a second enqueue (the
+# io_callback taps', which left in PR 60): read by the benchmark, written by
+# no step. The one exception test_span_tables_agree allows.
+READ_AND_NEVER_WRITTEN = {"bps.tap.push"}
 
 
 def test_span_tables_agree():
     """The program's table of eight and the benchmark's mirror of it name
-    the same spans; docs/timeline.md's table documents those and the taps'
-    one, in the program's order; the reader of all three designs names no
-    span the program does not."""
+    the same spans; docs/timeline.md's table documents those, in the
+    program's order; the reader of both designs names no span the program
+    does not, but for the one name above."""
     from benchmark.layers import bridge
 
     assert len(ps.SPANS) == len(set(ps.SPANS)) == 8
     assert bridge.SPANS == ps.SPANS
-    assert ps.ALL_SPANS == ps.SPANS + (ps.SPAN_TAP_PUSH,)
-    assert set(psleg.SPANS) <= set(ps.ALL_SPANS)
+    assert set(psleg.SPANS) - set(ps.SPANS) == READ_AND_NEVER_WRITTEN
     with open(os.path.join(REPO, "docs", "timeline.md")) as f:
         documented = re.findall(r"^\| `(bps\.[a-z0-9_.]+)` \|", f.read(), re.M)
-    assert tuple(documented) == ps.ALL_SPANS
+    assert tuple(documented) == ps.SPANS
 
 
-def _fleet(tmp_path, builder="serial", **env):
-    devices = DESIGNS[builder][0]
+def _fleet(tmp_path, design="serial", **env):
+    builder, devices = DESIGNS[design][:2]
     (out,) = run_topology(
         1, 1, WORKER, extra={
             "BYTEPS_PS_MODE": "ps", "BYTEPS_FORCE_DISTRIBUTED": "1",
@@ -102,49 +108,44 @@ def _serial_bridge_spans(by_name, mono):
     return first
 
 
-@pytest.mark.parametrize("builder", list(DESIGNS))
-def test_a_ps_step_writes_its_spans(tmp_path, builder):
+@pytest.mark.parametrize("design", list(DESIGNS))
+def test_a_ps_step_writes_its_spans(tmp_path, design):
     """1 worker + 1 server on loopback, two traced steps of one design:
     every span of the design as many times a step as the table says and no
     other; the three step spans on the caller's line, the binding's on
-    another (the bridge thread), the taps' pushes on lines that are neither
-    (the runtime's threads); ``bps.ps.wait`` inside ``bps.step.ps``;
-    ``mono_ns`` on ``bps.step.ps``, on the C core's clock. The rounds the
-    core has closed by then carry their stages and resources as elapsed
-    time, and the first traced step's, put on the capture's clock through
-    that ``mono_ns`` alone, lies inside the step's leg — first enqueue to
-    the end of ``bps.ps.wait`` — to 200 us."""
-    _, per_step, parts = DESIGNS[builder]
-    found = _fleet(tmp_path, builder)
+    another (the bridge thread); ``bps.ps.wait`` inside ``bps.step.ps``;
+    ``mono_ns`` on ``bps.step.ps``, on the C core's clock; a step's last
+    ``bps.ps.stage`` has counted one gradient tree's bytes, however many
+    chips the controller drives. The rounds the core has closed by then
+    carry their stages and resources as elapsed time, and the first traced
+    step's, put on the capture's clock through that ``mono_ns`` alone, lies
+    inside the step's leg — first enqueue to the end of ``bps.ps.wait`` — to
+    200 us."""
+    builder, _, per_step, parts = DESIGNS[design]
+    found = _fleet(tmp_path, design)
     events = found["events"]
     assert {e["plane"] for e in events} == {"/host:CPU"}
+    assert {e["name"] for e in events} <= set(ps.SPANS)
     by_name = {name: [e for e in events if e["name"] == name]
-               for name in ps.ALL_SPANS}
+               for name in ps.SPANS}
     assert {n: len(v) for n, v in by_name.items()} == {
-        n: TRACED * per_step.get(n, 0) for n in ps.ALL_SPANS}
+        n: TRACED * per_step.get(n, 0) for n in ps.SPANS}
 
     caller = {e["line"] for n in ps.SPANS[:3] for e in by_name[n]}
     assert len(caller) == 1
     binding = {e["line"] for n in ps.SPANS[3:] for e in by_name[n]}
-    runtime = {e["line"] for e in by_name[ps.SPAN_TAP_PUSH]}
-    if builder == "taps":
-        # collect runs on the caller's thread, the pushes never do
-        assert binding == caller and runtime and not runtime & caller
-    else:
-        assert len(binding) == 1 and binding != caller and not runtime
+    assert len(binding) == 1 and binding != caller
 
     lo, hi = found["mono_ns"]
     for outer, wait in zip(by_name[ps.SPAN_STEP_PS], by_name[ps.SPAN_WAIT]):
         assert outer["start_ns"] <= wait["start_ns"] <= _end(wait) <= _end(
             outer)
         assert lo < outer["stats"]["mono_ns"] < hi
-    for push in by_name[ps.SPAN_TAP_PUSH]:
-        assert push["stats"]["direct_bytes"] == 0 < push["stats"]["bytes"]
-        assert 0 <= push["stats"]["leaf"] < LEAVES
-        assert push["stats"]["shard"] in (0, 1)
-    if builder == "taps":
-        assert sum(e["stats"]["bytes"] for e in by_name[ps.SPAN_TAP_PUSH]) \
-            == TRACED * BYTES
+    # a round's pieces add to its first: the step's last stage span has the
+    # whole tree, once
+    stages = per_step[ps.SPAN_STAGE]
+    assert [e["stats"]["bytes"] for e in by_name[ps.SPAN_STAGE][
+        stages - 1::stages]] == [BYTES] * TRACED
     first_push_pull = (_serial_bridge_spans(by_name, found["mono_ns"])
                        if builder == "serial" else None)
 
@@ -174,18 +175,15 @@ def test_a_ps_step_writes_its_spans(tmp_path, builder):
          for e in events], CPU)
     assert len(steps) == TRACED
     for step in steps:
-        assert step["enqueues"] == per_step.get(
-            ps.SPAN_STAGE, 0) + per_step.get(ps.SPAN_TAP_PUSH, 0)
+        assert step["enqueues"] == per_step[ps.SPAN_STAGE]
         assert step["hidden"] == 0 and step["exposed"] == step["leg"] > 0
     (row,) = psleg.align(rounds[1:], steps, [
         (e["start_ns"], e["stats"]["mono_ns"])
         for e in by_name[ps.SPAN_STEP_PS]])
     assert row["round"] == 1
     assert row["start_margin_ms"] >= -0.2 and row["end_margin_ms"] >= -0.2
-    if builder != "taps":
-        # the binding's steps enqueue inside bps.step.ps; the taps' rounds
-        # begin under bps.step.grad, by design
-        assert row["step_ps_start_margin_ms"] >= -0.2
+    # the binding's steps enqueue inside bps.step.ps
+    assert row["step_ps_start_margin_ms"] >= -0.2
     assert row["step_ps_end_margin_ms"] >= -0.2
     if first_push_pull:
         # and, as before bps.step.ps carried the anchor, inside push_pull
