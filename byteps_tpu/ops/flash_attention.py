@@ -4,9 +4,10 @@ Blockwise attention with online softmax: Q blocks in VMEM, the kernel
 streams K/V blocks and keeps only O(block) state — never materialising the
 [S, S] score matrix in HBM. Block matmuls hit the MXU at the (128, 128)
 tile shape; masking (causal / key padding) is computed on the VPU with
-broadcasted iota. Per /opt/skills/guides/pallas_guide.md patterns: grid
-iterates (batch*heads, q_block, k_block) with the k_block dimension
-innermost so VMEM scratch carries the running (m, l, acc) across K steps.
+broadcasted iota, on the blocks a mask can change. Per
+/opt/skills/guides/pallas_guide.md patterns: grid iterates (batch*heads,
+q_block, k_block) with the k_block dimension innermost so VMEM scratch
+carries the running (m, l, acc) across K steps.
 
 Layout as byteps_tpu.parallel attention: [batch, seq, heads, head_dim], v's
 head_dim (and the output's) free to differ from q's and k's; f32 accumulation.
@@ -40,6 +41,11 @@ dK/dV kernel walking the group's heads one after another over one key
 block), so no repeated K/V and no [heads] dK/dV are written (at 64 / 8 x
 128 x s8192 3-9% faster than repeating ahead of the call, PERF.md section
 3). Under a ``window`` every grid walks only the blocks the band touches.
+Of the blocks a grid computes (the live ones), one that no mask can change
+— wholly under the diagonal, inside the window, no padding: ``_interior``,
+from the block's corners — runs the same arithmetic with no mask formed,
+in all four kernels; the diagonal's blocks, a band's edges and a padded
+tail run the masked body (``block_census`` counts both kinds).
 """
 
 from __future__ import annotations
@@ -124,13 +130,40 @@ def _mask(q_start, k_start, bq, bk, seq_q, seq_k, causal, window):
     return mask
 
 
-def _when_live(live, compute):
-    """``compute()`` unless the block is dead; ``live`` is ``True`` (the
-    Python value) where no mask can kill a whole block."""
-    if live is True:
-        compute()
+def _interior(q_start, k_start, bq, bk, seq_q, seq_k, causal, window):
+    """Whether ``_mask`` of the block is all true, from the block's corners:
+    it holds no padding (keys; queries where ``seq_q`` is given, as the
+    backward kernels give it), its last key is no later than its first
+    query, and its farthest pair — last query, first key — is inside the
+    window. ``False`` (the Python value) where no block of the call can be:
+    a causal block's farthest pair lies ``bq + bk - 2`` apart at the least,
+    so a window shorter than ``bq + bk - 1`` has edge blocks alone."""
+    if causal and window is not None and window < bq + bk - 1:
+        return False
+    inside = k_start + bk <= seq_k
+    if seq_q is not None:
+        inside &= q_start + bq <= seq_q
+    if causal:
+        inside &= k_start + bk - 1 <= q_start
+        if window is not None:
+            inside &= q_start + bq - 1 - k_start < window
+    return inside
+
+
+def _when_live(live, interior, compute):
+    """``compute(masked)`` unless the block is dead: ``compute(False)``, the
+    same arithmetic with no mask formed, where the block is ``interior``
+    (which implies live), ``compute(True)`` on the edge blocks. ``live`` is
+    ``True`` and ``interior`` ``False`` (the Python values) where no mask
+    can kill a whole block and where none can spare one."""
+    edge = live
+    if interior is not False:
+        pl.when(interior)(lambda: compute(False))
+        edge = jnp.logical_and(live, jnp.logical_not(interior))
+    if edge is True:
+        compute(True)
     else:
-        pl.when(live)(compute)
+        pl.when(edge)(lambda: compute(True))
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
@@ -159,13 +192,14 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         k_start = (_window_start_block(q_start, window, block_k) + ki) \
             * block_k
 
-    def _compute():
+    def _compute(masked):
         v = v_ref[0]
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [bq, bk]
-        s = jnp.where(_mask(q_start, k_start, block_q, block_k, None, seq_k,
-                            causal, window), s, _NEG_INF)
+        if masked:
+            s = jnp.where(_mask(q_start, k_start, block_q, block_k, None,
+                                seq_k, causal, window), s, _NEG_INF)
 
         m_prev = m_ref[:, 0:1]             # [bq, 1]
         m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -191,7 +225,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                 live, k_start + block_k - 1 >= q_start - (window - 1))
         if nk_total is not None:
             live = jnp.logical_and(live, k_start < nk_total * block_k)
-    _when_live(live, _compute)
+    _when_live(live, _interior(q_start, k_start, block_q, block_k, None,
+                               seq_k, causal, window), _compute)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -393,6 +428,22 @@ def window_walked_pairs(s_q: int, s_k: int, d: int, window: int) -> int:
     return sum(_window_k_blocks(window, bq, bk, nq, nk)) * bq * bk
 
 
+def block_census(s_q: int, s_k: int, d: int,
+                 window: Optional[int] = None) -> tuple:
+    """(live, interior): the blocks one head of one sequence costs a causal
+    call — those its grids compute (``_window_k_blocks``; no window is one
+    as long as the queries) — and, of them, those that run with no mask
+    formed, by the predicate the kernels ask (``_interior``, as the
+    backward's ask it: a block with padded queries is an edge block)."""
+    bq, bk = _clamped(s_q, s_k, *_blocks(s_q, s_k, d, window))
+    nq, nk = -(-s_q // bq), -(-s_k // bk)
+    live = sum(_window_k_blocks(window or s_q, bq, bk, nq, nk))
+    interior = sum(
+        bool(_interior(qi * bq, ki * bk, bq, bk, s_q, s_k, True, window))
+        for qi in range(nq) for ki in range(nk))
+    return live, interior
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(
     q: jax.Array,
@@ -558,30 +609,38 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     return out, (q, k, v, out, lse)
 
 
-def _bwd_live(q_start, k_start, bq, bk, causal, window):
-    """Block-level skip predicate shared by the backward kernels."""
+def _bwd_live(q_start, k_start, bq, bk, seq_q, seq_k, causal, window):
+    """(live, interior): the block-level predicates shared by the backward
+    kernels, which block to skip and which to run with no mask."""
+    interior = _interior(q_start, k_start, bq, bk, seq_q, seq_k, causal,
+                         window)
     if not causal:
-        return True
+        return True, interior
     live = q_start + bq - 1 >= k_start
     if window is not None:
         live = jnp.logical_and(live,
                                k_start + bk - 1 >= q_start - (window - 1))
-    return live
+    return live, interior
 
 
 def _bwd_recompute(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
-                   q_start, k_start, *, scale, causal, block_q, block_k,
-                   seq_q, seq_k, window=None):
+                   q_start, k_start, masked, *, scale, causal, block_q,
+                   block_k, seq_q, seq_k, window=None):
     """A block's recompute, shared by the fused kernel and the pair:
-    returns (p, ds), float32. The one place the score/probability/ds math
-    lives, so the backward kernels cannot silently diverge."""
+    returns (p, ds), float32; ``masked`` False on an interior block, whose
+    mask is all true and is not formed. The one place the
+    score/probability/ds math lives, so the backward kernels cannot
+    silently diverge."""
     lse = lse_ref[0][:, 0:1]
     dd = dd_ref[0][:, 0:1]
     sc = jax.lax.dot_general(
         q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    p = jnp.where(_mask(q_start, k_start, block_q, block_k, seq_q, seq_k,
-                        causal, window), jnp.exp(sc - lse), 0.0)
+    if masked:
+        p = jnp.where(_mask(q_start, k_start, block_q, block_k, seq_q, seq_k,
+                            causal, window), jnp.exp(sc - lse), 0.0)
+    else:
+        p = jnp.exp(sc - lse)
     dp = jax.lax.dot_general(
         do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -606,20 +665,21 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref,
         ki = _window_start_block(q_start, window, block_k) + ki
     k_start = ki * block_k
 
-    def _compute():
+    def _compute(masked):
         _, ds = _bwd_recompute(
             q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_start, k_start,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-            seq_q=seq_q, seq_k=seq_k, window=window)
+            masked, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, seq_q=seq_q, seq_k=seq_k, window=window)
         k = k_ref[0]
         dq_acc[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    live = _bwd_live(q_start, k_start, block_q, block_k, causal, window)
+    live, interior = _bwd_live(q_start, k_start, block_q, block_k, seq_q,
+                               seq_k, causal, window)
     if nk_total is not None:
         live = jnp.logical_and(live, k_start < nk_total * block_k)
-    _when_live(live, _compute)
+    _when_live(live, interior, _compute)
 
     @pl.when(step == nk - 1)
     def _finish():
@@ -648,11 +708,11 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     q_start = qi * block_q
     k_start = ki * block_k
 
-    def _compute():
+    def _compute(masked):
         p, ds = _bwd_recompute(
             q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_start, k_start,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-            seq_q=seq_q, seq_k=seq_k, window=window)
+            masked, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, seq_q=seq_q, seq_k=seq_k, window=window)
         q, do = q_ref[0], do_ref[0]
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -661,10 +721,11 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    live = _bwd_live(q_start, k_start, block_q, block_k, causal, window)
+    live, interior = _bwd_live(q_start, k_start, block_q, block_k, seq_q,
+                               seq_k, causal, window)
     if nq_total is not None:
         live = jnp.logical_and(live, q_start < nq_total * block_q)
-    _when_live(live, _compute)
+    _when_live(live, interior, _compute)
 
     @pl.when(step == nq - 1)
     def _finish():
@@ -710,11 +771,11 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         ki = _window_start_block(q_start, window, block_k) + ki
     k_start = ki * block_k
 
-    def _compute():
+    def _compute(masked):
         p, ds = _bwd_recompute(
             q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, q_start, k_start,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-            seq_q=seq_q, seq_k=seq_k, window=window)
+            masked, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, seq_q=seq_q, seq_k=seq_k, window=window)
         q, k, do = q_ref[0], k_ref[0], do_ref[0]
         dv_acc[rows(ki), :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -727,10 +788,11 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    live = _bwd_live(q_start, k_start, block_q, block_k, causal, window)
+    live, interior = _bwd_live(q_start, k_start, block_q, block_k, seq_q,
+                               seq_k, causal, window)
     if nk_total is not None:
         live = jnp.logical_and(live, k_start < nk_total * block_k)
-    _when_live(live, _compute)
+    _when_live(live, interior, _compute)
 
     @pl.when(step == nk - 1)
     def _finish():

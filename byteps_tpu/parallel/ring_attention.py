@@ -147,6 +147,11 @@ XLA_SITES = "bps_attention_xla_sites_total"
 # ... and the kernel sites whose backward pass is the one fused kernel
 # (``ops/flash_attention.py::backward_form`` of the site's shapes)
 FUSED_BACKWARD_SITES = "bps_attention_fused_backward_sites_total"
+# ... and the blocks the kernel sites' grids compute, over batch and heads,
+# beside those of them that run with no mask formed: wholly under the
+# diagonal, inside the window, no padding (``flash_attention.block_census``)
+LIVE_BLOCKS = "bps_attention_live_blocks_total"
+INTERIOR_BLOCKS = "bps_attention_interior_blocks_total"
 
 # The shortest sequence and the head widths (queries and keys, values) at
 # which the Pallas kernel was measured against the XLA form on a TPU v5e,
@@ -221,9 +226,15 @@ def full_attention(q, k, v, *, causal: bool = False,
         # s128, any CPU run) pays for no kernel library
         # (tests/test_import_footprint.py)
         from byteps_tpu.ops.flash_attention import (
-            backward_form, flash_attention, window_walked_pairs)
+            backward_form, block_census, flash_attention,
+            window_walked_pairs)
 
         metrics.inc_counter(KERNEL_SITES)
+        live, interior = block_census(q.shape[1], k.shape[1], q.shape[-1],
+                                      window)
+        metrics.inc_counter(LIVE_BLOCKS, q.shape[0] * q.shape[2] * live)
+        metrics.inc_counter(INTERIOR_BLOCKS,
+                            q.shape[0] * q.shape[2] * interior)
         if backward_form(q.shape[1], k.shape[1], q.shape[-1], v.shape[-1],
                          groups, window, q.dtype.itemsize) == "fused":
             metrics.inc_counter(FUSED_BACKWARD_SITES)

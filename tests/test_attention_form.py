@@ -18,8 +18,10 @@ import pytest
 
 from byteps_tpu.monitor import metrics
 from byteps_tpu.parallel.ring_attention import (
-    FUSED_BACKWARD_SITES, KERNEL_SCOPE, KERNEL_SITES, XLA_SCOPE, XLA_SITES,
-    _single_device_attention, attention_form, full_attention)
+    FUSED_BACKWARD_SITES, INTERIOR_BLOCKS, KERNEL_SCOPE, KERNEL_SITES,
+    LIVE_BLOCKS, XLA_SCOPE, XLA_SITES, _single_device_attention,
+    attention_form, full_attention)
+from tests.test_flash_attention import BACKWARD_FORMS
 
 # the module: the package re-exports a function of the same name
 ra = importlib.import_module("byteps_tpu.parallel.ring_attention")
@@ -247,6 +249,64 @@ def test_the_fused_backward_counter_counts_the_sites_that_take_it(
     for name in ("bps_flash_fwd", "bps_flash_dq", "bps_flash_dkv",
                  "bps_flash_bwd"):
         assert (name in lowered) == (name in kernels), name
+
+
+# (live, interior) blocks a head: what PERF.md section 6 (PR 62) prices
+CENSUS = {
+    "gpt2-124m.collective.1chip": (1, 0),
+    "olmoe-1b-7b.collective-moe.1chip": (10, 6),
+    "joyai-llm-flash.collective-mtp.1chip": (36, 28),
+    "laguna-xs.2.collective-swa.1chip, windowed": (31, 0),
+    "mellum2-12b-a2.5b.collective-swa-moe.1chip, windowed": (15, 0),
+    "zaya1-8b.collective-cca.1chip": (136, 120),
+}
+
+
+@pytest.mark.parametrize(
+    "site", sorted(site for site in BACKWARD_FORMS if "chip" in site))
+def test_the_census_counts_the_blocks_no_mask_can_change(site):
+    """``block_census`` of a cell's attention shape against the mask
+    itself, block by block in numpy: a block is live where any pair of it
+    survives, interior where every pair does."""
+    fa = importlib.import_module("byteps_tpu.ops.flash_attention")
+    s_q, s_k, d, _, _, window = BACKWARD_FORMS[site][0]
+    bq, bk = fa._clamped(s_q, s_k, *fa._blocks(s_q, s_k, d, window))
+    live = interior = 0
+    for q_start in range(0, s_q, bq):
+        q_pos = q_start + np.arange(bq)[:, None]
+        for k_start in range(0, s_k, bk):
+            k_pos = k_start + np.arange(bk)[None, :]
+            mask = (q_pos < s_q) & (k_pos < s_k) & (q_pos >= k_pos)
+            if window is not None:
+                mask &= q_pos - k_pos < window
+            live += mask.any()
+            interior += mask.all()
+    assert fa.block_census(s_q, s_k, d, window) == (live, interior)
+    assert CENSUS.get(site, (live, interior)) == (live, interior)
+
+
+def test_the_block_counters_count_a_site_over_batch_and_heads(
+        rng, monkeypatch):
+    """``bps_attention_live_blocks_total`` and ``_interior_blocks_total``
+    beside the kernel sites' counter, at trace time: ``block_census`` of
+    the site's shapes times batch and query heads; an XLA site adds
+    nothing."""
+    q, k, v = _operands(rng, 4, 64, s=512, b=2)
+
+    def blocks():
+        return metrics.counter(LIVE_BLOCKS), metrics.counter(INTERIOR_BLOCKS)
+
+    def trace():
+        jax.eval_shape(lambda q, k, v: full_attention(q, k, v, causal=True),
+                       q, k[:, :, :2], v[:, :, :2])
+
+    before = blocks()
+    trace()
+    assert blocks() == before
+    with _kernel_form(monkeypatch):
+        trace()
+    # 8 x 4 blocks of 64 x 128: 20 on or under the diagonal, 12 wholly under
+    assert blocks() == (before[0] + 2 * 4 * 20, before[1] + 2 * 4 * 12)
 
 
 def test_each_form_is_named_in_the_lowered_program(rng, monkeypatch):

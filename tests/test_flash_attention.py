@@ -182,21 +182,120 @@ def test_interpret_resolution(monkeypatch, backend, asked, want):
     assert _resolve_interpret(asked) is want
 
 
-def test_equal_widths_lower_as_before_the_second_width(monkeypatch):
+@pytest.fixture
+def retraced():
+    """The kernels' jitted callers forget what they traced, before and
+    after: a test that patches what a kernel's body calls must not meet,
+    or leave, a trace of the other body."""
+    def forget():
+        fa._flash_fwd_impl.clear_cache()
+        fa._flash_bwd_impl.clear_cache()
+    forget()
+    yield forget
+    forget()
+
+
+@pytest.mark.parametrize("bodies, pin", [
+    ("one_masked_body",
+     "87ce996fa8e50d2dd795149e030aa8b9f4c1dd8b238dae133638605f1ee2b108"),
+    ("two_bodies",
+     "03a1ae93db44fdd83727f08c3f1facfbc6bfa82b94c0f7d6c8041635ba96d8b8"),
+])
+def test_equal_widths_lower_as_before_the_second_width(monkeypatch, retraced,
+                                                       bodies, pin):
     """PR 39 gave the kernels a value width of their own. With v as wide
     as q and k the three kernels lower to the text they lowered to at
     ``3f4a582`` (interpret mode: plain HLO, no source position in it):
     forward, dQ and dK/dV at 1 x 256 x 2 x 64, causal, blocks of 128. The
-    pair is what a shape over the fused backward's budget still runs."""
+    pair is what a shape over the fused backward's budget still runs.
+
+    Since PR 62 a kernel holds its block's body twice, under a branch on
+    the block's position (``_interior``), so the lowered text gained a
+    branch and its pin moved: ``03a1ae93`` is the text of PR 62's own
+    commit, the child of ``e72435c``. The pin of ``3f4a582`` stays beside
+    it: with ``_interior`` answering ``False`` no second body is built,
+    and what is left — the body every edge block runs — is that commit's
+    text to the byte."""
     import hashlib
 
     monkeypatch.setattr(fa, "backward_form", lambda *shape: "pair")
+    if bodies == "one_masked_body":
+        monkeypatch.setattr(fa, "_interior", lambda *block: False)
     x = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.float32)
     text = jax.jit(jax.grad(
         lambda q, k, v: flash_attention(q, k, v, True, None, 128, 128).sum(),
         argnums=(0, 1, 2))).lower(x, x, x).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == (
-        "87ce996fa8e50d2dd795149e030aa8b9f4c1dd8b238dae133638605f1ee2b108")
+    assert hashlib.sha256(text.encode()).hexdigest() == pin
+
+
+# s_q, s_k, block_q, block_k, causal, window, interior blocks as the
+# backward counts them (queries checked) | as the forward does
+INTERIOR_CASES = {
+    "causal": (128, 128, 16, 32, True, None, 12, 12),
+    "causal_wide_query_blocks": (128, 128, 32, 16, True, None, 12, 12),
+    "causal_square_blocks": (128, 128, 32, 32, True, None, 6, 6),
+    "one_block": (32, 32, 32, 32, True, None, 0, 0),
+    "not_causal": (64, 96, 16, 32, False, None, 12, 12),
+    "not_causal_unaligned": (50, 70, 16, 32, False, None, 6, 8),
+    "window_shorter_than_a_block": (128, 128, 16, 32, True, 10, 0, 0),
+    "window_of_a_block": (128, 128, 32, 32, True, 32, 0, 0),
+    # the nearest corners an interior block's farthest pair can have lie
+    # bq + bk - 2 = 46 apart; on this grid they lie 47 apart
+    "window_one_short_of_the_rule": (128, 128, 16, 32, True, 46, 0, 0),
+    "window_one_short_of_an_interior_block": (128, 128, 16, 32, True, 47,
+                                              0, 0),
+    "window_of_the_first_interior_block": (128, 128, 16, 32, True, 48, 3, 3),
+    "window_of_three_blocks": (256, 256, 32, 32, True, 96, 13, 13),
+    "rectangular_causal_more_keys": (96, 160, 16, 32, True, None, 6, 6),
+    "rectangular_causal_more_queries": (160, 96, 16, 32, True, None, 18, 18),
+    "unaligned": (100, 100, 16, 32, True, None, 6, 9),
+    "unaligned_window": (100, 100, 16, 32, True, 60, 2, 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(INTERIOR_CASES))
+def test_a_block_is_interior_exactly_where_its_mask_is_all_true(case):
+    """``_interior`` from a block's corners against ``_mask`` of the block,
+    at every position of the grid, the way the forward asks (no query
+    length) and the way the backward does: it never spares a block a mask
+    could change, and it engages wherever it could."""
+    s_q, s_k, bq, bk, causal, window, checked, unchecked = (
+        INTERIOR_CASES[case])
+    for seq_q, count in ((s_q, checked), (None, unchecked)):
+        found = 0
+        for q_start in range(0, s_q, bq):
+            for k_start in range(0, s_k, bk):
+                args = (q_start, k_start, bq, bk, seq_q, s_k, causal, window)
+                interior = bool(fa._interior(*args))
+                assert interior == bool(fa._mask(*args).all()), args
+                found += interior
+        assert found == count, seq_q
+
+
+@pytest.mark.parametrize("window", [None, 160], ids=["causal", "window_160"])
+def test_flash_four_by_four_blocks_match_full(rng, window):
+    """Forward and gradients over 4 x 4 blocks of 64, where six blocks (one
+    under the window, at the band's corner) run with no mask formed and
+    the diagonal's and the band's edge run masked, against the XLA form."""
+    q, k, v = _qkv(rng, b=1, s=256, h=2, d=32)
+    assert fa._interior(192, 0, 64, 64, 256, 256, True, None)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, None, 64, 64, None, window)
+
+    def full(q, k, v):
+        return full_attention(q, k, v, causal=True, window=window)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(full(q, k, v)),
+                               rtol=2e-5, atol=2e-6)
+    g1 = jax.grad(lambda *a: (flash(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    g2 = jax.grad(lambda *a: (full(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-4)
 
 
 # batch, s_q, s_k, query heads, key heads, key width, value width, causal,
@@ -219,6 +318,12 @@ FUSED_CASES = {
     "eight_by_four_blocks": (1, 512, 512, 2, 1, 64, 64, True, None,
                              jnp.bfloat16),
     "float32": (1, 300, 300, 2, 2, 32, 32, True, None, jnp.float32),
+    # a window of 191 keys or more has interior blocks at 64 x 128: these
+    # walk the unmasked body and the masked one in all three kernels
+    "window_with_interior_blocks": (1, 512, 512, 4, 2, 64, 64, True, 300,
+                                    jnp.bfloat16),
+    "window_with_interior_blocks_unaligned": (2, 600, 600, 2, 1, 64, 64,
+                                              True, 384, jnp.bfloat16),
 }
 
 
@@ -251,6 +356,41 @@ def test_fused_backward_is_the_pair_to_the_last_bit(rng, monkeypatch, case):
                                       np.asarray(want, np.float32), name)
 
 
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+def test_interior_blocks_change_no_bit(rng, monkeypatch, retraced, case):
+    """With ``_interior`` answering ``False`` every live block runs the one
+    masked body, the kernels as they were before PR 62. Out, logsumexp,
+    dQ, dK and dV with the rule in place are those to the last bit: on an
+    interior block the mask is all true and the select returns its
+    operand. At a scale of 1 / 8: interpreted, a body is a computation
+    XLA's CPU backend fuses by itself, and with no select between them it
+    contracts ``s * scale - m`` into one fused multiply-add, which rounds
+    once where the masked body rounds twice unless the product is exact
+    (at 1 / sqrt(128) a third of the outputs differ by an ulp). Mosaic
+    contracts nothing: on the chip the bits are equal at every width
+    (PERF.md section 6, PR 62)."""
+    b, s_q, s_k, h, h_kv, d, d_v, causal, window, dtype = FUSED_CASES[case]
+    q = jnp.asarray(rng.standard_normal((b, s_q, h, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((b, s_k, h_kv, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((b, s_k, h_kv, d_v)), dtype)
+    g = jnp.asarray(rng.standard_normal((b, s_q, h, d_v)), dtype)
+    monkeypatch.setattr(fa, "_blocks", lambda *shape: (64, 128))
+
+    def run():
+        out, res = fa._flash_fwd(q, k, v, causal, 0.125, None, None, None,
+                                 window)
+        return (out, res[-1]) + fa._flash_bwd(causal, 0.125, None, None,
+                                              None, window, res, g)
+
+    got = run()
+    retraced()
+    monkeypatch.setattr(fa, "_interior", lambda *block: False)
+    want = run()
+    for name, a, b_ in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b_, np.float32), name)
+
+
 # s_q, s_k, key width, value width, query heads a key head, window: what
 # each cell's attention would ask (BERT's and Keye's never do: the XLA
 # form at s 128, ``ops/sparse_flash.py``)
@@ -278,6 +418,10 @@ BACKWARD_FORMS = {
         (16384, 16384, 256, 256, 8, None), "fused"),
     "zaya1-8b.collective-cca.1chip": (
         (16384, 16384, 128, 128, 4, None), "fused"),
+    "mellum2-12b-a2.5b.collective-swa-moe.1chip, global": (
+        (8192, 8192, 128, 128, 8, None), "fused"),
+    "mellum2-12b-a2.5b.collective-swa-moe.1chip, windowed": (
+        (8192, 8192, 128, 128, 8, 1024), "fused"),
     # 32,768 keys 256 wide: 64 MiB of float32 dK and dV, 64 more of their
     # output blocks' two buffers, against a limit of 96
     "too long for the limit": ((32768, 32768, 256, 256, 8, None), "pair"),
