@@ -93,13 +93,14 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
-def causal_conv(x, w):
+def causal_conv(x, w, bias=None):
     """Depthwise causal convolution: x [b, s, channels], w [taps,
     channels]; ``y_t = sum_i w[i] x_{t - taps + 1 + i}``, zeros before the
-    sequence."""
+    sequence, plus ``bias`` [channels] where one is given."""
     taps, s = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    return sum(padded[:, i:i + s] * w[i] for i in range(taps))
+    y = sum(padded[:, i:i + s] * w[i] for i in range(taps))
+    return y if bias is None else y + bias
 
 
 class KimiDeltaAttention(nn.Module):
@@ -227,11 +228,26 @@ class KimiLatentAttention(nn.Module):
                 out.reshape(b, s, self.heads * self.v_dim))
 
 
+class Relu2MLP(nn.Module):
+    """The ungated feed-forward ``W_down relu(W_up x)^2``."""
+
+    mlp_dim: int
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        return dense(x.shape[-1], name="down")(
+            jnp.square(nn.relu(dense(self.mlp_dim, name="up")(x))))
+
+
 class KimiSparseMoe(nn.Module):
-    """Router over ``num_experts`` and its selection bias; the SwiGLU
-    experts ``first_expert .. first_expert + num_local_experts - 1`` of
-    width ``mlp_dim`` held here; ``shared`` experts' worth of one SwiGLU
-    that every token passes (0: no such module is built, nothing added)."""
+    """Router over ``num_experts`` and its selection bias; the experts
+    ``first_expert .. first_expert + num_local_experts - 1`` of width
+    ``mlp_dim`` held here; ``shared`` experts' worth of one expert of the
+    same body that every token passes (0: no such module is built, nothing
+    added). The body is SwiGLU, or with ``gated`` False the ungated
+    ``down(relu(up(x))^2)``: no ``gate`` matrix is built, held or shared."""
 
     num_experts: int
     num_local_experts: int
@@ -246,6 +262,7 @@ class KimiSparseMoe(nn.Module):
     # the shared expert's output times sigmoid(h w_sg), w_sg [d, 1]
     shared_gate: bool = False
     aux: bool = False             # True: returns (y, load-balance loss)
+    gated: bool = True            # False: relu^2 experts of two matrices
 
     @nn.compact
     def __call__(self, x):
@@ -258,7 +275,8 @@ class KimiSparseMoe(nn.Module):
             x.reshape(b * s, d),
             self.param("router", nn.initializers.lecun_normal(),
                        (d, self.num_experts), jnp.float32),
-            self.param("gate", init, (held, d, m), jnp.float32),
+            self.param("gate", init, (held, d, m), jnp.float32)
+            if self.gated else None,
             self.param("up", init, (held, d, m), jnp.float32),
             self.param("down", init, (held, m, d), jnp.float32),
             top_k=self.top_k, dtype=self.dtype,
@@ -274,7 +292,8 @@ class KimiSparseMoe(nn.Module):
             y = y.reshape(b, s, d)
             if not self.shared:
                 return (y, load_balance) if self.aux else y
-            shared = LlamaMLP(self.shared * m, self.dtype, name="shared")(x)
+            shared = (LlamaMLP if self.gated else Relu2MLP)(
+                self.shared * m, self.dtype, name="shared")(x)
             if self.shared_gate:
                 shared = shared * jax.nn.sigmoid(nn.Dense(
                     1, use_bias=False, dtype=self.dtype,

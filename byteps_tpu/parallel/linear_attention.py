@@ -1,6 +1,8 @@
 """Gated delta-rule linear attention with a per-channel decay (KDA: Kimi
 Linear, Moonshot AI 2025, arXiv 2510.26692; the public ``fla`` layer
-``KimiDeltaAttention``) as a chunked scan.
+``KimiDeltaAttention``) as a chunked scan, and its two siblings: one decay
+a head (Gated DeltaNet) and no delta rule at all (Mamba-2's state-space
+scan).
 
 Per head, a float32 state ``S`` [d_k, d_v] (keys x values), ``S_0 = 0``:
 
@@ -92,6 +94,31 @@ The decay broadcast over the channels into the per-channel kernels instead
 (16 MB of float32 decay a layer and 1000 tokens) had moved no step at s
 8,192 and did not compile in the step at 16,384 (PERF.md section 6, PR 50).
 
+**No delta rule** (the selective state-space recurrence of Mamba-2, Dao &
+Gu 2024, arXiv 2405.21060; ``ssd_scan``): per head a float32 state ``S``
+[n, p] (state entries x channels) under one decay a head,
+
+    S_t = e^{g_t} S_{t-1} + B_t (dt_t x_t)^T,        y_t = S_t^T C_t,
+
+``C`` the query, ``B`` the key, ``x`` the value and the step ``dt`` the
+write strength, with ``B`` and ``C`` in groups under the heads as key heads
+lie under value heads above. It is the per-head form with the triangular
+system gone: a token writes ``dt x`` whatever the state holds, so ``U`` is
+``dt x`` itself, ``W`` is zero, and the pairs are ``P(C, B)[i, j] = (C_i .
+B_j) e^{G_i - G_j}`` under the same rule — masked to ``-inf`` above the
+diagonal before the ``exp``, never clamped. With no ``W S`` nothing of a
+chunk but its first state waits for the chunk before it: a group's pairs,
+its products with the writes and every chunk's share of the next state
+``(B e^{G_C - G})^T U`` are batched products over the group's chunks, the
+pairs and that share multiplied once a *group* of ``B`` and ``C`` and not
+once a head, and what runs chunk after chunk is ``S <- e^{G_C} S + Z``.
+It shares ``chunked``, ``chunk_log_decay``, the pair-and-mask code and the
+two-level scan over groups of ``SSD_GROUP`` chunks. One form, XLA's, on
+every backend: this state is 128 x 64 a head at the one configuration that
+runs it and both kernel pairs are 128 x 128, so ``kda_form`` has no answer
+for it and its call sites have a counter of their own
+(``bps_ssm_scan_sites_total``).
+
 **The scan over chunks has two forms too, and the operands' form picks
 it.** Where XLA builds the operands (``"xla"`` and ``"head"``) the scan has
 two levels, groups of chunks and the chunks of a group, and its backward
@@ -155,6 +182,10 @@ HEAD_SITES = "bps_kda_head_sites_total"
 # ... and of those, the ones whose operands are the kernel pair of
 # ``byteps_tpu.ops.gdn_chunk``
 HEAD_KERNEL_SITES = "bps_kda_head_kernel_sites_total"
+# ... where there is no delta rule at all (the selective state-space scan),
+# with its own counter: no ``kda_form`` answers for it
+SSM_PREP_SCOPE, SSM_SCAN_SCOPE = "bps.ssm.prep", "bps.ssm.scan"
+SSM_SCAN_SITES = "bps_ssm_scan_sites_total"
 # ... and of all scan sites, those whose scan over chunks is the kernel pair
 # of ``byteps_tpu.ops.kda_recurrence`` (both kernel forms': it is their
 # layout it reads)
@@ -187,6 +218,18 @@ HEAD_KERNEL_CHUNK, HEAD_KERNEL_ROWS = 32, 1024
 # for it, at s 16384 (my chip runs, PR 54): 4 chunks 52.5 ms, 8 51.1, 16
 # 53.3, 32 59.2 against ``_recurrence`` at 4 chunks 50.9, which stays.
 HEAD_GROUP = 4
+
+# The chunks of a group in the state-space scan, whose pairs, chunk states
+# and products are computed (and recomputed, and differentiated) a group at
+# a time: everything of a group but the few multiply-adds that hand the
+# state from chunk to chunk is one batched product over its chunks. On a TPU
+# v5e at [1, 16384, 64, 64] over 8 groups of state 128, bf16, forward +
+# backward (PERF.md section 6, my chip runs, PR 63): chunks of 128 in groups
+# of 1 29.3 ms, 2 20.5, 4 20.5, 8 20.0 (18.6 at 4 in that call), 16 28.0,
+# 32 32.2; chunks of 256 in groups of 1 22.5, 2 20.3, 4 21.0, 8 28.3; chunks
+# of 64 35.5 at 8 and worse above; 512 27.2 at 1. Flat from 2 to 8 at 128:
+# 4 holds half the float32 masks of 8 alive.
+SSD_GROUP = 4
 
 # float32 operands as three bf16 passes: the triangular system's inverse and
 # the products between sub-chunks need more than the one pass a TPU gives a
@@ -286,6 +329,14 @@ def _chunk_operands(q, k, v, beta, G, sub, dtype):
             jnp.exp(total[..., 0, :]), a_q.astype(dtype))
 
 
+def _pair_decay(G, i, j):
+    """``e^{G_i - G_j}`` for the pairs ``j <= i`` of a chunk, 0 above the
+    diagonal: G [..., h, C], one decay a head; masked to ``-inf`` before
+    the ``exp``, so that no positive number is exponentiated."""
+    return jnp.exp(jnp.where(j <= i, G[..., :, None] - G[..., None, :],
+                             -jnp.inf))
+
+
 def _head_operands(q, k, v, beta, G, dtype):
     """``_chunk_operands`` for one decay a head (module docstring): G [...,
     h, C]; q, k [..., h_k, C, d_k] with ``h_k`` a divisor of h; v [..., h,
@@ -293,8 +344,7 @@ def _head_operands(q, k, v, beta, G, dtype):
     it scales every row of the state alike."""
     groups, c = G.shape[-2] // q.shape[-3], G.shape[-1]
     i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
-    decay = jnp.exp(jnp.where(j <= i, G[..., :, None] - G[..., None, :],
-                              -jnp.inf))                # [..., h, C, C]
+    decay = _pair_decay(G, i, j)                        # [..., h, C, C]
 
     def pairs(a):       # a key head's products, under each of its decays
         return jnp.repeat(jnp.einsum("...id,...jd->...ij", a, k,
@@ -373,6 +423,26 @@ def _recurrence(state, w, u_v, q_g, k_d, gamma, a_q, dtype):
         return state, o
 
     return lax.scan(body, state, (w, u_v, q_g, k_d, gamma, a_q))
+
+
+def _scan_groups(one_group, state, xs, group: int):
+    """The scan's two levels: ``xs`` [b, n, C, h, ...] in groups of ``group``
+    chunks, ``one_group(state, xs of a group [b, group, h, C, ...]) ->
+    (state, o [group, b, h, C, d_v])`` one at a time from a zero state of
+    shape ``state`` and recomputed in the backward pass, so that the scan
+    keeps one state a group and a group's operands are alive for that group
+    alone. Returns o [b, n C, h, d_v]."""
+    b, n, chunk, h = xs[-1].shape[:4]
+
+    def grouped(x):              # [b, n, C, h, ...] -> [n / group, b,
+        x = x.reshape(b, n // group, group, *x.shape[2:])      # group,
+        return jnp.moveaxis(x, 1, 0).swapaxes(3, 4)       # h, C, ...]
+
+    xs = tuple(grouped(x) for x in xs)
+    o = lax.scan(jax.checkpoint(one_group), jnp.zeros(state, jnp.float32),
+                 xs)[1]
+    # [n / group, group, b, h, C, d_v] -> [b, n C, h, d_v]
+    return o.transpose(2, 0, 1, 4, 3, 5).reshape(b, n * chunk, h, -1)
 
 
 def kda_attention(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
@@ -460,34 +530,97 @@ def kda_attention(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
         operands = _head_operands if per_head else partial(
             _chunk_operands, sub=sub)
 
-        def grouped(x):              # [b, n, C, h, ...] -> [n / group, b,
-            x = x.reshape(b, n // group, group, *x.shape[2:])      # group,
-            return jnp.moveaxis(x, 1, 0).swapaxes(3, 4)       # h, C, ...]
-
-        @jax.checkpoint
         def one_group(state, xs):
             return _recurrence(state, *(
                 jnp.moveaxis(x, 1, 0) for x in operands(*xs, dtype=dtype)),
                 dtype)
 
-        xs = tuple(grouped(x) for x in (*tokens, G))
-        o = lax.scan(one_group, jnp.zeros(state, f32), xs)[1]
-        # [n / group, group, b, h, C, d_v] -> [b, s, h, d_v]
-        return o.transpose(2, 0, 1, 4, 3, 5).reshape(
-            b, n * chunk, h, -1)[:, :s]
+        return _scan_groups(one_group, state, (*tokens, G), group)[:, :s]
 
 
-def publish_kda_stats(kda_stats) -> dict:
+def _ssd_group(state, xs, dtype):
+    """One group of chunks of the state-space scan from ``state`` [b, h, n,
+    p]: c, b [b, group, h_k, C, n], u [b, group, h, C, p] (the write,
+    ``dt x``) and G [b, group, h, C] float32 -> (the state after the group,
+    y [group, b, h, C, p] float32). A token's output is its chunk's pairs
+    over the chunk's writes plus the chunk's first state read through its
+    own decay; every chunk's share of the next state is known before any
+    state is, so that what runs chunk after chunk is ``S <- e^{G_C} S + Z``
+    and nothing else. A group's ``B`` and ``C`` are multiplied once a key
+    head, never repeated over the heads they serve."""
+    c, b, u, G = xs
+    lead, h = G.shape[:2], G.shape[2]
+    groups = h // c.shape[2]
+    size = G.shape[-1]
+    i, j = jnp.arange(size)[:, None], jnp.arange(size)[None, :]
+
+    def product(spec, x, y):
+        return jnp.einsum(spec, x.astype(dtype), y.astype(dtype),
+                          preferred_element_type=jnp.float32)
+
+    def by_key_head(x):     # [b, group, h, ...] -> [b, group, h_k, r, ...]
+        return x.reshape(*lead, h // groups, groups, *x.shape[3:])
+
+    pairs = (jnp.repeat(product("...id,...jd->...ij", c, b), groups, 2)
+             * _pair_decay(G, i, j))                   # [b, group, h, C, C]
+    total = G[..., -1:]                             # a chunk's whole decay
+    z = product("bgkcn,bgkrcp->bgkrnp", b,
+                by_key_head(u * jnp.exp(total - G)[..., None]))
+    state, first = lax.scan(
+        lambda state, chunk: (chunk[0] * state + chunk[1], state), state,
+        (jnp.moveaxis(jnp.exp(total)[..., None], 1, 0),
+         jnp.moveaxis(z.reshape(*lead, h, *z.shape[-2:]), 1, 0)))
+    read = product("bgkcn,bgkrnp->bgkrcp", c, by_key_head(
+        jnp.moveaxis(first, 0, 1))).reshape(u.shape)
+    y = product("...ij,...jp->...ip", pairs, u) + jnp.exp(G)[..., None] * read
+    return state, jnp.moveaxis(y, 1, 0)
+
+
+def ssd_scan(c, b, x, g, dt, *, chunk: int = 128, dtype=jnp.bfloat16):
+    """``y`` [b, s, h, p] float32 of the selective state-space recurrence
+    (Mamba-2's SSD): per head a float32 state ``S`` [n, p] from zero,
+
+        S_t = e^{g_t} S_{t-1} + B_t (dt_t x_t)^T,        y_t = S_t^T C_t.
+
+    c, b [b, s, h_k, n] (``h_k`` groups, a divisor of h: group j serves
+    heads ``j h / h_k .. (j + 1) h / h_k - 1``), x [b, s, h, p], g the
+    log-decay (<= 0) and dt the step, [b, s, h]. ``chunk`` need not divide
+    s. The module's chunk algebra with one decay a head and the triangular
+    system gone: ``U`` is ``dt x`` itself and ``W`` zero (module
+    docstring)."""
+    h = x.shape[2]
+    if not (c.shape == b.shape and c.shape[:2] == x.shape[:2]
+            and h % c.shape[2] == 0 and g.shape == dt.shape == x.shape[:3]):
+        raise ValueError("ssd_scan: c, b [b, s, h_k, n] (h_k a divisor of "
+                         "h), x [b, s, h, p], g, dt [b, s, h]; got "
+                         f"{c.shape}, {b.shape}, {x.shape}, {g.shape}, "
+                         f"{dt.shape}")
+    s = x.shape[1]
+    metrics.inc_counter(SSM_SCAN_SITES)
+    f32 = jnp.float32
+    with jax.named_scope(SSM_PREP_SCOPE):
+        G = chunk_log_decay(g, chunk)                   # [b, n, C, h]
+    with jax.named_scope(SSM_SCAN_SCOPE):
+        u = dt.astype(f32)[..., None] * x.astype(f32)
+        tokens = tuple(chunked(t.astype(f32), chunk) for t in (c, b, u))
+        return _scan_groups(
+            partial(_ssd_group, dtype=dtype),
+            (x.shape[0], h, c.shape[3], x.shape[3]), (*tokens, G),
+            _divisor(G.shape[1], SSD_GROUP))[:, :s]
+
+
+def publish_kda_stats(kda_stats,
+                      gauge: str = "bps_kda_min_chunk_log_decay") -> dict:
     """The ``"kda_stats"`` collection of a model applied with it mutable
     (every leaf the most negative cumulated log-decay of a chunk, one per
     KDA layer) to ``monitor/metrics.py``: gauge
     ``bps_kda_min_chunk_log_decay``, the least over the layers — under -87.3
     ``exp`` of it is 0 in float32, and a form that exponentiated its
-    negation would have overflowed. Returns what it published."""
+    negation would have overflowed. ``gauge``: another name for the same
+    reading of another scan (``bps_ssm_min_chunk_log_decay`` of a
+    state-space model's ``"ssm_stats"``). Returns what it published."""
     leaves = [float(x) for x in jax.tree_util.tree_leaves(kda_stats)]
     if not leaves or not all(map(math.isfinite, leaves)):
         return {}
-    out = {"bps_kda_min_chunk_log_decay": min(leaves)}
-    metrics.set_gauge("bps_kda_min_chunk_log_decay",
-                      out["bps_kda_min_chunk_log_decay"])
-    return out
+    metrics.set_gauge(gauge, min(leaves))
+    return {gauge: min(leaves)}

@@ -14,15 +14,18 @@ computation otherwise.
 ``dropless_moe_ffn`` is the other design, for models that route every token
 to its ``top_k`` experts whatever the load (OLMoE, Mixtral): no capacity and
 no ``[T, E, C]`` one-hot (0.67 GB each way at 4096 tokens x 64 experts x 8),
-but the assignments sorted by expert and three grouped matmuls over the
-sorted rows. A device holds all experts or a contiguous share of them
-(``first_expert``): it routes over all, computes its own experts' part of
-the result and leaves the rest out; it has no ``ep_axis`` (no exchange) yet.
+but the assignments sorted by expert and the experts' two or three grouped
+matmuls over the sorted rows. A device holds all experts or a contiguous
+share of them (``first_expert``): it routes over all, computes its own
+experts' part of the result and leaves the rest out; it has no ``ep_axis``
+(no exchange) yet.
 The shares that run today (``benchmark/configs``): 8 of 256 at top-8
 (Kimi-Linear, JoyAI), 16 of 128 (Keye) and 16 of 256 (Laguna) at top-8, 32
-of 512 at top-10 (Qwen3-Next), 8 of 16 at top-1 (ZAYA1) and 16 of 64 at
+of 512 at top-10 (Qwen3-Next), 8 of 16 at top-1 (ZAYA1), 16 of 64 at
 top-8 (Mellum2: a quarter of the experts, two held assignments a token, a
-pass of half of all T k rows).
+pass of half of all T k rows) and 8 of 128 at top-6 (Nemotron-H, whose
+experts are the layer's second body: ungated, ``down(relu(up(x))^2)``, two
+grouped matmuls where SwiGLU has three).
 """
 
 from __future__ import annotations
@@ -201,6 +204,8 @@ def moe_ffn(
 
 ROUTE_SCOPE = "bps.moe.route"      # router, top-k, sort, gather, combine
 EXPERTS_SCOPE = "bps.moe.experts"  # the grouped matmuls and their casts
+# the calls, at trace time, whose experts have no gate projection
+UNGATED_SITES = "bps_moe_ungated_sites_total"
 
 
 @jax.custom_vjp
@@ -243,9 +248,12 @@ def held_row_bound(t: int, top_k: int, held: int, e: int) -> int:
     return min(rows, -(-room // HELD_ROWS_MULTIPLE) * HELD_ROWS_MULTIPLE)
 
 
-def _grouped_ffn(xs, w_gate, w_up, w_down, groups, rows, dtype):
-    """``down(silu(gate(xs)) * up(xs))`` over rows sorted by expert; ``rows``
-    marks those inside ``groups`` where the groups do not cover them all."""
+def _grouped_ffn(xs, weights, groups, rows, dtype):
+    """An expert's body over rows sorted by expert, by the matrices it has:
+    ``down(silu(gate(xs)) * up(xs))`` of ``weights`` (gate, up, down), three
+    grouped matmuls, or the ungated ``down(relu(up(xs))^2)`` of (up, down),
+    two. ``rows`` marks the rows inside ``groups`` where the groups do not
+    cover them all."""
     with jax.named_scope(EXPERTS_SCOPE):
         # lax.ragged_dot: row i of the sorted rows times the matrix of its
         # group, float32 accumulation, result in `dtype`. The TPU compiler
@@ -260,9 +268,13 @@ def _grouped_ffn(xs, w_gate, w_up, w_down, groups, rows, dtype):
             # a share takes zeros there, forward and backward
             return out if rows is None else jnp.where(rows, out, 0)
 
-        gate = grouped(xs, w_gate)
-        up = grouped(xs, w_up)
-        return grouped(jax.nn.silu(gate) * up, w_down)
+        *first, w_down = weights
+        if len(first) == 2:
+            gate = grouped(xs, first[0])
+            up = grouped(xs, first[1])
+            return grouped(jax.nn.silu(gate) * up, w_down)
+        return grouped(jnp.square(jax.nn.relu(grouped(xs, first[0]))),
+                       w_down)
 
 
 # jitted: the written-out pass and the loop's, forward and backward, in every
@@ -270,8 +282,7 @@ def _grouped_ffn(xs, w_gate, w_up, w_down, groups, rows, dtype):
 # and one lowered function serve them all (≈ 1 s less set-up on the chip's
 # host; the compiled step is the same to the byte of its memory)
 @partial(jax.jit, static_argnums=(0, 1))
-def _held_pass(dtype, bound, start, y, x, top_w, w_gate, w_up, w_down, order,
-               groups):
+def _held_pass(dtype, bound, start, y, x, top_w, weights, order, groups):
     """``y`` [T, D] float32 plus the held experts' part of the layer for
     the sorted rows ``start .. start + bound - 1``: those rows gathered
     from ``x`` (float32, so that its gradient adds in float32) by token,
@@ -286,25 +297,22 @@ def _held_pass(dtype, bound, start, y, x, top_w, w_gate, w_up, w_down, order,
         rows = (jnp.arange(bound) < ends[-1])[:, None]
         xs = jnp.where(rows, x[token].astype(dtype), 0)
         weight = top_w.reshape(-1)[first]
-    ys = _grouped_ffn(xs, w_gate, w_up, w_down, jnp.diff(ends, prepend=0),
-                      rows, dtype)
+    ys = _grouped_ffn(xs, weights, jnp.diff(ends, prepend=0), rows, dtype)
     with jax.named_scope(ROUTE_SCOPE):
         return y.at[token].add(ys * weight[:, None])
 
 
-def _pass_operands(bound, dtype, x, top_w, w_gate, w_up, w_down, order,
-                   groups):
+def _pass_operands(bound, dtype, x, top_w, weights, order, groups):
     """What every pass reads, made once and under the scope that reads it:
-    x in float32, the weights in ``dtype``, the order padded to whole
-    passes."""
+    x in float32, the weights (the experts' two or three matrices, a tuple)
+    in ``dtype``, the order padded to whole passes."""
     with jax.named_scope(ROUTE_SCOPE):
         x = x.astype(jnp.float32)
     with jax.named_scope(EXPERTS_SCOPE):
-        w_gate, w_up, w_down = (w.astype(dtype)
-                                for w in (w_gate, w_up, w_down))
+        weights = tuple(w.astype(dtype) for w in weights)
     with jax.named_scope(ROUTE_SCOPE):
         order = jnp.pad(order, (0, -order.size % bound))
-    return x, top_w, w_gate, w_up, w_down, order, groups
+    return x, top_w, weights, order, groups
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -340,7 +348,7 @@ def _held_rows_fwd(bound, dtype, *args):
 
 def _held_rows_bwd(bound, dtype, args, g):
     *floats, order, groups = _pass_operands(bound, dtype, *args)
-    held_rows = groups.sum()
+    floats, held_rows = tuple(floats), groups.sum()
 
     def pulled(start):
         """The float operands' gradients through the pass at ``start``."""
@@ -355,17 +363,18 @@ def _held_rows_bwd(bound, dtype, args, g):
         layer alive to the optimizer: + 0.9% of the Keye cell's memory)."""
         totals = lax.while_loop(
             lambda at: at[0] < held_rows,
-            lambda at: (at[0] + bound, tuple(
-                total + part.astype(jnp.float32)
-                for total, part in zip(at[1], pulled(at[0])))),
-            (jnp.int32(0), tuple(
-                jnp.zeros(f.shape, jnp.float32) for f in floats)))[1]
-        return tuple(total.astype(f.dtype)
-                     for total, f in zip(totals, floats))
+            lambda at: (at[0] + bound, jax.tree_util.tree_map(
+                lambda total, part: total + part.astype(jnp.float32),
+                at[1], pulled(at[0]))),
+            (jnp.int32(0), jax.tree_util.tree_map(
+                lambda f: jnp.zeros(f.shape, jnp.float32), floats)))[1]
+        return jax.tree_util.tree_map(
+            lambda total, f: total.astype(f.dtype), totals, floats)
 
     grads = lax.cond(held_rows <= bound, lambda: pulled(jnp.int32(0)), summed)
-    return (*(grad.astype(arg.dtype) for grad, arg in zip(grads, args)),
-            None, None)
+    return (*jax.tree_util.tree_map(
+        lambda grad, arg: grad.astype(arg.dtype), grads, tuple(args[:3])),
+        None, None)
 
 
 _held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
@@ -374,7 +383,7 @@ _held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
 def dropless_moe_ffn(
     x: jax.Array,
     router_w: Optional[jax.Array],
-    w_gate: jax.Array,
+    w_gate: Optional[jax.Array],
     w_up: jax.Array,
     w_down: jax.Array,
     *,
@@ -388,8 +397,11 @@ def dropless_moe_ffn(
     routed_scale: float = 1.0,
     logits: Optional[jax.Array] = None,
 ):
-    """Dropless top-k SwiGLU expert layer: every token reaches its
-    ``top_k`` experts.
+    """Dropless top-k expert layer: every token reaches its ``top_k``
+    experts. An expert has one of two bodies, by the matrices it is given:
+    SwiGLU, ``down(silu(gate(x)) * up(x))``, or with ``w_gate`` None the
+    ungated ``down(relu(up(x))^2)`` (Nemotron-H's), two grouped matmuls
+    where the other has three, through the same sort, passes and masks.
 
     x: [T, D]; router_w: [D, E]; w_gate, w_up: [H, D, M]; w_down:
     [H, M, D], the weights of the H <= E experts ``first_expert ..
@@ -407,10 +419,10 @@ def dropless_moe_ffn(
     ``norm_eps`` joins the renormalisation's denominator (the source's
     1e-20) and ``routed_scale`` multiplies the weights. At their defaults
     the four change nothing. The assignments are sorted by expert
-    (stable), the tokens gathered into that order,
-    ``down(silu(gate(x)) * up(x))`` computed as three grouped matmuls with
-    ``dtype`` operands and float32 accumulation, and the rows un-permuted
-    and summed with their weights in float32.
+    (stable), the tokens gathered into that order, the experts' body
+    computed as its grouped matmuls with ``dtype`` operands and float32
+    accumulation, and the rows un-permuted and summed with their weights in
+    float32.
 
     A share (H < E) routes over all E all the same and computes the
     assignments that fall to its own experts, all of them whatever the
@@ -436,7 +448,12 @@ def dropless_moe_ffn(
         raise ValueError("give router_w or the caller's own logits, one of "
                          "the two")
     e = (router_w if logits is None else logits).shape[1]
-    held = w_gate.shape[0]
+    weights = (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down)
+    if w_gate is None:
+        from byteps_tpu.monitor import metrics
+
+        metrics.inc_counter(UNGATED_SITES)
+    held = w_up.shape[0]
     if not 1 <= top_k <= e:
         raise ValueError(f"top_k must be in 1..{e}, got {top_k}")
     if not 0 <= first_expert <= e - held:
@@ -483,15 +500,15 @@ def dropless_moe_ffn(
                         * probs.mean(axis=0)).sum() * (e / (t * top_k))
         z_loss = jnp.mean(lse * lse)
     if held == e:
-        ys = _grouped_ffn(xs, w_gate, w_up, w_down, counts, None, dtype)
+        ys = _grouped_ffn(xs, weights, counts, None, dtype)
         with jax.named_scope(ROUTE_SCOPE):
             y = jnp.einsum("tkd,tk->td",
                            _permute(ys, back, order).reshape(t, top_k, d),
                            top_w, preferred_element_type=jnp.float32)
     else:
         y = _held_rows(
-            held_row_bound(t, top_k, held, e), dtype, x, top_w, w_gate, w_up,
-            w_down, order,
+            held_row_bound(t, top_k, held, e), dtype, x, top_w, weights,
+            order,
             lax.slice_in_dim(counts, first_expert, first_expert + held))
     return y.astype(x.dtype), load_balance, z_loss, counts
 

@@ -577,6 +577,62 @@ def test_joyai_collective_step_compiles_for_one_v5e(topo, as_on_a_tpu):
     assert "bps.mtp" in text and "ragged-dot" in text
 
 
+def test_nemotron_collective_step_compiles_for_one_v5e(topo, as_on_a_tpu):
+    """make_train_step over NemotronHModel at the cell's widths and size, b
+    1 x s 16384, adamw, for one described chip — cut to one layer of each
+    kind (``ME*``: a Mamba-2 layer with the state-space scan in its XLA
+    form, an ungated expert layer of 8 held experts with its shared expert,
+    the attention layer at 16 query heads a key head, where the fused
+    backward kernel stays), which is every kind of program the cell's step
+    holds, so that the case stays in tier-1's time (the whole nine layers
+    compile in 44 s to 14.26 GB: ``compiled_bytes`` in the configuration's
+    file)."""
+    import sys
+
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    import byteps_tpu.jax as bps
+    from byteps_tpu.jax.training import make_train_step
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmark.lib import cell as cell_lib
+
+    path = os.path.join(repo, "benchmark", "configs",
+                        "nemotron-3-nano-30b-a3b")
+    cfg = {**cell_lib.load_json(path + ".json"), "num_hidden_layers": 3,
+           "hybrid_override_pattern": "ME*"}
+    assert cfg["seq_len"] == 16384
+    init, loss_fn = cell_lib.load_module(
+        path + ".py", "nemotron_config").build(cfg)
+    tx = optax.adamw(1e-4)
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dcn", "ici"))
+    bps.init(mesh=mesh)
+    step = make_train_step(loss_fn, tx)
+    params = jax.eval_shape(init, jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((1, cfg["seq_len"]), jnp.int32)}
+    compiled = step.lower(
+        _described(mesh, params, P()),
+        _described(mesh, jax.eval_shape(tx.init, params), P()),
+        _described(mesh, batch, P(("dcn", "ici")))).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 16e9
+    text = compiled.as_text()
+    assert "bps_flash_fwd" in text and "bps_flash_bwd" in text
+    assert "bps_flash_dq" not in text       # the fused backward, not the pair
+    assert "ragged-dot" in text
+    for scope in ("bps.ssm.proj", "bps.ssm.prep", "bps.ssm.scan",
+                  "bps.ssm.out", "bps.nattn.attend", "bps.nattn.proj",
+                  "bps.moe.route", "bps.moe.shared"):
+        assert scope in text, scope
+    # no kernel of the delta-rule scans: this scan is XLA's on every backend
+    assert "bps_kda_recurrence" not in text and "bps_gdn" not in text
+
+
 @pytest.mark.slow
 def test_gpt2_124m_collective_step_compiles_for_one_v5e(topo):
     """The whole chip_smoke phase-1 program — make_train_step, GPT-2 124M,

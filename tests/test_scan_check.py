@@ -2,8 +2,9 @@
 runs at the Qwen3-Next cell's shapes passes for the sound scan and fails for
 a bf16 state, a clamped decay and the other head grouping, and so does its
 per-channel twin at the Kimi-Linear cell's shapes (a bf16 state, a clamped
-decay); the 256-wide attention case is ``tools/attention_check.py``'s own
-check."""
+decay) and the scan with no delta rule at the Nemotron cell's (the same
+three controls); the 256-wide attention case is
+``tools/attention_check.py``'s own check."""
 
 import importlib
 import json
@@ -42,6 +43,48 @@ def test_the_per_channel_check_passes_the_scan_and_fails_its_controls(seed):
     for control in record["controls"].values():
         assert max(control.values()) > tool.SCAN_TOLERANCE
     assert record["min_chunk_log_decay"] < tool.CLAMP
+
+
+SMALL_SSD = tool.SsdCase(1024, 2, 4, 32, 16, 64)
+
+
+@pytest.mark.parametrize("seed", (0, 2147483907))
+def test_the_state_space_check_passes_the_scan_and_fails_its_controls(seed):
+    record = tool.check_ssd(SMALL_SSD, seed)
+    assert record["ok"], record
+    assert set(record["scan"]) == set(tool.SSD_TENSORS)
+    assert max(record["scan"].values()) <= tool.SCAN_TOLERANCE
+    assert set(record["controls"]) == {"bf16_state", "heads_interleaved",
+                                       "clamped_at_-20"}
+    for control in record["controls"].values():
+        assert max(control.values()) > tool.SCAN_TOLERANCE
+    assert record["min_chunk_log_decay"] < tool.CLAMP
+
+
+def test_a_wrong_state_space_scan_fails_the_check():
+    """The program computed wrongly (the write unscaled by the step) reads
+    above the tolerance, so ``ok`` is false."""
+    from byteps_tpu.parallel.linear_attention import ssd_scan
+
+    def unscaled(x, b, c, dt, a_log):
+        return ssd_scan(c, b, x, -jnp.exp(a_log) * dt, jnp.ones_like(dt),
+                        chunk=SMALL_SSD.chunk)
+
+    record = tool.check_ssd(SMALL_SSD, 1, scan=unscaled)
+    assert not record["ok"]
+    assert max(record["scan"].values()) > tool.SCAN_TOLERANCE
+
+
+def test_the_state_space_case_is_the_configuration_s():
+    cfg = json.load(open(os.path.join(
+        REPO, "benchmark", "configs", "nemotron-3-nano-30b-a3b.json")))
+    assert tool.ssd_case() == (cfg["seq_len"], 8, 64, 128, 64,
+                               cfg["ssm_chunk"])
+    x, b, c, dt, a_log, w = tool.ssd_inputs(SMALL_SSD, 0)
+    assert x.shape == w.shape == (1, 1024, 4, 16)
+    assert b.shape == c.shape == (1, 1024, 2, 32)
+    assert dt.shape == (1, 1024, 4) and a_log.shape == (4,)
+    assert float(dt.min()) > 0.0
 
 
 def test_a_wrong_scan_fails_the_check():

@@ -5,6 +5,7 @@ and every gradient, tensor by tensor — and the wrong computations the
 tolerance must fail.
 
     chiprun --chips 1 -- python3 tools/scan_check.py [--seed N]
+        [--cases gdn,kda,attention,ssd]
 
 What a benchmark run cannot see (``benchmark/lib/reference.py`` compares
 three losses to 2e-3: PERF.md section 7, "``correct`` by cell") is held
@@ -46,6 +47,16 @@ heads over 2 key heads of 256 under the causal triangle: out, dQ, dK, dV
 within its ``TOLERANCE``, out alone within its ``OUT_TOLERANCE``, the other
 head grouping and bf16 logits and statistics above them.
 
+**The scan with no delta rule** (since PR 63): ``ssd_scan`` at b 1 x s of
+the Nemotron cell, 8 groups of ``B`` and ``C`` of 128 under 64 heads of 64,
+bf16 operands, the file's chunk, against ``benchmark/lib/
+plain_nemotron_h.py::selective_scan`` — ``y`` and the gradients of x, B, C,
+the step and ``A_log`` (the decay ``g = -exp(A_log) dt`` is formed on both
+sides, so that both gradients pass through it) —, the step and the rate
+Mamba's rule spread over the heads as above, the same measure and
+tolerance, and the three controls: the bf16 state, the clamped decay, head i
+reading group ``i % 8``. ``--cases ssd`` runs it alone.
+
 My chip run's readings (PR 50) are in PERF.md section 6. One JSON line a
 case, then ``{"ok": ..., "device": ...}``; off the chip both run at a small
 size (``tests/test_scan_check.py``).
@@ -68,11 +79,17 @@ SCAN_TOLERANCE = 1.0e-2     # every tensor of the scan (PERF.md, PR 50)
 CLAMP = -20.0
 TENSORS = ("o", "dq", "dk", "dv", "dg", "dbeta")
 
+SSD_TENSORS = ("y", "dx", "db", "dc", "ddt", "da_log")
+
 ScanCase = collections.namedtuple(
     "ScanCase", "seq key_heads heads key_dim value_dim chunk")
 # one decay a channel: as many key heads as value heads, and sub-chunks
 ChannelCase = collections.namedtuple(
     "ChannelCase", "seq heads key_dim value_dim chunk sub")
+# no delta rule: ``groups`` groups of B and C of ``state`` entries under
+# ``heads`` heads of ``channels``
+SsdCase = collections.namedtuple(
+    "SsdCase", "seq groups heads state channels chunk")
 
 
 def cell_cases():
@@ -100,6 +117,16 @@ def channel_case() -> ChannelCase:
     return ChannelCase(cfg["seq_len"], linear["num_heads"],
                        linear["head_dim"], linear["head_dim"],
                        cfg["kda_chunk"], cfg["kda_sub_chunk"])
+
+
+def ssd_case() -> SsdCase:
+    """The Nemotron cell's scan from its configuration's file."""
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "nemotron-3-nano-30b-a3b.json")) as f:
+        cfg = json.load(f)
+    return SsdCase(cfg["seq_len"], cfg["n_groups"], cfg["mamba_num_heads"],
+                   cfg["ssm_state_size"], cfg["mamba_head_dim"],
+                   cfg["ssm_chunk"])
 
 
 def clamped_in_chunks(g, chunk: int, floor: float):
@@ -156,6 +183,114 @@ def scan_inputs(case, seed: int):
             jax.random.normal(keys[5], (1, s, h, case.value_dim)))
 
 
+def _judged(record: dict) -> dict:
+    """``record`` with its ``ok``: every tensor of the program's scan within
+    ``SCAN_TOLERANCE`` and every control's worst tensor above it."""
+    record["ok"] = bool(
+        max(record["scan"].values()) <= SCAN_TOLERANCE
+        and all(max(c.values()) > SCAN_TOLERANCE
+                for c in record["controls"].values()))
+    return record
+
+
+def _outputs(fn, operands, w, **compiler_options):
+    """``fn(*operands)`` and its gradient for each of the five operands
+    under the cotangent ``w``, compiled (with ``compiler_options``) and
+    fetched."""
+    import jax
+
+    def scalar(*a):
+        out = fn(*a)
+        return (out * w).sum(), out
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        scalar, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(
+            *operands).compile(compiler_options or None)(*operands)
+    return jax.device_get((out, *grads))
+
+
+def ssd_inputs(case: SsdCase, seed: int):
+    """(x, B, C, dt, A_log, cotangent), float32, b 1: x, B, C and the
+    cotangent standard normal; the step ``softplus(n + dt_bias)`` and the
+    rate ``exp(A_log)`` Mamba's rule as the configuration initialises it,
+    dt over (1e-3, 1e-1) and A over (1, 16), both spread evenly in the
+    logarithm over the heads (``scan_inputs`` has the reason)."""
+    import jax
+    import jax.numpy as jnp
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    s, h = case.seq, case.heads
+    spread = jnp.arange(h, dtype=jnp.float32) / max(h - 1, 1)
+    dt = 1e-3 * 100.0 ** spread
+    return (jax.random.normal(keys[0], (1, s, h, case.channels)),
+            jax.random.normal(keys[1], (1, s, case.groups, case.state)),
+            jax.random.normal(keys[2], (1, s, case.groups, case.state)),
+            jax.nn.softplus(jax.random.normal(keys[3], (1, s, h))
+                            + dt + jnp.log(-jnp.expm1(-dt))),
+            spread * jnp.log(16.0),
+            jax.random.normal(keys[4], (1, s, h, case.channels)))
+
+
+def check_ssd(case: SsdCase, seed: int, scan=None) -> dict:
+    """The program's state-space scan (``scan(x, B, C, dt, A_log)``, by
+    default ``ssd_scan`` in bf16 at the case's chunk under the decay ``g =
+    -exp(A_log) dt``) against ``benchmark/lib/plain_nemotron_h.py::
+    selective_scan``, the recurrence token by token in float32: ``y`` and
+    the gradients of x, B, C, the step and ``A_log``, each by ``|got -
+    want|_2 / |want|_2``, and the controls: a state rounded to bf16 after
+    every token, a chunk's cumulated log-decay clamped at -20 and head i
+    reading group ``i % groups`` and not ``i // (heads / groups)``."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.lib.plain_nemotron_h import selective_scan
+
+    *operands, w = ssd_inputs(case, seed)
+
+    def decay(dt, a_log):
+        return -jnp.exp(a_log) * dt
+
+    if scan is None:
+        from byteps_tpu.parallel.linear_attention import ssd_scan
+
+        def scan(x, b, c, dt, a_log):
+            return ssd_scan(c, b, x, decay(dt, a_log), dt, chunk=case.chunk,
+                            dtype=jnp.bfloat16)
+
+    block = min(128, case.seq)
+
+    def plain(clamp=None, **wrong):
+        def fn(x, b, c, dt, a_log):
+            g = decay(dt, a_log)
+            if clamp is not None:
+                g = clamped_in_chunks(g, case.chunk, clamp)
+            with jax.default_matmul_precision("highest"):
+                return selective_scan(c[0], b[0], x[0], g[0], dt[0],
+                                      scan_block=block, **wrong)[None]
+
+        return fn
+
+    def readings(got):
+        return dict(zip(SSD_TENSORS,
+                        map(attention_check._relative, got, want)))
+
+    want = _outputs(plain(), operands, w)
+    record = {
+        "case": case._asdict(), "seed": seed,
+        "min_chunk_log_decay": float(cumulated_in_chunks(
+            decay(*operands[3:]), case.chunk).min()),
+        "scan": readings(_outputs(scan, operands, w)),
+        "controls": {
+            "bf16_state": readings(_outputs(
+                plain(state_dtype=jnp.bfloat16), operands, w)),
+            "clamped_at_-20": readings(_outputs(
+                plain(clamp=CLAMP), operands, w)),
+            "heads_interleaved": readings(_outputs(plain(group_of=[
+                i % case.groups for i in range(case.heads)]), operands, w))},
+        "tolerance": SCAN_TOLERANCE}
+    return _judged(record)
+
+
 def check_scan(case, seed: int, scan=None) -> dict:
     """The program's scan (``scan(q, k, v, g, beta)``, by default
     ``kda_attention`` in bf16 at the case's chunk) against the recurrence
@@ -180,15 +315,7 @@ def check_scan(case, seed: int, scan=None) -> dict:
                 sub=case.chunk if per_head else case.sub, dtype=jnp.bfloat16)
 
     def run(fn, **compiler_options):
-        """(o, dq, dk, dv, dg, dbeta) of ``fn(q, k, v, g, beta)``."""
-        def scalar(*a):
-            out = fn(*a)
-            return (out * w).sum(), out
-
-        (_, out), grads = jax.jit(jax.value_and_grad(
-            scalar, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(
-                *operands).compile(compiler_options or None)(*operands)
-        return jax.device_get((out, *grads))
+        return _outputs(fn, operands, w, **compiler_options)
 
     block = min(128, case.seq)
 
@@ -221,17 +348,16 @@ def check_scan(case, seed: int, scan=None) -> dict:
             cumulated_in_chunks(operands[3], case.chunk).min()),
         "scan": readings(run(scan)), "controls": controls,
         "tolerance": SCAN_TOLERANCE}
-    record["ok"] = bool(
-        max(record["scan"].values()) <= SCAN_TOLERANCE
-        and all(max(c.values()) > SCAN_TOLERANCE
-                for c in record["controls"].values()))
-    return record
+    return _judged(record)
 
 
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--cases", default="gdn,kda,attention,ssd",
+                    help="which of the four to run, by name")
     args = ap.parse_args()
+    cases = args.cases.split(",")
 
     import jax
 
@@ -239,13 +365,17 @@ def main() -> int:
     ok = device.platform == "tpu"        # never a CPU's figures by mistake
     if ok:
         scan_case, attention_case = cell_cases()
-        for case in (scan_case, channel_case()):
-            record = check_scan(case, args.seed)
-            ok = ok and record["ok"]
+        for name, check, case in (("gdn", check_scan, scan_case),
+                                  ("kda", check_scan, channel_case()),
+                                  ("ssd", check_ssd, ssd_case())):
+            if name in cases:
+                record = check(case, args.seed)
+                ok = ok and record["ok"]
+                print(json.dumps(record), flush=True)
+        if "attention" in cases:
+            record = attention_check.check(attention_case, args.seed)
+            ok = ok and record["ok"] and record["kernel_in_program"]
             print(json.dumps(record), flush=True)
-        record = attention_check.check(attention_case, args.seed)
-        ok = ok and record["ok"] and record["kernel_in_program"]
-        print(json.dumps(record), flush=True)
     print(json.dumps({"ok": ok, "device": {
         "platform": device.platform, "kind": device.device_kind}}))
     return 0 if ok else 1
