@@ -25,9 +25,10 @@ float32 state ``S`` [state, head_dim] from zero:
 
     S_t = e^{g_t} S_{t-1} + B_t (Delta_t x_t)^T;   y_t = S_t^T C_t + D x_t
 
-(``parallel/linear_attention.py::ssd_scan``: the chunked scan with no delta
-rule). Output ``W_out GN(y SiLU(z))``: gate first, then ``GN``, an RMSNorm
-over each of the ``groups`` groups of channels times one weight vector (the
+(``parallel/linear_attention.py::ssd_scan_channels``: the chunked scan with
+no delta rule, over ``x``, ``B`` and ``C`` as the convolution leaves them).
+Output ``W_out GN(y SiLU(z))``: gate first, then ``GN``, an RMSNorm over
+each of the ``groups`` groups of channels times one weight vector (the
 family's ``MambaRMSNormGated`` with ``norm_before_gate`` false).
 
 **E, experts** (``models/kimi_linear.py::KimiSparseMoe`` with ``gated``
@@ -72,7 +73,8 @@ from byteps_tpu.models.kimi_linear import (KDA_SAVED, KimiSparseMoe,
 from byteps_tpu.models.llama import RMSNorm
 from byteps_tpu.monitor import metrics
 from byteps_tpu.parallel.linear_attention import (SSM_PREP_SCOPE,
-                                                  chunk_log_decay, ssd_scan)
+                                                  chunk_log_decay,
+                                                  ssd_scan_channels)
 from byteps_tpu.parallel.ring_attention import full_attention
 
 SSM_PROJ_SCOPE = "bps.ssm.proj"          # in- and out-projection
@@ -121,27 +123,28 @@ class Mamba2Mixer(nn.Module):
         @jax.checkpoint
         def prepared(xbc, conv, conv_bias, dt, a_log, dt_bias):
             with jax.named_scope(SSM_PREP_SCOPE):
-                mixed = causal_conv(xbc, conv, conv_bias, activation="silu")
-                c_in, b_in = (
-                    mixed[..., inner + i * bc:inner + (i + 1) * bc].reshape(
-                        b, s, self.groups, self.state) for i in (1, 0))
                 step = jax.nn.softplus(dt + dt_bias)
-                return (c_in, b_in, mixed[..., :inner].reshape(
-                    b, s, self.heads, self.head_dim),
-                    -jnp.exp(a_log) * step, step)
+                return (causal_conv(xbc, conv, conv_bias, activation="silu"),
+                        -jnp.exp(a_log) * step, step)
 
-        c_in, b_in, x_in, g, step = prepared(
+        # ``mixed`` = [x | B | C], a token's channels on lanes as the
+        # convolution leaves them: the scan takes them so (its kernels read
+        # and write that layout; its XLA form cuts heads and groups itself)
+        # and hands ``x`` back for the skip, whose cotangent then reaches
+        # ``mixed`` through the scan's own backward pass
+        mixed, g, step = prepared(
             zxbc[..., inner:], conv, conv_bias, dt, a_log, dt_bias)
         if (self.is_mutable_collection("ssm_stats")
                 and not self.is_initializing()):   # init(): parameters only
             self.sow("ssm_stats", "min_chunk_log_decay",
                      chunk_log_decay(g, self.chunk).min())
+        y, x_in = ssd_scan_channels(
+            mixed, g, step, heads=self.heads, groups=self.groups,
+            state=self.state, chunk=self.chunk, dtype=self.dtype)
         # kept when the layer is recomputed, as Kimi-Linear's scan output
-        y = checkpoint_name(
-            ssd_scan(c_in, b_in, x_in, g, step, chunk=self.chunk,
-                     dtype=self.dtype), KDA_SAVED)
+        y = checkpoint_name(y, KDA_SAVED)
         with jax.named_scope(SSM_OUT_SCOPE):
-            gated = ((y + skip[:, None] * x_in).reshape(b, s, inner)
+            gated = ((y + jnp.repeat(skip, self.head_dim) * x_in)
                      * jax.nn.silu(zxbc[..., :inner].astype(f32)))
             # an RMSNorm a group of channels, one weight vector over all
             grouped = gated.reshape(b, s, self.groups, inner // self.groups)

@@ -113,11 +113,25 @@ its products with the writes and every chunk's share of the next state
 pairs and that share multiplied once a *group* of ``B`` and ``C`` and not
 once a head, and what runs chunk after chunk is ``S <- e^{G_C} S + Z``.
 It shares ``chunked``, ``chunk_log_decay``, the pair-and-mask code and the
-two-level scan over groups of ``SSD_GROUP`` chunks. One form, XLA's, on
-every backend: this state is 128 x 64 a head at the one configuration that
-runs it and both kernel pairs are 128 x 128, so ``kda_form`` has no answer
-for it and its call sites have a counter of their own
-(``bps_ssm_scan_sites_total``).
+two-level scan over groups of ``SSD_GROUP`` chunks. This state is 128 x 64
+a head at the one configuration that runs it and both kernel pairs above
+are 128 x 128, so ``kda_form`` has no answer for it: it has a rule of its
+own, ``ssd_form``, and two forms under it (each counted at trace time:
+``bps_ssm_scan_sites_total``, ``bps_ssm_scan_kernel_sites_total``). The XLA
+form, ``_ssd_group``, is what every CPU run, float32 ``dtype`` and any
+shape but the rule's gets: its masks ``e^{G_i - G_j}`` are a float32
+``[b, group, heads, C, C]`` tensor in HBM, its state goes through HBM
+around every chunk, and it takes ``x`` ``[b, s, heads, p]``. On a ``tpu``
+backend, bf16 ``dtype``, a state of 128 entries, heads of 64 channels in
+eights a group and chunks of 128, the kernels of
+``byteps_tpu.ops.ssd_scan`` (since PR 65) hold every group's state in VMEM
+from the first chunk to the last, form a chunk's masks there, and read
+``x``, ``B`` and ``C`` — and write ``y`` and their cotangents — in the
+``[s, channels]`` layout the convolution before the scan and the gate after
+it use (``ssd_scan_channels`` is that entry; a hand-written backward kernel
+under a states-only forward walk). Same guarantees: float32 state, bf16
+operands with float32 accumulation, masked to ``-inf`` before the ``exp``,
+nothing clamped.
 
 **The scan over chunks has two forms too, and the operands' form picks
 it.** Where XLA builds the operands (``"xla"`` and ``"head"``) the scan has
@@ -183,9 +197,11 @@ HEAD_SITES = "bps_kda_head_sites_total"
 # ``byteps_tpu.ops.gdn_chunk``
 HEAD_KERNEL_SITES = "bps_kda_head_kernel_sites_total"
 # ... where there is no delta rule at all (the selective state-space scan),
-# with its own counter: no ``kda_form`` answers for it
+# with its own counters: ``ssd_form`` answers for it, not ``kda_form``
 SSM_PREP_SCOPE, SSM_SCAN_SCOPE = "bps.ssm.prep", "bps.ssm.scan"
 SSM_SCAN_SITES = "bps_ssm_scan_sites_total"
+# ... and of those, the ones that took the kernels of ``ops/ssd_scan.py``
+SSM_SCAN_KERNEL_SITES = "bps_ssm_scan_kernel_sites_total"
 # ... and of all scan sites, those whose scan over chunks is the kernel pair
 # of ``byteps_tpu.ops.kda_recurrence`` (both kernel forms': it is their
 # layout it reads)
@@ -576,6 +592,76 @@ def _ssd_group(state, xs, dtype):
     return state, jnp.moveaxis(y, 1, 0)
 
 
+def ssd_form(backend: str, dtype, state: int, channels: int, heads: int,
+             groups: int, chunk: int) -> str:
+    """``"kernel"`` or ``"xla"``: how the state-space scan runs at these
+    shapes. One algorithm; the XLA form (``_ssd_group`` under
+    ``_scan_groups``) for every backend, dtype and shape, and the kernels of
+    ``byteps_tpu.ops.ssd_scan`` under one rule of the shapes: a ``tpu``
+    backend, bf16 operands, a state of ``KERNEL_WIDTH`` entries (``B`` and
+    ``C`` of a group are a row of lanes), heads of half that many channels
+    (two heads a row of lanes), the heads of a group a multiple of 8 (the
+    group's log-decay with tokens on lanes is whole sublane groups) and
+    chunks of ``KERNEL_WIDTH`` tokens (a chunk's masks are one tile a
+    head)."""
+    if backend != "tpu" or jnp.dtype(dtype) != jnp.bfloat16:
+        return "xla"
+    if (state, channels, chunk) != (KERNEL_WIDTH, KERNEL_WIDTH // 2,
+                                    KERNEL_WIDTH):
+        return "xla"
+    if groups < 1 or heads % groups or (heads // groups) % 8:
+        return "xla"
+    return "kernel"
+
+
+def ssd_scan_channels(mixed, g, dt, *, heads: int, groups: int, state: int,
+                      chunk: int = 128, dtype=jnp.bfloat16):
+    """``ssd_scan`` over operands as a Mamba-2 mixer's convolution leaves
+    them, a token's channels on lanes: ``mixed`` [b, s, heads p + 2 groups
+    n] = ``[x | B | C]`` (head h of ``x`` on channels ``p h .. p h + p -
+    1``, group j of ``B`` and of ``C`` on ``n j .. n j + n - 1`` of theirs),
+    g and dt [b, s, heads] -> ``(y, x)``, ``y`` [b, s, heads p] float32 and
+    ``x`` = ``mixed[..., :heads p]`` for a caller that uses it beside ``y``
+    (``ops/ssd_scan.py::ssd_scan_kernel`` has the reason). Where
+    ``ssd_form`` says ``"kernel"`` nothing is laid out anew on the way in or
+    out; the XLA form cuts ``mixed`` into heads and groups as its caller
+    used to."""
+    b_, s, channels = mixed.shape
+    inner = channels - 2 * groups * state
+    if (inner <= 0 or inner % heads
+            or not g.shape == dt.shape == (b_, s, heads)):
+        raise ValueError("ssd_scan_channels: mixed [b, s, heads p + 2 groups "
+                         f"n], g, dt [b, s, heads]; got {mixed.shape}, "
+                         f"{g.shape}, {dt.shape} at {heads} heads, {groups} "
+                         f"groups of {state}")
+    if ssd_form(jax.default_backend(), dtype, state, inner // heads, heads,
+                groups, chunk) != "kernel":
+        c, b = (mixed[..., inner + i * groups * state:
+                      inner + (i + 1) * groups * state].reshape(
+                          b_, s, groups, state) for i in (1, 0))
+        x = mixed[..., :inner]
+        return ssd_scan(c, b, x.reshape(b_, s, heads, -1), g, dt,
+                        chunk=chunk, dtype=dtype).reshape(b_, s, inner), x
+    # imported here: a process that never reaches this line (every other
+    # model, any CPU run) pays for no kernel library
+    # (tests/test_import_footprint.py)
+    from byteps_tpu.ops.ssd_scan import ssd_scan_kernel
+
+    metrics.inc_counter(SSM_SCAN_SITES)
+    metrics.inc_counter(SSM_SCAN_KERNEL_SITES)
+    f32 = jnp.float32
+    with jax.named_scope(SSM_PREP_SCOPE):
+        G = chunk_log_decay(g, chunk)                   # [b, n, C, h]
+        G = G.reshape(b_, -1, heads)
+    with jax.named_scope(SSM_SCAN_SCOPE):
+        after = [(0, 0), (0, G.shape[1] - s), (0, 0)]   # zero tokens
+        y, x = ssd_scan_kernel(
+            jnp.pad(mixed.astype(f32), after), G,
+            jnp.pad(dt.astype(f32), after), groups=groups, state=state,
+            dtype=dtype)
+        return y[:, :s], x[:, :s]
+
+
 def ssd_scan(c, b, x, g, dt, *, chunk: int = 128, dtype=jnp.bfloat16):
     """``y`` [b, s, h, p] float32 of the selective state-space recurrence
     (Mamba-2's SSD): per head a float32 state ``S`` [n, p] from zero,
@@ -587,7 +673,9 @@ def ssd_scan(c, b, x, g, dt, *, chunk: int = 128, dtype=jnp.bfloat16):
     log-decay (<= 0) and dt the step, [b, s, h]. ``chunk`` need not divide
     s. The module's chunk algebra with one decay a head and the triangular
     system gone: ``U`` is ``dt x`` itself and ``W`` zero (module
-    docstring)."""
+    docstring). Where ``ssd_form`` says ``"kernel"`` the operands are laid
+    out ``[s, channels]`` for ``ssd_scan_channels``, which a caller that
+    has them so calls itself."""
     h = x.shape[2]
     if not (c.shape == b.shape and c.shape[:2] == x.shape[:2]
             and h % c.shape[2] == 0 and g.shape == dt.shape == x.shape[:3]):
@@ -596,6 +684,13 @@ def ssd_scan(c, b, x, g, dt, *, chunk: int = 128, dtype=jnp.bfloat16):
                          f"{c.shape}, {b.shape}, {x.shape}, {g.shape}, "
                          f"{dt.shape}")
     s = x.shape[1]
+    if ssd_form(jax.default_backend(), dtype, c.shape[3], x.shape[3], h,
+                c.shape[2], chunk) == "kernel":
+        return ssd_scan_channels(
+            jnp.concatenate([t.reshape(*t.shape[:2], -1) for t in (x, b, c)],
+                            axis=-1),
+            g, dt, heads=h, groups=c.shape[2], state=c.shape[3], chunk=chunk,
+            dtype=dtype)[0].reshape(x.shape)
     metrics.inc_counter(SSM_SCAN_SITES)
     f32 = jnp.float32
     with jax.named_scope(SSM_PREP_SCOPE):

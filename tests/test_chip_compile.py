@@ -280,6 +280,40 @@ def test_kda_recurrence_kernels_compile_for_v5e(topo, chunks, with_grads):
     assert FWD_NAME in text and (BWD_NAME in text) == with_grads
 
 
+@pytest.mark.parametrize("with_grads", [False, True], ids=["fwd", "fwd_bwd"])
+def test_ssd_scan_kernels_compile_for_v5e(topo, with_grads):
+    """The state-space scan of a Nemotron layer (PR 65) at the cell's shape,
+    b 1 x s 16,384, 64 heads of 64 over 8 groups of state 128, reading ``[s,
+    6144]`` as the convolution leaves it: the forward kernel, and through
+    ``jax.grad`` the states-only walk and the backward kernel (the forward
+    kernel's ``y`` is no residual, so a gradient alone runs no forward
+    kernel), each within the VMEM it asks for."""
+    from byteps_tpu.ops.ssd_scan import (BWD_NAME, FWD_NAME, STATES_NAME,
+                                         ssd_scan_kernel)
+
+    one = SingleDeviceSharding(topo.devices[0])
+    shapes = tuple(jax.ShapeDtypeStruct((1, 16384, width), jnp.float32,
+                                        sharding=one)
+                   for width in (6144, 64, 64))
+
+    def fwd(mixed, log_decay, dt):
+        return ssd_scan_kernel(mixed, log_decay, dt, groups=8, state=128,
+                               interpret=False)[0]
+
+    def both(*args):
+        y, vjp = jax.vjp(fwd, *args)
+        return y, vjp(y)
+
+    text = jax.jit(both if with_grads else fwd).lower(
+        *shapes).compile().as_text()
+    assert text.count("tpu_custom_call") == (3 if with_grads else 1)
+    assert FWD_NAME in text
+    assert (STATES_NAME in text) == (BWD_NAME in text) == with_grads
+    # x, B, C are blocks of the one array: nothing is cut out of it
+    assert "f32[1,16384,4096]{2,1,0:T(8,128)} slice(" not in text
+    assert "[1,16384,64,64]" not in text
+
+
 # (s, channels, taps, x's dtype, a bias, SiLU): causal_conv's four call
 # sites in the benchmark's cells, b 1
 CONV_CASES = {
@@ -391,8 +425,8 @@ def _described(mesh, tree, spec):
 
 @pytest.fixture
 def as_on_a_tpu(monkeypatch):
-    """``full_attention``, ``kda_attention`` and ``causal_conv`` pick their
-    forms by the backend they run on, and a kernel interprets itself off a
+    """``full_attention``, ``kda_attention``, ``ssd_scan`` and ``causal_conv``
+    pick their forms by the backend they run on, and a kernel interprets itself off a
     TPU: here all are told the described chip's answer."""
     import importlib
 
@@ -402,6 +436,9 @@ def as_on_a_tpu(monkeypatch):
     la = importlib.import_module("byteps_tpu.parallel.linear_attention")
     kl = importlib.import_module("byteps_tpu.models.kimi_linear")
     rule, scan_rule, conv_rule = ra.attention_form, la.kda_form, kl.conv_form
+    ssd_rule = la.ssd_form
+    monkeypatch.setattr(la, "ssd_form",
+                        lambda backend, *rest: ssd_rule("tpu", *rest))
     monkeypatch.setattr(ra, "attention_form",
                         lambda backend, *rest: rule("tpu", *rest))
     monkeypatch.setattr(la, "kda_form",
@@ -411,7 +448,7 @@ def as_on_a_tpu(monkeypatch):
     # ... in every module that bound the name when it was imported
     for ops in (fa, *(importlib.import_module(f"byteps_tpu.ops.{name}")
                       for name in ("kda_chunk", "kda_recurrence",
-                                   "gdn_chunk", "causal_conv"))):
+                                   "gdn_chunk", "causal_conv", "ssd_scan"))):
         monkeypatch.setattr(ops, "_resolve_interpret",
                             lambda interpret: False)
 
@@ -631,8 +668,8 @@ def test_joyai_collective_step_compiles_for_one_v5e(topo, as_on_a_tpu):
 def test_nemotron_collective_step_compiles_for_one_v5e(topo, as_on_a_tpu):
     """make_train_step over NemotronHModel at the cell's widths and size, b
     1 x s 16384, adamw, for one described chip — cut to one layer of each
-    kind (``ME*``: a Mamba-2 layer with the state-space scan in its XLA
-    form, an ungated expert layer of 8 held experts with its shared expert,
+    kind (``ME*``: a Mamba-2 layer with the state-space scan as its kernels
+    (PR 65), an ungated expert layer of 8 held experts with its shared expert,
     the attention layer at 16 query heads a key head, where the fused
     backward kernel stays), which is every kind of program the cell's step
     holds, so that the case stays in tier-1's time (the whole nine layers
@@ -680,8 +717,14 @@ def test_nemotron_collective_step_compiles_for_one_v5e(topo, as_on_a_tpu):
                   "bps.ssm.out", "bps.nattn.attend", "bps.nattn.proj",
                   "bps.moe.route", "bps.moe.shared"):
         assert scope in text, scope
-    # no kernel of the delta-rule scans: this scan is XLA's on every backend
+    # no kernel of the delta-rule scans: this scan has its own (PR 65),
+    # forward, the states-only walk and backward, and hands them [s, 6144]
+    # as the convolution leaves it: no [s, 64, 64] tensor is left
     assert "bps_kda_recurrence" not in text and "bps_gdn" not in text
+    for name in ("bps_ssd_scan_fwd", "bps_ssd_scan_states",
+                 "bps_ssd_scan_bwd"):
+        assert name in text, name
+    assert "[1,16384,64,64]" not in text
     # ... and the convolution before it is a kernel forward (PR 64)
     assert "bps_causal_conv_fwd" in text
 

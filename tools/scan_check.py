@@ -55,7 +55,12 @@ the step and ``A_log`` (the decay ``g = -exp(A_log) dt`` is formed on both
 sides, so that both gradients pass through it) —, the step and the rate
 Mamba's rule spread over the heads as above, the same measure and
 tolerance, and the three controls: the bf16 state, the clamped decay, head i
-reading group ``i % 8``. ``--cases ssd`` runs it alone.
+reading group ``i % 8``. What is held is the form ``ssd_form`` picks on the
+backend the tool runs on (on the chip, since PR 65, the kernels of
+``ops/ssd_scan.py``; the record's ``form`` says which), and on the chip the
+record also times the op alone, forward + backward, in that form and in the
+XLA form (``ms``: the median of ``TIMED_RUNS`` calls each). ``--cases ssd``
+runs it alone.
 
 **The short convolution** (since PR 64): ``causal_conv`` as the program
 runs it on the chip — its forward pass the kernel of ``ops/causal_conv.py``
@@ -96,6 +101,7 @@ CLAMP = -20.0
 TENSORS = ("o", "dq", "dk", "dv", "dg", "dbeta")
 
 SSD_TENSORS = ("y", "dx", "db", "dc", "ddt", "da_log")
+TIMED_RUNS = 10             # calls of the op alone a timing is the median of
 
 # the short convolution's tensors, each with its own tolerance: y is the
 # same float32 arithmetic up to how a multiply-add rounds; dx is rounded
@@ -334,9 +340,13 @@ def check_ssd(case: SsdCase, seed: int, scan=None) -> dict:
         return dict(zip(SSD_TENSORS,
                         map(attention_check._relative, got, want)))
 
+    from byteps_tpu.parallel.linear_attention import ssd_form
+
     want = _outputs(plain(), operands, w)
     record = {
         "case": case._asdict(), "seed": seed,
+        "form": ssd_form(jax.default_backend(), jnp.bfloat16, case.state,
+                         case.channels, case.heads, case.groups, case.chunk),
         "min_chunk_log_decay": float(cumulated_in_chunks(
             decay(*operands[3:]), case.chunk).min()),
         "scan": readings(_outputs(scan, operands, w)),
@@ -349,6 +359,45 @@ def check_ssd(case: SsdCase, seed: int, scan=None) -> dict:
                 i % case.groups for i in range(case.heads)]), operands, w))},
         "tolerance": SCAN_TOLERANCE}
     return _judged(record)
+
+
+def time_ssd(case: SsdCase, seed: int) -> dict:
+    """Milliseconds of ``ssd_scan`` alone, forward + backward under a
+    cotangent, at the case's shapes: ``picked`` in the form ``ssd_form``
+    picks here, ``xla`` with the rule told to refuse — each the median of
+    ``TIMED_RUNS`` calls after one that compiles."""
+    import statistics
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from byteps_tpu.parallel import linear_attention as la
+
+    x, b, c, dt, a_log, w = ssd_inputs(case, seed)
+
+    def loss(x, b, c, dt, a_log):
+        return (la.ssd_scan(c, b, x, -jnp.exp(a_log) * dt, dt,
+                            chunk=case.chunk, dtype=jnp.bfloat16) * w).sum()
+
+    def timed():
+        fn = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+        jax.block_until_ready(fn(x, b, c, dt, a_log))
+        runs = []
+        for _ in range(TIMED_RUNS):
+            start = time.perf_counter()
+            jax.block_until_ready(fn(x, b, c, dt, a_log))
+            runs.append((time.perf_counter() - start) * 1e3)
+        return statistics.median(runs)
+
+    out = {"picked": timed()}
+    rule = la.ssd_form
+    la.ssd_form = lambda *shapes: "xla"
+    try:
+        out["xla"] = timed()
+    finally:
+        la.ssd_form = rule
+    return out
 
 
 def check_conv(case: ConvCase, seed: int, conv=None) -> dict:
@@ -512,6 +561,8 @@ def main() -> int:
                                   ("ssd", check_ssd, ssd_case())):
             if name in cases:
                 record = check(case, args.seed)
+                if name == "ssd":
+                    record["ms"] = time_ssd(case, args.seed)
                 ok = ok and record["ok"]
                 print(json.dumps(record), flush=True)
         if "conv" in cases:
