@@ -8,9 +8,20 @@ copied):
 
 ``dsa.indexer_ms``   ``bps.dsa.indexer``: the indexer's projections, the
                      index scores, the KL term and their gradients.
-``dsa.select_ms``    ``bps.dsa.select``: ``lax.top_k`` and the mask.
+``dsa.select_ms``    ``bps.dsa.select``: each query's ``topk``-th score
+                     (since PR 34 16 compare-and-count passes over the
+                     scores' ordered bits, no ``lax.top_k``, forward only:
+                     the backward pass rebuilds the mask from the saved
+                     threshold) and the mask.
 ``dsa.attend_ms``    ``bps.dsa.attend``: attention scores, softmax, values
-                     and their gradients, recomputation included.
+                     and their gradients, recomputation included. On the
+                     chip, since PR 42, the kernels of ``ops/
+                     sparse_flash.py`` (``bps_dsa_fwd``, ``bps_dsa_probs``,
+                     ``bps_dsa_bwd``: a [block, tile] score in VMEM, the
+                     selection an int8 operand, the forward kernel once a
+                     step) and the little XLA around them; the masked form
+                     with its float32 scores in HBM is a CPU run's. The
+                     reader sums by scope and knows neither form.
 ``dsa.layer_share_pct``  their sum over the time of the capture's programs
                      on ``XLA Modules``.
 ``dsa.attend_roofline_pct``  the least time the chip could take for the

@@ -26,15 +26,17 @@ own:
 
 ``mmoe.held_load`` and ``mmoe.compact_share_pct`` are readings of the
 initialisation: the probe runs once, before the first step, so both read
-about 1.0 and 100 whatever the window does. In this cell the window does
-move — the router sends the held quarter over half of the assignments
-within its 44 steps and every layer comes to a second pass, + 70 ms each
-(PERF.md section 6, PR 58) — and neither figure, nor any other of this
-reader (the capture is of the window's first 8 steps, before the first
-second pass), can show that or explain a ``step_ms_p50`` that moved inside
-a window: the run's ``step_ms_quartiles`` on its diagnostics line is where
-it shows. ``moves`` names what a change of the figure at the initialisation
-would move.
+about 1.0 and 100 whatever the window does. While this cell's pool of 16
+batches cycled, the window did move — the model memorised them, the router
+sent the held quarter over half of the assignments within 44 steps and
+every layer came to a second pass, + 70 ms each (PERF.md section 6, PR 58)
+— and no figure of this reader could show it. Since PR 66 the pool is 128,
+no batch is seen twice and the held load ends a window where it began;
+``layers/share.py`` reads the same probe at the window's end
+(``share.held_load_end``, by layer on the diagnostics line: a second pass
+comes where a layer's passes 2.0), and ``mmoe.compact_share_pct``, 100.0 on
+every line of the ledger, left ``BENCHMARK.json`` for it: it is still
+computed here and dropped from the line.
 
 A program without the kernels, the scopes or the collection reports nothing.
 """

@@ -391,6 +391,12 @@ def run_cell(repo: str, manifest: dict, cell_name: str, *, seed: int,
         run.window = win
         memory_peak = device_lib.memory_peak_bytes()
         _ps_counters(run, "after")
+        # after the window and the peak's reading: a reader's last look at
+        # the state the window ends with, in no timed window and no peak
+        if not win.error:
+            for reader in run.readers:
+                if hasattr(reader, "finish"):
+                    reader.finish(run, state)
         if chips > 1 and run.mode == "collective" and not win.error:
             hlo = step.lower(*state, shard_batch(pool[0])).compile().as_text()
             if count_all_reduce(hlo) < 1:
@@ -438,12 +444,14 @@ def run_cell(repo: str, manifest: dict, cell_name: str, *, seed: int,
         "cell": cell_name, "seed": seed, "seconds": seconds, "trace": trace,
         "steps": win.completed, "step_samples": len(win.step_s),
         "window_s": win.wall_s, "traced_steps": traced,
+        "pool_cycles": (warm.attempted + win.attempted) / len(pool),
         "n_params": run.n_params, "problems": problems, "setup_s": setup_s,
         "setup_marks": marks, "timings": run.timings, "agreement": agreement,
         "probes": run.probes, "step_ms_quartiles": [
             1e3 * q for q in (min(win.step_s), *statistics.quantiles(
                 win.step_s, n=4, method="inclusive"), max(win.step_s))]
         if len(win.step_s) > 1 else None,
+        "step_ms_series": [1e3 * t for t in win.step_s],
         "round_medians_us": _round_stats(run, statistics.median),
         "round_max_us": _round_stats(run, max),
         "rounds": ((run.counters["round_summary_after"]["completed_total"]

@@ -185,7 +185,13 @@ def reduce_events(events, *, steps: int, spans, step_span: str,
     first = planes[0]
     in_window = [(max(s, lo), min(e, hi), n, sync)
                  for s, e, n, sync in ops[first] if min(e, hi) > max(s, lo)]
-    progs = [(s, e, n) for s, e, n in programs[first] if lo <= s < hi]
+    # A program is the window's if it ENDS after the window's start: the
+    # first traced step's may start a hair before the step span that
+    # dispatched it (the device's clock against the host's), while the
+    # warm-up's last ended before the loss fetch that closed the warm-up.
+    # (And if it starts before the window's end: a list cut from a capture
+    # ends at its last operation, 1 us before that operation's program.)
+    progs = [(s, e, n) for s, e, n in programs[first] if e > lo and s < hi]
 
     # Collectives on any op line (an asynchronous one spans start to done);
     # exposed is the part during which no other operation of the

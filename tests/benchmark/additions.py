@@ -35,6 +35,18 @@ METRICS = {
 }
 
 
+def finish(run, state):
+    """``lib/cell.py`` calls this once, after the window and with its last
+    state: what it saw goes to the diagnostics line's ``probes``."""
+    import jax
+
+    run.probes["counted_finish_calls"] = (
+        run.probes.get("counted_finish_calls", 0) + 1)
+    run.probes["counted_finish_saw_steps"] = run.window.completed
+    run.probes["counted_finish_leaves"] = len(
+        jax.tree_util.tree_leaves(state[0]))
+
+
 def read(run):
     if not run.window.step_s:
         return {}

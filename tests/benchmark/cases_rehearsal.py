@@ -87,6 +87,25 @@ def test_four_chip_cell_traced_line():
     assert diag["traced_steps"] == 10 and diag["problems"] == []
 
 
+def test_a_share_cell_s_traced_line_has_its_window():
+    """A share cell tiny on the CPU: the traced run goes on to the window's
+    end, so the drift of the whole series and the probe at the window's end
+    are on the line beside the probe at its start, and the diagnostics
+    line says how far round its pool of 128 the run went."""
+    last, diag = _rehearse("laguna-xs.2.collective-swa.1chip", 1, devices=1)
+    assert last["correct"] is True and diag["problems"] == []
+    m = last["metrics"]
+    assert 0.5 < m["share.held_load_end"]["value"] < 2.0
+    assert m["share.held_load_end"]["value"] == \
+        diag["probes"]["share_held_load_end"]
+    assert len(diag["probes"]["share_held_load_end_by_layer"]) == 3
+    assert "wmoe.held_load" in m
+    assert len(diag["step_ms_series"]) == diag["step_samples"] >= 6
+    assert m["share.step_drift_pct"]["unit"] == "%"
+    assert diag["pool_cycles"] == (3 + last["attempted"]) / 128
+    assert diag["traced_steps"] == 8 < diag["steps"]
+
+
 def test_a_cell_needs_exactly_its_chips():
     out = _python([os.path.join(HERE, "bench_tiny.py"),
                    "bert-large.collective.4chip", "0"], devices=2)
@@ -113,6 +132,12 @@ def test_an_added_cell_runs_from_a_copy_of_the_benchmark(tmp_path):
     assert 0 < last["metrics"]["counted.steps_per_fetch"]["value"] <= 5
     # six layers cut to the rehearsal's two, by the new file's own sizing
     assert diag["problems"] == [] and diag["n_params"] == 103_936
+    # the new reader's ``finish``: once, after the window, its last state
+    assert diag["probes"]["counted_finish_calls"] == 1
+    assert diag["probes"]["counted_finish_saw_steps"] == diag["steps"] > 0
+    assert diag["probes"]["counted_finish_leaves"] > 0
+    # steps started, warm-up and window, over the pool of 16
+    assert diag["pool_cycles"] == (3 + last["attempted"]) / 16
     assert os.path.isdir(tmp_path / ".benchmark_out" / additions.CELLS[1])
 
 

@@ -112,6 +112,25 @@ def test_reduction_of_a_made_up_capture():
         loop.SPANS[1]: 20 * ns, BETWEEN_CALLS: 30 * ns}
 
 
+def test_a_program_belongs_to_the_window_it_ends_in():
+    """OLMoE's line of PR 65 read 0.9 | 1.0 programs a step: the first
+    traced program's start on the device's clock lay a hair before the step
+    span that dispatched it. A program that straddles the window's start is
+    the window's, whole; one that ended before it (the warm-up's last) and
+    one that starts after it are not."""
+    events = [((p, l, n, s - 5000, d + 5000)
+               if (l, s) == ("XLA Modules", 100_000) and p == DEV0
+               else (p, l, n, s, d)) for p, l, n, s, d in _made_up()]
+    events += [(DEV0, "XLA Modules", "jit__step(1)", -400_000, 300_000),
+               (DEV0, "XLA Modules", "jit__step(1)", 1_000_000, 100_000)]
+    r = tr.reduce_events(events, steps=2, spans=loop.SPANS,
+                         step_span=loop.STEP_SPAN)
+    assert r["window_s"] == pytest.approx(1000e-6)
+    assert r["programs"] == 2 and r["programs_per_step"] == 1.0
+    # 95..600 and 650..900, neither clipped
+    assert r["program_s_per_step"] == pytest.approx((505 + 250) / 2 * 1e-6)
+
+
 def test_without_host_spans_the_window_is_the_device_s_own_extent():
     events = [e for e in _made_up() if e[0] != HOST]
     r = tr.reduce_events(events, steps=2, spans=loop.SPANS,

@@ -226,6 +226,28 @@ def test_per_layer_metric_has_a_reader_that_declares_it(roots, tree, entry):
             name
 
 
+SHARE_CELLS = next(m["workloads"] for m in MANIFEST["per_layer"]
+                   if m["name"] == "share.step_drift_pct")
+# its pass bound is every assignment, so its step does not turn on the routing
+STEP_FREE_OF_THE_ROUTING = "zaya1-8b.collective-cca.1chip"
+
+
+@pytest.mark.parametrize("name", SHARE_CELLS)
+def test_no_share_cell_s_window_sees_a_batch_twice(name):
+    """A share's step turns on its routing and the routing on what the
+    model has seen: a window over a cycled pool swings with the seed (PERF.md
+    section 6, PRs 63 and 66). Every cell whose drift is on the ledger has
+    the reader and, but for the one whose step does not turn on the routing,
+    draws a pool no 30 s window cycles (``pool_cycles`` on the diagnostics
+    line of a run says whether one did)."""
+    cell = cell_lib.find_cell(MANIFEST, name)
+    traffic = _traffic(REPO, cell)
+    assert "share" in traffic["readers"]
+    assert traffic["log_every"] == 4 and traffic["trace_steps"] == 8
+    if name != STEP_FREE_OF_THE_ROUTING:
+        assert traffic["batch_pool"] >= 64
+
+
 @each_tree
 def test_paths_hold_only_well_named_files(roots, tree):
     allowed = re.compile(r"^[A-Za-z0-9_./-]+$")

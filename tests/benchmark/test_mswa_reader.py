@@ -195,7 +195,10 @@ def test_the_readers_declare_what_the_manifest_lists(reader, prefix, layer):
     listed = {m["name"]: m for m in manifest["per_layer"]
               if m["name"].startswith(prefix)}
     assert reader.LAYER == layer
-    assert set(listed) == set(reader.METRICS)
+    # the manifest's list was full at 128 in PR 66: the probe's gauge, 100.0
+    # on every line of the ledger, left it for ``share.held_load_end``; the
+    # reader still computes it and the line drops it
+    assert set(listed) == set(reader.METRICS) - {"mmoe.compact_share_pct"}
     for name, metric in listed.items():
         assert metric["layer"] == reader.LAYER
         assert metric["workloads"] == [CELL]
