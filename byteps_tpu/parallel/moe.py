@@ -23,7 +23,7 @@ The shares that run today (``benchmark/configs``): 8 of 256 at top-8
 (Kimi-Linear, JoyAI), 16 of 128 (Keye) and 16 of 256 (Laguna) at top-8, 32
 of 512 at top-10 (Qwen3-Next), 8 of 16 at top-1 (ZAYA1), 16 of 64 at
 top-8 (Mellum2: a quarter of the experts, two held assignments a token, a
-pass of half of all T k rows) and 8 of 128 at top-6 (Nemotron-H, whose
+pass of at most half of all T k rows) and 8 of 128 at top-6 (Nemotron-H, whose
 experts are the layer's second body: ungated, ``down(relu(up(x))^2)``, two
 grouped matmuls where SwiGLU has three).
 """
@@ -228,24 +228,70 @@ def _permute_bwd(res, g):
 
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
-# A share's rows a pass over its even part T k H / E. The three cells that
-# hold a share start at held loads of 0.87-1.02 of the even part (PERF.md
-# section 5): 1 x would send JoyAI's 1.024 through two passes every step,
-# 2 x leaves a balanced router (auxiliary loss or selection bias) room.
+# A share's rows a pass over its even part T k H / E: the top rung of the
+# ladder below and the stride of the loop beyond it. The cells that hold a
+# share start at held loads of 0.87-1.36 of the even part (PERF.md section
+# 5): 1 x would send JoyAI's 1.024 through two passes every step, 2 x leaves
+# a balanced router (auxiliary loss or selection bias) room before the loop.
 HELD_ROWS_OVER_EVEN = 2
 HELD_ROWS_MULTIPLE = 512
+# The rung under the bound, in eighths of it: 5/8 holds loads up to 1.25 of
+# the even part where the bound is twice it. A pass gathers, masks,
+# multiplies and scatter-adds its rows whatever the count, so a layer near
+# its even part pays for 5/8 of the bound and not for all of it (Mellum2's
+# step 591 -> 547 ms: PERF.md section 6, PR 67). One rung, and the bound
+# left to the loop, by measurement: a pass written out costs a body a
+# direction a layer to compile, load and hold. With the bound's pass written
+# out as well the Mellum2 step compiled in 41.6 s where the parent's takes
+# 31.6-40.8 and this form 35.9-37.1, loaded from the compile cache in 9.2
+# for 8.2-8.5 and 8.4-8.8, and peaked at 12.023 GB for 12.039 and 11.831; a
+# further rung at 3/4 (45.8-48.9 s, 10.0-10.4 s, 12.045 GB) was reached by
+# one layer of one seed in five, at its window's end. The three forms' steps
+# lie within 1.3 ms of each other (-45.5, -44.3 and -44.2 ms on the parent).
+HELD_RUNG_EIGHTHS = 5
+# The fewest rows the rung has to save (bound less rung) for a shape to get
+# one, set between what was measured on either side of it. Above: Mellum2's
+# rung saves 24,576 rows of 2304, Qwen3-Next's 7,680 of 2048 (its step 600.5
+# -> 587.9 ms at the parent's memory and set-up). Below: ZAYA1's 6,144 of a
+# 16,384-row pass (Keye's the same) read +2.0% of peak memory on the chip
+# against a 1% bound under a ladder of three rungs and no shorter step, its
+# load being at 2.0 by the window's end; Nemotron's 4,608, Laguna's 3,072
+# and Kimi-Linear's and JoyAI's 1,536 are smaller still. Under the floor a
+# shape keeps the bound's pass beside the loop, and its step lowers to the
+# text it had before there was a rung.
+HELD_RUNG_MIN_SAVED = 7168
 
 
 def held_row_bound(t: int, top_k: int, held: int, e: int) -> int:
-    """The rows a share of ``held`` of ``e`` experts works on in one pass:
-    twice the even part of the assignments that reach it, in 512s, and at
-    most all T k. 4,096 of 65,536 for 8 of 256 experts, 16,384 for 16 of
-    128; half of all rows for 16 of 64 at top-8, two held assignments a
-    token (65,536 of 131,072 at 16,384 tokens, 131,072 of 262,144 at
-    32,768); every row where half the experts or more are held."""
+    """The most rows a share of ``held`` of ``e`` experts works on in one
+    pass, the top rung of ``held_row_rungs`` and the stride of the loop
+    that takes over beyond it: twice the even part of the assignments that
+    reach the share, in 512s, and at most all T k. 4,096 of 65,536 for 8 of
+    256 experts, 16,384 for 16 of 128; half of all rows for 16 of 64 at
+    top-8, two held assignments a token (65,536 of 131,072 at 16,384
+    tokens, 131,072 of 262,144 at 32,768); every row where half the experts
+    or more are held."""
     rows = t * top_k
     room = -(-HELD_ROWS_OVER_EVEN * rows * held // e)
     return min(rows, -(-room // HELD_ROWS_MULTIPLE) * HELD_ROWS_MULTIPLE)
+
+
+def held_row_rungs(t: int, top_k: int, held: int, e: int) -> tuple:
+    """The sizes a share's pass comes in, ascending, the last of them
+    ``held_row_bound``: a layer takes the first that holds the assignments
+    that reached its held experts. Under the bound one rung,
+    ``HELD_RUNG_EIGHTHS`` of it in 512s, where that lies at or over the
+    even part T k H / E (no rung is sized for a routing that has fled the
+    share) and saves ``HELD_RUNG_MIN_SAVED`` rows or more: (40,960, 65,536)
+    for 16 of 64 experts at top-8 over 16,384 tokens; else the bound alone:
+    (4,096,) for 8 of 256 at top-8 over 8,192."""
+    bound = held_row_bound(t, top_k, held, e)
+    even = -(-t * top_k * held // e)
+    rung = -(-bound * HELD_RUNG_EIGHTHS
+             // (8 * HELD_ROWS_MULTIPLE)) * HELD_ROWS_MULTIPLE
+    if rung < even or bound - rung < HELD_RUNG_MIN_SAVED:
+        return (bound,)
+    return (rung, bound)
 
 
 def _grouped_ffn(xs, weights, groups, rows, dtype):
@@ -279,8 +325,8 @@ def _grouped_ffn(xs, weights, groups, rows, dtype):
 
 # jitted: the written-out pass and the loop's, forward and backward, in every
 # expert layer of a model are this function at the same shapes, so one trace
-# and one lowered function serve them all (≈ 1 s less set-up on the chip's
-# host; the compiled step is the same to the byte of its memory)
+# and one lowered function a rung serve them all (≈ 1 s less set-up on the
+# chip's host; the compiled step is the same to the byte of its memory)
 @partial(jax.jit, static_argnums=(0, 1))
 def _held_pass(dtype, bound, start, y, x, top_w, weights, order, groups):
     """``y`` [T, D] float32 plus the held experts' part of the layer for
@@ -305,7 +351,7 @@ def _held_pass(dtype, bound, start, y, x, top_w, weights, order, groups):
 def _pass_operands(bound, dtype, x, top_w, weights, order, groups):
     """What every pass reads, made once and under the scope that reads it:
     x in float32, the weights (the experts' two or three matrices, a tuple)
-    in ``dtype``, the order padded to whole passes."""
+    in ``dtype``, the order padded to whole passes of the top rung."""
     with jax.named_scope(ROUTE_SCOPE):
         x = x.astype(jnp.float32)
     with jax.named_scope(EXPERTS_SCOPE):
@@ -316,25 +362,32 @@ def _pass_operands(bound, dtype, x, top_w, weights, order, groups):
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_rows(bound, dtype, *args):
+def _held_rows(rungs, dtype, *args):
     """A share's part of the layer, [T, D] float32: the sorted rows of the
-    held experts, which come first, in passes of ``bound`` rows — one where
-    the assignments that reach them fit ``bound``, as many as it takes
-    where they do not, so whatever the routing no assignment is dropped and
-    none beyond the held experts' is gathered or multiplied. One pass is
-    written out beside the loop, which would run it as well: around a
-    ``while`` at the top level of a step the TPU compiler kept several
-    blocks' recomputed residuals alive (15.2 against 12.0 GB compiled for
-    the Kimi-Linear cell; PERF.md, PR 43), around a ``conditional`` it does
-    not. Both sit outside the two scopes, which a pass opens itself: on a
-    device trace neither carries one. Nothing but the arguments is kept for
-    the backward pass, which runs the same passes over ``jax.vjp`` of each
-    and adds up their gradients in float32."""
+    held experts, which come first, in one pass of the first of ``rungs``
+    (``held_row_rungs``) where the assignments that reached them fit it,
+    and else in passes of the last, the bound, as many as it takes — so
+    whatever the routing no assignment is dropped and none beyond the held
+    experts' is gathered or multiplied, and a layer near its even part does
+    not move the bound's rows of zeros. Two bodies a direction behind one
+    conditional on the count: the first rung's pass written out, and the
+    loop, which would run it as well: around a ``while`` at the top level
+    of a step the TPU compiler kept several blocks' recomputed residuals
+    alive (15.2 against 12.0 GB compiled for the Kimi-Linear cell; PERF.md,
+    PR 43), around a ``conditional`` it does not, so the loop stays inside
+    a branch and the branches share their buffers. Where the bound has a
+    rung under it the bound's own pass is the loop's first and has no body
+    of its own (``HELD_RUNG_EIGHTHS`` has the measurement). Both sit outside
+    the two scopes, which a pass opens itself: on a device trace neither
+    carries one. Nothing but the arguments is kept for the backward pass,
+    which makes the same choice from the same count, runs the same passes
+    over ``jax.vjp`` of each and adds up the loop's gradients in float32."""
+    rung, bound = rungs[0], rungs[-1]
     operands = _pass_operands(bound, dtype, *args)
     held_rows, y = args[-1].sum(), jnp.zeros(args[0].shape, jnp.float32)
     return lax.cond(
-        held_rows <= bound,
-        lambda: _held_pass(dtype, bound, jnp.int32(0), y, *operands),
+        held_rows <= rung,
+        lambda: _held_pass(dtype, rung, jnp.int32(0), y, *operands),
         lambda: lax.while_loop(
             lambda at: at[0] < held_rows,
             lambda at: (at[0] + bound,
@@ -342,18 +395,20 @@ def _held_rows(bound, dtype, *args):
             (jnp.int32(0), y))[1])
 
 
-def _held_rows_fwd(bound, dtype, *args):
-    return _held_rows(bound, dtype, *args), args
+def _held_rows_fwd(rungs, dtype, *args):
+    return _held_rows(rungs, dtype, *args), args
 
 
-def _held_rows_bwd(bound, dtype, args, g):
+def _held_rows_bwd(rungs, dtype, args, g):
+    rung, bound = rungs[0], rungs[-1]
     *floats, order, groups = _pass_operands(bound, dtype, *args)
     floats, held_rows = tuple(floats), groups.sum()
 
-    def pulled(start):
-        """The float operands' gradients through the pass at ``start``."""
+    def pulled(rows, start):
+        """The float operands' gradients through the pass of ``rows`` rows
+        at ``start``."""
         return jax.vjp(lambda *f: _held_pass(
-            dtype, bound, start, jnp.zeros_like(g), *f, order, groups),
+            dtype, rows, start, jnp.zeros_like(g), *f, order, groups),
             *floats)[1](g)
 
     def summed():
@@ -365,13 +420,14 @@ def _held_rows_bwd(bound, dtype, args, g):
             lambda at: at[0] < held_rows,
             lambda at: (at[0] + bound, jax.tree_util.tree_map(
                 lambda total, part: total + part.astype(jnp.float32),
-                at[1], pulled(at[0]))),
+                at[1], pulled(bound, at[0]))),
             (jnp.int32(0), jax.tree_util.tree_map(
                 lambda f: jnp.zeros(f.shape, jnp.float32), floats)))[1]
         return jax.tree_util.tree_map(
             lambda total, f: total.astype(f.dtype), totals, floats)
 
-    grads = lax.cond(held_rows <= bound, lambda: pulled(jnp.int32(0)), summed)
+    grads = lax.cond(held_rows <= rung,
+                     lambda: pulled(rung, jnp.int32(0)), summed)
     return (*jax.tree_util.tree_map(
         lambda grad, arg: grad.astype(arg.dtype), grads, tuple(args[:3])),
         None, None)
@@ -428,13 +484,16 @@ def dropless_moe_ffn(
     assignments that fall to its own experts, all of them whatever the
     routing. The sort puts the held experts' rows first, and the share
     gathers, multiplies and adds to their tokens (float32) those rows
-    alone, ``held_row_bound`` of the shapes at a pass: one pass where the
-    routing sends the held experts up to twice their even part, as many as
-    the held rows take where it sends more (a loop on their count), never a
-    row of an expert held elsewhere. Rows of a pass beyond the held
-    experts' last are zero on the way in and out of every grouped matmul,
-    so ``y`` is this share's part of the layer's output: the parts of
-    shares that cover 0..E-1 add up to the whole layer's.
+    alone, in one pass of the first size of ``held_row_rungs`` of the
+    shapes that holds them — picked on the device from their count, so a
+    layer near its even part moves 5/8 of ``held_row_bound``'s rows and not
+    all of them — up to the bound, twice their even part, and in as many
+    passes of the bound as the held rows take where the routing sends more
+    (a loop on their count), never a row of an expert held elsewhere. Rows
+    of a pass beyond the held experts' last are zero on the way in and out
+    of every grouped matmul, so ``y`` is this share's part of the layer's
+    output: the parts of shares that cover 0..E-1 add up to the whole
+    layer's.
 
     Returns ``(y [T, D] in x's dtype, load_balance, z_loss, counts)``:
     ``load_balance`` = E / (T k) * sum_e counts_e * mean_t p[t, e] (1 when
@@ -507,7 +566,7 @@ def dropless_moe_ffn(
                            top_w, preferred_element_type=jnp.float32)
     else:
         y = _held_rows(
-            held_row_bound(t, top_k, held, e), dtype, x, top_w, weights,
+            held_row_rungs(t, top_k, held, e), dtype, x, top_w, weights,
             order,
             lax.slice_in_dim(counts, first_expert, first_expert + held))
     return y.astype(x.dtype), load_balance, z_loss, counts
@@ -521,9 +580,13 @@ def publish_moe_stats(moe_stats, held=None) -> dict:
     ``bps_moe_assignments_total`` and, for a share ``held`` = (first expert,
     experts held), gauges ``bps_moe_held_load``: the assignments that reached
     the held experts over their even part T k H / E, all layers together,
-    and ``bps_moe_compact_share``: the share of layers whose held
-    assignments fit ``held_row_bound``, the layers that take one pass over
-    their rows and not several. Returns what it published."""
+    ``bps_moe_compact_share``: the share of layers whose held assignments
+    fit ``held_row_bound``, the layers that take one pass over their rows
+    and not several, and ``bps_moe_pass_rows_share``: the rows of the pass
+    each layer's count picks (its rung of ``held_row_rungs``; beyond the
+    bound the loop's passes together) over the bound, averaged over layers
+    — 1.0 where every layer takes the bound, 0.625 where every layer takes
+    the rung of 5/8. Returns what it published."""
     import numpy as np
 
     from byteps_tpu.monitor import metrics
@@ -539,11 +602,19 @@ def publish_moe_stats(moe_stats, held=None) -> dict:
         out["bps_moe_held_load"] = float(
             sum(c[first:first + n].sum() for c in leaves)
             / sum(c.sum() * n / c.size for c in leaves))
-        # the bound reads t and top_k as their product, the counts' sum
+        # a layer's held rows and its rungs, which read t and top_k as their
+        # product, the counts' sum
+        layers = [(int(c[first:first + n].sum()),
+                   held_row_rungs(int(c.sum()), 1, n, c.size))
+                  for c in leaves]
         out["bps_moe_compact_share"] = float(np.mean([
-            c[first:first + n].sum()
-            <= held_row_bound(int(c.sum()), 1, n, c.size) for c in leaves]))
-        for name in ("bps_moe_held_load", "bps_moe_compact_share"):
+            rows <= rungs[-1] for rows, rungs in layers]))
+        out["bps_moe_pass_rows_share"] = float(np.mean([
+            next((rung for rung in rungs if rows <= rung),
+                 -(-rows // rungs[-1]) * rungs[-1]) / rungs[-1]
+            for rows, rungs in layers]))
+        for name in ("bps_moe_held_load", "bps_moe_compact_share",
+                     "bps_moe_pass_rows_share"):
             metrics.set_gauge(name, out[name])
     metrics.set_gauge("bps_moe_max_expert_load",
                       out["bps_moe_max_expert_load"])

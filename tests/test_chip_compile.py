@@ -380,6 +380,30 @@ def test_sparse_flash_kernels_compile_for_v5e(topo, with_grads):
     assert ("bps_dsa_bwd" if with_grads else "bps_dsa_probs") in text
 
 
+def _share_layer_text(topo, t, d, e, held, width, **gate):
+    """The compiled text of a share's routed experts, forward and backward
+    as a block recomputes them: ``t`` bf16 tokens ``d`` wide, a router over
+    ``e`` experts, ``held`` of them here from expert 16 on, ``width`` wide."""
+    from byteps_tpu.parallel.moe import dropless_moe_ffn
+
+    one = SingleDeviceSharding(topo.devices[0])
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+              for shape, dtype in (((t, d), jnp.bfloat16),
+                                   ((d, e), jnp.float32),
+                                   ((held, d, width), jnp.float32),
+                                   ((held, d, width), jnp.float32),
+                                   ((held, width, d), jnp.float32))]
+
+    @jax.checkpoint
+    def layer(*args):
+        return dropless_moe_ffn(*args, first_expert=16, norm_topk=True,
+                                **gate)[0]
+
+    return jax.jit(jax.value_and_grad(
+        lambda *args: layer(*args).astype(jnp.float32).sum(),
+        argnums=range(5))).lower(*shapes).compile().as_text()
+
+
 def test_expert_share_compiles_its_passes_for_v5e(topo):
     """The routed experts of a Kimi-Linear layer, forward and backward as
     the block recomputes them: T 8192, k 8, experts 16..23 of 256, D 2304,
@@ -389,29 +413,39 @@ def test_expert_share_compiles_its_passes_for_v5e(topo):
     it with its gather, its grouped matmuls and its scatter-add lowered for
     the chip, and no array of 65,536 rows by the model's or the experts'
     width anywhere."""
-    from byteps_tpu.parallel.moe import dropless_moe_ffn, held_row_bound
+    from byteps_tpu.parallel.moe import held_row_bound, held_row_rungs
 
-    one = SingleDeviceSharding(topo.devices[0])
-    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-              for shape, dtype in (((8192, 2304), jnp.bfloat16),
-                                   ((2304, 256), jnp.float32),
-                                   ((8, 2304, 1024), jnp.float32),
-                                   ((8, 2304, 1024), jnp.float32),
-                                   ((8, 1024, 2304), jnp.float32))]
     assert held_row_bound(8192, 8, 8, 256) == 4096
-
-    @jax.checkpoint
-    def layer(*args):
-        return dropless_moe_ffn(
-            *args, top_k=8, first_expert=16, norm_topk=True,
-            scoring="sigmoid", norm_eps=1e-20, routed_scale=2.446)[0]
-
-    text = jax.jit(jax.value_and_grad(
-        lambda *args: layer(*args).astype(jnp.float32).sum(),
-        argnums=range(5))).lower(*shapes).compile().as_text()
+    # a pass this small has no rung under it: the bound's beside the loop
+    assert held_row_rungs(8192, 8, 8, 256) == (4096,)
+    text = _share_layer_text(topo, 8192, 2304, 256, 8, 1024, top_k=8,
+                             scoring="sigmoid", norm_eps=1e-20,
+                             routed_scale=2.446)
     assert text.count(" conditional(") == text.count(" while(") == 2
     assert "bf16[4096,2304]" in text and "bf16[4096,1024]" in text
     assert "[65536,2304]" not in text and "[65536,1024]" not in text
+    assert "bf16[2560,2304]" not in text and "bf16[3072,2304]" not in text
+
+
+def test_expert_share_compiles_a_pass_a_rung_for_v5e(topo):
+    """The routed experts of a Mellum2 layer, forward and backward as the
+    block recomputes them: T 16384 (two sequences of 8,192), k 8, experts
+    16..31 of 64, D 2304, width 896, softmax gate renormalised. The bound
+    is half of the 131,072 sorted rows and the rung under it 40,960: one
+    choice a direction between the rung's pass and the loop of bound-sized
+    passes, so the compiled text holds the gather and the grouped matmuls
+    at 40,960 rows and at 65,536, still two conditionals, and the loop once
+    a direction and no second copy of it."""
+    from byteps_tpu.parallel.moe import held_row_rungs
+
+    rungs = held_row_rungs(16384, 8, 16, 64)
+    assert rungs == (40960, 65536)
+    text = _share_layer_text(topo, 16384, 2304, 64, 16, 896, top_k=8)
+    assert text.count(" conditional(") == text.count(" while(") == 2
+    for rung in rungs:
+        assert f"bf16[{rung},2304]" in text and f"bf16[{rung},896]" in text
+    assert "[49152,2304]" not in text
+    assert "[131072,2304]" not in text and "[131072,896]" not in text
 
 
 def _described(mesh, tree, spec):
@@ -598,8 +632,12 @@ def test_expert_share_given_logits_compiles_one_pass_for_v5e(topo):
     recomputes them: T 16384, top-1 by the caller's logits, experts 0..7 of
     16, D 2048, width 2048. Half the experts held: the bound is every
     assignment, 16,384 rows, one pass whatever the routing, still behind
-    the choice between a pass and a loop."""
-    from byteps_tpu.parallel.moe import dropless_moe_ffn, held_row_bound
+    the choice between a pass and a loop; a rung under it (10,240 rows: an
+    even load is 8,192) would save too few rows to be written out
+    (``HELD_RUNG_MIN_SAVED``: on the chip it read +2.0% of the cell's peak
+    memory and no shorter step, PERF.md section 6, PR 67)."""
+    from byteps_tpu.parallel.moe import (dropless_moe_ffn, held_row_bound,
+                                         held_row_rungs)
 
     one = SingleDeviceSharding(topo.devices[0])
     shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -609,6 +647,7 @@ def test_expert_share_given_logits_compiles_one_pass_for_v5e(topo):
                                    ((8, 2048, 2048), jnp.float32),
                                    ((8, 2048, 2048), jnp.float32))]
     assert held_row_bound(16384, 1, 8, 16) == 16384
+    assert held_row_rungs(16384, 1, 8, 16) == (16384,)
 
     @jax.checkpoint
     def layer(x, logits, *weights):
@@ -619,7 +658,7 @@ def test_expert_share_given_logits_compiles_one_pass_for_v5e(topo):
         lambda *args: layer(*args).astype(jnp.float32).sum(),
         argnums=range(5))).lower(*shapes).compile().as_text()
     assert text.count(" conditional(") == text.count(" while(") == 2
-    assert "bf16[16384,2048]" in text
+    assert "bf16[16384,2048]" in text and "bf16[10240,2048]" not in text
 
 
 def test_joyai_collective_step_compiles_for_one_v5e(topo, as_on_a_tpu):

@@ -34,7 +34,9 @@ from byteps_tpu.models.kimi_linear import KimiSparseMoe
 from byteps_tpu.models.llama import yarn_inv_freq, yarn_ramp
 from byteps_tpu.models.mellum import MELLUM_SITES
 from byteps_tpu.monitor import metrics
-from byteps_tpu.parallel.moe import held_row_bound, publish_moe_stats
+from byteps_tpu.parallel import moe as moe_lib
+from byteps_tpu.parallel.moe import (dropless_moe_ffn, held_row_bound,
+                                     held_row_rungs, publish_moe_stats)
 from byteps_tpu.parallel.ring_attention import (KERNEL_SITES, WINDOW_NEEDED,
                                                 WINDOW_SITES, WINDOW_WALKED,
                                                 XLA_SITES)
@@ -312,18 +314,107 @@ def test_a_model_with_a_shared_expert_keeps_its_tree(name):
     assert moe["shared"]["gate"]["kernel"].shape[1] == width
 
 
-@pytest.mark.parametrize("t, top_k, held, e, bound", [
-    (32768, 8, 16, 64, 131072),      # the deployment's 4 x 8,192 tokens
-    (16384, 8, 16, 64, 65536),       # this cell's 2 x 8,192
-    (8192, 8, 8, 256, 4096),         # Kimi-Linear's and JoyAI's share
-    (8192, 8, 16, 128, 16384),       # Keye's
-    (16384, 1, 8, 16, 16384),        # ZAYA1's: every row
+@pytest.mark.parametrize("t, top_k, held, e, rungs", [
+    # the deployment's 4 x 8,192 tokens, then this cell's 2 x 8,192: half of
+    # all rows is the bound, and 5/8 of it the rung under it
+    (32768, 8, 16, 64, (81920, 131072)),
+    (16384, 8, 16, 64, (40960, 65536)),
+    # Qwen3-Next's: the rung saves 7,680 rows, the least that gets one
+    (16384, 10, 32, 512, (12800, 20480)),
+    # the six other shares that run: the rung would save 1,536 (Kimi-Linear
+    # and JoyAI), 3,072 (Laguna), 6,144 (Keye, ZAYA1: every row is the bound,
+    # and twice the even part all the same) and 4,608 rows (Nemotron), under
+    # HELD_RUNG_MIN_SAVED: the bound alone
+    (8192, 8, 8, 256, (4096,)),
+    (8192, 8, 16, 256, (8192,)),
+    (8192, 8, 16, 128, (16384,)),
+    (16384, 1, 8, 16, (16384,)),
+    (16384, 6, 8, 128, (12288,)),
+    # a saving of 6,656 rows, one row multiple under the floor, and of 7,168
+    (32768, 1, 7, 26, (17920,)),
+    (32768, 1, 7, 24, (12288, 19456)),
+    # three quarters of the experts held: the even part is over 5/8 of all
+    # rows, so no rung is left between it and the bound
+    (65536, 1, 12, 16, (65536,)),
+    # these tests' sizes
+    (512, 2, 2, 8, (512,)),
 ])
-def test_held_row_bound_by_hand(t, top_k, held, e, bound):
+def test_held_row_bound_by_hand(t, top_k, held, e, rungs):
     """Twice the even part T k H / E, in 512s, at most all T k: half of all
-    rows for a quarter of the experts."""
-    assert held_row_bound(t, top_k, held, e) == bound
-    assert bound <= t * top_k
+    rows for a quarter of the experts. The rungs under it by hand: eighths
+    of the bound in 512s, none under the even part, the bound last, and the
+    bound alone where the rung would save too little."""
+    assert held_row_bound(t, top_k, held, e) == rungs[-1] <= t * top_k
+    assert held_row_rungs(t, top_k, held, e) == rungs
+    assert len(rungs) <= 2 and list(rungs) == sorted(set(rungs))
+    assert all(rung % moe_lib.HELD_ROWS_MULTIPLE == 0 for rung in rungs)
+    assert all(rung * e >= t * top_k * held for rung in rungs)
+    assert len(rungs) == 1 or (rungs[-1] - rungs[0]
+                               >= moe_lib.HELD_RUNG_MIN_SAVED)
+
+
+# T tokens at top-1 over 16 experts, experts 4..7 held: an even part of
+# 16,384 rows and a rung of 20,480 under a bound of 32,768
+LADDER = dict(t=65536, e=16, held=4, first=4, d=8, m=8)
+
+
+def _ladder_layer(held_rows, rungs=None):
+    """The layer's output and its five gradients (x, logits, gate, up,
+    down) in float32 with exactly ``held_rows`` of the tokens routed to the
+    held experts by their logits; under ``rungs`` where given, else under
+    the ladder the shapes have."""
+    t, e, held, first, d, m = (LADDER[k] for k in
+                               ("t", "e", "held", "first", "d", "m"))
+    rng = np.random.default_rng(held_rows)
+    normal = lambda *shape, scale=1.0: jnp.asarray(  # noqa: E731
+        rng.normal(size=shape) * scale, jnp.float32)
+    x, cot = normal(t, d), normal(t, d)
+    weights = (normal(held, d, m, scale=d ** -0.5),
+               normal(held, d, m, scale=d ** -0.5),
+               normal(held, m, d, scale=m ** -0.5))
+    elsewhere = [i for i in range(e) if not first <= i < first + held]
+    expert = np.concatenate([
+        first + np.arange(held_rows) % held,
+        np.asarray(elsewhere)[np.arange(t - held_rows) % len(elsewhere)]])
+    rng.shuffle(expert)
+    logits = rng.normal(size=(t, e)).astype(np.float32)
+    logits[np.arange(t), expert] += 8.0
+    logits = jnp.asarray(logits)
+
+    def layer(x, logits, *weights):
+        y, _, _, counts = dropless_moe_ffn(
+            x, None, *weights, top_k=1, dtype=jnp.float32,
+            first_expert=first, logits=logits)
+        return (y * cot).sum(), (y, counts)
+
+    with pytest.MonkeyPatch.context() as patch:
+        if rungs is not None:
+            patch.setattr(moe_lib, "held_row_rungs", lambda *shape: rungs)
+        (_, (y, counts)), grads = jax.value_and_grad(
+            layer, argnums=range(5), has_aux=True)(x, logits, *weights)
+    assert int(counts[first:first + held].sum()) == held_rows
+    return (y, *grads)
+
+
+@pytest.mark.parametrize("held_rows", (
+    0, 16384,                   # none, the even part: the lower rung
+    20479, 20480, 20481,        # the lower rung's edge
+    32767, 32768, 32769,        # the bound's: beyond it the loop
+    49152, 65536,               # a pass and a half of the loop; every row
+))
+def test_every_rung_and_the_loop_give_the_one_pass_result(held_rows):
+    """Whichever rung the held experts' count picks, at each rung's edge, a
+    row under and a row over it, and in the loop beyond the bound: output
+    and all five gradients are those of one pass over every row, the code
+    path there was before there were rungs, to 1e-6."""
+    assert held_row_rungs(LADDER["t"], 1, LADDER["held"], LADDER["e"]) == (
+        20480, 32768)
+    got = _ladder_layer(held_rows)
+    want = _ladder_layer(held_rows, rungs=(LADDER["t"],))
+    for name, a, b in zip(("y", "x", "logits", "gate", "up", "down"),
+                          got, want):
+        assert _rel(a, b) <= 1e-6, name
+    assert float(jnp.abs(want[0]).max()) > 0 or held_rows == 0
 
 
 def test_the_probe_says_how_many_layers_take_one_pass():
@@ -339,6 +430,36 @@ def test_the_probe_says_how_many_layers_take_one_pass():
     assert out["bps_moe_compact_share"] == 0.5
     assert abs(out["bps_moe_held_load"]
                - (128 + 400) / (2 * 512 * 2 / 8)) < 1e-9
+
+
+@pytest.mark.parametrize("held_rows, share", [
+    ((16384,), 0.625), ((20480,), 0.625),       # the lower rung, to its edge
+    ((20481,), 1.0), ((32768,), 1.0),           # the bound
+    ((32769,), 2.0), ((65536,), 2.0),           # the loop: two passes' rows
+    ((16384, 18000, 22000, 40000), (0.625 + 0.625 + 1.0 + 2.0) / 4),
+])
+def test_the_probe_says_which_rung_each_layer_takes(held_rows, share):
+    """``bps_moe_pass_rows_share``: the rows of the pass a layer's count
+    picks over the bound, averaged over layers, from made-up counts of
+    65,536 assignments over 16 experts with experts 4..7 held (a rung of
+    20,480 under a bound of 32,768)."""
+    def layer(rows):
+        counts = np.zeros(16, np.int64)
+        counts[4], counts[6], counts[12] = rows - rows // 3, rows // 3, \
+            65536 - rows
+        return counts
+
+    out = publish_moe_stats([layer(rows) for rows in held_rows], held=(4, 4))
+    assert out["bps_moe_pass_rows_share"] == pytest.approx(share)
+    assert metrics._py_gauges["bps_moe_pass_rows_share"] == \
+        out["bps_moe_pass_rows_share"]
+    assert out["bps_moe_compact_share"] == pytest.approx(
+        np.mean([rows <= 32768 for rows in held_rows]))
+    # a shape with one rung reads what it did before there were rungs
+    small = np.array([100, 100, 28, 28, 64, 64, 64, 64], np.int64)
+    assert publish_moe_stats([small], held=(0, 2))[
+        "bps_moe_pass_rows_share"] == 1.0
+    assert "bps_moe_pass_rows_share" not in publish_moe_stats([small])
 
 
 # --------------------------------------------------------------------------
