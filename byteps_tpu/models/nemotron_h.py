@@ -29,7 +29,17 @@ float32 state ``S`` [state, head_dim] from zero:
 no delta rule, over ``x``, ``B`` and ``C`` as the convolution leaves them).
 Output ``W_out GN(y SiLU(z))``: gate first, then ``GN``, an RMSNorm over
 each of the ``groups`` groups of channels times one weight vector (the
-family's ``MambaRMSNormGated`` with ``norm_before_gate`` false).
+family's ``MambaRMSNormGated`` with ``norm_before_gate`` false). Skip, gate
+and norm are one function, ``gated_group_norm``: float32 arithmetic, rounded
+once to ``dtype``, the operand ``W_out``'s product takes. Its XLA form is the
+``jax.numpy`` lines; where ``gate_form`` says so — a ``tpu`` backend, a bf16
+result, groups of whole 128-lane tiles up to 512 channels that hold whole
+heads, the sequence in blocks of 512 tokens — it is the kernel pair of
+``byteps_tpu.ops.gated_norm``, ``bps_gated_norm_fwd`` / ``bps_gated_norm_bwd``
+(PR 69), which reads ``z`` and ``x`` where the in-projection and the
+convolution leave them and writes the bf16 operand once, so that XLA has no
+chain to rebuild inside the product; each call site is counted at trace time
+(``bps_gated_norm_sites_total``, ``bps_gated_norm_kernel_sites_total``).
 
 **E, experts** (``models/kimi_linear.py::KimiSparseMoe`` with ``gated``
 False): sigmoid scores over all ``num_experts`` in float32, a selection
@@ -82,8 +92,117 @@ SSM_OUT_SCOPE = "bps.ssm.out"            # D x, the SiLU(z) gate, group norm
 NATTN_ATTEND_SCOPE = "bps.nattn.attend"  # around full_attention's own scope
 NATTN_PROJ_SCOPE = "bps.nattn.proj"      # the four projections
 NEMOTRON_SITES = "bps_nemotron_sites_total"   # layers, at trace time
+# call sites of ``gated_group_norm`` traced into a program, and of those the
+# ones that took the kernels of ``byteps_tpu.ops.gated_norm``
+GATE_SITES = "bps_gated_norm_sites_total"
+GATE_KERNEL_SITES = "bps_gated_norm_kernel_sites_total"
+# the kernels' tiling, as ``byteps_tpu.ops.gated_norm`` has it (ROWS,
+# MAX_GROUP_WIDTH; tests/test_gated_norm_kernel.py holds the two equal):
+# kept here so that asking for the form imports no kernel library
+GATE_ROWS, GATE_MAX_GROUP_WIDTH = 512, 512
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
+
+
+def gate_form(backend: str, s: int, inner: int, groups: int, head_dim: int,
+              dtype) -> str:
+    """``"kernel"`` or ``"xla"``: how ``gated_group_norm`` runs at these
+    shapes. One algorithm: the XLA form for every backend and shape, and the
+    kernel pair of ``byteps_tpu.ops.gated_norm`` on a ``tpu`` backend where
+    its tiling holds — a group's channels whole 128-lane tiles, at most
+    ``GATE_MAX_GROUP_WIDTH`` (a block's lanes), and whole heads of
+    ``head_dim``; the sequence in whole blocks of ``GATE_ROWS`` tokens; a
+    bf16 result."""
+    if backend != "tpu" or jnp.dtype(dtype) != jnp.bfloat16:
+        return "xla"
+    if groups < 1 or head_dim < 1 or inner % groups or s % GATE_ROWS:
+        return "xla"
+    width = inner // groups
+    if width % 128 or width > GATE_MAX_GROUP_WIDTH or width % head_dim:
+        return "xla"
+    return "kernel"
+
+
+def gated_group_norm(y, x, z, skip, weight, *, groups: int, head_dim: int,
+                     eps: float = 1e-5, dtype=jnp.bfloat16, x_lies_in=None):
+    """A Mamba-2 mixer's output chain, the out-projection's operand:
+    ``GN((y + repeat(skip) x) SiLU(z)) weight`` [b, s, inner] in ``dtype``
+    — gate before norm, ``GN`` an RMSNorm over each of the ``groups`` groups
+    of channels, float32 arithmetic, rounded once at the end (the rounding a
+    ``Dense(dtype=dtype)`` gives its input). y, x [b, s, inner] as the scan
+    hands them back; z [b, s, >= inner], its first ``inner`` columns the
+    gate's (the in-projection's output as it lies); skip [inner /
+    head_dim], a number a head; weight [inner].
+
+    On a TPU at the shapes ``gate_form`` names it is one kernel each way,
+    ``bps_gated_norm_fwd`` and, under a rule that keeps only the operands
+    it is given, ``bps_gated_norm_bwd``; everywhere else XLA's
+    (``gated_group_norm_xla``: what the kernels are held to,
+    ``tools/scan_check.py --cases gate``). ``x_lies_in``: the array [b, s,
+    >= inner] whose first ``inner`` columns ``x`` is (what the scan was
+    handed): the kernels then read ``x`` there, as they read ``z``, and no
+    slice of it is written, while ``x``'s cotangent still goes back through
+    ``x`` and none to that array; the XLA form takes no notice of it."""
+    metrics.inc_counter(GATE_SITES)
+    if gate_form(jax.default_backend(), y.shape[1], y.shape[2], groups,
+                 head_dim, dtype) == "kernel":
+        metrics.inc_counter(GATE_KERNEL_SITES)
+        return _kernel_gate(y, x, x if x_lies_in is None else x_lies_in, z,
+                            skip, weight, groups, head_dim, eps,
+                            jnp.dtype(dtype))
+    return gated_group_norm_xla(y, x, z, skip, weight, groups=groups,
+                                head_dim=head_dim, eps=eps, dtype=dtype)
+
+
+def gated_group_norm_xla(y, x, z, skip, weight, *, groups: int,
+                         head_dim: int, eps: float = 1e-5,
+                         dtype=jnp.bfloat16):
+    """``gated_group_norm``'s XLA form, whatever the backend."""
+    b, s, inner = y.shape
+    f32 = jnp.float32
+    gated = ((y + jnp.repeat(skip, head_dim) * x)
+             * jax.nn.silu(z[..., :inner].astype(f32)))
+    # an RMSNorm a group of channels, one weight vector over all
+    grouped = gated.reshape(b, s, groups, inner // groups)
+    normed = (grouped * jax.lax.rsqrt(
+        jnp.mean(grouped * grouped, axis=-1, keepdims=True)
+        + eps)).reshape(b, s, inner) * weight
+    return normed.astype(dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _kernel_gate(y, x, x_wide, z, skip, weight, groups, head_dim, eps, dtype):
+    """``x_wide`` is what the kernels read ``x`` in; ``x`` itself is here
+    for its cotangent's way back alone."""
+    # imported here: a CPU run pays for no kernel library
+    # (tests/test_import_footprint.py)
+    from byteps_tpu.ops.gated_norm import gated_norm_forward
+
+    return gated_norm_forward(y, x_wide, z, jnp.repeat(skip, head_dim),
+                              weight, groups=groups, eps=eps, dtype=dtype)
+
+
+def _kernel_gate_fwd(y, x, x_wide, z, skip, weight, groups, head_dim, eps,
+                     dtype):
+    return (_kernel_gate(y, x, x_wide, z, skip, weight, groups, head_dim,
+                         eps, dtype), (y, x_wide, z, skip, weight))
+
+
+def _kernel_gate_bwd(groups, head_dim, eps, dtype, res, ct):
+    from byteps_tpu.ops.gated_norm import gated_norm_backward
+
+    y, x_wide, z, skip, weight = res
+    dy, dx, dz, dskip, dweight = gated_norm_backward(
+        y, x_wide, z, jnp.repeat(skip, head_dim), weight, ct, groups=groups,
+        eps=eps)
+    # the gate's columns of ``z``'s cotangent; the others read nothing here
+    dz = jnp.pad(dz, ((0, 0), (0, 0), (0, z.shape[2] - dz.shape[2])))
+    return (dy, dx, None, dz,
+            dskip.reshape(-1, head_dim).sum(-1).astype(skip.dtype),
+            dweight.astype(weight.dtype))
+
+
+_kernel_gate.defvjp(_kernel_gate_fwd, _kernel_gate_bwd)
 
 
 class Mamba2Mixer(nn.Module):
@@ -98,7 +217,7 @@ class Mamba2Mixer(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        b, s, d_model = x.shape
+        d_model = x.shape[-1]
         f32 = jnp.float32
         inner, bc = self.heads * self.head_dim, self.groups * self.state
         dense = partial(nn.Dense, use_bias=False, dtype=self.dtype)
@@ -144,14 +263,13 @@ class Mamba2Mixer(nn.Module):
         # kept when the layer is recomputed, as Kimi-Linear's scan output
         y = checkpoint_name(y, KDA_SAVED)
         with jax.named_scope(SSM_OUT_SCOPE):
-            gated = ((y + jnp.repeat(skip, self.head_dim) * x_in)
-                     * jax.nn.silu(zxbc[..., :inner].astype(f32)))
-            # an RMSNorm a group of channels, one weight vector over all
-            grouped = gated.reshape(b, s, self.groups, inner // self.groups)
-            normed = (grouped * jax.lax.rsqrt(
-                jnp.mean(grouped * grouped, axis=-1, keepdims=True)
-                + self.eps)).reshape(b, s, inner) * self.param(
-                    "norm", nn.initializers.ones, (inner,), f32)
+            # ``z`` and ``x_in`` where they lie: the first ``inner`` columns
+            # of the projection and of ``mixed``
+            normed = gated_group_norm(
+                y, x_in, zxbc, skip, self.param(
+                    "norm", nn.initializers.ones, (inner,), f32),
+                groups=self.groups, head_dim=self.head_dim, eps=self.eps,
+                dtype=self.dtype, x_lies_in=mixed)
         with jax.named_scope(SSM_PROJ_SCOPE):
             return dense(d_model, name="out")(normed)
 

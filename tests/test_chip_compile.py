@@ -314,6 +314,39 @@ def test_ssd_scan_kernels_compile_for_v5e(topo, with_grads):
     assert "[1,16384,64,64]" not in text
 
 
+@pytest.mark.parametrize("with_grads", [False, True], ids=["fwd", "fwd_bwd"])
+def test_gated_norm_kernels_compile_for_v5e(topo, as_on_a_tpu, with_grads):
+    """The output chain of a Nemotron mixer (PR 69) as the model calls it at
+    the cell's shape, b 1 x s 16,384, 8 groups of 512 channels, heads of 64,
+    ``x`` in the convolution's ``[s, 6144]`` and ``z`` in the projection's
+    ``[s, 12288]``: one kernel each way with the scoped VMEM a kernel gets
+    unasked, and neither operand cut out of its array."""
+    from byteps_tpu.models.nemotron_h import gated_group_norm
+    from byteps_tpu.ops.gated_norm import BWD_NAME, FWD_NAME
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def fwd(y, mixed, z, skip, weight):
+        return gated_group_norm(y, mixed[..., :4096], z, skip, weight,
+                                groups=8, head_dim=64, x_lies_in=mixed)
+
+    def both(*args):
+        out, vjp = jax.vjp(fwd, *args)
+        return out, vjp(out)
+
+    text = jax.jit(both if with_grads else fwd).lower(
+        shape(1, 16384, 4096), shape(1, 16384, 6144),
+        shape(1, 16384, 12288, dtype=jnp.bfloat16), shape(64),
+        shape(4096)).compile().as_text()
+    assert text.count("tpu_custom_call") == (2 if with_grads else 1)
+    assert FWD_NAME in text and (BWD_NAME in text) == with_grads
+    assert "[1,16384,4096]{2,1,0:T(8,128)} slice(" not in text
+    assert "[1,16384,4096]{2,1,0:T(8,128)(2,1)} slice(" not in text
+
+
 # (s, channels, taps, x's dtype, a bias, SiLU): causal_conv's four call
 # sites in the benchmark's cells, b 1
 CONV_CASES = {
@@ -459,9 +492,10 @@ def _described(mesh, tree, spec):
 
 @pytest.fixture
 def as_on_a_tpu(monkeypatch):
-    """``full_attention``, ``kda_attention``, ``ssd_scan`` and ``causal_conv``
-    pick their forms by the backend they run on, and a kernel interprets itself off a
-    TPU: here all are told the described chip's answer."""
+    """``full_attention``, ``kda_attention``, ``ssd_scan``, ``causal_conv``
+    and ``gated_group_norm`` pick their forms by the backend they run on, and
+    a kernel interprets itself off a TPU: here all are told the described
+    chip's answer."""
     import importlib
 
     # the package exports functions under both modules' names
@@ -469,8 +503,11 @@ def as_on_a_tpu(monkeypatch):
     ra = importlib.import_module("byteps_tpu.parallel.ring_attention")
     la = importlib.import_module("byteps_tpu.parallel.linear_attention")
     kl = importlib.import_module("byteps_tpu.models.kimi_linear")
+    nh = importlib.import_module("byteps_tpu.models.nemotron_h")
     rule, scan_rule, conv_rule = ra.attention_form, la.kda_form, kl.conv_form
-    ssd_rule = la.ssd_form
+    ssd_rule, gate_rule = la.ssd_form, nh.gate_form
+    monkeypatch.setattr(nh, "gate_form",
+                        lambda backend, *rest: gate_rule("tpu", *rest))
     monkeypatch.setattr(la, "ssd_form",
                         lambda backend, *rest: ssd_rule("tpu", *rest))
     monkeypatch.setattr(ra, "attention_form",
@@ -482,7 +519,8 @@ def as_on_a_tpu(monkeypatch):
     # ... in every module that bound the name when it was imported
     for ops in (fa, *(importlib.import_module(f"byteps_tpu.ops.{name}")
                       for name in ("kda_chunk", "kda_recurrence",
-                                   "gdn_chunk", "causal_conv", "ssd_scan"))):
+                                   "gdn_chunk", "causal_conv", "ssd_scan",
+                                   "gated_norm"))):
         monkeypatch.setattr(ops, "_resolve_interpret",
                             lambda interpret: False)
 
@@ -766,6 +804,12 @@ def test_nemotron_collective_step_compiles_for_one_v5e(topo, as_on_a_tpu):
     assert "[1,16384,64,64]" not in text
     # ... and the convolution before it is a kernel forward (PR 64)
     assert "bps_causal_conv_fwd" in text
+    # ... and the skip, gate and group norm after it a kernel each way (PR
+    # 69), the forward's once more where the layer is recomputed, reading
+    # ``x`` and ``z`` where they lie: neither is cut out
+    assert text.count("bps_gated_norm_fwd") >= 2 and "bps_gated_norm_bwd" in text
+    assert "f32[1,16384,4096]{2,1,0:T(8,128)} slice(" not in text
+    assert "bf16[1,16384,4096]{2,1,0:T(8,128)(2,1)} slice(" not in text
 
 
 @pytest.mark.slow

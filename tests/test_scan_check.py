@@ -173,6 +173,75 @@ def test_a_wrong_convolution_fails_the_check(wrong):
     assert record["conv"]["y"] > tool.CONV_TOLERANCE["y"]
 
 
+SMALL_GATES = [tool.GateCase(512, 4, 64, 2, 16),
+               tool.GateCase(1024, 8, 64, 1, 32)]
+
+
+@pytest.mark.parametrize("case", SMALL_GATES, ids=["two_groups", "one_group"])
+@pytest.mark.parametrize("seed", (0, 2147483907))
+def test_the_gate_check_passes_the_kernels_and_fails_its_controls(
+        monkeypatch, case, seed):
+    """``gated_group_norm`` told it is on a TPU: the kernel pair
+    interpreted, blocks of 512 rows, ``x`` and ``z`` read where they lie."""
+    import byteps_tpu.models.nemotron_h as nh
+
+    monkeypatch.setattr(nh, "gate_form", lambda *shapes: "kernel")
+    record = tool.check_gate(case, seed)
+    assert record["ok"], record
+    assert record["form"] == "kernel" and record["kernel_in_program"]
+    assert set(record["gate"]) == set(tool.GATE_TENSORS)
+    assert set(record["tolerance"]) == set(tool.GATE_TENSORS) | {"rounding"}
+    # one rounding to bf16 from the float32 result, and no more
+    assert tool.GATE_TOLERANCE["out"] < record["rounding"] \
+        <= tool.GATE_ROUNDING
+    assert set(record["controls"]) == {
+        "norm_before_gate", "bf16_gated_product"} | (
+            {"groups_twice_as_wide"} if case.groups > 1 else set())
+    for reading in record["controls"].values():
+        assert reading > tool.GATE_TOLERANCE["out"]
+
+
+def test_the_gate_check_passes_the_xla_form_on_the_cpu():
+    record = tool.check_gate(SMALL_GATES[0], 1)
+    assert record["ok"] and record["form"] == "xla", record
+    assert max(record["gate"].values()) == 0.0
+
+
+@pytest.mark.parametrize("wrong", ["no_skip", "eps_1e-3", "a_token_late",
+                                   "float32_result"])
+def test_a_wrong_chain_fails_the_gate_check(wrong):
+    from byteps_tpu.models.nemotron_h import gated_group_norm_xla
+
+    case = SMALL_GATES[0]
+    inner = case.heads * case.head_dim
+
+    def chain(y, mixed, z, skip, weight):
+        kw = {"groups": case.groups, "head_dim": case.head_dim}
+        x = mixed[..., :inner]
+        if wrong == "no_skip":
+            skip = jnp.zeros_like(skip)
+        elif wrong == "eps_1e-3":
+            kw["eps"] = 1e-3
+        elif wrong == "a_token_late":
+            z = jnp.roll(z, 1, axis=1)
+        else:
+            kw["dtype"] = jnp.float32
+        return gated_group_norm_xla(y, x, z, skip, weight, **kw)
+
+    record = tool.check_gate(case, 1, chain=chain)
+    assert not record["ok"]
+    assert record["gate"]["out"] > tool.GATE_TOLERANCE["out"]
+
+
+def test_the_gate_case_is_the_configuration():
+    from byteps_tpu.models.nemotron_h import gate_form
+
+    case = tool.gate_case()
+    assert tuple(case) == (16384, 64, 64, 8, 128)
+    assert gate_form("tpu", case.seq, case.heads * case.head_dim, case.groups,
+                     case.head_dim, jnp.bfloat16) == "kernel"
+
+
 def test_the_convolution_cases_are_the_configurations():
     assert [tuple(c) for c in tool.conv_cases()] == [
         ("nemotron", 16384, 6144, 4, "bfloat16", True, "silu"),

@@ -5,7 +5,7 @@ and every gradient, tensor by tensor — and the wrong computations the
 tolerance must fail.
 
     chiprun --chips 1 -- python3 tools/scan_check.py [--seed N]
-        [--cases gdn,kda,attention,ssd,conv]
+        [--cases gdn,kda,attention,ssd,conv,gate]
 
 What a benchmark run cannot see (``benchmark/lib/reference.py`` compares
 three losses to 2e-3: PERF.md section 7, "``correct`` by cell") is held
@@ -78,6 +78,26 @@ XLA form computed wrongly: the taps in the other order, and the output
 rounded to bf16 (the precision below the op's). ``--cases conv`` runs it
 alone, in about three minutes.
 
+**The mixer's output chain** (since PR 69): ``gated_group_norm`` as the
+Nemotron mixer calls it on the chip — the kernel pair of
+``ops/gated_norm.py`` wherever ``gate_form`` says so, and the compiled
+program must name both — at the cell's shapes from the configuration's
+file: ``y`` [1, 16384, 4096], ``x`` the first 4,096 columns of the
+convolution's ``[s, 6144]`` and ``z`` of the projection's bf16 ``[s,
+12288]``, 8 groups, heads of 64, a bf16 result. The result and the five
+gradients (``dx`` and ``dz`` as wide as the arrays they lie in) against the
+XLA form ``gated_group_norm_xla`` with the same bf16 result, each by ``|got
+- want|_2 / |want|_2`` within its ``GATE_TOLERANCE`` (the same float32
+arithmetic on both sides: a rounding that fell the other way here and
+there), and the result against the XLA form's float32 result within
+``GATE_ROUNDING``, the one rounding it is allowed. The controls are the XLA
+form computed wrongly, their results against the tolerance of ``out``: the
+norm before the gate, groups twice as wide, and the gated product rounded
+to bf16 before the norm (the precision below the chain's). On the chip the
+record also times the chain alone, forward + backward, in the picked form
+and in the XLA form (``ms``). ``--cases gate`` runs it alone, in about two
+minutes.
+
 My chip run's readings (PR 50) are in PERF.md section 6. One JSON line a
 case, then ``{"ok": ..., "device": ...}``; off the chip both run at a small
 size (``tests/test_scan_check.py``).
@@ -87,6 +107,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import functools
 import json
 import os
 import sys
@@ -111,6 +132,16 @@ TIMED_RUNS = 10             # calls of the op alone a timing is the median of
 CONV_TOLERANCE = {"y": 1.0e-6, "dx": 1.0e-4, "dw": 1.0e-5, "dbias": 1.0e-5}
 CONV_SEEDS = 2
 
+# the mixer's output chain: ``out`` and ``dz`` are rounded to bf16 on both
+# sides (a bf16 ulp, 2^-8, on the few elements whose float32 values differ
+# in the last bits); dy and dx are float32; dskip and dweight sums over
+# every token
+GATE_TENSORS = ("out", "dy", "dx", "dz", "dskip", "dweight")
+GATE_TOLERANCE = {"out": 2.0e-4, "dy": 1.0e-5, "dx": 1.0e-5, "dz": 2.0e-4,
+                  "dskip": 1.0e-5, "dweight": 1.0e-5}
+# a bf16 result against the float32 one: one rounding, 1.7e-3 rms
+GATE_ROUNDING = 2.5e-3
+
 ScanCase = collections.namedtuple(
     "ScanCase", "seq key_heads heads key_dim value_dim chunk")
 # one decay a channel: as many key heads as value heads, and sub-chunks
@@ -123,6 +154,11 @@ SsdCase = collections.namedtuple(
 # one call site of ``causal_conv``: ``dtype`` the name of x's
 ConvCase = collections.namedtuple(
     "ConvCase", "name seq channels taps dtype biased activation")
+# the output chain of a Mamba-2 mixer: ``heads`` heads of ``head_dim``
+# channels in ``groups`` groups, ``x`` lying in [x | B | C] with ``state``
+# entries a group of B and of C, ``z`` in [z | x | B | C]
+GateCase = collections.namedtuple(
+    "GateCase", "seq heads head_dim groups state")
 
 
 def cell_cases():
@@ -160,6 +196,16 @@ def ssd_case() -> SsdCase:
     return SsdCase(cfg["seq_len"], cfg["n_groups"], cfg["mamba_num_heads"],
                    cfg["ssm_state_size"], cfg["mamba_head_dim"],
                    cfg["ssm_chunk"])
+
+
+def gate_case() -> GateCase:
+    """The Nemotron cell's output chain from its configuration's file."""
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "nemotron-3-nano-30b-a3b.json")) as f:
+        cfg = json.load(f)
+    return GateCase(cfg["seq_len"], cfg["mamba_num_heads"],
+                    cfg["mamba_head_dim"], cfg["n_groups"],
+                    cfg["ssm_state_size"])
 
 
 def conv_cases():
@@ -361,14 +407,28 @@ def check_ssd(case: SsdCase, seed: int, scan=None) -> dict:
     return _judged(record)
 
 
+def _median_ms(fn, operands) -> float:
+    """The median over ``TIMED_RUNS`` calls of ``fn(*operands)``, in
+    milliseconds, after one call that compiles."""
+    import statistics
+    import time
+
+    import jax
+
+    jax.block_until_ready(fn(*operands))
+    runs = []
+    for _ in range(TIMED_RUNS):
+        start = time.perf_counter()
+        jax.block_until_ready(fn(*operands))
+        runs.append((time.perf_counter() - start) * 1e3)
+    return statistics.median(runs)
+
+
 def time_ssd(case: SsdCase, seed: int) -> dict:
     """Milliseconds of ``ssd_scan`` alone, forward + backward under a
     cotangent, at the case's shapes: ``picked`` in the form ``ssd_form``
     picks here, ``xla`` with the rule told to refuse — each the median of
     ``TIMED_RUNS`` calls after one that compiles."""
-    import statistics
-    import time
-
     import jax
     import jax.numpy as jnp
 
@@ -381,14 +441,8 @@ def time_ssd(case: SsdCase, seed: int) -> dict:
                             chunk=case.chunk, dtype=jnp.bfloat16) * w).sum()
 
     def timed():
-        fn = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
-        jax.block_until_ready(fn(x, b, c, dt, a_log))
-        runs = []
-        for _ in range(TIMED_RUNS):
-            start = time.perf_counter()
-            jax.block_until_ready(fn(x, b, c, dt, a_log))
-            runs.append((time.perf_counter() - start) * 1e3)
-        return statistics.median(runs)
+        return _median_ms(jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))),
+                          (x, b, c, dt, a_log))
 
     out = {"picked": timed()}
     rule = la.ssd_form
@@ -482,6 +536,139 @@ def check_conv(case: ConvCase, seed: int, conv=None) -> dict:
                            for c in controls.values()))}
 
 
+def gate_inputs(case: GateCase, seed: int):
+    """(y, mixed, z, skip, weight, cotangent), b 1: ``y`` and ``mixed`` =
+    ``[x | B | C]`` standard normal float32, ``z`` = ``[z | x | B | C]``
+    standard normal rounded to bf16, a skip a head about one (0.5 wide), a
+    weight a channel about one (0.1 wide), a standard normal cotangent
+    rounded to bf16."""
+    import jax
+    import jax.numpy as jnp
+
+    inner, bc = case.heads * case.head_dim, case.groups * case.state
+    keys = jax.random.split(jax.random.PRNGKey(seed % 2 ** 31), 6)
+    return (jax.random.normal(keys[0], (1, case.seq, inner)),
+            jax.random.normal(keys[1], (1, case.seq, inner + 2 * bc)),
+            jax.random.normal(keys[2], (1, case.seq, 2 * inner + 2 * bc)
+                              ).astype(jnp.bfloat16),
+            1.0 + 0.5 * jax.random.normal(keys[3], (case.heads,)),
+            1.0 + 0.1 * jax.random.normal(keys[4], (inner,)),
+            jax.random.normal(keys[5], (1, case.seq, inner)
+                              ).astype(jnp.bfloat16))
+
+
+def _gate_call(fn, case: GateCase, lies_in: bool = False, **kw):
+    """``fn`` (a form of ``gated_group_norm``) over ``gate_inputs``' first
+    five, as the mixer calls it: ``x`` the first columns of ``mixed``, and
+    with ``lies_in`` told so."""
+    inner = case.heads * case.head_dim
+
+    def call(y, mixed, z, skip, weight):
+        return fn(y, mixed[..., :inner], z, skip, weight, groups=case.groups,
+                  head_dim=case.head_dim,
+                  **({"x_lies_in": mixed} if lies_in else {}), **kw)
+    return call
+
+
+def _with_gradients(fn):
+    """``(ct, *operands)`` -> ``fn``'s result and its operands' gradients
+    under the cotangent ``ct`` (in the result's dtype), as one function to
+    compile."""
+    import jax
+
+    def both(ct, *operands):
+        out, vjp = jax.vjp(fn, *operands)
+        return (out, *vjp(ct.astype(out.dtype)))
+    return jax.jit(both)
+
+
+def check_gate(case: GateCase, seed: int, chain=None) -> dict:
+    """The program's output chain (``chain(y, mixed, z, skip, weight)``, by
+    default ``gated_group_norm`` as ``gate_form`` runs it here, told where
+    ``x`` lies) against ``gated_group_norm_xla``: the bf16 result and the
+    five gradients within ``GATE_TOLERANCE``, the result within
+    ``GATE_ROUNDING`` of the float32 one, and the three controls' results
+    above ``out``'s tolerance."""
+    import jax
+    import jax.numpy as jnp
+
+    from byteps_tpu.models import nemotron_h as nh
+
+    inner = case.heads * case.head_dim
+    *operands, ct = gate_inputs(case, seed)
+    if chain is None:
+        chain = _gate_call(nh.gated_group_norm, case, lies_in=True)
+    plain = _gate_call(nh.gated_group_norm_xla, case)
+
+    def outputs(fn):
+        compiled = _with_gradients(fn).lower(ct, *operands).compile()
+        return compiled.as_text(), dict(zip(GATE_TENSORS, jax.device_get(
+            compiled(ct, *operands))))
+
+    def wrong(y, mixed, z, skip, weight, *, norm_first=False, groups=None,
+              bf16_gate=False):
+        f32 = jnp.float32
+        groups = groups or case.groups
+        u = y + jnp.repeat(skip, case.head_dim) * mixed[..., :inner]
+        gate = jax.nn.silu(z[..., :inner].astype(f32))
+        h = u if norm_first else u * gate
+        if bf16_gate:   # not a cast there and back (excess precision)
+            h = jax.lax.reduce_precision(h, 8, 7)
+        grouped = h.reshape(1, case.seq, groups, inner // groups)
+        normed = (grouped * jax.lax.rsqrt(
+            jnp.mean(grouped * grouped, axis=-1, keepdims=True)
+            + 1e-5)).reshape(1, case.seq, inner) * weight
+        return (normed * gate if norm_first else normed).astype(jnp.bfloat16)
+
+    form = nh.gate_form(jax.default_backend(), case.seq, inner, case.groups,
+                        case.head_dim, jnp.bfloat16)
+    _, want = outputs(plain)
+    text, got = outputs(chain)
+    kernel_in_program = True
+    if form == "kernel":
+        from byteps_tpu.ops.gated_norm import BWD_NAME, FWD_NAME
+
+        kernel_in_program = FWD_NAME in text and BWD_NAME in text
+    readings = {name: attention_check._relative(got[name], want[name])
+                for name in GATE_TENSORS}
+    exact = jax.device_get(jax.jit(_gate_call(
+        nh.gated_group_norm_xla, case, dtype=jnp.float32))(*operands))
+    rounding = attention_check._relative(got["out"], exact)
+    controls = {"norm_before_gate": {"norm_first": True},
+                "bf16_gated_product": {"bf16_gate": True}}
+    if case.groups % 2 == 0:
+        controls["groups_twice_as_wide"] = {"groups": case.groups // 2}
+    controls = {
+        name: attention_check._relative(jax.device_get(jax.jit(
+            functools.partial(wrong, **kw))(*operands)), want["out"])
+        for name, kw in controls.items()}
+    return {
+        "case": case._asdict(), "seed": seed, "form": form,
+        "kernel_in_program": kernel_in_program, "gate": readings,
+        "rounding": rounding, "controls": controls,
+        "tolerance": {**GATE_TOLERANCE, "rounding": GATE_ROUNDING},
+        "ok": bool(kernel_in_program and rounding <= GATE_ROUNDING
+                   and all(readings[n] <= GATE_TOLERANCE[n]
+                           for n in GATE_TENSORS)
+                   and all(c > GATE_TOLERANCE["out"]
+                           for c in controls.values()))}
+
+
+def time_gate(case: GateCase, seed: int) -> dict:
+    """Milliseconds of ``gated_group_norm`` alone, forward + backward under
+    a cotangent, at the case's shapes: ``picked`` in the form ``gate_form``
+    picks here, ``xla`` in the XLA form — each the median of ``TIMED_RUNS``
+    calls after one that compiles."""
+    from byteps_tpu.models import nemotron_h as nh
+
+    *operands, ct = gate_inputs(case, seed)
+    return {name: _median_ms(_with_gradients(fn), (ct, *operands))
+            for name, fn in (
+                ("picked", _gate_call(nh.gated_group_norm, case,
+                                      lies_in=True)),
+                ("xla", _gate_call(nh.gated_group_norm_xla, case)))}
+
+
 def check_scan(case, seed: int, scan=None) -> dict:
     """The program's scan (``scan(q, k, v, g, beta)``, by default
     ``kda_attention`` in bf16 at the case's chunk) against the recurrence
@@ -545,8 +732,8 @@ def check_scan(case, seed: int, scan=None) -> dict:
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--cases", default="gdn,kda,attention,ssd,conv",
-                    help="which of the five to run, by name")
+    ap.add_argument("--cases", default="gdn,kda,attention,ssd,conv,gate",
+                    help="which of the six to run, by name")
     args = ap.parse_args()
     cases = args.cases.split(",")
 
@@ -570,6 +757,11 @@ def main() -> int:
                 record = check_conv(case, args.seed)
                 ok = ok and record["ok"]
                 print(json.dumps(record), flush=True)
+        if "gate" in cases:
+            record = check_gate(gate_case(), args.seed)
+            record["ms"] = time_gate(gate_case(), args.seed)
+            ok = ok and record["ok"]
+            print(json.dumps(record), flush=True)
         if "attention" in cases:
             record = attention_check.check(attention_case, args.seed)
             ok = ok and record["ok"] and record["kernel_in_program"]
