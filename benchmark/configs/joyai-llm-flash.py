@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 # The run's seed and first batch, as ``make_batch`` saw them, and what the
-# probe made of them (``layer_stats``): the ``mtp`` and ``lmoe`` readers
+# probe made of them (``layer_stats``): the ``mtp`` and ``eshare`` readers
 # both ask, the first to ask pays. ``lib/cell.py`` hands a reader neither.
 FIRST = {}
 STATS = {}

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 # The run's seed and first batch, as ``make_batch`` saw them, and what the
-# probe made of them (``layer_stats``): the ``kda`` and ``smoe`` readers
+# probe made of them (``layer_stats``): the ``kda`` and ``eshare`` readers
 # both ask, the first to ask pays. ``lib/cell.py`` hands a reader neither.
 FIRST = {}
 STATS = {}

@@ -11,9 +11,10 @@ From the device trace, first device, line ``XLA Ops``, per traced step
 readers), over every layer:
 
 ``cca.attend_ms``  what runs under ``bps.cca.attend``, the attention call:
-                   the three kernels (``bps_flash_fwd``, ``bps_flash_dq``,
-                   ``bps_flash_dkv``) and the transposes, casts and row sums
-                   around them — forward, the forward recomputed in the
+                   the two kernels (``bps_flash_fwd``, ``bps_flash_bwd``:
+                   one backward call since PR 57, where ``bps_flash_dq``
+                   and ``bps_flash_dkv`` were two) and the transposes, casts
+                   and row sums around them — forward, the forward recomputed in the
                    backward pass, and backward.
 ``cca.mix_ms``     what runs under ``bps.cca.mix``: the depthwise and the
                    grouped convolution, the q-k mean, the value shift, the

@@ -9,9 +9,11 @@ From the device trace, first device, line ``XLA Ops``, per traced step
 readers), over every gated attention layer:
 
 ``gattn.attend_ms``  what runs under ``bps.gattn.attend``, the attention
-                     call: the three kernels (``bps_flash_fwd``,
-                     ``bps_flash_dq``, ``bps_flash_dkv``) and the
-                     transposes, casts and row sums around them — forward,
+                     call: the two kernels (``bps_flash_fwd``,
+                     ``bps_flash_bwd``: one backward call since PR 57,
+                     where ``bps_flash_dq`` and ``bps_flash_dkv`` were
+                     two) and the transposes, casts and row sums around
+                     them — forward,
                      the forward recomputed in the backward pass, and
                      backward.
 ``gattn.proj_ms``    what runs under ``bps.gattn.proj``: the q (with its

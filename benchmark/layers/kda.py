@@ -25,9 +25,14 @@ copied):
                   — over ``kda.scan_ms``. Forward once and backward twice
                   that: the recomputed forward earns nothing.
 
-The scan is a ``%while`` on that line, an event as long as all the ops of
-its body, which are events of their own and carry the scope it was opened
-under: a container is skipped by name, so nothing is counted twice.
+Where the scan runs as an XLA loop it is a ``%while`` on that line, an event
+as long as all the ops of its body, which are events of their own and carry
+the scope it was opened under: a container is skipped by name, so nothing is
+counted twice. The chip has not run that form since PR 54 — the scan over a
+layer's chunks is one kernel pair there (``bps_kda_recurrence_fwd`` /
+``_bwd``) and the capture holds no ``%while`` under ``bps.kda.scan`` — but
+other scopes' loops are on the line, and the recorded list of PR 39 under
+``tests/benchmark/data`` still has the scan's.
 
 By hand, one chunk of C tokens of one head, keys d_k, values d_v, forward,
 2 operations a multiply-add, a triangle counted as half its square:
@@ -51,8 +56,8 @@ comes from a probe before the window: the first batch through the run's own
 weights with the ``"kda_stats"`` collection mutable, published by
 ``parallel/linear_attention.py::publish_kda_stats``.
 
-``capture_ms`` reads the capture once a process for this reader and the two
-that share its cell (``layers/mla.py``, ``layers/smoe.py``).
+``capture_ms`` reads the capture once a process for this reader and every
+other that asks (``layers/mla.py``, ``layers/eshare.py`` and the rest).
 
 A program without the scopes or the collection reports nothing.
 """

@@ -6,10 +6,11 @@ From the device trace, first device, line ``XLA Ops``, per traced step
 (``layers/kda.py::capture_ms`` reads the capture once for the cell's three
 readers):
 
-``mla.attend_ms``  what runs under the scope ``bps.mla.attend``: the three
-                   kernels (``bps_flash_fwd``, ``bps_flash_dq``,
-                   ``bps_flash_dkv``) and the transposes, casts and row sums
-                   around them — forward, the forward recomputed in the
+``mla.attend_ms``  what runs under the scope ``bps.mla.attend``: the two
+                   kernels (``bps_flash_fwd``, ``bps_flash_bwd``: one
+                   backward call since PR 57, where ``bps_flash_dq`` and
+                   ``bps_flash_dkv`` were two) and the transposes, casts and
+                   row sums around them — forward, the forward recomputed in the
                    backward pass, and backward.
 ``mla.attend_roofline_pct``  the least time the chip could take for exact
                    causal attention — the larger of ``attend_flops`` over

@@ -16,7 +16,7 @@ readers):
                    on ``XLA Modules``.
 
 The module's block carries ``bps.mla.*`` and ``bps.moe.*`` inside
-``bps.mtp``, so its ops are in ``qmla.*`` and ``lmoe.*`` too: the three
+``bps.mtp``, so its ops are in ``qmla.*`` and ``eshare.*`` too: the three
 prefixes overlap by that block and are not to be added up.
 
 ``bps_mtp_main_loss`` and ``bps_mtp_next2_loss`` (gauges, ``probes`` on the

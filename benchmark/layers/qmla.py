@@ -10,9 +10,11 @@ readers), over every layer that has the scopes — the main stack's and the
 MTP module's, whose ops are also in ``mtp.module_ms``: no reader adds
 shares across prefixes.
 
-``qmla.attend_ms``  what runs under ``bps.mla.attend``: the three kernels
-                    (``bps_flash_fwd``, ``bps_flash_dq``, ``bps_flash_dkv``)
-                    and the transposes, casts and row sums around them —
+``qmla.attend_ms``  what runs under ``bps.mla.attend``: the two kernels
+                    (``bps_flash_fwd``, ``bps_flash_bwd``: one backward
+                    call since PR 57, where ``bps_flash_dq`` and
+                    ``bps_flash_dkv`` were two) and the transposes, casts
+                    and row sums around them —
                     forward, the forward recomputed in the backward pass,
                     and backward.
 ``qmla.attend_roofline_pct``  the least time the chip could take for exact

@@ -7,26 +7,15 @@ as ``models/nemotron_h.py`` stacks it: four expert layers, each a layer of
 its own, 98,304 assignments each sorted, 8 grouped matmuls of about 768 rows
 x 2688 x 1856).
 
-Two readers under this cell's names — ``eshare.*``'s and ``smoe.*``'s
-``workloads`` lists are not this PR's to append to. ``rmoe.gmm_ms``,
-``rmoe.route_ms`` and ``rmoe.held_load`` are ``layers/eshare.py``'s
-``eshare.*`` of those names, word for word (the ``%ragged-dot`` kernels by
-name; the probe's count of the rows at the held experts): its ``setup`` and
-``read`` and ``layers/smoe.py``'s ``read`` are called, nothing of them is
-copied. Two figures are this reader's own:
-
-``rmoe.layer_share_pct``  the expert layers' share of the capture's program
-                  time from the four parts the two readers leave: the
-                  route scope and the grouped-matmul kernels BY NAME
-                  (``eshare``), what else runs under ``bps.moe.experts``
-                  (``probes.eshare_experts_other_ms``) and the shared
-                  expert under ``bps.moe.shared``
-                  (``probes.smoe_shared_ms``). ``smoe.layer_share_pct``
-                  sums by scope alone, and on this chip the ``ragged-dot``
-                  events of a share's pass carry no scope (their ``tf_op``
-                  ends ``.../moe/cond/branch_1_fun/jit(_held_pass)/
-                  ragged-dot``: my traced run, PR 63), so it read 14.5% of
-                  a step of which the four parts are 24.3%.
+``layers/eshare.py``'s reader under this layer's names, which a test outside
+the benchmark holds (its ``setup`` and ``read`` are called, nothing of it is
+copied): ``rmoe.gmm_ms``, ``rmoe.route_ms``, ``rmoe.layer_share_pct`` and
+``rmoe.held_load`` are ``eshare.*`` of those names, word for word — the
+``%ragged-dot`` kernels by name, the route scope, the share of the capture's
+program time that the kernels, the route scope, the rest of
+``bps.moe.experts`` and the shared expert under ``bps.moe.shared`` take
+together, the probe's count of the rows at the held experts. One figure is
+this reader's own:
 
 ``rmoe.gmm_roofline_pct``  ``eshare.gmm_roofline_pct`` with the calls this
                   body has: **six** grouped matmuls a layer the mathematics
@@ -60,7 +49,7 @@ METRICS = {
                        "source": "program_counter",
                        "moves": "tokens_per_s_per_chip"},
 }
-FROM_ESHARE = ("route_ms", "gmm_ms", "held_load")
+FROM_ESHARE = ("route_ms", "gmm_ms", "layer_share_pct", "held_load")
 CALLS = 6     # up and down, each forward, dgrad and wgrad
 
 
@@ -101,19 +90,11 @@ def setup(run):
 
 
 def read(run):
-    from benchmark.layers import eshare, smoe
+    from benchmark.layers import eshare
 
     out = {"rmoe." + name.partition(".")[2]: value
            for name, value in eshare.read(run).items()
            if name.partition(".")[2] in FROM_ESHARE}
-    if smoe.read(run).get("smoe.layer_share_pct") is not None:
-        from benchmark.layers import kda
-
-        parts = (out.get("rmoe.route_ms"), out.get("rmoe.gmm_ms"),
-                 run.probes.get("eshare_experts_other_ms"),
-                 run.probes.get("smoe_shared_ms"))
-        out["rmoe.layer_share_pct"] = (
-            100.0 * sum(p or 0.0 for p in parts) / kda.capture_ms(run)[1])
     held_rows = run.probes.get("eshare_held_rows")
     if out.get("rmoe.gmm_ms") and held_rows:
         import jax

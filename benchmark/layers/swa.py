@@ -9,9 +9,11 @@ From the device trace, first device, line ``XLA Ops``, per traced step
 readers), over every layer of the kind:
 
 ``swa.window_ms``  what runs under ``bps.swa.window``, the attention call
-                   of the windowed layers: the three kernels
-                   (``bps_flash_fwd``, ``bps_flash_dq``, ``bps_flash_dkv``)
-                   and the transposes, casts and row sums around them —
+                   of the windowed layers: the two kernels
+                   (``bps_flash_fwd``, ``bps_flash_bwd``: one backward call
+                   since PR 57, where ``bps_flash_dq`` and ``bps_flash_dkv``
+                   were two) and the transposes, casts and row sums around
+                   them —
                    forward, the forward recomputed in the backward pass,
                    and backward.
 ``swa.full_ms``    the same under ``bps.swa.full``, the global layers.

@@ -99,7 +99,8 @@ def test_a_share_cell_s_traced_line_has_its_window():
     assert m["share.held_load_end"]["value"] == \
         diag["probes"]["share_held_load_end"]
     assert len(diag["probes"]["share_held_load_end_by_layer"]) == 3
-    assert "wmoe.held_load" in m
+    assert "eshare.held_load" in m
+    assert diag["probes"]["eshare_expert_layers"] == 3
     assert len(diag["step_ms_series"]) == diag["step_samples"] >= 6
     assert m["share.step_drift_pct"]["unit"] == "%"
     assert diag["pool_cycles"] == (3 + last["attempted"]) / 128

@@ -97,6 +97,8 @@ def test_top_level_keys_and_limits(roots, tree):
     assert os.path.getsize(
         os.path.join(roots[tree], "BENCHMARK.json")) < 64 * 1024
     assert 1 <= len(cells) <= 24 and 1 <= len(manifest["configs"]) <= 24
+    assert 1 <= len(manifest["per_layer"]) <= 128
+    assert 1 <= len(manifest["end_to_end"]) <= 16
     four = [c for c in cells if c["chips"] == 4]
     assert len(four) <= max(1, len(cells) // 4)
 

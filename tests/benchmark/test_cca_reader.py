@@ -1,5 +1,5 @@
 """The two readers of ``zaya1-8b.collective-cca.1chip``
-(``benchmark/layers/cca.py``, ``zmoe.py``): the rooflines' operations and
+(``benchmark/layers/cca.py``, ``eshare.py``): the rooflines' operations and
 bytes by hand at the cell's size, their reading of a made-up ``.xplane.pb``
 (encoded by ``test_moe_reader.py``'s helpers, with hand-worked sums) through
 the one shared read of the capture, and their reading of what the builder's
@@ -20,7 +20,7 @@ sys.path.insert(0, HERE)
 from bench_tiny import REPO  # noqa: E402,F401
 from test_moe_reader import MS, _capture, _plane  # noqa: E402
 
-from benchmark.layers import cca, eshare, kda, moe, swa, zmoe  # noqa: E402
+from benchmark.layers import cca, eshare, kda, moe, swa  # noqa: E402
 from benchmark.lib import cell as cell_lib  # noqa: E402
 from benchmark.lib import trace_reduce as tr  # noqa: E402
 
@@ -53,7 +53,7 @@ def test_the_attention_s_roofline_by_hand():
 
 
 def test_the_grouped_matmuls_roofline_by_hand():
-    """``zmoe.gmm_roofline_pct`` is ``layers/eshare.py``'s count: the rows
+    """``eshare.gmm_roofline_pct`` at this cell's shapes: the rows
     that reached the 8 held experts, nine calls, the held weights only. At
     even routing 5 x 8,192 rows through 2048 x 2048: bound by arithmetic,
     the one share cell that is."""
@@ -66,7 +66,7 @@ def test_the_grouped_matmuls_roofline_by_hand():
     flops_ms = 1e3 * flops / 197e12
     assert flops_ms == pytest.approx(15.697, abs=1e-3)
     assert 1e3 * nbytes / 819e9 < flops_ms                 # arithmetic binds
-    assert eshare.gmm_roofline_pct(40.0, CFG, rows, V5E) == pytest.approx(
+    assert eshare.gmm_roofline_pct(40.0, CFG, rows, V5E, 5) == pytest.approx(
         100 * flops_ms / 40.0)
 
 
@@ -117,29 +117,30 @@ def _run(tmp_path, trace, **more):
 
 def test_each_layer_counts_under_its_own_scope(tmp_path, monkeypatch):
     """Two steps; the programs' line reads 0.999 ms over them. Attend 5 +
-    15, mix 3 + 4, proj 2; the router 1 + 0.5, which ``eshare.route_ms``
-    (4 + 0.25, the metadata helper, + 1.5) holds too and ``zmoe.route_ms``
-    does not; grouped matmuls 8, the experts' casts 0.5; the layer's share
-    is all of it, router included."""
+    15, mix 3 + 4, proj 2; the router 1 + 0.5, which the expert layer's own
+    split (``layers/moe.py``: 4 + 0.25, the metadata helper, + 1.5) holds in
+    the route and ``eshare.route_ms`` does not; grouped matmuls 8, the
+    experts' casts 0.5; the layer's share is all of it, router included."""
     trace = _capture(tmp_path, [_plane("/device:TPU:0", OPS)])
     monkeypatch.setattr("jax.devices", lambda: [types.SimpleNamespace(
         device_kind="TPU v5 lite")])
     run = _run(tmp_path, trace)
-    run.probes.update(bps_moe_held_load=0.97, eshare_held_rows=40_960)
-    got = {**cca.read(run), **zmoe.read(run)}
+    run.probes.update(bps_moe_held_load=0.97, eshare_held_rows=40_960,
+                      eshare_expert_layers=5)
+    got = {**cca.read(run), **eshare.read(run)}
     programs_ms = 999_000_000 * 1e-9 / 2               # 0.4995 ms a step
     assert got == {
         "cca.attend_ms": 20.0, "cca.mix_ms": 7.0, "cca.proj_ms": 2.0,
         "cca.layer_share_pct": pytest.approx(100 * 29.0 / programs_ms),
         "cca.attend_roofline_pct": pytest.approx(100 * 41.862 / 20.0,
                                                  abs=1e-2),
-        "zmoe.router_ms": 1.5, "zmoe.route_ms": pytest.approx(4.25),
-        "zmoe.gmm_ms": 8.0,
-        "zmoe.gmm_roofline_pct": pytest.approx(100 * 15.697 / 8.0,
-                                               abs=1e-2),
-        "zmoe.layer_share_pct": pytest.approx(100 * 14.25 / programs_ms),
-        "zmoe.held_load": 0.97}
-    assert eshare.read(run)["eshare.route_ms"] == pytest.approx(5.75)
+        "eshare.router_ms": 1.5, "eshare.route_ms": 4.25,
+        "eshare.gmm_ms": 8.0,
+        "eshare.gmm_roofline_pct": pytest.approx(100 * 15.697 / 8.0,
+                                                 abs=1e-2),
+        "eshare.layer_share_pct": pytest.approx(100 * 14.25 / programs_ms),
+        "eshare.held_load": 0.97}
+    assert moe.split_ms(kda.capture_ms(run)[0], 2)["route"] == 5.75
 
 
 def test_a_capture_without_the_scopes_reports_nothing(tmp_path):
@@ -149,18 +150,18 @@ def test_a_capture_without_the_scopes_reports_nothing(tmp_path):
     trace = _capture(tmp_path, [_plane("/device:TPU:0", OPS[-1:])])
     run = _run(tmp_path, trace)
     assert cca.read(run) == {}
-    assert zmoe.read(run) == {"zmoe.held_load": None}
+    assert eshare.read(run) == {"eshare.held_load": None}
     run.trace = None
     assert cca.read(run) == {}
-    assert zmoe.read(run) == {"zmoe.held_load": None}
-    zmoe.setup(run)                     # no probe to run: nothing, no raise
+    assert eshare.read(run) == {"eshare.held_load": None}
+    eshare.setup(run)                    # no probe to run: nothing, no raise
     run.config = types.SimpleNamespace(layer_stats=lambda cfg, rows: {},
                                        FIRST={})
-    zmoe.setup(run)
+    eshare.setup(run)
     assert run.probes == {}
 
 
-@pytest.mark.parametrize("reader,prefix", [(cca, "cca."), (zmoe, "zmoe.")])
+@pytest.mark.parametrize("reader,prefix", [(cca, "cca.")])
 def test_the_readers_declare_what_the_manifest_lists(reader, prefix):
     manifest = cell_lib.load_json(os.path.join(REPO, "BENCHMARK.json"))
     listed = {m["name"]: m for m in manifest["per_layer"]
@@ -176,14 +177,6 @@ def test_the_readers_declare_what_the_manifest_lists(reader, prefix):
                 "%", "higher", "mfu_pct")
 
 
-def test_zmoe_is_eshare_under_this_cell_s_names_and_the_router_s_scope():
-    for name, metric in zmoe.METRICS.items():
-        rest = name.partition(".")[2]
-        if rest != "router_ms":
-            assert metric == eshare.METRICS["eshare." + rest]
-    assert zmoe.METRICS["zmoe.router_ms"] == zmoe.METRICS["zmoe.route_ms"]
-
-
 def test_the_scopes_are_the_program_s():
     """Read, not imported: no JAX here."""
     with open(os.path.join(REPO, "byteps_tpu", "models", "zaya.py")) as f:
@@ -191,9 +184,7 @@ def test_the_scopes_are_the_program_s():
     for key, name in (("proj", "CCA_PROJ_SCOPE"), ("mix", "CCA_MIX_SCOPE"),
                       ("attend", "CCA_ATTEND_SCOPE")):
         assert '%s = "%s"' % (name, cca.SCOPES[key]) in model
-    assert 'ROUTER_SCOPE = "%s"' % zmoe.ROUTER["router"] in model
-    # why zmoe.route_ms subtracts: the one name begins with the other
-    assert zmoe.ROUTER["router"].startswith(moe.ROUTE_SCOPE)
+    assert 'ROUTER_SCOPE = "%s"' % eshare.SCOPES["router"] in model
 
 
 # --------------------------------------------------------------------------
@@ -223,18 +214,12 @@ def test_the_readers_over_the_recorded_scoped_ops(recorded):
         want["cca.layer_share_pct"], rel=1e-9)
     assert cca.attend_roofline_pct(got["attend"], CFG, 1, V5E) \
         == pytest.approx(want["cca.attend_roofline_pct"], rel=1e-9)
-    split = moe.split_ms(ops, steps)
-    router = kda.scoped_ms(ops, zmoe.ROUTER, steps)["router"]
-    assert router == pytest.approx(want["zmoe.router_ms"], rel=1e-9)
-    assert split["gmm"] == pytest.approx(want["zmoe.gmm_ms"], rel=1e-9)
-    assert split["route"] - router == pytest.approx(want["zmoe.route_ms"],
-                                                    rel=1e-9)
-    assert 100 * sum(split.values()) / programs_ms == pytest.approx(
-        want["zmoe.layer_share_pct"], rel=1e-9)
-    for name in ("cca.attend_roofline_pct", "zmoe.gmm_roofline_pct",
-                 "cca.layer_share_pct", "zmoe.layer_share_pct"):
+    for name in ("cca.attend_roofline_pct", "cca.layer_share_pct"):
         assert 0 < want[name] < 100, name
-    assert 0.5 < want["zmoe.held_load"] < 2.0
+    # the expert layers beside them (``test_eshare_reader.py`` holds their
+    # figures over this list): the two layers together inside the step
+    share = eshare.split_ms(ops, steps)
+    assert sum(got.values()) + sum(share.values()) < programs_ms
 
 
 def test_the_kernels_in_the_recorded_capture(recorded):

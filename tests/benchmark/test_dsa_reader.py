@@ -68,7 +68,7 @@ def test_share_operations_and_bytes_by_hand():
     # by a tenth (512 rows an expert against weights of 2048 x 768)
     least_ms = max(1e3 * 9 * one / 197e12, 1e3 * 9 * per_call / 819e9)
     assert least_ms == pytest.approx(4.7092, abs=1e-3)
-    assert eshare.gmm_roofline_pct(20.0, KEYE, rows, V5E) == pytest.approx(
+    assert eshare.gmm_roofline_pct(20.0, KEYE, rows, V5E, 4) == pytest.approx(
         100 * least_ms / 20.0)
     # no rows, no operations: the weights' bytes remain
     assert eshare.gmm_flops(0, 2048, 768) == 0
@@ -149,8 +149,9 @@ def test_the_readers_declare_what_the_manifest_lists():
         "dsa.layer_share_pct", "dsa.attend_roofline_pct",
         "dsa.kept_keys_pct"}
     assert set(eshare.METRICS) == {
-        "eshare.gmm_ms", "eshare.route_ms", "eshare.layer_share_pct",
-        "eshare.gmm_roofline_pct", "eshare.held_load"}
+        "eshare.gmm_ms", "eshare.router_ms", "eshare.route_ms",
+        "eshare.layer_share_pct", "eshare.gmm_roofline_pct",
+        "eshare.held_load"}
     for name in ("dsa.attend_roofline_pct", "eshare.gmm_roofline_pct"):
         metric = {**dsa.METRICS, **eshare.METRICS}[name]
         assert metric["better"] == "higher" and metric["moves"] == "mfu_pct"
@@ -181,7 +182,7 @@ def test_both_readers_over_the_recorded_scoped_ops(recorded):
     assert 100 * sum(got.values()) / programs_ms == pytest.approx(
         want["dsa.layer_share_pct"], rel=1e-9)
     assert got["attend"] > got["indexer"] > 0 and got["select"] > 0
-    share = moe.split_ms(ops, steps)
+    share = eshare.split_ms(ops, steps)
     assert share["gmm"] == pytest.approx(want["eshare.gmm_ms"], rel=1e-9)
     assert share["route"] == pytest.approx(want["eshare.route_ms"], rel=1e-9)
     assert 100 * sum(share.values()) / programs_ms == pytest.approx(

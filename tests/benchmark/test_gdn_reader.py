@@ -1,5 +1,5 @@
 """The three readers of ``qwen3-next-80b-a3b.collective-gdn.1chip``
-(``benchmark/layers/gdn.py``, ``gattn.py``, ``nmoe.py``): the rooflines'
+(``benchmark/layers/gdn.py``, ``gattn.py``, ``eshare.py``): the rooflines'
 operations and bytes by hand at the cell's size, their reading of a made-up
 ``.xplane.pb`` (encoded by ``test_moe_reader.py``'s helpers, with hand-worked
 sums) through the one shared read of the capture, and their reading of what
@@ -21,8 +21,7 @@ sys.path.insert(0, HERE)
 from bench_tiny import REPO  # noqa: E402,F401
 from test_moe_reader import MS, _capture, _plane  # noqa: E402
 
-from benchmark.layers import (eshare, gattn, gdn, kda, moe, nmoe,  # noqa: E402
-                              smoe, swa)
+from benchmark.layers import eshare, gattn, gdn, kda, swa  # noqa: E402
 from benchmark.lib import cell as cell_lib  # noqa: E402
 from benchmark.lib import trace_reduce as tr  # noqa: E402
 
@@ -83,7 +82,7 @@ def test_the_attention_s_roofline_by_hand():
 
 
 def test_the_grouped_matmuls_roofline_by_hand():
-    """``nmoe.gmm_roofline_pct`` is ``layers/eshare.py``'s count: the rows
+    """``eshare.gmm_roofline_pct`` at this cell's shapes: the rows
     that reached the 32 held experts, nine calls, the held weights only. At
     even routing 4 x 10,240 rows: bound by the weights' bytes."""
     rows = 4 * 10_240
@@ -93,7 +92,7 @@ def test_the_grouped_matmuls_roofline_by_hand():
     assert nbytes == 9 * 2 * (rows * 2560 + 4 * 32 * 2048 * 512) \
         == 4_303_355_904
     assert 1e3 * nbytes / 819e9 > 1e3 * flops / 197e12      # bandwidth binds
-    assert eshare.gmm_roofline_pct(20.0, CFG, rows, V5E) == pytest.approx(
+    assert eshare.gmm_roofline_pct(20.0, CFG, rows, V5E, 4) == pytest.approx(
         100 * (1e3 * nbytes / 819e9) / 20.0)
 
 
@@ -163,8 +162,9 @@ def test_each_layer_counts_under_its_own_scope(tmp_path, monkeypatch):
     monkeypatch.setattr("jax.devices", lambda: [types.SimpleNamespace(
         device_kind="TPU v5 lite")])
     run = _run(tmp_path, trace)
-    run.probes.update(bps_moe_held_load=0.9, eshare_held_rows=40_960)
-    got = {**gdn.read(run), **gattn.read(run), **nmoe.read(run)}
+    run.probes.update(bps_moe_held_load=0.9, eshare_held_rows=40_960,
+                      eshare_expert_layers=4)
+    got = {**gdn.read(run), **gattn.read(run), **eshare.read(run)}
     programs_ms = 999_000_000 * 1e-9 / 2               # 0.4995 ms a step
     assert got == {
         "gdn.scan_ms": 20.0, "gdn.prep_ms": 3.0,
@@ -174,13 +174,13 @@ def test_each_layer_counts_under_its_own_scope(tmp_path, monkeypatch):
         "gattn.attend_ms": 20.0, "gattn.proj_ms": 5.0,
         "gattn.attend_roofline_pct": pytest.approx(100 * 33.4897 / 20.0,
                                                    abs=1e-2),
-        "nmoe.gmm_ms": 1.0, "nmoe.route_ms": 4.25,
-        "nmoe.gmm_roofline_pct": pytest.approx(
+        "eshare.gmm_ms": 1.0, "eshare.route_ms": 4.25,
+        "eshare.gmm_roofline_pct": pytest.approx(
             100 * (1e3 * 4_303_355_904 / 819e9) / 1.0, abs=1e-2),
-        "nmoe.layer_share_pct": pytest.approx(100 * 7.75 / programs_ms),
-        "nmoe.held_load": 0.9}
+        "eshare.layer_share_pct": pytest.approx(100 * 7.75 / programs_ms),
+        "eshare.held_load": 0.9}
     assert run.probes["gdn_out_ms"] == 3.0
-    assert run.probes["smoe_shared_ms"] == 2.0
+    assert run.probes["eshare_shared_ms"] == 2.0
 
 
 def test_a_capture_without_the_scopes_reports_nothing(tmp_path):
@@ -190,23 +190,22 @@ def test_a_capture_without_the_scopes_reports_nothing(tmp_path):
     trace = _capture(tmp_path, [_plane("/device:TPU:0", OPS[-1:])])
     run = _run(tmp_path, trace)
     assert gdn.read(run) == {} and gattn.read(run) == {}
-    assert nmoe.read(run) == {"nmoe.held_load": None}
+    assert eshare.read(run) == {"eshare.held_load": None}
     run.trace = None
     assert gdn.read(run) == {} and gattn.read(run) == {}
-    assert nmoe.read(run) == {"nmoe.held_load": None}
+    assert eshare.read(run) == {"eshare.held_load": None}
     gdn.setup(run)                      # no probe to run: nothing, no raise
-    nmoe.setup(run)
+    eshare.setup(run)
     run.config = types.SimpleNamespace(layer_stats=lambda cfg, rows: {},
                                        FIRST={})
     gdn.setup(run)
-    nmoe.setup(run)
+    eshare.setup(run)
     assert run.probes == {}
 
 
 @pytest.mark.parametrize("reader,prefix,layer", [
     (gdn, "gdn.", "per-head linear attention"),
-    (gattn, "gattn.", "gated attention"),
-    (nmoe, "nmoe.", "expert share, many small experts")])
+    (gattn, "gattn.", "gated attention")])
 def test_the_readers_declare_what_the_manifest_lists(reader, prefix, layer):
     manifest = cell_lib.load_json(os.path.join(REPO, "BENCHMARK.json"))
     listed = {m["name"]: m for m in manifest["per_layer"]
@@ -221,14 +220,6 @@ def test_the_readers_declare_what_the_manifest_lists(reader, prefix, layer):
         if name.endswith("_roofline_pct"):
             assert (metric["unit"], metric["better"], metric["moves"]) == (
                 "%", "higher", "mfu_pct")
-
-
-def test_nmoe_is_eshare_and_smoe_under_this_cell_s_names():
-    for name, metric in nmoe.METRICS.items():
-        rest = name.partition(".")[2]
-        other = (eshare.METRICS["eshare." + rest] if rest in nmoe.FROM_ESHARE
-                 else smoe.METRICS["smoe." + rest])
-        assert metric == other
 
 
 def test_the_scopes_are_the_program_s():
@@ -281,14 +272,13 @@ def test_the_readers_over_the_recorded_scoped_ops(recorded):
                                               rel=1e-9)
     assert gattn.attend_roofline_pct(attention["attend"], CFG, 1, V5E) \
         == pytest.approx(want["gattn.attend_roofline_pct"], rel=1e-9)
-    split = moe.split_ms(ops, steps)
-    assert split["gmm"] == pytest.approx(want["nmoe.gmm_ms"], rel=1e-9)
-    assert split["route"] == pytest.approx(want["nmoe.route_ms"], rel=1e-9)
     for name in ("gdn.scan_roofline_pct", "gattn.attend_roofline_pct",
-                 "nmoe.gmm_roofline_pct", "gdn.layer_share_pct",
-                 "nmoe.layer_share_pct"):
+                 "gdn.layer_share_pct"):
         assert 0 < want[name] < 100, name
-    assert 0.5 < want["nmoe.held_load"] < 2.0
+    # the expert layers beside them: ``test_eshare_reader.py`` holds their
+    # figures over this list
+    assert sum(eshare.split_ms(ops, steps).values()) + sum(
+        got.values()) + sum(attention.values()) < programs_ms
 
 
 def test_the_kernels_in_the_recorded_capture(recorded):

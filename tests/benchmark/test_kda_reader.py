@@ -1,5 +1,5 @@
 """The three readers of ``kimi-linear-48b-a3b.collective-kda.1chip``
-(``benchmark/layers/kda.py``, ``mla.py``, ``smoe.py``): their operations and
+(``benchmark/layers/kda.py``, ``mla.py``, ``eshare.py``): their operations and
 bytes by hand at the cell's size, their reading of a made-up ``.xplane.pb``
 (encoded by ``test_moe_reader.py``'s helpers, with hand-worked sums) through
 the one shared read of the capture, and their reading of what the builder's
@@ -20,7 +20,7 @@ sys.path.insert(0, HERE)
 from bench_tiny import REPO  # noqa: E402,F401
 from test_moe_reader import MS, _capture, _plane  # noqa: E402
 
-from benchmark.layers import kda, mla, smoe  # noqa: E402
+from benchmark.layers import eshare, kda, mla  # noqa: E402
 from benchmark.layers import moe  # noqa: E402
 from benchmark.lib import cell as cell_lib  # noqa: E402
 from benchmark.lib import trace_reduce as tr  # noqa: E402
@@ -140,7 +140,7 @@ def test_scopes_are_read_once_and_a_loop_is_not_counted_twice(
         tmp_path, monkeypatch):
     """Two steps; the programs' line reads 0.999 ms over them. KDA scan 5 + 3
     ms a step, prep 2, out 0.5; the attention 7; route 4 + 0.25 (the
-    metadata helper), experts 1, shared 2; the two loops (16 ms a step)
+    metadata helper), the kernel 1, shared 2; the two loops (16 ms a step)
     count nowhere. The capture is parsed twice (ops, programs) whichever
     readers ask, and however often."""
     trace = _capture(tmp_path, [_plane("/device:TPU:0", OPS)])
@@ -157,7 +157,7 @@ def test_scopes_are_read_once_and_a_loop_is_not_counted_twice(
         device_kind="TPU v5 lite")])
     run = _run(tmp_path, trace)
     run.probes["bps_moe_held_load"] = 0.9
-    got = {**kda.read(run), **mla.read(run), **smoe.read(run)}
+    got = {**kda.read(run), **mla.read(run), **eshare.read(run)}
     assert len(reads) == 2
     programs_ms = 999_000_000 * 1e-9 / 2               # 0.4995 ms a step
     assert got == {
@@ -168,11 +168,11 @@ def test_scopes_are_read_once_and_a_loop_is_not_counted_twice(
         "mla.attend_ms": 7.0,
         "mla.attend_roofline_pct": pytest.approx(100 * 41.862 / 7.0,
                                                  abs=1e-2),
-        "smoe.route_ms": 4.25,
-        "smoe.layer_share_pct": pytest.approx(100 * 7.25 / programs_ms),
-        "smoe.held_load": 0.9}
+        "eshare.route_ms": 4.25, "eshare.gmm_ms": 1.0,
+        "eshare.layer_share_pct": pytest.approx(100 * 7.25 / programs_ms),
+        "eshare.held_load": 0.9}
     assert run.probes["kda_out_ms"] == 0.5
-    assert run.probes["smoe_shared_ms"] == 2.0
+    assert run.probes["eshare_shared_ms"] == 2.0
     assert run.probes["mla_attend_share_pct"] == pytest.approx(
         100 * 7.0 / programs_ms)
 
@@ -183,11 +183,11 @@ def test_a_capture_without_the_scopes_reports_nothing(tmp_path):
     trace = _capture(tmp_path, [_plane("/device:TPU:0", OPS[-1:])])
     run = _run(tmp_path, trace)
     assert kda.read(run) == {} and mla.read(run) == {}
-    assert smoe.read(run) == {"smoe.held_load": None}
+    assert eshare.read(run) == {"eshare.held_load": None}
     run.trace = None
     assert kda.read(run) == {} and mla.read(run) == {}
-    assert smoe.read(run) == {"smoe.held_load": None}
-    for reader in (kda, smoe):
+    assert eshare.read(run) == {"eshare.held_load": None}
+    for reader in (kda, eshare):
         reader.setup(run)               # no probe to run: nothing, no raise
         run.config = types.SimpleNamespace(layer_stats=None, FIRST={})
         reader.setup(run)
@@ -212,18 +212,17 @@ def test_the_probes_publish_what_the_model_sowed(tmp_path):
     run.config = types.SimpleNamespace(layer_stats=layer_stats,
                                        FIRST={"seed": 1}, FIRST_EXPERT=0)
     kda.setup(run)
-    smoe.setup(run)
+    eshare.setup(run)
     assert calls == [1, 1]                             # one chip's batch
     assert run.probes["bps_kda_min_chunk_log_decay"] == -91.5
     assert run.probes["bps_moe_held_load"] == pytest.approx(
         8 * 256 / ((8 * 256 + 248 * 512) * 8 / 256))
-    assert smoe.read(run)["smoe.held_load"] == \
+    assert eshare.read(run)["eshare.held_load"] == \
         run.probes["bps_moe_held_load"]
 
 
 @pytest.mark.parametrize("reader,prefix,layer", [
-    (kda, "kda.", "linear attention"), (mla, "mla.", "latent attention"),
-    (smoe, "smoe.", "shared-expert share")])
+    (kda, "kda.", "linear attention"), (mla, "mla.", "latent attention")])
 def test_the_readers_declare_what_the_manifest_lists(reader, prefix, layer):
     manifest = cell_lib.load_json(os.path.join(REPO, "BENCHMARK.json"))
     listed = {m["name"]: m for m in manifest["per_layer"]
@@ -253,9 +252,9 @@ def test_the_scopes_are_the_program_s():
         kda.SCOPES["prep"], kda.SCOPES["scan"]) in op
     assert 'KDA_OUT_SCOPE = "%s"' % kda.SCOPES["out"] in model
     assert 'MLA_ATTEND_SCOPE = "%s"' % mla.SCOPE in model
-    assert 'SHARED_SCOPE = "%s"' % smoe.SCOPES["shared"] in model
-    assert 'ROUTE_SCOPE = "%s"' % smoe.SCOPES["route"] in moe_py
-    assert 'EXPERTS_SCOPE = "%s"' % smoe.SCOPES["experts"] in moe_py
+    assert 'SHARED_SCOPE = "%s"' % eshare.SCOPES["shared"] in model
+    assert 'ROUTE_SCOPE = "%s"' % eshare.SCOPES["route"] in moe_py
+    assert 'EXPERTS_SCOPE = "%s"' % eshare.SCOPES["experts_other"] in moe_py
 
 
 # --------------------------------------------------------------------------

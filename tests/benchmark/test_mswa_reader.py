@@ -1,5 +1,5 @@
 """The two readers of ``mellum2-12b-a2.5b.collective-swa-moe.1chip``
-(``benchmark/layers/mswa.py``, ``mmoe.py``): the rooflines' operations and
+(``benchmark/layers/mswa.py``, ``eshare.py``): the rooflines' operations and
 bytes by hand at the cell's size, their reading of a made-up ``.xplane.pb``
 (encoded by ``test_moe_reader.py``'s helpers, with hand-worked sums) through
 the one shared read of the capture, the probe's two gauges, and their
@@ -21,7 +21,7 @@ sys.path.insert(0, HERE)
 from bench_tiny import REPO  # noqa: E402,F401
 from test_moe_reader import MS, _capture, _plane  # noqa: E402
 
-from benchmark.layers import eshare, kda, mmoe, moe, mswa, swa  # noqa: E402
+from benchmark.layers import eshare, kda, mswa, swa  # noqa: E402
 from benchmark.lib import cell as cell_lib  # noqa: E402
 from benchmark.lib import trace_reduce as tr  # noqa: E402
 
@@ -81,7 +81,7 @@ def test_the_grouped_matmuls_roofline_by_hand():
     flops_ms, bytes_ms = 1e3 * flops / 197e12, 1e3 * moved / 819e9
     assert flops_ms == pytest.approx(24.723, abs=1e-3)
     assert bytes_ms == pytest.approx(12.122, abs=1e-3)   # arithmetic binds
-    assert eshare.gmm_roofline_pct(80.0, CFG, rows, V5E) == pytest.approx(
+    assert eshare.gmm_roofline_pct(80.0, CFG, rows, V5E, 4) == pytest.approx(
         100 * flops_ms / 80.0)
 
 
@@ -135,15 +135,16 @@ def test_each_kind_of_layer_counts_under_its_own_scope(tmp_path,
     """Two steps; the programs' line reads 0.999 ms over them. Windowed 2 +
     6, global 10 + 15, projections 4 + 1; route 4 + 0.25 (the metadata
     helper), the grouped matmul 8, the rest of the experts' scope 2. The
-    ratio comes from the counters, the two gauges from the probe."""
+    ratio comes from the counters, the load, the rows and the layers from
+    the probe."""
     trace = _capture(tmp_path, [_plane("/device:TPU:0", OPS)])
     monkeypatch.setattr("jax.devices", lambda: [types.SimpleNamespace(
         device_kind="TPU v5 lite")])
     monkeypatch.setattr(swa, "walked_pairs_ratio", lambda: 1.5)
     run = _run(tmp_path, trace)
-    run.probes.update(bps_moe_held_load=0.97, bps_moe_compact_share=1.0,
-                      eshare_held_rows=131_072)
-    got = {**mswa.read(run), **mmoe.read(run)}
+    run.probes.update(bps_moe_held_load=0.97, eshare_held_rows=131_072,
+                      eshare_expert_layers=4)
+    got = {**mswa.read(run), **eshare.read(run)}
     programs_ms = 999_000_000 * 1e-9 / 2               # 0.4995 ms a step
     assert got == {
         "mswa.window_ms": 8.0, "mswa.full_ms": 25.0, "mswa.proj_ms": 5.0,
@@ -153,11 +154,11 @@ def test_each_kind_of_layer_counts_under_its_own_scope(tmp_path,
         "mswa.full_roofline_pct": pytest.approx(100 * 16.746 / 25.0,
                                                 abs=1e-2),
         "mswa.walked_pairs_ratio": 1.5,
-        "mmoe.route_ms": 4.25, "mmoe.gmm_ms": 8.0,
-        "mmoe.layer_share_pct": pytest.approx(100 * 14.25 / programs_ms),
-        "mmoe.gmm_roofline_pct": pytest.approx(100 * 24.723 / 8.0,
-                                               abs=1e-2),
-        "mmoe.held_load": 0.97, "mmoe.compact_share_pct": 100.0}
+        "eshare.route_ms": 4.25, "eshare.gmm_ms": 8.0,
+        "eshare.layer_share_pct": pytest.approx(100 * 14.25 / programs_ms),
+        "eshare.gmm_roofline_pct": pytest.approx(100 * 24.723 / 8.0,
+                                                 abs=1e-2),
+        "eshare.held_load": 0.97}
 
 
 def test_a_capture_without_the_scopes_reports_nothing(tmp_path, monkeypatch):
@@ -170,35 +171,24 @@ def test_a_capture_without_the_scopes_reports_nothing(tmp_path, monkeypatch):
     trace = _capture(tmp_path, [_plane("/device:TPU:0", OPS[-1:])])
     run = _run(tmp_path, trace)
     assert mswa.read(run) == {"mswa.walked_pairs_ratio": None}
-    assert mmoe.read(run) == {"mmoe.held_load": None}
+    assert eshare.read(run) == {"eshare.held_load": None}
     run.trace = None
     assert mswa.read(run) == {"mswa.walked_pairs_ratio": None}
-    assert mmoe.read(run) == {"mmoe.held_load": None}
-    mmoe.setup(run)                     # no probe to run: nothing, no raise
+    assert eshare.read(run) == {"eshare.held_load": None}
+    eshare.setup(run)                    # no probe to run: nothing, no raise
     run.config = types.SimpleNamespace(layer_stats=None, FIRST={})
-    mmoe.setup(run)
+    eshare.setup(run)
     assert run.probes == {}
 
 
-def test_the_compact_share_is_the_probe_s_gauge():
-    run = types.SimpleNamespace(trace=None, probes={
-        "bps_moe_held_load": 1.02, "bps_moe_compact_share": 0.75})
-    assert mmoe.read(run) == {"mmoe.held_load": 1.02,
-                              "mmoe.compact_share_pct": 75.0}
-
-
 @pytest.mark.parametrize("reader,prefix,layer", [
-    (mswa, "mswa.", "windowed and global attention"),
-    (mmoe, "mmoe.", "expert share, a quarter of the experts at top-8")])
+    (mswa, "mswa.", "windowed and global attention")])
 def test_the_readers_declare_what_the_manifest_lists(reader, prefix, layer):
     manifest = cell_lib.load_json(os.path.join(REPO, "BENCHMARK.json"))
     listed = {m["name"]: m for m in manifest["per_layer"]
               if m["name"].startswith(prefix)}
     assert reader.LAYER == layer
-    # the manifest's list was full at 128 in PR 66: the probe's gauge, 100.0
-    # on every line of the ledger, left it for ``share.held_load_end``; the
-    # reader still computes it and the line drops it
-    assert set(listed) == set(reader.METRICS) - {"mmoe.compact_share_pct"}
+    assert set(listed) == set(reader.METRICS)
     for name, metric in listed.items():
         assert metric["layer"] == reader.LAYER
         assert metric["workloads"] == [CELL]
@@ -209,12 +199,9 @@ def test_the_readers_declare_what_the_manifest_lists(reader, prefix, layer):
                 "%", "higher", "mfu_pct")
 
 
-def test_the_readers_are_the_older_ones_under_this_cell_s_names():
+def test_the_reader_is_the_older_one_under_this_cell_s_names():
     assert {name.partition(".")[2]: m for name, m in mswa.METRICS.items()} \
         == {name.partition(".")[2]: m for name, m in swa.METRICS.items()}
-    assert {name.partition(".")[2]: m for name, m in mmoe.METRICS.items()
-            if "compact" not in name} \
-        == {name.partition(".")[2]: m for name, m in eshare.METRICS.items()}
     assert mswa.LAYER == swa.LAYER
 
 
@@ -266,20 +253,11 @@ def test_the_readers_over_the_recorded_scoped_ops(recorded):
         assert mswa.roofline_pct(got[key], CFG, ROWS, V5E, windowed) == \
             pytest.approx(want[f"mswa.{key}_roofline_pct"], rel=1e-9)
         assert 0 < want[f"mswa.{key}_roofline_pct"] < 100
-    share = moe.split_ms(ops, steps)
-    assert share["route"] == pytest.approx(want["mmoe.route_ms"], rel=1e-9)
-    assert share["gmm"] == pytest.approx(want["mmoe.gmm_ms"], rel=1e-9)
-    assert 100 * sum(share.values()) / programs_ms == pytest.approx(
-        want["mmoe.layer_share_pct"], rel=1e-9)
-    assert eshare.gmm_roofline_pct(
-        share["gmm"], CFG, recorded["held_rows"], V5E) == pytest.approx(
-        want["mmoe.gmm_roofline_pct"], rel=1e-9)
-    assert 0 < want["mmoe.gmm_roofline_pct"] < 100
-    assert want["mswa.layer_share_pct"] + want["mmoe.layer_share_pct"] < 100
-    assert 0.9 < want["mmoe.held_load"] < 1.1
-    assert want["mmoe.compact_share_pct"] == 100.0
-    assert want["mmoe.held_load"] == pytest.approx(
-        recorded["held_rows"] / (4 * ROWS * 8_192 * 8 * 16 / 64), rel=1e-6)
+    # the expert layers beside them (``test_eshare_reader.py`` holds their
+    # figures over this list): the two layers together inside the step
+    share = eshare.split_ms(ops, steps)
+    assert want["mswa.layer_share_pct"] \
+        + 100 * sum(share.values()) / programs_ms < 100
 
 
 def test_the_kernels_in_the_recorded_capture(recorded):

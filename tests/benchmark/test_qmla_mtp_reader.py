@@ -1,9 +1,9 @@
 """The three readers of ``joyai-llm-flash.collective-mtp.1chip``
-(``benchmark/layers/qmla.py``, ``mtp.py``, ``lmoe.py``): the roofline's
+(``benchmark/layers/qmla.py``, ``mtp.py``, ``eshare.py``): the roofline's
 operations and bytes by hand at the cell's size, their reading of a made-up
 ``.xplane.pb`` (encoded by ``test_moe_reader.py``'s helpers, with hand-worked
 sums) through the one shared read of the capture — the module's block under
-``bps.mtp`` counted by ``qmla`` / ``lmoe`` and by ``mtp`` alike, the main
+``bps.mtp`` counted by ``qmla`` / ``eshare`` and by ``mtp`` alike, the main
 head by neither — and their reading of what the builder's own traced run of
 the cell recorded (my chip run, PR 41, seed 2147483907): the capture's
 scoped ops, equal ones summed, cut by ``benchmark/layers/kda.py``'s command,
@@ -22,7 +22,7 @@ sys.path.insert(0, HERE)
 from bench_tiny import REPO  # noqa: E402,F401
 from test_moe_reader import MS, _capture, _plane  # noqa: E402
 
-from benchmark.layers import kda, lmoe, mla, moe, mtp, qmla  # noqa: E402
+from benchmark.layers import eshare, kda, mla, moe, mtp, qmla  # noqa: E402
 from benchmark.lib import cell as cell_lib  # noqa: E402
 from benchmark.lib import trace_reduce as tr  # noqa: E402
 
@@ -117,7 +117,7 @@ def test_the_module_s_block_counts_under_both_prefixes(tmp_path,
     """Two steps; the programs' line reads 0.999 ms over them. Attention 10
     (main) + 6 (the module's), projections 3 + 1; under ``bps.mtp``: 6 + 1 +
     0.5 (combine) + 2 (its head) + 1 (its experts) = 10.5, the scan around
-    the head nowhere; route 4 + 0.25 (the metadata helper), experts 1,
+    the head nowhere; route 4 + 0.25 (the metadata helper), the kernel 1,
     shared 2. The main head (2) is in no metric of these readers. The
     capture is parsed twice (ops, programs) whichever readers ask."""
     trace = _capture(tmp_path, [_plane("/device:TPU:0", OPS)])
@@ -128,7 +128,7 @@ def test_the_module_s_block_counts_under_both_prefixes(tmp_path,
         device_kind="TPU v5 lite")])
     run = _run(tmp_path, trace)
     run.probes["bps_moe_held_load"] = 0.9
-    got = {**qmla.read(run), **mtp.read(run), **lmoe.read(run)}
+    got = {**qmla.read(run), **mtp.read(run), **eshare.read(run)}
     assert len(reads) == 2
     programs_ms = 999_000_000 * 1e-9 / 2               # 0.4995 ms a step
     assert got == {
@@ -138,11 +138,11 @@ def test_the_module_s_block_counts_under_both_prefixes(tmp_path,
                                                   abs=1e-2),
         "mtp.module_ms": 10.5, "mtp.head_ms": 2.0,
         "mtp.share_pct": pytest.approx(100 * 10.5 / programs_ms),
-        "lmoe.route_ms": 4.25,
-        "lmoe.layer_share_pct": pytest.approx(100 * 7.25 / programs_ms),
-        "lmoe.held_load": 0.9}
+        "eshare.route_ms": 4.25, "eshare.gmm_ms": 1.0,
+        "eshare.layer_share_pct": pytest.approx(100 * 7.25 / programs_ms),
+        "eshare.held_load": 0.9}
     assert run.probes["mtp_combine_ms"] == 0.5
-    assert run.probes["smoe_shared_ms"] == 2.0
+    assert run.probes["eshare_shared_ms"] == 2.0
 
 
 def test_a_capture_without_the_scopes_reports_nothing(tmp_path):
@@ -151,11 +151,11 @@ def test_a_capture_without_the_scopes_reports_nothing(tmp_path):
     trace = _capture(tmp_path, [_plane("/device:TPU:0", OPS[-1:])])
     run = _run(tmp_path, trace)
     assert qmla.read(run) == {} and mtp.read(run) == {}
-    assert lmoe.read(run) == {"lmoe.held_load": None}
+    assert eshare.read(run) == {"eshare.held_load": None}
     run.trace = None
     assert qmla.read(run) == {} and mtp.read(run) == {}
-    assert lmoe.read(run) == {"lmoe.held_load": None}
-    for reader in (mtp, lmoe):
+    assert eshare.read(run) == {"eshare.held_load": None}
+    for reader in (mtp, eshare):
         reader.setup(run)               # no probe to run: nothing, no raise
         run.config = types.SimpleNamespace(layer_stats=None, FIRST={})
         reader.setup(run)
@@ -180,20 +180,19 @@ def test_the_probes_publish_what_the_model_sowed(tmp_path):
     run.config = types.SimpleNamespace(layer_stats=layer_stats,
                                        FIRST={"seed": 1}, FIRST_EXPERT=0)
     mtp.setup(run)
-    lmoe.setup(run)
+    eshare.setup(run)
     assert calls == [1, 1]                             # one chip's batch
     assert run.probes["bps_mtp_main_loss"] == 10.25
     assert run.probes["bps_mtp_next2_loss"] == 10.5
     assert run.probes["bps_moe_held_load"] == pytest.approx(
         8 * 256 / ((8 * 256 + 248 * 512) * 8 / 256))
-    assert lmoe.read(run)["lmoe.held_load"] == \
+    assert eshare.read(run)["eshare.held_load"] == \
         run.probes["bps_moe_held_load"]
 
 
 @pytest.mark.parametrize("reader,prefix,layer", [
     (qmla, "qmla.", "rotary latent attention"),
-    (mtp, "mtp.", "multi-token prediction"),
-    (lmoe, "lmoe.", "expert share, latent stack")])
+    (mtp, "mtp.", "multi-token prediction")])
 def test_the_readers_declare_what_the_manifest_lists(reader, prefix, layer):
     manifest = cell_lib.load_json(os.path.join(REPO, "BENCHMARK.json"))
     listed = {m["name"]: m for m in manifest["per_layer"]
@@ -208,13 +207,6 @@ def test_the_readers_declare_what_the_manifest_lists(reader, prefix, layer):
         if name.endswith("_roofline_pct"):
             assert (metric["unit"], metric["better"], metric["moves"]) == (
                 "%", "higher", "mfu_pct")
-
-
-def test_lmoe_is_smoe_under_this_cell_s_names():
-    from benchmark.layers import smoe
-
-    assert {name.partition(".")[2]: m for name, m in lmoe.METRICS.items()} \
-        == {name.partition(".")[2]: m for name, m in smoe.METRICS.items()}
 
 
 def test_the_scopes_are_the_program_s():
@@ -267,8 +259,7 @@ def test_the_readers_over_the_recorded_scoped_ops(recorded):
     assert head == pytest.approx(want["mtp.head_ms"], rel=1e-9)
     assert 100 * module / programs_ms == pytest.approx(
         want["mtp.share_pct"], rel=1e-9)
-    for share in ("qmla.layer_share_pct", "mtp.share_pct",
-                  "lmoe.layer_share_pct"):
+    for share in ("qmla.layer_share_pct", "mtp.share_pct"):
         assert 0 < want[share] < 100
 
 

@@ -1,5 +1,5 @@
 """The two readers of ``laguna-xs.2.collective-swa.1chip``
-(``benchmark/layers/swa.py``, ``wmoe.py``): the rooflines' operations and
+(``benchmark/layers/swa.py``, ``eshare.py``): the rooflines' operations and
 bytes by hand at the cell's size, their reading of a made-up ``.xplane.pb``
 (encoded by ``test_moe_reader.py``'s helpers, with hand-worked sums) through
 the one shared read of the capture, the counters' ratio, and their reading
@@ -21,7 +21,7 @@ sys.path.insert(0, HERE)
 from bench_tiny import REPO  # noqa: E402,F401
 from test_moe_reader import MS, _capture, _plane  # noqa: E402
 
-from benchmark.layers import kda, moe, smoe, swa, wmoe  # noqa: E402
+from benchmark.layers import eshare, kda, moe, swa  # noqa: E402
 from benchmark.lib import cell as cell_lib  # noqa: E402
 from benchmark.lib import trace_reduce as tr  # noqa: E402
 
@@ -119,7 +119,7 @@ def test_each_kind_of_layer_counts_under_its_own_scope(tmp_path,
                                                        monkeypatch):
     """Two steps; the programs' line reads 0.999 ms over them. Windowed 2 +
     3, global 10 + 15, projections 4 + 1; route 4 + 0.25 (the metadata
-    helper), experts 1, shared 2. The capture is parsed twice (ops,
+    helper), the kernel 1, shared 2. The capture is parsed twice (ops,
     programs) whichever readers ask. The ratio comes from the counters."""
     trace = _capture(tmp_path, [_plane("/device:TPU:0", OPS)])
     reads = []
@@ -130,7 +130,7 @@ def test_each_kind_of_layer_counts_under_its_own_scope(tmp_path,
     monkeypatch.setattr(swa, "walked_pairs_ratio", lambda: 2.0)
     run = _run(tmp_path, trace)
     run.probes["bps_moe_held_load"] = 0.9
-    got = {**swa.read(run), **wmoe.read(run)}
+    got = {**swa.read(run), **eshare.read(run)}
     assert len(reads) == 2
     programs_ms = 999_000_000 * 1e-9 / 2               # 0.4995 ms a step
     assert got == {
@@ -141,10 +141,10 @@ def test_each_kind_of_layer_counts_under_its_own_scope(tmp_path,
         "swa.full_roofline_pct": pytest.approx(100 * 25.119 / 25.0,
                                                abs=1e-2),
         "swa.walked_pairs_ratio": 2.0,
-        "wmoe.route_ms": 4.25,
-        "wmoe.layer_share_pct": pytest.approx(100 * 7.25 / programs_ms),
-        "wmoe.held_load": 0.9}
-    assert run.probes["smoe_shared_ms"] == 2.0
+        "eshare.route_ms": 4.25, "eshare.gmm_ms": 1.0,
+        "eshare.layer_share_pct": pytest.approx(100 * 7.25 / programs_ms),
+        "eshare.held_load": 0.9}
+    assert run.probes["eshare_shared_ms"] == 2.0
 
 
 def test_a_capture_without_the_scopes_reports_nothing(tmp_path, monkeypatch):
@@ -156,13 +156,13 @@ def test_a_capture_without_the_scopes_reports_nothing(tmp_path, monkeypatch):
     trace = _capture(tmp_path, [_plane("/device:TPU:0", OPS[-1:])])
     run = _run(tmp_path, trace)
     assert swa.read(run) == {"swa.walked_pairs_ratio": None}
-    assert wmoe.read(run) == {"wmoe.held_load": None}
+    assert eshare.read(run) == {"eshare.held_load": None}
     run.trace = None
     assert swa.read(run) == {"swa.walked_pairs_ratio": None}
-    assert wmoe.read(run) == {"wmoe.held_load": None}
-    wmoe.setup(run)                     # no probe to run: nothing, no raise
+    assert eshare.read(run) == {"eshare.held_load": None}
+    eshare.setup(run)                    # no probe to run: nothing, no raise
     run.config = types.SimpleNamespace(layer_stats=None, FIRST={})
-    wmoe.setup(run)
+    eshare.setup(run)
     assert run.probes == {}
 
 
@@ -178,8 +178,7 @@ def test_the_ratio_is_the_counters(monkeypatch):
 
 
 @pytest.mark.parametrize("reader,prefix,layer", [
-    (swa, "swa.", "windowed and global attention"),
-    (wmoe, "wmoe.", "expert share, windowed stack")])
+    (swa, "swa.", "windowed and global attention")])
 def test_the_readers_declare_what_the_manifest_lists(reader, prefix, layer):
     manifest = cell_lib.load_json(os.path.join(REPO, "BENCHMARK.json"))
     listed = {m["name"]: m for m in manifest["per_layer"]
@@ -194,11 +193,6 @@ def test_the_readers_declare_what_the_manifest_lists(reader, prefix, layer):
         if name.endswith("_roofline_pct"):
             assert (metric["unit"], metric["better"], metric["moves"]) == (
                 "%", "higher", "mfu_pct")
-
-
-def test_wmoe_is_smoe_under_this_cell_s_names():
-    assert {name.partition(".")[2]: m for name, m in wmoe.METRICS.items()} \
-        == {name.partition(".")[2]: m for name, m in smoe.METRICS.items()}
 
 
 def test_the_scopes_and_counters_are_the_program_s():
@@ -251,13 +245,11 @@ def test_the_readers_over_the_recorded_scoped_ops(recorded):
     # init()'s trace of 8 tokens, whose square of 64 holds 36)
     assert want["swa.walked_pairs_ratio"] == pytest.approx(
         31 * 512 * 512 / 4_063_488, rel=1e-5)
-    share = kda.scoped_ms([
-        (name, smoe.SCOPES["route"] if name.startswith("%ragged-dot-metadata")
-         else tf_op, ps) for name, tf_op, ps in ops], smoe.SCOPES, steps)
-    assert share["route"] == pytest.approx(want["wmoe.route_ms"], rel=1e-9)
-    assert 100 * sum(share.values()) / programs_ms == pytest.approx(
-        want["wmoe.layer_share_pct"], rel=1e-9)
-    assert 0 < want["wmoe.layer_share_pct"] < want["swa.layer_share_pct"]
+    # the expert layers beside them (``test_eshare_reader.py`` holds their
+    # figures): a share of the step well under the attention's
+    share = eshare.split_ms(ops, steps)
+    assert 0 < 100 * sum(share.values()) / programs_ms \
+        < want["swa.layer_share_pct"]
 
 
 def test_the_kernels_in_the_recorded_capture(recorded):
