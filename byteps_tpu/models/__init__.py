@@ -24,6 +24,7 @@ from byteps_tpu.models.qwen3_next import Qwen3Next80BA3B, Qwen3NextModel, Qwen3N
 from byteps_tpu.models.zaya import Zaya1_8B, ZayaModel, ZayaTiny, zaya_loss  # noqa: F401,E501
 from byteps_tpu.models.mellum import Mellum2_12B, MellumModel, MellumTiny, mellum_loss  # noqa: F401,E501
 from byteps_tpu.models.nemotron_h import Nemotron3Nano30BA3B, NemotronHModel, NemotronHTiny, nemotron_h_loss  # noqa: F401,E501
+from byteps_tpu.models.phi4_flash import Phi4FlashModel, Phi4FlashTiny, Phi4MiniFlash, phi4_flash_loss  # noqa: F401,E501
 from byteps_tpu.models.ouro import Ouro2_6B, OuroModel, OuroTiny, ouro_loss, publish_loop_stats  # noqa: F401,E501
 from byteps_tpu.models.olmoe import Olmoe1B7B, OlmoeModel, OlmoeTiny, olmoe_loss  # noqa: F401,E501
 from byteps_tpu.models.transformer import (  # noqa: F401

@@ -1,7 +1,8 @@
 """Gated delta-rule linear attention with a per-channel decay (KDA: Kimi
 Linear, Moonshot AI 2025, arXiv 2510.26692; the public ``fla`` layer
-``KimiDeltaAttention``) as a chunked scan, and its two siblings: one decay
-a head (Gated DeltaNet) and no delta rule at all (Mamba-2's state-space
+``KimiDeltaAttention``) as a chunked scan, and its three siblings: one
+decay a head (Gated DeltaNet), no delta rule at all (Mamba-2's state-space
+scan), and one decay a channel and state entry (Mamba-1's selective
 scan).
 
 Per head, a float32 state ``S`` [d_k, d_v] (keys x values), ``S_0 = 0``:
@@ -133,6 +134,29 @@ under a states-only forward walk). Same guarantees: float32 state, bf16
 operands with float32 accumulation, masked to ``-inf`` before the ``exp``,
 nothing clamped.
 
+**One decay a channel and state entry** (Mamba-1's selective scan, Gu &
+Dao 2023, arXiv 2312.00752; ``selective_scan``): per channel a float32
+state ``S`` [n] under the transition ``exp(dt_t[c] a[c, n])``,
+
+    S_t[c, n] = exp(dt_t[c] a[c, n]) S_{t-1}[c, n] + dt_t[c] b_t[n] x_t[c],
+    y_t[c] = sum_n c_t[n] S_t[c, n].
+
+The decay of a pair of tokens turns on the channel *and* the state entry,
+so no pair product factors into a matmul (the per-head form's ``[C, C]``
+mask, the per-channel form's sub-chunk products and ``ops/ssd_scan.py``'s
+chunked matmuls all need a decay that is one number a head or a key
+channel): what the recurrence needs is 5 elementwise operations a token,
+channel and state entry, and this is that recurrence itself, token by
+token, with no chunk algebra. It shares ``chunked`` and the rule of the
+two-level scan — the state alone is kept from chunk to chunk and a chunk
+of ``SEL_CHUNK`` tokens is recomputed in the backward pass, so that the
+[s, n, channels] history (5.4 GB in float32 at s 16,384 x 16 x 5,120) never
+exists — with the state laid ``[n, channels]``: state entries on sublanes,
+channels on lanes. One form, every backend (``SEL_CHUNK`` has the sweep);
+counted at trace time (``bps_sel_scan_sites_total``). No decay is clamped
+and none cumulated: ``exp`` is taken of a token's own ``dt a <= 0``, and an
+underflow to 0 is the exact float32 value.
+
 **The scan over chunks has two forms too, and the operands' form picks
 it.** Where XLA builds the operands (``"xla"`` and ``"head"``) the scan has
 two levels, groups of chunks and the chunks of a group, and its backward
@@ -180,6 +204,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from byteps_tpu.monitor import metrics
 
@@ -202,6 +227,12 @@ SSM_PREP_SCOPE, SSM_SCAN_SCOPE = "bps.ssm.prep", "bps.ssm.scan"
 SSM_SCAN_SITES = "bps_ssm_scan_sites_total"
 # ... and of those, the ones that took the kernels of ``ops/ssd_scan.py``
 SSM_SCAN_KERNEL_SITES = "bps_ssm_scan_kernel_sites_total"
+# ... and where the decay is one number a channel AND state entry (Mamba-1's
+# selective scan): one form, every backend
+SEL_PREP_SCOPE, SEL_SCAN_SCOPE = "bps.sel.prep", "bps.sel.scan"
+SEL_SCAN_SITES = "bps_sel_scan_sites_total"
+# ``checkpoint_name`` of the states it keeps, one a chunk
+SEL_STATES = "sel_chunk_states"
 # ... and of all scan sites, those whose scan over chunks is the kernel pair
 # of ``byteps_tpu.ops.kda_recurrence`` (both kernel forms': it is their
 # layout it reads)
@@ -246,6 +277,26 @@ HEAD_GROUP = 4
 # of 64 35.5 at 8 and worse above; 512 27.2 at 1. Flat from 2 to 8 at 128:
 # 4 holds half the float32 masks of 8 alive.
 SSD_GROUP = 4
+
+# The tokens of a chunk of ``selective_scan``: the scan keeps one state a
+# chunk and its backward pass recomputes a chunk at a time. On a TPU v5e for
+# the op alone at [1, 16384, 5120] x 16 states, float32, forward | forward +
+# backward (PERF.md section 6, my chip runs, PR 71; two calls): the two loops
+# below — a ``lax.scan`` over a chunk's tokens inside the scan over chunks,
+# in the sweep under ``jax.checkpoint`` and ``jax.grad`` (the committed
+# backward rule does the same work; 8.2 | 26.6 ms at s 8,192, ``tools/
+# scan_check.py --cases sel``) — at 32 tokens 14.0 | 67.6 ms, 64 13.4 | 65.9,
+# 128 13.4 | 88.2, 256 14.7 | 116.4, 1024 13.2 | 125.4; unrolled twice at 64
+# 13.2 | 69.3, four times at 128 12.8 | 94.1. A chunk's tokens written out as one straight
+# elementwise chain (no inner loop): 4 tokens 18.4 | 70.4 (3.0 GB of kept
+# states), 8 16.6 | 74.1, 16 13.3 | 105.0, 32 12.8 | 167.4; the same with
+# the chunk's states stacked and ``C S`` one reduction after the chain 17.3
+# | 73.8 at 8, 16.9 | 84.6 at 16, 16.2 | 133.7 at 32; ``lax.
+# associative_scan`` inside a chunk 42.4 | 115.6 at 16 and 38.6 | 271.3 at
+# 64. Every form's forward pass sits at 0.8 us a token, the float32 state
+# [16, 5120] (320 KB) read and written once a token; the backward pass
+# moves a chunk's states and decays besides.
+SEL_CHUNK = 64
 
 # float32 operands as three bf16 passes: the triangular system's inverse and
 # the products between sub-chunks need more than the one pass a TPU gives a
@@ -702,6 +753,109 @@ def ssd_scan(c, b, x, g, dt, *, chunk: int = 128, dtype=jnp.bfloat16):
             partial(_ssd_group, dtype=dtype),
             (x.shape[0], h, c.shape[3], x.shape[3]), (*tokens, G),
             _divisor(G.shape[1], SSD_GROUP))[:, :s]
+
+
+def sel_chunk_log_decay(dt: jax.Array, a: jax.Array,
+                        chunk: int = 0) -> jax.Array:
+    """[b, ceil(s / chunk), channels] float32: the log-decay a chunk of
+    ``selective_scan`` lays on its channels' fastest state entry, ``min_n
+    a[c, n]`` times the chunk's summed ``dt`` (``chunk`` 0: ``SEL_CHUNK``).
+    The scan multiplies token by token and never forms this number: the
+    gauge says how far a state decays between two kept states."""
+    return (chunked(dt.astype(jnp.float32), chunk or SEL_CHUNK).sum(2)
+            * a.astype(jnp.float32).min(-1))
+
+
+def selective_scan(x, dt, a, b, c, *, chunk: int = 0):
+    """``y`` [b, s, channels] float32 of Mamba-1's selective state-space
+    recurrence (module docstring, "one decay a channel and state entry"):
+    per channel a float32 state ``S`` [n] from zero,
+
+        S_t[c, n] = exp(dt_t[c] a[c, n]) S_{t-1}[c, n] + dt_t[c] b_t[n] x_t[c]
+        y_t[c] = sum_n c_t[n] S_t[c, n]
+
+    without the skip ``D x``: the caller adds it. x, dt [b, s, channels]
+    (dt the step after its softplus), a [channels, n] (< 0), b, c [b, s, n].
+    ``chunk`` (0: ``SEL_CHUNK``) need not divide s: zero tokens (dt 0: a
+    decay of 1 and no write) are appended and dropped."""
+    if not (x.shape == dt.shape and b.shape == c.shape
+            and b.shape[:2] == x.shape[:2]
+            and a.shape == (x.shape[2], b.shape[2])):
+        raise ValueError("selective_scan: x, dt [b, s, channels], a "
+                         "[channels, n], b, c [b, s, n]; got "
+                         f"{x.shape}, {dt.shape}, {a.shape}, {b.shape}, "
+                         f"{c.shape}")
+    metrics.inc_counter(SEL_SCAN_SITES)
+    f32 = jnp.float32
+    size = chunk or SEL_CHUNK
+    with jax.named_scope(SEL_SCAN_SCOPE):
+        # [n_chunks, C, b, ...]: both scans' leading axes
+        dt = dt.astype(f32)
+        tokens = tuple(jnp.moveaxis(chunked(t.astype(f32), size), 0, 2)
+                       for t in (dt, dt * x.astype(f32), b, c))
+        y = _sel_chunks(a.astype(f32).T, tokens)  # [n_chunks, C, b, channels]
+        return jnp.moveaxis(y.reshape(-1, *y.shape[2:]), 0, 1)[:, :x.shape[1]]
+
+
+def _sel_chunk(a_t, state, tokens):
+    """One chunk from ``state`` [b, n, channels] — state entries on
+    sublanes, channels on lanes — under ``a_t`` [n, channels]: a ``lax.scan``
+    over the chunk's tokens, each its dt, u = dt x [b, channels] and b, c
+    [b, n]. Returns (the state after the chunk, y [C, b, channels])."""
+    def token(state, inputs):
+        dt_t, u_t, b_t, c_t = inputs
+        state = (jnp.exp(dt_t[:, None, :] * a_t) * state
+                 + b_t[:, :, None] * u_t[:, None, :])
+        return state, (c_t[:, :, None] * state).sum(1)
+
+    return lax.scan(token, state, tokens)
+
+
+def _sel_forward(a_t, tokens):
+    """(y [n_chunks, C, b, channels], every chunk's first state [n_chunks,
+    b, n, channels]): the state alone is kept from chunk to chunk."""
+    dt = tokens[0]
+
+    def chunk(state, chunk_tokens):
+        after, y = _sel_chunk(a_t, state, chunk_tokens)
+        return after, (y, state)
+
+    return lax.scan(chunk, jnp.zeros((dt.shape[2], *a_t.shape), jnp.float32),
+                    tokens)[1]
+
+
+@jax.custom_vjp
+def _sel_chunks(a_t, tokens):
+    return _sel_forward(a_t, tokens)[0]
+
+
+def _sel_chunks_fwd(a_t, tokens):
+    y, first = _sel_forward(a_t, tokens)
+    # named, so that a caller's recomputation can keep them beside ``y`` and
+    # then has no forward scan left to run again
+    return y, (a_t, tokens, checkpoint_name(first, SEL_STATES))
+
+
+def _sel_chunks_bwd(res, dy):
+    """Chunk by chunk from the last: a chunk is computed again from its kept
+    first state and differentiated (``jax.vjp``): its C states and decays are
+    the most that is alive of the [s, n, channels] history."""
+    a_t, tokens, first = res
+
+    def chunk(carry, inputs):
+        d_after, d_a = carry
+        state, chunk_tokens, dy_c = inputs
+        d_a_c, d_state, d_tokens = jax.vjp(
+            _sel_chunk, a_t, state, chunk_tokens)[1]((d_after, dy_c))
+        return (d_state, d_a + d_a_c), d_tokens
+
+    (_, d_a), d_tokens = lax.scan(
+        chunk, (jnp.zeros_like(first[0]), jnp.zeros_like(a_t)),
+        (first, tokens, dy), reverse=True)
+    return d_a, d_tokens
+
+
+_sel_chunks.defvjp(_sel_chunks_fwd, _sel_chunks_bwd)
 
 
 def publish_kda_stats(kda_stats,

@@ -43,6 +43,10 @@ def _interpreted(q, k, v, window, scale=None):
         "window_minus_1", "window_plus_1", "heads_interleaved"}),
     (("global_32_over_4", 96, 8, 2, 16, None, None, 2),
      {"heads_interleaved"}),
+    # the Phi-4-mini-flash cell's: two query heads a key head
+    (("dattn_windowed_512", 160, 8, 4, 16, 48), {
+        "window_minus_1", "window_plus_1", "heads_interleaved"}),
+    (("dattn_causal", 96, 8, 4, 16, None), {"heads_interleaved"}),
 ], ids=lambda value: value[0] if isinstance(value, tuple) else "")
 def test_the_check_passes_the_kernel_and_fails_its_controls(
         tool, case, controls):
@@ -126,6 +130,20 @@ def test_the_mellum_cases_are_the_configuration_s(tool):
         assert case.value_dim is None
     assert (windowed.window, full.window) == (cfg["sliding_window"], None)
     assert set(cfg["layer_types"]) == {"sliding_attention", "full_attention"}
+
+
+def test_the_differential_cases_are_the_configuration_s(tool):
+    cfg = _config("phi-4-mini-flash-reasoning")
+    windowed, full = (c for c in tool.CELL_CASES
+                      if c.name in ("dattn_windowed_512", "dattn_causal"))
+    for case in (windowed, full):
+        assert (case.batch, case.seq) == (cfg["batch_per_chip"],
+                                          cfg["seq_len"])
+        assert (case.heads, case.kv_heads, case.head_dim) == (
+            cfg["num_attention_heads"], cfg["num_key_value_heads"],
+            cfg["hidden_size"] // cfg["num_attention_heads"])
+        assert case.value_dim is None
+    assert (windowed.window, full.window) == (cfg["sliding_window"], None)
 
 
 def test_the_dense_case_is_the_gpt2_configuration(tool):

@@ -876,3 +876,57 @@ def test_gpt2_124m_ps_gradients_leave_the_chip_row_major(topo, as_on_a_tpu):
         assert out.ndim == (leaf.ndim if leaf.ndim <= 2 else 1)
         assert fmt.layout.major_to_minor == tuple(range(out.ndim)), (
             leaf.shape, fmt)
+
+
+def test_phi4_flash_collective_step_compiles_for_one_v5e(topo, as_on_a_tpu):
+    """make_train_step over Phi4FlashModel at the cell's widths and size,
+    the whole cut (published layers 0, 1, 16, 17, 18, 19: every kind of
+    layer the model has), adamw, for one described chip: under the 15.0 GB
+    the sequence length's rule asks (``compiled_bytes`` in the
+    configuration's file), the flash kernels at 40 / 20 x 64 with the fused
+    backward, the convolution's kernel at 5,120 channels, the selective
+    scan as XLA's loops (no kernel of another scan), and every scope the
+    cell's readers read."""
+    import sys
+
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    import byteps_tpu.jax as bps
+    from byteps_tpu.jax.training import make_train_step
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmark.lib import cell as cell_lib
+
+    path = os.path.join(repo, "benchmark", "configs",
+                        "phi-4-mini-flash-reasoning")
+    cfg = cell_lib.load_json(path + ".json")
+    init, loss_fn = cell_lib.load_module(
+        path + ".py", "phi4_flash_config").build(cfg)
+    tx = optax.adamw(1e-4)
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dcn", "ici"))
+    bps.init(mesh=mesh)
+    step = make_train_step(loss_fn, tx)
+    params = jax.eval_shape(init, jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((1, cfg["seq_len"]), jnp.int32)}
+    compiled = step.lower(
+        _described(mesh, params, P()),
+        _described(mesh, jax.eval_shape(tx.init, params), P()),
+        _described(mesh, batch, P(("dcn", "ici")))).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.0e9
+    text = compiled.as_text()
+    assert "bps_flash_fwd" in text and "bps_flash_bwd" in text
+    assert "bps_flash_dq" not in text       # the fused backward, not the pair
+    assert "bps_causal_conv_fwd" in text
+    for scope in ("bps.sel.proj", "bps.sel.prep", "bps.sel.scan",
+                  "bps.sel.out", "bps.dattn.proj", "bps.dattn.window",
+                  "bps.dattn.full", "bps.dattn.cross", "bps.dattn.diff",
+                  "bps.gmu"):
+        assert scope in text, scope
+    for other in ("bps_ssd_scan", "bps_kda_recurrence", "bps_gdn"):
+        assert other not in text, other

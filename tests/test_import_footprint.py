@@ -28,13 +28,16 @@ imported = set(sys.modules)
 
 import jax, jax.numpy as jnp, numpy as np
 from byteps_tpu.models import (KeyeTiny, MellumTiny, NemotronHTiny, OlmoeTiny,
-                               OuroTiny, keye_loss, mellum_loss,
-                               nemotron_h_loss, olmoe_loss, ouro_loss)
+                               OuroTiny, Phi4FlashTiny, keye_loss, mellum_loss,
+                               nemotron_h_loss, olmoe_loss, ouro_loss,
+                               phi4_flash_loss)
 for tiny, loss, seq in ((OlmoeTiny, olmoe_loss, 16), (KeyeTiny, keye_loss, 32),
                         (OuroTiny, lambda out, tokens: ouro_loss(out), 16),
                         (MellumTiny, lambda out, tokens: mellum_loss(out), 16),
                         (NemotronHTiny,
-                         lambda out, tokens: nemotron_h_loss(out), 16)):
+                         lambda out, tokens: nemotron_h_loss(out), 16),
+                        (Phi4FlashTiny,
+                         lambda out, tokens: phi4_flash_loss(out), 16)):
     model = tiny()
     tokens = np.zeros((1, seq), np.int32)
     params = model.init(jax.random.PRNGKey(0), tokens)
@@ -66,7 +69,8 @@ def test_importing_the_library_loads_no_kernel_library(loaded):
                    "byteps_tpu.models.keye",
                    "byteps_tpu.parallel.sparse_attention",
                    "byteps_tpu.models.ouro", "byteps_tpu.models.mellum",
-                   "byteps_tpu.models.nemotron_h"):
+                   "byteps_tpu.models.nemotron_h",
+                   "byteps_tpu.models.phi4_flash"):
         assert module in loaded["imported"]
     assert _kernel_modules(loaded["imported"]) == []
 
@@ -80,7 +84,8 @@ def test_the_expert_model_loads_only_what_its_grouped_matmul_needs(loaded):
     the tiny MellumModel (windowed and global attention, a share of the
     experts with no shared one), nor the tiny NemotronHModel (the
     state-space scan in XLA, ungated experts, attention with no
-    rotation)."""
+    rotation), nor the tiny Phi4FlashModel (the selective scan, differential
+    attention, the handed memory and key-value pair)."""
     new = loaded["by_the_model"]
     assert _kernel_modules(new) == []
     assert [n for n in new if n.startswith("byteps_tpu")] == []

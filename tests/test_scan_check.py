@@ -252,3 +252,47 @@ def test_the_convolution_cases_are_the_configurations():
     for case in tool.conv_cases():
         assert conv_form("tpu", case.seq, case.channels,
                          case.taps) == "kernel"
+
+
+SMALL_SEL = tool.SelCase(512, 24, 4, 16)
+
+
+@pytest.mark.parametrize("seed", (0, 2147483907))
+def test_the_selective_check_passes_the_scan_and_fails_its_controls(seed):
+    """The scan with one decay a channel and state entry at a small size:
+    the program's form within ``SEL_TOLERANCE`` in ``y`` and all five
+    gradients, and a bf16 state, a chunk's cumulated log-decay clamped at
+    -20 and one decay a channel each over it."""
+    record = tool.check_sel(SMALL_SEL, seed)
+    assert record["ok"], record
+    assert set(record["scan"]) == set(tool.SEL_TENSORS)
+    assert max(record["scan"].values()) <= tool.SEL_TOLERANCE
+    assert set(record["controls"]) == {"bf16_state", "clamped_at_-20",
+                                       "one_decay_a_channel"}
+    for control in record["controls"].values():
+        assert max(control.values()) > tool.SEL_TOLERANCE
+    assert record["min_chunk_log_decay"] < tool.CLAMP
+
+
+def test_a_wrong_selective_scan_fails_the_check():
+    """The program computed wrongly (its state entries read one decay a
+    channel, the first entry's) reads above the tolerance."""
+    from byteps_tpu.parallel.linear_attention import selective_scan
+
+    record = tool.check_sel(
+        SMALL_SEL, 1, scan=lambda x, dt, a, b, c: selective_scan(
+            x, dt, jnp.broadcast_to(a[:, :1], a.shape), b, c))
+    assert not record["ok"]
+    assert max(record["scan"].values()) > tool.SEL_TOLERANCE
+
+
+def test_the_selective_case_is_the_configuration_s():
+    from byteps_tpu.parallel.linear_attention import SEL_CHUNK
+
+    cfg = json.load(open(os.path.join(
+        REPO, "benchmark", "configs", "phi-4-mini-flash-reasoning.json")))
+    assert tool.sel_case() == (cfg["seq_len"], 5120, 16, SEL_CHUNK)
+    x, dt, a, b, c, w = tool.sel_inputs(SMALL_SEL, 0)
+    assert x.shape == dt.shape == w.shape == (1, 512, 24)
+    assert b.shape == c.shape == (1, 512, 4) and a.shape == (24, 4)
+    assert float(dt.min()) > 0.0 and float(a.max()) <= -1.0

@@ -58,6 +58,13 @@ Since PR 58 two cases more are the Mellum2 cell's calls (``windowed_1024``,
 two sequences of 8,192 in one call, under a window of 1024 and under the
 causal triangle; ``--cases a,b`` runs the named cases alone.
 
+Since PR 71 two cases more are the Phi-4-mini-flash cell's calls
+(``dattn_windowed_512``, ``dattn_causal``): differential attention's two
+softmax maps as the model sends them, 40 query heads over 20 key heads of
+64 — the 64-wide kernels GPT-2 runs, here grouped and, in one, under a
+window of 512 — one sequence of 8,192; their controls are the band one key
+off and head i reading key head ``i % 20``.
+
 One JSON line a case, then ``{"ok": ..., "device": ...}``; off the chip the
 kernels are interpreted at a small size (``tests/test_attention_check.py``).
 """
@@ -107,7 +114,12 @@ CELL_CASES = (Case("windowed", 8192, 64, 8, 128, 512),
               # the Mellum2 cell's two calls (mellum2-12b-a2.5b.json): 32
               # query heads over 4 key heads, two sequences in one call
               Case("windowed_1024", 8192, 32, 4, 128, 1024, batch=2),
-              Case("global_32_over_4", 8192, 32, 4, 128, None, batch=2))
+              Case("global_32_over_4", 8192, 32, 4, 128, None, batch=2),
+              # the Phi-4-mini-flash cell's calls
+              # (phi-4-mini-flash-reasoning.json): differential attention's
+              # two maps, 40 query heads over 20 key heads of 64
+              Case("dattn_windowed_512", 8192, 40, 20, 64, 512),
+              Case("dattn_causal", 8192, 40, 20, 64, None))
 
 
 def _relative(got, want) -> float:

@@ -98,6 +98,22 @@ record also times the chain alone, forward + backward, in the picked form
 and in the XLA form (``ms``). ``--cases gate`` runs it alone, in about two
 minutes.
 
+**The selective scan** (since PR 71): ``selective_scan`` — Mamba-1's
+recurrence, one decay a channel *and* state entry, the one form every
+backend runs — at the Phi-4-mini-flash cell's shapes from the
+configuration's file (``[1, s, 5120]`` under 16 state entries, float32):
+``y`` and the gradients of x, the step, ``A``, ``B`` and ``C`` against
+``benchmark/lib/plain_phi4_flash.py::selective_scan``, the recurrence token
+by token, each by ``|got - want|_2 / |want|_2`` within ``SEL_TOLERANCE``
+(float32 elementwise arithmetic on both sides; what differs is the order of
+a sum over 16 entries, and over the sequence for ``A``'s gradient). The
+controls are that reference computed wrongly: a state rounded to bf16 after
+every token, the log-decay cumulated inside the scan's chunks clamped at
+-20, and one decay a channel (``A``'s mean over the state entries: the
+transition Mamba-2 has). On the chip the record also times the op alone,
+forward and forward + backward (``ms``). ``--cases sel`` runs it alone, in
+about two minutes.
+
 My chip run's readings (PR 50) are in PERF.md section 6. One JSON line a
 case, then ``{"ok": ..., "device": ...}``; off the chip both run at a small
 size (``tests/test_scan_check.py``).
@@ -123,6 +139,10 @@ TENSORS = ("o", "dq", "dk", "dv", "dg", "dbeta")
 
 SSD_TENSORS = ("y", "dx", "db", "dc", "ddt", "da_log")
 TIMED_RUNS = 10             # calls of the op alone a timing is the median of
+SEL_TENSORS = ("y", "dx", "ddt", "da", "db", "dc")
+# the selective scan's tensors: float32 elementwise on both sides (PERF.md
+# section 6, PR 71, has the readings this lies between)
+SEL_TOLERANCE = 1.0e-4
 
 # the short convolution's tensors, each with its own tolerance: y is the
 # same float32 arithmetic up to how a multiply-add rounds; dx is rounded
@@ -161,6 +181,10 @@ GateCase = collections.namedtuple(
     "GateCase", "seq heads head_dim groups state")
 
 
+# one decay a channel and state entry: ``channels`` under ``state`` entries
+SelCase = collections.namedtuple("SelCase", "seq channels state chunk")
+
+
 def cell_cases():
     """``(the scan's case, the attention's)`` from the configuration's
     file."""
@@ -196,6 +220,17 @@ def ssd_case() -> SsdCase:
     return SsdCase(cfg["seq_len"], cfg["n_groups"], cfg["mamba_num_heads"],
                    cfg["ssm_state_size"], cfg["mamba_head_dim"],
                    cfg["ssm_chunk"])
+
+
+def sel_case() -> SelCase:
+    """The Phi-4-mini-flash cell's scan from its configuration's file."""
+    from byteps_tpu.parallel.linear_attention import SEL_CHUNK
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "phi-4-mini-flash-reasoning.json")) as f:
+        cfg = json.load(f)
+    return SelCase(cfg["seq_len"], cfg["mamba_expand"] * cfg["hidden_size"],
+                   cfg["mamba_d_state"], SEL_CHUNK)
 
 
 def gate_case() -> GateCase:
@@ -405,6 +440,104 @@ def check_ssd(case: SsdCase, seed: int, scan=None) -> dict:
                 i % case.groups for i in range(case.heads)]), operands, w))},
         "tolerance": SCAN_TOLERANCE}
     return _judged(record)
+
+
+def sel_inputs(case: SelCase, seed: int):
+    """(x, dt, A, B, C, cotangent), float32, b 1: x, B, C and the cotangent
+    standard normal; the step ``softplus`` of a unit normal around Mamba's
+    initial steps spread over the channels from 1e-3 to 1 (so that the fast
+    channels' chunks pass the controls' floor); ``A = -exp(log U(1, 16))`` a
+    channel and entry, the model's initialisation."""
+    import jax
+    import jax.numpy as jnp
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    s, ch, n = case.seq, case.channels, case.state
+    dt = 1e-3 * 1000.0 ** (jnp.arange(ch, dtype=jnp.float32) / max(ch - 1, 1))
+    return (jax.random.normal(keys[0], (1, s, ch)),
+            jax.nn.softplus(jax.random.normal(keys[1], (1, s, ch))
+                            + dt + jnp.log(-jnp.expm1(-dt))),
+            -jax.random.uniform(keys[2], (ch, n), minval=1.0, maxval=16.0),
+            jax.random.normal(keys[3], (1, s, n)),
+            jax.random.normal(keys[4], (1, s, n)),
+            jax.random.normal(keys[5], (1, s, ch)))
+
+
+def check_sel(case: SelCase, seed: int, scan=None) -> dict:
+    """The program's selective scan (``scan(x, dt, A, B, C)``, by default
+    ``selective_scan`` at the case's chunk) against ``benchmark/lib/
+    plain_phi4_flash.py::selective_scan``, the recurrence token by token in
+    float32: ``y`` and the five gradients, each by ``|got - want|_2 /
+    |want|_2`` within ``SEL_TOLERANCE``, and the controls: a state rounded
+    to bf16 after every token, a chunk's cumulated log-decay clamped at -20
+    and one decay a channel."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.lib.plain_phi4_flash import selective_scan as plain_scan
+
+    *operands, w = sel_inputs(case, seed)
+    if scan is None:
+        from byteps_tpu.parallel.linear_attention import selective_scan
+
+        def scan(x, dt, a, b, c):
+            return selective_scan(x, dt, a, b, c, chunk=case.chunk)
+
+    block = min(128, case.seq)
+
+    def plain(**wrong):
+        def fn(x, dt, a, b, c):
+            return plain_scan(x[0], dt[0], a, b[0], c[0], scan_block=block,
+                              **wrong)[None]
+
+        return fn
+
+    def readings(got):
+        return dict(zip(SEL_TENSORS,
+                        map(attention_check._relative, got, want)))
+
+    want = _outputs(plain(), operands, w)
+    record = {
+        "case": case._asdict(), "seed": seed,
+        "min_chunk_log_decay": float(
+            (cumulated_in_chunks(operands[1], case.chunk)[:, :, -1]
+             * operands[2].min(-1)).min()),
+        "scan": readings(_outputs(scan, operands, w)),
+        "controls": {
+            "bf16_state": readings(_outputs(
+                plain(state_dtype=jnp.bfloat16), operands, w)),
+            "clamped_at_-20": readings(_outputs(
+                plain(decay_floor=CLAMP, floor_chunk=case.chunk), operands,
+                w)),
+            "one_decay_a_channel": readings(_outputs(
+                plain(per_channel=True), operands, w))},
+        "tolerance": SEL_TOLERANCE}
+    record["ok"] = bool(
+        max(record["scan"].values()) <= SEL_TOLERANCE
+        and all(max(c.values()) > SEL_TOLERANCE
+                for c in record["controls"].values()))
+    return record
+
+
+def time_sel(case: SelCase, seed: int) -> dict:
+    """Milliseconds of ``selective_scan`` alone at the case's shapes:
+    ``forward``, and ``forward_backward`` under a cotangent (all five
+    gradients) — each the median of ``TIMED_RUNS`` calls after one that
+    compiles."""
+    import jax
+
+    from byteps_tpu.parallel.linear_attention import selective_scan
+
+    *operands, w = sel_inputs(case, seed)
+
+    def loss(x, dt, a, b, c, w):
+        return (selective_scan(x, dt, a, b, c, chunk=case.chunk) * w).sum()
+
+    return {
+        "forward": _median_ms(jax.jit(
+            lambda *a: selective_scan(*a, chunk=case.chunk)), operands),
+        "forward_backward": _median_ms(jax.jit(jax.grad(
+            loss, argnums=(0, 1, 2, 3, 4))), (*operands, w))}
 
 
 def _median_ms(fn, operands) -> float:
@@ -732,8 +865,8 @@ def check_scan(case, seed: int, scan=None) -> dict:
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--cases", default="gdn,kda,attention,ssd,conv,gate",
-                    help="which of the six to run, by name")
+    ap.add_argument("--cases", default="gdn,kda,attention,ssd,conv,gate,sel",
+                    help="which of the seven to run, by name")
     args = ap.parse_args()
     cases = args.cases.split(",")
 
@@ -760,6 +893,11 @@ def main() -> int:
         if "gate" in cases:
             record = check_gate(gate_case(), args.seed)
             record["ms"] = time_gate(gate_case(), args.seed)
+            ok = ok and record["ok"]
+            print(json.dumps(record), flush=True)
+        if "sel" in cases:
+            record = check_sel(sel_case(), args.seed)
+            record["ms"] = time_sel(sel_case(), args.seed)
             ok = ok and record["ok"]
             print(json.dumps(record), flush=True)
         if "attention" in cases:
